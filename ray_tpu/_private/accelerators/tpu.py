@@ -2,8 +2,9 @@
 
 Behavioral parity with the reference's TPU support (reference:
 ``python/ray/_private/accelerators/tpu.py:75-398``): chips are detected from
-``/dev/accel*`` / ``/dev/vfio`` or env overrides; per-task chip visibility is
-granted via ``TPU_VISIBLE_CHIPS`` (+ host-bounds vars); multi-host pod slices
+``/dev/accel*`` / ``/dev/vfio`` or env overrides; a process is bound to its
+leased chips via ``TPU_VISIBLE_CHIPS`` (+ the process-bounds vars when it sees
+fewer chips than the host has) and the TPU platform by name; multi-host pod slices
 advertise a ``{slice_name}: 1`` resource on every host plus a
 ``TPU-{pod_type}-head: 1`` resource on worker 0, so a driver can schedule one
 task on the slice head and fan SPMD tasks out to every host of the slice.
@@ -30,6 +31,17 @@ ENV_SLICE_NAME = "TPU_NAME"                    # slice/pod name
 ENV_CHIPS_PER_HOST_BOUNDS = "TPU_CHIPS_PER_HOST_BOUNDS"
 ENV_HOST_BOUNDS = "TPU_HOST_BOUNDS"
 ENV_VISIBLE_CHIPS = "TPU_VISIBLE_CHIPS"
+ENV_CHIPS_PER_PROCESS_BOUNDS = "TPU_CHIPS_PER_PROCESS_BOUNDS"
+ENV_PROCESS_BOUNDS = "TPU_PROCESS_BOUNDS"
+
+# A process that sees fewer chips than the host has must be told the box
+# they form; libtpu 0.0.34 aborts on a box that does not match. One chip is
+# 1,1,1 whichever it is (measured: four such processes side by side on a
+# v5litepod-4). Pairs are for the 2x2 host, whose chips sit at (x, y) =
+# (0,0) (1,0) (0,1) (1,1) in id order (jax device coords): chips 2,3 ->
+# 2,1,1 was measured, the others follow from the coordinates.
+_PAIR_BOUNDS_2X2 = {(0, 1): "2,1,1", (2, 3): "2,1,1",
+                    (0, 2): "1,2,1", (1, 3): "1,2,1"}
 
 VALID_CHIP_REQUESTS = (1, 2, 4, 8)
 
@@ -125,7 +137,24 @@ class TPUAcceleratorManager(AcceleratorManager):
 
     @staticmethod
     def set_visible_accelerator_ids(ids: List[int]) -> None:
+        """Bind this process to its leased chips. Must run before the
+        process first imports jax. The platform is named, not left to
+        jax's own choice: a chip that does not come up then raises instead
+        of giving way to the CPU (``cpu`` stays listed for host arrays; an
+        explicit list fails loudly on every entry)."""
+        ids = sorted(int(i) for i in ids)
         os.environ[ENV_VISIBLE_CHIPS] = ",".join(str(i) for i in ids)
+        os.environ["JAX_PLATFORMS"] = "tpu,cpu"
+        on_node = TPUAcceleratorManager.get_current_node_num_accelerators()
+        if len(ids) < on_node:
+            bounds = ("1,1,1" if len(ids) == 1
+                      else _PAIR_BOUNDS_2X2.get(tuple(ids)))
+            if bounds is None:
+                raise ValueError(
+                    f"TPU chips {ids} of {on_node} form no box libtpu can "
+                    "be given; lease 1, an aligned pair, or the whole host")
+            os.environ[ENV_CHIPS_PER_PROCESS_BOUNDS] = bounds
+            os.environ[ENV_PROCESS_BOUNDS] = "1,1,1"
 
     @staticmethod
     def get_current_node_additional_resources() -> Dict[str, float]:

@@ -218,7 +218,11 @@ class NodeResources:
                 self.assigned_instances[owner].setdefault(name, []).extend(ids)
         return assigned
 
-    def release(self, request: ResourceSet, owner: str = "") -> None:
+    def release(self, request: ResourceSet, owner: str = "",
+                instances: Optional[Dict[str, list]] = None) -> None:
+        """Give ``request`` back, with the instance ids recorded under
+        ``owner`` or, for a holder that kept its own ids (a placement-group
+        bundle), the ``instances`` it names."""
         self.available.add(request)
         # Clamp: never exceed total (defensive against double-release).
         for name, total_fp in self.total.to_wire().items():
@@ -228,8 +232,10 @@ class NodeResources:
                     {**self.available.to_wire(), name: total_fp}
                 )
         if owner and owner in self.assigned_instances:
-            for name, ids in self.assigned_instances.pop(owner).items():
-                self.free_instances.setdefault(name, []).extend(sorted(ids))
+            instances = self.assigned_instances.pop(owner)
+        for name, ids in (instances or {}).items():
+            self.free_instances[name] = sorted(
+                self.free_instances.get(name, []) + list(ids))
 
     def to_wire(self) -> Dict:
         return {

@@ -724,10 +724,12 @@ def _u32(i: int) -> bytes:
 
 
 def _apply_accelerator_env(assigned: Dict[str, List[int]]) -> None:
-    if "TPU" in assigned:
-        chips = ",".join(str(i) for i in assigned["TPU"])
-        os.environ["TPU_VISIBLE_CHIPS"] = chips
-        os.environ.pop("JAX_PLATFORMS", None)
+    if assigned.get("TPU"):
+        from ray_tpu._private.accelerators.tpu import TPUAcceleratorManager
+        from ray_tpu._private.compile_cache import place_compile_cache
+
+        TPUAcceleratorManager.set_visible_accelerator_ids(assigned["TPU"])
+        place_compile_cache()
     if "GPU" in assigned:
         os.environ["CUDA_VISIBLE_DEVICES"] = ",".join(
             str(i) for i in assigned["GPU"]

@@ -35,10 +35,10 @@ def _ring_seq_attention(q, k, v):
     """Sequence-parallel exact attention: shard_map over the ambient mesh's
     ``seq`` axis; kv chunks ride the ICI ring (ops.ring_attention)."""
     from ray_tpu.ops.ring_attention import ring_attention
-    from ray_tpu.parallel.sharding import compat_shard_map, logical_to_spec
+    from ray_tpu.parallel.sharding import logical_to_spec
 
     qs = logical_to_spec(("batch", "seq", "heads", "head_dim"))
-    fn = compat_shard_map(
+    fn = jax.shard_map(
         partial(ring_attention, axis_name="seq", causal=True),
         in_specs=(qs, qs, qs), out_specs=qs, check_vma=False)
     return fn(q, k, v)
@@ -68,6 +68,17 @@ class LlamaConfig:
     @staticmethod
     def llama2_7b() -> "LlamaConfig":
         return LlamaConfig()
+
+    @staticmethod
+    def llama2_7b_smoke() -> "LlamaConfig":
+        """Llama-2-7B at full width (hidden 4096, MLP 11008, 32 heads x
+        128, vocabulary 32000, sequence 2048), cut to 2 layers: 667M
+        parameters, so fp32 params + AdamW moments + grads (16 B each) and
+        a batch of 4 fit one 16 GB v5e chip (8.0 GB resident after a step,
+        chip_smoke.py, PR 21). The flash kernel is named, not chosen; the
+        chunked loss keeps the fp32 logits to B x 512 x V."""
+        return LlamaConfig(num_layers=2, max_seq_len=2048, attn_impl="flash",
+                           loss_chunk=512)
 
     @staticmethod
     def tiny(vocab_size: int = 256) -> "LlamaConfig":

@@ -6,6 +6,7 @@ H % KVH == 0. Returns [B, S, H, D] in q.dtype.
 
 from __future__ import annotations
 
+import warnings
 from typing import Optional
 
 import jax
@@ -51,11 +52,27 @@ def reference_attention(
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+def _flash_per_shard(q: jax.Array, k: jax.Array, v: jax.Array,
+                     causal: bool) -> jax.Array:
+    """The Pallas kernel, run on each device's own shard when a mesh is in
+    scope. A ``pallas_call`` has no partitioning rule: left to GSPMD its
+    operands are all-gathered and every chip computes the whole batch."""
+    from ray_tpu.ops.pallas.flash_attention import flash_attention
+    from ray_tpu.parallel.sharding import ambient_mesh, logical_to_spec
+
+    mesh = ambient_mesh()
+    if mesh is None or mesh.size == 1:
+        return flash_attention(q, k, v, causal)
+    if mesh.shape.get("seq", 1) > 1:
+        raise ValueError(
+            "flash attention keeps each sequence on one device; a mesh "
+            "with seq > 1 needs the model layer's 'ring_seq' path")
+    q_spec = logical_to_spec(("batch", "seq", "heads", "head_dim"))
+    kv_spec = logical_to_spec(("batch", "seq", "kv_heads", "head_dim"))
+    return jax.shard_map(
+        lambda q, k, v: flash_attention(q, k, v, causal),
+        in_specs=(q_spec, kv_spec, kv_spec), out_specs=q_spec,
+        check_vma=False)(q, k, v)
 
 
 def attention(
@@ -64,28 +81,43 @@ def attention(
     q_offset: Optional[jax.Array] = None,
     valid_kv_len: Optional[jax.Array] = None,
 ) -> jax.Array:
-    """impl: auto (flash on TPU when shapes allow, else reference), flash,
-    blockwise (scan over KV blocks; memory-efficient fwd AND bwd),
-    reference. Ring attention is invoked explicitly via ops.ring_attention
-    by the seq-parallel layer, not through this dispatcher."""
+    """impl: auto (on the TPU platform the flash kernel, on the CPU platform
+    the reference), flash, blockwise (scan over KV blocks; memory-efficient
+    fwd AND bwd), reference. Ring attention is invoked explicitly via
+    ops.ring_attention by the seq-parallel layer, not through this
+    dispatcher.
+
+    ``auto`` on a TPU still takes the reference for what the kernel has no
+    path for (cached decode, lengths off the 128 grid, head dims off the
+    lane width) and says so once per shape; ``flash`` raises there."""
     if impl == "auto":
-        use_flash = (
-            _on_tpu() and q_offset is None and valid_kv_len is None
-            and q.shape[1] == k.shape[1]
-            and q.shape[1] % 128 == 0 and q.shape[3] % 128 == 0
-        )
-        impl = "flash" if use_flash else "reference"
-    if impl == "flash":
-        from ray_tpu.ops.pallas.flash_attention import flash_attention
-        return flash_attention(q, k, v, causal=causal)
-    if impl == "blockwise":
-        # pure-JAX memory-efficient path (scan over KV blocks) for
-        # platforms without Pallas; flash handles GQA natively now
-        # (fwd + bwd). Decode-time kwargs are not supported here.
+        platform = jax.default_backend()
+        if platform not in ("tpu", "cpu"):
+            raise RuntimeError(
+                f"attention impl 'auto' knows the tpu and cpu platforms, "
+                f"not {platform!r}; name an impl")
+        impl = "reference"
+        if platform == "tpu":
+            kernel_takes_it = (
+                q_offset is None and valid_kv_len is None
+                and q.shape[1] == k.shape[1]
+                and q.shape[1] % 128 == 0 and q.shape[3] % 128 == 0)
+            if kernel_takes_it:
+                impl = "flash"
+            else:
+                warnings.warn(
+                    "attention impl 'auto' on TPU: reference path for "
+                    f"q{tuple(q.shape)} k{tuple(k.shape)} (cached decode, "
+                    "or a shape off the flash kernel's 128 grid)",
+                    stacklevel=2)
+    if impl in ("flash", "blockwise"):
         if q_offset is not None or valid_kv_len is not None:
             raise NotImplementedError(
-                "blockwise attention does not support q_offset/"
-                "valid_kv_len; use impl='reference' for cached decode")
+                f"{impl} attention does not support q_offset/valid_kv_len; "
+                "use impl='reference' for cached decode")
+        if impl == "flash":
+            return _flash_per_shard(q, k, v, causal)
+        # pure-JAX memory-efficient path (scan over KV blocks)
         from ray_tpu.ops.blockwise_attention import blockwise_attention
         return blockwise_attention(q, k, v, causal=causal)
     if impl != "reference":

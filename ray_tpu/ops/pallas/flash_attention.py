@@ -33,6 +33,20 @@ from jax.experimental.pallas import tpu as pltpu
 _NEG_INF = -1e30
 
 
+def _interpret() -> bool:
+    """Compile for the TPU; interpret on the CPU platform, which a caller
+    gets only by asking for it (JAX_PLATFORMS=cpu — the tests). Any other
+    platform has no path here and is an error, not an interpreted run."""
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(
+        f"flash attention runs on the tpu platform (compiled) or the cpu "
+        f"platform (interpreted), not {platform!r}")
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
                 *, scale: float, causal: bool, block_q: int, block_k: int):
     iq = pl.program_id(2)
@@ -136,7 +150,7 @@ def _flash_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
-        interpret=jax.devices()[0].platform != "tpu",
+        interpret=_interpret(),
     )(q, k, v)
 
 
@@ -290,7 +304,7 @@ def _flash_bwd(q, k, v, o, lse, do, *, causal: bool, block_q: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
-        interpret=jax.devices()[0].platform != "tpu",
+        interpret=_interpret(),
     )(q, k, v, do, lse, delta)
 
     # dk/dv: grid (B, KVH, ik, r, iq) — r walks the kv head's query group
@@ -317,7 +331,7 @@ def _flash_bwd(q, k, v, o, lse, do, *, causal: bool, block_q: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary", "arbitrary")),
-        interpret=jax.devices()[0].platform != "tpu",
+        interpret=_interpret(),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 

@@ -13,7 +13,6 @@ from ray_tpu.parallel.mesh import (
     MeshConfig,
     create_mesh,
     best_mesh_shape,
-    local_mesh,
 )
 from ray_tpu.parallel.sharding import (
     LogicalAxisRules,
@@ -31,7 +30,7 @@ from ray_tpu.parallel.train_step import (
 )
 
 __all__ = [
-    "MeshConfig", "create_mesh", "best_mesh_shape", "local_mesh",
+    "MeshConfig", "create_mesh", "best_mesh_shape",
     "LogicalAxisRules", "DEFAULT_RULES", "logical_to_spec", "shard_pytree",
     "constrain", "param_shardings",
     "TrainState", "create_train_state", "make_train_step", "make_eval_step",

@@ -112,7 +112,3 @@ def create_mesh(
         dev_array = np.array(devices).reshape(shape)
     return Mesh(dev_array, AXIS_ORDER)
 
-
-def local_mesh() -> Mesh:
-    """1-device mesh (all axes size 1 except data) for single-chip paths."""
-    return create_mesh(MeshConfig(data=-1), devices=jax.devices()[:1])

@@ -146,9 +146,7 @@ def pipeline_apply(
             jnp.where(stage == S - 1, outs, jnp.zeros_like(outs)), axis)
         return outs
 
-    from ray_tpu.parallel.sharding import compat_shard_map
-
-    shard = compat_shard_map(
+    shard = jax.shard_map(
         staged, mesh=mesh,
         in_specs=(stage_param_spec(stage_params, axis), micro_spec),
         out_specs=micro_spec,

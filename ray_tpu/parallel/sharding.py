@@ -79,53 +79,9 @@ def shard_pytree(tree: Any, shardings: Any) -> Any:
 
 
 def ambient_mesh():
-    """The mesh currently in scope, or None — across jax versions:
-    ``get_abstract_mesh`` (new) or the pxla thread-resources mesh (0.4.x).
-    A toolchain bump must degrade gracefully, not AttributeError."""
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        mesh = get_abstract()
-        return mesh if (mesh is not None and mesh.shape_tuple) else None
-    try:
-        from jax.interpreters.pxla import thread_resources
-
-        mesh = thread_resources.env.physical_mesh
-        return None if mesh.empty else mesh
-    except Exception:
-        return None
-
-
-def compat_mesh_ctx(mesh):
-    """Activate a mesh across jax versions: ``jax.set_mesh`` (new),
-    ``jax.sharding.use_mesh`` (mid), or the Mesh object's own context
-    manager (0.4.x)."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    use_mesh = getattr(jax.sharding, "use_mesh", None)
-    if use_mesh is not None:
-        return use_mesh(mesh)
-    return mesh
-
-
-def compat_shard_map(f, mesh=None, in_specs=None, out_specs=None,
-                     check_vma: bool = False):
-    """``jax.shard_map`` across jax versions: the new top-level API
-    (ambient-mesh capable, ``check_vma``) or the 0.4.x experimental one
-    (explicit mesh, ``check_rep``)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kwargs = dict(in_specs=in_specs, out_specs=out_specs,
-                      check_vma=check_vma)
-        if mesh is not None:
-            kwargs["mesh"] = mesh
-        return sm(f, **kwargs)
-    from jax.experimental.shard_map import shard_map as old_sm
-
-    if mesh is None:
-        mesh = ambient_mesh()
-    return old_sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_vma)
+    """The mesh ``jax.set_mesh`` put in scope, or None."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return mesh if mesh.shape_tuple else None
 
 
 def constrain(x: jax.Array, logical_axes: Sequence[Optional[str]],

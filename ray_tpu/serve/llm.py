@@ -54,7 +54,9 @@ class LlamaGenerator:
         # LoRA target set, and enough for adapters to produce distinct
         # generations
         self._lcfg = LoraConfig(rank=lora_rank, targets=("wq", "wv"))
-        self._params = init_llama(self._cfg, jax.random.PRNGKey(seed))
+        # one jitted init: op-by-op it is a compile per parameter leaf
+        self._params = jax.jit(lambda k: init_llama(self._cfg, k))(
+            jax.random.PRNGKey(seed))
         self.max_new_tokens = max_new_tokens
         self.seq_bucket = max(8, int(seq_bucket))
         self._max_adapters = max_adapters
@@ -62,16 +64,19 @@ class LlamaGenerator:
             collections.OrderedDict()
         self._adapter_lock = threading.Lock()
 
-        cfg, lcfg, params = self._cfg, self._lcfg, self._params
+        cfg, lcfg = self._cfg, self._lcfg
 
-        def fwd(tokens, lora):
+        def fwd(params, tokens, lora):
             from ray_tpu.models.llama import llama_forward
 
             return llama_forward(params, tokens, cfg,
                                  lora=lora, lora_cfg=lcfg)
 
         # one jit; the trace cache keys on (shape, adapter-pytree
-        # structure), so base (lora=None) and adapted calls coexist
+        # structure), so base (lora=None) and adapted calls coexist. The
+        # weights are an ARGUMENT: closed over, they are lowered as
+        # constants (2.67 GB at 7B width and 2 layers) and the replica
+        # sits in the lowering long enough to miss its health probe
         self._fwd = jax.jit(fwd)
         self.engine = ContinuousBatchingEngine(
             self._step, prefill_fn=self._prefill,
@@ -145,7 +150,8 @@ class LlamaGenerator:
         for row, (_, s) in enumerate(live):
             ts = s["tokens"][-pad_len:]
             tokens[row, :len(ts)] = ts
-        logits = self._fwd(jnp.asarray(tokens), self._adapter(model_id))
+        logits = self._fwd(self._params, jnp.asarray(tokens),
+                           self._adapter(model_id))
         logits = np.asarray(logits)
         results: List[Optional[tuple]] = [None] * len(states)
         for row, (idx, s) in enumerate(live):
@@ -168,6 +174,26 @@ class LlamaGenerator:
 
     def engine_stats(self) -> Dict[str, int]:
         return self.engine.stats()
+
+    def device_info(self) -> Dict[str, Any]:
+        """Where this replica's model lives, as jax reports it."""
+        import os
+
+        import jax
+
+        devices = jax.devices()
+        return {
+            "platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "device_count": len(devices),
+            "visible_chips": os.environ.get("TPU_VISIBLE_CHIPS"),
+            "attn_impl": self._cfg.attn_impl,
+            "num_layers": self._cfg.num_layers,
+            "hidden": self._cfg.hidden,
+            "compile_cache_dir": jax.config.jax_compilation_cache_dir,
+            "forward_compiles": self._fwd._cache_size(),
+            "pid": os.getpid(),
+        }
 
 
 def build_llama_app(*, config: str = "tiny", lora_rank: int = 4,

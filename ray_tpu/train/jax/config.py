@@ -62,27 +62,8 @@ def _setup_worker(rank: int, world_size: int, coordinator: str,
         if cfg_wire.get("jax_platform"):
             jax.config.update("jax_platforms", cfg_wire["jax_platform"])
         if cfg_wire.get("num_local_devices"):
-            try:
-                jax.config.update("jax_num_cpu_devices",
-                                  cfg_wire["num_local_devices"])
-            except AttributeError:
-                # older jax: the config option doesn't exist yet — the
-                # XLA flag does the same thing if it lands before the
-                # first backend touch (we are before it by construction).
-                # XLA's parser honors the FIRST occurrence, so an
-                # inherited setting (e.g. the test harness's =8) must be
-                # stripped, not shadowed.
-                from ray_tpu._private.xla_flags import normalize_xla_flags
-
-                kept = [f for f in os.environ.get("XLA_FLAGS", "").split()
-                        if not f.startswith(
-                            "--xla_force_host_platform_device_count")]
-                kept.append("--xla_force_host_platform_device_count="
-                            f"{cfg_wire['num_local_devices']}")
-                # normalize: a bare token (e.g. intra_op_parallelism_
-                # threads=1) left LEADING reads as a flags-file name and
-                # FATALs the worker (parse_flags_from_env.cc:169)
-                os.environ["XLA_FLAGS"] = normalize_xla_flags(" ".join(kept))
+            jax.config.update("jax_num_cpu_devices",
+                              cfg_wire["num_local_devices"])
         if cfg_wire.get("cpu_collectives"):
             jax.config.update("jax_cpu_collectives_implementation",
                               cfg_wire["cpu_collectives"])

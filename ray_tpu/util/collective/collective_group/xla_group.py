@@ -69,12 +69,11 @@ class XLAGroup(CPUGroup):
         """Reduce a per-device value over one axis of the member's local mesh
         — pure ICI traffic via ``jax.lax.psum`` under ``shard_map``."""
         import jax
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         spec = P(axis)
         return jax.jit(
-            shard_map(
+            jax.shard_map(
                 lambda x: jax.lax.psum(x, axis),
                 mesh=mesh, in_specs=(spec,), out_specs=P()))(tensor)
 

@@ -254,8 +254,8 @@ def capture_jax_trace(worker_id: str, duration_s: float = 2.0,
 
     async def go():
         client = await w._owner_client(addr)
-        # generous window: jax.profiler start/stop on a remote-tunnel TPU
-        # can take tens of seconds beyond the capture itself
+        # generous window: jax.profiler start/stop can take tens of
+        # seconds beyond the capture itself
         return await client.call(
             "CaptureJaxTrace",
             {"duration_s": duration_s, "out_dir": out_dir},

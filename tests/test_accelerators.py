@@ -119,3 +119,131 @@ def test_intel_skips_boot_vga_igpu(tmp_path, monkeypatch):
                         str(tmp_path))
     assert IntelGPUAcceleratorManager.\
         get_current_node_num_accelerators() == 0
+
+
+# ---------------------------------------------------------------------------
+# Chip leases on a fake four-chip node (RAY_TPU_NUM_CHIPS=4). The workers
+# report their environment only — nothing here imports jax, so the same
+# assertions hold on the CPU box and on a TPU host.
+# ---------------------------------------------------------------------------
+def _chip_env():
+    return {"chips": os.environ.get("TPU_VISIBLE_CHIPS"),
+            "platforms": os.environ.get("JAX_PLATFORMS"),
+            "process_bounds": os.environ.get("TPU_CHIPS_PER_PROCESS_BOUNDS"),
+            "compile_cache": os.environ.get("JAX_COMPILATION_CACHE_DIR"),
+            "pid": os.getpid()}
+
+
+@pytest.fixture(scope="module")
+def four_fake_chips():
+    import ray_tpu
+
+    os.environ["RAY_TPU_NUM_CHIPS"] = "4"
+    try:
+        ray_tpu.init(num_cpus=8)
+        yield
+    finally:
+        ray_tpu.shutdown()
+        del os.environ["RAY_TPU_NUM_CHIPS"]
+
+
+def _bundle(pg, index):
+    from ray_tpu.util.scheduling_strategies import (
+        PlacementGroupSchedulingStrategy)
+
+    return PlacementGroupSchedulingStrategy(
+        placement_group=pg, placement_group_bundle_index=index)
+
+
+def test_bundle_placed_work_owns_its_chip(four_fake_chips):
+    """An actor and a task placed in {"TPU": 1} bundles get the bundle's
+    chip and the TPU platform named; two bundles hold distinct chips; once
+    the group is removed and its tenants are gone, all four chips are
+    leasable again."""
+    import ray_tpu
+    from ray_tpu.util.placement_group import (
+        placement_group, remove_placement_group)
+
+    assert ray_tpu.cluster_resources()["TPU"] == 4.0
+
+    @ray_tpu.remote
+    class Holder:
+        def env(self):
+            return _chip_env()
+
+    env_task = ray_tpu.remote(_chip_env)
+
+    pg = placement_group([{"TPU": 1, "CPU": 1}, {"TPU": 1, "CPU": 1}])
+    assert pg.wait(timeout_seconds=30)
+    actor = Holder.options(num_tpus=1, num_cpus=1,
+                           scheduling_strategy=_bundle(pg, 0)).remote()
+    in_actor = ray_tpu.get(actor.env.remote(), timeout=60)
+    in_task = ray_tpu.get(env_task.options(
+        num_tpus=1, num_cpus=1,
+        scheduling_strategy=_bundle(pg, 1)).remote(), timeout=60)
+    for seen in (in_actor, in_task):
+        assert seen["chips"] in ("0", "1", "2", "3"), seen
+        assert seen["platforms"].split(",")[0] == "tpu", seen
+    assert in_actor["chips"] != in_task["chips"]
+
+    ray_tpu.kill(actor)
+    remove_placement_group(pg)
+    actors = [Holder.options(num_tpus=1, num_cpus=1).remote()
+              for _ in range(4)]
+    seen = ray_tpu.get([a.env.remote() for a in actors], timeout=120)
+    assert sorted(s["chips"] for s in seen) == ["0", "1", "2", "3"]
+    for a in actors:
+        ray_tpu.kill(a)
+
+
+def test_chip_lease_ends_with_its_worker(four_fake_chips):
+    """A chip lease is served by a process that has run nothing else and
+    that is retired with the lease: the next chip task gets another
+    process, and a task without chips never lands on a chip holder."""
+    import time
+
+    import ray_tpu
+
+    env_task = ray_tpu.remote(_chip_env)
+    plain = ray_tpu.get(env_task.remote(), timeout=60)
+    assert plain["chips"] is None and plain["platforms"] == "cpu"
+    first = ray_tpu.get(env_task.options(num_tpus=1).remote(), timeout=60)
+    assert first["platforms"].split(",")[0] == "tpu"
+    assert first["pid"] != plain["pid"]
+    time.sleep(1.0)  # past lease_idle_ttl_ms: the lease is returned
+    second = ray_tpu.get(env_task.options(num_tpus=1).remote(), timeout=60)
+    assert second["pid"] != first["pid"]
+    again = ray_tpu.get(env_task.remote(), timeout=60)
+    assert again["chips"] is None and again["platforms"] == "cpu"
+
+
+def test_jax_trainer_worker_reports_its_chip(four_fake_chips):
+    import time
+
+    import ray_tpu
+    from ray_tpu import train
+    from ray_tpu.air.config import ScalingConfig
+    from ray_tpu.train.jax import JaxTrainer
+
+    def loop(config):
+        train.report(_chip_env())
+
+    result = JaxTrainer(
+        loop, scaling_config=ScalingConfig(
+            num_workers=1, use_tpu=True,
+            resources_per_worker={"TPU": 1, "CPU": 1})).fit()
+    assert result.error is None
+    assert result.metrics["chips"] in ("0", "1", "2", "3"), result.metrics
+    assert result.metrics["platforms"].split(",")[0] == "tpu"
+    # one chip of four: the process is told the box it sees
+    assert result.metrics["process_bounds"] == "1,1,1"
+    # the compile cache is placed from outside, else under the checkout
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert result.metrics["compile_cache"] == (
+        os.environ.get("JAX_COMPILATION_CACHE_DIR")
+        or os.path.join(repo, ".jax_cache"))
+    # the trainer's group is returned with fit(): nothing leaked
+    deadline = time.monotonic() + 30
+    while ray_tpu.available_resources().get("TPU") != 4.0:
+        assert time.monotonic() < deadline, ray_tpu.available_resources()
+        time.sleep(0.2)
