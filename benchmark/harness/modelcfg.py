@@ -1,0 +1,46 @@
+"""A ``LlamaConfig`` from a configuration file's dict. Imports jax: worker-side only.
+
+The file keeps the model's published key names at its top level and what
+the program is told beside the model under ``program`` (attention
+implementation, activation and parameter types, rematerialisation, chunk
+of the loss). No preset is added to ``models/llama.py``: the object is
+built here and handed over.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict
+
+MODEL_KEYS = {
+    "vocab_size": "vocab_size", "hidden_size": "hidden",
+    "intermediate_size": "mlp_hidden", "num_hidden_layers": "num_layers",
+    "num_attention_heads": "num_heads", "num_key_value_heads": "num_kv_heads",
+    "head_dim": "head_dim", "max_position_embeddings": "max_seq_len",
+    "rope_theta": "rope_theta", "rms_norm_eps": "rms_eps",
+}
+PROGRAM_KEYS = ("attn_impl", "remat", "remat_policy", "loss_chunk")
+
+
+def check_supported(m: Dict[str, Any]) -> None:
+    """What the dense path of ``models/llama.py`` does not compute."""
+    if m.get("sliding_window") is not None:
+        raise ValueError("models/llama.py has no sliding-window attention")
+    if m.get("tie_word_embeddings"):
+        raise ValueError("models/llama.py keeps a separate output head")
+    if m.get("hidden_act", "silu") != "silu":
+        raise ValueError(f"hidden_act {m['hidden_act']!r}: only silu")
+
+
+def build_llama_config(m: Dict[str, Any]):
+    import jax.numpy as jnp
+
+    from ray_tpu.models.llama import LlamaConfig
+
+    check_supported(m)
+    kwargs = {ours: m[theirs] for theirs, ours in MODEL_KEYS.items()}
+    program = m.get("program", {})
+    kwargs.update({k: program[k] for k in PROGRAM_KEYS if k in program})
+    kwargs["dtype"] = jnp.dtype(program.get("dtype", "bfloat16")).type
+    kwargs["param_dtype"] = jnp.dtype(
+        program.get("param_dtype", "float32")).type
+    return LlamaConfig(**kwargs)
