@@ -6,6 +6,7 @@ exercised without TPU hardware (SURVEY §4 "fake TPU topology" note).
 """
 
 import os
+import signal
 
 # Tests run on the deterministic 8-device virtual CPU mesh (SURVEY §4
 # fake-TPU-topology note), whatever the machine holds: the platform is
@@ -155,18 +156,32 @@ def sanitizer_gate():
 
 
 # ---------------------------------------------------------------------------
-# Leak gate (ISSUE 1): any ray_tpu daemon or session dir that survives the
-# whole run fails the suite — orphaned gcs/agent/forkserver processes and
-# stale /dev/shm segments are exactly what starved the round-5 MULTICHIP
-# gate. Everything found is also reaped so one leak can't poison the NEXT
-# run. Disable with RAY_TPU_LEAK_CHECK=0 (e.g. when running a subset
-# against an intentionally long-lived external cluster).
+# Leak gate (ISSUE 1, ISSUE 24). Two halves.
+#
+# Per process (this fixture, in every xdist worker and in a serial run
+# alike): a driver left connected is shut down, and a continuous-batching
+# stepper thread that survives the run fails it.
+#
+# Per run (the two hooks below): any ray_tpu daemon or session dir that
+# survives the whole run fails it — orphaned gcs/agent/forkserver
+# processes and stale /dev/shm segments are exactly what starved the
+# round-5 MULTICHIP gate — and is reaped so one leak can't poison the NEXT
+# run. Only the process that owns the run sweeps (the only process under
+# `-p no:xdist`, the controller under `-n 6`), once, after xdist has shut
+# its workers down: the session roots are shared, so a worker that swept
+# when IT ran out of files killed the live clusters of the workers still
+# running. A run killed from outside never gets to sweep; what it leaves
+# is the next run's ray_tpu.init() -> gc_stale_sessions() to collect.
+#
+# RAY_TPU_LEAK_CHECK=0 turns both halves off (e.g. a subset run beside a
+# deliberately long-lived external cluster, which must never be reaped).
 # ---------------------------------------------------------------------------
+def _leak_check_enabled() -> bool:
+    return os.environ.get("RAY_TPU_LEAK_CHECK", "1") != "0"
+
+
 @pytest.fixture(scope="session", autouse=True)
 def lifecycle_leak_gate():
-    from ray_tpu._private import lifecycle
-
-    baseline = {s["path"] for s in lifecycle.list_sessions()}
     yield
     import ray_tpu
 
@@ -175,49 +190,108 @@ def lifecycle_leak_gate():
             ray_tpu.shutdown()
     except Exception:
         pass
-    if os.environ.get("RAY_TPU_LEAK_CHECK", "1") == "0":
-        return  # disabled: report nothing, and never reap what may be a
-        # deliberately long-lived external cluster
+    if not _leak_check_enabled():
+        return
     # serving-plane stepper gate: a ContinuousBatchingEngine stepper
     # thread surviving the whole run means some engine was neither
     # drained (serve.shutdown → Replica.drain → engine.shutdown) nor
     # idle-expired — the exact daemon-leak class that turned the round-5
     # MULTICHIP gate red. Idle exit takes idle_timeout_s, so give the
     # threads a short window to wind down before calling it a leak.
-    import sys as _sys
     import time as _time
 
-    failures = []
-    eng_mod = _sys.modules.get("ray_tpu.serve._private.engine")
-    if eng_mod is not None:
-        deadline = _time.monotonic() + 3.0
+    eng_mod = sys.modules.get("ray_tpu.serve._private.engine")
+    if eng_mod is None:
+        return
+    deadline = _time.monotonic() + 3.0
+    steppers = eng_mod.live_stepper_threads()
+    while steppers and _time.monotonic() < deadline:
+        _time.sleep(0.1)
         steppers = eng_mod.live_stepper_threads()
-        while steppers and _time.monotonic() < deadline:
-            _time.sleep(0.1)
-            steppers = eng_mod.live_stepper_threads()
-        if steppers:
-            failures.append(
-                "continuous-batching engine stepper threads leaked past "
-                "the end of the test run (engines must be shut down or "
-                "left idle): " + ", ".join(steppers))
-    # the session sweep must run even when the stepper gate failed — one
-    # leak class must never shield another from being reaped
-    leaked = [s for s in lifecycle.list_sessions()
-              if s["path"] not in baseline]
+    if steppers:
+        pytest.fail(
+            "continuous-batching engine stepper threads leaked past "
+            "the end of the test run (engines must be shut down or "
+            "left idle): " + ", ".join(steppers), pytrace=False)
+
+
+# Sessions that stood when the run started, kept by the process that owns
+# the run. xdist exports PYTEST_XDIST_WORKER in each worker, and a pytest
+# that a worker's test starts (test_sanitizer.py) inherits it: neither may
+# sweep roots their neighbours' clusters live under.
+_SESSION_BASELINE = pytest.StashKey[set]()
+
+
+def pytest_sessionstart(session):
+    if "PYTEST_XDIST_WORKER" not in os.environ and _leak_check_enabled():
+        from ray_tpu._private import lifecycle
+
+        session.config.stash[_SESSION_BASELINE] = {
+            s["path"] for s in lifecycle.list_sessions()}
+
+
+@pytest.hookimpl(trylast=True)  # after xdist's, which ends the workers
+def pytest_sessionfinish(session):
+    baseline = session.config.stash.get(_SESSION_BASELINE, None)
+    if baseline is None:
+        return
+    from ray_tpu._private import lifecycle
+
     report = []
-    for sess in leaked:
+    for sess in lifecycle.list_sessions():
+        if sess["path"] in baseline:
+            continue
         live = ", ".join(
             f"{r.get('role', '?')}:{r['pid']}" for r in sess["live"])
         report.append(f"{sess['path']}"
                       + (f" [live: {live}]" if live else " [stale dir]"))
         lifecycle.reap_session(sess["path"], remove=True)
-    if report:
-        failures.append(
-            "ray_tpu sessions leaked past the end of the test run "
-            "(reaped now, but the teardown path that should have cleaned "
-            "them is broken):\n  " + "\n  ".join(report))
-    if failures:
-        pytest.fail("\n".join(failures), pytrace=False)
+    if not report:
+        return
+    if session.exitstatus == pytest.ExitCode.OK:
+        session.exitstatus = pytest.ExitCode.TESTS_FAILED
+    reporter = session.config.pluginmanager.get_plugin("terminalreporter")
+    reporter.write_sep("=", "ray_tpu sessions leaked", red=True)
+    reporter.write_line(
+        "ray_tpu sessions leaked past the end of the test run "
+        "(reaped now, but the teardown path that should have cleaned "
+        "them is broken):\n  " + "\n  ".join(report))
+
+
+# ---------------------------------------------------------------------------
+# Per-test time limit (ISSUE 24): pytest-timeout is not installed, and one
+# test wedged in a wait without a timeout used to cost the run its whole
+# 1470 s. The alarm covers setup, call and teardown (a wedged cluster also
+# wedges the module fixture's ray_tpu.shutdown()) and repeats, so each
+# wedged phase is failed in its turn. xdist runs tests on the worker's
+# main thread, where the handler runs and Python's lock and queue waits are
+# interruptible; the handler raises pytest's Failed (a BaseException, so a
+# test's `except Exception` retry loop cannot swallow it) and the
+# traceback shows where the test sat. pytest.ini's faulthandler_timeout
+# dumps every thread's stack a little earlier. Tier-1 tests only: the slow
+# tier's learning and compile tests keep no limit.
+# ---------------------------------------------------------------------------
+TEST_TIME_LIMIT_S = 180.0
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_protocol(item):
+    if not hasattr(signal, "SIGALRM") \
+            or item.get_closest_marker("slow") is not None:
+        return (yield)
+
+    def expired(signum, frame):
+        pytest.fail(f"{item.nodeid} exceeded the per-test time limit of "
+                    f"{TEST_TIME_LIMIT_S:g} s (tests/conftest.py)")
+
+    previous = signal.signal(signal.SIGALRM, expired)
+    signal.setitimer(signal.ITIMER_REAL, TEST_TIME_LIMIT_S,
+                     TEST_TIME_LIMIT_S)
+    try:
+        return (yield)
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, previous)
 
 
 # ---------------------------------------------------------------------------

@@ -375,14 +375,7 @@ class TestWarmPoolCluster:
         from ray_tpu._private import lifecycle, worker as wm
 
         st = _wait_warm(3)
-        session_dir = None
-        for root in lifecycle.default_session_roots():
-            if os.path.isdir(root):
-                sessions = sorted(
-                    (os.path.join(root, d) for d in os.listdir(root)),
-                    key=os.path.getmtime)
-                if sessions:
-                    session_dir = sessions[-1]
+        session_dir = wm.global_worker.session_dir
         assert session_dir
         # a parked warm worker = registered role=worker pid hosting no actor
         live = [r for r in lifecycle.live_registered(session_dir)

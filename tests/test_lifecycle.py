@@ -279,3 +279,234 @@ def _wait_pid_dead(proc, timeout_s: float) -> bool:
             return True
         time.sleep(0.1)
     return False
+
+
+# ---------------------------------------------------------------------------
+# The suite's own gates (ISSUE 24): tests/conftest.py sweeps leaked
+# sessions once a run, in the process that owns the run, and gives every
+# tier-1 test a time limit. Each test below drives an inner pytest run in
+# a directory of its own that takes the repo's conftest in as a plugin.
+#
+# An inner run whose sweep looked at the shared session roots would kill
+# the clusters of the OUTER run's other workers — the very fault these
+# tests guard. So the inner conftest first points the driver side's two
+# root lookups at a root of its own (daemons are handed their
+# session_dir, so nothing else needs it), and only then loads the hooks.
+# Inner test files carry FAST_FILES names so that they are tier-1 tests
+# to the hooks (limit, ref-leak gate) like the files they stand for.
+# ---------------------------------------------------------------------------
+_INNER_CONFTEST = """\
+import importlib.util
+import sys
+
+from ray_tpu._private import lifecycle, node
+
+lifecycle.default_session_roots = lambda: [{root!r}]
+node.default_session_root = lambda: {root!r}
+
+spec = importlib.util.spec_from_file_location(
+    "repo_conftest", {repo!r} + "/tests/conftest.py")
+repo_conftest = importlib.util.module_from_spec(spec)
+sys.modules["repo_conftest"] = repo_conftest
+spec.loader.exec_module(repo_conftest)
+{extra}
+pytest_plugins = ("repo_conftest",)
+"""
+
+_RUN_MODES = {
+    "serial": ["-p", "no:xdist"],
+    "xdist": ["-p", "xdist", "-n", "2", "--dist", "loadfile"],
+}
+
+
+@pytest.fixture
+def inner_root():
+    """A session root for one inner run: short (unix socket paths live
+    under it), and swept here of whatever the inner run left, its
+    deliberate leak included should the gate under test miss it."""
+    import shutil
+    import tempfile
+
+    from ray_tpu._private import lifecycle
+
+    root = tempfile.mkdtemp(
+        prefix="rt_", dir="/dev/shm" if os.path.isdir("/dev/shm") else None)
+    yield root
+    for sess in lifecycle.list_sessions([root]):
+        lifecycle.reap_session(sess["path"], remove=True)
+    shutil.rmtree(root, ignore_errors=True)
+
+
+def _inner_pytest(tmp_path, root, mode, files, extra=""):
+    # an ini of its own: the rootdir is here, and the repo's does not apply
+    (tmp_path / "pytest.ini").write_text("[pytest]\n")
+    (tmp_path / "conftest.py").write_text(
+        _INNER_CONFTEST.format(repo=REPO, root=root, extra=extra))
+    for name, body in files.items():
+        (tmp_path / name).write_text(body)
+    # the inner run owns itself: it is nobody's xdist worker
+    env = {k: v for k, v in os.environ.items()
+           if not k.startswith("PYTEST_XDIST")}
+    # it runs outside the checkout, and so do the daemons it starts
+    env["PYTHONPATH"] = os.pathsep.join(
+        [REPO] + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
+    proc = subprocess.run(
+        [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
+         *_RUN_MODES[mode], str(tmp_path)],
+        cwd=str(tmp_path), env=env, capture_output=True, text=True,
+        timeout=150)
+    return proc.returncode, proc.stdout + proc.stderr
+
+
+_ENDS_FIRST = """\
+import os
+import time
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+
+
+def test_ends_first():
+    with open(os.path.join(HERE, "a_pid.tmp"), "w") as f:
+        f.write(str(os.getpid()))
+    os.replace(os.path.join(HERE, "a_pid.tmp"), os.path.join(HERE, "a_pid"))
+    deadline = time.monotonic() + 60
+    while not os.path.exists(os.path.join(HERE, "b_up")):
+        assert time.monotonic() < deadline, "the neighbour never came up"
+        time.sleep(0.1)
+"""
+
+_HOLDS_CLUSTER = """\
+import os
+import time
+
+import ray_tpu
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+
+
+def test_cluster_outlives_neighbour():
+    # start after the neighbour's worker is under way, so that the
+    # cluster is in no process's start-of-run baseline
+    deadline = time.monotonic() + 60
+    while not os.path.exists(os.path.join(HERE, "a_pid")):
+        assert time.monotonic() < deadline, "the neighbour never started"
+        time.sleep(0.1)
+    ray_tpu.init(num_cpus=1)
+    try:
+        open(os.path.join(HERE, "b_up"), "w").close()
+        with open(os.path.join(HERE, "a_pid")) as f:
+            neighbour = f.read()
+        while not os.path.exists(os.path.join(HERE, "ended_" + neighbour)):
+            assert time.monotonic() < deadline, "the neighbour never ended"
+            time.sleep(0.1)
+        time.sleep(2.0)  # longer than a reaper's SIGTERM takes to land
+
+        @ray_tpu.remote
+        def f():
+            return 7
+
+        assert ray_tpu.get(f.remote(), timeout=30) == 7
+    finally:
+        ray_tpu.shutdown()
+"""
+
+
+# a worker's process outlives its session (xdist keeps the gateway), so
+# the inner conftest says when each process's session, fixtures and all,
+# is over
+_MARK_PROCESS_ENDED = """
+def pytest_sessionfinish(session):
+    import os
+
+    open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                      f"ended_{os.getpid()}"), "w").close()
+"""
+
+
+def test_gate_spares_a_neighbour_workers_cluster(tmp_path, inner_root):
+    """Under xdist one worker runs out of files while another still
+    holds a cluster: the cluster answers a task afterwards, and the run
+    passes. (The per-worker gate reaped it: ISSUE 24.)"""
+    rc, out = _inner_pytest(tmp_path, inner_root, "xdist", {
+        "test_core_api.py": _ENDS_FIRST,
+        "test_actors.py": _HOLDS_CLUSTER,
+    }, extra=_MARK_PROCESS_ENDED)
+    assert rc == 0, out
+    assert "2 passed" in out, out
+    assert "sessions leaked" not in out, out
+
+
+_LEAKS_SESSION = """\
+import os
+import subprocess
+import sys
+
+from ray_tpu._private import lifecycle
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+
+
+def test_leaves_a_session_behind():
+    sleeper = subprocess.Popen(
+        [sys.executable, "-c", "import time; time.sleep(120)"])
+    session = os.path.join(lifecycle.default_session_roots()[0],
+                           "session_left_behind")
+    os.makedirs(session)
+    lifecycle.register_process(session, "agent", sleeper.pid)
+    with open(os.path.join(HERE, "sleeper_pid"), "w") as f:
+        f.write(str(sleeper.pid))
+"""
+
+_PASSES = """\
+def test_passes():
+    pass
+"""
+
+
+@pytest.mark.parametrize("mode", sorted(_RUN_MODES))
+def test_gate_fails_the_run_on_a_leaked_session(tmp_path, inner_root, mode):
+    """A session that outlives the run fails the RUN — every test of it
+    passed, none is blamed with an error at its teardown — is named once
+    with its live pid, and is reaped."""
+    from ray_tpu._private import lifecycle
+
+    rc, out = _inner_pytest(tmp_path, inner_root, mode, {
+        "test_core_api.py": _LEAKS_SESSION,
+        "test_actors.py": _PASSES,
+    })
+    session = os.path.join(inner_root, "session_left_behind")
+    sleeper = int((tmp_path / "sleeper_pid").read_text())
+    assert rc == 1, out
+    assert "2 passed" in out and "error" not in out.lower(), out
+    assert out.count(session) == 1, out
+    assert f"{session} [live: agent:{sleeper}]" in out, out
+    assert not os.path.exists(session), "leaked session was not reaped"
+    assert not lifecycle._pid_alive(sleeper), "leaked pid was not reaped"
+
+
+_BLOCKS = """\
+import queue
+
+
+def test_blocks_for_ever():
+    queue.Queue().get()
+
+
+def test_next_one_still_runs():
+    pass
+"""
+
+
+@pytest.mark.parametrize("mode", sorted(_RUN_MODES))
+def test_time_limit_fails_a_wedged_test(tmp_path, inner_root, mode):
+    """A test that sits in a wait without a timeout is failed by the
+    harness's limit (shortened here by patching the constant), its stack
+    is in the report, and the file's next test runs and passes."""
+    rc, out = _inner_pytest(tmp_path, inner_root, mode, {
+        "test_lifecycle.py": _BLOCKS,
+    }, extra="repo_conftest.TEST_TIME_LIMIT_S = 2.0")
+    assert rc == 1, out
+    assert "1 failed, 1 passed" in out, out
+    assert "exceeded the per-test time limit of 2 s" in out, out
+    # where it sat: the test's own line and the wait inside queue.py
+    assert "queue.Queue().get()" in out and "queue.py" in out, out
