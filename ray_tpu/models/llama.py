@@ -467,6 +467,37 @@ def llama_forward(
     return logits.astype(jnp.float32)
 
 
+def llama_head(params: Dict[str, Any], x: jax.Array,
+               cfg: LlamaConfig) -> jax.Array:
+    """Final hidden states [..., H] → logits [..., V], accumulated and
+    kept in fp32 (``llama_forward`` rounds the product to the activation
+    dtype first; the operands are the same)."""
+    return jnp.einsum("...h,hv->...v", x, params["lm_head"].astype(cfg.dtype),
+                      preferred_element_type=jnp.float32)
+
+
+def llama_next_token(
+    params: Dict[str, Any],
+    tokens: jax.Array,
+    last: jax.Array,
+    cfg: LlamaConfig,
+    *,
+    lora: Optional[Dict[str, Any]] = None,
+    lora_cfg: Optional[LoraConfig] = None,
+) -> Tuple[jax.Array, jax.Array]:
+    """Greedy next token of each row without the [B, S, V] logits: tokens
+    [B, S] and the index ``last`` [B] int32 of each row's newest token →
+    (next ids [B] int32, final hidden states [B, S, H]). Only the B rows
+    ``hidden[b, last[b]]`` meet the head; the argmax is over their fp32
+    logits and a tie goes to the lowest id, as ``np.argmax`` has it. The
+    hidden states are returned so that a caller who wants every position's
+    logits applies ``llama_head`` to them and runs the layers once."""
+    x = llama_hidden(params, tokens, cfg, lora=lora, lora_cfg=lora_cfg)
+    rows = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
+    ids = jnp.argmax(llama_head(params, rows, cfg), axis=-1)
+    return ids.astype(jnp.int32), x
+
+
 def _nll_from_logits(logits: jax.Array, targets: jax.Array) -> jax.Array:
     """-log p(target) without gather/scatter: the target logit comes from
     an iota-compare + masked reduce, so the backward is softmax - onehot

@@ -8,11 +8,19 @@ multiplexed by model id, greedy decode driven step-by-step by
 :class:`~ray_tpu.serve._private.engine.ContinuousBatchingEngine` so
 mixed-length generations share the compiled batch.
 
-TPU notes: the per-step forward is jitted per (batch bucket, padded seq)
+TPU notes: the per-step program is jitted per (batch bucket, padded seq)
 shape pair — the engine's ``allowed_batch_sizes`` snapping plus a seq-pad
-bucket keep the compile-cache menu finite. Decoding here recomputes the
-full prefix each step (tiny demo configs; a kv-cache paged-attention
-variant slots into ``_step`` without touching the engine contract).
+bucket keep the compile-cache menu finite. It ends in the next token of
+each row (``models.llama.llama_next_token``: the head meets only each
+row's last position, the argmax runs on the device), so a step brings
+``bucket`` int32s to the host and the ``[bucket, S, vocab]`` logits are
+never made. ``LlamaGenerator._fwd`` is the same program followed by the
+head over every position, for callers that want the logits themselves
+(a benchmark's warm-up and its check against a reference): it compiles
+what ``_step`` runs, so warming a shape through it warms the step.
+Decoding still recomputes the full prefix each step (a kv-cache
+paged-attention variant slots into ``_step`` without touching the engine
+contract).
 
 Usage::
 
@@ -32,6 +40,27 @@ from typing import Any, Dict, List, Optional, Sequence
 
 from ray_tpu.serve._private.engine import ContinuousBatchingEngine
 from ray_tpu.serve.deployment import Application, Deployment
+
+
+class _FullLogits:
+    """``LlamaGenerator._fwd``: ``(params, tokens [B, S], lora)`` →
+    logits ``[B, S, V]`` float32, as ``jit(llama_forward)`` gave them,
+    from the step's own program and a second small one for the head."""
+
+    def __init__(self, step_fn, head_fn):
+        self._step_fn = step_fn
+        self._head_fn = head_fn
+
+    def __call__(self, params, tokens, lora):
+        import numpy as np
+
+        _, hidden = self._step_fn(params, tokens, lora,
+                                  np.zeros(tokens.shape[0], np.int32))
+        return self._head_fn(params, hidden)
+
+    def _cache_size(self) -> int:
+        """Compiled (shape, adapter structure) variants of the step."""
+        return self._step_fn._cache_size()
 
 
 class LlamaGenerator:
@@ -66,18 +95,26 @@ class LlamaGenerator:
 
         cfg, lcfg = self._cfg, self._lcfg
 
-        def fwd(params, tokens, lora):
-            from ray_tpu.models.llama import llama_forward
+        def step_fn(params, tokens, lora, last):
+            from ray_tpu.models.llama import llama_next_token
 
-            return llama_forward(params, tokens, cfg,
-                                 lora=lora, lora_cfg=lcfg)
+            return llama_next_token(params, tokens, last, cfg,
+                                    lora=lora, lora_cfg=lcfg)
+
+        def head_fn(params, hidden):
+            from ray_tpu.models.llama import llama_head
+
+            return llama_head(params, hidden, cfg)
 
         # one jit; the trace cache keys on (shape, adapter-pytree
         # structure), so base (lora=None) and adapted calls coexist. The
         # weights are an ARGUMENT: closed over, they are lowered as
         # constants (2.67 GB at 7B width and 2 layers) and the replica
         # sits in the lowering long enough to miss its health probe
-        self._fwd = jax.jit(fwd)
+        self._step_fn = jax.jit(step_fn)
+        self._fwd = _FullLogits(self._step_fn, jax.jit(head_fn))
+        # bytes of device results `_step` has brought to the host
+        self._host_bytes = 0
         self.engine = ContinuousBatchingEngine(
             self._step, prefill_fn=self._prefill,
             max_batch_size=max_batch_size,
@@ -136,8 +173,12 @@ class LlamaGenerator:
 
     def _step(self, model_id: str, states: List[Optional[Dict]]) -> List:
         """One decode iteration for one adapter group: pad the live rows
-        to (bucket, seq_bucket-multiple), one jitted forward, greedy next
-        token per row."""
+        to (bucket, seq_bucket-multiple) and run the step's one jitted
+        program, which re-runs every row's whole prefix and returns the
+        greedy next token of each row; ``bucket`` int32s come to the
+        host. Nothing else runs on the device here (an op-by-op ``jnp``
+        call would compile a program of its own per shape), and ``last``
+        goes in as numpy, as ``_fwd`` passes it."""
         import jax.numpy as jnp
         import numpy as np
 
@@ -147,16 +188,19 @@ class LlamaGenerator:
         pad_len = -(-max_len // self.seq_bucket) * self.seq_bucket
         pad_len = min(pad_len, self._cfg.max_seq_len)
         tokens = np.zeros((bucket, pad_len), np.int32)
+        # index of each row's newest token; 0 for a padded row
+        last = np.zeros(bucket, np.int32)
         for row, (_, s) in enumerate(live):
             ts = s["tokens"][-pad_len:]
             tokens[row, :len(ts)] = ts
-        logits = self._fwd(self._params, jnp.asarray(tokens),
-                           self._adapter(model_id))
-        logits = np.asarray(logits)
+            last[row] = len(ts) - 1
+        ids, _ = self._step_fn(self._params, jnp.asarray(tokens),
+                               self._adapter(model_id), last)
+        ids = np.asarray(ids)
+        self._host_bytes += ids.nbytes
         results: List[Optional[tuple]] = [None] * len(states)
         for row, (idx, s) in enumerate(live):
-            last = min(len(s["tokens"]), pad_len) - 1
-            nxt = int(np.argmax(logits[row, last]))
+            nxt = int(ids[row])
             s["tokens"].append(nxt)
             done = len(s["tokens"]) - s["prompt_len"] >= s["max_new"]
             results[idx] = (nxt, done)
@@ -173,7 +217,9 @@ class LlamaGenerator:
         yield from self.engine.submit(p, model_id)
 
     def engine_stats(self) -> Dict[str, int]:
-        return self.engine.stats()
+        """The engine's counters, and ``host_bytes``: the bytes of device
+        results ``_step`` has brought to the host (4 a row a step)."""
+        return {**self.engine.stats(), "host_bytes": self._host_bytes}
 
     def device_info(self) -> Dict[str, Any]:
         """Where this replica's model lives, as jax reports it."""

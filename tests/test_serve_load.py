@@ -530,3 +530,97 @@ def test_llama_engine_generation():
         assert stats["completed"] == 4
     finally:
         gen.engine.shutdown()
+
+
+def _llama_gen(allowed=(1, 2), seq_bucket=8):
+    from ray_tpu.serve.llm import LlamaGenerator
+
+    return LlamaGenerator(config="debug_1l", lora_rank=2,
+                          max_batch_size=max(allowed),
+                          allowed_batch_sizes=allowed,
+                          max_new_tokens=4, seq_bucket=seq_bucket)
+
+
+@pytest.mark.parametrize("bucket", [1, 2])
+@pytest.mark.parametrize("adapter", ["", "a1"])
+def test_llama_step_ids_match_full_logits(adapter, bucket):
+    """The ids ``_step`` emits (last-position head and argmax on the
+    device) are the argmax of the full-logits call ``_fwd`` at each row's
+    last position on the same padded tokens: the path a benchmark checks
+    against its reference and the path it times are one computation.
+    Rows of unequal length share the batch, the longer one crosses a
+    sequence bucket, and the shorter one leaves a padded row behind."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    gen = _llama_gen()
+    try:
+        prompts = [[9, 8, 7, 6, 5, 4, 3], [3, 5, 7]][:bucket]
+        states = [gen._prefill({"prompt": p, "max_new": 4 - 2 * i}, adapter)
+                  for i, p in enumerate(prompts)]
+        pad_lens = set()
+        for _ in range(4):
+            before = [None if s is None else list(s["tokens"])
+                      for s in states]
+            out = gen._step(adapter, states)
+            pad_len = -(-max(len(t) for t in before if t) // 8) * 8
+            pad_lens.add(pad_len)
+            tokens = np.zeros((bucket, pad_len), np.int32)
+            live = [t for t in before if t is not None]
+            for row, t in enumerate(live):
+                tokens[row, :len(t)] = t
+            logits = np.asarray(gen._fwd(gen._params, jnp.asarray(tokens),
+                                         gen._adapter(adapter)))
+            assert logits.shape == (bucket, pad_len, 128)
+            assert logits.dtype == np.float32
+            want = [int(np.argmax(logits[row, len(t) - 1]))
+                    for row, t in enumerate(live)]
+            assert [r[0] for r in out if r is not None] == want
+            # a finished request leaves, as the engine has it
+            states = [None if r is None or r[1] else s
+                      for s, r in zip(states, out)]
+        assert pad_lens == {8, 16}
+        assert states[0] is None
+        assert gen.engine_stats()["host_bytes"] == 4 * bucket * 4
+    finally:
+        gen.engine.shutdown()
+
+
+def test_llama_host_bytes_counts_ids_only():
+    """What a step brings to the host is one int32 a row of the bucket."""
+    gen = _llama_gen(allowed=(2,))
+    try:
+        assert gen.engine_stats()["host_bytes"] == 0
+        out = list(gen({"prompt": [3, 5, 7], "max_new": 4}))
+        assert len(out) == 4
+        stats = gen.engine_stats()
+        assert stats["steps"] == 4
+        assert stats["host_bytes"] == stats["steps"] * 2 * 4
+    finally:
+        gen.engine.shutdown()
+
+
+def test_llama_step_compiles_nothing_after_fwd_warmed_its_shape():
+    """A caller that warms a (batch, seq) shape through ``_fwd`` has
+    compiled everything ``_step`` runs at that shape: no backend
+    compilation fires in the step, by the count a benchmark run is
+    failed on (``compiles_in_window``)."""
+    import jax.numpy as jnp
+
+    from benchmark.harness.onchip import count_compiles
+
+    compiles = count_compiles()
+    gen = _llama_gen(allowed=(2,), seq_bucket=24)
+    try:
+        gen._fwd(gen._params, jnp.zeros((2, 24), jnp.int32), None)
+        assert compiles, "the listener saw the warm-up compile nothing"
+        warmed = len(compiles)
+        states = [gen._prefill({"prompt": [3, 5, 7]}, ""),
+                  gen._prefill({"prompt": list(range(1, 12))}, "")]
+        for _ in range(2):
+            out = gen._step("", states)
+            assert all(0 <= tok < 128 for tok, _ in out)
+        assert len(compiles) == warmed, (
+            f"{len(compiles) - warmed} compilation(s) in a warmed step")
+    finally:
+        gen.engine.shutdown()
