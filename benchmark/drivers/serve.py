@@ -1,12 +1,14 @@
 """Serving cells: ``serve.run`` of one replica, driven by an open loop.
 
 ``run`` is the harness side (load generator, clocks at the client) and
-never touches jax. ``BenchGenerator`` is what is served: the program's
-``LlamaGenerator`` with nothing added to the served path but a clock round
-``_step``, plus methods that are called through the handle, as
-``chip_smoke.py`` calls ``device_info``: only the replica holds the chip,
-so only it can trace the chip, read its memory or compare the model with
-the reference on the weights it holds.
+never touches jax. What is served is the deployment class of the
+configuration's family (``benchmark/families/``) under ``BenchGenerator``,
+which adds nothing to the served path but a clock round ``_step``, plus
+methods that are called through the handle, as ``chip_smoke.py`` calls
+``device_info``: only the replica holds the chip, so only it can trace the
+chip, read its memory or compare the model with the family's reference on
+the weights it holds. Warming a shape, the check's logits and the count of
+compiled step programs are the served class's own methods.
 """
 
 from __future__ import annotations
@@ -18,18 +20,20 @@ import threading
 import time
 from typing import Any, Dict, List
 
-from benchmark.harness import stats
-from ray_tpu.serve.llm import LlamaGenerator
+from benchmark.harness import loader, stats
 
 HANDLE_TIMEOUT_S = 55.0  # the router gives a request 60 s
 
 
 # ----------------------------------------------------------- replica side
-class BenchGenerator(LlamaGenerator):
+class BenchGenerator:
+    """The benchmark's half of what is served; ``bind_app`` puts it in
+    front of the family's ``Served``, whose ``__init__`` and ``_step`` the
+    ``super()`` calls below reach."""
+
     def __init__(self, model: Dict[str, Any], engine: Dict[str, Any],
                  seed: int, rehearsal: bool, chips: int = 1):
         from benchmark.harness import onchip
-        from benchmark.harness.modelcfg import build_llama_config
 
         self._bench_model = model
         self._bench_rehearsal = rehearsal
@@ -38,12 +42,7 @@ class BenchGenerator(LlamaGenerator):
         self._bench_trace: Dict[str, Any] = {}
         self._bench_compiles = onchip.count_compiles()
         super().__init__(
-            config=build_llama_config(model),
-            lora_rank=engine["lora_rank"],
-            max_batch_size=engine["max_batch_size"],
-            allowed_batch_sizes=tuple(engine["allowed_batch_sizes"]),
-            max_new_tokens=engine["max_new_tokens"],
-            seq_bucket=engine["seq_bucket"], seed=seed % (2 ** 31))
+            **loader.load_family(model).served_kwargs(model, engine, seed))
 
     def _step(self, model_id, states):
         import jax
@@ -64,7 +63,7 @@ class BenchGenerator(LlamaGenerator):
         if not self._bench_rehearsal:
             onchip.require_chips(info, self._bench_chips)
         info.update(
-            forward_compiles=self._fwd._cache_size(),
+            forward_compiles=self.compiled_step_programs(),
             compiles=len(self._bench_compiles),
             compile_s=sum(self._bench_compiles),
             param_dtypes=sorted({str(x.dtype) for x in
@@ -73,14 +72,10 @@ class BenchGenerator(LlamaGenerator):
         return info
 
     def bench_warm(self, seq_len: int) -> float:
-        """Compile (or find in the cache) and run the forward at one
-        sequence bucket, the logits brought to the host as ``_step`` does."""
-        import jax.numpy as jnp
-        import numpy as np
-
+        """Seconds the served class takes to compile (or find in the
+        cache) and run what a step runs at one sequence bucket."""
         t = time.perf_counter()
-        tokens = np.zeros((self.engine.max_batch_size, seq_len), np.int32)
-        np.asarray(self._fwd(self._params, jnp.asarray(tokens), None))
+        self.warm_step_programs(seq_len)
         return time.perf_counter() - t
 
     def bench_check(self, prompt: List[int]) -> Dict[str, Any]:
@@ -89,14 +84,9 @@ class BenchGenerator(LlamaGenerator):
         import jax.numpy as jnp
         import numpy as np
 
-        from benchmark.reference import dense_decoder
-
         t = time.perf_counter()
-        tokens = np.zeros((self.engine.max_batch_size, len(prompt)), np.int32)
-        tokens[0] = prompt
-        got = np.asarray(self._fwd(self._params, jnp.asarray(tokens),
-                                   None))[0, len(prompt) - 1]
-        want = np.asarray(dense_decoder.last_logits(
+        got = self.last_position_logits(prompt)
+        want = np.asarray(loader.load_reference(self._bench_model).last_logits(
             self._params, jnp.asarray(prompt, jnp.int32), self._bench_model))
         return {
             "max_abs_diff": float(np.abs(got - want).max()),
@@ -142,12 +132,16 @@ class BenchGenerator(LlamaGenerator):
 
 def bind_app(cell: Dict[str, Any], *, seed: int, rehearsal: bool):
     """The application ``serve.run`` is given: one deployment of
-    ``BenchGenerator``, sized as ``llm.build_llama_app`` sizes its own."""
+    ``BenchGenerator`` over the family's served class, sized as
+    ``llm.build_llama_app`` sizes its own. The class made here has no body:
+    everything it runs is importable by name in the replica."""
     from ray_tpu.serve.deployment import Deployment
 
     eng = cell["engine"]
+    served = type("BenchServed", (
+        BenchGenerator, loader.load_family(cell["model"]).Served), {})
     dep = Deployment(
-        BenchGenerator, "BenchGenerator", num_replicas=1,
+        served, "BenchGenerator", num_replicas=1,
         max_ongoing_requests=max(eng["max_ongoing_requests"],
                                  2 * eng["max_batch_size"]),
         max_queued_requests=eng["max_queued_requests"],

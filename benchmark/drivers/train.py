@@ -2,8 +2,9 @@
 
 ``run`` is the harness side and never touches jax. ``train_loop`` is the
 benchmark's own loop and runs inside the worker, the only process that
-holds the chip: it builds the model from the configuration file, checks
-the program's loss against the float32 reference, warms the step up, runs
+holds the chip: it builds the model from the configuration file through
+the file's family (``benchmark/families/``), checks the program's loss
+against the family's float32 reference, warms the step up, runs
 the measured window, traces a few steps of it when asked, reads the
 device's memory, and sends everything back in its last ``train.report``.
 """
@@ -26,12 +27,8 @@ def train_loop(config: Dict[str, Any]) -> None:
     import optax
     from jax.sharding import NamedSharding
 
-    from benchmark.harness import onchip, xplane
-    from benchmark.harness.modelcfg import build_llama_config
-    from benchmark.reference import dense_decoder
+    from benchmark.harness import loader, onchip, xplane
     from ray_tpu import train
-    from ray_tpu.models.llama import (
-        init_llama, llama_logical_axes, llama_loss)
     from ray_tpu.parallel.mesh import MeshConfig, create_mesh
     from ray_tpu.parallel.sharding import logical_to_spec
     from ray_tpu.parallel.train_step import (
@@ -48,18 +45,20 @@ def train_loop(config: Dict[str, Any]) -> None:
         onchip.require_chips(device, cell["chips"])
     compiles = onchip.count_compiles()
 
-    cfg = build_llama_config(m)
+    model = loader.load_family(m).training(m)
     rows, seq = mix["rows_per_step"], mix["seq"]
-    mesh = create_mesh(MeshConfig(data=1, fsdp=len(devices)))
+    # the cell's own axes, or every chip on fsdp
+    mesh = create_mesh(MeshConfig(
+        **tr.get("mesh", {"data": 1, "fsdp": len(devices)})))
     tx = optax.adamw(tr["lr"])
     batch_sharding = NamedSharding(mesh, logical_to_spec(("batch", "seq")))
-    loss_fn = lambda p, b: llama_loss(p, b, cfg)  # noqa: E731
+    loss_fn = model["loss"]
     out: Dict[str, Any] = {}
     with jax.set_mesh(mesh):
         t = time.time()
         state, shardings = create_train_state(
-            lambda key: init_llama(cfg, key), tx, mesh,
-            llama_logical_axes(cfg), seed=config["seed"] % (2 ** 31))
+            model["init"], tx, mesh, model["logical_axes"],
+            seed=config["seed"] % (2 ** 31))
         step = make_train_step(loss_fn, tx, mesh, shardings,
                                batch_logical_axes=("batch", "seq"))
         jax.block_until_ready(state)
@@ -90,7 +89,7 @@ def train_loop(config: Dict[str, Any]) -> None:
         eval_step = make_eval_step(loss_fn, mesh, shardings,
                                    batch_logical_axes=("batch", "seq"))
         loss_program = float(eval_step(state.params, check_batch))
-        loss_reference = float(dense_decoder.loss(
+        loss_reference = float(loader.load_reference(m).loss(
             state.params, jnp.asarray(one_in[0]), jnp.asarray(one_tg[0]), m))
         out["check"] = {
             "loss_program": loss_program, "loss_reference": loss_reference,

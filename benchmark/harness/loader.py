@@ -1,7 +1,9 @@
 """Find a cell's files by the names in ``BENCHMARK.json``. Imports no jax.
 
 A cell is ``workloads/<cell>.json``; it names its configuration
-(``configs/<config>.json``), its ``kind`` (the driver under ``drivers/``)
+(``configs/<config>.json``, which names its ``family``, the module under
+``families/`` that knows the program's names for that kind of model), its
+``kind`` (the driver under ``drivers/``)
 and its traffic mix (``traffic/<mix>.json``, a file of parameters that
 names the general generator, a module under ``traffic/``, that reads it). Per-layer metrics
 are the files under ``metrics/`` whose ``cells`` or ``kinds`` include the
@@ -19,6 +21,12 @@ from typing import Any, Dict, List, Optional
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 REPO_ROOT = os.path.dirname(BENCH_DIR)
+# keys of every configuration file that say where it comes from and how it
+# is run, and describe no shape: a family refuses any other key it does
+# not understand
+BOOKKEEPING_KEYS = ("name", "source", "family", "model_type", "reduced",
+                    "changed_from_source", "assumed", "program",
+                    "deployment", "notes")
 
 
 def export_environment(*, rehearsal: bool) -> None:
@@ -85,6 +93,7 @@ def load_cell(name: str, *, bench_dir: str = BENCH_DIR,
             bench_dir, "workloads", name + ".rehearsal.json"))
         model = _merge(model, over.get("model", {}))
         cell = _merge(cell, {k: v for k, v in over.items() if k != "model"})
+    load_family(model, bench_dir=bench_dir).check(model)
     cell["model"] = model
     return cell
 
@@ -149,6 +158,24 @@ def load_traffic(cell: Dict[str, Any], *, bench_dir: str = BENCH_DIR):
 
 def load_driver(cell: Dict[str, Any], *, bench_dir: str = BENCH_DIR):
     return _load_module(bench_dir, "drivers", cell["kind"])
+
+
+def load_family(model: Dict[str, Any], *, bench_dir: str = BENCH_DIR):
+    """``families/<family>.py``, named by the configuration file: what the
+    drivers and readers take from the program and the reference for this
+    kind of model (``families/dense_decoder.py`` says what a family gives)."""
+    if "family" not in model:
+        raise BenchmarkFileError(
+            f"configs/{model.get('name')}.json names no family")
+    return _load_module(bench_dir, "families", model["family"])
+
+
+def load_reference(model: Dict[str, Any], *, bench_dir: str = BENCH_DIR):
+    """``reference/<name>.py``, named by the configuration's family: the
+    plain implementation whose ``loss`` and ``last_logits`` decide
+    ``correct``. It imports jax: worker and replica only."""
+    family = load_family(model, bench_dir=bench_dir)
+    return _load_module(bench_dir, "reference", family.REFERENCE)
 
 
 def manifest_metrics(manifest: Dict[str, Any], cell_name: str,

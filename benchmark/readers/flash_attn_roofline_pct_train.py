@@ -1,6 +1,6 @@
 """Reader of `flash_attn_roofline_pct.train`; what it reads is in metrics/flash_attn_roofline_pct.train.json."""
 
-from benchmark.harness import flops
+from benchmark.harness import loader
 from benchmark.readers import common
 
 
@@ -9,8 +9,9 @@ def read(view, metric):
     if not ms:
         return None
     cell, pk = view["cell"], view["peaks"]
+    family = loader.load_family(cell["model"])
     rows = cell["traffic"]["rows_per_step"] / view["device"]["count"]
     need_s = max(
-        flops.flash_train_flops(cell["model"], rows, cell["traffic"]["seq"]) / pk["bf16_flops_per_s"],
-        flops.flash_train_bytes(cell["model"], rows, cell["traffic"]["seq"]) / pk["hbm_bytes_per_s"])
+        family.attention_kernel_flops(cell["model"], rows, cell["traffic"]["seq"]) / pk["bf16_flops_per_s"],
+        family.attention_kernel_bytes(cell["model"], rows, cell["traffic"]["seq"]) / pk["hbm_bytes_per_s"])
     return 100.0 * need_s / (1e-3 * ms)

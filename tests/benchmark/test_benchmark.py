@@ -9,6 +9,7 @@ leak gate sweeps ``/dev/shm/ray_tpu``. No test here starts a cluster in
 this process; each subprocess has its own time limit.
 """
 
+import ast
 import copy
 import json
 import os
@@ -22,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmark.families import dense_decoder as dense_family
 from benchmark.harness import flops, lastline, loader, peaks, xplane
 from benchmark.harness.modelcfg import build_llama_config, check_supported
 from benchmark.reference import dense_decoder
@@ -275,41 +277,79 @@ def test_every_listed_cell_resolves_and_agrees_with_its_files(manifest):
         tiny = loader.load_cell(w["name"], rehearsal=True)
         assert tiny["model"]["hidden_size"] < cell["model"]["hidden_size"]
         assert set(tiny["model"]) == set(cell["model"])
+        # the configuration's family and its reference are files too
+        family = loader.load_family(cell["model"])
+        assert family is loader.load_family(tiny["model"])
+        assert os.path.exists(os.path.join(
+            loader.BENCH_DIR, "reference", family.REFERENCE + ".py"))
     assert used == {c["name"] for c in manifest["configs"]}
-    # written, rehearsed, not proved on the chip in PR 23: not listed
-    assert "train_l8_fsdp4" in cell_names()
-    assert "train_l8_fsdp4" not in {w["name"] for w in manifest["workloads"]}
 
 
 def test_every_cell_file_is_loadable_even_if_not_listed():
     for name in cell_names():
         cell = loader.load_cell(name)
-        assert cell["kind"] in ("train", "serve")
+        assert callable(loader.load_driver(cell).run)
         assert loader.metrics_for_cell(cell)
 
 
+def config_names():
+    return [f[:-5] for f in sorted(os.listdir(
+        os.path.join(loader.BENCH_DIR, "configs"))) if f.endswith(".json")]
+
+
 def test_configurations_state_source_cut_and_keep_every_width(manifest):
+    """What every configuration file owes, whatever its family."""
     files = [c["file"] for c in manifest["configs"]]
     assert len(files) == len(set(files))
+    listed = {c["name"]: c for c in manifest["configs"]}
+    assert set(listed) <= set(config_names())
+    for name in config_names():
+        cfg = loader.load_config(name)
+        assert cfg["source"].startswith("https://huggingface.co/"), name
+        assert not any(WIDTHS.search(k) for k in cfg["reduced"]), name
+        assert set(cfg["changed_from_source"]) == set(cfg["reduced"]), name
+        for k, change in cfg["changed_from_source"].items():
+            assert set(change) == {"source", "here"}, (name, k)
+            assert change["here"] == cfg[k] != change["source"], (name, k)
+        assert cfg["assumed"] and cfg["deployment"], name
+        # its family resolves and accepts the file
+        loader.load_family(cfg).check(cfg)
+        if name in listed:
+            c = listed[name]
+            assert c["file"] == f"benchmark/configs/{name}.json"
+            assert (cfg["source"], cfg["reduced"]) == (c["source"],
+                                                       c["reduced"])
+
+
+MISTRAL_7B_V03 = ("https://huggingface.co/mistralai/Mistral-7B-v0.3/"
+                  "blob/main/config.json")
+
+
+@pytest.mark.parametrize("name", [
+    n for n in config_names()
+    if loader.load_config(n)["source"] == MISTRAL_7B_V03])
+def test_the_mistral_files_keep_mistrals_widths(name):
+    """A pin of these files alone: another source brings its own."""
     full = {"hidden_size": 4096, "intermediate_size": 14336,
             "num_attention_heads": 32, "num_key_value_heads": 8,
             "head_dim": 128, "vocab_size": 32768, "rope_theta": 1e6,
             "rms_norm_eps": 1e-5, "max_position_embeddings": 32768,
             "sliding_window": None, "tie_word_embeddings": False}
-    for c in manifest["configs"]:
-        assert c["file"] == f"benchmark/configs/{c['name']}.json"
-        cfg = loader.load_config(c["name"])
-        assert cfg["source"] == c["source"]
-        assert c["source"].startswith("https://huggingface.co/mistralai/")
-        assert cfg["reduced"] == c["reduced"] == ["num_hidden_layers"]
-        assert not any(WIDTHS.search(k) for k in c["reduced"])
-        for k, v in full.items():
-            assert cfg[k] == v, (c["name"], k)
-        assert set(cfg["changed_from_source"]) == set(cfg["reduced"])
-        assert cfg["changed_from_source"]["num_hidden_layers"] == {
-            "source": 32, "here": cfg["num_hidden_layers"]}
-        assert cfg["assumed"] and cfg["deployment"]
-        assert cfg["program"]["attn_impl"] == "flash"
+    cfg = loader.load_config(name)
+    for k, v in full.items():
+        assert cfg[k] == v, (name, k)
+    assert cfg["family"] == "dense_decoder"
+    assert cfg["reduced"] == ["num_hidden_layers"]
+    assert cfg["changed_from_source"]["num_hidden_layers"] == {
+        "source": 32, "here": cfg["num_hidden_layers"]}
+    assert cfg["program"]["attn_impl"] == "flash"
+
+
+def test_all_three_mistral_files_are_pinned():
+    names = [n for n in config_names()
+             if loader.load_config(n)["source"] == MISTRAL_7B_V03]
+    assert names == ["mistral7b-serve-l16", "mistral7b-train-l2",
+                     "mistral7b-train-l8-fsdp4"]
 
 
 def test_metric_files_agree_with_the_manifest(manifest):
@@ -361,14 +401,51 @@ def test_files_under_paths_are_named_from_a_names_characters(manifest,
                 assert ok.match(rel), rel
 
 
+# what a `model_config` PR brings for a family the benchmark does not have:
+# a module under families/, its reference, a configuration of it
+NEW_FAMILY = '''
+from benchmark.harness.loader import BOOKKEEPING_KEYS
+
+REFERENCE = "sparse_decoder"
+SHAPE_KEYS = ("hidden_size", "intermediate_size", "num_hidden_layers",
+              "vocab_size", "num_experts", "num_experts_per_tok")
+
+
+def check(m):
+    unknown = sorted(set(m) - set(SHAPE_KEYS) - set(BOOKKEEPING_KEYS))
+    if unknown:
+        raise ValueError(f"the sparse family does not understand {unknown}")
+
+
+def train_flops_per_token(m, seq):
+    active = (3 * m["hidden_size"] * m["intermediate_size"]
+              * m["num_experts_per_tok"])
+    return 6.0 * (m["num_hidden_layers"] * active
+                  + m["vocab_size"] * m["hidden_size"])
+'''
+NEW_REFERENCE = '''
+def loss(params, inputs, targets, m):
+    return 0.0
+
+
+def last_logits(params, tokens, m):
+    return [0.0] * m["vocab_size"]
+'''
+
+
 def test_one_of_each_can_be_added_without_editing_a_file(tmp_path, manifest):
     bench = tmp_path / "benchmark"
     shutil.copytree(loader.BENCH_DIR, bench,
                     ignore=shutil.ignore_patterns("__pycache__", "fixtures"))
     before = {p: p.read_bytes() for p in bench.rglob("*") if p.is_file()}
 
-    cfg = loader.load_config("mistral7b-train-l2")
-    cfg.update(name="other-l3", num_hidden_layers=3)
+    (bench / "families" / "sparse_decoder.py").write_text(NEW_FAMILY)
+    (bench / "reference" / "sparse_decoder.py").write_text(NEW_REFERENCE)
+    cfg = {k: v for k, v in loader.load_config("mistral7b-train-l2").items()
+           if k in loader.BOOKKEEPING_KEYS}
+    cfg.update(name="other-l3", family="sparse_decoder", hidden_size=2048,
+               intermediate_size=1024, num_hidden_layers=3, vocab_size=50304,
+               num_experts=64, num_experts_per_tok=8)
     (bench / "configs" / "other-l3.json").write_text(json.dumps(cfg))
     (bench / "traffic" / "saw_tooth.py").write_text(
         "def rows(table, *, params, seed, vocab):\n"
@@ -390,6 +467,21 @@ def test_one_of_each_can_be_added_without_editing_a_file(tmp_path, manifest):
 
     got = loader.load_cell("train_l3_saw", bench_dir=str(bench))
     assert got["model"]["num_hidden_layers"] == 3
+    # the cell goes through its own family and reference, found by name
+    family = loader.load_family(got["model"], bench_dir=str(bench))
+    assert family.__file__ == str(bench / "families" / "sparse_decoder.py")
+    reference = loader.load_reference(got["model"], bench_dir=str(bench))
+    assert reference.__file__ == str(bench / "reference" / "sparse_decoder.py")
+    assert reference.loss(None, [], [], got["model"]) == 0.0
+    # which the dense family would have refused, naming the key it meets
+    with pytest.raises(ValueError, match="num_experts"):
+        dense_family.check(got["model"])
+    # and a key its own family does not know stops the cell from loading
+    (bench / "configs" / "other-l3.json").write_text(
+        json.dumps(dict(cfg, kv_lora_rank=512)))
+    with pytest.raises(ValueError, match="kv_lora_rank"):
+        loader.load_cell("train_l3_saw", bench_dir=str(bench))
+    (bench / "configs" / "other-l3.json").write_text(json.dumps(cfg))
     assert got["traffic"]["rows_per_step"] == 3
     assert loader.load_traffic(got, bench_dir=str(bench)).rows(
         None, params=None, seed=0, vocab=0) == {"inputs": [], "targets": []}
@@ -397,6 +489,10 @@ def test_one_of_each_can_be_added_without_editing_a_file(tmp_path, manifest):
     mine = loader.metrics_for_cell(got, bench_dir=str(bench))
     assert "steps_done.train" in {m["name"] for m in mine}
     assert "mfu_pct.train" in {m["name"] for m in mine}  # by its kind
+    # whose reader counts with the cell's family, not with the dense
+    # family's layer: 8 experts of 1024 a token, and the head
+    assert family.train_flops_per_token(got["model"], 1024) == 6.0 * (
+        3 * 3 * 2048 * 1024 * 8 + 50304 * 2048)
     new = next(m for m in mine if m["name"] == "steps_done.train")
     assert loader.load_reader(new, bench_dir=str(bench))(
         {"obs": {"steps": 7}}, new) == 7.0
@@ -440,6 +536,10 @@ def test_the_harness_side_imports_no_jax(repo_root):
         "import sys; sys.path.insert(0, %r)\n"
         "import benchmark.run\n"
         "from benchmark.harness import lastline, loader, peaks, stats, flops\n"
+        "from benchmark.harness import modelcfg\n"
+        "from benchmark.families import dense_decoder\n"
+        "assert loader.load_family({'family': 'dense_decoder'})"
+        " is dense_decoder\n"
         "from benchmark.drivers import train, serve\n"
         "from benchmark.traffic import open_loop_lognormal, packed_documents\n"
         "from benchmark.tools import sweep_rate\n"
@@ -465,6 +565,7 @@ def model(name):
 def test_parameter_counts(config, millions):
     assert flops.num_params(model(config)) / 1e6 == pytest.approx(
         millions, abs=0.06)
+    assert dense_family.num_params is flops.num_params
 
 
 def test_one_layer_and_the_embedding():
@@ -826,6 +927,212 @@ def test_attention_blocks_of_queries_change_nothing(monkeypatch):
 def test_what_the_dense_path_does_not_compute_is_refused(key, value):
     with pytest.raises(ValueError):
         check_supported(tiny_model(**{key: value}))
+
+
+# --------------------------------------------------------------------------
+# the seam between the benchmark and the program: a family module named by
+# the configuration file, and a served class that warms and checks itself
+# --------------------------------------------------------------------------
+@pytest.mark.parametrize("key,value", [
+    ("num_experts", 64), ("num_experts_per_tok", 8),
+    ("norm_topk_prob", False), ("kv_lora_rank", 512),
+    ("a_key_nobody_knows", 1), ("attention_dropout", 0.1)])
+def test_the_dense_family_refuses_a_key_it_does_not_understand(key, value):
+    """Before, ``num_experts: 64`` beside ``intermediate_size: 1024`` built
+    a dense model of width 1024 and ran ``correct`` under the file's name."""
+    m = dict(loader.load_config("mistral7b-train-l2"), **{key: value})
+    with pytest.raises(ValueError, match=key):
+        dense_family.check(m)
+    with pytest.raises(ValueError, match=key):
+        build_llama_config(m)
+
+
+def test_a_configuration_that_names_no_family_is_an_error():
+    m = loader.load_config("mistral7b-train-l2")
+    del m["family"]
+    with pytest.raises(loader.BenchmarkFileError, match="names no family"):
+        loader.load_family(m)
+
+
+BARRED = ("ray_tpu.models", "ray_tpu.serve.llm", "benchmark.harness.modelcfg",
+          "benchmark.harness.flops", "benchmark.reference")
+
+
+def imported_names(path):
+    """Every module an ``import`` of the file names, at any depth."""
+    with open(path) as f:
+        tree = ast.parse(f.read())
+    names = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            names.update(a.name for a in node.names)
+        elif isinstance(node, ast.ImportFrom):
+            names.add(node.module)
+            names.update(f"{node.module}.{a.name}" for a in node.names)
+    return names
+
+
+@pytest.mark.parametrize("driver", ["train", "serve"])
+def test_a_driver_reaches_the_program_only_through_the_family(driver):
+    names = imported_names(os.path.join(loader.BENCH_DIR, "drivers",
+                                        driver + ".py"))
+    assert "benchmark.harness.loader" in names
+    bad = sorted(n for n in names
+                 if any(n == b or n.startswith(b + ".") for b in BARRED))
+    assert bad == []
+    # the check finds what it looks for: the family imports all of them
+    fam = imported_names(dense_family.__file__)
+    assert {"ray_tpu.models.llama", "ray_tpu.serve.llm",
+            "benchmark.harness.modelcfg", "benchmark.harness.flops"} <= fam
+
+
+class FakeFamily:
+    """A family whose counts no dense layer gives."""
+
+    @staticmethod
+    def train_flops_per_token(m, seq):
+        return 1e9
+
+    @staticmethod
+    def attention_kernel_flops(m, rows, seq):
+        return 197e12 * 1e-3 * rows
+
+    @staticmethod
+    def attention_kernel_bytes(m, rows, seq):
+        return 0.0
+
+
+@pytest.mark.parametrize("config,gflop_per_token", [
+    ("mistral7b-train-l2", 3.66), ("mistral7b-train-l8-fsdp4", 12.21)])
+def test_the_two_readers_count_with_the_cells_family(config, gflop_per_token,
+                                                     monkeypatch):
+    """The numbers the ledger's lines were made with, through the seam."""
+    from benchmark.readers import flash_attn_roofline_pct_train as roofline
+    from benchmark.readers import mfu_pct_train as mfu
+
+    m = loader.load_config(config)
+    pk = peaks.peak("TPU v5 lite")
+    view = {"cell": {"model": m, "traffic": {"seq": 4096, "rows_per_step": 2}},
+            "peaks": pk, "device": {"count": 1},
+            "e2e": {"train_tokens_per_s_per_chip": 28313.0},
+            "trace": {"ops": [("tpu_custom_call:x", 0.040, 6),
+                              ("fusion.1", 1.0, 6)], "steps": 2}}
+    got = mfu.read(view, {})
+    assert got == pytest.approx(
+        100.0 * gflop_per_token * 1e9 * 28313.0 / 197e12, rel=3e-3)
+    assert got == 100.0 * flops.train_flops_per_token(m, 4096) * 28313.0 \
+        / 197e12
+    # seven causal matmuls a layer over 20 ms of kernels a step
+    one = 2.0 * 4096 * 4096 * 128 * 32 / 2
+    metric = {"match": "^tpu_custom_call:"}
+    assert roofline.read(view, metric) == pytest.approx(
+        100.0 * (m["num_hidden_layers"] * 7 * one * 2 / 197e12) / 0.020)
+    assert roofline.read(dict(view, trace={"ops": [], "steps": 2}),
+                         metric) is None
+    # and it is the family the loader names that counts, not `flops`
+    monkeypatch.setattr(loader, "load_family", lambda model: FakeFamily)
+    assert mfu.read(view, {}) == pytest.approx(
+        100.0 * 1e9 * 28313.0 / 197e12)
+    assert roofline.read(view, metric) == pytest.approx(
+        100.0 * 2e-3 / 0.020)
+
+
+def test_the_family_gives_training_its_three_parts(setup):
+    m, cfg, params, tokens = setup
+    parts = dense_family.training(m)
+    assert set(parts) == {"init", "logical_axes", "loss"}
+    mine = parts["init"](jax.random.key(3))
+    assert jax.tree.structure(mine) == jax.tree.structure(params) \
+        == jax.tree.structure(parts["logical_axes"],
+                              is_leaf=lambda x: isinstance(x, tuple))
+    batch = {"inputs": tokens[:, :-1], "targets": tokens[:, 1:]}
+    assert float(parts["loss"](params, batch)) == float(
+        llama_loss(params, batch, cfg))
+    assert loader.load_reference(m) is dense_decoder
+
+
+@pytest.fixture(scope="module")
+def served():
+    gen = dense_family.Served(config="tiny", max_batch_size=2,
+                              allowed_batch_sizes=(2,), max_new_tokens=4,
+                              seq_bucket=24)
+    yield gen
+    gen.engine.shutdown()
+
+
+def test_last_position_logits_are_fwds_at_the_prompts_end(served):
+    prompt = [3, 5, 7, 11, 13, 17, 19, 23]
+    got = served.last_position_logits(prompt)
+    tokens = np.zeros((2, len(prompt)), np.int32)
+    tokens[0] = prompt
+    want = np.asarray(served._fwd(served._params, jnp.asarray(tokens),
+                                  None))[0, len(prompt) - 1]
+    assert got.shape == (served._cfg.vocab_size,)
+    assert got.dtype == np.float32
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    # the step's own choice of token is the argmax of these logits
+    state = served._prefill({"prompt": prompt}, "")
+    assert served._step("", [state, None])[0][0] == int(got.argmax())
+
+
+def test_a_warmed_shape_leaves_the_step_nothing_to_compile(served):
+    from benchmark.harness.onchip import count_compiles
+
+    compiles = count_compiles()
+    before = served.compiled_step_programs()
+    served.warm_step_programs(48)
+    assert compiles, "the listener saw the warm-up compile nothing"
+    assert served.compiled_step_programs() == before + 1
+    warmed = len(compiles)
+    states = [served._prefill({"prompt": list(range(1, 30))}, ""),
+              served._prefill({"prompt": list(range(1, 40))}, "")]
+    for _ in range(2):
+        out = served._step("", states)
+        assert all(0 <= tok < served._cfg.vocab_size for tok, _ in out)
+    assert len(compiles) == warmed, (
+        f"{len(compiles) - warmed} compilation(s) in a warmed step")
+    assert served.compiled_step_programs() == before + 1
+
+
+def test_a_method_the_program_gains_takes_the_stand_ins_place():
+    from ray_tpu.serve.llm import LlamaGenerator
+
+    assert dense_family.Served.__mro__[1:3] == (
+        LlamaGenerator, dense_family._OwedByTheProgram)
+
+    class Program(LlamaGenerator):
+        def warm_step_programs(self, seq_len):
+            return "the program's own"
+
+    class ServedThen(Program, dense_family._OwedByTheProgram):
+        pass
+
+    assert ServedThen.warm_step_programs is Program.warm_step_programs
+    assert (ServedThen.last_position_logits
+            is dense_family._OwedByTheProgram.last_position_logits)
+
+
+def test_what_is_deployed_is_found_by_name_in_the_replica():
+    """``bind_app``'s class has no body of its own: pickled by value, it
+    names its two bases, which the replica imports."""
+    import cloudpickle
+
+    from benchmark.drivers import serve as serve_driver
+
+    cell = loader.load_cell("serve_chat_steady", rehearsal=True)
+    app = serve_driver.bind_app(cell, seed=1, rehearsal=True)
+    cls = app.deployment.func_or_class
+    assert cls.__bases__ == (serve_driver.BenchGenerator,
+                             dense_family.Served)
+    assert not set(vars(cls)) - {"__module__", "__doc__", "__qualname__"}
+    again = cloudpickle.loads(cloudpickle.dumps(cls))
+    assert again.__bases__ == cls.__bases__
+    for name in ("bench_warm", "bench_check", "bench_device", "_step"):
+        assert getattr(again, name) is getattr(serve_driver.BenchGenerator,
+                                               name)
+    for name in ("warm_step_programs", "last_position_logits",
+                 "compiled_step_programs", "engine_stats", "__call__"):
+        assert getattr(again, name) is getattr(dense_family.Served, name)
 
 
 # --------------------------------------------------------------------------
