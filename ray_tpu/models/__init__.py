@@ -4,6 +4,10 @@ The reference ships no models of its own for Train (users bring torch models);
 RLlib ships torch/tf model catalogs (reference: rllib/models/, 12.1k LoC).
 TPU-native, the framework provides sharding-annotated JAX model families that
 the Train/Serve/RLlib layers consume directly.
+
+A decoder with routed experts (OLMoE) is a ``LlamaConfig`` with
+``num_experts`` above 0: ``llama.py`` holds the one block, ``moe.py`` the
+routed feed-forward it calls.
 """
 
 from ray_tpu.models.llama import (

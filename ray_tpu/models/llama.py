@@ -27,6 +27,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models import moe
 from ray_tpu.ops.attention import attention
 from ray_tpu.parallel.sharding import constrain
 
@@ -64,6 +65,16 @@ class LlamaConfig:
     loss_chunk: int = 0            # >0: lm-head CE in seq chunks of this size
     #   (peak logits memory B*chunk*V instead of B*S*V; the backward
     #    recomputes each chunk's logits under jax.checkpoint)
+    # What the model is, beyond the dense decoder (OLMoE-1B-7B has all of
+    # it): with num_experts > 0 the block's feed-forward is models/moe.py's
+    # routed experts, each of width mlp_hidden, experts_per_token a
+    # position; qk_norm puts an RMSNorm with a learned weight over the
+    # whole query and the whole key projection, before the heads and rope.
+    num_experts: int = 0
+    experts_per_token: int = 0
+    norm_topk_prob: bool = False   # renormalise the chosen experts' weights
+    router_aux_loss_coef: float = 0.0  # load-balancing term in llama_loss
+    qk_norm: bool = False
 
     @staticmethod
     def llama2_7b() -> "LlamaConfig":
@@ -93,9 +104,10 @@ class LlamaConfig:
                            head_dim=32, max_seq_len=128, remat=False)
 
     def flops_per_token(self, seq_len: Optional[int] = None) -> float:
-        """Approximate fwd+bwd FLOPs/token: 6*N, plus the attention
-        quadratic term 12*L*H*D*S when ``seq_len`` is given."""
-        flops = 6.0 * self.num_params()
+        """Approximate fwd+bwd FLOPs/token: 6*N (with experts, the N a
+        position meets), plus the attention quadratic term 12*L*H*D*S when
+        ``seq_len`` is given."""
+        flops = 6.0 * self.num_params(active=True)
         if seq_len is not None:
             flops += (12.0 * self.num_layers * self.num_heads
                       * self.head_dim * seq_len)
@@ -114,12 +126,17 @@ class LlamaConfig:
                       * self.head_dim * seq_len)
         return flops
 
-    def num_params(self) -> int:
+    def num_params(self, active: bool = False) -> int:
+        """All parameters, or with ``active`` those one position meets
+        (``experts_per_token`` of the experts)."""
         h, m, v = self.hidden, self.mlp_hidden, self.vocab_size
-        qkv = h * (self.num_heads + 2 * self.num_kv_heads) * self.head_dim
-        o = self.num_heads * self.head_dim * h
+        q, kv = self.num_heads * self.head_dim, self.num_kv_heads * self.head_dim
+        attn = h * (q + 2 * kv) + q * h + ((q + kv) if self.qk_norm else 0)
         mlp = 3 * h * m
-        per_layer = qkv + o + mlp + 2 * h
+        if self.num_experts:
+            used = self.experts_per_token if active else self.num_experts
+            mlp = used * mlp + h * self.num_experts
+        per_layer = attn + mlp + 2 * h
         return self.num_layers * per_layer + 2 * v * h + h
 
 
@@ -178,6 +195,10 @@ def init_lora(cfg: LlamaConfig, lcfg: LoraConfig, key: jax.Array) -> Dict:
     at the base), stacked over layers for the scanned body."""
     dims = _lora_dims(cfg)
     L, r = cfg.num_layers, lcfg.rank
+    routed = sorted(set(lcfg.targets) & {"w_gate", "w_up", "w_down"})
+    if cfg.num_experts and routed:
+        raise ValueError(f"LoRA targets {routed}: a model with experts has "
+                         "no dense feed-forward to adapt")
     out = {}
     keys = jax.random.split(key, len(lcfg.targets))
     for k, name in zip(keys, lcfg.targets):
@@ -231,12 +252,16 @@ def llama_logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
         "wk": ("embed", "kv_heads", "head_dim"),
         "wv": ("embed", "kv_heads", "head_dim"),
         "wo": ("heads", "head_dim", "embed"),
-        "w_gate": ("embed", "mlp"),
-        "w_up": ("embed", "mlp"),
-        "w_down": ("mlp", "embed"),
         "attn_norm": ("norm",),
         "mlp_norm": ("norm",),
     }
+    if cfg.num_experts:
+        layer.update(moe.EXPERT_LOGICAL_AXES)
+    else:
+        layer.update(w_gate=("embed", "mlp"), w_up=("embed", "mlp"),
+                     w_down=("mlp", "embed"))
+    if cfg.qk_norm:
+        layer.update(q_norm=("norm",), k_norm=("norm",))
     # scanned layers carry a leading 'layers' dim — replicated (None)
     layers = {k: (None,) + v for k, v in layer.items()}
     return {
@@ -264,12 +289,18 @@ def init_llama(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
         "wk": norm_init((L, h, nkv, hd), ks[1], h),
         "wv": norm_init((L, h, nkv, hd), ks[2], h),
         "wo": norm_init((L, nh, hd, h), ks[3], nh * hd),
-        "w_gate": norm_init((L, h, m), ks[4], h),
-        "w_up": norm_init((L, h, m), ks[5], h),
-        "w_down": norm_init((L, m, h), ks[6], m),
-        "attn_norm": jnp.ones((L, h), pd),
-        "mlp_norm": jnp.ones((L, h), pd),
     }
+    if cfg.num_experts:
+        layers.update(moe.init_experts(cfg, ks[9]))
+    else:
+        layers.update(w_gate=norm_init((L, h, m), ks[4], h),
+                      w_up=norm_init((L, h, m), ks[5], h),
+                      w_down=norm_init((L, m, h), ks[6], m))
+    layers.update(attn_norm=jnp.ones((L, h), pd),
+                  mlp_norm=jnp.ones((L, h), pd))
+    if cfg.qk_norm:
+        layers.update(q_norm=jnp.ones((L, nh * hd), pd),
+                      k_norm=jnp.ones((L, nkv * hd), pd))
     return {
         "embed": norm_init((cfg.vocab_size, h), ks[7], 1.0),
         "layers": layers,
@@ -303,7 +334,9 @@ def _layer(cfg: LlamaConfig, x: jax.Array, lp: Dict[str, jax.Array],
            positions: jax.Array, kv_cache=None,
            cache_index: Optional[jax.Array] = None,
            lora: Optional[Dict[str, Any]] = None, lora_scale: float = 0.0):
-    """One transformer block. x: [B, S, H_model]."""
+    """One transformer block. x: [B, S, H_model] -> (x, the updated
+    key/value cache or None, the router's books of ``moe.expert_ffn`` or
+    None for a dense feed-forward)."""
     dt = cfg.dtype
 
     def _ld(name, t_in, eq_a, eq_b):
@@ -322,6 +355,11 @@ def _layer(cfg: LlamaConfig, x: jax.Array, lp: Dict[str, jax.Array],
          + _ld("wk", h, "bsh,hr->bsr", "bsr,rnd->bsnd"))
     v = (jnp.einsum("bsh,hnd->bsnd", h, lp["wv"].astype(dt))
          + _ld("wv", h, "bsh,hr->bsr", "bsr,rnd->bsnd"))
+    if cfg.qk_norm:  # over the whole projection, heads x head_dim
+        q = _rms_norm(q.reshape(q.shape[:2] + (-1,)), lp["q_norm"],
+                      cfg.rms_eps).reshape(q.shape)
+        k = _rms_norm(k.reshape(k.shape[:2] + (-1,)), lp["k_norm"],
+                      cfg.rms_eps).reshape(k.shape)
     q = _rope(q, positions, cfg.rope_theta)
     k = _rope(k, positions, cfg.rope_theta)
     q = constrain(q, ("batch", "seq", "heads", None))
@@ -343,8 +381,11 @@ def _layer(cfg: LlamaConfig, x: jax.Array, lp: Dict[str, jax.Array],
     attn_out = constrain(attn_out, ("batch", "seq", "heads", None))
     x = (x + jnp.einsum("bsnd,ndh->bsh", attn_out, lp["wo"].astype(dt))
          + _ld("wo", attn_out, "bsnd,ndr->bsr", "bsr,rh->bsh"))
-    # --- mlp (SwiGLU) ---
+    # --- feed-forward: routed experts, or the dense SwiGLU ---
     h = _rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
+    if cfg.num_experts:
+        y, books = moe.expert_ffn(cfg, h, lp)
+        return constrain(x + y, ("batch", "seq", "embed")), new_cache, books
     gate = (jnp.einsum("bsh,hm->bsm", h, lp["w_gate"].astype(dt))
             + _ld("w_gate", h, "bsh,hr->bsr", "bsr,rm->bsm"))
     up = (jnp.einsum("bsh,hm->bsm", h, lp["w_up"].astype(dt))
@@ -353,7 +394,7 @@ def _layer(cfg: LlamaConfig, x: jax.Array, lp: Dict[str, jax.Array],
     x = (x + jnp.einsum("bsm,mh->bsh", act, lp["w_down"].astype(dt))
          + _ld("w_down", act, "bsm,mr->bsr", "bsr,rh->bsh"))
     x = constrain(x, ("batch", "seq", "embed"))
-    return x, new_cache
+    return x, new_cache, None
 
 
 def llama_decode(
@@ -376,7 +417,9 @@ def llama_decode(
     new_caches = []
     for i in range(cfg.num_layers):
         lp = jax.tree.map(lambda a: a[i], params["layers"])
-        x, c = _layer(cfg, x, lp, positions, kv_caches[i], cache_index)
+        if cfg.num_experts:
+            lp = moe.in_stack(lp, params["layers"], i)
+        x, c, _ = _layer(cfg, x, lp, positions, kv_caches[i], cache_index)
         new_caches.append(c)
     x = _rms_norm(x, params["final_norm"], cfg.rms_eps)
     logits = jnp.einsum("bsh,hv->bsv", x, params["lm_head"].astype(cfg.dtype))
@@ -395,6 +438,22 @@ def llama_hidden(
     """tokens [B, S] int32 → final hidden states [B, S, H] (activation
     dtype, post final-norm). Layers run under ``lax.scan`` with optional
     per-layer remat; LoRA adapters (if given) scan alongside the base."""
+    return _hidden_and_books(params, tokens, cfg, positions=positions,
+                             lora=lora, lora_cfg=lora_cfg)[0]
+
+
+def _hidden_and_books(
+    params: Dict[str, Any],
+    tokens: jax.Array,
+    cfg: LlamaConfig,
+    *,
+    positions: Optional[jax.Array] = None,
+    lora: Optional[Dict[str, Any]] = None,
+    lora_cfg: Optional[LoraConfig] = None,
+    router_mask: Optional[jax.Array] = None,
+) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
+    """``llama_hidden``, and with it the routers' books stacked over the
+    layers (``moe.expert_ffn``; None for a model without experts)."""
     B, S = tokens.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
@@ -403,10 +462,21 @@ def llama_hidden(
 
     scale = lora_cfg.scale if lora_cfg is not None else 0.0
 
-    def scan_fn(carry, xs):
-        lp, lo = xs
-        y, _ = _layer(cfg, carry, lp, positions, lora=lo, lora_scale=scale)
-        return y, None
+    def scan_over(layers, lo):
+        """(scan body, xs) over a stack of layers. With experts the body
+        also sees the whole stack and its own index in it."""
+        n = jax.tree.leaves(layers)[0].shape[0]
+        index = jnp.arange(n) if cfg.num_experts else None
+
+        def scan_fn(carry, xs):
+            lp, lo_i, i = xs
+            if cfg.num_experts:
+                lp = moe.in_stack(lp, layers, i, router_mask)
+            y, _, books = _layer(cfg, carry, lp, positions, lora=lo_i,
+                                 lora_scale=scale)
+            return y, books
+
+        return scan_fn, (layers, lo, index)
 
     lo_layers = lora["layers"] if lora is not None else None
     if cfg.remat:
@@ -421,33 +491,35 @@ def llama_hidden(
             k = int(cfg.remat_policy.split(":", 1)[1])
             n = cfg.num_layers
             k = max(0, min(k, n))
-            dots_fn = jax.checkpoint(
-                scan_fn,
-                policy=jax.checkpoint_policies.
-                dots_with_no_batch_dims_saveable)
-            full_fn = jax.checkpoint(
-                scan_fn, policy=jax.checkpoint_policies.nothing_saveable)
+            dots = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+            full = jax.checkpoint_policies.nothing_saveable
             head = jax.tree.map(lambda a: a[:k], params["layers"])
             tail = jax.tree.map(lambda a: a[k:], params["layers"])
             lo_head = (jax.tree.map(lambda a: a[:k], lo_layers)
                        if lo_layers is not None else {})
             lo_tail = (jax.tree.map(lambda a: a[k:], lo_layers)
                        if lo_layers is not None else {})
-            x, _ = jax.lax.scan(dots_fn, x, (head, lo_head))
-            x, _ = jax.lax.scan(full_fn, x, (tail, lo_tail))
-            return _rms_norm(x, params["final_norm"], cfg.rms_eps)
+            fn, xs = scan_over(head, lo_head)
+            x, b_head = jax.lax.scan(jax.checkpoint(fn, policy=dots), x, xs)
+            fn, xs = scan_over(tail, lo_tail)
+            x, b_tail = jax.lax.scan(jax.checkpoint(fn, policy=full), x, xs)
+            books = jax.tree.map(lambda a, b: jnp.concatenate([a, b]),
+                                 b_head, b_tail)
+            return _rms_norm(x, params["final_norm"], cfg.rms_eps), books
         if cfg.remat_policy not in ("dots", "full"):
             raise ValueError(
                 f"remat_policy {cfg.remat_policy!r}: expected "
                 "'dots'|'full'|'mixed:K'")
+    # broadcast None through the scan when no adapters: xs must be a pytree
+    # of arrays, so substitute an empty dict
+    scan_fn, xs = scan_over(params["layers"], lo_layers or {})
+    if cfg.remat:
         policy = (jax.checkpoint_policies.nothing_saveable
                   if cfg.remat_policy == "full"
                   else jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
         scan_fn = jax.checkpoint(scan_fn, policy=policy)
-    # broadcast None through the scan when no adapters: xs must be a pytree
-    # of arrays, so substitute an empty dict
-    x, _ = jax.lax.scan(scan_fn, x, (params["layers"], lo_layers or {}))
-    return _rms_norm(x, params["final_norm"], cfg.rms_eps)
+    x, books = jax.lax.scan(scan_fn, x, xs)
+    return _rms_norm(x, params["final_norm"], cfg.rms_eps), books
 
 
 def llama_forward(
@@ -484,18 +556,24 @@ def llama_next_token(
     *,
     lora: Optional[Dict[str, Any]] = None,
     lora_cfg: Optional[LoraConfig] = None,
-) -> Tuple[jax.Array, jax.Array]:
+    live: Optional[jax.Array] = None,
+) -> Tuple[jax.Array, jax.Array, Optional[Dict[str, jax.Array]]]:
     """Greedy next token of each row without the [B, S, V] logits: tokens
     [B, S] and the index ``last`` [B] int32 of each row's newest token →
-    (next ids [B] int32, final hidden states [B, S, H]). Only the B rows
-    ``hidden[b, last[b]]`` meet the head; the argmax is over their fp32
-    logits and a tie goes to the lowest id, as ``np.argmax`` has it. The
-    hidden states are returned so that a caller who wants every position's
-    logits applies ``llama_head`` to them and runs the layers once."""
-    x = llama_hidden(params, tokens, cfg, lora=lora, lora_cfg=lora_cfg)
+    (next ids [B] int32, final hidden states [B, S, H], the routers'
+    load). Only the B rows ``hidden[b, last[b]]`` meet the head; the argmax
+    is over their fp32 logits and a tie goes to the lowest id, as
+    ``np.argmax`` has it. The hidden states are returned so that a caller
+    who wants every position's logits applies ``llama_head`` to them and
+    runs the layers once. The load is None for a model without experts,
+    else ``moe.router_load`` over the positions ``live [B, S]`` marks (the
+    rows' own tokens and not their padding): two float32 a layer."""
+    x, books = _hidden_and_books(params, tokens, cfg, lora=lora,
+                                 lora_cfg=lora_cfg, router_mask=live)
     rows = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
     ids = jnp.argmax(llama_head(params, rows, cfg), axis=-1)
-    return ids.astype(jnp.int32), x
+    load = moe.router_load(books) if cfg.num_experts else None
+    return ids.astype(jnp.int32), x, load
 
 
 def _nll_from_logits(logits: jax.Array, targets: jax.Array) -> jax.Array:
@@ -552,15 +630,21 @@ def llama_loss(params: Dict[str, Any], batch: Dict[str, jax.Array],
     else:
         inputs, targets = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
         mask = None
-    x = llama_hidden(params, inputs, cfg, lora=lora, lora_cfg=lora_cfg)
+    x, books = _hidden_and_books(params, inputs, cfg, lora=lora,
+                                 lora_cfg=lora_cfg)
     if cfg.loss_chunk:
-        return _chunked_ce(x, params["lm_head"], targets, mask,
-                           cfg.loss_chunk, cfg.dtype)
-    logits = jnp.einsum("bsh,hv->bsv", x, params["lm_head"].astype(cfg.dtype))
-    nll = _nll_from_logits(logits, targets)
-    if mask is not None:
-        return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
-    return jnp.mean(nll)
+        ce = _chunked_ce(x, params["lm_head"], targets, mask,
+                         cfg.loss_chunk, cfg.dtype)
+    else:
+        logits = jnp.einsum("bsh,hv->bsv", x,
+                            params["lm_head"].astype(cfg.dtype))
+        nll = _nll_from_logits(logits, targets)
+        ce = (jnp.mean(nll) if mask is None else
+              jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0))
+    if cfg.num_experts:  # the routers' load-balancing term rides on it
+        ce = ce + cfg.router_aux_loss_coef * moe.load_balancing_loss(
+            books, cfg)
+    return ce
 
 
 def llama_lora_loss(base_params: Dict[str, Any], lora: Dict[str, Any],

@@ -1,207 +1,192 @@
-"""Mixtral-style sparse Mixture-of-Experts decoder with expert parallelism.
+"""Sparse expert feed-forward: the routed half of ``models/llama.py``'s block.
 
-New first-class capability (reference has no MoE or expert parallelism —
-SURVEY §2.5 marks EP as absent): top-k token routing with capacity-bounded
-einsum dispatch, experts sharded over the mesh ``expert`` axis so GSPMD
-lowers the dispatch/combine einsums to all_to_all over ICI.
+A model with experts is a ``LlamaConfig`` whose ``num_experts`` is above 0
+(OLMoE-1B-7B: 64 experts of width 1024, 8 a token, no shared expert). Its
+block is ``llama.py::_layer``: the attention half, the scan, remat, the
+head and the loss are the dense model's. This module holds what only the
+routed feed-forward needs: the router, the dispatch, the expert matmuls,
+the load-balancing term, and the expert leaves' initialiser and logical
+axes (the leading ``expert`` dim of the three stacks shards over the mesh's
+``expert`` axis).
 
-TPU shape discipline: routing is static-shape throughout — top-k gates,
-one-hot dispatch masks (B,S,E,C), no gather/scatter with dynamic sizes —
-so XLA tiles the expert FFNs onto the MXU like any dense matmul batch.
-Aux load-balancing loss (Switch Transformer, Fedus 2021) keeps routing
-uniform.
+The dispatch drops nothing and has no capacity: the ``T x K`` (position,
+expert) pairs are sorted by expert, the rows gathered in that order, and
+the three matmuls run as grouped matmuls over the sorted rows
+(``jax.lax.ragged_dot``, which on a TPU is XLA's own Mosaic kernel and
+reads each expert's weights once). Every shape is static, the sort is over
+a fixed ``T x K``, so a (batch, length) shape compiles once whatever the
+routing.
+
+The layers are scanned, and a custom call cannot read its operand through
+the scan's slice as a dense matmul does: XLA copies the layer's three
+``[E, hidden, width]`` slices out of the stacked ``[L, E, ...]`` arrays
+first (0.8 GB a layer at OLMoE's widths, 39 ms a serving step on a v5e).
+So the forward pass multiplies by the whole stack, seen as ``L x E``
+groups of which only this layer's hold rows (``_in_place``): an empty
+group costs the kernel nothing, and nothing is copied. The backward pass
+works on the layer's slice, so that the weights' gradient is the slice's
+and the scan stacks it as it stacks every other leaf's. Where the
+parameters are kept in another type than the activations' the slice is
+cast on its way in, which is that copy, and the matmul takes the slice.
+The stack and the layer's index in it travel with the layer's leaves
+(``in_stack``), so the block's signature is the dense model's.
 """
 
 from __future__ import annotations
 
-import dataclasses
-from typing import Any, Dict, Optional, Tuple
+from functools import partial
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.llama import _rms_norm, _rope
-from ray_tpu.ops.attention import attention
-from ray_tpu.parallel.sharding import constrain
+EXPERT_LOGICAL_AXES = {
+    "router": ("embed", "expert"),
+    "we_gate": ("expert", "embed", "mlp"),
+    "we_up": ("expert", "embed", "mlp"),
+    "we_down": ("expert", "mlp", "embed"),
+}
+EXPERT_STACKS = ("we_gate", "we_up", "we_down")
+# keys `in_stack` adds to a layer's leaves: not leaves themselves
+_WHERE, _MASK = "_experts_in", "_router_mask"
 
 
-@dataclasses.dataclass(frozen=True)
-class MoEConfig:
-    vocab_size: int = 32000
-    hidden: int = 512
-    mlp_hidden: int = 1024
-    num_layers: int = 4
-    num_heads: int = 8
-    num_kv_heads: int = 8
-    num_experts: int = 8
-    experts_per_token: int = 2  # top-k
-    capacity_factor: float = 1.25
-    rope_theta: float = 10000.0
-    rms_eps: float = 1e-5
-    aux_loss_coeff: float = 0.01
-    dtype: Any = jnp.bfloat16
-    param_dtype: Any = jnp.float32
-
-    @property
-    def head_dim(self) -> int:
-        return self.hidden // self.num_heads
-
-    @staticmethod
-    def tiny(vocab_size: int = 256) -> "MoEConfig":
-        return MoEConfig(vocab_size=vocab_size, hidden=64, mlp_hidden=128,
-                         num_layers=2, num_heads=4, num_kv_heads=4,
-                         num_experts=4)
-
-    @staticmethod
-    def mixtral_8x7b_proxy() -> "MoEConfig":
-        """Mixtral-8x7B-shaped config (for flops math; full size needs a
-        pod slice)."""
-        return MoEConfig(vocab_size=32000, hidden=4096, mlp_hidden=14336,
-                         num_layers=32, num_heads=32, num_kv_heads=8,
-                         num_experts=8, experts_per_token=2)
+def in_stack(lp: Dict[str, jax.Array], layers: Dict[str, jax.Array], layer,
+             mask: Optional[jax.Array] = None) -> Dict[str, jax.Array]:
+    """The layer's leaves ``lp`` (``layers[name][layer]``) with where its
+    experts lie: the three ``EXPERT_STACKS`` over all layers and the
+    layer's index in them, for ``expert_ffn`` to read them in place; and
+    ``mask [B, S]``, the positions the router's books count."""
+    return dict(lp, **{_WHERE: ({n: layers[n] for n in EXPERT_STACKS}, layer),
+                       _MASK: mask})
 
 
-def moe_logical_axes(cfg: MoEConfig) -> Dict[str, Any]:
-    layer = {
-        "wq": ("embed", "heads", "head_dim"),
-        "wk": ("embed", "kv_heads", "head_dim"),
-        "wv": ("embed", "kv_heads", "head_dim"),
-        "wo": ("heads", "head_dim", "embed"),
-        "router": ("embed", "expert"),
-        # expert FFN stacks: leading 'expert' dim shards over the EP axis
-        "we_gate": ("expert", "embed", "mlp"),
-        "we_up": ("expert", "embed", "mlp"),
-        "we_down": ("expert", "mlp", "embed"),
-        "attn_norm": ("norm",),
-        "mlp_norm": ("norm",),
-    }
-    layers = {k: (None,) + v for k, v in layer.items()}
-    return {
-        "embed": ("vocab", "embed"),
-        "layers": layers,
-        "final_norm": ("norm",),
-        "lm_head": ("embed", "vocab"),
-    }
-
-
-def init_moe(cfg: MoEConfig, key: jax.Array) -> Dict[str, Any]:
-    h, m, E = cfg.hidden, cfg.mlp_hidden, cfg.num_experts
-    nh, nkv, hd, L = (cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
-                      cfg.num_layers)
-    ks = jax.random.split(key, 12)
+def init_experts(cfg, key: jax.Array) -> Dict[str, jax.Array]:
+    """The router and the three expert stacks, stacked over layers
+    (truncated normal, fan-in scaled, as ``init_llama`` draws the dense
+    leaves). One layer is drawn at a time: at OLMoE's widths a stack is
+    ``[16, 64, 2048, 1024]``, 8.6 GB in float32 on its way to bf16, and a
+    layer of it is 0.5 GB."""
+    h, m, E, L = cfg.hidden, cfg.mlp_hidden, cfg.num_experts, cfg.num_layers
     pd = cfg.param_dtype
 
-    def tn(k, shape, fan_in):
-        return (jax.random.truncated_normal(k, -2, 2, shape, jnp.float32)
+    def stack(k, shape, fan_in):
+        def one(layer_key):
+            return (jax.random.truncated_normal(
+                layer_key, -2, 2, shape, jnp.float32)
                 * fan_in ** -0.5).astype(pd)
+        return jax.lax.map(one, jax.random.split(k, L))
 
-    layers = {
-        "wq": tn(ks[0], (L, h, nh, hd), h),
-        "wk": tn(ks[1], (L, h, nkv, hd), h),
-        "wv": tn(ks[2], (L, h, nkv, hd), h),
-        "wo": tn(ks[3], (L, nh, hd, h), nh * hd),
-        "router": tn(ks[4], (L, h, E), h),
-        "we_gate": tn(ks[5], (L, E, h, m), h),
-        "we_up": tn(ks[6], (L, E, h, m), h),
-        "we_down": tn(ks[7], (L, E, m, h), m),
-        "attn_norm": jnp.ones((L, h), pd),
-        "mlp_norm": jnp.ones((L, h), pd),
-    }
+    ks = jax.random.split(key, 4)
     return {
-        "embed": tn(ks[8], (cfg.vocab_size, h), h),
-        "layers": layers,
-        "final_norm": jnp.ones((h,), pd),
-        "lm_head": tn(ks[9], (h, cfg.vocab_size), h),
+        "router": stack(ks[0], (h, E), h),
+        "we_gate": stack(ks[1], (E, h, m), h),
+        "we_up": stack(ks[2], (E, h, m), h),
+        "we_down": stack(ks[3], (E, m, h), m),
     }
 
 
-def _moe_ffn(cfg: MoEConfig, x: jax.Array, lp: Dict[str, jax.Array]
-             ) -> Tuple[jax.Array, jax.Array]:
-    """Sparse expert FFN. x: [B,S,H] -> ([B,S,H], aux_loss)."""
+@partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _in_place(rows, w, sizes, stack, layer, out_dtype):
+    """``ragged_dot(rows, w, sizes)`` for ``w = stack[layer]``, read from
+    the stack where it lies: the stack is ``L x E`` groups and ``sizes``
+    sits at this layer's ``E`` of them."""
+    L, E = stack.shape[:2]
+    flat = jax.lax.dynamic_update_slice(
+        jnp.zeros((L * E,), sizes.dtype), sizes, (layer * E,))
+    return jax.lax.ragged_dot(rows, stack.reshape((L * E,) + stack.shape[2:]),
+                              flat, preferred_element_type=out_dtype)
+
+
+def _in_place_fwd(rows, w, sizes, stack, layer, out_dtype):
+    return _in_place(rows, w, sizes, stack, layer, out_dtype), (rows, w, sizes)
+
+
+def _in_place_bwd(out_dtype, res, g):
+    rows, w, sizes = res
+    _, vjp = jax.vjp(lambda r, ww: jax.lax.ragged_dot(
+        r, ww, sizes, preferred_element_type=out_dtype), rows, w)
+    return vjp(g) + (None, None, None)
+
+
+_in_place.defvjp(_in_place_fwd, _in_place_bwd)
+
+
+def expert_ffn(cfg, h: jax.Array, lp: Dict[str, jax.Array]
+               ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """``h [B, S, H]`` (after the block's second norm) -> the routed
+    feed-forward's output ``[B, S, H]`` and the router's books of this
+    layer: ``pairs [E]`` (how many (position, expert) pairs each expert
+    took), ``prob [E]`` (the router's probability summed over positions)
+    and ``positions`` (how many were counted), all float32 and all over the
+    positions where ``in_stack``'s mask is true (every position without
+    one). Padded positions are computed like any other; the mask only
+    keeps them out of the books. ``lp`` is the layer's leaves, as they are
+    or from ``in_stack``."""
     dt = cfg.dtype
-    B, S, H = x.shape
+    where, mask = lp.get(_WHERE), lp.get(_MASK)
+    B, S, H = h.shape
     E, K = cfg.num_experts, cfg.experts_per_token
-    C = max(1, int(cfg.capacity_factor * S * K / E))  # per-expert capacity
+    T = B * S
+    x = h.reshape(T, H)
+    with jax.named_scope("moe_router"):
+        # logits, softmax and the chosen weights in float32; the operands
+        # are the activations and the router as every other matmul has them
+        logits = jnp.einsum("th,he->te", x, lp["router"].astype(dt),
+                            preferred_element_type=jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, chosen = jax.lax.top_k(probs, K)            # [T, K]
+        if cfg.norm_topk_prob:
+            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    with jax.named_scope("moe_dispatch"):
+        flat = chosen.reshape(T * K)
+        order = jnp.argsort(flat)                 # stable: pairs by expert
+        onehot = flat[:, None] == jnp.arange(E, dtype=flat.dtype)[None, :]
+        sizes = jnp.sum(onehot, axis=0, dtype=jnp.int32)     # [E]
+        rows = jnp.take(x, order // K, axis=0)               # [T*K, H]
+    with jax.named_scope("moe_experts"):
+        def matmul(x, name, out_dtype):
+            if where is None or lp[name].dtype != dt:
+                # the layer's slice is cast on its way in, which is the
+                # copy: multiply by it (float32 master weights in training)
+                return jax.lax.ragged_dot(x, lp[name].astype(dt), sizes,
+                                          preferred_element_type=out_dtype)
+            stacks, layer = where
+            return _in_place(x, lp[name], sizes, stacks[name], layer,
+                             out_dtype)
 
-    # ---- routing (fp32 for numerics)
-    logits = jnp.einsum("bsh,he->bse", x.astype(jnp.float32),
-                        lp["router"].astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)  # [B,S,E]
-    gate_vals, expert_idx = jax.lax.top_k(probs, K)  # [B,S,K]
-    gate_vals = gate_vals / jnp.maximum(
-        gate_vals.sum(-1, keepdims=True), 1e-9)
-
-    # ---- capacity-bounded dispatch masks, static shapes only
-    # position of each (token, k) in its expert's buffer
-    onehot = jax.nn.one_hot(expert_idx, E, dtype=jnp.int32)  # [B,S,K,E]
-    flat = onehot.reshape(B, S * K, E)
-    pos_in_expert = (jnp.cumsum(flat, axis=1) - flat).reshape(B, S, K, E)
-    keep = (pos_in_expert < C) & (onehot > 0)  # overflow tokens drop
-    # dispatch [B,S,E,C]: token -> (expert, slot)
-    slot_oh = jax.nn.one_hot(pos_in_expert, C, dtype=x.dtype)  # [B,S,K,E,C]
-    keep_f = keep.astype(x.dtype)  # onehot is folded into `keep` already
-    dispatch = jnp.einsum("bske,bskec->bsec", keep_f, slot_oh)
-    combine = jnp.einsum("bsk,bske,bskec->bsec",
-                         gate_vals.astype(x.dtype), keep_f, slot_oh)
-
-    # ---- expert compute; EP shards the leading E dim -> all_to_all
-    expert_in = jnp.einsum("bsec,bsh->ebch", dispatch, x)  # [E,B,C,H]
-    expert_in = constrain(expert_in, ("expert", "batch", None, "embed"))
-    gate = jnp.einsum("ebch,ehm->ebcm", expert_in, lp["we_gate"].astype(dt))
-    up = jnp.einsum("ebch,ehm->ebcm", expert_in, lp["we_up"].astype(dt))
-    act = jax.nn.silu(gate) * up
-    out = jnp.einsum("ebcm,emh->ebch", act, lp["we_down"].astype(dt))
-    out = constrain(out, ("expert", "batch", None, "embed"))
-    y = jnp.einsum("ebch,bsec->bsh", out, combine)
-
-    # ---- Switch-style load-balancing aux loss
-    me = probs.mean(axis=(0, 1))                        # router prob mass
-    ce = (onehot.sum(2) > 0).astype(jnp.float32).mean(axis=(0, 1))
-    aux = E * jnp.sum(me * ce)
-    return y, aux
-
-
-def _moe_layer(cfg: MoEConfig, x: jax.Array, lp: Dict[str, jax.Array],
-               positions: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    dt = cfg.dtype
-    h = _rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-    q = jnp.einsum("bsh,hnd->bsnd", h, lp["wq"].astype(dt))
-    k = jnp.einsum("bsh,hnd->bsnd", h, lp["wk"].astype(dt))
-    v = jnp.einsum("bsh,hnd->bsnd", h, lp["wv"].astype(dt))
-    q = _rope(q, positions, cfg.rope_theta)
-    k = _rope(k, positions, cfg.rope_theta)
-    attn = attention(q, k, v, impl="reference", causal=True)
-    x = x + jnp.einsum("bsnd,ndh->bsh", attn, lp["wo"].astype(dt))
-    h = _rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-    y, aux = _moe_ffn(cfg, h, lp)
-    return x + y, aux
+        gate, up = matmul(rows, "we_gate", dt), matmul(rows, "we_up", dt)
+        out = matmul(jax.nn.silu(gate) * up, "we_down", jnp.float32)
+    with jax.named_scope("moe_combine"):
+        # back to the pairs' own order, then the weighted sum of each
+        # position's K expert outputs, in float32
+        out = jnp.take(out, jnp.argsort(order), axis=0).reshape(T, K, H)
+        y = jnp.einsum("tkh,tk->th", out, weights).astype(dt)
+    live = (jnp.ones((T,), jnp.float32) if mask is None
+            else mask.reshape(T).astype(jnp.float32))
+    books = {"pairs": jnp.einsum("t,te->e", jnp.repeat(live, K),
+                                 onehot.astype(jnp.float32)),
+             "prob": jnp.einsum("t,te->e", live, probs),
+             "positions": jnp.sum(live)}
+    return y.reshape(B, S, H), books
 
 
-def moe_forward(params: Dict[str, Any], tokens: jax.Array,
-                cfg: MoEConfig) -> Tuple[jax.Array, jax.Array]:
-    """tokens [B,S] -> (logits [B,S,V], total_aux_loss)."""
-    dt = cfg.dtype
-    x = params["embed"].astype(dt)[tokens]
-    x = constrain(x, ("batch", "seq", "embed"))
-    B, S = tokens.shape
-    positions = jnp.broadcast_to(jnp.arange(S), (B, S))
-
-    def scan_fn(carry, lp):
-        x, aux = carry
-        x, layer_aux = _moe_layer(cfg, x, lp, positions)
-        return (x, aux + layer_aux), None
-
-    (x, aux), _ = jax.lax.scan(scan_fn, (x, jnp.float32(0.0)),
-                               params["layers"])
-    x = _rms_norm(x, params["final_norm"], cfg.rms_eps)
-    logits = jnp.einsum("bsh,hv->bsv", x, params["lm_head"].astype(dt))
-    return logits.astype(jnp.float32), aux
+def load_balancing_loss(books: Dict[str, jax.Array], cfg) -> jax.Array:
+    """The Switch load-balancing term over all layers' routers together,
+    as HuggingFace's ``load_balancing_loss_func`` computes it: ``E`` times
+    the sum over experts of (the share of all (layer, position) pairs'
+    choices that went to the expert) x (the router's mean probability for
+    it). ``books`` is ``expert_ffn``'s, stacked over layers. The gradient
+    flows through the probabilities alone."""
+    n = jnp.sum(books["positions"])
+    share = jnp.sum(books["pairs"], axis=0) / n
+    prob = jnp.sum(books["prob"], axis=0) / n
+    return cfg.num_experts * jnp.sum(jax.lax.stop_gradient(share) * prob)
 
 
-def moe_loss(params: Dict[str, Any], batch: Dict[str, jax.Array],
-             cfg: MoEConfig) -> jax.Array:
-    logits, aux = moe_forward(params, batch["inputs"], cfg)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(
-        logp, batch["targets"][..., None], axis=-1)[..., 0]
-    return nll.mean() + cfg.aux_loss_coeff * aux / cfg.num_layers
+def router_load(books: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
+    """For each layer, the pairs the fullest expert took and the pairs the
+    mean expert took (``[L]`` float32 each): what a serving step brings to
+    the host beside its token ids."""
+    return {"fullest": jnp.max(books["pairs"], axis=-1),
+            "mean": jnp.mean(books["pairs"], axis=-1)}

@@ -13,11 +13,14 @@ shape pair — the engine's ``allowed_batch_sizes`` snapping plus a seq-pad
 bucket keep the compile-cache menu finite. It ends in the next token of
 each row (``models.llama.llama_next_token``: the head meets only each
 row's last position, the argmax runs on the device), so a step brings
-``bucket`` int32s to the host and the ``[bucket, S, vocab]`` logits are
-never made. ``LlamaGenerator._fwd`` is the same program followed by the
-head over every position, for callers that want the logits themselves
-(a benchmark's warm-up and its check against a reference): it compiles
-what ``_step`` runs, so warming a shape through it warms the step.
+``bucket`` int32s to the host (and, from a model with experts, two
+float32 a layer of its routers' load) and the ``[bucket, S, vocab]``
+logits are never made. ``LlamaGenerator._fwd`` is the same program
+followed by the head over every position, for callers that want the
+logits themselves: it compiles what ``_step`` runs, so warming a shape
+through it warms the step. ``warm_step_programs``,
+``logits_after_prompt`` and ``compiled_step_programs`` are what a
+benchmark asks of a served class.
 Decoding still recomputes the full prefix each step (a kv-cache
 paged-attention variant slots into ``_step`` without touching the engine
 contract).
@@ -54,8 +57,8 @@ class _FullLogits:
     def __call__(self, params, tokens, lora):
         import numpy as np
 
-        _, hidden = self._step_fn(params, tokens, lora,
-                                  np.zeros(tokens.shape[0], np.int32))
+        _, hidden, _ = self._step_fn(
+            params, tokens, lora, np.zeros(tokens.shape[0], np.int32), None)
         return self._head_fn(params, hidden)
 
     def _cache_size(self) -> int:
@@ -66,6 +69,13 @@ class _FullLogits:
 class LlamaGenerator:
     """Deployment callable: streaming greedy generation with multiplexed
     LoRA adapters, continuously batched."""
+
+    # what `_step` counts (`engine_stats`): bytes of device results brought
+    # to the host, positions computed (rows x padded length) and live among
+    # them, and over live positions the (position, expert) pairs of the
+    # fullest and of the mean expert, summed over steps and layers
+    STEP_COUNTERS = ("host_bytes", "positions_computed", "positions_live",
+                     "expert_pairs_fullest", "expert_pairs_mean")
 
     def __init__(self, config: str = "tiny", lora_rank: int = 4,
                  max_batch_size: int = 4,
@@ -95,11 +105,11 @@ class LlamaGenerator:
 
         cfg, lcfg = self._cfg, self._lcfg
 
-        def step_fn(params, tokens, lora, last):
+        def step_fn(params, tokens, lora, last, live):
             from ray_tpu.models.llama import llama_next_token
 
             return llama_next_token(params, tokens, last, cfg,
-                                    lora=lora, lora_cfg=lcfg)
+                                    lora=lora, lora_cfg=lcfg, live=live)
 
         def head_fn(params, hidden):
             from ray_tpu.models.llama import llama_head
@@ -112,9 +122,9 @@ class LlamaGenerator:
         # constants (2.67 GB at 7B width and 2 layers) and the replica
         # sits in the lowering long enough to miss its health probe
         self._step_fn = jax.jit(step_fn)
-        self._fwd = _FullLogits(self._step_fn, jax.jit(head_fn))
-        # bytes of device results `_step` has brought to the host
-        self._host_bytes = 0
+        self._head_fn = jax.jit(head_fn)
+        self._fwd = _FullLogits(self._step_fn, self._head_fn)
+        self._counts = dict.fromkeys(self.STEP_COUNTERS, 0)
         self.engine = ContinuousBatchingEngine(
             self._step, prefill_fn=self._prefill,
             max_batch_size=max_batch_size,
@@ -171,33 +181,61 @@ class LlamaGenerator:
                            self.max_new_tokens),
         }
 
+    def _padded_len(self, states: List[Optional[Dict]]) -> int:
+        """The length a step pads these rows to: the longest row's, up to
+        the next multiple of ``seq_bucket``."""
+        max_len = max(len(s["tokens"]) for s in states if s is not None)
+        pad_len = -(-max_len // self.seq_bucket) * self.seq_bucket
+        return min(pad_len, self._cfg.max_seq_len)
+
+    def _run_step(self, tokens, last, mask, lora=None):
+        """The step's one jitted program on numpy ``tokens [B, S]``, ``last
+        [B]`` and ``mask [B, S]`` (the rows' own tokens: only a model with
+        experts is told, for its routers' load) -> (ids, hidden, load)."""
+        import jax.numpy as jnp
+
+        return self._step_fn(self._params, jnp.asarray(tokens), lora, last,
+                             mask if self._cfg.num_experts else None)
+
     def _step(self, model_id: str, states: List[Optional[Dict]]) -> List:
         """One decode iteration for one adapter group: pad the live rows
         to (bucket, seq_bucket-multiple) and run the step's one jitted
         program, which re-runs every row's whole prefix and returns the
         greedy next token of each row; ``bucket`` int32s come to the
-        host. Nothing else runs on the device here (an op-by-op ``jnp``
+        host, and the routers' load with them where the model has
+        experts. Nothing else runs on the device here (an op-by-op ``jnp``
         call would compile a program of its own per shape), and ``last``
         goes in as numpy, as ``_fwd`` passes it."""
-        import jax.numpy as jnp
         import numpy as np
 
         live = [(i, s) for i, s in enumerate(states) if s is not None]
         bucket = len(states)
-        max_len = max(len(s["tokens"]) for _, s in live)
-        pad_len = -(-max_len // self.seq_bucket) * self.seq_bucket
-        pad_len = min(pad_len, self._cfg.max_seq_len)
+        pad_len = self._padded_len(states)
         tokens = np.zeros((bucket, pad_len), np.int32)
         # index of each row's newest token; 0 for a padded row
         last = np.zeros(bucket, np.int32)
+        # a row's own tokens, as against its padding and the padded rows
+        # (which all carry token 0 and route alike): what a router's load
+        # is counted over
+        mask = np.zeros((bucket, pad_len), bool)
         for row, (_, s) in enumerate(live):
             ts = s["tokens"][-pad_len:]
             tokens[row, :len(ts)] = ts
             last[row] = len(ts) - 1
-        ids, _ = self._step_fn(self._params, jnp.asarray(tokens),
-                               self._adapter(model_id), last)
+            mask[row, :len(ts)] = True
+        ids, _, load = self._run_step(tokens, last, mask,
+                                      self._adapter(model_id))
         ids = np.asarray(ids)
-        self._host_bytes += ids.nbytes
+        counts = self._counts
+        counts["host_bytes"] += ids.nbytes
+        counts["positions_computed"] += bucket * pad_len
+        counts["positions_live"] += int(mask.sum())
+        if load is not None:
+            fullest, mean = np.asarray(load["fullest"]), np.asarray(
+                load["mean"])
+            counts["host_bytes"] += fullest.nbytes + mean.nbytes
+            counts["expert_pairs_fullest"] += float(fullest.sum())
+            counts["expert_pairs_mean"] += float(mean.sum())
         results: List[Optional[tuple]] = [None] * len(states)
         for row, (idx, s) in enumerate(live):
             nxt = int(ids[row])
@@ -216,10 +254,48 @@ class LlamaGenerator:
         model_id = get_multiplexed_model_id() or str(p.get("adapter", ""))
         yield from self.engine.submit(p, model_id)
 
-    def engine_stats(self) -> Dict[str, int]:
-        """The engine's counters, and ``host_bytes``: the bytes of device
-        results ``_step`` has brought to the host (4 a row a step)."""
-        return {**self.engine.stats(), "host_bytes": self._host_bytes}
+    def engine_stats(self) -> Dict[str, Any]:
+        """The engine's counters, and ``_step``'s own: ``host_bytes`` (the
+        bytes of device results brought to the host: 4 a row a step, and
+        8 a layer from a model with experts), ``positions_computed`` (rows
+        x padded length) and ``positions_live`` (the rows' own tokens
+        among them), ``expert_pairs_fullest`` and ``expert_pairs_mean``
+        (over live positions, the (position, expert) pairs of the fullest
+        and of the mean expert, summed over steps and layers; 0 for a
+        model without experts)."""
+        return {**self.engine.stats(), **self._counts}
+
+    # ------------------------------------------- what a benchmark asks for
+    def warm_step_programs(self, seq_len: int) -> None:
+        """Compile (or find in the cache) and run every device program a
+        step runs at one padded length and the engine's batch: the one
+        program of ``_step``, its results brought to the host."""
+        import jax
+        import numpy as np
+
+        rows = self.engine.max_batch_size
+        ids, _, load = self._run_step(np.zeros((rows, seq_len), np.int32),
+                                      np.zeros(rows, np.int32),
+                                      np.zeros((rows, seq_len), bool))
+        jax.tree.map(np.asarray, (ids, load))
+
+    def logits_after_prompt(self, prompt: List[int]):
+        """``[vocab]`` float32 after the prompt's last token, from the
+        weights this replica serves, through the step's program at the
+        engine's batch; the head meets that one position."""
+        import numpy as np
+
+        rows, n = self.engine.max_batch_size, len(prompt)
+        tokens = np.zeros((rows, n), np.int32)
+        tokens[0] = prompt
+        mask = np.zeros((rows, n), bool)
+        mask[0] = True
+        _, hidden, _ = self._run_step(tokens, np.zeros(rows, np.int32), mask)
+        return np.asarray(self._head_fn(self._params, hidden[0, n - 1]))
+
+    def compiled_step_programs(self) -> int:
+        """Compiled (shape, adapter structure) variants of the step."""
+        return self._step_fn._cache_size()
 
     def device_info(self) -> Dict[str, Any]:
         """Where this replica's model lives, as jax reports it."""
