@@ -18,12 +18,27 @@ kv head = q head // n_rep; the dk/dv kernel's grid walks each kv head's
 whole query group (an extra sequential grid dim), accumulating the group's
 contributions in VMEM scratch — so GQA models (Llama-3-class) train under
 flash instead of falling back to blockwise attention.
+
+Tiles. ``flash_tiles`` cuts the two lengths into blocks for the forward and
+the backward alike, from the lengths and ``tile_vmem_bytes`` alone; no
+argument, field or variable chooses a tile. On a v5e a grid step costs more
+in what surrounds its matmuls (the rescale of the running max, sum and
+accumulator at every key block, and the step's own overhead) than in the
+matmuls, so few large blocks win for as long as they fit VMEM: the forward
+at 1152, 8 x 16 heads, takes 5.05 ms at 128 x 128, 1.62 at 384 x 384 and
+0.61 as one block. The rule, for each length: one that 512 divides is cut
+into blocks of 512, the tile training runs at; any other takes the largest
+of its divisors (in multiples of 128) whose square tile fits VMEM, which in
+the forward is the whole length up to 1152. A key length left at 128 by
+that (a prime number of 128s, too long for one block) takes the widest key
+block that fits beside its query block: wide key blocks are what saves the
+rescales. A length that 128 does not divide is refused.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import List, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -45,6 +60,64 @@ def _interpret() -> bool:
     raise RuntimeError(
         f"flash attention runs on the tpu platform (compiled) or the cpu "
         f"platform (interpreted), not {platform!r}")
+
+
+_LANES = 128
+_GRID_BLOCK = 512
+# Scoped VMEM a v5e kernel gets by default; nothing here asks for more.
+VMEM_LIMIT_BYTES = 16 * 2 ** 20
+
+
+def tile_vmem_bytes(block_q: int, block_k: int, *, head_dim: int = 128,
+                    backward: bool = False) -> int:
+    """Upper reckoning of the VMEM one grid step holds at a tile, every
+    element taken at 4 bytes: each block of an operand or a result in its
+    two pipeline buffers, the float32 casts and the scratch once, and the
+    ``[block_q, block_k]`` float32 values that outlive a strip (the
+    forward's ``s``; the backward's ``p`` and ``ds``, of its two kernels
+    the larger on each side). Mosaic reports less at every tile tried
+    (``tests/test_flash_tiles_v5e.py`` compiles for a described v5e)."""
+    d, lanes = 4 * head_dim, 4 * _LANES
+    if backward:
+        # a query row: q, do and dq twice, the casts of q and do, dq's
+        # scratch (9 d); lse and delta twice (4 lanes). A key row: k, v,
+        # dk and dv twice, the casts of k and v, dk's and dv's scratch
+        q_row, k_row, tiles = 9 * d + 4 * lanes, 12 * d, 2
+    else:
+        # a query row: q and o twice, q's cast, acc and its rescaled copy
+        # (7 d); lse twice, m and l (4 lanes). A key row: k and v twice,
+        # their casts
+        q_row, k_row, tiles = 7 * d + 4 * lanes, 6 * d, 1
+    return (4 * tiles * block_q * block_k + block_q * q_row
+            + block_k * k_row)
+
+
+def _divisors(n: int) -> List[int]:
+    """The multiples of 128 that divide ``n``, ascending."""
+    return [b for b in range(_LANES, n + 1, _LANES) if n % b == 0]
+
+
+def flash_tiles(sq: int, skv: int, *, head_dim: int = 128,
+                backward: bool = False) -> Tuple[int, int]:
+    """``(block_q, block_k)`` for query and key lengths: the module
+    docstring's rule. Pure: the lengths, the head's width and which pass
+    holds the tile are all it reads."""
+    if sq % _LANES or skv % _LANES:
+        raise ValueError(f"seq lens ({sq},{skv}) must divide by 128")
+
+    def fits(bq: int, bk: int) -> bool:
+        return tile_vmem_bytes(bq, bk, head_dim=head_dim,
+                               backward=backward) <= VMEM_LIMIT_BYTES
+
+    def block(n: int) -> int:
+        if n % _GRID_BLOCK == 0:
+            return _GRID_BLOCK
+        return max(b for b in _divisors(n) if fits(b, b))
+
+    block_q, block_k = block(sq), block(skv)
+    if block_k == _LANES < skv:
+        block_k = max(b for b in _divisors(skv) if fits(block_q, b))
+    return block_q, block_k
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
@@ -102,19 +175,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
 
 
 def _flash_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
-               causal: bool, block_q: int, block_k: int
-               ) -> Tuple[jax.Array, jax.Array]:
+               causal: bool) -> Tuple[jax.Array, jax.Array]:
     """q [B,H,S,D], k/v [B,KVH,S,D] → (o [B,H,S,D], lse [B,H,S,128])."""
     B, H, Sq, D = q.shape
     KVH, Skv = k.shape[1], k.shape[2]
     n_rep = H // KVH
     scale = D ** -0.5
-    block_q = next(b for b in (block_q, 512, 256, 128)
-                   if Sq % b == 0 or b == 128)
-    block_k = next(b for b in (block_k, 512, 256, 128)
-                   if Skv % b == 0 or b == 128)
-    if Sq % block_q or Skv % block_k:
-        raise ValueError(f"seq lens ({Sq},{Skv}) must divide by 128")
+    block_q, block_k = flash_tiles(Sq, Skv, head_dim=D)
     grid = (B, H, Sq // block_q, Skv // block_k)
 
     kernel = functools.partial(
@@ -262,8 +329,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _flash_bwd(q, k, v, o, lse, do, *, causal: bool, block_q: int,
-               block_k: int):
+def _flash_bwd(q, k, v, o, lse, do, *, causal: bool):
     """q/o/do [B,H,Sq,D], k/v [B,KVH,Skv,D] (lse [B,H,Sq,128]); returns
     (dq [B,H,Sq,D], dk/dv [B,KVH,Skv,D]). GQA (KVH < H) is handled in the
     index maps: dq reads kv head h//n_rep; dk/dv accumulate across the
@@ -272,10 +338,7 @@ def _flash_bwd(q, k, v, o, lse, do, *, causal: bool, block_q: int,
     KVH, Skv = k.shape[1], k.shape[2]
     n_rep = H // KVH
     scale = D ** -0.5
-    block_q = next(b for b in (block_q, 512, 256, 128)
-                   if Sq % b == 0 or b == 128)
-    block_k = next(b for b in (block_k, 512, 256, 128)
-                   if Skv % b == 0 or b == 128)
+    block_q, block_k = flash_tiles(Sq, Skv, head_dim=D, backward=True)
 
     # delta_i = rowsum(dO_i * O_i) — cheap elementwise, stays in XLA;
     # broadcast across 128 lanes to match the lse layout
@@ -344,7 +407,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
-    o, _ = _flash_fwd(qt, kt, vt, causal=causal, block_q=512, block_k=512)
+    o, _ = _flash_fwd(qt, kt, vt, causal=causal)
     return jnp.swapaxes(o, 1, 2)
 
 
@@ -352,15 +415,14 @@ def _fa_fwd(q, k, v, causal):
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
-    o, lse = _flash_fwd(qt, kt, vt, causal=causal, block_q=512, block_k=512)
+    o, lse = _flash_fwd(qt, kt, vt, causal=causal)
     return jnp.swapaxes(o, 1, 2), (qt, kt, vt, o, lse)
 
 
 def _fa_bwd(causal, res, g):
     qt, kt, vt, o, lse = res
     do = jnp.swapaxes(g, 1, 2)
-    dq, dk, dv = _flash_bwd(qt, kt, vt, o, lse, do, causal=causal,
-                            block_q=512, block_k=512)
+    dq, dk, dv = _flash_bwd(qt, kt, vt, o, lse, do, causal=causal)
     return (jnp.swapaxes(dq, 1, 2), jnp.swapaxes(dk, 1, 2),
             jnp.swapaxes(dv, 1, 2))
 

@@ -106,6 +106,11 @@ FAST_FILES = {
     # in FAST so tier-1 exercises the gate (its standalone failure used
     # to hide behind the `-m 'not slow'` deselection — ISSUE 11)
     "test_dryrun_gate.py",
+    # the flash kernels' tiles and their correctness in interpret mode
+    # (under a minute together), and the same tiles compiled for a
+    # described v5e: what both serving cells and training run on the chip
+    "test_ops.py",
+    "test_flash_tiles_v5e.py",
 }
 SLOW_TESTS: set = set()
 

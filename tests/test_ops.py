@@ -50,6 +50,100 @@ class TestFlashAttention:
         np.testing.assert_allclose(g, gref, atol=1e-4, rtol=1e-4)
 
 
+def _old_block(n):
+    """What the flash kernels tried before PR 29: 512, 256, then 128."""
+    return next(b for b in (512, 256, 128) if n % b == 0 or b == 128)
+
+
+class TestFlashTiles:
+    """``flash_tiles`` is a pure function of the lengths: no chip needed."""
+
+    @pytest.mark.parametrize("backward", [False, True])
+    @pytest.mark.parametrize("seq", range(128, 4097, 128))
+    def test_every_multiple_of_128(self, seq, backward):
+        from ray_tpu.ops.pallas.flash_attention import (
+            VMEM_LIMIT_BYTES, flash_tiles, tile_vmem_bytes)
+
+        bq, bk = flash_tiles(seq, seq, backward=backward)
+        assert seq % bq == 0 and seq % bk == 0
+        assert bq % 128 == 0 and bk % 128 == 0
+        assert (seq // bq) * (seq // bk) <= (seq // _old_block(seq)) ** 2
+        assert tile_vmem_bytes(bq, bk, backward=backward) <= VMEM_LIMIT_BYTES
+        if (bq, bk) == (128, 128):
+            # left at 128 x 128 only where nothing coarser is reckoned to fit
+            divisors = [b for b in range(128, seq + 1, 128) if seq % b == 0]
+            assert not [
+                (q, k) for q in divisors for k in divisors
+                if (q, k) != (128, 128) and tile_vmem_bytes(
+                    q, k, backward=backward) <= VMEM_LIMIT_BYTES]
+
+    @pytest.mark.parametrize("backward", [False, True])
+    @pytest.mark.parametrize("seq, tile", [
+        (128, (128, 128)), (256, (256, 256)), (512, (512, 512)),
+        (1024, (512, 512)), (2048, (512, 512)), (4096, (512, 512))])
+    def test_lengths_on_the_old_grid_keep_their_tiles(self, seq, tile,
+                                                      backward):
+        from ray_tpu.ops.pallas.flash_attention import flash_tiles
+
+        assert flash_tiles(seq, seq, backward=backward) == tile
+
+    @pytest.mark.parametrize("seq, tile", [
+        (384, (384, 384)), (640, (640, 640)), (768, (768, 768)),
+        (896, (896, 896)), (1152, (1152, 1152)), (1408, (128, 1408))])
+    def test_the_serving_buckets_run_as_one_block(self, seq, tile):
+        from ray_tpu.ops.pallas.flash_attention import flash_tiles
+
+        assert flash_tiles(seq, seq) == tile
+
+    def test_each_length_is_cut_by_itself(self):
+        from ray_tpu.ops.pallas.flash_attention import flash_tiles
+
+        assert flash_tiles(384, 1024) == (384, 512)
+        assert flash_tiles(2048, 640) == (512, 640)
+
+    @pytest.mark.parametrize("seq", [64, 192, 1000])
+    def test_a_length_128_does_not_divide_is_refused(self, seq):
+        from ray_tpu.ops.pallas.flash_attention import (
+            flash_attention, flash_tiles)
+
+        with pytest.raises(ValueError, match="divide by 128"):
+            flash_tiles(seq, seq)
+        q, k, v = _rand_qkv(jax.random.key(0), B=1, S=seq, H=2, KVH=1)
+        with pytest.raises(ValueError, match="divide by 128"):
+            flash_attention(q, k, v, True)
+
+
+class TestFlashOffTheOldGrid:
+    """Interpret mode, grouped heads (2 query heads on 1 key/value head),
+    D 128, B 1: the serving buckets the old rule left at block 128, and
+    1024 and 1408 for the paths with more than one block (the running
+    maximum and sum folded across key blocks, the causal skip)."""
+
+    @pytest.mark.parametrize("seq", [384, 640, 896, 1152, 1024, 1408])
+    def test_forward_matches_reference(self, seq):
+        from ray_tpu.ops.pallas.flash_attention import flash_attention
+
+        q, k, v = _rand_qkv(jax.random.key(seq), B=1, S=seq, H=2, KVH=1,
+                            D=128)
+        out = flash_attention(q, k, v, True)
+        ref = reference_attention(q, k, v, causal=True)
+        np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+
+    @pytest.mark.parametrize("seq", [384, 640, 1024])
+    def test_gradient_matches_reference(self, seq):
+        from ray_tpu.ops.pallas.flash_attention import flash_attention
+
+        q, k, v = _rand_qkv(jax.random.key(seq), B=1, S=seq, H=2, KVH=1,
+                            D=128)
+        g = jax.random.normal(jax.random.key(seq + 1), q.shape, q.dtype)
+        gr = jax.grad(lambda *a: (reference_attention(
+            *a, causal=True) * g).sum(), argnums=(0, 1, 2))(q, k, v)
+        gf = jax.grad(lambda *a: (flash_attention(*a, True) * g).sum(),
+                      argnums=(0, 1, 2))(q, k, v)
+        for a, b, n in zip(gr, gf, "qkv"):
+            assert float(jnp.abs(a - b).max()) < 1e-4, n
+
+
 class TestRingAttention:
     def test_matches_reference(self):
         from jax.sharding import Mesh, PartitionSpec as P
