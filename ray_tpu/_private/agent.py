@@ -574,7 +574,8 @@ class NodeAgent:
         pre-fork toward it NOW — by the time the entries clear resource
         admission, their workers are already booting through the
         admission queue (the 1-fork/tick pacing this replaces left
-        hit_ratio at 0.17 under a burst of 200, ACTORS_latest r10)."""
+        hit_ratio at 0.17 under a burst of 200, round 10's actor_scale
+        run on a CPU box)."""
         if n > 0:
             self._demand_events.append((time.monotonic(), n))
             # prune HERE, not just in the stats read (the only other
@@ -1436,8 +1437,9 @@ class NodeAgent:
         their (unix) agent connection; the agent coalesces a creation
         burst into ONE ActorReadyBatch head RPC (+ one WAL group commit
         head-side) per flush window. The worker is acked only after the
-        head acked — its retry/exit-on-persistent-failure contract (the
-        PROFILE_ACTORS zombie fix) is preserved end to end."""
+        head acked — its retry/exit-on-persistent-failure contract (a
+        worker the head never acked exits and is no zombie) is preserved
+        end to end."""
         fut = asyncio.get_running_loop().create_future()
         self._last_ready_report = time.monotonic()
         self._ready_queue.append((p, fut))
@@ -1831,7 +1833,7 @@ class NodeAgent:
             self._resources_dirty = True
         # Warm-pool lease (ISSUE 10): a pre-booted pristine worker skips
         # the whole fork + loop setup + handshake + store-attach boot
-        # (~0.1 core-s measured, PROFILE_ACTORS step 4) — actor creation
+        # (~0.1 core-s measured on a CPU box, round 5) — actor creation
         # pays only class unpickle + __init__. Cold fork is the fallback,
         # never a failure mode.
         wants_chip = bool(assigned.get(TPU))

@@ -3,9 +3,11 @@
 ``RAY_TPU_PROFILE_DIR=<dir>`` makes the head and agent profile their
 entire lifetime and dump ``<name>-<pid>.pstats`` on clean shutdown
 (SIGTERM). This is the instrument behind the multi-client loop analysis
-(PROFILE_MULTICLIENT.md): where do the head/agent asyncio loops spend
-time while 4 clients submit task batches (reference analog: the asio
-event-stats instrumentation, src/ray/common/asio + debug_state dumps).
+(git history, `c35bee1`: PROFILE_MULTICLIENT.md; load the dumps with
+``pstats.Stats`` and sort by own time): where do the head/agent asyncio
+loops spend time while 4 clients submit task batches (reference analog:
+the asio event-stats instrumentation, src/ray/common/asio + debug_state
+dumps).
 """
 
 from __future__ import annotations

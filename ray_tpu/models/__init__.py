@@ -18,11 +18,8 @@ from ray_tpu.models.llama import (
     llama_loss,
     llama_logical_axes,
 )
-from ray_tpu.models.mlp import (
-    MLPConfig, init_mlp, mlp_forward, mlp_loss, mlp_logical_axes)
 
 __all__ = [
     "LlamaConfig", "init_llama", "llama_forward", "llama_decode",
     "llama_loss", "llama_logical_axes",
-    "MLPConfig", "init_mlp", "mlp_forward", "mlp_loss", "mlp_logical_axes",
 ]

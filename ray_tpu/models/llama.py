@@ -77,10 +77,6 @@ class LlamaConfig:
     qk_norm: bool = False
 
     @staticmethod
-    def llama2_7b() -> "LlamaConfig":
-        return LlamaConfig()
-
-    @staticmethod
     def llama2_7b_smoke() -> "LlamaConfig":
         """Llama-2-7B at full width (hidden 4096, MLP 11008, 32 heads x
         128, vocabulary 32000, sequence 2048), cut to 2 layers: 667M
@@ -103,39 +99,13 @@ class LlamaConfig:
                            num_layers=1, num_heads=2, num_kv_heads=1,
                            head_dim=32, max_seq_len=128, remat=False)
 
-    def flops_per_token(self, seq_len: Optional[int] = None) -> float:
-        """Approximate fwd+bwd FLOPs/token: 6*N (with experts, the N a
-        position meets), plus the attention quadratic term 12*L*H*D*S when
-        ``seq_len`` is given."""
-        flops = 6.0 * self.num_params(active=True)
-        if seq_len is not None:
-            flops += (12.0 * self.num_layers * self.num_heads
-                      * self.head_dim * seq_len)
-        return flops
-
-    def flops_per_token_frozen(self, trainable_params: int,
-                               seq_len: Optional[int] = None) -> float:
-        """Frozen-base (LoRA) fwd+bwd FLOPs/token: the backward still
-        propagates activation grads through every frozen layer (2N) but
-        forms weight grads only for the adapters — 4N_base + 6N_adapters.
-        Attention's quadratic term keeps its full factor (dQ/dK/dV are
-        activation grads)."""
-        flops = 4.0 * self.num_params() + 6.0 * trainable_params
-        if seq_len is not None:
-            flops += (12.0 * self.num_layers * self.num_heads
-                      * self.head_dim * seq_len)
-        return flops
-
-    def num_params(self, active: bool = False) -> int:
-        """All parameters, or with ``active`` those one position meets
-        (``experts_per_token`` of the experts)."""
+    def num_params(self) -> int:
         h, m, v = self.hidden, self.mlp_hidden, self.vocab_size
         q, kv = self.num_heads * self.head_dim, self.num_kv_heads * self.head_dim
         attn = h * (q + 2 * kv) + q * h + ((q + kv) if self.qk_norm else 0)
         mlp = 3 * h * m
         if self.num_experts:
-            used = self.experts_per_token if active else self.num_experts
-            mlp = used * mlp + h * self.num_experts
+            mlp = self.num_experts * mlp + h * self.num_experts
         per_layer = attn + mlp + 2 * h
         return self.num_layers * per_layer + 2 * v * h + h
 

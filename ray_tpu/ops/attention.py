@@ -82,9 +82,8 @@ def attention(
     valid_kv_len: Optional[jax.Array] = None,
 ) -> jax.Array:
     """impl: auto (on the TPU platform the flash kernel, on the CPU platform
-    the reference), flash, blockwise (scan over KV blocks; memory-efficient
-    fwd AND bwd), reference. Ring attention is invoked explicitly via
-    ops.ring_attention by the seq-parallel layer, not through this
+    the reference), flash, reference. Ring attention is invoked explicitly
+    via ops.ring_attention by the seq-parallel layer, not through this
     dispatcher.
 
     ``auto`` on a TPU still takes the reference for what the kernel has no
@@ -110,20 +109,16 @@ def attention(
                     f"q{tuple(q.shape)} k{tuple(k.shape)} (cached decode, "
                     "or a shape off the flash kernel's 128 grid)",
                     stacklevel=2)
-    if impl in ("flash", "blockwise"):
+    if impl == "flash":
         if q_offset is not None or valid_kv_len is not None:
             raise NotImplementedError(
-                f"{impl} attention does not support q_offset/valid_kv_len; "
+                "flash attention does not support q_offset/valid_kv_len; "
                 "use impl='reference' for cached decode")
-        if impl == "flash":
-            return _flash_per_shard(q, k, v, causal)
-        # pure-JAX memory-efficient path (scan over KV blocks)
-        from ray_tpu.ops.blockwise_attention import blockwise_attention
-        return blockwise_attention(q, k, v, causal=causal)
+        return _flash_per_shard(q, k, v, causal)
     if impl != "reference":
         raise ValueError(
             f"unknown attention impl {impl!r}; expected "
-            "auto|flash|blockwise|reference "
+            "auto|flash|reference "
             "(ring attention is the model layer's 'ring_seq' path)")
     return reference_attention(q, k, v, causal=causal, q_offset=q_offset,
                                valid_kv_len=valid_kv_len)

@@ -17,7 +17,7 @@ GQA is handled in the index maps throughout: the forward and dq read
 kv head = q head // n_rep; the dk/dv kernel's grid walks each kv head's
 whole query group (an extra sequential grid dim), accumulating the group's
 contributions in VMEM scratch — so GQA models (Llama-3-class) train under
-flash instead of falling back to blockwise attention.
+flash instead of falling back to reference attention.
 
 Tiles. ``flash_tiles`` cuts the two lengths into blocks for the forward and
 the backward alike, from the lengths and ``tile_vmem_bytes`` alone; no

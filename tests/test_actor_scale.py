@@ -283,14 +283,18 @@ def _pool_stats():
                     timeout=15)
 
 
-def _wait_warm(n, timeout=60):
+def _wait_pool(ready, what, timeout=60):
     deadline = time.monotonic() + timeout
     while time.monotonic() < deadline:
         st = _pool_stats()
-        if st["warm"] >= n:
+        if ready(st):
             return st
         time.sleep(0.2)
-    raise AssertionError(f"warm pool never reached {n}: {_pool_stats()}")
+    raise AssertionError(f"warm pool never {what}: {_pool_stats()}")
+
+
+def _wait_warm(n, timeout=60):
+    return _wait_pool(lambda st: st["warm"] >= n, f"reached {n}", timeout)
 
 
 @ray_tpu.remote
@@ -318,9 +322,25 @@ class TestWarmPoolCluster:
         _wait_warm(4)
         before = _pool_stats()
         n = 100
-        actors = [Probe.options(num_cpus=0.001).remote() for _ in range(n)]
-        assert ray_tpu.get([a.ping.remote() for a in actors],
-                           timeout=600) == [1] * n
+
+        def burst(k):
+            wave = [Probe.options(num_cpus=0.001).remote() for _ in range(k)]
+            assert ray_tpu.get([a.ping.remote() for a in wave],
+                               timeout=600) == [1] * k
+            return wave
+
+        # the front of the burst takes what is parked (4 at the least) and
+        # drains the pool: more actors than the pool's target of 8, and an
+        # actor keeps its worker
+        actors = burst(n // 2)
+        # the refill, as the pool reports it and not as the burst's timing
+        # has it: forks counted as refills since `before`, and parked
+        # again. A worker that registers while a start is waiting goes to
+        # the waiter as a demand hit, so on a loaded box a burst without
+        # this wait sees no parked refill at all.
+        _wait_pool(lambda st: st["refills"] > before["refills"]
+                   and st["warm"] >= 4, "refilled after the drain")
+        actors += burst(n - n // 2)
         after = _pool_stats()
         hits = after["hits"] - before["hits"]
         # the pool serves the front of the burst + refills along the way

@@ -160,12 +160,16 @@ class TestTrainStep:
 
 
 class TestGraftEntry:
-    def test_entry_and_dryrun(self):
+    def test_entry(self):
         import __graft_entry__ as g
 
         fn, args = g.entry()
         out = jax.jit(fn)(*args)
         assert out.shape[-1] == 256
+
+    def test_dryrun_multichip(self):
+        import __graft_entry__ as g
+
         g.dryrun_multichip(8)
 
 

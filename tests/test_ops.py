@@ -179,49 +179,6 @@ class TestRingAttention:
         np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
 
-class TestBlockwiseAttention:
-    def test_matches_reference_fwd_and_grad(self):
-        import jax
-        import jax.numpy as jnp
-
-        from ray_tpu.ops.attention import reference_attention
-        from ray_tpu.ops.blockwise_attention import blockwise_attention
-
-        k1, k2, k3, k4 = jax.random.split(jax.random.key(0), 4)
-        B, S, H, D = 2, 256, 4, 64
-        q = jax.random.normal(k1, (B, S, H, D), jnp.float32)
-        k = jax.random.normal(k2, (B, S, H, D), jnp.float32)
-        v = jax.random.normal(k3, (B, S, H, D), jnp.float32)
-        g = jax.random.normal(k4, (B, S, H, D), jnp.float32)
-        ref = reference_attention(q, k, v, causal=True)
-        blk = blockwise_attention(q, k, v, causal=True, block_k=64)
-        assert jnp.allclose(ref, blk, atol=2e-4), \
-            float(jnp.abs(ref - blk).max())
-        gr = jax.grad(lambda *a: (reference_attention(
-            *a, causal=True) * g).sum(), argnums=(0, 1, 2))(q, k, v)
-        gb = jax.grad(lambda *a: (blockwise_attention(
-            *a, causal=True, block_k=64) * g).sum(),
-            argnums=(0, 1, 2))(q, k, v)
-        for a, b in zip(gr, gb):
-            assert float(jnp.abs(a - b).max()) < 1e-3
-
-    def test_gqa(self):
-        import jax
-        import jax.numpy as jnp
-
-        from ray_tpu.ops.attention import reference_attention
-        from ray_tpu.ops.blockwise_attention import blockwise_attention
-
-        k1, k2, k3 = jax.random.split(jax.random.key(1), 3)
-        q = jax.random.normal(k1, (1, 128, 8, 32), jnp.float32)
-        k = jax.random.normal(k2, (1, 128, 2, 32), jnp.float32)
-        v = jax.random.normal(k3, (1, 128, 2, 32), jnp.float32)
-        assert jnp.allclose(
-            reference_attention(q, k, v, causal=True),
-            blockwise_attention(q, k, v, causal=True, block_k=32),
-            atol=2e-4)
-
-
 class TestFlashBackward:
     def test_pallas_bwd_matches_reference(self):
         """The custom dq/dkv kernels (interpret mode on CPU) must produce
