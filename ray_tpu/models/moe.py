@@ -11,10 +11,9 @@ axes (the leading ``expert`` dim of the three stacks shards over the mesh's
 
 The dispatch drops nothing and has no capacity: the ``T x K`` (position,
 expert) pairs are sorted by expert, the rows gathered in that order, and
-the three matmuls run as grouped matmuls over the sorted rows
-(``jax.lax.ragged_dot``, which on a TPU is XLA's own Mosaic kernel and
-reads each expert's weights once). Every shape is static, the sort is over
-a fixed ``T x K``, so a (batch, length) shape compiles once whatever the
+the three matmuls run as grouped matmuls over the sorted rows, each
+expert's weights read once. Every shape is static, the sort is over a
+fixed ``T x K``, so a (batch, length) shape compiles once whatever the
 routing.
 
 The layers are scanned, and a custom call cannot read its operand through
@@ -22,12 +21,21 @@ the scan's slice as a dense matmul does: XLA copies the layer's three
 ``[E, hidden, width]`` slices out of the stacked ``[L, E, ...]`` arrays
 first (0.8 GB a layer at OLMoE's widths, 39 ms a serving step on a v5e).
 So the forward pass multiplies by the whole stack, seen as ``L x E``
-groups of which only this layer's hold rows (``_in_place``): an empty
-group costs the kernel nothing, and nothing is copied. The backward pass
-works on the layer's slice, so that the weights' gradient is the slice's
+groups, and nothing is copied (``_in_place``, ``_gated_in_place``). Which
+kernel reads it follows from what the program can see when it is traced
+(``_kernel_takes``), as ``attention(impl="auto")`` chooses: where both of
+a weight's dims are on the lane width and no mesh of more than one device
+is in scope, the repo's own (``ops/pallas/grouped_matmul.py``: the layer's
+first group reaches its index maps, only the strips of a row tile that
+hold an expert's rows are computed, and gate, up and SiLU are one pass over
+the rows); everywhere
+else ``jax.lax.ragged_dot``, XLA's kernel, for which only this layer's
+``E`` of the ``L x E`` groups hold rows: it differentiates and partitions,
+which a ``pallas_call`` does not. The backward pass is ``ragged_dot``'s on
+the layer's slice either way, so that the weights' gradient is the slice's
 and the scan stacks it as it stacks every other leaf's. Where the
 parameters are kept in another type than the activations' the slice is
-cast on its way in, which is that copy, and the matmul takes the slice.
+cast on its way in, which is that copy, and the matmuls take the slice.
 The stack and the layer's index in it travel with the layer's leaves
 (``in_stack``), so the block's signature is the dense model's.
 """
@@ -39,6 +47,8 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from ray_tpu.ops.pallas import grouped_matmul
 
 EXPERT_LOGICAL_AXES = {
     "router": ("embed", "expert"),
@@ -86,16 +96,45 @@ def init_experts(cfg, key: jax.Array) -> Dict[str, jax.Array]:
     }
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _in_place(rows, w, sizes, stack, layer, out_dtype):
-    """``ragged_dot(rows, w, sizes)`` for ``w = stack[layer]``, read from
-    the stack where it lies: the stack is ``L x E`` groups and ``sizes``
-    sits at this layer's ``E`` of them."""
+def _kernel_takes(stack: jax.Array) -> bool:
+    """Whether ``ops/pallas/grouped_matmul.py`` has a path for a stack, from
+    what the program can see when it is traced: both of a weight's dims on
+    the lane width, and no mesh of more than one device in scope (a
+    ``pallas_call`` has no partitioning rule; ``ops/attention.py`` asks the
+    same of the flash kernel)."""
+    from ray_tpu.parallel.sharding import ambient_mesh
+
+    mesh = ambient_mesh()
+    return (grouped_matmul.takes(*stack.shape[2:])
+            and (mesh is None or mesh.size == 1))
+
+
+def _as_groups(stack: jax.Array) -> jax.Array:
+    """``[L, E, K, N]`` seen as ``L x E`` groups: layer ``l``'s experts are
+    groups ``l * E`` onwards."""
+    return stack.reshape((-1,) + stack.shape[2:])
+
+
+def _ragged_dot_in_stack(rows, sizes, stack, layer, out_dtype):
+    """``ragged_dot(rows, stack[layer], sizes)`` by XLA's kernel, read from
+    the stack where it lies: ``sizes`` sits at this layer's ``E`` of the
+    ``L x E`` groups and the others are empty, which costs it nothing."""
     L, E = stack.shape[:2]
     flat = jax.lax.dynamic_update_slice(
         jnp.zeros((L * E,), sizes.dtype), sizes, (layer * E,))
-    return jax.lax.ragged_dot(rows, stack.reshape((L * E,) + stack.shape[2:]),
-                              flat, preferred_element_type=out_dtype)
+    return jax.lax.ragged_dot(rows, _as_groups(stack), flat,
+                              preferred_element_type=out_dtype)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _in_place(rows, w, sizes, stack, layer, out_dtype):
+    """``ragged_dot(rows, w, sizes)`` for ``w = stack[layer]``, read from
+    the stack where it lies; the repo's kernel is handed the layer's first
+    group."""
+    if _kernel_takes(stack):
+        return grouped_matmul.grouped_matmul(
+            rows, _as_groups(stack), sizes, layer * stack.shape[1], out_dtype)
+    return _ragged_dot_in_stack(rows, sizes, stack, layer, out_dtype)
 
 
 def _in_place_fwd(rows, w, sizes, stack, layer, out_dtype):
@@ -110,6 +149,43 @@ def _in_place_bwd(out_dtype, res, g):
 
 
 _in_place.defvjp(_in_place_fwd, _in_place_bwd)
+
+
+@jax.custom_vjp
+def _gated_in_place(rows, w_gate, w_up, sizes, gate_stack, up_stack, layer):
+    """``silu(ragged_dot(rows, w_gate)) * ragged_dot(rows, w_up)`` in the
+    rows' type, both read from their stacks where they lie. The kernel
+    makes it in one pass over the rows and rounds once, from the float32
+    products; XLA's two matmuls round each product first."""
+    if _kernel_takes(gate_stack):
+        return grouped_matmul.grouped_swiglu(
+            rows, _as_groups(gate_stack), _as_groups(up_stack), sizes,
+            layer * gate_stack.shape[1], rows.dtype)
+    return (jax.nn.silu(_ragged_dot_in_stack(rows, sizes, gate_stack, layer,
+                                             rows.dtype))
+            * _ragged_dot_in_stack(rows, sizes, up_stack, layer, rows.dtype))
+
+
+def _gated_in_place_fwd(rows, w_gate, w_up, sizes, gate_stack, up_stack,
+                        layer):
+    return (_gated_in_place(rows, w_gate, w_up, sizes, gate_stack, up_stack,
+                            layer), (rows, w_gate, w_up, sizes))
+
+
+def _gated_in_place_bwd(res, g):
+    # through the layer's slices, the two products made again
+    rows, w_gate, w_up, sizes = res
+
+    def on_slices(r, wg, wu):
+        return (jax.nn.silu(jax.lax.ragged_dot(
+            r, wg, sizes, preferred_element_type=r.dtype))
+            * jax.lax.ragged_dot(r, wu, sizes,
+                                 preferred_element_type=r.dtype))
+    _, vjp = jax.vjp(on_slices, rows, w_gate, w_up)
+    return vjp(g) + (None, None, None, None)
+
+
+_gated_in_place.defvjp(_gated_in_place_fwd, _gated_in_place_bwd)
 
 
 def expert_ffn(cfg, h: jax.Array, lp: Dict[str, jax.Array]
@@ -145,18 +221,23 @@ def expert_ffn(cfg, h: jax.Array, lp: Dict[str, jax.Array]
         sizes = jnp.sum(onehot, axis=0, dtype=jnp.int32)     # [E]
         rows = jnp.take(x, order // K, axis=0)               # [T*K, H]
     with jax.named_scope("moe_experts"):
-        def matmul(x, name, out_dtype):
-            if where is None or lp[name].dtype != dt:
-                # the layer's slice is cast on its way in, which is the
-                # copy: multiply by it (float32 master weights in training)
+        if where is None or lp["we_gate"].dtype != dt:
+            # the layer's slices are cast on their way in, which is the
+            # copy: multiply by them (float32 master weights in training)
+            def matmul(x, name, out_dtype):
                 return jax.lax.ragged_dot(x, lp[name].astype(dt), sizes,
                                           preferred_element_type=out_dtype)
-            stacks, layer = where
-            return _in_place(x, lp[name], sizes, stacks[name], layer,
-                             out_dtype)
 
-        gate, up = matmul(rows, "we_gate", dt), matmul(rows, "we_up", dt)
-        out = matmul(jax.nn.silu(gate) * up, "we_down", jnp.float32)
+            hidden = (jax.nn.silu(matmul(rows, "we_gate", dt))
+                      * matmul(rows, "we_up", dt))
+            out = matmul(hidden, "we_down", jnp.float32)
+        else:
+            stacks, layer = where
+            hidden = _gated_in_place(
+                rows, lp["we_gate"], lp["we_up"], sizes, stacks["we_gate"],
+                stacks["we_up"], layer)
+            out = _in_place(hidden, lp["we_down"], sizes, stacks["we_down"],
+                            layer, jnp.float32)
     with jax.named_scope("moe_combine"):
         # back to the pairs' own order, then the weighted sum of each
         # position's K expert outputs, in float32

@@ -116,6 +116,10 @@ FAST_FILES = {
     # described v5e: what both serving cells and training run on the chip
     "test_ops.py",
     "test_flash_tiles_v5e.py",
+    # the grouped-matmul kernel the sparse serving cell's experts run in,
+    # interpreted at lane-grid shapes, and `moe.py` through it against
+    # itself through `ragged_dot` (under a minute)
+    "test_grouped_matmul.py",
     # the model layer's own tests (ISSUE 30): what the three cells trace.
     # The one block's forward, `mixed:K` remat, cached decode, the chunked
     # loss, LoRA; the train step on an fsdp x tensor mesh and the

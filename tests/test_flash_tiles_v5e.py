@@ -1,6 +1,7 @@
-"""The flash kernels at the tiles ``flash_tiles`` gives, compiled for a
-described TPU v5e (no chip, no times): Mosaic takes each tile, and reports
-no more scoped VMEM than ``tile_vmem_bytes`` reckons. All compiles for a
+"""The flash kernels at the tiles ``flash_tiles`` gives, and the grouped
+matmul at the tiles ``gmm_tiles`` gives, compiled for a described TPU v5e
+(no chip, no times): Mosaic takes each tile, and reports no more scoped
+VMEM than ``tile_vmem_bytes`` and ``gmm_vmem_bytes`` reckon. All compiles for a
 described chip live in this one file, behind one fixture: the worker that
 is dealt the file loads the TPU's library, and no other does."""
 
@@ -11,6 +12,7 @@ import jax.numpy as jnp
 import pytest
 
 from ray_tpu.ops.pallas import flash_attention as fa
+from ray_tpu.ops.pallas import grouped_matmul as gm
 
 
 @pytest.fixture(scope="module")
@@ -77,3 +79,37 @@ def test_tiles_compile_within_their_reckoning(seq, backward, one_chip,
     reckoned = fa.tile_vmem_bytes(
         *fa.flash_tiles(seq, seq, backward=backward), backward=backward)
     assert max(used) <= reckoned <= fa.VMEM_LIMIT_BYTES
+
+
+# serve_olmoe_chat's expert matmuls: 8 rows x 8 experts a token x the
+# shortest, the median and the longest bucket, read from the 16 x 64 stack
+@pytest.mark.parametrize("fused", [True, False])
+@pytest.mark.parametrize("seq", [128, 512, 1152])
+def test_grouped_matmul_compiles_within_its_reckoning(seq, fused, one_chip,
+                                                      compiled_for_tpu):
+    rows, experts, hidden, width = 8 * 8 * seq, 64, 2048, 1024
+    k, n = (hidden, width) if fused else (width, hidden)
+    out = jnp.bfloat16 if fused else jnp.float32
+    x = jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=one_chip)
+    stack = jax.ShapeDtypeStruct((16 * experts, k, n), jnp.bfloat16,
+                                 sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((experts,), jnp.int32, sharding=one_chip)
+    layer = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    if fused:
+        compiled = jax.jit(lambda x, g, u, s, i: gm.grouped_swiglu(
+            x, g, u, s, i * experts, out)).lower(
+                x, stack, stack, sizes, layer).compile()
+    else:
+        compiled = jax.jit(lambda x, w, s, i: gm.grouped_matmul(
+            x, w, s, i * experts, out)).lower(
+                x, stack, sizes, layer).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    stacks = 2 if fused else 1
+    tm, tn = gm.gmm_tiles(rows, k, n, stacks=stacks,
+                          out_itemsize=jnp.dtype(out).itemsize)
+    reckoned = gm.gmm_vmem_bytes(tm, tn, k, stacks=stacks,
+                                 out_itemsize=jnp.dtype(out).itemsize)
+    # the kernel's figure is the program's largest: the rest are XLA's own
+    # fusions for the visits' table
+    assert 2 ** 20 < max(_scoped_vmem(compiled)) <= reckoned \
+        <= gm.VMEM_LIMIT_BYTES
