@@ -9,6 +9,16 @@ Design notes (why this is not a torch translation):
 - Layers are stacked into single arrays (num_layers leading dim) and scanned
   with ``jax.lax.scan``: one compiled layer body regardless of depth, which
   keeps XLA compile time flat and enables per-layer remat.
+- A layer pattern is data on ``LlamaConfig`` (LFM2-24B-A2B has all of it):
+  ``layer_types`` names each layer's operator, causal attention or a gated
+  short convolution, and the first ``num_dense_layers`` of a model with
+  experts keep a dense SwiGLU. A layer's kind is its operator and its
+  feed-forward (``LlamaConfig.layer_kinds``). A model of one kind (every
+  dense decoder, OLMoE) keeps its layers as one stacked pytree under
+  ``params["layers"]``; a model of several keeps one stacked pytree a kind,
+  ``params["layers"][kind]``. There is one block (``_layer``, which reads a
+  layer's kind off its leaves) and one forward, which scans each run of
+  like layers in the configuration's order (``LlamaConfig.layer_runs``).
 - Attention dispatches to ``ray_tpu.ops`` (Pallas flash attention on TPU,
   reference einsum path elsewhere; ring attention when the seq axis > 1).
 - bfloat16 activations / fp32 params+optimizer by default: MXU-native.
@@ -75,6 +85,27 @@ class LlamaConfig:
     norm_topk_prob: bool = False   # renormalise the chosen experts' weights
     router_aux_loss_coef: float = 0.0  # load-balancing term in llama_loss
     qk_norm: bool = False
+    # The router's other variants (models/moe.py's docstring; the defaults
+    # are OLMoE's router)
+    router_scores: str = "softmax"     # softmax | sigmoid
+    router_bias: bool = False          # choose on scores + a buffer [E]
+    router_norm_eps: float = 0.0       # beside the renormalising sum
+    routed_scaling_factor: float = 1.0
+    # The layer pattern (LFM2-24B-A2B has all of it). layer_types names
+    # each layer's operator, "full_attention" or "conv" (empty: attention
+    # everywhere); conv is the gated short convolution of conv_kernel taps.
+    # The first num_dense_layers layers of a model with experts keep a
+    # dense SwiGLU, of width dense_mlp_hidden. qk_head_norm puts an RMSNorm
+    # with one learned weight [head_dim] over each head of the queries and
+    # of the keys, before rope (qk_norm's is over the whole projection: two
+    # norms, both kept). tie_embeddings: the head is the embedding's
+    # transpose and params has no "lm_head".
+    layer_types: Tuple[str, ...] = ()
+    conv_kernel: int = 3
+    num_dense_layers: int = 0
+    dense_mlp_hidden: int = 0
+    qk_head_norm: bool = False
+    tie_embeddings: bool = False
 
     @staticmethod
     def llama2_7b_smoke() -> "LlamaConfig":
@@ -99,15 +130,69 @@ class LlamaConfig:
                            num_layers=1, num_heads=2, num_kv_heads=1,
                            head_dim=32, max_seq_len=128, remat=False)
 
+    def layer_kinds(self) -> Tuple[str, ...]:
+        """Each layer's kind, ``<operator>_<feed-forward>``: ``attention``
+        or ``conv``, then ``routed`` (experts) or ``dense``."""
+        ops = tuple(self.layer_types) or ("full_attention",) * self.num_layers
+        if len(ops) != self.num_layers:
+            raise ValueError(f"layer_types names {len(ops)} layers, "
+                             f"num_layers is {self.num_layers}")
+        names = {"full_attention": "attention", "conv": "conv"}
+        unknown = sorted(set(ops) - set(names))
+        if unknown:
+            raise ValueError(f"layer_types {unknown}: expected "
+                             "'full_attention'|'conv'")
+        return tuple(
+            names[op] + ("_routed" if self.num_experts
+                         and i >= self.num_dense_layers else "_dense")
+            for i, op in enumerate(ops))
+
+    def layer_places(self) -> Tuple[Tuple[str, int], ...]:
+        """Where each layer's leaves lie: ``(kind, index among the layers
+        of that kind)``, in the model's order."""
+        seen: Dict[str, int] = {}
+        places = []
+        for kind in self.layer_kinds():
+            places.append((kind, seen.get(kind, 0)))
+            seen[kind] = places[-1][1] + 1
+        return tuple(places)
+
+    def layer_runs(self) -> Tuple[Tuple[str, int, int], ...]:
+        """The runs of like layers in the model's order: ``(kind, index of
+        the run's first layer among the layers of its kind, layers)``."""
+        runs = []
+        for kind, j in self.layer_places():
+            if runs and runs[-1][0] == kind:
+                runs[-1][2] += 1
+            else:
+                runs.append([kind, j, 1])
+        return tuple(tuple(r) for r in runs)
+
+    def kind_counts(self) -> Dict[str, int]:
+        """How many layers of each kind, in the order they first appear."""
+        kinds = self.layer_kinds()
+        return {k: kinds.count(k) for k in dict.fromkeys(kinds)}
+
+    def dense_width(self) -> int:
+        """Width of a dense SwiGLU: the leading dense layers' own in a
+        model with experts, whose ``mlp_hidden`` is one expert's."""
+        return (self.dense_mlp_hidden if self.num_experts
+                else self.mlp_hidden)
+
     def num_params(self) -> int:
-        h, m, v = self.hidden, self.mlp_hidden, self.vocab_size
+        h, v, E = self.hidden, self.vocab_size, self.num_experts
         q, kv = self.num_heads * self.head_dim, self.num_kv_heads * self.head_dim
-        attn = h * (q + 2 * kv) + q * h + ((q + kv) if self.qk_norm else 0)
-        mlp = 3 * h * m
-        if self.num_experts:
-            mlp = self.num_experts * mlp + h * self.num_experts
-        per_layer = attn + mlp + 2 * h
-        return self.num_layers * per_layer + 2 * v * h + h
+        norms = ((q + kv) if self.qk_norm
+                 else 2 * self.head_dim if self.qk_head_norm else 0)
+        half = {"attention": h * (q + 2 * kv) + q * h + norms,
+                "conv": 4 * h * h + h * self.conv_kernel,
+                "routed": (E * 3 * h * self.mlp_hidden + h * E
+                           + (E if self.router_bias else 0)),
+                "dense": 3 * h * self.dense_width()}
+        layers = sum(
+            n * (sum(half[part] for part in kind.split("_")) + 2 * h)
+            for kind, n in self.kind_counts().items())
+        return layers + (1 if self.tie_embeddings else 2) * v * h + h
 
 
 @dataclasses.dataclass(frozen=True)
@@ -132,17 +217,21 @@ class LoraConfig:
         return self.alpha / self.rank
 
     def num_params(self, cfg: LlamaConfig) -> int:
-        h, m, r = cfg.hidden, cfg.mlp_hidden, self.rank
+        h, m, r = cfg.hidden, cfg.dense_width(), self.rank
         nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
         per = {"wq": h * r + r * nh * hd, "wk": h * r + r * nkv * hd,
                "wv": h * r + r * nkv * hd, "wo": nh * hd * r + r * h,
                "w_gate": h * r + r * m, "w_up": h * r + r * m,
                "w_down": m * r + r * h}
-        return cfg.num_layers * sum(per[t] for t in self.targets)
+        counts = cfg.kind_counts()
+        return sum(counts[kind] * per[t]
+                   for kind, ts in _lora_targets(cfg, self).items()
+                   for t in ts)
 
 
 # (in_axes of A, out_axes of B) per adaptable projection; the A/B shapes are
-# in_axes+(rank,) and (rank,)+out_axes with a leading num_layers dim.
+# in_axes+(rank,) and (rank,)+out_axes with a leading dim over the layers
+# that have the projection.
 _LORA_SHAPES = {
     "wq": (("embed",), ("heads", "head_dim")),
     "wk": (("embed",), ("kv_heads", "head_dim")),
@@ -154,98 +243,152 @@ _LORA_SHAPES = {
 }
 
 
+def _lora_targets(cfg: LlamaConfig, lcfg: LoraConfig
+                  ) -> Dict[str, Tuple[str, ...]]:
+    """kind of layer -> the targets its layers have: attention's four
+    projections in an attention layer, the SwiGLU's three in a layer with
+    a dense feed-forward. A target that no layer of the model has raises
+    by name."""
+    half = {t: "dense" if t.startswith("w_") else "attention"
+            for t in _LORA_SHAPES}
+    unknown = sorted(set(lcfg.targets) - set(half))
+    if unknown:
+        raise ValueError(f"LoRA targets {unknown}: expected some of "
+                         f"{sorted(half)}")
+    out = {kind: tuple(t for t in lcfg.targets if half[t] in kind.split("_"))
+           for kind in cfg.kind_counts()}
+    absent = sorted(t for t in lcfg.targets
+                    if not any(t in ts for ts in out.values()))
+    if absent:
+        raise ValueError(
+            f"LoRA targets {absent}: no layer of this model has them (its "
+            f"layers are {cfg.kind_counts()}; routed experts and the short "
+            "convolution are not adapted)")
+    return {kind: ts for kind, ts in out.items() if ts}
+
+
+def _by_kind(layers: Dict, cfg: LlamaConfig) -> Dict[str, Dict]:
+    """``params["layers"]`` (or an adapter tree's) as kind -> stacked
+    pytree: a model of one kind keeps the stack itself there."""
+    kinds = list(cfg.kind_counts())
+    return {kinds[0]: layers} if len(kinds) == 1 else layers
+
+
+def _as_layers(by_kind: Dict[str, Dict], cfg: LlamaConfig) -> Dict:
+    """The inverse of ``_by_kind``."""
+    kinds = list(cfg.kind_counts())
+    return by_kind.get(kinds[0], {}) if len(kinds) == 1 else by_kind
+
+
 def _lora_dims(cfg: LlamaConfig):
-    return {"embed": (cfg.hidden,), "mlp": (cfg.mlp_hidden,),
+    return {"embed": (cfg.hidden,), "mlp": (cfg.dense_width(),),
             "heads": (cfg.num_heads,), "kv_heads": (cfg.num_kv_heads,),
             "head_dim": (cfg.head_dim,)}
 
 
 def init_lora(cfg: LlamaConfig, lcfg: LoraConfig, key: jax.Array) -> Dict:
     """A ~ truncated-normal fan-in, B = 0 (the adapted model starts exactly
-    at the base), stacked over layers for the scanned body."""
+    at the base), stacked over the layers of each kind that have the
+    projection, for the scanned body; the tree has ``params["layers"]``'s
+    shape (``_by_kind``)."""
     dims = _lora_dims(cfg)
-    L, r = cfg.num_layers, lcfg.rank
-    routed = sorted(set(lcfg.targets) & {"w_gate", "w_up", "w_down"})
-    if cfg.num_experts and routed:
-        raise ValueError(f"LoRA targets {routed}: a model with experts has "
-                         "no dense feed-forward to adapt")
-    out = {}
-    keys = jax.random.split(key, len(lcfg.targets))
-    for k, name in zip(keys, lcfg.targets):
-        in_ax, out_ax = _LORA_SHAPES[name]
-        in_shape = sum((dims[a] for a in in_ax), ())
-        out_shape = sum((dims[a] for a in out_ax), ())
-        fan_in = 1
-        for d in in_shape:
-            fan_in *= d
-        a = (jax.random.truncated_normal(
-            k, -2, 2, (L,) + in_shape + (r,), jnp.float32)
-            * fan_in ** -0.5).astype(lcfg.param_dtype)
-        b = jnp.zeros((L, r) + out_shape, lcfg.param_dtype)
-        out[name] = {"a": a, "b": b}
-    return {"layers": out}
+    r, counts = lcfg.rank, cfg.kind_counts()
+    keys = dict(zip(lcfg.targets, jax.random.split(key, len(lcfg.targets))))
+    by_kind = {}
+    for j, (kind, targets) in enumerate(_lora_targets(cfg, lcfg).items()):
+        L, out = counts[kind], {}
+        for name in targets:
+            k = keys[name] if j == 0 else jax.random.fold_in(keys[name], j)
+            in_ax, out_ax = _LORA_SHAPES[name]
+            in_shape = sum((dims[a] for a in in_ax), ())
+            out_shape = sum((dims[a] for a in out_ax), ())
+            fan_in = 1
+            for d in in_shape:
+                fan_in *= d
+            a = (jax.random.truncated_normal(
+                k, -2, 2, (L,) + in_shape + (r,), jnp.float32)
+                * fan_in ** -0.5).astype(lcfg.param_dtype)
+            b = jnp.zeros((L, r) + out_shape, lcfg.param_dtype)
+            out[name] = {"a": a, "b": b}
+        by_kind[kind] = out
+    return {"layers": _as_layers(by_kind, cfg)}
 
 
 def lora_logical_axes(cfg: LlamaConfig, lcfg: LoraConfig) -> Dict:
     """Rank dim stays unsharded (it is tiny); in/out dims shard like the
     base weight they adapt so the activation-side matmuls need no extra
     resharding."""
-    out = {}
-    for name in lcfg.targets:
-        in_ax, out_ax = _LORA_SHAPES[name]
-        out[name] = {"a": (None,) + in_ax + (None,),
-                     "b": (None, None) + out_ax}
-    return {"layers": out}
+    by_kind = {}
+    for kind, targets in _lora_targets(cfg, lcfg).items():
+        by_kind[kind] = {
+            name: {"a": (None,) + _LORA_SHAPES[name][0] + (None,),
+                   "b": (None, None) + _LORA_SHAPES[name][1]}
+            for name in targets}
+    return {"layers": _as_layers(by_kind, cfg)}
 
 
 def merge_lora(params: Dict, lora: Dict, cfg: LlamaConfig,
                lcfg: LoraConfig) -> Dict:
     """Fold adapters into the base weights (for serving/export)."""
-    merged = dict(params)
-    layers = dict(params["layers"])
-    for name, ab in lora["layers"].items():
-        w = layers[name]
-        a2 = ab["a"].reshape(cfg.num_layers, -1, lcfg.rank)
-        b2 = ab["b"].reshape(cfg.num_layers, lcfg.rank, -1)
-        delta = jnp.einsum("lir,lro->lio", a2.astype(jnp.float32),
-                           b2.astype(jnp.float32)) * lcfg.scale
-        layers[name] = (w.astype(jnp.float32)
-                        + delta.reshape(w.shape)).astype(w.dtype)
-    merged["layers"] = layers
-    return merged
+    by_kind = {kind: dict(layers)
+               for kind, layers in _by_kind(params["layers"], cfg).items()}
+    for kind, adapters in _by_kind(lora["layers"], cfg).items():
+        layers = by_kind[kind]
+        for name, ab in adapters.items():
+            w = layers[name]
+            a2 = ab["a"].reshape(w.shape[0], -1, lcfg.rank)
+            b2 = ab["b"].reshape(w.shape[0], lcfg.rank, -1)
+            delta = jnp.einsum("lir,lro->lio", a2.astype(jnp.float32),
+                               b2.astype(jnp.float32)) * lcfg.scale
+            layers[name] = (w.astype(jnp.float32)
+                            + delta.reshape(w.shape)).astype(w.dtype)
+    return dict(params, layers=_as_layers(by_kind, cfg))
+
+
+def _kind_logical_axes(cfg: LlamaConfig, kind: str) -> Dict[str, Any]:
+    """One layer of a kind: leaf -> logical axes, without the leading dim
+    over layers."""
+    operator, ffn = kind.split("_")
+    layer = {"attn_norm": ("norm",), "mlp_norm": ("norm",)}
+    if operator == "attention":
+        layer.update(wq=("embed", "heads", "head_dim"),
+                     wk=("embed", "kv_heads", "head_dim"),
+                     wv=("embed", "kv_heads", "head_dim"),
+                     wo=("heads", "head_dim", "embed"))
+        if cfg.qk_norm or cfg.qk_head_norm:
+            layer.update(q_norm=("norm",), k_norm=("norm",))
+    else:  # the gated short convolution: in-projection, taps, out
+        layer.update(conv_in=("embed", "mlp"), conv_w=("mlp", None),
+                     conv_out=("mlp", "embed"))
+    if ffn == "routed":
+        layer.update(moe.EXPERT_LOGICAL_AXES)
+        if cfg.router_bias:
+            layer.update(router_bias=("expert",))
+    else:
+        layer.update(w_gate=("embed", "mlp"), w_up=("embed", "mlp"),
+                     w_down=("mlp", "embed"))
+    return layer
 
 
 def llama_logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
     """Pytree (same structure as params) of logical-axis tuples."""
-    layer = {
-        "wq": ("embed", "heads", "head_dim"),
-        "wk": ("embed", "kv_heads", "head_dim"),
-        "wv": ("embed", "kv_heads", "head_dim"),
-        "wo": ("heads", "head_dim", "embed"),
-        "attn_norm": ("norm",),
-        "mlp_norm": ("norm",),
-    }
-    if cfg.num_experts:
-        layer.update(moe.EXPERT_LOGICAL_AXES)
-    else:
-        layer.update(w_gate=("embed", "mlp"), w_up=("embed", "mlp"),
-                     w_down=("mlp", "embed"))
-    if cfg.qk_norm:
-        layer.update(q_norm=("norm",), k_norm=("norm",))
     # scanned layers carry a leading 'layers' dim — replicated (None)
-    layers = {k: (None,) + v for k, v in layer.items()}
-    return {
+    by_kind = {kind: {k: (None,) + v
+                      for k, v in _kind_logical_axes(cfg, kind).items()}
+               for kind in cfg.kind_counts()}
+    out = {
         "embed": ("vocab", "embed"),
-        "layers": layers,
+        "layers": _as_layers(by_kind, cfg),
         "final_norm": ("norm",),
-        "lm_head": ("embed", "vocab"),
     }
+    if not cfg.tie_embeddings:
+        out["lm_head"] = ("embed", "vocab")
+    return out
 
 
 def init_llama(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
     """Initialize params (truncated-normal fan-in scaling, fp32)."""
-    h, m = cfg.hidden, cfg.mlp_hidden
-    nh, nkv, hd, L = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim, cfg.num_layers
+    h, nh, nkv, hd = cfg.hidden, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     ks = jax.random.split(key, 10)
     pd = cfg.param_dtype
 
@@ -254,29 +397,62 @@ def init_llama(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
         return (jax.random.truncated_normal(k, -2, 2, shape, jnp.float32)
                 * scale).astype(pd)
 
-    layers = {
-        "wq": norm_init((L, h, nh, hd), ks[0], h),
-        "wk": norm_init((L, h, nkv, hd), ks[1], h),
-        "wv": norm_init((L, h, nkv, hd), ks[2], h),
-        "wo": norm_init((L, nh, hd, h), ks[3], nh * hd),
-    }
-    if cfg.num_experts:
-        layers.update(moe.init_experts(cfg, ks[9]))
-    else:
-        layers.update(w_gate=norm_init((L, h, m), ks[4], h),
-                      w_up=norm_init((L, h, m), ks[5], h),
-                      w_down=norm_init((L, m, h), ks[6], m))
-    layers.update(attn_norm=jnp.ones((L, h), pd),
-                  mlp_norm=jnp.ones((L, h), pd))
-    if cfg.qk_norm:
-        layers.update(q_norm=jnp.ones((L, nh * hd), pd),
-                      k_norm=jnp.ones((L, nkv * hd), pd))
-    return {
+    def init_kind(kind, L, ks):
+        """The L layers of a kind, stacked; ks: ten keys, of which the
+        eighth and ninth are the embedding's and the head's."""
+        operator, ffn = kind.split("_")
+        if operator == "attention":
+            layers = {
+                "wq": norm_init((L, h, nh, hd), ks[0], h),
+                "wk": norm_init((L, h, nkv, hd), ks[1], h),
+                "wv": norm_init((L, h, nkv, hd), ks[2], h),
+                "wo": norm_init((L, nh, hd, h), ks[3], nh * hd),
+            }
+        else:
+            layers = {
+                "conv_in": norm_init((L, h, 3 * h), ks[0], h),
+                "conv_w": norm_init((L, h, cfg.conv_kernel), ks[1],
+                                    cfg.conv_kernel),
+                "conv_out": norm_init((L, h, h), ks[3], h),
+            }
+        if ffn == "routed":
+            layers.update(moe.init_experts(cfg, ks[9], L))
+        else:
+            m = cfg.dense_width()
+            layers.update(w_gate=norm_init((L, h, m), ks[4], h),
+                          w_up=norm_init((L, h, m), ks[5], h),
+                          w_down=norm_init((L, m, h), ks[6], m))
+        layers.update(attn_norm=jnp.ones((L, h), pd),
+                      mlp_norm=jnp.ones((L, h), pd))
+        if operator == "attention" and (cfg.qk_norm or cfg.qk_head_norm):
+            # over the whole projection, or one weight shared by the heads
+            q, k = (nh * hd, nkv * hd) if cfg.qk_norm else (hd, hd)
+            layers.update(q_norm=jnp.ones((L, q), pd),
+                          k_norm=jnp.ones((L, k), pd))
+        return layers
+
+    counts = cfg.kind_counts()
+    # a model of one kind draws its layers from the ten keys themselves,
+    # as it always has; each kind of a pattern from ten of its own
+    by_kind = {
+        kind: init_kind(kind, L, ks if len(counts) == 1 else
+                        jax.random.split(jax.random.fold_in(key, j + 1), 10))
+        for j, (kind, L) in enumerate(counts.items())}
+    out = {
         "embed": norm_init((cfg.vocab_size, h), ks[7], 1.0),
-        "layers": layers,
+        "layers": _as_layers(by_kind, cfg),
         "final_norm": jnp.ones((h,), pd),
-        "lm_head": norm_init((h, cfg.vocab_size), ks[8], h),
     }
+    if not cfg.tie_embeddings:
+        out["lm_head"] = norm_init((h, cfg.vocab_size), ks[8], h)
+    return out
+
+
+def _lm_head(params: Dict[str, Any]) -> jax.Array:
+    """The head's ``[hidden, vocab]``: its own leaf, or the embedding's
+    transpose where the two are tied."""
+    head = params.get("lm_head")
+    return params["embed"].T if head is None else head
 
 
 def _rms_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
@@ -300,13 +476,45 @@ def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return out.astype(x.dtype)
 
 
+def _short_conv(cfg: LlamaConfig, u: jax.Array, lp: Dict[str, jax.Array],
+                state: Optional[jax.Array] = None):
+    """The gated short convolution (LFM2's ``Lfm2ShortConv``) on the
+    normed input ``u [B, S, H]`` -> (its output ``[B, S, H]``, the state
+    after it). ``[B_t, C_t, X_t] = W_in u_t``; ``z = B * X``; ``c_t`` is
+    the causal depthwise convolution of ``z`` over ``conv_kernel`` taps,
+    ``sum_j w[:, j] z_{t-(K-1)+j}``, summed in float32; the output is
+    ``W_out (C * c)``. No bias. ``state [B, K-1, H]`` is the last ``K-1``
+    rows of ``z`` before this call (zeros at a sequence's start, which is
+    what ``None`` stands for): all an incremental decode keeps of a row."""
+    dt, taps = cfg.dtype, cfg.conv_kernel
+    with jax.named_scope("short_conv"):
+        bcx = jnp.einsum("bsh,hc->bsc", u, lp["conv_in"].astype(dt))
+        gate_b, gate_c, x = jnp.split(bcx, 3, axis=-1)
+        z = gate_b * x
+        if state is None:
+            state = jnp.zeros((z.shape[0], taps - 1, z.shape[2]), dt)
+        padded = jnp.concatenate([state.astype(dt), z], axis=1)
+        S = z.shape[1]
+        w = lp["conv_w"].astype(jnp.float32)               # [H, taps]
+        c = sum(w[:, j] * padded[:, j:j + S].astype(jnp.float32)
+                for j in range(taps))
+        y = jnp.einsum("bsh,hd->bsd", gate_c * c.astype(dt),
+                       lp["conv_out"].astype(dt))
+    return y, padded[:, S:]
+
+
 def _layer(cfg: LlamaConfig, x: jax.Array, lp: Dict[str, jax.Array],
            positions: jax.Array, kv_cache=None,
            cache_index: Optional[jax.Array] = None,
            lora: Optional[Dict[str, Any]] = None, lora_scale: float = 0.0):
-    """One transformer block. x: [B, S, H_model] -> (x, the updated
-    key/value cache or None, the router's books of ``moe.expert_ffn`` or
-    None for a dense feed-forward)."""
+    """One block. x: [B, S, H_model] -> (x, the layer's updated state or
+    None, the router's books of ``moe.expert_ffn`` or None for a dense
+    feed-forward). The layer's kind is read off its leaves: ``conv_in``
+    makes the operator the gated short convolution and not attention,
+    ``router`` makes the feed-forward the routed experts and not the dense
+    SwiGLU. ``kv_cache`` is the layer's own state in an incremental decode:
+    (keys, values) for attention, the last rows of ``z`` for the short
+    convolution (``_short_conv``)."""
     dt = cfg.dtype
 
     def _ld(name, t_in, eq_a, eq_b):
@@ -317,43 +525,54 @@ def _layer(cfg: LlamaConfig, x: jax.Array, lp: Dict[str, jax.Array],
         t = jnp.einsum(eq_a, t_in, ab["a"].astype(dt))
         return jnp.einsum(eq_b, t, ab["b"].astype(dt)) * lora_scale
 
-    # --- attention ---
     h = _rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-    q = (jnp.einsum("bsh,hnd->bsnd", h, lp["wq"].astype(dt))
-         + _ld("wq", h, "bsh,hr->bsr", "bsr,rnd->bsnd"))
-    k = (jnp.einsum("bsh,hnd->bsnd", h, lp["wk"].astype(dt))
-         + _ld("wk", h, "bsh,hr->bsr", "bsr,rnd->bsnd"))
-    v = (jnp.einsum("bsh,hnd->bsnd", h, lp["wv"].astype(dt))
-         + _ld("wv", h, "bsh,hr->bsr", "bsr,rnd->bsnd"))
-    if cfg.qk_norm:  # over the whole projection, heads x head_dim
-        q = _rms_norm(q.reshape(q.shape[:2] + (-1,)), lp["q_norm"],
-                      cfg.rms_eps).reshape(q.shape)
-        k = _rms_norm(k.reshape(k.shape[:2] + (-1,)), lp["k_norm"],
-                      cfg.rms_eps).reshape(k.shape)
-    q = _rope(q, positions, cfg.rope_theta)
-    k = _rope(k, positions, cfg.rope_theta)
-    q = constrain(q, ("batch", "seq", "heads", None))
-    k = constrain(k, ("batch", "seq", "kv_heads", None))
-    new_cache = None
-    if kv_cache is not None:
-        ck, cv = kv_cache  # [B, max_S, nkv, d]
-        ck = jax.lax.dynamic_update_slice_in_dim(ck, k, cache_index, axis=1)
-        cv = jax.lax.dynamic_update_slice_in_dim(cv, v, cache_index, axis=1)
-        k, v = ck, cv
-        new_cache = (ck, cv)
-        attn_out = attention(q, k, v, impl="reference", causal=True,
-                             q_offset=cache_index)
+    if "conv_in" in lp:
+        y, state = _short_conv(cfg, h, lp, kv_cache)
+        new_cache = None if kv_cache is None else state
+        x = x + y
     else:
-        if cfg.attn_impl == "ring_seq":
-            attn_out = _ring_seq_attention(q, k, v)
+        # --- attention ---
+        q = (jnp.einsum("bsh,hnd->bsnd", h, lp["wq"].astype(dt))
+             + _ld("wq", h, "bsh,hr->bsr", "bsr,rnd->bsnd"))
+        k = (jnp.einsum("bsh,hnd->bsnd", h, lp["wk"].astype(dt))
+             + _ld("wk", h, "bsh,hr->bsr", "bsr,rnd->bsnd"))
+        v = (jnp.einsum("bsh,hnd->bsnd", h, lp["wv"].astype(dt))
+             + _ld("wv", h, "bsh,hr->bsr", "bsr,rnd->bsnd"))
+        if cfg.qk_norm:  # over the whole projection, heads x head_dim
+            q = _rms_norm(q.reshape(q.shape[:2] + (-1,)), lp["q_norm"],
+                          cfg.rms_eps).reshape(q.shape)
+            k = _rms_norm(k.reshape(k.shape[:2] + (-1,)), lp["k_norm"],
+                          cfg.rms_eps).reshape(k.shape)
+        elif cfg.qk_head_norm:  # over each head's head_dim, one weight
+            q = _rms_norm(q, lp["q_norm"], cfg.rms_eps)
+            k = _rms_norm(k, lp["k_norm"], cfg.rms_eps)
+        q = _rope(q, positions, cfg.rope_theta)
+        k = _rope(k, positions, cfg.rope_theta)
+        q = constrain(q, ("batch", "seq", "heads", None))
+        k = constrain(k, ("batch", "seq", "kv_heads", None))
+        new_cache = None
+        if kv_cache is not None:
+            ck, cv = kv_cache  # [B, max_S, nkv, d]
+            ck = jax.lax.dynamic_update_slice_in_dim(ck, k, cache_index,
+                                                     axis=1)
+            cv = jax.lax.dynamic_update_slice_in_dim(cv, v, cache_index,
+                                                     axis=1)
+            k, v = ck, cv
+            new_cache = (ck, cv)
+            attn_out = attention(q, k, v, impl="reference", causal=True,
+                                 q_offset=cache_index)
         else:
-            attn_out = attention(q, k, v, impl=cfg.attn_impl, causal=True)
-    attn_out = constrain(attn_out, ("batch", "seq", "heads", None))
-    x = (x + jnp.einsum("bsnd,ndh->bsh", attn_out, lp["wo"].astype(dt))
-         + _ld("wo", attn_out, "bsnd,ndr->bsr", "bsr,rh->bsh"))
+            if cfg.attn_impl == "ring_seq":
+                attn_out = _ring_seq_attention(q, k, v)
+            else:
+                attn_out = attention(q, k, v, impl=cfg.attn_impl,
+                                     causal=True)
+        attn_out = constrain(attn_out, ("batch", "seq", "heads", None))
+        x = (x + jnp.einsum("bsnd,ndh->bsh", attn_out, lp["wo"].astype(dt))
+             + _ld("wo", attn_out, "bsnd,ndr->bsr", "bsr,rh->bsh"))
     # --- feed-forward: routed experts, or the dense SwiGLU ---
     h = _rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-    if cfg.num_experts:
+    if "router" in lp:
         y, books = moe.expert_ffn(cfg, h, lp)
         return constrain(x + y, ("batch", "seq", "embed")), new_cache, books
     gate = (jnp.einsum("bsh,hm->bsm", h, lp["w_gate"].astype(dt))
@@ -367,6 +586,19 @@ def _layer(cfg: LlamaConfig, x: jax.Array, lp: Dict[str, jax.Array],
     return x, new_cache, None
 
 
+def init_decode_state(cfg: LlamaConfig, batch: int, max_len: int) -> list:
+    """What ``llama_decode`` carries from call to call, a layer's own
+    state in the model's order: keys and values ``[batch, max_len,
+    kv_heads, head_dim]`` for an attention layer, the last ``conv_kernel -
+    1`` rows of ``z`` ``[batch, conv_kernel - 1, hidden]`` for a short
+    convolution, all zeros."""
+    kv = jnp.zeros((batch, max_len, cfg.num_kv_heads, cfg.head_dim),
+                   cfg.dtype)
+    z = jnp.zeros((batch, cfg.conv_kernel - 1, cfg.hidden), cfg.dtype)
+    return [(kv, kv) if kind.startswith("attention") else z
+            for kind in cfg.layer_kinds()]
+
+
 def llama_decode(
     params: Dict[str, Any],
     tokens: jax.Array,
@@ -376,23 +608,25 @@ def llama_decode(
     *,
     positions: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, list]:
-    """Incremental decode: tokens [B, S] appended to the kv caches at
-    ``cache_index`` → (logits [B, S, V] fp32, updated caches). Python loop
-    over layers so each layer's cache updates functionally in place."""
+    """Incremental decode: tokens [B, S] appended to the layers' states
+    (``init_decode_state``) at ``cache_index`` → (logits [B, S, V] fp32,
+    updated states). Python loop over layers so each layer's state updates
+    functionally in place."""
     B, S = tokens.shape
     if positions is None:
         positions = jnp.broadcast_to(
             jnp.arange(S, dtype=jnp.int32) + cache_index, (B, S))
     x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
+    stacks = _by_kind(params["layers"], cfg)
     new_caches = []
-    for i in range(cfg.num_layers):
-        lp = jax.tree.map(lambda a: a[i], params["layers"])
-        if cfg.num_experts:
-            lp = moe.in_stack(lp, params["layers"], i)
+    for i, (kind, j) in enumerate(cfg.layer_places()):
+        lp = jax.tree.map(lambda a: a[j], stacks[kind])
+        if "router" in lp:
+            lp = moe.in_stack(lp, stacks[kind], j)
         x, c, _ = _layer(cfg, x, lp, positions, kv_caches[i], cache_index)
         new_caches.append(c)
     x = _rms_norm(x, params["final_norm"], cfg.rms_eps)
-    logits = jnp.einsum("bsh,hv->bsv", x, params["lm_head"].astype(cfg.dtype))
+    logits = jnp.einsum("bsh,hv->bsv", x, _lm_head(params).astype(cfg.dtype))
     return logits.astype(jnp.float32), new_caches
 
 
@@ -423,7 +657,8 @@ def _hidden_and_books(
     router_mask: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
     """``llama_hidden``, and with it the routers' books stacked over the
-    layers (``moe.expert_ffn``; None for a model without experts)."""
+    layers that have routed experts, in the model's order
+    (``moe.expert_ffn``; None for a model without experts)."""
     B, S = tokens.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
@@ -431,65 +666,71 @@ def _hidden_and_books(
     x = constrain(x, ("batch", "seq", "embed"))
 
     scale = lora_cfg.scale if lora_cfg is not None else 0.0
+    stacks = _by_kind(params["layers"], cfg)
+    lo_stacks = _by_kind(lora["layers"], cfg) if lora is not None else {}
 
-    def scan_over(layers, lo):
-        """(scan body, xs) over a stack of layers. With experts the body
-        also sees the whole stack and its own index in it."""
-        n = jax.tree.leaves(layers)[0].shape[0]
-        index = jnp.arange(n) if cfg.num_experts else None
+    def scan_over(kind, start, n):
+        """(scan body, xs) over layers ``start`` to ``start + n`` of a
+        kind's stack. With experts the body also sees the whole stack and
+        its own index in it."""
+        def part(tree):
+            # broadcast None through the scan when no adapters: xs must be
+            # a pytree of arrays, so substitute an empty dict
+            if not tree or jax.tree.leaves(tree)[0].shape[0] == n:
+                return tree or {}
+            return jax.tree.map(lambda a: a[start:start + n], tree)
+
+        routed = "router" in stacks[kind]
+        index = jnp.arange(start, start + n) if routed else None
 
         def scan_fn(carry, xs):
             lp, lo_i, i = xs
-            if cfg.num_experts:
-                lp = moe.in_stack(lp, layers, i, router_mask)
+            if routed:
+                lp = moe.in_stack(lp, stacks[kind], i, router_mask)
             y, _, books = _layer(cfg, carry, lp, positions, lora=lo_i,
                                  lora_scale=scale)
             return y, books
 
-        return scan_fn, (layers, lo, index)
+        return scan_fn, (part(stacks[kind]), part(lo_stacks.get(kind)),
+                         index)
 
-    lo_layers = lora["layers"] if lora is not None else None
-    if cfg.remat:
-        # "dots": keep matmul outputs, recompute elementwise — near-zero
-        # extra MXU work for most of full remat's memory win. "full":
-        # recompute everything (longest-context fallback). "mixed:K":
-        # first K layers keep their matmul outputs, the rest recompute —
-        # spends whatever HBM headroom full remat leaves on skipping
-        # recompute FLOPs (each dots layer trades ~160 MB at 7B/B=1/S=2k
-        # for one layer-forward less recompute per step).
-        if cfg.remat_policy.startswith("mixed:"):
-            k = int(cfg.remat_policy.split(":", 1)[1])
-            n = cfg.num_layers
-            k = max(0, min(k, n))
-            dots = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-            full = jax.checkpoint_policies.nothing_saveable
-            head = jax.tree.map(lambda a: a[:k], params["layers"])
-            tail = jax.tree.map(lambda a: a[k:], params["layers"])
-            lo_head = (jax.tree.map(lambda a: a[:k], lo_layers)
-                       if lo_layers is not None else {})
-            lo_tail = (jax.tree.map(lambda a: a[k:], lo_layers)
-                       if lo_layers is not None else {})
-            fn, xs = scan_over(head, lo_head)
-            x, b_head = jax.lax.scan(jax.checkpoint(fn, policy=dots), x, xs)
-            fn, xs = scan_over(tail, lo_tail)
-            x, b_tail = jax.lax.scan(jax.checkpoint(fn, policy=full), x, xs)
-            books = jax.tree.map(lambda a, b: jnp.concatenate([a, b]),
-                                 b_head, b_tail)
-            return _rms_norm(x, params["final_norm"], cfg.rms_eps), books
-        if cfg.remat_policy not in ("dots", "full"):
-            raise ValueError(
-                f"remat_policy {cfg.remat_policy!r}: expected "
-                "'dots'|'full'|'mixed:K'")
-    # broadcast None through the scan when no adapters: xs must be a pytree
-    # of arrays, so substitute an empty dict
-    scan_fn, xs = scan_over(params["layers"], lo_layers or {})
-    if cfg.remat:
-        policy = (jax.checkpoint_policies.nothing_saveable
-                  if cfg.remat_policy == "full"
-                  else jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-        scan_fn = jax.checkpoint(scan_fn, policy=policy)
-    x, books = jax.lax.scan(scan_fn, x, xs)
-    return _rms_norm(x, params["final_norm"], cfg.rms_eps), books
+    # Each run of like layers is one scan, in the model's order, under the
+    # layer's remat policy. "dots": keep matmul outputs, recompute
+    # elementwise — near-zero extra MXU work for most of full remat's
+    # memory win. "full": recompute everything (longest-context fallback).
+    # "mixed:K": the model's first K layers keep their matmul outputs, the
+    # rest recompute — spends whatever HBM headroom full remat leaves on
+    # skipping recompute FLOPs (each dots layer trades ~160 MB at
+    # 7B/B=1/S=2k for one layer-forward less recompute per step); a run
+    # that K falls inside is scanned in two parts.
+    dots = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+    full = jax.checkpoint_policies.nothing_saveable
+    keep = cfg.num_layers if cfg.remat_policy == "dots" else 0
+    if cfg.remat and cfg.remat_policy.startswith("mixed:"):
+        keep = max(0, min(int(cfg.remat_policy.split(":", 1)[1]),
+                          cfg.num_layers))
+    elif cfg.remat and cfg.remat_policy not in ("dots", "full"):
+        raise ValueError(
+            f"remat_policy {cfg.remat_policy!r}: expected "
+            "'dots'|'full'|'mixed:K'")
+    books, first = [], 0  # first: the run's first layer in the model
+    for kind, start, n in cfg.layer_runs():
+        head = max(0, min(keep - first, n))
+        for begin, count, policy in ((start, head, dots),
+                                     (start + head, n - head, full)):
+            if not count:
+                continue
+            scan_fn, xs = scan_over(kind, begin, count)
+            if cfg.remat:
+                scan_fn = jax.checkpoint(scan_fn, policy=policy)
+            x, b = jax.lax.scan(scan_fn, x, xs)
+            if b is not None:
+                books.append(b)
+        first += n
+    if len(books) > 1:  # the routed layers', in the model's order
+        books = [jax.tree.map(lambda *bs: jnp.concatenate(bs), *books)]
+    return (_rms_norm(x, params["final_norm"], cfg.rms_eps),
+            books[0] if books else None)
 
 
 def llama_forward(
@@ -505,7 +746,7 @@ def llama_forward(
     use ``llama_decode``."""
     x = llama_hidden(params, tokens, cfg, positions=positions,
                      lora=lora, lora_cfg=lora_cfg)
-    logits = jnp.einsum("bsh,hv->bsv", x, params["lm_head"].astype(cfg.dtype))
+    logits = jnp.einsum("bsh,hv->bsv", x, _lm_head(params).astype(cfg.dtype))
     return logits.astype(jnp.float32)
 
 
@@ -514,7 +755,7 @@ def llama_head(params: Dict[str, Any], x: jax.Array,
     """Final hidden states [..., H] → logits [..., V], accumulated and
     kept in fp32 (``llama_forward`` rounds the product to the activation
     dtype first; the operands are the same)."""
-    return jnp.einsum("...h,hv->...v", x, params["lm_head"].astype(cfg.dtype),
+    return jnp.einsum("...h,hv->...v", x, _lm_head(params).astype(cfg.dtype),
                       preferred_element_type=jnp.float32)
 
 
@@ -537,12 +778,12 @@ def llama_next_token(
     who wants every position's logits applies ``llama_head`` to them and
     runs the layers once. The load is None for a model without experts,
     else ``moe.router_load`` over the positions ``live [B, S]`` marks (the
-    rows' own tokens and not their padding): two float32 a layer."""
+    rows' own tokens and not their padding): two float32 a routed layer."""
     x, books = _hidden_and_books(params, tokens, cfg, lora=lora,
                                  lora_cfg=lora_cfg, router_mask=live)
     rows = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
     ids = jnp.argmax(llama_head(params, rows, cfg), axis=-1)
-    load = moe.router_load(books) if cfg.num_experts else None
+    load = moe.router_load(books) if books is not None else None
     return ids.astype(jnp.int32), x, load
 
 
@@ -603,15 +844,15 @@ def llama_loss(params: Dict[str, Any], batch: Dict[str, jax.Array],
     x, books = _hidden_and_books(params, inputs, cfg, lora=lora,
                                  lora_cfg=lora_cfg)
     if cfg.loss_chunk:
-        ce = _chunked_ce(x, params["lm_head"], targets, mask,
+        ce = _chunked_ce(x, _lm_head(params), targets, mask,
                          cfg.loss_chunk, cfg.dtype)
     else:
         logits = jnp.einsum("bsh,hv->bsv", x,
-                            params["lm_head"].astype(cfg.dtype))
+                            _lm_head(params).astype(cfg.dtype))
         nll = _nll_from_logits(logits, targets)
         ce = (jnp.mean(nll) if mask is None else
               jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0))
-    if cfg.num_experts:  # the routers' load-balancing term rides on it
+    if books is not None:  # the routers' load-balancing term rides on it
         ce = ce + cfg.router_aux_loss_coef * moe.load_balancing_loss(
             books, cfg)
     return ce

@@ -1,13 +1,24 @@
 """Sparse expert feed-forward: the routed half of ``models/llama.py``'s block.
 
 A model with experts is a ``LlamaConfig`` whose ``num_experts`` is above 0
-(OLMoE-1B-7B: 64 experts of width 1024, 8 a token, no shared expert). Its
-block is ``llama.py::_layer``: the attention half, the scan, remat, the
+(OLMoE-1B-7B: 64 experts of width 1024, 8 a token, no shared expert;
+LFM2-24B-A2B: 64 of width 1536, 4 a token, after its leading dense layers).
+Its block is ``llama.py::_layer``: the operator half, the scan, remat, the
 head and the loss are the dense model's. This module holds what only the
 routed feed-forward needs: the router, the dispatch, the expert matmuls,
 the load-balancing term, and the expert leaves' initialiser and logical
 axes (the leading ``expert`` dim of the three stacks shards over the mesh's
 ``expert`` axis).
+
+The router's variants are fields of the configuration, one function: the
+scores are a softmax or a sigmoid of the router's logits
+(``router_scores``); the ``K`` experts are chosen on the scores or, with
+``router_bias``, on the scores plus a per-expert bias, a buffer no gradient
+reaches (kept in the parameters' type like every leaf, added in float32),
+while the weights stay the scores without it; the chosen
+weights are renormalised or not (``norm_topk_prob``, over their sum plus
+``router_norm_eps``) and scaled by ``routed_scaling_factor``. The defaults
+are OLMoE's router, to the bit.
 
 The dispatch drops nothing and has no capacity: the ``T x K`` (position,
 expert) pairs are sorted by expert, the rows gathered in that order, and
@@ -50,6 +61,7 @@ import jax.numpy as jnp
 
 from ray_tpu.ops.pallas import grouped_matmul
 
+ROUTER_SCORES = ("softmax", "sigmoid")
 EXPERT_LOGICAL_AXES = {
     "router": ("embed", "expert"),
     "we_gate": ("expert", "embed", "mlp"),
@@ -71,13 +83,16 @@ def in_stack(lp: Dict[str, jax.Array], layers: Dict[str, jax.Array], layer,
                        _MASK: mask})
 
 
-def init_experts(cfg, key: jax.Array) -> Dict[str, jax.Array]:
-    """The router and the three expert stacks, stacked over layers
-    (truncated normal, fan-in scaled, as ``init_llama`` draws the dense
-    leaves). One layer is drawn at a time: at OLMoE's widths a stack is
-    ``[16, 64, 2048, 1024]``, 8.6 GB in float32 on its way to bf16, and a
-    layer of it is 0.5 GB."""
-    h, m, E, L = cfg.hidden, cfg.mlp_hidden, cfg.num_experts, cfg.num_layers
+def init_experts(cfg, key: jax.Array, num_layers: int
+                 ) -> Dict[str, jax.Array]:
+    """The router and the three expert stacks, stacked over ``num_layers``
+    layers (truncated normal, fan-in scaled, as ``init_llama`` draws the
+    dense leaves). One layer is drawn at a time: at OLMoE's widths a stack
+    is ``[16, 64, 2048, 1024]``, 8.6 GB in float32 on its way to bf16, and
+    a layer of it is 0.5 GB. With ``router_bias`` the per-expert bias rides
+    along as zeros, as HuggingFace starts it: a buffer that training's
+    balancing moves and no gradient reaches."""
+    h, m, E, L = cfg.hidden, cfg.mlp_hidden, cfg.num_experts, num_layers
     pd = cfg.param_dtype
 
     def stack(k, shape, fan_in):
@@ -88,12 +103,15 @@ def init_experts(cfg, key: jax.Array) -> Dict[str, jax.Array]:
         return jax.lax.map(one, jax.random.split(k, L))
 
     ks = jax.random.split(key, 4)
-    return {
+    out = {
         "router": stack(ks[0], (h, E), h),
         "we_gate": stack(ks[1], (E, h, m), h),
         "we_up": stack(ks[2], (E, h, m), h),
         "we_down": stack(ks[3], (E, m, h), m),
     }
+    if cfg.router_bias:
+        out["router_bias"] = jnp.zeros((L, E), pd)
+    return out
 
 
 def _kernel_takes(stack: jax.Array) -> bool:
@@ -193,7 +211,7 @@ def expert_ffn(cfg, h: jax.Array, lp: Dict[str, jax.Array]
     """``h [B, S, H]`` (after the block's second norm) -> the routed
     feed-forward's output ``[B, S, H]`` and the router's books of this
     layer: ``pairs [E]`` (how many (position, expert) pairs each expert
-    took), ``prob [E]`` (the router's probability summed over positions)
+    took), ``prob [E]`` (the router's scores summed over positions)
     and ``positions`` (how many were counted), all float32 and all over the
     positions where ``in_stack``'s mask is true (every position without
     one). Padded positions are computed like any other; the mask only
@@ -205,15 +223,31 @@ def expert_ffn(cfg, h: jax.Array, lp: Dict[str, jax.Array]
     E, K = cfg.num_experts, cfg.experts_per_token
     T = B * S
     x = h.reshape(T, H)
+    if cfg.router_scores not in ROUTER_SCORES:
+        raise ValueError(f"router_scores {cfg.router_scores!r}: expected "
+                         + "|".join(ROUTER_SCORES))
     with jax.named_scope("moe_router"):
-        # logits, softmax and the chosen weights in float32; the operands
+        # logits, scores and the chosen weights in float32; the operands
         # are the activations and the router as every other matmul has them
         logits = jnp.einsum("th,he->te", x, lp["router"].astype(dt),
                             preferred_element_type=jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)
-        weights, chosen = jax.lax.top_k(probs, K)            # [T, K]
+        probs = (jax.nn.sigmoid(logits) if cfg.router_scores == "sigmoid"
+                 else jax.nn.softmax(logits, axis=-1))
+        if "router_bias" in lp:
+            # the bias moves the choice and not the weights
+            _, chosen = jax.lax.top_k(
+                probs + jax.lax.stop_gradient(
+                    lp["router_bias"].astype(jnp.float32)), K)
+            weights = jnp.take_along_axis(probs, chosen, axis=-1)
+        else:
+            weights, chosen = jax.lax.top_k(probs, K)        # [T, K]
         if cfg.norm_topk_prob:
-            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+            total = jnp.sum(weights, axis=-1, keepdims=True)
+            if cfg.router_norm_eps:
+                total = total + cfg.router_norm_eps
+            weights = weights / total
+        if cfg.routed_scaling_factor != 1.0:
+            weights = weights * cfg.routed_scaling_factor
     with jax.named_scope("moe_dispatch"):
         flat = chosen.reshape(T * K)
         order = jnp.argsort(flat)                 # stable: pairs by expert

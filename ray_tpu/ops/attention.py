@@ -87,8 +87,10 @@ def attention(
     dispatcher.
 
     ``auto`` on a TPU still takes the reference for what the kernel has no
-    path for (cached decode, lengths off the 128 grid, head dims off the
-    lane width) and says so once per shape; ``flash`` raises there."""
+    path for (cached decode, lengths off the 128 grid, a head dim that is
+    neither a multiple of the 128 lanes nor 64:
+    ``flash_attention.takes_head_dim``) and says so once per shape;
+    ``flash`` raises for cached decode and hands the kernel any shape."""
     if impl == "auto":
         platform = jax.default_backend()
         if platform not in ("tpu", "cpu"):
@@ -97,17 +99,20 @@ def attention(
                 f"not {platform!r}; name an impl")
         impl = "reference"
         if platform == "tpu":
+            from ray_tpu.ops.pallas.flash_attention import takes_head_dim
+
             kernel_takes_it = (
                 q_offset is None and valid_kv_len is None
                 and q.shape[1] == k.shape[1]
-                and q.shape[1] % 128 == 0 and q.shape[3] % 128 == 0)
+                and q.shape[1] % 128 == 0 and takes_head_dim(q.shape[3]))
             if kernel_takes_it:
                 impl = "flash"
             else:
                 warnings.warn(
                     "attention impl 'auto' on TPU: reference path for "
                     f"q{tuple(q.shape)} k{tuple(k.shape)} (cached decode, "
-                    "or a shape off the flash kernel's 128 grid)",
+                    "a length off the flash kernel's 128 grid, or a head "
+                    "dim it does not take)",
                     stacklevel=2)
     if impl == "flash":
         if q_offset is not None or valid_kv_len is not None:

@@ -33,6 +33,14 @@ the forward is the whole length up to 1152. A key length left at 128 by
 that (a prime number of 128s, too long for one block) takes the widest key
 block that fits beside its query block: wide key blocks are what saves the
 rescales. A length that 128 does not divide is refused.
+
+Head dims. A block's last dim is the head's whole width, so a width on the
+128 lanes is whole lane tiles. A head of 64 (LFM2-24B-A2B's) is half a
+lane tile: Mosaic takes a block whose last dim is the array's own, pads it
+to the lane width in VMEM and runs the same kernel, the two matmuls at half
+the MXU's width (``takes_head_dim``; PERF.md, PR 33, has its time beside a
+head of 128). ``tile_vmem_bytes`` is told the head's width and stays above
+Mosaic's figure there too (``tests/test_flash_tiles_v5e.py``).
 """
 
 from __future__ import annotations
@@ -66,6 +74,13 @@ _LANES = 128
 _GRID_BLOCK = 512
 # Scoped VMEM a v5e kernel gets by default; nothing here asks for more.
 VMEM_LIMIT_BYTES = 16 * 2 ** 20
+
+
+def takes_head_dim(head_dim: int) -> bool:
+    """Whether the compiled kernels have run at a head of this width:
+    whole lane tiles, or the 64 of the module docstring. ``attention``'s
+    ``auto`` asks; the interpreted kernels take any width."""
+    return head_dim % _LANES == 0 or head_dim == 64
 
 
 def tile_vmem_bytes(block_q: int, block_k: int, *, head_dim: int = 128,

@@ -14,7 +14,7 @@ bucket keep the compile-cache menu finite. It ends in the next token of
 each row (``models.llama.llama_next_token``: the head meets only each
 row's last position, the argmax runs on the device), so a step brings
 ``bucket`` int32s to the host (and, from a model with experts, two
-float32 a layer of its routers' load) and the ``[bucket, S, vocab]``
+float32 a routed layer of its routers' load) and the ``[bucket, S, vocab]``
 logits are never made. ``LlamaGenerator._fwd`` is the same program
 followed by the head over every position, for callers that want the
 logits themselves: it compiles what ``_step`` runs, so warming a shape
@@ -91,7 +91,8 @@ class LlamaGenerator:
             if isinstance(config, str) else config
         # adapt only the attention q/v projections: the cheap standard
         # LoRA target set, and enough for adapters to produce distinct
-        # generations
+        # generations; the stacks are over the attention layers alone in a
+        # model that has other operators (``init_lora``)
         self._lcfg = LoraConfig(rank=lora_rank, targets=("wq", "wv"))
         # one jitted init: op-by-op it is a compile per parameter leaf
         self._params = jax.jit(lambda k: init_llama(self._cfg, k))(
@@ -151,11 +152,9 @@ class LlamaGenerator:
         # B starts at 0 in real LoRA (adapted == base); nudge it so
         # distinct adapters actually generate distinct tokens in demos
         k2 = jax.random.split(key, 1)[0]
-        lora["layers"] = {
-            name: {"a": ab["a"],
-                   "b": jax.random.normal(k2, ab["b"].shape,
-                                          ab["b"].dtype) * 0.02}
-            for name, ab in lora["layers"].items()}
+        lora = jax.tree_util.tree_map_with_path(
+            lambda path, leaf: leaf if path[-1].key == "a" else
+            jax.random.normal(k2, leaf.shape, leaf.dtype) * 0.02, lora)
         with self._adapter_lock:
             self._adapters[model_id] = lora
             while len(self._adapters) > self._max_adapters:
@@ -257,13 +256,17 @@ class LlamaGenerator:
     def engine_stats(self) -> Dict[str, Any]:
         """The engine's counters, and ``_step``'s own: ``host_bytes`` (the
         bytes of device results brought to the host: 4 a row a step, and
-        8 a layer from a model with experts), ``positions_computed`` (rows
+        8 a routed layer from a model with experts), ``positions_computed`` (rows
         x padded length) and ``positions_live`` (the rows' own tokens
         among them), ``expert_pairs_fullest`` and ``expert_pairs_mean``
         (over live positions, the (position, expert) pairs of the fullest
-        and of the mean expert, summed over steps and layers; 0 for a
-        model without experts)."""
-        return {**self.engine.stats(), **self._counts}
+        and of the mean expert, summed over steps and over the layers that
+        have routed experts; 0 for a model without experts); and
+        ``layer_kinds``, how many layers of each kind this replica serves
+        (``LlamaConfig.kind_counts``: ``attention_dense`` alone for a dense
+        decoder)."""
+        return {**self.engine.stats(), **self._counts,
+                "layer_kinds": self._cfg.kind_counts()}
 
     # ------------------------------------------- what a benchmark asks for
     def warm_step_programs(self, seq_len: int) -> None:

@@ -81,13 +81,46 @@ def test_tiles_compile_within_their_reckoning(seq, backward, one_chip,
     assert max(used) <= reckoned <= fa.VMEM_LIMIT_BYTES
 
 
-# serve_olmoe_chat's expert matmuls: 8 rows x 8 experts a token x the
-# shortest, the median and the longest bucket, read from the 16 x 64 stack
+# a head of 64, half a lane tile (serve_lfm2_rag's 32 query heads on 8):
+# the block's last dim is the whole head, and Mosaic takes it
+@pytest.mark.parametrize("backward", [False, True])
+@pytest.mark.parametrize("seq", [128, 384, 1024, 1408])
+def test_a_head_of_64_compiles_within_its_reckoning(seq, backward, one_chip,
+                                                    compiled_for_tpu):
+    q = jax.ShapeDtypeStruct((1, 4, seq, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    k = jax.ShapeDtypeStruct((1, 1, seq, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    lse = jax.ShapeDtypeStruct((1, 4, seq, 128), jnp.float32,
+                               sharding=one_chip)
+    if backward:
+        compiled = jax.jit(
+            lambda q, k, v, o, lse, do: fa._flash_bwd(
+                q, k, v, o, lse, do, causal=True)
+        ).lower(q, k, k, q, lse, q).compile()
+    else:
+        compiled = jax.jit(
+            lambda q, k, v: fa._flash_fwd(q, k, v, causal=True)
+        ).lower(q, k, k).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    tile = fa.flash_tiles(seq, seq, head_dim=64, backward=backward)
+    reckoned = fa.tile_vmem_bytes(*tile, head_dim=64, backward=backward)
+    # the kernels' figures; the transposes round them are XLA's own and
+    # hold a few hundred KB at this size
+    assert max(_scoped_vmem(compiled)) <= reckoned <= fa.VMEM_LIMIT_BYTES
+
+
+# the serving cells' expert matmuls at the shortest, the median and the
+# longest bucket, read from a layers x 64 stack: serve_olmoe_chat's (8
+# experts a token, width 1024, to 1152) and serve_lfm2_rag's (4, 1536, 1408)
 @pytest.mark.parametrize("fused", [True, False])
-@pytest.mark.parametrize("seq", [128, 512, 1152])
-def test_grouped_matmul_compiles_within_its_reckoning(seq, fused, one_chip,
+@pytest.mark.parametrize("seq, pairs, width", [
+    (128, 8, 1024), (512, 8, 1024), (1152, 8, 1024),
+    (128, 4, 1536), (384, 4, 1536), (1408, 4, 1536)])
+def test_grouped_matmul_compiles_within_its_reckoning(seq, pairs, width, fused,
+                                                      one_chip,
                                                       compiled_for_tpu):
-    rows, experts, hidden, width = 8 * 8 * seq, 64, 2048, 1024
+    rows, experts, hidden = 8 * pairs * seq, 64, 2048
     k, n = (hidden, width) if fused else (width, hidden)
     out = jnp.bfloat16 if fused else jnp.float32
     x = jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=one_chip)

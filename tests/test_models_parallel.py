@@ -2,6 +2,8 @@
 sharded init, train-step convergence, decode-cache equivalence, and the
 full multi-axis (fsdp, seq, tensor) dryrun."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -104,6 +106,43 @@ class TestLlama:
                 positions=pos)
             np.testing.assert_allclose(
                 logits[:, 0], full[:, t], atol=3e-2, rtol=3e-2)
+
+    def test_a_pattern_of_conv_and_attention_layers(self):
+        """A dense model with a layer pattern and its own head: the tree
+        goes by kind, the runs are scanned in order under every remat
+        policy, and prefill + decode through both kinds of state is the
+        full forward."""
+        from ray_tpu.models.llama import init_decode_state
+
+        cfg = dataclasses.replace(
+            LlamaConfig.tiny(), num_layers=5, dtype=jnp.float32,
+            layer_types=("conv", "conv", "full_attention", "conv",
+                         "full_attention"))
+        assert cfg.layer_runs() == (
+            ("conv_dense", 0, 2), ("attention_dense", 0, 1),
+            ("conv_dense", 2, 1), ("attention_dense", 1, 1))
+        params = init_llama(cfg, jax.random.key(0))
+        assert sorted(params["layers"]) == ["attention_dense", "conv_dense"]
+        assert params["layers"]["conv_dense"]["conv_w"].shape == (3, 128, 3)
+        assert "lm_head" in params
+        n = sum(x.size for x in jax.tree.leaves(params))
+        assert n == cfg.num_params()
+        tokens = jax.random.randint(jax.random.key(1), (2, 12), 0,
+                                    cfg.vocab_size)
+        full = llama_forward(params, tokens, cfg)
+        for policy in ("dots", "full", "mixed:1", "mixed:3"):
+            c = dataclasses.replace(cfg, remat=True, remat_policy=policy)
+            np.testing.assert_allclose(llama_forward(params, tokens, c),
+                                       full, atol=1e-5, rtol=1e-5)
+        state = init_decode_state(cfg, 2, 16)
+        logits, state = llama_decode(params, tokens[:, :8], cfg, state,
+                                     jnp.int32(0))
+        np.testing.assert_allclose(logits, full[:, :8], atol=1e-4, rtol=1e-4)
+        for t in range(8, 12):
+            logits, state = llama_decode(params, tokens[:, t:t + 1], cfg,
+                                         state, jnp.int32(t))
+            np.testing.assert_allclose(logits[:, 0], full[:, t], atol=1e-4,
+                                       rtol=1e-4)
 
     def test_param_count(self):
         cfg = LlamaConfig.tiny()

@@ -144,6 +144,47 @@ class TestFlashOffTheOldGrid:
             assert float(jnp.abs(a - b).max()) < 1e-4, n
 
 
+class TestFlashHeadDim64:
+    """A head of 64, half the lane width (LFM2-24B-A2B's: 4 query heads on
+    each key/value head), interpreted: the block's last dim is the whole
+    head. What Mosaic makes of it is in ``test_flash_tiles_v5e.py``."""
+
+    @pytest.mark.parametrize("seq", [384, 1024, 1408])
+    def test_forward_matches_reference(self, seq):
+        from ray_tpu.ops.pallas.flash_attention import flash_attention
+
+        q, k, v = _rand_qkv(jax.random.key(seq), B=1, S=seq, H=4, KVH=1,
+                            D=64)
+        out = flash_attention(q, k, v, True)
+        ref = reference_attention(q, k, v, causal=True)
+        np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+
+    def test_gradient_matches_reference(self):
+        from ray_tpu.ops.pallas.flash_attention import flash_attention
+
+        q, k, v = _rand_qkv(jax.random.key(7), B=1, S=384, H=4, KVH=1, D=64)
+        g = jax.random.normal(jax.random.key(8), q.shape, q.dtype)
+        gr = jax.grad(lambda *a: (reference_attention(
+            *a, causal=True) * g).sum(), argnums=(0, 1, 2))(q, k, v)
+        gf = jax.grad(lambda *a: (flash_attention(*a, True) * g).sum(),
+                      argnums=(0, 1, 2))(q, k, v)
+        for a, b, n in zip(gr, gf, "qkv"):
+            assert float(jnp.abs(a - b).max()) < 1e-4, n
+
+    def test_the_tile_and_the_dims_the_kernel_takes(self):
+        from ray_tpu.ops.pallas.flash_attention import (
+            flash_tiles, takes_head_dim)
+
+        assert [d for d in (32, 64, 96, 128, 192, 256)
+                if takes_head_dim(d)] == [64, 128, 256]
+        # a narrower head leaves room for one block of the longest
+        # serving bucket, which a head of 128 cuts into (128, 1408)
+        assert flash_tiles(1408, 1408, head_dim=64) == (1408, 1408)
+        assert flash_tiles(1408, 1408, head_dim=128) == (128, 1408)
+        for seq in range(128, 1153, 128):
+            assert flash_tiles(seq, seq, head_dim=64) == flash_tiles(seq, seq)
+
+
 class TestRingAttention:
     def test_matches_reference(self):
         from jax.sharding import Mesh, PartitionSpec as P
