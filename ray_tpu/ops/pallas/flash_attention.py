@@ -20,19 +20,27 @@ contributions in VMEM scratch — so GQA models (Llama-3-class) train under
 flash instead of falling back to reference attention.
 
 Tiles. ``flash_tiles`` cuts the two lengths into blocks for the forward and
-the backward alike, from the lengths and ``tile_vmem_bytes`` alone; no
-argument, field or variable chooses a tile. On a v5e a grid step costs more
-in what surrounds its matmuls (the rescale of the running max, sum and
-accumulator at every key block, and the step's own overhead) than in the
-matmuls, so few large blocks win for as long as they fit VMEM: the forward
-at 1152, 8 x 16 heads, takes 5.05 ms at 128 x 128, 1.62 at 384 x 384 and
-0.61 as one block. The rule, for each length: one that 512 divides is cut
-into blocks of 512, the tile training runs at; any other takes the largest
-of its divisors (in multiples of 128) whose square tile fits VMEM, which in
-the forward is the whole length up to 1152. A key length left at 128 by
-that (a prime number of 128s, too long for one block) takes the widest key
-block that fits beside its query block: wide key blocks are what saves the
-rescales. A length that 128 does not divide is refused.
+the backward alike, from the lengths, the head's width and
+``tile_vmem_bytes`` alone; no argument, field or variable chooses a tile.
+On a v5e a grid step costs more in what surrounds its matmuls (the rescale
+of the running max, sum and accumulator at every key block, and the step's
+own overhead) than in the matmuls, so few large blocks win for as long as
+they fit VMEM: the forward at 1152, 8 x 16 heads, takes 5.05 ms at
+128 x 128, 1.62 at 384 x 384 and 0.61 as one block. One rule, for every
+length: the largest of its divisors (in multiples of 128) whose square tile
+fits VMEM. In the forward that is the whole length up to 1152 and
+1024 x 1024 at 2048 and 4096; the backward's two kernels share one tile,
+hold more a row, and get the whole length up to 1024 and 1024 x 1024 at
+2048 and 4096. At training's shape (2 x 32 heads on 8 of 128 at 4096;
+PERF.md, PR 34) a layer takes, at 512 x 512 and at 1024 x 1024: forward
+6.10 and 3.20 ms, dq 3.85 and 3.48, dk/dv 6.08 and 4.98. No rectangle of
+512 and 1024 beats the square by more than 1 %, and with more scoped VMEM
+than the default neither 2048 x 2048 (3.41) nor one block of 4096 (3.26)
+beats it in the forward: past 1024 the causal skip loses more blocks than
+the rescales save. A key length left at 128 by the rule (a prime number of
+128s, too long for one block) takes the widest key block that fits beside
+its query block: wide key blocks are what saves the rescales. A length
+that 128 does not divide is refused.
 
 Head dims. A block's last dim is the head's whole width, so a width on the
 128 lanes is whole lane tiles. A head of 64 (LFM2-24B-A2B's) is half a
@@ -71,7 +79,6 @@ def _interpret() -> bool:
 
 
 _LANES = 128
-_GRID_BLOCK = 512
 # Scoped VMEM a v5e kernel gets by default; nothing here asks for more.
 VMEM_LIMIT_BYTES = 16 * 2 ** 20
 
@@ -87,24 +94,30 @@ def tile_vmem_bytes(block_q: int, block_k: int, *, head_dim: int = 128,
                     backward: bool = False) -> int:
     """Upper reckoning of the VMEM one grid step holds at a tile, every
     element taken at 4 bytes: each block of an operand or a result in its
-    two pipeline buffers, the float32 casts and the scratch once, and the
-    ``[block_q, block_k]`` float32 values that outlive a strip (the
-    forward's ``s``; the backward's ``p`` and ``ds``, of its two kernels
-    the larger on each side). Mosaic reports less at every tile tried
-    (``tests/test_flash_tiles_v5e.py`` compiles for a described v5e)."""
+    two pipeline buffers, the float32 casts and the scratch once, and one
+    ``[block_q, block_k]`` float32 tile for the scores and what is made of
+    them (Mosaic works through ``s``, ``p``, ``dp`` and ``ds`` in strips
+    and holds less than one such tile). The backward's figure is the
+    larger of its two kernels'. Mosaic reports less at every tile tried
+    (``tests/test_flash_tiles_v5e.py`` compiles for a described v5e): at
+    1024 x 1024, 10.0 MB of the 13.1 reckoned in the forward, 9.8 (dq)
+    and 11.1 (dk/dv) of 15.7 in the backward."""
     d, lanes = 4 * head_dim, 4 * _LANES
-    if backward:
-        # a query row: q, do and dq twice, the casts of q and do, dq's
-        # scratch (9 d); lse and delta twice (4 lanes). A key row: k, v,
-        # dk and dv twice, the casts of k and v, dk's and dv's scratch
-        q_row, k_row, tiles = 9 * d + 4 * lanes, 12 * d, 2
-    else:
+    tile = 4 * block_q * block_k
+    if not backward:
         # a query row: q and o twice, q's cast, acc and its rescaled copy
         # (7 d); lse twice, m and l (4 lanes). A key row: k and v twice,
         # their casts
-        q_row, k_row, tiles = 7 * d + 4 * lanes, 6 * d, 1
-    return (4 * tiles * block_q * block_k + block_q * q_row
-            + block_k * k_row)
+        return tile + block_q * (7 * d + 4 * lanes) + block_k * 6 * d
+    # dq. A query row: q, do and dq twice, the casts of q and do, dq's
+    # scratch (9 d); lse and delta twice (4 lanes). A key row: k and v
+    # twice, their casts
+    dq = block_q * (9 * d + 4 * lanes) + block_k * 6 * d
+    # dk/dv. A query row: q and do twice, their casts; lse and delta twice.
+    # A key row: k, v, dk and dv twice, the casts of k and v, dk's and
+    # dv's scratch
+    dkv = block_q * (6 * d + 4 * lanes) + block_k * 12 * d
+    return tile + max(dq, dkv)
 
 
 def _divisors(n: int) -> List[int]:
@@ -125,8 +138,6 @@ def flash_tiles(sq: int, skv: int, *, head_dim: int = 128,
                                backward=backward) <= VMEM_LIMIT_BYTES
 
     def block(n: int) -> int:
-        if n % _GRID_BLOCK == 0:
-            return _GRID_BLOCK
         return max(b for b in _divisors(n) if fits(b, b))
 
     block_q, block_k = block(sq), block(skv)

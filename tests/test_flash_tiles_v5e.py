@@ -54,9 +54,11 @@ def _scoped_vmem(compiled) -> list:
         r'"offset":"0","size":"(\d+)"', compiled.as_text())]
 
 
-# the serving buckets, training's length, and a prime number of 128s
+# the serving buckets, a prime number of 128s, and the lengths past one
+# block: 1024 x 1024 at 2048 and at training's 4096
 @pytest.mark.parametrize("backward", [False, True])
-@pytest.mark.parametrize("seq", [384, 640, 768, 896, 1152, 1408, 4096])
+@pytest.mark.parametrize("seq", [384, 640, 768, 896, 1024, 1152, 1408, 2048,
+                                 4096])
 def test_tiles_compile_within_their_reckoning(seq, backward, one_chip,
                                               compiled_for_tpu):
     q = jax.ShapeDtypeStruct((1, 2, seq, 128), jnp.bfloat16,

@@ -80,9 +80,9 @@ class TestFlashTiles:
     @pytest.mark.parametrize("backward", [False, True])
     @pytest.mark.parametrize("seq, tile", [
         (128, (128, 128)), (256, (256, 256)), (512, (512, 512)),
-        (1024, (512, 512)), (2048, (512, 512)), (4096, (512, 512))])
-    def test_lengths_on_the_old_grid_keep_their_tiles(self, seq, tile,
-                                                      backward):
+        (1024, (1024, 1024)), (2048, (1024, 1024)), (4096, (1024, 1024))])
+    def test_one_rule_cuts_the_lengths_512_divides_too(self, seq, tile,
+                                                       backward):
         from ray_tpu.ops.pallas.flash_attention import flash_tiles
 
         assert flash_tiles(seq, seq, backward=backward) == tile
@@ -98,8 +98,8 @@ class TestFlashTiles:
     def test_each_length_is_cut_by_itself(self):
         from ray_tpu.ops.pallas.flash_attention import flash_tiles
 
-        assert flash_tiles(384, 1024) == (384, 512)
-        assert flash_tiles(2048, 640) == (512, 640)
+        assert flash_tiles(384, 1024) == (384, 1024)
+        assert flash_tiles(2048, 640) == (1024, 640)
 
     @pytest.mark.parametrize("seq", [64, 192, 1000])
     def test_a_length_128_does_not_divide_is_refused(self, seq):
@@ -113,13 +113,16 @@ class TestFlashTiles:
             flash_attention(q, k, v, True)
 
 
-class TestFlashOffTheOldGrid:
+class TestFlashAtTheRulesTiles:
     """Interpret mode, grouped heads (2 query heads on 1 key/value head),
-    D 128, B 1: the serving buckets the old rule left at block 128, and
-    1024 and 1408 for the paths with more than one block (the running
-    maximum and sum folded across key blocks, the causal skip)."""
+    D 128, B 1: the serving buckets the rule before PR 29 left at block
+    128; 1024 as one block in both passes; 1408 for a forward with more
+    than one key block; and 2048, the shortest length 512 divides that the
+    one rule cuts into more than one block in every pass (1024 x 1024, the
+    tile training's 4096 runs: the running maximum and sum folded across
+    key blocks, dq and dk/dv summed across blocks, the causal skip)."""
 
-    @pytest.mark.parametrize("seq", [384, 640, 896, 1152, 1024, 1408])
+    @pytest.mark.parametrize("seq", [384, 640, 896, 1152, 1024, 1408, 2048])
     def test_forward_matches_reference(self, seq):
         from ray_tpu.ops.pallas.flash_attention import flash_attention
 
@@ -129,7 +132,7 @@ class TestFlashOffTheOldGrid:
         ref = reference_attention(q, k, v, causal=True)
         np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
-    @pytest.mark.parametrize("seq", [384, 640, 1024])
+    @pytest.mark.parametrize("seq", [384, 640, 1024, 2048])
     def test_gradient_matches_reference(self, seq):
         from ray_tpu.ops.pallas.flash_attention import flash_attention
 
