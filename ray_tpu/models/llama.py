@@ -19,6 +19,14 @@ Design notes (why this is not a torch translation):
   ``params["layers"][kind]``. There is one block (``_layer``, which reads a
   layer's kind off its leaves) and one forward, which scans each run of
   like layers in the configuration's order (``LlamaConfig.layer_runs``).
+- A third operator, ``"latent_attention"`` (DeepSeek-V2's MLA;
+  ``_latent_attention``): queries through a low-rank pair with a norm
+  between, keys and values decompressed from one normed latent row a
+  position, a rotary part of the key that every head shares, heads wider
+  in the query and key than in the value, YaRN's frequencies and softmax
+  scale (``RopeScaling``). A forward pass decompresses and calls
+  ``ops.attention`` with the two widths; ``llama_decode`` keeps the latent
+  row and the rotated shared key alone and attends in the absorbed form.
 - Attention dispatches to ``ray_tpu.ops`` (Pallas flash attention on TPU,
   reference einsum path elsewhere; ring attention when the seq axis > 1).
 - bfloat16 activations / fp32 params+optimizer by default: MXU-native.
@@ -31,11 +39,13 @@ _finetuning); here the model is framework-native.
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ray_tpu.models import moe
 from ray_tpu.ops.attention import attention
@@ -53,6 +63,56 @@ def _ring_seq_attention(q, k, v):
         partial(ring_attention, axis_name="seq", causal=True),
         in_specs=(qs, qs, qs), out_specs=qs, check_vma=False)
     return fn(q, k, v)
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN, as DeepSeek-V2's ``rope_scaling`` gives it: each rotary
+    frequency a blend of the extrapolated one (``theta``'s own) and the
+    interpolated one (that over ``factor``) by a linear ramp between the
+    two correction dims, the dims that turn ``beta_fast`` and ``beta_slow``
+    times over ``original_max_position_embeddings`` positions; cos and sin
+    times ``mscale(factor, mscale) / mscale(factor, mscale_all_dim)``, and
+    the softmax scale times ``mscale(factor, mscale_all_dim) ** 2``."""
+    factor: float = 1.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    @staticmethod
+    def _mscale(factor: float, mscale: float) -> float:
+        return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+    def rotary_amplitude(self) -> float:
+        """What cos and sin are multiplied by."""
+        return (self._mscale(self.factor, self.mscale)
+                / self._mscale(self.factor, self.mscale_all_dim))
+
+    def softmax_amplitude(self) -> float:
+        """What the softmax scale is multiplied by: ``m ** 2``."""
+        return self._mscale(self.factor, self.mscale_all_dim) ** 2
+
+    def inv_freq(self, dim: int, theta: float) -> np.ndarray:
+        """The ``dim / 2`` rotary frequencies, float32."""
+        exponent = np.arange(0, dim, 2, dtype=np.float32) / dim
+        extrapolated = 1.0 / theta ** exponent
+        interpolated = 1.0 / (self.factor * theta ** exponent)
+
+        def correction_dim(rotations: float) -> float:
+            return (dim * math.log(self.original_max_position_embeddings
+                                   / (rotations * 2 * math.pi))
+                    / (2 * math.log(theta)))
+
+        low = max(math.floor(correction_dim(self.beta_fast)), 0)
+        high = min(math.ceil(correction_dim(self.beta_slow)), dim - 1)
+        if low == high:
+            high += 0.001  # the ramp's own guard against a zero span
+        ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                       / (high - low), 0.0, 1.0)
+        return (interpolated * ramp + extrapolated * (1.0 - ramp)
+                ).astype(np.float32)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -106,6 +166,32 @@ class LlamaConfig:
     dense_mlp_hidden: int = 0
     qk_head_norm: bool = False
     tie_embeddings: bool = False
+    # Latent attention and what comes with it (DeepSeek-V2 has all of it).
+    # layer_types' third operator, "latent_attention": the queries are
+    # RMSNorm(x W_qa [q_lora_rank]) W_qb, num_heads heads of
+    # qk_nope_head_dim + qk_rope_head_dim; x W_kva gives kv_lora_rank
+    # latent dims, normed, and qk_rope_head_dim rotary dims that are every
+    # head's rotary key; the latent row W_kvb gives each head its
+    # qk_nope_head_dim of key and v_head_dim of value (head_dim and
+    # num_kv_heads are not read). rope_scaling: YaRN over the rotary dims
+    # (None: theta's own frequencies, scale width ** -0.5).
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_scaling: Optional[RopeScaling] = None
+    # The routed feed-forward's other parts (models/moe.py's docstring).
+    # num_shared_experts: a dense SwiGLU of that many times mlp_hidden
+    # beside the routed experts, every position's. router_groups > 0: the
+    # group-limited choice, the experts in that many groups of which a
+    # position's best router_topk_groups stay. experts_held (first,
+    # count): the experts of each routed layer that live here, one chip's
+    # share; the router stays num_experts wide (None: all of them).
+    num_shared_experts: int = 0
+    router_groups: int = 0
+    router_topk_groups: int = 0
+    experts_held: Optional[Tuple[int, int]] = None
 
     @staticmethod
     def llama2_7b_smoke() -> "LlamaConfig":
@@ -131,17 +217,18 @@ class LlamaConfig:
                            head_dim=32, max_seq_len=128, remat=False)
 
     def layer_kinds(self) -> Tuple[str, ...]:
-        """Each layer's kind, ``<operator>_<feed-forward>``: ``attention``
-        or ``conv``, then ``routed`` (experts) or ``dense``."""
+        """Each layer's kind, ``<operator>_<feed-forward>``: ``attention``,
+        ``conv`` or ``latent``, then ``routed`` (experts) or ``dense``."""
         ops = tuple(self.layer_types) or ("full_attention",) * self.num_layers
         if len(ops) != self.num_layers:
             raise ValueError(f"layer_types names {len(ops)} layers, "
                              f"num_layers is {self.num_layers}")
-        names = {"full_attention": "attention", "conv": "conv"}
+        names = {"full_attention": "attention", "conv": "conv",
+                 "latent_attention": "latent"}
         unknown = sorted(set(ops) - set(names))
         if unknown:
             raise ValueError(f"layer_types {unknown}: expected "
-                             "'full_attention'|'conv'")
+                             "'full_attention'|'conv'|'latent_attention'")
         return tuple(
             names[op] + ("_routed" if self.num_experts
                          and i >= self.num_dense_layers else "_dense")
@@ -184,9 +271,17 @@ class LlamaConfig:
         q, kv = self.num_heads * self.head_dim, self.num_kv_heads * self.head_dim
         norms = ((q + kv) if self.qk_norm
                  else 2 * self.head_dim if self.qk_head_norm else 0)
+        nh, qr, kvr = self.num_heads, self.q_lora_rank, self.kv_lora_rank
+        nope, rope, vd = (self.qk_nope_head_dim, self.qk_rope_head_dim,
+                          self.v_head_dim)
+        held = self.experts_held[1] if self.experts_held else E
         half = {"attention": h * (q + 2 * kv) + q * h + norms,
                 "conv": 4 * h * h + h * self.conv_kernel,
-                "routed": (E * 3 * h * self.mlp_hidden + h * E
+                "latent": (h * qr + qr + qr * nh * (nope + rope)
+                           + h * (kvr + rope) + kvr
+                           + kvr * nh * (nope + vd) + nh * vd * h),
+                "routed": ((held + self.num_shared_experts) * 3 * h
+                           * self.mlp_hidden + h * E
                            + (E if self.router_bias else 0)),
                 "dense": 3 * h * self.dense_width()}
         layers = sum(
@@ -357,6 +452,12 @@ def _kind_logical_axes(cfg: LlamaConfig, kind: str) -> Dict[str, Any]:
                      wo=("heads", "head_dim", "embed"))
         if cfg.qk_norm or cfg.qk_head_norm:
             layer.update(q_norm=("norm",), k_norm=("norm",))
+    elif operator == "latent":  # the ranks stay whole on every device
+        layer.update(wq_a=("embed", None), q_a_norm=("norm",),
+                     wq_b=(None, "heads", "head_dim"),
+                     wkv_a=("embed", None), kv_a_norm=("norm",),
+                     wkv_b=(None, "heads", "head_dim"),
+                     wo=("heads", "head_dim", "embed"))
     else:  # the gated short convolution: in-projection, taps, out
         layer.update(conv_in=("embed", "mlp"), conv_w=("mlp", None),
                      conv_out=("mlp", "embed"))
@@ -364,6 +465,8 @@ def _kind_logical_axes(cfg: LlamaConfig, kind: str) -> Dict[str, Any]:
         layer.update(moe.EXPERT_LOGICAL_AXES)
         if cfg.router_bias:
             layer.update(router_bias=("expert",))
+        if cfg.num_shared_experts:
+            layer.update(moe.SHARED_LOGICAL_AXES)
     else:
         layer.update(w_gate=("embed", "mlp"), w_up=("embed", "mlp"),
                      w_down=("mlp", "embed"))
@@ -407,6 +510,27 @@ def init_llama(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
                 "wk": norm_init((L, h, nkv, hd), ks[1], h),
                 "wv": norm_init((L, h, nkv, hd), ks[2], h),
                 "wo": norm_init((L, nh, hd, h), ks[3], nh * hd),
+            }
+        elif operator == "latent":
+            qr, kvr = cfg.q_lora_rank, cfg.kv_lora_rank
+            nope, rope, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                              cfg.v_head_dim)
+            kq, kk = jax.random.split(ks[1])
+
+            def a_layer_at_a_time(shape, k, fan_in):
+                # as moe.init_experts draws its stacks: a float32 draw of
+                # all layers' wo on its way to bf16 is gigabytes
+                return jax.lax.map(lambda lk: norm_init(shape, lk, fan_in),
+                                   jax.random.split(k, L))
+
+            layers = {
+                "wq_a": a_layer_at_a_time((h, qr), ks[0], h),
+                "q_a_norm": jnp.ones((L, qr), pd),
+                "wq_b": a_layer_at_a_time((qr, nh, nope + rope), kq, qr),
+                "wkv_a": a_layer_at_a_time((h, kvr + rope), kk, h),
+                "kv_a_norm": jnp.ones((L, kvr), pd),
+                "wkv_b": a_layer_at_a_time((kvr, nh, nope + vd), ks[2], kvr),
+                "wo": a_layer_at_a_time((nh, vd, h), ks[3], nh * vd),
             }
         else:
             layers = {
@@ -462,6 +586,16 @@ def _rms_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
     return (x * w.astype(jnp.float32)).astype(dt)
 
 
+def _rotate_pairs(x: jax.Array, cos: jax.Array, sin: jax.Array
+                  ) -> jax.Array:
+    """Rotate pairs (d, d + D/2) of the last dim — llama convention."""
+    half = x.shape[-1] // 2
+    x1, x2 = x[..., :half], x[..., half:]
+    out = jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return out.astype(x.dtype)
+
+
 def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     """x: [B, S, H, D]; rotate pairs (d, d + D/2) — llama convention."""
     d = x.shape[-1]
@@ -470,10 +604,108 @@ def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     angles = positions[..., None].astype(jnp.float32) * freq  # [B,S,half]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    out = jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-    return out.astype(x.dtype)
+    return _rotate_pairs(x, cos, sin)
+
+
+def _yarn_rope(x: jax.Array, positions: jax.Array, theta: float,
+               scaling: Optional[RopeScaling]) -> jax.Array:
+    """``_rope`` at ``scaling``'s frequencies and amplitude, of ``x [B, S,
+    H, D]`` or, one row a position, ``[B, S, D]``; None is theta's own."""
+    heads = x if x.ndim == 4 else x[:, :, None, :]
+    if scaling is None:
+        return _rope(heads, positions, theta).reshape(x.shape)
+    angles = (positions[..., None].astype(jnp.float32)
+              * scaling.inv_freq(x.shape[-1], theta))       # [B,S,half]
+    amplitude = scaling.rotary_amplitude()
+    return _rotate_pairs(heads, (jnp.cos(angles) * amplitude)[:, :, None, :],
+                         (jnp.sin(angles) * amplitude)[:, :, None, :]
+                         ).reshape(x.shape)
+
+
+def _pairs_apart(w: jax.Array) -> jax.Array:
+    """A projection's rotary columns from the interleaved order the source
+    stores them in, ``(x0, y0, x1, y1, ...)``, to ``(x0, x1, ..., y0, y1,
+    ...)``: what HuggingFace's ``apply_rotary_pos_emb`` does to the
+    activations before its rotate-half, done to the weight's columns, which
+    is the same product in another order and costs a pass over a weight
+    where that costs one over the activations."""
+    pairs = w.reshape(w.shape[:-1] + (w.shape[-1] // 2, 2))
+    return jnp.concatenate([pairs[..., 0], pairs[..., 1]], axis=-1)
+
+
+def latent_softmax_scale(cfg: LlamaConfig) -> float:
+    """``(qk_nope_head_dim + qk_rope_head_dim) ** -0.5``, times YaRN's
+    ``m ** 2`` under a ``rope_scaling``."""
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    if cfg.rope_scaling is not None:
+        scale *= cfg.rope_scaling.softmax_amplitude()
+    return scale
+
+
+def _latent_attention(cfg: LlamaConfig, u: jax.Array,
+                      lp: Dict[str, jax.Array], positions: jax.Array,
+                      state: Optional[jax.Array] = None,
+                      cache_index: Optional[jax.Array] = None):
+    """Latent attention (DeepSeek-V2's MLA) on the normed input ``u [B, S,
+    H]`` -> (its output ``[B, S, H]``, the state after it or None).
+    ``c_q = RMSNorm(u W_qa)``; ``[q_nope | q_pe] = c_q W_qb`` a head;
+    ``[c_kv | k_pe] = u W_kva``, ``c_kv = RMSNorm(c_kv)``; ``[k_nope | v] =
+    c_kv W_kvb`` a head; the rotary parts rotated (``k_pe`` is one row a
+    position, every head's); causal softmax of ``(q_nope k_nope^T + q_pe
+    k_pe^T) * latent_softmax_scale``; the heads' values through ``W_o``.
+
+    Without a state the latent rows are decompressed to every head and
+    ``ops.attention`` is handed the two widths. ``state [B, max_len,
+    kv_lora_rank + qk_rope_head_dim]`` is all a decode keeps of a position,
+    the normed ``c_kv`` and the rotated ``k_pe``: the new rows are written
+    at ``cache_index`` and attention runs in the absorbed form, ``q_nope
+    W_kvb[k]^T`` against ``c_kv`` itself and the weighted ``c_kv`` through
+    ``W_kvb[v]``, so no key or value of a head is ever made."""
+    dt = cfg.dtype
+    nope, kvr = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    scale = latent_softmax_scale(cfg)
+    with jax.named_scope("latent_attention"):
+        c_q = _rms_norm(jnp.einsum("bsh,hr->bsr", u, lp["wq_a"].astype(dt)),
+                        lp["q_a_norm"], cfg.rms_eps)
+        wq_b, wkv_a = lp["wq_b"].astype(dt), lp["wkv_a"].astype(dt)
+        wkv_b = lp["wkv_b"].astype(dt)
+        q_nope = jnp.einsum("bsr,rnd->bsnd", c_q, wq_b[..., :nope])
+        q_pe = _yarn_rope(
+            jnp.einsum("bsr,rnd->bsnd", c_q, _pairs_apart(wq_b[..., nope:])),
+            positions, cfg.rope_theta, cfg.rope_scaling)
+        c_kv = _rms_norm(jnp.einsum("bsh,hr->bsr", u, wkv_a[:, :kvr]),
+                         lp["kv_a_norm"], cfg.rms_eps)
+        k_pe = _yarn_rope(
+            jnp.einsum("bsh,hr->bsr", u, _pairs_apart(wkv_a[:, kvr:])),
+            positions, cfg.rope_theta, cfg.rope_scaling)
+        q_nope = constrain(q_nope, ("batch", "seq", "heads", None))
+        q_pe = constrain(q_pe, ("batch", "seq", "heads", None))
+        if state is None:
+            k_nope = jnp.einsum("bsr,rnd->bsnd", c_kv, wkv_b[..., :nope])
+            v = jnp.einsum("bsr,rnd->bsnd", c_kv, wkv_b[..., nope:])
+            k_nope = constrain(k_nope, ("batch", "seq", "heads", None))
+            out = attention(q_nope, k_nope, v, impl=cfg.attn_impl,
+                            causal=True, q_rope=q_pe, k_rope=k_pe,
+                            scale=scale)
+        else:
+            state = jax.lax.dynamic_update_slice_in_dim(
+                state, jnp.concatenate([c_kv, k_pe], axis=-1).astype(
+                    state.dtype), cache_index, axis=1)
+            c_all, pe_all = state[..., :kvr], state[..., kvr:]
+            q_abs = jnp.einsum("bsnd,rnd->bsnr", q_nope, wkv_b[..., :nope])
+            scores = (jnp.einsum("bsnr,btr->bnst", q_abs, c_all
+                                 ).astype(jnp.float32)
+                      + jnp.einsum("bsnd,btd->bnst", q_pe, pe_all
+                                   ).astype(jnp.float32)) * scale
+            q_pos = jnp.arange(u.shape[1]) + cache_index
+            seen = q_pos[:, None] >= jnp.arange(state.shape[1])[None, :]
+            probs = jax.nn.softmax(jnp.where(seen[None, None], scores, -1e30),
+                                   axis=-1).astype(dt)
+            weighted = jnp.einsum("bnst,btr->bsnr", probs, c_all)
+            out = jnp.einsum("bsnr,rnd->bsnd", weighted, wkv_b[..., nope:])
+        out = constrain(out, ("batch", "seq", "heads", None))
+        y = jnp.einsum("bsnd,ndh->bsh", out, lp["wo"].astype(dt))
+    return y, state
 
 
 def _short_conv(cfg: LlamaConfig, u: jax.Array, lp: Dict[str, jax.Array],
@@ -510,11 +742,12 @@ def _layer(cfg: LlamaConfig, x: jax.Array, lp: Dict[str, jax.Array],
     """One block. x: [B, S, H_model] -> (x, the layer's updated state or
     None, the router's books of ``moe.expert_ffn`` or None for a dense
     feed-forward). The layer's kind is read off its leaves: ``conv_in``
-    makes the operator the gated short convolution and not attention,
-    ``router`` makes the feed-forward the routed experts and not the dense
-    SwiGLU. ``kv_cache`` is the layer's own state in an incremental decode:
-    (keys, values) for attention, the last rows of ``z`` for the short
-    convolution (``_short_conv``)."""
+    makes the operator the gated short convolution and ``wkv_a`` latent
+    attention, and not attention; ``router`` makes the feed-forward the
+    routed experts and not the dense SwiGLU. ``kv_cache`` is the layer's
+    own state in an incremental decode: (keys, values) for attention, the
+    last rows of ``z`` for the short convolution (``_short_conv``), the
+    latent rows for latent attention (``_latent_attention``)."""
     dt = cfg.dtype
 
     def _ld(name, t_in, eq_a, eq_b):
@@ -529,6 +762,10 @@ def _layer(cfg: LlamaConfig, x: jax.Array, lp: Dict[str, jax.Array],
     if "conv_in" in lp:
         y, state = _short_conv(cfg, h, lp, kv_cache)
         new_cache = None if kv_cache is None else state
+        x = x + y
+    elif "wkv_a" in lp:
+        y, new_cache = _latent_attention(cfg, h, lp, positions, kv_cache,
+                                         cache_index)
         x = x + y
     else:
         # --- attention ---
@@ -591,12 +828,17 @@ def init_decode_state(cfg: LlamaConfig, batch: int, max_len: int) -> list:
     state in the model's order: keys and values ``[batch, max_len,
     kv_heads, head_dim]`` for an attention layer, the last ``conv_kernel -
     1`` rows of ``z`` ``[batch, conv_kernel - 1, hidden]`` for a short
-    convolution, all zeros."""
-    kv = jnp.zeros((batch, max_len, cfg.num_kv_heads, cfg.head_dim),
-                   cfg.dtype)
-    z = jnp.zeros((batch, cfg.conv_kernel - 1, cfg.hidden), cfg.dtype)
-    return [(kv, kv) if kind.startswith("attention") else z
-            for kind in cfg.layer_kinds()]
+    convolution, the normed latent row and the rotated shared key
+    ``[batch, max_len, kv_lora_rank + qk_rope_head_dim]`` for latent
+    attention, all zeros."""
+    shapes = {
+        "attention": (batch, max_len, cfg.num_kv_heads, cfg.head_dim),
+        "conv": (batch, cfg.conv_kernel - 1, cfg.hidden),
+        "latent": (batch, max_len, cfg.kv_lora_rank + cfg.qk_rope_head_dim)}
+    operators = [kind.split("_")[0] for kind in cfg.layer_kinds()]
+    zeros = {op: jnp.zeros(shapes[op], cfg.dtype) for op in set(operators)}
+    return [(zeros[op], zeros[op]) if op == "attention" else zeros[op]
+            for op in operators]
 
 
 def llama_decode(

@@ -2,7 +2,9 @@
 
 A model with experts is a ``LlamaConfig`` whose ``num_experts`` is above 0
 (OLMoE-1B-7B: 64 experts of width 1024, 8 a token, no shared expert;
-LFM2-24B-A2B: 64 of width 1536, 4 a token, after its leading dense layers).
+LFM2-24B-A2B: 64 of width 1536, 4 a token, after its leading dense layers;
+DeepSeek-V2: 160 of width 1536, 6 a token from 3 of 8 groups, beside two
+shared experts, of which a chip holds one group's 20).
 Its block is ``llama.py::_layer``: the operator half, the scan, remat, the
 head and the loss are the dense model's. This module holds what only the
 routed feed-forward needs: the router, the dispatch, the expert matmuls,
@@ -17,8 +19,33 @@ scores are a softmax or a sigmoid of the router's logits
 reaches (kept in the parameters' type like every leaf, added in float32),
 while the weights stay the scores without it; the chosen
 weights are renormalised or not (``norm_topk_prob``, over their sum plus
-``router_norm_eps``) and scaled by ``routed_scaling_factor``. The defaults
-are OLMoE's router, to the bit.
+``router_norm_eps``) and scaled by ``routed_scaling_factor``. With
+``router_groups`` the choice is group-limited (DeepSeek-V2's
+``group_limited_greedy``): the experts lie in that many groups of
+neighbours, a group's score is its best expert's, a position keeps its
+best ``router_topk_groups`` groups and chooses its ``K`` among their
+experts (``_best_groups``). The defaults are OLMoE's router, to the bit.
+
+Shared experts (``num_shared_experts``) are one dense SwiGLU of that many
+times an expert's width, every position's, added to the routed sum (leaves
+``ws_gate``, ``ws_up``, ``ws_down``).
+
+A share of the experts (``experts_held = (first, count)``) is what one
+chip of an expert-parallel deployment holds of a layer: the router stays
+``num_experts`` wide and every position still chooses its ``K`` among all
+of them, the three stacks hold the ``count`` experts from ``first`` on
+alone, and the result is those experts' part of the routed sum; what the
+absent experts would add is left out, and nothing stands in for the other
+chips or their traffic. The ``T x K`` pairs are sorted as ever, the pairs
+of absent experts past the held ones into no group, and the combine gives
+them zero: every pair's row is gathered and gathered back as for a model
+that holds all its experts, and the grouped matmuls do not visit the absent
+pairs' rows (``grouped_matmul``'s ``tail="unwritten"``: zeroed, as a model
+that holds all its experts has its empty tail, they would be seven eighths
+of the rows multiplied to write zeros). Nothing is dropped. A step's padded
+positions are kept off the held experts too (``in_stack``'s mask).
+The books count both: ``pairs`` over all the experts, ``pairs_here``
+over the held ones.
 
 The dispatch drops nothing and has no capacity: the ``T x K`` (position,
 expert) pairs are sorted by expert, the rows gathered in that order, and
@@ -68,6 +95,10 @@ EXPERT_LOGICAL_AXES = {
     "we_up": ("expert", "embed", "mlp"),
     "we_down": ("expert", "mlp", "embed"),
 }
+SHARED_LOGICAL_AXES = {
+    "ws_gate": ("embed", "mlp"), "ws_up": ("embed", "mlp"),
+    "ws_down": ("mlp", "embed"),
+}
 EXPERT_STACKS = ("we_gate", "we_up", "we_down")
 # keys `in_stack` adds to a layer's leaves: not leaves themselves
 _WHERE, _MASK = "_experts_in", "_router_mask"
@@ -91,8 +122,12 @@ def init_experts(cfg, key: jax.Array, num_layers: int
     is ``[16, 64, 2048, 1024]``, 8.6 GB in float32 on its way to bf16, and
     a layer of it is 0.5 GB. With ``router_bias`` the per-expert bias rides
     along as zeros, as HuggingFace starts it: a buffer that training's
-    balancing moves and no gradient reaches."""
+    balancing moves and no gradient reaches. With ``experts_held`` the
+    stacks hold that many experts and the router all ``num_experts``
+    columns; with ``num_shared_experts`` the shared SwiGLU's three leaves
+    ride along."""
     h, m, E, L = cfg.hidden, cfg.mlp_hidden, cfg.num_experts, num_layers
+    held = held_experts(cfg)[1]
     pd = cfg.param_dtype
 
     def stack(k, shape, fan_in):
@@ -105,13 +140,51 @@ def init_experts(cfg, key: jax.Array, num_layers: int
     ks = jax.random.split(key, 4)
     out = {
         "router": stack(ks[0], (h, E), h),
-        "we_gate": stack(ks[1], (E, h, m), h),
-        "we_up": stack(ks[2], (E, h, m), h),
-        "we_down": stack(ks[3], (E, m, h), m),
+        "we_gate": stack(ks[1], (held, h, m), h),
+        "we_up": stack(ks[2], (held, h, m), h),
+        "we_down": stack(ks[3], (held, m, h), m),
     }
     if cfg.router_bias:
         out["router_bias"] = jnp.zeros((L, E), pd)
+    if cfg.num_shared_experts:
+        ms = cfg.num_shared_experts * m
+        kg, ku, kd = jax.random.split(jax.random.fold_in(key, 1), 3)
+        out.update(ws_gate=stack(kg, (h, ms), h), ws_up=stack(ku, (h, ms), h),
+                   ws_down=stack(kd, (ms, h), ms))
     return out
+
+
+def held_experts(cfg) -> Tuple[int, int]:
+    """``(first, count)`` of the experts whose weights are here: the
+    configuration's share, or all of them."""
+    first, count = cfg.experts_held or (0, cfg.num_experts)
+    if not (0 <= first and 0 < count and first + count <= cfg.num_experts):
+        raise ValueError(f"experts_held {cfg.experts_held!r} lies outside "
+                         f"the {cfg.num_experts} experts")
+    return first, count
+
+
+def _best_groups(cfg, probs: jax.Array) -> jax.Array:
+    """``probs [T, E]`` with the experts outside each position's best
+    ``router_topk_groups`` of ``router_groups`` groups set to 0, as
+    DeepSeek-V2's ``group_limited_greedy`` masks them: a group is
+    ``E / router_groups`` neighbouring experts and its score its largest;
+    between groups that tie, the lower index stays (``lax.top_k``)."""
+    T, E = probs.shape
+    G, keep = cfg.router_groups, cfg.router_topk_groups
+    if E % G or not 0 < keep <= G:
+        raise ValueError(f"{E} experts in {G} groups, {keep} kept")
+    # by comparisons against each expert's group, not by reshaping the
+    # expert axis into (group, member): on a TPU that reshape is a
+    # relayout of every row (19 ms a step at 8 x 1536 positions, PERF.md)
+    group_of = jnp.arange(E, dtype=jnp.int32) // (E // G)       # [E]
+    member = group_of[None, :] == jnp.arange(G, dtype=jnp.int32)[:, None]
+    best = jnp.max(jnp.where(member[None], probs[:, None, :], -jnp.inf),
+                   axis=-1)                                     # [T, G]
+    _, kept = jax.lax.top_k(best, keep)                         # [T, keep]
+    stays = jnp.any(kept[:, :, None] == group_of[None, None, :],
+                    axis=1)                                     # [T, E]
+    return jnp.where(stays, probs, 0.0)
 
 
 def _kernel_takes(stack: jax.Array) -> bool:
@@ -144,22 +217,25 @@ def _ragged_dot_in_stack(rows, sizes, stack, layer, out_dtype):
                               preferred_element_type=out_dtype)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _in_place(rows, w, sizes, stack, layer, out_dtype):
+@partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _in_place(rows, w, sizes, stack, layer, out_dtype, tail="zero"):
     """``ragged_dot(rows, w, sizes)`` for ``w = stack[layer]``, read from
     the stack where it lies; the repo's kernel is handed the layer's first
-    group."""
+    group, and ``tail``: what it leaves in the rows past the groups' end
+    (``grouped_matmul.TAILS``; XLA's kernel leaves zeros)."""
     if _kernel_takes(stack):
         return grouped_matmul.grouped_matmul(
-            rows, _as_groups(stack), sizes, layer * stack.shape[1], out_dtype)
+            rows, _as_groups(stack), sizes, layer * stack.shape[1], out_dtype,
+            tail)
     return _ragged_dot_in_stack(rows, sizes, stack, layer, out_dtype)
 
 
-def _in_place_fwd(rows, w, sizes, stack, layer, out_dtype):
-    return _in_place(rows, w, sizes, stack, layer, out_dtype), (rows, w, sizes)
+def _in_place_fwd(rows, w, sizes, stack, layer, out_dtype, tail):
+    return (_in_place(rows, w, sizes, stack, layer, out_dtype, tail),
+            (rows, w, sizes))
 
 
-def _in_place_bwd(out_dtype, res, g):
+def _in_place_bwd(out_dtype, tail, res, g):
     rows, w, sizes = res
     _, vjp = jax.vjp(lambda r, ww: jax.lax.ragged_dot(
         r, ww, sizes, preferred_element_type=out_dtype), rows, w)
@@ -169,28 +245,30 @@ def _in_place_bwd(out_dtype, res, g):
 _in_place.defvjp(_in_place_fwd, _in_place_bwd)
 
 
-@jax.custom_vjp
-def _gated_in_place(rows, w_gate, w_up, sizes, gate_stack, up_stack, layer):
+@partial(jax.custom_vjp, nondiff_argnums=(7,))
+def _gated_in_place(rows, w_gate, w_up, sizes, gate_stack, up_stack, layer,
+                    tail="zero"):
     """``silu(ragged_dot(rows, w_gate)) * ragged_dot(rows, w_up)`` in the
     rows' type, both read from their stacks where they lie. The kernel
     makes it in one pass over the rows and rounds once, from the float32
-    products; XLA's two matmuls round each product first."""
+    products; XLA's two matmuls round each product first. ``tail`` as
+    ``_in_place``'s."""
     if _kernel_takes(gate_stack):
         return grouped_matmul.grouped_swiglu(
             rows, _as_groups(gate_stack), _as_groups(up_stack), sizes,
-            layer * gate_stack.shape[1], rows.dtype)
+            layer * gate_stack.shape[1], rows.dtype, tail)
     return (jax.nn.silu(_ragged_dot_in_stack(rows, sizes, gate_stack, layer,
                                              rows.dtype))
             * _ragged_dot_in_stack(rows, sizes, up_stack, layer, rows.dtype))
 
 
 def _gated_in_place_fwd(rows, w_gate, w_up, sizes, gate_stack, up_stack,
-                        layer):
+                        layer, tail):
     return (_gated_in_place(rows, w_gate, w_up, sizes, gate_stack, up_stack,
-                            layer), (rows, w_gate, w_up, sizes))
+                            layer, tail), (rows, w_gate, w_up, sizes))
 
 
-def _gated_in_place_bwd(res, g):
+def _gated_in_place_bwd(tail, res, g):
     # through the layer's slices, the two products made again
     rows, w_gate, w_up, sizes = res
 
@@ -216,13 +294,22 @@ def expert_ffn(cfg, h: jax.Array, lp: Dict[str, jax.Array]
     positions where ``in_stack``'s mask is true (every position without
     one). Padded positions are computed like any other; the mask only
     keeps them out of the books. ``lp`` is the layer's leaves, as they are
-    or from ``in_stack``."""
+    or from ``in_stack``. With a share of the experts (``experts_held``)
+    the output is the held experts' part of the routed sum (and the shared
+    experts', whole), ``pairs`` stays over all the experts and
+    ``pairs_here [count]`` is the held ones' own; there the mask also
+    keeps the padded positions' pairs off the held experts (their routed
+    part is left out, like an absent expert's)."""
     dt = cfg.dtype
     where, mask = lp.get(_WHERE), lp.get(_MASK)
     B, S, H = h.shape
     E, K = cfg.num_experts, cfg.experts_per_token
+    first, count = held_experts(cfg)
+    share = count < E
     T = B * S
     x = h.reshape(T, H)
+    if cfg.router_groups and "router_bias" in lp:
+        raise ValueError("the group-limited choice has no bias on it here")
     if cfg.router_scores not in ROUTER_SCORES:
         raise ValueError(f"router_scores {cfg.router_scores!r}: expected "
                          + "|".join(ROUTER_SCORES))
@@ -233,7 +320,10 @@ def expert_ffn(cfg, h: jax.Array, lp: Dict[str, jax.Array]
                             preferred_element_type=jnp.float32)
         probs = (jax.nn.sigmoid(logits) if cfg.router_scores == "sigmoid"
                  else jax.nn.softmax(logits, axis=-1))
-        if "router_bias" in lp:
+        if cfg.router_groups:
+            # among the experts of each position's best groups
+            weights, chosen = jax.lax.top_k(_best_groups(cfg, probs), K)
+        elif "router_bias" in lp:
             # the bias moves the choice and not the weights
             _, chosen = jax.lax.top_k(
                 probs + jax.lax.stop_gradient(
@@ -250,10 +340,30 @@ def expert_ffn(cfg, h: jax.Array, lp: Dict[str, jax.Array]
             weights = weights * cfg.routed_scaling_factor
     with jax.named_scope("moe_dispatch"):
         flat = chosen.reshape(T * K)
-        order = jnp.argsort(flat)                 # stable: pairs by expert
+        by = flat
+        if share:
+            # the held experts' pairs first, by expert; the absent ones'
+            # past them, in no group, and weightless in the combine
+            here = (chosen >= first) & (chosen < first + count)   # [T, K]
+            if mask is not None:
+                # and of those the rows' own tokens' alone: a step's
+                # padding is one token repeated, which routes alike, and
+                # thousands of its rows on whichever held experts it likes
+                # are work for no result (and moved a step by 2 % from one
+                # draw of the weights to the next: PERF.md, PR 35)
+                here = here & mask.reshape(T, 1)
+            weights = jnp.where(here, weights, 0.0)
+            by = jnp.where(here, chosen - first, count).reshape(T * K)
+        order = jnp.argsort(by)                   # stable: pairs by expert
         onehot = flat[:, None] == jnp.arange(E, dtype=flat.dtype)[None, :]
         sizes = jnp.sum(onehot, axis=0, dtype=jnp.int32)     # [E]
+        if share:
+            sizes = jnp.sum(by[:, None] == jnp.arange(
+                count, dtype=by.dtype)[None, :], axis=0, dtype=jnp.int32)
         rows = jnp.take(x, order // K, axis=0)               # [T*K, H]
+    # with a share most sorted rows are absent experts': not visited, and
+    # masked in the combine
+    tail = "unwritten" if share else "zero"
     with jax.named_scope("moe_experts"):
         if where is None or lp["we_gate"].dtype != dt:
             # the layer's slices are cast on their way in, which is the
@@ -269,20 +379,30 @@ def expert_ffn(cfg, h: jax.Array, lp: Dict[str, jax.Array]
             stacks, layer = where
             hidden = _gated_in_place(
                 rows, lp["we_gate"], lp["we_up"], sizes, stacks["we_gate"],
-                stacks["we_up"], layer)
+                stacks["we_up"], layer, tail)
             out = _in_place(hidden, lp["we_down"], sizes, stacks["we_down"],
-                            layer, jnp.float32)
+                            layer, jnp.float32, tail)
     with jax.named_scope("moe_combine"):
         # back to the pairs' own order, then the weighted sum of each
         # position's K expert outputs, in float32
         out = jnp.take(out, jnp.argsort(order), axis=0).reshape(T, K, H)
+        if share:  # an absent pair's row was never written
+            out = jnp.where(here[:, :, None], out, 0.0)
         y = jnp.einsum("tkh,tk->th", out, weights).astype(dt)
+    if "ws_gate" in lp:
+        with jax.named_scope("moe_shared"):
+            act = (jax.nn.silu(jnp.einsum("th,hm->tm", x,
+                                          lp["ws_gate"].astype(dt)))
+                   * jnp.einsum("th,hm->tm", x, lp["ws_up"].astype(dt)))
+            y = y + jnp.einsum("tm,mh->th", act, lp["ws_down"].astype(dt))
     live = (jnp.ones((T,), jnp.float32) if mask is None
             else mask.reshape(T).astype(jnp.float32))
     books = {"pairs": jnp.einsum("t,te->e", jnp.repeat(live, K),
                                  onehot.astype(jnp.float32)),
              "prob": jnp.einsum("t,te->e", live, probs),
              "positions": jnp.sum(live)}
+    if share:
+        books["pairs_here"] = books["pairs"][first:first + count]
     return y.reshape(B, S, H), books
 
 
@@ -302,6 +422,12 @@ def load_balancing_loss(books: Dict[str, jax.Array], cfg) -> jax.Array:
 def router_load(books: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
     """For each layer, the pairs the fullest expert took and the pairs the
     mean expert took (``[L]`` float32 each): what a serving step brings to
-    the host beside its token ids."""
-    return {"fullest": jnp.max(books["pairs"], axis=-1),
-            "mean": jnp.mean(books["pairs"], axis=-1)}
+    the host beside its token ids. With a share of the experts both are
+    over the experts held, and ``all [L]``, the pairs the router made over
+    every expert, comes with them."""
+    if "pairs_here" not in books:
+        return {"fullest": jnp.max(books["pairs"], axis=-1),
+                "mean": jnp.mean(books["pairs"], axis=-1)}
+    return {"fullest": jnp.max(books["pairs_here"], axis=-1),
+            "mean": jnp.mean(books["pairs_here"], axis=-1),
+            "all": jnp.sum(books["pairs"], axis=-1)}

@@ -2,6 +2,13 @@
 
 GQA layout everywhere: q [B, S, H, D], k/v [B, S_kv, KVH, D] with
 H % KVH == 0. Returns [B, S, H, D] in q.dtype.
+
+Two widths (latent attention's prefill form): the values may be narrower
+than the queries and keys, ``v [B, S_kv, KVH, Dv]``, and the queries and
+keys may have a second, rotary part whose key is one row a position shared
+by every head: ``q_rope [B, S, H, R]``, ``k_rope [B, S_kv, R]``; the scores
+are ``q k^T + q_rope k_rope^T``, times ``scale`` (by default the whole
+query width ``** -0.5``), and the result is ``[B, S, H, Dv]``.
 """
 
 from __future__ import annotations
@@ -29,6 +36,9 @@ def reference_attention(
     *, causal: bool = True,
     q_offset: Optional[jax.Array] = None,
     valid_kv_len: Optional[jax.Array] = None,
+    q_rope: Optional[jax.Array] = None,
+    k_rope: Optional[jax.Array] = None,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Plain einsum attention with fp32 softmax. ``q_offset`` positions the
     query block inside a longer kv sequence (decode with kv cache)."""
@@ -36,8 +46,16 @@ def reference_attention(
     Skv, KVH = k.shape[1], k.shape[2]
     k = _repeat_kv(k, H // KVH)
     v = _repeat_kv(v, H // KVH)
-    scale = D ** -0.5
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
+    if q_rope is None:
+        scale = D ** -0.5 if scale is None else scale
+        logits = (jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
+                  * scale)
+    else:  # the shared rotary key meets every head
+        if scale is None:
+            scale = (D + q_rope.shape[3]) ** -0.5
+        logits = (jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
+                  + jnp.einsum("bqhr,bkr->bhqk", q_rope, k_rope
+                               ).astype(jnp.float32)) * scale
     kv_pos = jnp.arange(Skv)
     if causal:
         q_pos = jnp.arange(Sq)
@@ -53,14 +71,30 @@ def reference_attention(
 
 
 def _flash_per_shard(q: jax.Array, k: jax.Array, v: jax.Array,
-                     causal: bool) -> jax.Array:
+                     causal: bool, q_rope: Optional[jax.Array] = None,
+                     k_rope: Optional[jax.Array] = None,
+                     scale: Optional[float] = None) -> jax.Array:
     """The Pallas kernel, run on each device's own shard when a mesh is in
     scope. A ``pallas_call`` has no partitioning rule: left to GSPMD its
     operands are all-gathered and every chip computes the whole batch."""
-    from ray_tpu.ops.pallas.flash_attention import flash_attention
+    from ray_tpu.ops.pallas.flash_attention import (
+        flash_attention, flash_attention_shared_rope)
     from ray_tpu.parallel.sharding import ambient_mesh, logical_to_spec
 
     mesh = ambient_mesh()
+    if q_rope is not None:
+        if mesh is not None and mesh.size > 1:
+            raise NotImplementedError(
+                "flash attention at two widths runs on one device; a mesh "
+                "needs impl='reference'")
+        if scale is None:
+            scale = (q.shape[3] + q_rope.shape[3]) ** -0.5
+        return flash_attention_shared_rope(q, q_rope, k, k_rope, v, scale,
+                                           causal)
+    if scale is not None or v.shape[3] != q.shape[3]:
+        raise NotImplementedError(
+            "the equal-width flash kernels scale by head_dim ** -0.5 and "
+            "take values of the keys' width")
     if mesh is None or mesh.size == 1:
         return flash_attention(q, k, v, causal)
     if mesh.shape.get("seq", 1) > 1:
@@ -75,22 +109,33 @@ def _flash_per_shard(q: jax.Array, k: jax.Array, v: jax.Array,
         check_vma=False)(q, k, v)
 
 
+def _device_memory_bytes() -> Optional[int]:
+    """What the first device says it can hold, where it says."""
+    stats = jax.devices()[0].memory_stats() or {}
+    return stats.get("bytes_limit")
+
+
 def attention(
     q: jax.Array, k: jax.Array, v: jax.Array,
     *, impl: str = "auto", causal: bool = True,
     q_offset: Optional[jax.Array] = None,
     valid_kv_len: Optional[jax.Array] = None,
+    q_rope: Optional[jax.Array] = None,
+    k_rope: Optional[jax.Array] = None,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """impl: auto (on the TPU platform the flash kernel, on the CPU platform
     the reference), flash, reference. Ring attention is invoked explicitly
     via ops.ring_attention by the seq-parallel layer, not through this
-    dispatcher.
+    dispatcher. ``q_rope``, ``k_rope`` and ``scale`` are the module
+    docstring's two widths.
 
     ``auto`` on a TPU still takes the reference for what the kernel has no
-    path for (cached decode, lengths off the 128 grid, a head dim that is
-    neither a multiple of the 128 lanes nor 64:
-    ``flash_attention.takes_head_dim``) and says so once per shape;
-    ``flash`` raises for cached decode and hands the kernel any shape."""
+    path for (cached decode, lengths off the 128 grid, widths it does not
+    take: ``flash_attention.takes_head_dim``) and says so once per shape;
+    where the reference's float32 scores alone would not fit the device's
+    memory it raises instead. ``flash`` raises for cached decode and hands
+    the kernel any shape."""
     if impl == "auto":
         platform = jax.default_backend()
         if platform not in ("tpu", "cpu"):
@@ -101,16 +146,30 @@ def attention(
         if platform == "tpu":
             from ray_tpu.ops.pallas.flash_attention import takes_head_dim
 
+            shared = 0 if q_rope is None else q_rope.shape[3]
             kernel_takes_it = (
                 q_offset is None and valid_kv_len is None
                 and q.shape[1] == k.shape[1]
-                and q.shape[1] % 128 == 0 and takes_head_dim(q.shape[3]))
+                and q.shape[1] % 128 == 0
+                and (q_rope is not None or scale is None)
+                and takes_head_dim(q.shape[3] + shared, v.shape[3],
+                                   shared_dim=shared))
             if kernel_takes_it:
                 impl = "flash"
             else:
+                shapes = f"q{tuple(q.shape)} k{tuple(k.shape)}" + (
+                    "" if q_rope is None else f" q_rope{tuple(q_rope.shape)}")
+                scores = 4 * q.shape[0] * q.shape[2] * q.shape[1] * k.shape[1]
+                holds = _device_memory_bytes()
+                if holds is not None and scores > holds:
+                    raise ValueError(
+                        f"attention impl 'auto' on TPU: the flash kernel "
+                        f"has no path for {shapes} v{tuple(v.shape)}, and "
+                        f"the reference's float32 scores are {scores} "
+                        f"bytes where the device holds {holds}")
                 warnings.warn(
                     "attention impl 'auto' on TPU: reference path for "
-                    f"q{tuple(q.shape)} k{tuple(k.shape)} (cached decode, "
+                    f"{shapes} (cached decode, "
                     "a length off the flash kernel's 128 grid, or a head "
                     "dim it does not take)",
                     stacklevel=2)
@@ -119,11 +178,12 @@ def attention(
             raise NotImplementedError(
                 "flash attention does not support q_offset/valid_kv_len; "
                 "use impl='reference' for cached decode")
-        return _flash_per_shard(q, k, v, causal)
+        return _flash_per_shard(q, k, v, causal, q_rope, k_rope, scale)
     if impl != "reference":
         raise ValueError(
             f"unknown attention impl {impl!r}; expected "
             "auto|flash|reference "
             "(ring attention is the model layer's 'ring_seq' path)")
     return reference_attention(q, k, v, causal=causal, q_offset=q_offset,
-                               valid_kv_len=valid_kv_len)
+                               valid_kv_len=valid_kv_len, q_rope=q_rope,
+                               k_rope=k_rope, scale=scale)

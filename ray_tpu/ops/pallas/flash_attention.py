@@ -49,12 +49,32 @@ to the lane width in VMEM and runs the same kernel, the two matmuls at half
 the MXU's width (``takes_head_dim``; PERF.md, PR 33, has its time beside a
 head of 128). ``tile_vmem_bytes`` is told the head's width and stays above
 Mosaic's figure there too (``tests/test_flash_tiles_v5e.py``).
+
+Two widths (latent attention's prefill form: DeepSeek-V2's heads of
+128 + 64 against values of 128). ``flash_attention_shared_rope`` is the
+forward for queries and keys wider than the values, whose width is in two
+parts: one a head (``q``, ``k``: ``[B, S, H, D]``) and a rotary one whose
+key is ONE row a position shared by every head (``q_rope [B, S, H, R]``,
+``k_rope [B, S, R]``). The scores are ``q k^T + q_rope k_rope^T``: two
+dots into one float32 tile, the shared key's block read by its own index
+map, which has no head in it, so it is never broadcast to the heads in HBM;
+nothing is padded (a contraction over 64 is half a lane tile in VMEM, as
+the head of 64 above). The softmax scale is an argument (YaRN's is not
+``width ** -0.5``). The operands meet the MXU in their own type with a
+float32 accumulator, and ``p`` is rounded to the values' type for its dot:
+in bf16 that is the MXU's one-pass rate, where the equal-width kernels'
+float32 casts take several passes (left as they are: their cells' numbers
+and trace names are the parent's). It runs under a scope of its own,
+``SHARED_ROPE_TRACE_NAME``, which is what the device trace calls it; the
+equal-width path keeps the name it has there. There is no backward at two
+widths: differentiating it raises and says so (no cell trains such a
+model; the model layer's ``reference`` path differentiates).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import List, Tuple
+from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -83,14 +103,28 @@ _LANES = 128
 VMEM_LIMIT_BYTES = 16 * 2 ** 20
 
 
-def takes_head_dim(head_dim: int) -> bool:
-    """Whether the compiled kernels have run at a head of this width:
-    whole lane tiles, or the 64 of the module docstring. ``attention``'s
-    ``auto`` asks; the interpreted kernels take any width."""
-    return head_dim % _LANES == 0 or head_dim == 64
+def takes_head_dim(head_dim: int, value_dim: Optional[int] = None, *,
+                   shared_dim: int = 0) -> bool:
+    """Whether the compiled kernels have run at these widths. ``head_dim``
+    is the queries' and keys' whole width, ``value_dim`` the values' (the
+    same when not given) and ``shared_dim`` the trailing part of
+    ``head_dim`` whose key is one row a position shared by the heads. Equal
+    widths with nothing shared: whole lane tiles, or the 64 of the module
+    docstring. Two widths: the forward alone
+    (``flash_attention_shared_rope``), the head's own part and the values
+    whole lane tiles and the shared part whole lane tiles or 64.
+    ``attention``'s ``auto`` asks; the interpreted kernels take any width."""
+    value_dim = head_dim if value_dim is None else value_dim
+    if not shared_dim:
+        return value_dim == head_dim and (head_dim % _LANES == 0
+                                          or head_dim == 64)
+    own = head_dim - shared_dim
+    return (own > 0 and own % _LANES == 0 and value_dim % _LANES == 0
+            and (shared_dim % _LANES == 0 or shared_dim == 64))
 
 
 def tile_vmem_bytes(block_q: int, block_k: int, *, head_dim: int = 128,
+                    value_dim: Optional[int] = None,
                     backward: bool = False) -> int:
     """Upper reckoning of the VMEM one grid step holds at a tile, every
     element taken at 4 bytes: each block of an operand or a result in its
@@ -101,14 +135,21 @@ def tile_vmem_bytes(block_q: int, block_k: int, *, head_dim: int = 128,
     larger of its two kernels'. Mosaic reports less at every tile tried
     (``tests/test_flash_tiles_v5e.py`` compiles for a described v5e): at
     1024 x 1024, 10.0 MB of the 13.1 reckoned in the forward, 9.8 (dq)
-    and 11.1 (dk/dv) of 15.7 in the backward."""
+    and 11.1 (dk/dv) of 15.7 in the backward. ``value_dim`` is the values'
+    width where it is not the queries' and keys' ``head_dim`` (the forward
+    alone; a part of ``head_dim`` that is half a lane tile counts as the
+    whole tile it fills in VMEM)."""
     d, lanes = 4 * head_dim, 4 * _LANES
     tile = 4 * block_q * block_k
     if not backward:
-        # a query row: q and o twice, q's cast, acc and its rescaled copy
-        # (7 d); lse twice, m and l (4 lanes). A key row: k and v twice,
-        # their casts
-        return tile + block_q * (7 * d + 4 * lanes) + block_k * 6 * d
+        # a query row: q twice and its cast (3 d), o twice, acc and its
+        # rescaled copy (4 dv); lse twice, m and l (4 lanes). A key row:
+        # k and v twice, their casts
+        dv = d if value_dim is None else 4 * value_dim
+        return (tile + block_q * (3 * d + 4 * dv + 4 * lanes)
+                + block_k * 3 * (d + dv))
+    if value_dim not in (None, head_dim):
+        raise ValueError("the backward kernels take one width")
     # dq. A query row: q, do and dq twice, the casts of q and do, dq's
     # scratch (9 d); lse and delta twice (4 lanes). A key row: k and v
     # twice, their casts
@@ -126,15 +167,17 @@ def _divisors(n: int) -> List[int]:
 
 
 def flash_tiles(sq: int, skv: int, *, head_dim: int = 128,
+                value_dim: Optional[int] = None,
                 backward: bool = False) -> Tuple[int, int]:
     """``(block_q, block_k)`` for query and key lengths: the module
-    docstring's rule. Pure: the lengths, the head's width and which pass
-    holds the tile are all it reads."""
+    docstring's rule. Pure: the lengths, the widths and which pass holds
+    the tile are all it reads."""
     if sq % _LANES or skv % _LANES:
         raise ValueError(f"seq lens ({sq},{skv}) must divide by 128")
 
     def fits(bq: int, bk: int) -> bool:
         return tile_vmem_bytes(bq, bk, head_dim=head_dim,
+                               value_dim=value_dim,
                                backward=backward) <= VMEM_LIMIT_BYTES
 
     def block(n: int) -> int:
@@ -454,3 +497,143 @@ def _fa_bwd(causal, res, g):
 
 
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
+
+
+# ------------------------------------------- two widths, a shared rotary key
+
+# What the device trace calls the kernel: a Pallas call's HLO instruction
+# takes the name of its innermost named scope
+# (``tpu_custom_call:<this>.N``); the equal-width forward is
+# ``tpu_custom_call:checkpoint.N`` under a rematerialised layer.
+SHARED_ROPE_TRACE_NAME = "flash_fwd_shared_rope"
+
+
+def _fwd_shared_rope_kernel(q_ref, qr_ref, k_ref, kr_ref, v_ref, o_ref,
+                            m_scr, l_scr, acc_scr, *, scale: float,
+                            causal: bool, block_q: int, block_k: int):
+    """``_fwd_kernel`` with the scores in two parts, ``q k^T`` over the
+    head's own width and ``q_rope k_rope^T`` over the shared rotary key's;
+    operands in their own type, float32 accumulators, no logsumexp (there
+    is no backward to hand it to)."""
+    iq = pl.program_id(2)
+    ik = pl.program_id(3)
+    nk = pl.num_programs(3)
+
+    @pl.when(ik == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    run = True
+    if causal:
+        run = ik * block_k <= iq * block_q + block_q - 1
+
+    @pl.when(run)
+    def _compute():
+        contract_last = (((1,), (1,)), ((), ()))
+        v = v_ref[0, 0]                                  # [bk, dv]
+        s = (jax.lax.dot_general(
+            q_ref[0, 0], k_ref[0, 0], contract_last,
+            preferred_element_type=jnp.float32)
+            + jax.lax.dot_general(
+                qr_ref[0, 0], kr_ref[0], contract_last,
+                preferred_element_type=jnp.float32)) * scale   # [bq, bk]
+        if causal:
+            q_pos = iq * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 0)
+            k_pos = ik * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1)
+            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+        m_prev = m_scr[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = alpha * l_scr[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+
+    @pl.when(ik == nk - 1)
+    def _finalize():
+        o_ref[0, 0] = (acc_scr[:] / jnp.maximum(l_scr[:, :1], 1e-30)
+                       ).astype(o_ref.dtype)
+
+
+def _flash_fwd_shared_rope(q: jax.Array, q_rope: jax.Array, k: jax.Array,
+                           k_rope: jax.Array, v: jax.Array, *, scale: float,
+                           causal: bool) -> jax.Array:
+    """q [B,H,S,D], q_rope [B,H,S,R], k [B,H,S,D], k_rope [B,S,R] (one row
+    a position, every head's), v [B,H,S,Dv] → o [B,H,S,Dv]."""
+    B, H, Sq, D = q.shape
+    R, Skv, Dv = q_rope.shape[3], k.shape[2], v.shape[3]
+    # what the blocks fill in VMEM: a part of 64 pads to the lane width
+    padded = D + -(-R // _LANES) * _LANES
+    block_q, block_k = flash_tiles(Sq, Skv, head_dim=padded, value_dim=Dv)
+    kernel = functools.partial(
+        _fwd_shared_rope_kernel, scale=scale, causal=causal,
+        block_q=block_q, block_k=block_k)
+
+    def rows(block, width):   # a head's rows: q, q_rope and o by iq
+        return pl.BlockSpec((1, 1, block, width),
+                            lambda b, h, iq, ik: (b, h, iq, 0))
+
+    def keys(width):          # a head's keys and values by ik
+        return pl.BlockSpec((1, 1, block_k, width),
+                            lambda b, h, iq, ik: (b, h, ik, 0))
+
+    with jax.named_scope(SHARED_ROPE_TRACE_NAME):
+        return pl.pallas_call(
+            kernel,
+            grid=(B, H, Sq // block_q, Skv // block_k),
+            in_specs=[
+                rows(block_q, D), rows(block_q, R), keys(D),
+                # the shared rotary key: no head in its index
+                pl.BlockSpec((1, block_k, R),
+                             lambda b, h, iq, ik: (b, ik, 0)),
+                keys(Dv),
+            ],
+            out_specs=rows(block_q, Dv),
+            out_shape=jax.ShapeDtypeStruct((B, H, Sq, Dv), q.dtype),
+            scratch_shapes=[
+                pltpu.VMEM((block_q, 128), jnp.float32),   # running max
+                pltpu.VMEM((block_q, 128), jnp.float32),   # running sum
+                pltpu.VMEM((block_q, Dv), jnp.float32),    # accumulator
+            ],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "parallel",
+                                     "arbitrary")),
+            interpret=_interpret(),
+        )(q, q_rope, k, k_rope, v)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def flash_attention_shared_rope(q: jax.Array, q_rope: jax.Array,
+                                k: jax.Array, k_rope: jax.Array,
+                                v: jax.Array, scale: float,
+                                causal: bool = True) -> jax.Array:
+    """The forward at two widths (module docstring): q, k ``[B, S, H, D]``,
+    q_rope ``[B, S, H, R]``, k_rope ``[B, S, R]``, v ``[B, S, H, Dv]`` →
+    ``[B, S, H, Dv]``; softmax of ``(q k^T + q_rope k_rope^T) * scale``."""
+    o = _flash_fwd_shared_rope(
+        jnp.swapaxes(q, 1, 2), jnp.swapaxes(q_rope, 1, 2),
+        jnp.swapaxes(k, 1, 2), k_rope, jnp.swapaxes(v, 1, 2),
+        scale=scale, causal=causal)
+    return jnp.swapaxes(o, 1, 2)
+
+
+def _fa_shared_rope_fwd(q, q_rope, k, k_rope, v, scale, causal):
+    return flash_attention_shared_rope(q, q_rope, k, k_rope, v, scale,
+                                       causal), None
+
+
+def _fa_shared_rope_bwd(scale, causal, res, g):
+    raise NotImplementedError(
+        "flash attention at two widths (a shared rotary key beside the "
+        "head's own, values narrower than keys) has a forward only: train "
+        "such a model with attn_impl='reference'")
+
+
+flash_attention_shared_rope.defvjp(_fa_shared_rope_fwd, _fa_shared_rope_bwd)

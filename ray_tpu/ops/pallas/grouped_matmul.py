@@ -28,7 +28,12 @@ tile's: what is computed follows the rows a group really has, at any tile.
 The product is accumulated in float32 over the whole of ``K`` in one dot,
 and written under a mask of the group's rows: the other rows of the tile
 are the neighbouring visits'. Rows past the last group's end come out zero
-(as ``jax.lax.ragged_dot`` leaves them).
+(as ``jax.lax.ragged_dot`` leaves them): they are one more group, whose
+visits multiply and write zeros. A caller that masks those rows itself
+says ``tail="unwritten"`` and that group gets no visit: nothing is read,
+multiplied or written for them, and what the result holds there is not
+defined (``models/moe.py`` with a share of the experts, where seven
+eighths of the sorted pairs belong to experts on other chips).
 
 ``grouped_swiglu`` is the same kernel with two stacks: one pass over the
 rows holds the gate's and the up projection's blocks, accumulates both,
@@ -106,7 +111,10 @@ def gmm_tiles(rows: int, k: int, n: int, *, stacks: int = 1,
     raise ValueError(f"no column block of {n} fits VMEM beside k={k}")
 
 
-def _visits(sizes: jax.Array, rows: int, tm: int):
+TAILS = ("zero", "unwritten")
+
+
+def _visits(sizes: jax.Array, rows: int, tm: int, tail: str = "zero"):
     """The (row tile, group) pairs that share rows, in row order, padded to
     their static bound ``tiles + E`` by repeating the last: ``tile [V]``,
     ``group [V]``, how many are real ``[1]``, and each group's first and
@@ -129,6 +137,8 @@ def _visits(sizes: jax.Array, rows: int, tm: int):
     start = end - sizes
     first = jax.lax.div(start, tm)
     count = jnp.where(sizes > 0, jax.lax.div(end - 1, tm) - first + 1, 0)
+    if tail == "unwritten":  # the rows no group owns are not visited
+        count = jnp.where(e < E, count, 0)
     upto = running(count)
     total = upto[E:]
     v = jnp.minimum(jnp.arange(-(-rows // tm) + E, dtype=jnp.int32),
@@ -137,6 +147,9 @@ def _visits(sizes: jax.Array, rows: int, tm: int):
     group = jnp.sum(upto[None, :] <= v[:, None], axis=1, dtype=jnp.int32)
     tile = v + jnp.sum(jnp.where(group[:, None] == e[None, :],
                                  (first - upto + count)[None, :], 0), axis=1)
+    if tail == "unwritten":  # there may be no visit at all: stay in range
+        group = jnp.minimum(group, E)
+        tile = jnp.clip(tile, 0, -(-rows // tm) - 1)
     return tile, group, total, start, end
 
 
@@ -176,7 +189,9 @@ def _kernel(first_ref, tile_ref, group_ref, total_ref, start_ref, end_ref,
 
 
 def _grouped(rows: jax.Array, stacks: Sequence[jax.Array], sizes: jax.Array,
-             first_group, out_dtype) -> jax.Array:
+             first_group, out_dtype, tail: str = "zero") -> jax.Array:
+    if tail not in TAILS:
+        raise ValueError(f"tail {tail!r}: expected " + "|".join(TAILS))
     M, K = rows.shape
     N = stacks[0].shape[2]
     E = sizes.shape[0]
@@ -189,7 +204,7 @@ def _grouped(rows: jax.Array, stacks: Sequence[jax.Array], sizes: jax.Array,
     tm, tn = gmm_tiles(M, K, N, stacks=len(stacks),
                        itemsize=rows.dtype.itemsize,
                        out_itemsize=out_dtype.itemsize)
-    tile, group, total, start, end = _visits(sizes, M, tm)
+    tile, group, total, start, end = _visits(sizes, M, tm, tail)
     first = jnp.asarray(first_group, jnp.int32).reshape(1)
 
     def weights(j, v, first_ref, tile_ref, group_ref, *_):
@@ -218,20 +233,20 @@ def _grouped(rows: jax.Array, stacks: Sequence[jax.Array], sizes: jax.Array,
 
 
 def grouped_matmul(rows: jax.Array, stack: jax.Array, sizes: jax.Array,
-                   first_group, out_dtype) -> jax.Array:
+                   first_group, out_dtype, tail: str = "zero") -> jax.Array:
     """``rows [M, K]`` sorted by group, ``stack [G, K, N]``, ``sizes [E]``
     (int): the ``sizes[e]`` rows of group ``e`` times
     ``stack[first_group + e]``, accumulated in float32, as ``[M, N]`` in
     ``out_dtype``. ``first_group`` may be traced. Rows past ``sum(sizes)``
-    are zero."""
-    return _grouped(rows, (stack,), sizes, first_group, out_dtype)
+    are zero, or with ``tail="unwritten"`` not defined."""
+    return _grouped(rows, (stack,), sizes, first_group, out_dtype, tail)
 
 
 def grouped_swiglu(rows: jax.Array, gate_stack: jax.Array,
                    up_stack: jax.Array, sizes: jax.Array, first_group,
-                   out_dtype) -> jax.Array:
+                   out_dtype, tail: str = "zero") -> jax.Array:
     """``silu(rows @ gate) * (rows @ up)`` group by group, in one pass
     over the rows: both products in float32, rounded once to
     ``out_dtype``. Arguments as ``grouped_matmul``'s."""
     return _grouped(rows, (gate_stack, up_stack), sizes, first_group,
-                    out_dtype)
+                    out_dtype, tail)

@@ -14,7 +14,8 @@ bucket keep the compile-cache menu finite. It ends in the next token of
 each row (``models.llama.llama_next_token``: the head meets only each
 row's last position, the argmax runs on the device), so a step brings
 ``bucket`` int32s to the host (and, from a model with experts, two
-float32 a routed layer of its routers' load) and the ``[bucket, S, vocab]``
+float32 a routed layer of its routers' load, three where the replica
+holds a share of the experts) and the ``[bucket, S, vocab]``
 logits are never made. ``LlamaGenerator._fwd`` is the same program
 followed by the head over every position, for callers that want the
 logits themselves: it compiles what ``_step`` runs, so warming a shape
@@ -73,9 +74,13 @@ class LlamaGenerator:
     # what `_step` counts (`engine_stats`): bytes of device results brought
     # to the host, positions computed (rows x padded length) and live among
     # them, and over live positions the (position, expert) pairs of the
-    # fullest and of the mean expert, summed over steps and layers
+    # fullest and of the mean expert, summed over steps and layers; of a
+    # replica that holds a share of each layer's experts those two are over
+    # the held experts, `expert_pairs_here` is all of theirs and
+    # `expert_pairs_all` the pairs its routers made over every expert
     STEP_COUNTERS = ("host_bytes", "positions_computed", "positions_live",
-                     "expert_pairs_fullest", "expert_pairs_mean")
+                     "expert_pairs_fullest", "expert_pairs_mean",
+                     "expert_pairs_here", "expert_pairs_all")
 
     def __init__(self, config: str = "tiny", lora_rank: int = 4,
                  max_batch_size: int = 4,
@@ -86,9 +91,13 @@ class LlamaGenerator:
 
         from ray_tpu.models.llama import (
             LlamaConfig, LoraConfig, init_llama)
+        from ray_tpu.models.moe import held_experts
 
         self._cfg = getattr(LlamaConfig, config)() \
             if isinstance(config, str) else config
+        # how many of a routed layer's experts this replica holds
+        self._experts_held = (held_experts(self._cfg)[1]
+                              if self._cfg.num_experts else 0)
         # adapt only the attention q/v projections: the cheap standard
         # LoRA target set, and enough for adapters to produce distinct
         # generations; the stacks are over the attention layers alone in a
@@ -235,6 +244,14 @@ class LlamaGenerator:
             counts["host_bytes"] += fullest.nbytes + mean.nbytes
             counts["expert_pairs_fullest"] += float(fullest.sum())
             counts["expert_pairs_mean"] += float(mean.sum())
+            pairs_here = float(mean.sum()) * self._experts_held
+            pairs_all = pairs_here
+            if "all" in load:  # a share: the router's pairs on every chip
+                everywhere = np.asarray(load["all"])
+                counts["host_bytes"] += everywhere.nbytes
+                pairs_all = float(everywhere.sum())
+            counts["expert_pairs_here"] += pairs_here
+            counts["expert_pairs_all"] += pairs_all
         results: List[Optional[tuple]] = [None] * len(states)
         for row, (idx, s) in enumerate(live):
             nxt = int(ids[row])
@@ -261,7 +278,10 @@ class LlamaGenerator:
         among them), ``expert_pairs_fullest`` and ``expert_pairs_mean``
         (over live positions, the (position, expert) pairs of the fullest
         and of the mean expert, summed over steps and over the layers that
-        have routed experts; 0 for a model without experts); and
+        have routed experts; 0 for a model without experts),
+        ``expert_pairs_here`` and ``expert_pairs_all`` (the pairs on the
+        experts this replica holds and the pairs its routers made over
+        every expert: the same unless ``experts_held`` is a share); and
         ``layer_kinds``, how many layers of each kind this replica serves
         (``LlamaConfig.kind_counts``: ``attention_dense`` alone for a dense
         decoder)."""

@@ -112,17 +112,47 @@ def test_a_head_of_64_compiles_within_its_reckoning(seq, backward, one_chip,
     assert max(_scoped_vmem(compiled)) <= reckoned <= fa.VMEM_LIMIT_BYTES
 
 
+# latent attention's forward at two widths (serve_dsv2_docqa's seven
+# buckets): heads of 128 + 64 against values of 128, the rotary key one row
+# a position; the blocks of 64 pad to the lane width in VMEM, which is what
+# the reckoning is told
+@pytest.mark.parametrize("seq", [256, 512, 768, 1024, 1280, 1536, 1792])
+def test_the_two_width_forward_compiles_within_its_reckoning(
+        seq, one_chip, compiled_for_tpu):
+    def of(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    q, q_rope = of(1, 2, seq, 128), of(1, 2, seq, 64)
+    compiled = jax.jit(
+        lambda q, qr, k, kr, v: fa._flash_fwd_shared_rope(
+            q, qr, k, kr, v, scale=0.1147, causal=True)
+    ).lower(q, q_rope, q, of(1, seq, 64), q).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert fa.SHARED_ROPE_TRACE_NAME in compiled.as_text()
+    tile = fa.flash_tiles(seq, seq, head_dim=256, value_dim=128)
+    reckoned = fa.tile_vmem_bytes(*tile, head_dim=256, value_dim=128)
+    used = [n for n in _scoped_vmem(compiled) if n]
+    assert used and max(used) <= reckoned <= fa.VMEM_LIMIT_BYTES
+
+
 # the serving cells' expert matmuls at the shortest, the median and the
 # longest bucket, read from a layers x 64 stack: serve_olmoe_chat's (8
 # experts a token, width 1024, to 1152) and serve_lfm2_rag's (4, 1536, 1408)
+# and serve_dsv2_docqa's (6 pairs a position over 160 experts of which the
+# 20 held have groups, width 1536 from a hidden of 5120, to 1792): the tail
+# is the absent experts' rows, unwritten
 @pytest.mark.parametrize("fused", [True, False])
-@pytest.mark.parametrize("seq, pairs, width", [
-    (128, 8, 1024), (512, 8, 1024), (1152, 8, 1024),
-    (128, 4, 1536), (384, 4, 1536), (1408, 4, 1536)])
-def test_grouped_matmul_compiles_within_its_reckoning(seq, pairs, width, fused,
-                                                      one_chip,
-                                                      compiled_for_tpu):
-    rows, experts, hidden = 8 * pairs * seq, 64, 2048
+@pytest.mark.parametrize("seq, pairs, width, hidden, experts, tail", [
+    (128, 8, 1024, 2048, 64, "zero"), (512, 8, 1024, 2048, 64, "zero"),
+    (1152, 8, 1024, 2048, 64, "zero"),
+    (128, 4, 1536, 2048, 64, "zero"), (384, 4, 1536, 2048, 64, "zero"),
+    (1408, 4, 1536, 2048, 64, "zero"),
+    (256, 6, 1536, 5120, 20, "unwritten"),
+    (1792, 6, 1536, 5120, 20, "unwritten")])
+def test_grouped_matmul_compiles_within_its_reckoning(
+        seq, pairs, width, hidden, experts, tail, fused, one_chip,
+        compiled_for_tpu):
+    rows = 8 * pairs * seq
     k, n = (hidden, width) if fused else (width, hidden)
     out = jnp.bfloat16 if fused else jnp.float32
     x = jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=one_chip)
@@ -132,11 +162,11 @@ def test_grouped_matmul_compiles_within_its_reckoning(seq, pairs, width, fused,
     layer = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     if fused:
         compiled = jax.jit(lambda x, g, u, s, i: gm.grouped_swiglu(
-            x, g, u, s, i * experts, out)).lower(
+            x, g, u, s, i * experts, out, tail)).lower(
                 x, stack, stack, sizes, layer).compile()
     else:
         compiled = jax.jit(lambda x, w, s, i: gm.grouped_matmul(
-            x, w, s, i * experts, out)).lower(
+            x, w, s, i * experts, out, tail)).lower(
                 x, stack, sizes, layer).compile()
     assert "tpu_custom_call" in compiled.as_text()
     stacks = 2 if fused else 1

@@ -86,6 +86,42 @@ def test_a_row_count_the_tile_does_not_divide(rows, sizes):
                                per_group(x, w, sizes), atol=1e-4, rtol=0)
 
 
+# a share of the experts: most of the sorted rows belong to no group here
+@pytest.mark.parametrize("fused", [False, True])
+@pytest.mark.parametrize("sizes", [[100, 0, 60, 40], [0, 0, 0, 200],
+                                   [1, 0, 0, 0], [0, 0, 0, 0],
+                                   [300, 300, 300, 124]])
+def test_an_unwritten_tail_is_not_visited(sizes, fused):
+    """``tail="unwritten"``: the groups' own rows are what they are with
+    the tail zeroed; no visit is the tail's, and with no row in any group
+    there is no visit at all."""
+    rows, live = 1024, sum(sizes)
+    x, w, u = operands(5, rows, 128, 256, 4, jnp.bfloat16)
+    sz = jnp.asarray(sizes, jnp.int32)
+    if fused:
+        zeroed = gm.grouped_swiglu(x, w, u, sz, 0, jnp.float32)
+        got = gm.grouped_swiglu(x, w, u, sz, 0, jnp.float32, "unwritten")
+    else:
+        zeroed = gm.grouped_matmul(x, w, sz, 0, jnp.float32)
+        got = gm.grouped_matmul(x, w, sz, 0, jnp.float32, "unwritten")
+    np.testing.assert_array_equal(np.asarray(got)[:live],
+                                  np.asarray(zeroed)[:live])
+    assert not np.asarray(zeroed)[live:].any()
+    tm = 512
+    tile, group, total, _, _ = gm._visits(sz, rows, tm, "unwritten")
+    visits = int(total[0])
+    assert visits == sum(-(-(e) // tm) - s // tm
+                         for s, e in zip(np.cumsum([0] + sizes[:-1]),
+                                         np.cumsum(sizes)) if e > s)
+    assert np.all(np.asarray(group)[:max(visits, 1)] < 4 + (visits == 0))
+    assert np.all((0 <= np.asarray(tile)) & (np.asarray(tile) < rows // tm))
+    # the zeroed tail is one more group's visits, over all its tiles
+    _, _, with_tail, _, _ = gm._visits(sz, rows, tm)
+    assert int(with_tail[0]) >= visits + (live < rows)
+    with pytest.raises(ValueError, match="tail 'skip'"):
+        gm.grouped_matmul(x, w, sz, 0, jnp.float32, "skip")
+
+
 @pytest.mark.parametrize("layer", [0, 2, 4])
 @pytest.mark.parametrize("fused", [False, True])
 def test_reads_its_layer_from_a_poisoned_stack(layer, fused):
