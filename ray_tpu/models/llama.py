@@ -900,7 +900,13 @@ def _hidden_and_books(
 ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
     """``llama_hidden``, and with it the routers' books stacked over the
     layers that have routed experts, in the model's order
-    (``moe.expert_ffn``; None for a model without experts)."""
+    (``moe.expert_ffn``; None for a model without experts). ``router_mask
+    [B, S]`` marks the positions that are a row's own: the books count
+    them alone and the routed experts multiply their pairs alone, so a
+    masked-out position's hidden state lacks its routed part (rows are
+    padded on the right and every operator is causal: no position that is
+    marked reads one that is not). Without a mask every position is
+    computed and counted."""
     B, S = tokens.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
@@ -928,7 +934,10 @@ def _hidden_and_books(
         def scan_fn(carry, xs):
             lp, lo_i, i = xs
             if routed:
-                lp = moe.in_stack(lp, stacks[kind], i, router_mask)
+                # the masked positions are all that is wanted of the
+                # routed experts: the others' pairs are not multiplied
+                lp = moe.in_stack(lp, stacks[kind], i, router_mask,
+                                  skip_unmasked=True)
             y, _, books = _layer(cfg, carry, lp, positions, lora=lo_i,
                                  lora_scale=scale)
             return y, books
@@ -1020,7 +1029,9 @@ def llama_next_token(
     who wants every position's logits applies ``llama_head`` to them and
     runs the layers once. The load is None for a model without experts,
     else ``moe.router_load`` over the positions ``live [B, S]`` marks (the
-    rows' own tokens and not their padding): two float32 a routed layer."""
+    rows' own tokens and not their padding): two float32 a routed layer.
+    With ``live`` the routed experts compute the marked positions alone,
+    and the hidden states of the others are not a forward pass's."""
     x, books = _hidden_and_books(params, tokens, cfg, lora=lora,
                                  lora_cfg=lora_cfg, router_mask=live)
     rows = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]

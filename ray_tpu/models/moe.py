@@ -36,16 +36,24 @@ chip of an expert-parallel deployment holds of a layer: the router stays
 of them, the three stacks hold the ``count`` experts from ``first`` on
 alone, and the result is those experts' part of the routed sum; what the
 absent experts would add is left out, and nothing stands in for the other
-chips or their traffic. The ``T x K`` pairs are sorted as ever, the pairs
-of absent experts past the held ones into no group, and the combine gives
-them zero: every pair's row is gathered and gathered back as for a model
-that holds all its experts, and the grouped matmuls do not visit the absent
-pairs' rows (``grouped_matmul``'s ``tail="unwritten"``: zeroed, as a model
-that holds all its experts has its empty tail, they would be seven eighths
-of the rows multiplied to write zeros). Nothing is dropped. A step's padded
-positions are kept off the held experts too (``in_stack``'s mask).
-The books count both: ``pairs`` over all the experts, ``pairs_here``
-over the held ones.
+chips or their traffic. The books count both: ``pairs`` over all the
+experts, ``pairs_here`` over the held ones.
+
+Which pairs are multiplied is one rule (``expert_ffn``'s ``keep``): a
+pair whose expert is held here AND whose position is wanted. Without a
+share every expert is held; a caller that hands ``in_stack`` a mask and
+says ``skip_unmasked`` wants the masked positions alone (a serving step's
+rows' own tokens: its padding is one token repeated, four fifths of the
+positions of a step in the benchmark's cells), and without that every
+position is wanted. The ``T x K`` pairs are sorted as ever, the pairs not
+kept past the kept ones into no group, and the combine gives them zero:
+every pair's row is gathered and gathered back as ever, and the grouped
+matmuls do not visit the rows past the last group
+(``grouped_matmul``'s ``tail="unwritten"``: zeroed, as ``ragged_dot``
+leaves them, they would be seven eighths of a share's rows multiplied to
+write zeros). Nothing is dropped: a kept pair's row is the same row times
+the same expert whatever else is kept. Where every pair is kept (training,
+a forward pass with no mask) there is no tail and no select.
 
 The dispatch drops nothing and has no capacity: the ``T x K`` (position,
 expert) pairs are sorted by expert, the rows gathered in that order, and
@@ -101,17 +109,22 @@ SHARED_LOGICAL_AXES = {
 }
 EXPERT_STACKS = ("we_gate", "we_up", "we_down")
 # keys `in_stack` adds to a layer's leaves: not leaves themselves
-_WHERE, _MASK = "_experts_in", "_router_mask"
+_WHERE, _MASK, _SKIP = "_experts_in", "_router_mask", "_skip_unmasked"
 
 
 def in_stack(lp: Dict[str, jax.Array], layers: Dict[str, jax.Array], layer,
-             mask: Optional[jax.Array] = None) -> Dict[str, jax.Array]:
+             mask: Optional[jax.Array] = None,
+             skip_unmasked: bool = False) -> Dict[str, jax.Array]:
     """The layer's leaves ``lp`` (``layers[name][layer]``) with where its
     experts lie: the three ``EXPERT_STACKS`` over all layers and the
     layer's index in them, for ``expert_ffn`` to read them in place; and
-    ``mask [B, S]``, the positions the router's books count."""
+    ``mask [B, S]``, the positions the router's books count. With
+    ``skip_unmasked`` the caller wants outputs at the masked positions
+    alone (a serving step's rows' own tokens, as against its padding): the
+    other positions' pairs are not multiplied and their routed part comes
+    out zero."""
     return dict(lp, **{_WHERE: ({n: layers[n] for n in EXPERT_STACKS}, layer),
-                       _MASK: mask})
+                       _MASK: mask, _SKIP: skip_unmasked})
 
 
 def init_experts(cfg, key: jax.Array, num_layers: int
@@ -292,20 +305,20 @@ def expert_ffn(cfg, h: jax.Array, lp: Dict[str, jax.Array]
     took), ``prob [E]`` (the router's scores summed over positions)
     and ``positions`` (how many were counted), all float32 and all over the
     positions where ``in_stack``'s mask is true (every position without
-    one). Padded positions are computed like any other; the mask only
-    keeps them out of the books. ``lp`` is the layer's leaves, as they are
-    or from ``in_stack``. With a share of the experts (``experts_held``)
-    the output is the held experts' part of the routed sum (and the shared
-    experts', whole), ``pairs`` stays over all the experts and
-    ``pairs_here [count]`` is the held ones' own; there the mask also
-    keeps the padded positions' pairs off the held experts (their routed
-    part is left out, like an absent expert's)."""
+    one). ``lp`` is the layer's leaves, as they are or from ``in_stack``.
+    Under ``in_stack``'s ``skip_unmasked`` the masked-out positions' pairs
+    are not multiplied and their routed part is zero (the shared experts'
+    part, every position's, stays); the bare mask keeps them out of the
+    books only, and they are computed like any other. With a share of the
+    experts (``experts_held``) the output is the held experts' part of the
+    routed sum (and the shared experts', whole), ``pairs`` stays over all
+    the experts and ``pairs_here [count]`` is the held ones' own; a share
+    reads the bare mask as ``skip_unmasked`` too (the legacy line below)."""
     dt = cfg.dtype
-    where, mask = lp.get(_WHERE), lp.get(_MASK)
+    where, mask, skip = lp.get(_WHERE), lp.get(_MASK), lp.get(_SKIP)
     B, S, H = h.shape
     E, K = cfg.num_experts, cfg.experts_per_token
     first, count = held_experts(cfg)
-    share = count < E
     T = B * S
     x = h.reshape(T, H)
     if cfg.router_groups and "router_bias" in lp:
@@ -341,29 +354,40 @@ def expert_ffn(cfg, h: jax.Array, lp: Dict[str, jax.Array]
     with jax.named_scope("moe_dispatch"):
         flat = chosen.reshape(T * K)
         by = flat
-        if share:
-            # the held experts' pairs first, by expert; the absent ones'
-            # past them, in no group, and weightless in the combine
-            here = (chosen >= first) & (chosen < first + count)   # [T, K]
-            if mask is not None:
-                # and of those the rows' own tokens' alone: a step's
-                # padding is one token repeated, which routes alike, and
-                # thousands of its rows on whichever held experts it likes
-                # are work for no result (and moved a step by 2 % from one
-                # draw of the weights to the next: PERF.md, PR 35)
-                here = here & mask.reshape(T, 1)
-            weights = jnp.where(here, weights, 0.0)
-            by = jnp.where(here, chosen - first, count).reshape(T * K)
+        # the pairs to multiply: their expert is held here AND their
+        # position is wanted; None where both hold of every pair
+        keep = None
+        if count < E:
+            keep = (chosen >= first) & (chosen < first + count)   # [T, K]
+        # `skip or`: the one statement of the rule. `count < E` is the
+        # legacy line: a share reads the books' bare mask as the positions
+        # wanted, which tests/benchmark/test_deepseek_v2.py::
+        # test_a_share_keeps_the_padding_off_and_drops_nothing pins, while
+        # test_olmoe.py::test_the_routers_books_count_live_positions_only
+        # pins `y_masked == y_all` for the same call on a model that holds
+        # all its experts; once a `benchmark` issue drops that assertion
+        # the two fold into `skip` alone (PERF.md section 7)
+        if mask is not None and (skip or count < E):
+            # a step's padding is one token repeated, which routes alike:
+            # thousands of its rows on whichever experts it likes are work
+            # for no result (four fifths of a serving step's pairs, PERF.md)
+            wanted = mask.reshape(T, 1)
+            keep = wanted if keep is None else keep & wanted
+        if keep is not None:
+            # the kept pairs first, by expert; the others past them, in no
+            # group, and weightless in the combine
+            weights = jnp.where(keep, weights, 0.0)
+            by = jnp.where(keep, chosen - first, count).reshape(T * K)
         order = jnp.argsort(by)                   # stable: pairs by expert
         onehot = flat[:, None] == jnp.arange(E, dtype=flat.dtype)[None, :]
         sizes = jnp.sum(onehot, axis=0, dtype=jnp.int32)     # [E]
-        if share:
+        if keep is not None:  # the kept pairs', over the held experts
             sizes = jnp.sum(by[:, None] == jnp.arange(
                 count, dtype=by.dtype)[None, :], axis=0, dtype=jnp.int32)
         rows = jnp.take(x, order // K, axis=0)               # [T*K, H]
-    # with a share most sorted rows are absent experts': not visited, and
-    # masked in the combine
-    tail = "unwritten" if share else "zero"
+    # the rows past the kept pairs' are not visited, and masked in the
+    # combine; where every pair is kept there are none
+    tail = "zero" if keep is None else "unwritten"
     with jax.named_scope("moe_experts"):
         if where is None or lp["we_gate"].dtype != dt:
             # the layer's slices are cast on their way in, which is the
@@ -386,8 +410,8 @@ def expert_ffn(cfg, h: jax.Array, lp: Dict[str, jax.Array]
         # back to the pairs' own order, then the weighted sum of each
         # position's K expert outputs, in float32
         out = jnp.take(out, jnp.argsort(order), axis=0).reshape(T, K, H)
-        if share:  # an absent pair's row was never written
-            out = jnp.where(here[:, :, None], out, 0.0)
+        if keep is not None:  # a pair not kept: its row was never written
+            out = jnp.where(keep[:, :, None], out, 0.0)
         y = jnp.einsum("tkh,tk->th", out, weights).astype(dt)
     if "ws_gate" in lp:
         with jax.named_scope("moe_shared"):
@@ -401,7 +425,7 @@ def expert_ffn(cfg, h: jax.Array, lp: Dict[str, jax.Array]
                                  onehot.astype(jnp.float32)),
              "prob": jnp.einsum("t,te->e", live, probs),
              "positions": jnp.sum(live)}
-    if share:
+    if count < E:
         books["pairs_here"] = books["pairs"][first:first + count]
     return y.reshape(B, S, H), books
 

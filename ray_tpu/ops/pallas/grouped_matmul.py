@@ -32,8 +32,9 @@ are the neighbouring visits'. Rows past the last group's end come out zero
 visits multiply and write zeros. A caller that masks those rows itself
 says ``tail="unwritten"`` and that group gets no visit: nothing is read,
 multiplied or written for them, and what the result holds there is not
-defined (``models/moe.py`` with a share of the experts, where seven
-eighths of the sorted pairs belong to experts on other chips).
+defined (``models/moe.py`` wherever some pairs are not kept: a share of
+the experts, where seven eighths of the sorted pairs belong to experts on
+other chips, and a serving step's padding, four fifths of its pairs).
 
 ``grouped_swiglu`` is the same kernel with two stacks: one pass over the
 rows holds the gate's and the up projection's blocks, accumulates both,

@@ -1,7 +1,7 @@
 """LoRA-adapter llama generation on the continuous batching engine.
 
 This is the serving shape the repo's ``models/`` path is meant to run at
-production RPS (ROADMAP item 1; reference: Ray Serve LLM deployments —
+production RPS (ROADMAP S3 and S2b; reference: Ray Serve LLM deployments —
 multiplexed LoRA adapters over a shared base model, iteration-level
 batching): one frozen base model per replica, per-request LoRA adapters
 multiplexed by model id, greedy decode driven step-by-step by
@@ -16,15 +16,21 @@ row's last position, the argmax runs on the device), so a step brings
 ``bucket`` int32s to the host (and, from a model with experts, two
 float32 a routed layer of its routers' load, three where the replica
 holds a share of the experts) and the ``[bucket, S, vocab]``
-logits are never made. ``LlamaGenerator._fwd`` is the same program
+logits are never made. A model with experts is told which positions are
+the rows' own, and its routed experts multiply those alone: the
+padding's pairs are sorted past the last expert's and get no visit
+(``models/moe.py::expert_ffn``; ``expert_pairs_skipped`` counts them).
+``LlamaGenerator._fwd`` is the step's function with no mask (every
+position computed; for a dense model the very program ``_step`` runs),
 followed by the head over every position, for callers that want the
-logits themselves: it compiles what ``_step`` runs, so warming a shape
-through it warms the step. ``warm_step_programs``,
-``logits_after_prompt`` and ``compiled_step_programs`` are what a
-benchmark asks of a served class.
-Decoding still recomputes the full prefix each step (a kv-cache
-paged-attention variant slots into ``_step`` without touching the engine
-contract).
+logits themselves. What a benchmark asks of a served class is
+``warm_step_programs``, ``compiled_step_programs``, ``engine_stats()``
+with ``positions_computed`` and ``positions_live`` (and ``experts_met``,
+``attention_pairs`` and ``attention_keys`` once a program counts them:
+PERF.md section 7), and the stream it serves.
+Decoding still recomputes the full prefix each step (ROADMAP S3: a cached
+decode changes what ``_step`` computes, and waits for the ``benchmark``
+issue that PERF.md section 7 briefs).
 
 Usage::
 
@@ -77,10 +83,12 @@ class LlamaGenerator:
     # fullest and of the mean expert, summed over steps and layers; of a
     # replica that holds a share of each layer's experts those two are over
     # the held experts, `expert_pairs_here` is all of theirs and
-    # `expert_pairs_all` the pairs its routers made over every expert
+    # `expert_pairs_all` the pairs its routers made over every expert;
+    # `expert_pairs_skipped` the padding's pairs, which no expert multiplied
     STEP_COUNTERS = ("host_bytes", "positions_computed", "positions_live",
                      "expert_pairs_fullest", "expert_pairs_mean",
-                     "expert_pairs_here", "expert_pairs_all")
+                     "expert_pairs_here", "expert_pairs_all",
+                     "expert_pairs_skipped")
 
     def __init__(self, config: str = "tiny", lora_rank: int = 4,
                  max_batch_size: int = 4,
@@ -98,6 +106,11 @@ class LlamaGenerator:
         # how many of a routed layer's experts this replica holds
         self._experts_held = (held_experts(self._cfg)[1]
                               if self._cfg.num_experts else 0)
+        # the (position, expert) pairs a position makes over the routed
+        # layers: 0 for a dense model
+        self._pairs_a_position = self._cfg.experts_per_token * sum(
+            n for kind, n in self._cfg.kind_counts().items()
+            if kind.endswith("_routed"))
         # adapt only the attention q/v projections: the cheap standard
         # LoRA target set, and enough for adapters to produce distinct
         # generations; the stacks are over the attention layers alone in a
@@ -237,7 +250,12 @@ class LlamaGenerator:
         counts = self._counts
         counts["host_bytes"] += ids.nbytes
         counts["positions_computed"] += bucket * pad_len
-        counts["positions_live"] += int(mask.sum())
+        live_positions = int(mask.sum())
+        counts["positions_live"] += live_positions
+        # reckoned here, from the mask the step handed the program: no
+        # device result says it
+        counts["expert_pairs_skipped"] += (
+            (bucket * pad_len - live_positions) * self._pairs_a_position)
         if load is not None:
             fullest, mean = np.asarray(load["fullest"]), np.asarray(
                 load["mean"])
@@ -281,7 +299,11 @@ class LlamaGenerator:
         have routed experts; 0 for a model without experts),
         ``expert_pairs_here`` and ``expert_pairs_all`` (the pairs on the
         experts this replica holds and the pairs its routers made over
-        every expert: the same unless ``experts_held`` is a share); and
+        every expert: the same unless ``experts_held`` is a share),
+        ``expert_pairs_skipped`` (the pairs of a step's padding, which the
+        step's mask keeps off the routed experts: (``positions_computed`` -
+        ``positions_live``) x ``experts_per_token`` x routed layers, counted
+        on the host; 0 for a model without experts); and
         ``layer_kinds``, how many layers of each kind this replica serves
         (``LlamaConfig.kind_counts``: ``attention_dense`` alone for a dense
         decoder)."""

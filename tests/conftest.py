@@ -120,6 +120,13 @@ FAST_FILES = {
     # interpreted at lane-grid shapes, and `moe.py` through it against
     # itself through `ragged_dot` (under a minute)
     "test_grouped_matmul.py",
+    # a serving step's padding stays off the routed experts: `expert_ffn`
+    # under the step's mask, both grouped matmuls, and `LlamaGenerator.
+    # _step` over a padded batch (two minutes: it forgets every trace
+    # between the kernel's path and `ragged_dot`'s)
+    "test_expert_padding.py",
+    # each cell's programs, hashed: what a PR left alone and what it moved
+    "test_cell_programs.py",
     # the model layer's own tests (ISSUE 30): what the three cells trace.
     # The one block's forward, `mixed:K` remat, cached decode, the chunked
     # loss, LoRA; the train step on an fsdp x tensor mesh and the

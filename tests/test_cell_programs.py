@@ -1,0 +1,70 @@
+"""The text of each benchmark cell's programs, hashed: a PR that did not
+mean to change a cell's program finds here that it left it alone, and one
+that did re-baselines the lines it meant to move and no other.
+
+A program is ``tests/benchmark/test_deepseek_v2.py::program_text``'s: the
+jaxpr, at the cell's real sizes and with addresses blanked, of the model's
+initialiser (``init``), of a serving step at 8 rows of the cell's shortest
+and of its longest warmed length (``step<length>``), or of training's
+value-and-gradient at 2 x 4096 (``grad``). Only shapes are traced: no
+weight is made.
+
+Regenerate (prints the table below; paste the lines a PR means to move)::
+
+    JAX_PLATFORMS=cpu python tests/test_cell_programs.py
+
+History. PR 37 (a step's padding stays off the routed experts) moved
+``serve_olmoe_chat``'s and ``serve_lfm2_rag``'s four step programs; the ten
+others are what its parent ``2e5068b`` gives (``serve_chat_steady`` and
+``train_l2_seq4k`` have no routed layer, and ``serve_dsv2_docqa``'s share
+kept its padding off already).
+"""
+
+import hashlib
+
+import pytest
+
+PROGRAMS = {
+    "train_l2_seq4k.init": "d02563bf9b97ea28",
+    "train_l2_seq4k.grad": "cc40eeae09e6ec41",
+    "serve_chat_steady.init": "3ff45d688c80d7f8",
+    "serve_chat_steady.step128": "ad4fedae56577eac",
+    "serve_chat_steady.step384": "f2df55b80038d939",
+    "serve_olmoe_chat.init": "126fada9fb96dc80",
+    "serve_olmoe_chat.step128": "5de1ca4a0a58274c",
+    "serve_olmoe_chat.step1152": "9847f92a8bb11cd7",
+    "serve_lfm2_rag.init": "5766fc6f6af74d3d",
+    "serve_lfm2_rag.step128": "30990bfd106ba8f1",
+    "serve_lfm2_rag.step1408": "2c9a09928ab75c68",
+    "serve_dsv2_docqa.init": "91b10ec8ff63401e",
+    "serve_dsv2_docqa.step256": "e1ef689e819f8c3d",
+    "serve_dsv2_docqa.step1792": "7f52ab45feccc506",
+}
+
+
+def program_hash(program: str) -> str:
+    from tests.benchmark.test_deepseek_v2 import program_text
+
+    cell, which = program.split(".")
+    return hashlib.sha256(program_text(cell, which).encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("program", sorted(PROGRAMS))
+def test_a_cells_program_is_the_one_on_record(program):
+    assert program_hash(program) == PROGRAMS[program], (
+        f"{program} is another program than the one on record: if this PR "
+        "means to change it, regenerate (this file's docstring)")
+
+
+if __name__ == "__main__":
+    import os
+    import sys
+
+    import jax
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+
+    jax.config.update("jax_platforms", "cpu")
+    for name in PROGRAMS:
+        print(f'    "{name}": "{program_hash(name)}",')

@@ -140,7 +140,9 @@ def test_the_two_width_forward_compiles_within_its_reckoning(
 # experts a token, width 1024, to 1152) and serve_lfm2_rag's (4, 1536, 1408)
 # and serve_dsv2_docqa's (6 pairs a position over 160 experts of which the
 # 20 held have groups, width 1536 from a hidden of 5120, to 1792): the tail
-# is the absent experts' rows, unwritten
+# is the absent experts' rows, unwritten. Since PR 37 a step's padding is
+# every model's unwritten tail (the first two cells' shortest and longest
+# buckets below); a call with no mask has none and says "zero"
 @pytest.mark.parametrize("fused", [True, False])
 @pytest.mark.parametrize("seq, pairs, width, hidden, experts, tail", [
     (128, 8, 1024, 2048, 64, "zero"), (512, 8, 1024, 2048, 64, "zero"),
@@ -148,7 +150,11 @@ def test_the_two_width_forward_compiles_within_its_reckoning(
     (128, 4, 1536, 2048, 64, "zero"), (384, 4, 1536, 2048, 64, "zero"),
     (1408, 4, 1536, 2048, 64, "zero"),
     (256, 6, 1536, 5120, 20, "unwritten"),
-    (1792, 6, 1536, 5120, 20, "unwritten")])
+    (1792, 6, 1536, 5120, 20, "unwritten"),
+    (128, 8, 1024, 2048, 64, "unwritten"),
+    (1152, 8, 1024, 2048, 64, "unwritten"),
+    (128, 4, 1536, 2048, 64, "unwritten"),
+    (1408, 4, 1536, 2048, 64, "unwritten")])
 def test_grouped_matmul_compiles_within_its_reckoning(
         seq, pairs, width, hidden, experts, tail, fused, one_chip,
         compiled_for_tpu):
