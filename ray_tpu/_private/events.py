@@ -41,6 +41,7 @@ import mmap
 import os
 import random
 import struct
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
@@ -313,6 +314,75 @@ def current_ctx() -> Optional[Tuple[int, int]]:
     """Executor-side: the trace context of the task currently running on
     this thread (None outside a sampled task)."""
     return _CUR_CTX.get()
+
+
+# ------------------------------------------------------------- span helper
+def sampled_root() -> Optional[Tuple[int, int]]:
+    """``(trace_id, 0)`` for a root the armed recorder samples, else None:
+    what a caller hands :class:`span` (``trace=``) for spans that share a
+    trace and name no parent. One branch when the recorder is off."""
+    return (REC.next_id(), 0) if REC.sample() else None
+
+
+class span:
+    """One interval of a host path, timed once and given to two outlets.
+
+    ``with span(name, cat, extra, trace) as sp`` reads
+    ``time.perf_counter()`` on the way in and out (``sp.t0``, ``sp.t1``:
+    the caller's counters are sums of these, so a counter and its span are
+    one interval) and
+
+    - records into the ring as ``REC.record`` does (ids, parent,
+      ``time.time()`` stamp) when the recorder is armed and the span
+      belongs to a sampled trace: ``trace`` is the ``(trace_id, parent
+      span id)`` to record under, and with none given the span is a child
+      of the span or task that encloses it on this thread
+      (``current_ctx()``), else nothing is recorded. ``extra`` may be set
+      on ``sp`` until the block ends;
+    - enters ``jax.profiler.TraceAnnotation("ray_tpu:" + name)`` when jax
+      is ALREADY imported in this process (this module never imports it:
+      a process without jax pays one dict lookup), so whenever anybody
+      traces the process the span lies in the same ``.xplane.pb`` as the
+      device's operations, on the profiler's clock. With no trace running
+      an annotation is a check of one atomic.
+    """
+
+    __slots__ = ("name", "cat", "extra", "trace", "t0", "t1", "_ids",
+                 "_ts", "_tok", "_ann")
+
+    def __init__(self, name: str, cat: str, extra: Optional[Dict] = None,
+                 trace: Optional[Tuple[int, int]] = None):
+        self.name = name
+        self.cat = cat
+        self.extra = extra
+        self.trace = trace
+        self.t0 = self.t1 = 0.0
+        self._ids = self._ann = None
+
+    def __enter__(self) -> "span":
+        if REC.enabled:
+            parent = self.trace if self.trace is not None \
+                else _CUR_CTX.get()
+            if parent is not None:
+                self._ids = (parent[0], REC.next_id(), parent[1])
+                self._tok = _CUR_CTX.set(self._ids[:2])
+                self._ts = time.time()
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        if profiler is not None:
+            self._ann = profiler.TraceAnnotation("ray_tpu:" + self.name)
+            self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        if self._ids is not None:
+            _CUR_CTX.reset(self._tok)
+            REC.record(self.name, self.cat, self._ts, self.t1 - self.t0,
+                       *self._ids, self.extra)
+        return False
 
 
 # ------------------------------------------------------------ ring recovery

@@ -53,6 +53,7 @@ import time
 import weakref
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
+from ray_tpu._private import events
 from ray_tpu.exceptions import BackPressureError
 
 _DONE = object()
@@ -83,7 +84,7 @@ class _EngineError:
 
 class _Request:
     __slots__ = ("payload", "model_id", "state", "out", "cancelled",
-                 "joined_at")
+                 "queued_at", "joined_at", "emitted", "trace")
 
     def __init__(self, payload: Any, model_id: str):
         self.payload = payload
@@ -91,7 +92,24 @@ class _Request:
         self.state: Any = None
         self.out: "queue.SimpleQueue" = queue.SimpleQueue()
         self.cancelled = False
+        # `time.perf_counter()` on entering `_pending` and on getting a row
+        self.queued_at = 0.0
         self.joined_at = 0.0
+        self.emitted = 0
+        # (trace_id, 0) when the flight recorder samples this request
+        self.trace = events.sampled_root()
+
+
+def _request_span(name: str, req: _Request, dur_s: float) -> None:
+    """A sampled request's span, ended now. The ring's alone: it starts on
+    the caller's thread and ends on the stepper's, which no
+    ``TraceAnnotation`` can. A state that says its ``prompt_len`` (as
+    ``LlamaGenerator``'s does) has it in the extra."""
+    extra = {"emitted": req.emitted}
+    if isinstance(req.state, dict) and "prompt_len" in req.state:
+        extra["prompt_len"] = req.state["prompt_len"]
+    events.REC.record(name, "serve", time.time() - dur_s, dur_s,
+                      req.trace[0], events.REC.next_id(), 0, extra)
 
 
 class ContinuousBatchingEngine:
@@ -127,13 +145,28 @@ class ContinuousBatchingEngine:
         self._stopped = False
 
         # counters (exposed via stats(); the replica folds them into its
-        # health probe so the controller/bench see engine behavior)
+        # health probe so the controller/bench see engine behavior).
+        # Counts: steps, emitted, completed, shed, the widest batch, padded
+        # slots, `joined` (requests that got a row). Seconds, each a sum of
+        # `time.perf_counter()` intervals that are also spans
+        # (`events.span`): `active_s`, whole iterations from
+        # `engine.admit` through `engine.step_fn` to the end of
+        # `engine.emit` (what it leaves of a wall time is an engine with
+        # nothing to run); `queue_wait_s` and `queue_wait_max_s`, the sum
+        # and the longest of the waits in `_pending` of the requests that
+        # joined (`request.queue`). The stepper alone writes them (`shed`
+        # apart: `submit` does, under the lock); `stats()` reads them
+        # under the lock.
         self._steps = 0
         self._emitted = 0
         self._completed = 0
         self._shed = 0
         self._max_batch_seen = 0
         self._padded_slots = 0
+        self._joined = 0
+        self._active_s = 0.0
+        self._queue_wait_s = 0.0
+        self._queue_wait_max_s = 0.0
         _live_engines.add(self)
 
     # ---------------------------------------------------------------- public
@@ -176,12 +209,15 @@ class ContinuousBatchingEngine:
         with self._lock:
             if self._stopped:
                 raise RuntimeError(f"{self.name}: engine is shut down")
+            req.queued_at = time.perf_counter()
             self._pending.append(req)
             self._ensure_thread_locked()
         self._wake.set()
         return self._consume(req)
 
-    def stats(self) -> Dict[str, int]:
+    def stats(self) -> Dict[str, float]:
+        """The counters, a flat dict of numbers (the replica's health
+        probe calls this)."""
         with self._lock:
             running = sum(len(g) for g in self._groups.values())
             return {
@@ -190,6 +226,9 @@ class ContinuousBatchingEngine:
                 "running": running, "pending": len(self._pending),
                 "max_batch": self._max_batch_seen,
                 "padded_slots": self._padded_slots,
+                "joined": self._joined, "active_s": self._active_s,
+                "queue_wait_s": self._queue_wait_s,
+                "queue_wait_max_s": self._queue_wait_max_s,
             }
 
     def shutdown(self, timeout: float = 5.0) -> None:
@@ -243,28 +282,27 @@ class ContinuousBatchingEngine:
             with self._lock:
                 if self._stopped:
                     return
-                self._admit_locked()
-                model_id, batch = self._select_locked()
-                if batch is None and not self._pending:
+                idle = not self._pending and not any(self._groups.values())
+                if idle:
                     # nothing to do: wait for work, exit when idle past
                     # the timeout (restarted lazily by the next submit)
                     self._wake.clear()
-            if batch is None:
-                if not self._wake.wait(self.idle_timeout_s):
-                    with self._lock:
-                        if not self._pending and not any(
-                                self._groups.values()) \
-                                and self._thread is \
-                                threading.current_thread():
-                            self._thread = None
-                            return
-                continue
-            self._step(model_id, batch)
+            if not idle:
+                self._iteration()
+            elif not self._wake.wait(self.idle_timeout_s):
+                with self._lock:
+                    if not self._pending and not any(
+                            self._groups.values()) \
+                            and self._thread is \
+                            threading.current_thread():
+                        self._thread = None
+                        return
 
     def _admit_locked(self) -> None:
         """Join waiting requests at the step boundary, FIFO, capped by the
         per-group batch width."""
         skipped: List[_Request] = []
+        now = time.perf_counter()
         while self._pending:
             req = self._pending.popleft()
             if req.cancelled:
@@ -276,7 +314,13 @@ class ContinuousBatchingEngine:
             if len(group) >= self.max_batch_size:
                 skipped.append(req)  # group full: wait for a leave
                 continue
-            req.joined_at = time.monotonic()
+            wait = now - req.queued_at
+            req.joined_at = now
+            self._joined += 1
+            self._queue_wait_s += wait
+            self._queue_wait_max_s = max(self._queue_wait_max_s, wait)
+            if req.trace is not None:
+                _request_span("request.queue", req, wait)
             group.append(req)
         self._pending.extendleft(reversed(skipped))
 
@@ -292,6 +336,7 @@ class ContinuousBatchingEngine:
                 live = [r for r in group if not r.cancelled]
                 if len(live) != len(group):
                     self._groups[mid] = live
+                    self._left([r for r in group if r.cancelled])
                 if live:
                     return mid, list(live[:self.max_batch_size])
             if not self._groups.get(mid):
@@ -302,39 +347,70 @@ class ContinuousBatchingEngine:
                     pass
         return None, None
 
-    def _step(self, model_id: str, batch: List[_Request]) -> None:
-        # flight recorder (ISSUE 14): one sampled `engine_step` slice per
-        # iteration — batch size / bucket / pad in the extras answer
-        # "where did serving time go" without any engine-specific probe
-        from ray_tpu._private.events import REC as _rec
-
-        ev_trace = _rec.new_trace() if _rec.enabled and _rec.sample() \
-            else None
-        ev_t0 = time.time() if ev_trace is not None else 0.0
-        states: List[Optional[Any]] = [r.state for r in batch]
-        bucket = self.bucket_for(len(states))
-        pad = bucket - len(states)
-        if pad > 0:
-            states = states + [None] * pad
-        try:
-            results = self.step_fn(model_id, states)
-        except BaseException as e:  # noqa: BLE001 — user step code
-            with self._lock:
-                group = self._groups.get(model_id, [])
-                for r in batch:
-                    try:
-                        group.remove(r)
-                    except ValueError:
-                        pass
-            for r in batch:
-                r.out.put(_EngineError(e))
+    def _left(self, reqs: List[_Request]) -> None:
+        """The requests that gave their rows back (done, cancelled or
+        failed): a sampled one's ``request.generate`` ends here."""
+        if not events.REC.enabled:
             return
+        now = time.perf_counter()
+        for r in reqs:
+            if r.trace is not None:
+                _request_span("request.generate", r, now - r.joined_at)
+
+    def _iteration(self) -> None:
+        """One pass of the stepper, as three sibling spans that tile it:
+        ``engine.admit`` (the lock, the joins, the choice of a group,
+        padding it to its bucket), ``engine.step_fn`` (the call, whatever
+        the function) and ``engine.emit`` (results to the requests'
+        queues, the finished retired). A pass that finds no batch (every
+        waiting request had been cancelled) ends in ``engine.admit``."""
+        trace = events.sampled_root()
+        with events.span("engine.admit", "serve", trace=trace) as admit:
+            with self._lock:
+                self._admit_locked()
+                model_id, batch = self._select_locked()
+                pending = len(self._pending)
+            if batch is None:
+                return
+            states: List[Optional[Any]] = [r.state for r in batch]
+            bucket = self.bucket_for(len(states))
+            pad = bucket - len(states)
+            if pad > 0:
+                states = states + [None] * pad
+            admit.extra = extra = {"rows": len(batch), "bucket": bucket,
+                                   "pad": pad, "pending": pending}
+        failure: Optional[BaseException] = None
+        results = None
+        with events.span("engine.step_fn", "serve", extra, trace):
+            try:
+                results = self.step_fn(model_id, states)
+            except BaseException as e:  # noqa: BLE001 — user step code
+                failure = e
+        with events.span("engine.emit", "serve", extra, trace) as emit:
+            finished = list(batch) if failure is not None \
+                else self._emit(batch, results, bucket, pad)
+            if finished:
+                with self._lock:
+                    group = self._groups.get(model_id, [])
+                    for r in finished:
+                        try:
+                            group.remove(r)
+                        except ValueError:
+                            pass
+                self._left(finished)
+                for r in finished:
+                    if failure is not None:
+                        r.out.put(_EngineError(failure))
+                    else:
+                        r.out.put(_DONE)
+                        self._completed += 1
+        self._active_s += emit.t1 - admit.t0
+
+    def _emit(self, batch: List[_Request], results, bucket: int,
+              pad: int) -> List[_Request]:
+        """A step's results to the requests' queues; returns the requests
+        that are done."""
         self._steps += 1
-        if ev_trace is not None:
-            _rec.record("engine_step::" + str(model_id), "serve", ev_t0,
-                        time.time() - ev_t0, ev_trace[0], ev_trace[1], 0,
-                        {"batch": len(batch), "bucket": bucket,
-                         "pad": pad})
         self._max_batch_seen = max(self._max_batch_seen, len(batch))
         self._padded_slots += pad
         if results is None or len(results) < len(batch):
@@ -344,25 +420,14 @@ class ContinuousBatchingEngine:
                 f"bucket of {bucket} ({len(batch)} live)")
             for r in batch:
                 r.out.put(_EngineError(err))
-            results = []
-            finished = list(batch)
-        else:
-            finished = []
-            for r, res in zip(batch, results):
-                emit, done = (None, False) if res is None else res
-                if emit is not None and not r.cancelled:
-                    r.out.put(emit)
-                    self._emitted += 1
-                if done:
-                    finished.append(r)
-        if finished:
-            with self._lock:
-                group = self._groups.get(model_id, [])
-                for r in finished:
-                    try:
-                        group.remove(r)
-                    except ValueError:
-                        pass
-            for r in finished:
-                r.out.put(_DONE)
-                self._completed += 1
+            return list(batch)
+        finished = []
+        for r, res in zip(batch, results):
+            emit, done = (None, False) if res is None else res
+            if emit is not None and not r.cancelled:
+                r.out.put(emit)
+                r.emitted += 1
+                self._emitted += 1
+            if done:
+                finished.append(r)
+        return finished

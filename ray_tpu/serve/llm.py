@@ -48,6 +48,7 @@ import threading
 import zlib
 from typing import Any, Dict, List, Optional, Sequence
 
+from ray_tpu._private import events
 from ray_tpu.serve._private.engine import ContinuousBatchingEngine
 from ray_tpu.serve.deployment import Application, Deployment
 
@@ -84,11 +85,14 @@ class LlamaGenerator:
     # replica that holds a share of each layer's experts those two are over
     # the held experts, `expert_pairs_here` is all of theirs and
     # `expert_pairs_all` the pairs its routers made over every expert;
-    # `expert_pairs_skipped` the padding's pairs, which no expert multiplied
+    # `expert_pairs_skipped` the padding's pairs, which no expert multiplied;
+    # `step_device_s` the seconds (`time.perf_counter()`) from the call of
+    # the step's program until its results are on the host, the span
+    # `llm.device`
     STEP_COUNTERS = ("host_bytes", "positions_computed", "positions_live",
                      "expert_pairs_fullest", "expert_pairs_mean",
                      "expert_pairs_here", "expert_pairs_all",
-                     "expert_pairs_skipped")
+                     "expert_pairs_skipped", "step_device_s")
 
     def __init__(self, config: str = "tiny", lora_rank: int = 4,
                  max_batch_size: int = 4,
@@ -226,56 +230,69 @@ class LlamaGenerator:
         host, and the routers' load with them where the model has
         experts. Nothing else runs on the device here (an op-by-op ``jnp``
         call would compile a program of its own per shape), and ``last``
-        goes in as numpy, as ``_fwd`` passes it."""
+        goes in as numpy, as ``_fwd`` passes it. Three spans tile it where
+        the device takes over (``events.span``): ``llm.prepare`` (the
+        numpy rows, the adapter's lookup), ``llm.device`` (from the call of
+        the program until its results are on the host, summed in
+        ``step_device_s``) and ``llm.finish`` (the counters, the tokens
+        appended, the result list)."""
         import numpy as np
 
-        live = [(i, s) for i, s in enumerate(states) if s is not None]
-        bucket = len(states)
-        pad_len = self._padded_len(states)
-        tokens = np.zeros((bucket, pad_len), np.int32)
-        # index of each row's newest token; 0 for a padded row
-        last = np.zeros(bucket, np.int32)
-        # a row's own tokens, as against its padding and the padded rows
-        # (which all carry token 0 and route alike): what a router's load
-        # is counted over
-        mask = np.zeros((bucket, pad_len), bool)
-        for row, (_, s) in enumerate(live):
-            ts = s["tokens"][-pad_len:]
-            tokens[row, :len(ts)] = ts
-            last[row] = len(ts) - 1
-            mask[row, :len(ts)] = True
-        ids, _, load = self._run_step(tokens, last, mask,
-                                      self._adapter(model_id))
-        ids = np.asarray(ids)
-        counts = self._counts
-        counts["host_bytes"] += ids.nbytes
-        counts["positions_computed"] += bucket * pad_len
-        live_positions = int(mask.sum())
-        counts["positions_live"] += live_positions
-        # reckoned here, from the mask the step handed the program: no
-        # device result says it
-        counts["expert_pairs_skipped"] += (
-            (bucket * pad_len - live_positions) * self._pairs_a_position)
-        if load is not None:
-            fullest, mean = np.asarray(load["fullest"]), np.asarray(
-                load["mean"])
-            counts["host_bytes"] += fullest.nbytes + mean.nbytes
-            counts["expert_pairs_fullest"] += float(fullest.sum())
-            counts["expert_pairs_mean"] += float(mean.sum())
-            pairs_here = float(mean.sum()) * self._experts_held
-            pairs_all = pairs_here
-            if "all" in load:  # a share: the router's pairs on every chip
-                everywhere = np.asarray(load["all"])
-                counts["host_bytes"] += everywhere.nbytes
-                pairs_all = float(everywhere.sum())
-            counts["expert_pairs_here"] += pairs_here
-            counts["expert_pairs_all"] += pairs_all
-        results: List[Optional[tuple]] = [None] * len(states)
-        for row, (idx, s) in enumerate(live):
-            nxt = int(ids[row])
-            s["tokens"].append(nxt)
-            done = len(s["tokens"]) - s["prompt_len"] >= s["max_new"]
-            results[idx] = (nxt, done)
+        with events.span("llm.prepare", "serve"):
+            live = [(i, s) for i, s in enumerate(states) if s is not None]
+            bucket = len(states)
+            pad_len = self._padded_len(states)
+            tokens = np.zeros((bucket, pad_len), np.int32)
+            # index of each row's newest token; 0 for a padded row
+            last = np.zeros(bucket, np.int32)
+            # a row's own tokens, as against its padding and the padded
+            # rows (which all carry token 0 and route alike): what a
+            # router's load is counted over
+            mask = np.zeros((bucket, pad_len), bool)
+            for row, (_, s) in enumerate(live):
+                ts = s["tokens"][-pad_len:]
+                tokens[row, :len(ts)] = ts
+                last[row] = len(ts) - 1
+                mask[row, :len(ts)] = True
+            lora = self._adapter(model_id)
+        # dispatch, transfer in, the program, transfer out: until every
+        # result the host reads is on the host
+        with events.span("llm.device", "serve") as device:
+            ids, _, load = self._run_step(tokens, last, mask, lora)
+            ids = np.asarray(ids)
+            if load is not None:
+                load = {k: np.asarray(v) for k, v in load.items()}
+        with events.span("llm.finish", "serve"):
+            counts = self._counts
+            counts["step_device_s"] += device.t1 - device.t0
+            counts["host_bytes"] += ids.nbytes
+            counts["positions_computed"] += bucket * pad_len
+            live_positions = int(mask.sum())
+            counts["positions_live"] += live_positions
+            # reckoned here, from the mask the step handed the program: no
+            # device result says it
+            counts["expert_pairs_skipped"] += (
+                (bucket * pad_len - live_positions)
+                * self._pairs_a_position)
+            if load is not None:
+                fullest, mean = load["fullest"], load["mean"]
+                counts["host_bytes"] += fullest.nbytes + mean.nbytes
+                counts["expert_pairs_fullest"] += float(fullest.sum())
+                counts["expert_pairs_mean"] += float(mean.sum())
+                pairs_here = float(mean.sum()) * self._experts_held
+                pairs_all = pairs_here
+                if "all" in load:  # a share: the router's pairs everywhere
+                    everywhere = load["all"]
+                    counts["host_bytes"] += everywhere.nbytes
+                    pairs_all = float(everywhere.sum())
+                counts["expert_pairs_here"] += pairs_here
+                counts["expert_pairs_all"] += pairs_all
+            results: List[Optional[tuple]] = [None] * len(states)
+            for row, (idx, s) in enumerate(live):
+                nxt = int(ids[row])
+                s["tokens"].append(nxt)
+                done = len(s["tokens"]) - s["prompt_len"] >= s["max_new"]
+                results[idx] = (nxt, done)
         return results
 
     def __call__(self, payload: Any):
@@ -303,7 +320,12 @@ class LlamaGenerator:
         ``expert_pairs_skipped`` (the pairs of a step's padding, which the
         step's mask keeps off the routed experts: (``positions_computed`` -
         ``positions_live``) x ``experts_per_token`` x routed layers, counted
-        on the host; 0 for a model without experts); and
+        on the host; 0 for a model without experts); ``step_device_s``
+        (seconds of ``time.perf_counter()`` from the call of a step's
+        program until its results are on the host: dispatch, transfer in,
+        the program, transfer out; beside the engine's ``active_s`` it says
+        how long an iteration the host works while the device has nothing
+        to do); and
         ``layer_kinds``, how many layers of each kind this replica serves
         (``LlamaConfig.kind_counts``: ``attention_dense`` alone for a dense
         decoder)."""

@@ -235,6 +235,190 @@ def test_engine_idle_stepper_exits():
 
 
 # ---------------------------------------------------------------------------
+# What the engine says it was doing (ISSUE 38): counters in stats(), and
+# the same intervals as spans in the flight recorder's ring
+# ---------------------------------------------------------------------------
+def _wait_until(pred, timeout=30.0):
+    deadline = time.monotonic() + timeout
+    while not pred():
+        assert time.monotonic() < deadline, "condition never held"
+        time.sleep(0.002)
+
+
+def test_engine_counts_queue_wait_and_active_time():
+    """A batch of 2 and a third request: its wait for a row is counted
+    (at least one step), the iterations' time lies between the steps' own
+    sleeps and the wall time, and stats() is a flat dict of numbers."""
+    delay = 0.05
+    eng = ContinuousBatchingEngine(
+        _mk_step(delay=delay), prefill_fn=_mk_prefill(), max_batch_size=2,
+        idle_timeout_s=0.2, name="waits")
+    t0 = time.perf_counter()
+    out = {}
+    threads = [threading.Thread(target=_collect,
+                                args=(eng, {"tag": f"r{i}", "n": n}, "",
+                                      out, i))
+               for i, n in enumerate((8, 8, 2))]
+    for t in threads[:2]:
+        t.start()
+    _wait_until(lambda: eng.stats()["running"] == 2)
+    threads[2].start()  # both rows are held for several more steps
+    for t in threads:
+        t.join(timeout=60)
+        assert not t.is_alive(), "engine request hung"
+    # a request is done before the iteration that finished it is summed:
+    # read once the stepper has found nothing more to do and gone
+    _wait_until(lambda: eng._thread is None)
+    wall = time.perf_counter() - t0
+    stats = eng.stats()
+    eng.shutdown()
+    assert [len(out[i]) for i in range(3)] == [8, 8, 2]
+    assert stats["joined"] == 3 and stats["completed"] == 3
+    assert stats["queue_wait_max_s"] >= delay, stats
+    assert stats["queue_wait_s"] >= stats["queue_wait_max_s"]
+    assert stats["queue_wait_s"] < wall
+    assert stats["steps"] * delay <= stats["active_s"] <= wall, (stats, wall)
+    for name, value in stats.items():
+        assert isinstance(value, (int, float)) \
+            and not isinstance(value, bool), (name, value)
+
+
+def test_engine_cancelled_before_joining_counts_no_wait():
+    gate = threading.Event()
+    eng = ContinuousBatchingEngine(
+        _mk_step(gate=gate), prefill_fn=_mk_prefill(), max_batch_size=1,
+        idle_timeout_s=0.2, name="cancelled")
+    first = threading.Thread(target=_collect,
+                             args=(eng, {"tag": "a", "n": 2}))
+    first.start()
+    _wait_until(lambda: eng.stats()["running"] == 1)
+    eng.submit({"tag": "b", "n": 2})  # the one row is held: it waits
+    with eng._lock:
+        assert len(eng._pending) == 1
+        eng._pending[0].cancelled = True  # its consumer went away
+    time.sleep(0.05)
+    gate.set()
+    first.join(timeout=60)
+    assert not first.is_alive()
+    _wait_until(lambda: eng.stats()["pending"] == 0)
+    stats = eng.stats()
+    eng.shutdown()
+    # it waited 50 ms and more: counted, it would be a second join and
+    # the sum would pass the longest
+    assert stats["joined"] == 1 and stats["completed"] == 1
+    assert stats["queue_wait_s"] == stats["queue_wait_max_s"]
+
+
+@pytest.fixture
+def armed_recorder(tmp_path):
+    """The process's own recorder at rate 1.0 (the engine records into
+    ``events.REC``), as ``tests/test_flight_recorder.py`` arms one of its
+    own; left as it was found."""
+    from ray_tpu._private import events
+
+    rec = events.REC
+    was = (rec.enabled, rec.sample_rate)
+    assert rec.configure(str(tmp_path), "unit", sample_rate=1.0)
+    rec.drain()
+    yield rec
+    rec.enabled, rec.sample_rate = was
+
+
+def test_engine_spans_in_the_flight_recorder(armed_recorder):
+    """Armed at 1.0, one request is one trace (``request.queue`` then
+    ``request.generate``), and an iteration is three sibling spans that
+    share a trace, name no parent, carry the batch in their extra and do
+    not overlap on the ring's clock."""
+    from ray_tpu._private.events import _span_dict
+
+    eng = ContinuousBatchingEngine(
+        _mk_step(delay=0.01), prefill_fn=_mk_prefill(), max_batch_size=2,
+        allowed_batch_sizes=(2,), idle_timeout_s=0.2, name="recorded")
+    assert _collect(eng, {"tag": "a", "n": 3}) == ["a1", "a2", "a3"]
+    eng.shutdown()
+    spans = [_span_dict(t) for t in armed_recorder.drain()]
+    by_name = {}
+    for sp in spans:
+        by_name.setdefault(sp["name"], []).append(sp)
+    assert sorted(by_name) == ["engine.admit", "engine.emit",
+                               "engine.step_fn", "request.generate",
+                               "request.queue"], sorted(by_name)
+    (queue,), (generate,) = by_name["request.queue"], \
+        by_name["request.generate"]
+    assert queue["trace"] == generate["trace"]
+    assert queue["extra"] == {"emitted": 0}
+    assert generate["extra"] == {"emitted": 3}
+    assert queue["ts_us"] + queue["dur_us"] <= generate["ts_us"] + 1000
+    assert generate["dur_us"] >= 3 * 10_000
+    assert len(by_name["engine.step_fn"]) == 3
+    for step in by_name["engine.step_fn"]:
+        admit, = [s for s in by_name["engine.admit"]
+                  if s["trace"] == step["trace"]]
+        emit, = [s for s in by_name["engine.emit"]
+                 if s["trace"] == step["trace"]]
+        assert step["trace"] != queue["trace"]
+        for sp in (admit, step, emit):
+            assert sp["parent"] == 0 and sp["cat"] == "serve"
+            assert sp["extra"] == {"rows": 1, "bucket": 2, "pad": 1,
+                                   "pending": 0}
+        assert admit["ts_us"] <= step["ts_us"] <= emit["ts_us"]
+        assert step["dur_us"] >= 10_000
+
+
+def test_span_helper_nests_under_the_enclosing_span(armed_recorder):
+    """With no trace given a span is a child of the span that encloses it
+    on its thread (how ``llm.prepare``, ``llm.device`` and ``llm.finish``
+    come under ``engine.step_fn``), and outside any it records nothing."""
+    from ray_tpu._private import events
+
+    with events.span("alone", "serve"):
+        pass
+    assert armed_recorder.drain() == []
+    with events.span("outer", "serve", trace=events.sampled_root()) as outer:
+        with events.span("inner", "serve", {"k": 1}) as inner:
+            time.sleep(0.002)
+    assert inner.t0 >= outer.t0 and inner.t1 <= outer.t1
+    got = {t[3]: events._span_dict(t) for t in armed_recorder.drain()}
+    assert got["inner"]["trace"] == got["outer"]["trace"]
+    assert got["inner"]["parent"] == got["outer"]["span"]
+    assert got["outer"]["parent"] == 0
+    assert got["inner"]["extra"] == {"k": 1}
+    assert got["inner"]["dur_us"] >= 2000
+
+
+def test_engine_serves_in_a_process_that_never_imports_jax():
+    """``engine.py`` and the span helper import no jax: a process without
+    it serves through the engine, spans and all."""
+    import subprocess
+    import sys
+
+    body = (
+        "import sys\n"
+        "from ray_tpu.serve._private.engine import "
+        "ContinuousBatchingEngine\n"
+        "def step(model_id, states):\n"
+        "    return [None if s is None else (s, True) for s in states]\n"
+        "eng = ContinuousBatchingEngine(step, max_batch_size=2, "
+        "idle_timeout_s=0.1)\n"
+        "out = [list(eng.submit(i)) for i in range(3)]\n"
+        "stats = eng.stats()\n"
+        "eng.shutdown()\n"
+        "assert out == [[0], [1], [2]], out\n"
+        "assert stats['joined'] == 3 and stats['steps'] == 3, stats\n"
+        "assert stats['active_s'] > 0, stats\n"
+        "assert 'jax' not in sys.modules, 'the engine imported jax'\n"
+        "print('served without jax')\n")
+    env = {**os.environ, "PYTHONPATH": os.pathsep.join(
+        [os.path.dirname(os.path.dirname(os.path.abspath(__file__)))]
+        + [p for p in os.environ.get("PYTHONPATH", "").split(os.pathsep)
+           if p])}
+    done = subprocess.run([sys.executable, "-c", body], env=env,
+                          capture_output=True, text=True, timeout=120)
+    assert done.returncode == 0, done.stderr
+    assert "served without jax" in done.stdout
+
+
+# ---------------------------------------------------------------------------
 # @serve.batch per-instance queue keying (satellite: WeakKeyDictionary)
 # ---------------------------------------------------------------------------
 def test_batch_queues_not_shared_across_instances():
@@ -624,3 +808,77 @@ def test_llama_step_compiles_nothing_after_fwd_warmed_its_shape():
             f"{len(compiles) - warmed} compilation(s) in a warmed step")
     finally:
         gen.engine.shutdown()
+
+
+def _host_events(path, prefix):
+    """(name, start_ns, end_ns, plane, line) of every event on a trace's
+    planes whose name starts with ``prefix``."""
+    from jax.profiler import ProfileData
+
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(prefix):
+                    out.append((ev.name, ev.start_ns,
+                                ev.start_ns + ev.duration_ns,
+                                plane.name, line.name))
+    return sorted(out, key=lambda e: e[1])
+
+
+def test_llama_spans_lie_in_the_profilers_trace(tmp_path):
+    """Under ``jax.profiler.start_trace`` the program's spans are in the
+    ``.xplane.pb`` beside whatever else the process did, on the
+    profiler's clock: every iteration is ``ray_tpu:engine.admit``,
+    ``ray_tpu:engine.step_fn``, ``ray_tpu:engine.emit`` in that order
+    without overlap, and each ``engine.step_fn`` holds
+    ``ray_tpu:llm.prepare``, ``ray_tpu:llm.device`` and
+    ``ray_tpu:llm.finish``, likewise. The device's half of a step is
+    inside the engine's iterations."""
+    import glob
+
+    import jax
+
+    gen = _llama_gen(allowed=(2,))
+    try:
+        list(gen({"prompt": [3, 5, 7], "max_new": 2}))  # compiles
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            with jax.profiler.TraceAnnotation("test:requests"):
+                out = list(gen({"prompt": [3, 5, 7, 9], "max_new": 4}))
+        finally:
+            jax.profiler.stop_trace()
+        assert len(out) == 4
+        stats = gen.engine_stats()
+    finally:
+        gen.engine.shutdown()
+    assert 0 < stats["step_device_s"] <= stats["active_s"], stats
+    path, = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    assert _host_events(path, "test:requests"), "the trace holds no span"
+    spans = _host_events(path, "ray_tpu:")
+    lines = {e[3:] for e in spans}
+    assert len(lines) == 1, f"the stepper is one thread: {lines}"
+    engine = [e for e in spans if e[0].startswith("ray_tpu:engine.")]
+    steps = [e for e in engine if e[0] == "ray_tpu:engine.step_fn"]
+    assert len(steps) == 4, [e[0] for e in engine]
+    want = ["ray_tpu:engine.admit", "ray_tpu:engine.step_fn",
+            "ray_tpu:engine.emit"]
+    # a pass that found every request gone ends in `engine.admit` alone
+    names = [e[0] for e in engine]
+    first = names.index("ray_tpu:engine.step_fn") - 1
+    assert names[first:first + 12] == want * 4, names
+    for (_, _, end, *_), (_, start, *_) in zip(engine, engine[1:]):
+        assert start >= end, "two of the engine's spans overlap"
+    inside = ["ray_tpu:llm.prepare", "ray_tpu:llm.device",
+              "ray_tpu:llm.finish"]
+    for _, s0, s1, *_ in steps:
+        children = [e for e in spans if e[0].startswith("ray_tpu:llm.")
+                    and s0 <= e[1] and e[2] <= s1]
+        assert [e[0] for e in children] == inside, children
+        for (_, _, end, *_), (_, start, *_) in zip(children, children[1:]):
+            assert start >= end, "two of a step's spans overlap"
+    assert len([e for e in spans if e[0].startswith("ray_tpu:llm.")]) == 12
