@@ -1021,10 +1021,12 @@ class NodeAgent:
             return None
         if self._forkserver_proc is None or \
                 self._forkserver_proc.poll() is not None:
-            from ray_tpu._private.config import keep_off_accelerator
+            from ray_tpu._private.config import (
+                keep_off_accelerator, whole_malloc_heaps)
 
             env = dict(os.environ)
             keep_off_accelerator(env)
+            whole_malloc_heaps(env)   # the template's, so every fork's
             try:
                 os.unlink(self._forkserver_sock + ".ready")
             except FileNotFoundError:
@@ -1125,9 +1127,11 @@ class NodeAgent:
             cmd, ray_env = worker_conda_command(conda_prefix, ray_env)
             env = dict(os.environ)
             env.update(ray_env)
-            from ray_tpu._private.config import keep_off_accelerator
+            from ray_tpu._private.config import (
+                keep_off_accelerator, whole_malloc_heaps)
 
             keep_off_accelerator(env)
+            whole_malloc_heaps(env)
         else:
             cmd = [sys.executable, "-m", "ray_tpu._private.worker_process"]
             env = dict(os.environ)
@@ -1135,9 +1139,11 @@ class NodeAgent:
             # Workers must not grab the TPU runtime by default: work that
             # requests chips names the platform and its chips from the
             # lease's instance ids (worker_process._apply_accelerator_env)
-            from ray_tpu._private.config import keep_off_accelerator
+            from ray_tpu._private.config import (
+                keep_off_accelerator, whole_malloc_heaps)
 
             keep_off_accelerator(env)
+            whole_malloc_heaps(env)
         proc = subprocess.Popen(
             cmd,
             env=env,

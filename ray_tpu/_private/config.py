@@ -521,3 +521,30 @@ def keep_off_accelerator(env: dict) -> dict:
     for every spawn site."""
     env["JAX_PLATFORMS"] = "cpu"
     return env
+
+
+def whole_malloc_heaps(env: dict) -> dict:
+    """Let a worker's threads allocate as cheaply as its main thread does,
+    unless its launcher chose (in place; returned for chaining). A worker
+    runs what it is asked off its main thread, and glibc hands every other
+    thread an arena of its own: heaps of 64 MB that it reserves and then
+    opens a page at a time, one ``mprotect`` for each 4 KB the thread comes
+    to need (``sysmalloc`` pads the main arena's break by ``M_TOP_PAD`` and
+    a thread's heap by nothing), where a heap born with that pad is opened
+    whole. In a sandboxed kernel ``mprotect`` is dear, and work that
+    allocates much is four times slower off the main thread for nothing:
+    ``jax.profiler.stop_trace()`` over 407 000 device events took 49.3 s
+    from a thread and 12.8 s from the main thread of the same process;
+    with the pad at a heap's size 12.5 s from a thread (one TPU v5e host
+    under gVisor; PERF.md section 6, PR 42). One arena for all threads
+    (``MALLOC_ARENA_MAX=1``) does as much for that call and makes a cold
+    XLA compile, which allocates from many threads at once, three times
+    longer (31.3 s against 11.2): not that. Naming the pad freezes glibc's
+    moving ``mmap`` threshold at its first 128 KB, so that is named too, at
+    the 32 MB the moving one ends at. ``ptmalloc`` reads both once, when
+    the process starts: so they go into the environment the worker, or the
+    template it is forked from, is EXECUTED with, not into the one it is
+    handed after the fork."""
+    env.setdefault("MALLOC_TOP_PAD_", str(64 << 20))
+    env.setdefault("MALLOC_MMAP_THRESHOLD_", str(32 << 20))
+    return env

@@ -46,14 +46,19 @@ says ``skip_unmasked`` wants the masked positions alone (a serving step's
 rows' own tokens: its padding is one token repeated, four fifths of the
 positions of a step in the benchmark's cells), and without that every
 position is wanted. The ``T x K`` pairs are sorted as ever, the pairs not
-kept past the kept ones into no group, and the combine gives them zero:
-every pair's row is gathered and gathered back as ever, and the grouped
-matmuls do not visit the rows past the last group
-(``grouped_matmul``'s ``tail="unwritten"``: zeroed, as ``ragged_dot``
-leaves them, they would be seven eighths of a share's rows multiplied to
-write zeros). Nothing is dropped: a kept pair's row is the same row times
-the same expert whatever else is kept. Where every pair is kept (training,
-a forward pass with no mask) there is no tail and no select.
+kept past the kept ones into no group. Where some pairs are not kept, the
+kept pairs' rows alone move: the dispatch gathers the first
+``sum(sizes)`` rows of the sorted order, a pass of ``moved_chunk`` rows at
+a time (``_kept_rows``), and the combine visits the positions that have a
+kept pair, wanted positions first, and sums each one's ``K`` outputs in
+float32 in ``k`` order (``_kept_sum``); the trip counts are read on the
+device, no shape depends on them, and nothing branches. The rows past the
+kept pairs' are never written and never read (``grouped_matmul.unwritten``
+where the repo's kernels multiply, which visit no row past the last
+group; zeros for ``ragged_dot``). Nothing is dropped: a kept pair's row is
+the same row times the same expert whatever else is kept. Where every pair
+is kept (training, a forward pass with no mask) the dispatch and the
+combine are one whole gather each.
 
 The dispatch drops nothing and has no capacity: the ``T x K`` (position,
 expert) pairs are sorted by expert, the rows gathered in that order, and
@@ -230,25 +235,24 @@ def _ragged_dot_in_stack(rows, sizes, stack, layer, out_dtype):
                               preferred_element_type=out_dtype)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _in_place(rows, w, sizes, stack, layer, out_dtype, tail="zero"):
+@partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _in_place(rows, w, sizes, stack, layer, out_dtype):
     """``ragged_dot(rows, w, sizes)`` for ``w = stack[layer]``, read from
     the stack where it lies; the repo's kernel is handed the layer's first
-    group, and ``tail``: what it leaves in the rows past the groups' end
-    (``grouped_matmul.TAILS``; XLA's kernel leaves zeros)."""
+    group, and leaves the rows past the groups' end as they were (XLA's
+    kernel leaves zeros there)."""
     if _kernel_takes(stack):
         return grouped_matmul.grouped_matmul(
-            rows, _as_groups(stack), sizes, layer * stack.shape[1], out_dtype,
-            tail)
+            rows, _as_groups(stack), sizes, layer * stack.shape[1], out_dtype)
     return _ragged_dot_in_stack(rows, sizes, stack, layer, out_dtype)
 
 
-def _in_place_fwd(rows, w, sizes, stack, layer, out_dtype, tail):
-    return (_in_place(rows, w, sizes, stack, layer, out_dtype, tail),
+def _in_place_fwd(rows, w, sizes, stack, layer, out_dtype):
+    return (_in_place(rows, w, sizes, stack, layer, out_dtype),
             (rows, w, sizes))
 
 
-def _in_place_bwd(out_dtype, tail, res, g):
+def _in_place_bwd(out_dtype, res, g):
     rows, w, sizes = res
     _, vjp = jax.vjp(lambda r, ww: jax.lax.ragged_dot(
         r, ww, sizes, preferred_element_type=out_dtype), rows, w)
@@ -258,30 +262,28 @@ def _in_place_bwd(out_dtype, tail, res, g):
 _in_place.defvjp(_in_place_fwd, _in_place_bwd)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(7,))
-def _gated_in_place(rows, w_gate, w_up, sizes, gate_stack, up_stack, layer,
-                    tail="zero"):
+@jax.custom_vjp
+def _gated_in_place(rows, w_gate, w_up, sizes, gate_stack, up_stack, layer):
     """``silu(ragged_dot(rows, w_gate)) * ragged_dot(rows, w_up)`` in the
     rows' type, both read from their stacks where they lie. The kernel
     makes it in one pass over the rows and rounds once, from the float32
-    products; XLA's two matmuls round each product first. ``tail`` as
-    ``_in_place``'s."""
+    products; XLA's two matmuls round each product first."""
     if _kernel_takes(gate_stack):
         return grouped_matmul.grouped_swiglu(
             rows, _as_groups(gate_stack), _as_groups(up_stack), sizes,
-            layer * gate_stack.shape[1], rows.dtype, tail)
+            layer * gate_stack.shape[1], rows.dtype)
     return (jax.nn.silu(_ragged_dot_in_stack(rows, sizes, gate_stack, layer,
                                              rows.dtype))
             * _ragged_dot_in_stack(rows, sizes, up_stack, layer, rows.dtype))
 
 
 def _gated_in_place_fwd(rows, w_gate, w_up, sizes, gate_stack, up_stack,
-                        layer, tail):
+                        layer):
     return (_gated_in_place(rows, w_gate, w_up, sizes, gate_stack, up_stack,
-                            layer, tail), (rows, w_gate, w_up, sizes))
+                            layer), (rows, w_gate, w_up, sizes))
 
 
-def _gated_in_place_bwd(tail, res, g):
+def _gated_in_place_bwd(res, g):
     # through the layer's slices, the two products made again
     rows, w_gate, w_up, sizes = res
 
@@ -295,6 +297,144 @@ def _gated_in_place_bwd(tail, res, g):
 
 
 _gated_in_place.defvjp(_gated_in_place_fwd, _gated_in_place_bwd)
+
+
+# the sorted rows one pass moves, about: the dispatch gathers that many, the
+# combine the ``K`` rows of that many over ``K`` positions
+_PASS_ROWS = 8192
+
+
+def moved_chunk(rows: int, pairs: int = 1) -> int:
+    """How many of ``rows`` one pass of ``_kept_rows`` moves, or, of
+    ``rows`` positions of ``pairs`` outputs each, one pass of ``_kept_sum``
+    sums: ``_PASS_ROWS`` sorted rows either way, in whole lane tiles of
+    positions. Pure: the shapes are all it reads, as
+    ``grouped_matmul.gmm_tiles``. A pass is a few device operations
+    whatever its size and the kept rows are covered to the pass, so the
+    largest pass that wastes little: by a sweep on a v5e the dispatch is
+    flat within 2 % from 1024 to 8192 rows a pass at rows of 4 KB and of
+    10 KB (PERF.md, PR 41 and 42), and a traced run pays for every pass
+    (PERF.md section 6, PR 42)."""
+    return min(rows, max(128, _PASS_ROWS // pairs // 128 * 128))
+
+
+def _rows_at(a, at):
+    """``a[at]`` along the first axis for ``at [n]`` known to lie inside
+    it (a permutation's image): a bare gather, where ``jnp.take`` wraps
+    negative indices and fills the ones outside."""
+    return jax.lax.gather(
+        a, at[:, None], jax.lax.GatherDimensionNumbers(
+            offset_dims=tuple(range(1, a.ndim)), collapsed_slice_dims=(0,),
+            start_index_map=(0,)),
+        (1,) + a.shape[1:], mode="promise_in_bounds")
+
+
+def _some_rows(x, source, kept, visited_alone):
+    """``x[source]`` as far as its first ``kept`` rows, covered to the pass:
+    a pass gathers ``moved_chunk`` rows and lays them side by side; both
+    slices clamp alike at the end. The trip count is read on the device.
+    The rows no pass reaches are zeros, or with ``visited_alone`` never
+    written at all (``grouped_matmul.unwritten``)."""
+    shape = (source.shape[0], x.shape[1])
+    rows = (grouped_matmul.unwritten(shape, x.dtype) if visited_alone
+            else jnp.zeros(shape, x.dtype))
+    C = moved_chunk(shape[0])
+
+    def a_pass(i, rows):
+        at = jax.lax.dynamic_slice(source, (i * C,), (C,))
+        return jax.lax.dynamic_update_slice(
+            rows, _rows_at(x, at), (i * C, 0))
+
+    return jax.lax.fori_loop(0, jax.lax.div(kept + (C - 1), C), a_pass, rows)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _kept_rows(x, source, kept, visited_alone):
+    """``x[source]`` where only the first ``kept`` rows will be read
+    (``source [N]`` int, a permutation's image; ``kept`` a traced count).
+    ``visited_alone``: the repo's kernels multiply, and visit no row past
+    the last group. Differentiated, it is the whole gather's transpose,
+    over zeros."""
+    return _some_rows(x, source, kept, visited_alone)
+
+
+def _kept_rows_fwd(x, source, kept, visited_alone):
+    return _some_rows(x, source, kept, False), (x, source)
+
+
+def _kept_rows_bwd(visited_alone, res, g):
+    x, source = res
+    _, vjp = jax.vjp(lambda x: jnp.take(x, source, axis=0), x)
+    return vjp(g) + (None, None)
+
+
+_kept_rows.defvjp(_kept_rows_fwd, _kept_rows_bwd)
+
+
+def _weighted_sum(out, back, weights, keep, dtype):
+    """Each position's ``K`` expert outputs, ``out[back[t, k]]`` where
+    ``keep[t, k]`` and zero elsewhere (a pair not kept: its row was never
+    written), times ``weights [T, K]``, summed in float32: the whole
+    combine, one gather of every pair's row."""
+    T, K = weights.shape
+    got = jnp.take(out, back.reshape(T * K), axis=0).reshape(T, K, -1)
+    return jnp.einsum("tkh,tk->th", jnp.where(keep[:, :, None], got, 0.0),
+                      weights).astype(dtype)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _kept_sum(out, back, weights, keep, dtype):
+    """``_weighted_sum`` by the positions that have a kept pair alone: they
+    are visited first (a stable sort of ``[T]``), a pass gathers the ``K``
+    rows of ``moved_chunk`` positions in one gather, ``k`` outermost, and
+    sums them in float32 in ``k`` order. What a pass only reads (each
+    pair's row and weight) is taken in visit order once, before the loop,
+    and a pass slices it. A pair not kept has no row of its own (nobody
+    wrote one): it reads the first sorted row at a weight of zero, which a
+    kept pair's output fills whenever there is a pass at all. The sums are
+    laid side by side and gathered back to the positions' order after the
+    loop: a scatter inside a loop whose trip count the device reads stalls
+    a v5e (PERF.md section 7; ``tests/test_scatter_in_loop_stall.py``),
+    and the two sorts of ``[T]`` are its price. The positions not visited
+    come out zero. Differentiated, it is ``_weighted_sum``."""
+    T, K = weights.shape
+    H = out.shape[1]
+    wanted = jnp.any(keep, axis=1)
+    visit = jnp.argsort(~wanted)                  # stable: the wanted first
+    C = moved_chunk(T, K)
+    w = _rows_at(jnp.where(keep, weights, 0.0), visit)            # [T, K]
+    source = _rows_at(jnp.where(keep, back, 0), visit).T          # [K, T]
+
+    def a_pass(i, sums):
+        w_i = jax.lax.dynamic_slice(w, (i * C, 0), (C, K))
+        at = jax.lax.dynamic_slice(source, (0, i * C), (K, C))
+        got = _rows_at(out, at.reshape(K * C)).reshape(K, C, H)
+        acc = w_i[:, 0, None] * got[0]
+        for k in range(1, K):
+            acc = acc + w_i[:, k, None] * got[k]
+        return jax.lax.dynamic_update_slice(sums, acc.astype(dtype),
+                                            (i * C, 0))
+
+    sums = jax.lax.fori_loop(
+        0, jax.lax.div(jnp.sum(wanted, dtype=jnp.int32) + (C - 1), C),
+        a_pass, jnp.zeros((T, H), dtype))
+    return _rows_at(sums, jnp.argsort(visit))
+
+
+def _kept_sum_fwd(out, back, weights, keep, dtype):
+    return _kept_sum(out, back, weights, keep, dtype), (out, back, weights,
+                                                        keep)
+
+
+def _kept_sum_bwd(dtype, res, g):
+    out, back, weights, keep = res
+    _, vjp = jax.vjp(lambda o, w: _weighted_sum(o, back, w, keep, dtype),
+                     out, weights)
+    d_out, d_weights = vjp(g)
+    return d_out, None, d_weights, None
+
+
+_kept_sum.defvjp(_kept_sum_fwd, _kept_sum_bwd)
 
 
 def expert_ffn(cfg, h: jax.Array, lp: Dict[str, jax.Array]
@@ -384,12 +524,14 @@ def expert_ffn(cfg, h: jax.Array, lp: Dict[str, jax.Array]
         if keep is not None:  # the kept pairs', over the held experts
             sizes = jnp.sum(by[:, None] == jnp.arange(
                 count, dtype=by.dtype)[None, :], axis=0, dtype=jnp.int32)
-        rows = jnp.take(x, order // K, axis=0)               # [T*K, H]
-    # the rows past the kept pairs' are not visited, and masked in the
-    # combine; where every pair is kept there are none
-    tail = "zero" if keep is None else "unwritten"
+        in_place = where is not None and lp["we_gate"].dtype == dt
+        if keep is None:
+            rows = jnp.take(x, order // K, axis=0)           # [T*K, H]
+        else:  # the kept pairs' rows, and no other
+            rows = _kept_rows(x, order // K, jnp.sum(sizes),
+                              in_place and _kernel_takes(where[0]["we_gate"]))
     with jax.named_scope("moe_experts"):
-        if where is None or lp["we_gate"].dtype != dt:
+        if not in_place:
             # the layer's slices are cast on their way in, which is the
             # copy: multiply by them (float32 master weights in training)
             def matmul(x, name, out_dtype):
@@ -403,16 +545,19 @@ def expert_ffn(cfg, h: jax.Array, lp: Dict[str, jax.Array]
             stacks, layer = where
             hidden = _gated_in_place(
                 rows, lp["we_gate"], lp["we_up"], sizes, stacks["we_gate"],
-                stacks["we_up"], layer, tail)
+                stacks["we_up"], layer)
             out = _in_place(hidden, lp["we_down"], sizes, stacks["we_down"],
-                            layer, jnp.float32, tail)
+                            layer, jnp.float32)
     with jax.named_scope("moe_combine"):
         # back to the pairs' own order, then the weighted sum of each
         # position's K expert outputs, in float32
-        out = jnp.take(out, jnp.argsort(order), axis=0).reshape(T, K, H)
-        if keep is not None:  # a pair not kept: its row was never written
-            out = jnp.where(keep[:, :, None], out, 0.0)
-        y = jnp.einsum("tkh,tk->th", out, weights).astype(dt)
+        back = jnp.argsort(order)
+        if keep is None:
+            out = jnp.take(out, back, axis=0).reshape(T, K, H)
+            y = jnp.einsum("tkh,tk->th", out, weights).astype(dt)
+        else:  # the positions that have a kept pair, and no other
+            y = _kept_sum(out, back.reshape(T, K), weights,
+                          jnp.broadcast_to(keep, (T, K)), dt)
     if "ws_gate" in lp:
         with jax.named_scope("moe_shared"):
             act = (jax.nn.silu(jnp.einsum("th,hm->tm", x,

@@ -27,14 +27,14 @@ its group, so a boundary inside a tile costs a strip's matmul and not a
 tile's: what is computed follows the rows a group really has, at any tile.
 The product is accumulated in float32 over the whole of ``K`` in one dot,
 and written under a mask of the group's rows: the other rows of the tile
-are the neighbouring visits'. Rows past the last group's end come out zero
-(as ``jax.lax.ragged_dot`` leaves them): they are one more group, whose
-visits multiply and write zeros. A caller that masks those rows itself
-says ``tail="unwritten"`` and that group gets no visit: nothing is read,
-multiplied or written for them, and what the result holds there is not
-defined (``models/moe.py`` wherever some pairs are not kept: a share of
-the experts, where seven eighths of the sorted pairs belong to experts on
-other chips, and a serving step's padding, four fifths of its pairs).
+are the neighbouring visits'. Rows past the last group's end get no visit:
+nothing is read, multiplied or written for them, and what the result holds
+there is not defined (``jax.lax.ragged_dot`` leaves zeros). Nobody reads
+them: ``models/moe.py`` moves the kept pairs' rows alone wherever some
+pairs are not kept (a share of the experts, where seven eighths of the
+sorted pairs belong to experts on other chips, and a serving step's
+padding, four fifths of its pairs), and the rows it does not move are
+``unwritten``: an array no operation has filled.
 
 ``grouped_swiglu`` is the same kernel with two stacks: one pass over the
 rows holds the gate's and the up projection's blocks, accumulates both,
@@ -112,23 +112,19 @@ def gmm_tiles(rows: int, k: int, n: int, *, stacks: int = 1,
     raise ValueError(f"no column block of {n} fits VMEM beside k={k}")
 
 
-TAILS = ("zero", "unwritten")
-
-
-def _visits(sizes: jax.Array, rows: int, tm: int, tail: str = "zero"):
+def _visits(sizes: jax.Array, rows: int, tm: int):
     """The (row tile, group) pairs that share rows, in row order, padded to
-    their static bound ``tiles + E`` by repeating the last: ``tile [V]``,
-    ``group [V]``, how many are real ``[1]``, and each group's first and
-    one-past-last row ``[E + 1]``, with a last group for the rows past the
-    groups' end. Sums and lookups over the ``E + 1`` groups are written as
-    masked sums over a ``[., E + 1]`` comparison, which fuse into a few
-    device operations where a cumulative sum, a binary search and a gather
-    are a loop and a dozen each. Nothing here is negative where it is
-    divided, so the divisions truncate (``lax.div``)."""
+    their static bound ``tiles + E - 1`` by repeating the last: ``tile
+    [V]``, ``group [V]``, how many are real ``[1]`` (there may be none), and
+    each group's first and one-past-last row ``[E]``. Sums and lookups over
+    the ``E`` groups are written as masked sums over a ``[., E]``
+    comparison, which fuse into a few device operations where a cumulative
+    sum, a binary search and a gather are a loop and a dozen each. Nothing
+    here is negative where it is divided, so the divisions truncate
+    (``lax.div``)."""
     E = sizes.shape[0]
     sizes = sizes.astype(jnp.int32)
-    sizes = jnp.concatenate([sizes, rows - jnp.sum(sizes, keepdims=True)])
-    e = jnp.arange(E + 1, dtype=jnp.int32)
+    e = jnp.arange(E, dtype=jnp.int32)
     before = e[None, :] <= e[:, None]             # [g, g']: g' <= g
 
     def running(x):   # inclusive cumulative sum
@@ -138,26 +134,24 @@ def _visits(sizes: jax.Array, rows: int, tm: int, tail: str = "zero"):
     start = end - sizes
     first = jax.lax.div(start, tm)
     count = jnp.where(sizes > 0, jax.lax.div(end - 1, tm) - first + 1, 0)
-    if tail == "unwritten":  # the rows no group owns are not visited
-        count = jnp.where(e < E, count, 0)
     upto = running(count)
-    total = upto[E:]
-    v = jnp.minimum(jnp.arange(-(-rows // tm) + E, dtype=jnp.int32),
+    total = upto[E - 1:]
+    v = jnp.minimum(jnp.arange(-(-rows // tm) + E - 1, dtype=jnp.int32),
                     total - 1)
     # the group of visit v: how many groups' visits all lie before it
     group = jnp.sum(upto[None, :] <= v[:, None], axis=1, dtype=jnp.int32)
     tile = v + jnp.sum(jnp.where(group[:, None] == e[None, :],
                                  (first - upto + count)[None, :], 0), axis=1)
-    if tail == "unwritten":  # there may be no visit at all: stay in range
-        group = jnp.minimum(group, E)
-        tile = jnp.clip(tile, 0, -(-rows // tm) - 1)
+    # there may be no visit at all: stay in range
+    group = jnp.minimum(group, E - 1)
+    tile = jnp.clip(tile, 0, -(-rows // tm) - 1)
     return tile, group, total, start, end
 
 
 def _kernel(first_ref, tile_ref, group_ref, total_ref, start_ref, end_ref,
             x_ref, *refs):
     *w_refs, o_ref = refs
-    tm, groups = x_ref.shape[0], start_ref.shape[0] - 1
+    tm = x_ref.shape[0]
     v = pl.program_id(1)
     g = group_ref[v]
     # the group's rows, counted from the tile's first
@@ -172,9 +166,8 @@ def _kernel(first_ref, tile_ref, group_ref, total_ref, start_ref, end_ref,
                for w in w_refs]
         # silu(gate) * up, of the float32 products
         y = (acc[0] if len(acc) == 1
-             else acc[0] * jax.lax.logistic(acc[0]) * acc[1])
-        # the last group is the rows no group owns: zeros
-        y = jnp.where(g < groups, y, 0.0).astype(o_ref.dtype)
+             else acc[0] * jax.lax.logistic(acc[0]) * acc[1]
+             ).astype(o_ref.dtype)
         row = r0 + jax.lax.broadcasted_iota(jnp.int32, y.shape, 0)
         o_ref[here, :] = jnp.where((row >= lo) & (row < hi), y,
                                    o_ref[here, :])
@@ -190,9 +183,7 @@ def _kernel(first_ref, tile_ref, group_ref, total_ref, start_ref, end_ref,
 
 
 def _grouped(rows: jax.Array, stacks: Sequence[jax.Array], sizes: jax.Array,
-             first_group, out_dtype, tail: str = "zero") -> jax.Array:
-    if tail not in TAILS:
-        raise ValueError(f"tail {tail!r}: expected " + "|".join(TAILS))
+             first_group, out_dtype) -> jax.Array:
     M, K = rows.shape
     N = stacks[0].shape[2]
     E = sizes.shape[0]
@@ -205,11 +196,11 @@ def _grouped(rows: jax.Array, stacks: Sequence[jax.Array], sizes: jax.Array,
     tm, tn = gmm_tiles(M, K, N, stacks=len(stacks),
                        itemsize=rows.dtype.itemsize,
                        out_itemsize=out_dtype.itemsize)
-    tile, group, total, start, end = _visits(sizes, M, tm, tail)
+    tile, group, total, start, end = _visits(sizes, M, tm)
     first = jnp.asarray(first_group, jnp.int32).reshape(1)
 
     def weights(j, v, first_ref, tile_ref, group_ref, *_):
-        return (first_ref[0] + jnp.minimum(group_ref[v], E - 1), 0, j)
+        return (first_ref[0] + group_ref[v], 0, j)
 
     call = pl.pallas_call(
         _kernel,
@@ -234,20 +225,36 @@ def _grouped(rows: jax.Array, stacks: Sequence[jax.Array], sizes: jax.Array,
 
 
 def grouped_matmul(rows: jax.Array, stack: jax.Array, sizes: jax.Array,
-                   first_group, out_dtype, tail: str = "zero") -> jax.Array:
+                   first_group, out_dtype) -> jax.Array:
     """``rows [M, K]`` sorted by group, ``stack [G, K, N]``, ``sizes [E]``
     (int): the ``sizes[e]`` rows of group ``e`` times
     ``stack[first_group + e]``, accumulated in float32, as ``[M, N]`` in
     ``out_dtype``. ``first_group`` may be traced. Rows past ``sum(sizes)``
-    are zero, or with ``tail="unwritten"`` not defined."""
-    return _grouped(rows, (stack,), sizes, first_group, out_dtype, tail)
+    are neither read nor written: what they hold is not defined."""
+    return _grouped(rows, (stack,), sizes, first_group, out_dtype)
 
 
 def grouped_swiglu(rows: jax.Array, gate_stack: jax.Array,
                    up_stack: jax.Array, sizes: jax.Array, first_group,
-                   out_dtype, tail: str = "zero") -> jax.Array:
+                   out_dtype) -> jax.Array:
     """``silu(rows @ gate) * (rows @ up)`` group by group, in one pass
     over the rows: both products in float32, rounded once to
     ``out_dtype``. Arguments as ``grouped_matmul``'s."""
     return _grouped(rows, (gate_stack, up_stack), sizes, first_group,
-                    out_dtype, tail)
+                    out_dtype)
+
+
+def unwritten(shape: Sequence[int], dtype) -> jax.Array:
+    """An array of ``shape`` that no operation has filled: what it holds is
+    not defined. The rows a caller will write before anybody reads them
+    need no pass of zeros first (``models/moe.py``: the sorted pairs' rows,
+    of which a serving step moves a fifth; at OLMoE's widths the zeros were
+    2.4 to 7.7 ms a step, PERF.md). A Pallas call whose body does nothing
+    and whose result stays where XLA allocated it; in a device trace it
+    takes the name of the caller's innermost named scope."""
+    return pl.pallas_call(
+        lambda o_ref: None,
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        out_shape=jax.ShapeDtypeStruct(tuple(shape), dtype),
+        interpret=flash_attention._interpret(),
+    )()

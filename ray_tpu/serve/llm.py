@@ -86,13 +86,16 @@ class LlamaGenerator:
     # the held experts, `expert_pairs_here` is all of theirs and
     # `expert_pairs_all` the pairs its routers made over every expert;
     # `expert_pairs_skipped` the padding's pairs, which no expert multiplied;
+    # `expert_rows_moved` the sorted pairs' rows the routed layers gathered
+    # (a layer's kept pairs, covered to the pass) of `expert_rows_all`;
     # `step_device_s` the seconds (`time.perf_counter()`) from the call of
     # the step's program until its results are on the host, the span
     # `llm.device`
     STEP_COUNTERS = ("host_bytes", "positions_computed", "positions_live",
                      "expert_pairs_fullest", "expert_pairs_mean",
                      "expert_pairs_here", "expert_pairs_all",
-                     "expert_pairs_skipped", "step_device_s")
+                     "expert_pairs_skipped", "expert_rows_moved",
+                     "expert_rows_all", "step_device_s")
 
     def __init__(self, config: str = "tiny", lora_rank: int = 4,
                  max_batch_size: int = 4,
@@ -238,6 +241,8 @@ class LlamaGenerator:
         appended, the result list)."""
         import numpy as np
 
+        from ray_tpu.models.moe import moved_chunk
+
         with events.span("llm.prepare", "serve"):
             live = [(i, s) for i, s in enumerate(states) if s is not None]
             bucket = len(states)
@@ -287,6 +292,14 @@ class LlamaGenerator:
                     pairs_all = float(everywhere.sum())
                 counts["expert_pairs_here"] += pairs_here
                 counts["expert_pairs_all"] += pairs_all
+                # a layer's kept pairs are its held experts' over the live
+                # positions: what the dispatch's passes cover of them
+                rows = bucket * pad_len * self._cfg.experts_per_token
+                a_pass = moved_chunk(rows)
+                kept = np.rint(mean * self._experts_held)
+                counts["expert_rows_moved"] += int(np.minimum(
+                    np.ceil(kept / a_pass) * a_pass, rows).sum())
+                counts["expert_rows_all"] += rows * len(mean)
             results: List[Optional[tuple]] = [None] * len(states)
             for row, (idx, s) in enumerate(live):
                 nxt = int(ids[row])
@@ -320,7 +333,13 @@ class LlamaGenerator:
         ``expert_pairs_skipped`` (the pairs of a step's padding, which the
         step's mask keeps off the routed experts: (``positions_computed`` -
         ``positions_live``) x ``experts_per_token`` x routed layers, counted
-        on the host; 0 for a model without experts); ``step_device_s``
+        on the host; 0 for a model without experts); ``expert_rows_moved``
+        and ``expert_rows_all`` (of the ``positions_computed`` x
+        ``experts_per_token`` sorted rows of a routed layer, the ones its
+        dispatch gathered: the layer's kept pairs covered to the pass,
+        ``models/moe.py::moved_chunk``, reckoned on the host from the
+        routers' load; how far the kept pairs' rows alone move);
+        ``step_device_s``
         (seconds of ``time.perf_counter()`` from the call of a step's
         program until its results are on the host: dispatch, transfer in,
         the program, transfer out; beside the engine's ``active_s`` it says

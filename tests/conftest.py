@@ -125,6 +125,11 @@ FAST_FILES = {
     # _step` over a padded batch (two minutes: it forgets every trace
     # between the kernel's path and `ragged_dot`'s)
     "test_expert_padding.py",
+    # where some pairs are not kept the kept pairs' rows alone move:
+    # `_kept_rows` and `_kept_sum` against the whole gathers at every trip
+    # count, their gradients, no scatter inside a loop, the rows the
+    # engine counts (two minutes)
+    "test_moe_kept_rows.py",
     # each cell's programs, hashed: what a PR left alone and what it moved
     "test_cell_programs.py",
     # the model layer's own tests (ISSUE 30): what the three cells trace.

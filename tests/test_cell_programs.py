@@ -17,7 +17,10 @@ History. PR 37 (a step's padding stays off the routed experts) moved
 ``serve_olmoe_chat``'s and ``serve_lfm2_rag``'s four step programs; the ten
 others are what its parent ``2e5068b`` gives (``serve_chat_steady`` and
 ``train_l2_seq4k`` have no routed layer, and ``serve_dsv2_docqa``'s share
-kept its padding off already).
+kept its padding off already). PR 42 (where some pairs are not kept the
+kept pairs' rows alone move; `grouped_matmul`'s `tail` is gone) moved the
+six step programs of the three cells with experts; the inits, the dense
+cell's steps and training's are what its parent ``6f115a1`` gives.
 """
 
 import hashlib
@@ -31,14 +34,14 @@ PROGRAMS = {
     "serve_chat_steady.step128": "ad4fedae56577eac",
     "serve_chat_steady.step384": "f2df55b80038d939",
     "serve_olmoe_chat.init": "126fada9fb96dc80",
-    "serve_olmoe_chat.step128": "5de1ca4a0a58274c",
-    "serve_olmoe_chat.step1152": "9847f92a8bb11cd7",
+    "serve_olmoe_chat.step128": "c5d5a9838815288c",
+    "serve_olmoe_chat.step1152": "b6d7aefc5f242f6b",
     "serve_lfm2_rag.init": "5766fc6f6af74d3d",
-    "serve_lfm2_rag.step128": "30990bfd106ba8f1",
-    "serve_lfm2_rag.step1408": "2c9a09928ab75c68",
+    "serve_lfm2_rag.step128": "e2128e6a78e96d87",
+    "serve_lfm2_rag.step1408": "3f34f48cd5d440ad",
     "serve_dsv2_docqa.init": "91b10ec8ff63401e",
-    "serve_dsv2_docqa.step256": "e1ef689e819f8c3d",
-    "serve_dsv2_docqa.step1792": "7f52ab45feccc506",
+    "serve_dsv2_docqa.step256": "c3465377128f97b8",
+    "serve_dsv2_docqa.step1792": "f6519a8cefe24e4f",
 }
 
 

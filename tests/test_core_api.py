@@ -49,6 +49,35 @@ class TestTasks:
         r = add.options(num_returns=1, name="custom_add").remote(2, 3)
         assert ray_tpu.get(r) == 5
 
+    def test_a_worker_is_executed_with_whole_malloc_heaps(
+            self, ray_start_regular):
+        """``config.whole_malloc_heaps``: the variables are in the
+        environment the worker's process, or the template it was forked
+        from, was EXECUTED with (``ptmalloc`` reads them once, at the
+        start), and a launcher's own choice stands."""
+        from ray_tpu._private.config import whole_malloc_heaps
+
+        @ray_tpu.remote
+        def executed_with():
+            import os
+
+            def of(pid):
+                with open(f"/proc/{pid}/environ", "rb") as f:
+                    return dict(kv.split(b"=", 1) for kv in
+                                f.read().split(b"\0") if b"=" in kv)
+            # a forked worker keeps its template's malloc: its parent's
+            return [(of(pid).get(b"MALLOC_TOP_PAD_"),
+                     of(pid).get(b"MALLOC_MMAP_THRESHOLD_"))
+                    for pid in (os.getpid(), os.getppid())]
+
+        assert (b"67108864", b"33554432") in ray_tpu.get(
+            executed_with.remote())
+        assert whole_malloc_heaps({}) == {
+            "MALLOC_TOP_PAD_": "67108864",
+            "MALLOC_MMAP_THRESHOLD_": "33554432"}
+        assert whole_malloc_heaps({"MALLOC_TOP_PAD_": "0"}) == {
+            "MALLOC_TOP_PAD_": "0", "MALLOC_MMAP_THRESHOLD_": "33554432"}
+
     def test_error_propagation(self, ray_start_regular):
         @ray_tpu.remote
         def fail():

@@ -139,24 +139,18 @@ def test_the_two_width_forward_compiles_within_its_reckoning(
 # longest bucket, read from a layers x 64 stack: serve_olmoe_chat's (8
 # experts a token, width 1024, to 1152) and serve_lfm2_rag's (4, 1536, 1408)
 # and serve_dsv2_docqa's (6 pairs a position over 160 experts of which the
-# 20 held have groups, width 1536 from a hidden of 5120, to 1792): the tail
-# is the absent experts' rows, unwritten. Since PR 37 a step's padding is
-# every model's unwritten tail (the first two cells' shortest and longest
-# buckets below); a call with no mask has none and says "zero"
+# 20 held have groups, width 1536 from a hidden of 5120, to 1792), each
+# cell's shortest, a middle and its longest bucket
 @pytest.mark.parametrize("fused", [True, False])
-@pytest.mark.parametrize("seq, pairs, width, hidden, experts, tail", [
-    (128, 8, 1024, 2048, 64, "zero"), (512, 8, 1024, 2048, 64, "zero"),
-    (1152, 8, 1024, 2048, 64, "zero"),
-    (128, 4, 1536, 2048, 64, "zero"), (384, 4, 1536, 2048, 64, "zero"),
-    (1408, 4, 1536, 2048, 64, "zero"),
-    (256, 6, 1536, 5120, 20, "unwritten"),
-    (1792, 6, 1536, 5120, 20, "unwritten"),
-    (128, 8, 1024, 2048, 64, "unwritten"),
-    (1152, 8, 1024, 2048, 64, "unwritten"),
-    (128, 4, 1536, 2048, 64, "unwritten"),
-    (1408, 4, 1536, 2048, 64, "unwritten")])
+@pytest.mark.parametrize("seq, pairs, width, hidden, experts", [
+    (128, 8, 1024, 2048, 64), (512, 8, 1024, 2048, 64),
+    (1152, 8, 1024, 2048, 64),
+    (128, 4, 1536, 2048, 64), (384, 4, 1536, 2048, 64),
+    (1408, 4, 1536, 2048, 64),
+    (256, 6, 1536, 5120, 20), (1024, 6, 1536, 5120, 20),
+    (1792, 6, 1536, 5120, 20)])
 def test_grouped_matmul_compiles_within_its_reckoning(
-        seq, pairs, width, hidden, experts, tail, fused, one_chip,
+        seq, pairs, width, hidden, experts, fused, one_chip,
         compiled_for_tpu):
     rows = 8 * pairs * seq
     k, n = (hidden, width) if fused else (width, hidden)
@@ -168,11 +162,11 @@ def test_grouped_matmul_compiles_within_its_reckoning(
     layer = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     if fused:
         compiled = jax.jit(lambda x, g, u, s, i: gm.grouped_swiglu(
-            x, g, u, s, i * experts, out, tail)).lower(
+            x, g, u, s, i * experts, out)).lower(
                 x, stack, stack, sizes, layer).compile()
     else:
         compiled = jax.jit(lambda x, w, s, i: gm.grouped_matmul(
-            x, w, s, i * experts, out, tail)).lower(
+            x, w, s, i * experts, out)).lower(
                 x, stack, sizes, layer).compile()
     assert "tpu_custom_call" in compiled.as_text()
     stacks = 2 if fused else 1
@@ -184,3 +178,94 @@ def test_grouped_matmul_compiles_within_its_reckoning(
     # fusions for the visits' table
     assert 2 ** 20 < max(_scoped_vmem(compiled)) <= reckoned \
         <= gm.VMEM_LIMIT_BYTES
+
+
+# ------------------------------------------------------------------------
+# models/moe.py: the kept pairs' rows alone (``_kept_rows``, ``_kept_sum``)
+# ------------------------------------------------------------------------
+def _loops(text: str) -> list:
+    """For each ``while`` of an optimized HLO text, the instructions of its
+    body that are a device event a pass in a traced run: everything but
+    parameters, constants, tuples, bitcasts, the scalars of the loop's own
+    counting, and the copies XLA starts ahead between memory spaces (they
+    run beside the others, on the trace's asynchronous line)."""
+    bodies, name = {}, None
+    for line in text.splitlines():
+        m = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if m:
+            name = m.group(1)
+            bodies[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None and " = " in line:
+            bodies[name].append(line.strip())
+    def event(line):
+        kind, rest = line.split(" = ", 1)[1], ""
+        if kind.startswith("("):        # a tuple's type: skip to its end
+            depth = 0
+            for i, c in enumerate(kind):
+                depth += (c == "(") - (c == ")")
+                if depth == 0:
+                    kind, rest = kind[:i + 1], kind[i + 2:]
+                    break
+        else:
+            kind, rest = kind.split(" ", 1)
+        op = rest.split("(", 1)[0]
+        return (op not in ("parameter", "constant", "tuple", "bitcast",
+                           "get-tuple-element", "copy-start", "copy-done")
+                and not re.match(r"(s32|u32|pred)\[\]", kind))
+
+    found = []
+    for lines in bodies.values():
+        for line in lines:
+            m = re.search(r" while\(.*body=%?([\w.\-]+)", line)
+            if m:
+                found.append([ln for ln in bodies[m.group(1)] if event(ln)])
+    return found
+
+
+# each cell's longest step: positions, pairs a position, hidden
+MOVED = {"olmoe": (8 * 1152, 8, 2048), "lfm2": (8 * 1408, 4, 2048),
+         "dsv2": (8 * 1792, 6, 5120)}
+# the instructions of one pass, by the compile below (PERF.md, PR 42: 3 and
+# 5 to 6): what a step pays in device events for each pass of each routed
+# layer
+DISPATCH_PASS, COMBINE_PASS = 3, 7
+
+
+@pytest.mark.parametrize("cell", sorted(MOVED))
+def test_the_kept_rows_loop_is_thin_and_holds_no_scatter(
+        cell, one_chip, compiled_for_tpu):
+    from ray_tpu.models import moe
+
+    T, K, H = MOVED[cell]
+    x = jax.ShapeDtypeStruct((T, H), jnp.bfloat16, sharding=one_chip)
+    source = jax.ShapeDtypeStruct((T * K,), jnp.int32, sharding=one_chip)
+    kept = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    text = jax.jit(lambda x, s, n: moe._kept_rows(x, s, n, True)).lower(
+        x, source, kept).compile().as_text()
+    (a_pass,) = _loops(text)
+    assert len(a_pass) <= DISPATCH_PASS, a_pass
+    assert not [ln for ln in a_pass if " scatter(" in ln]
+    # the rows nobody wrote: a kernel that does nothing, under a scope of
+    # the caller's own, and no fill of their shape
+    assert text.count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("cell", sorted(MOVED))
+def test_the_kept_sums_loop_is_thin_and_holds_no_scatter(
+        cell, one_chip, compiled_for_tpu):
+    from ray_tpu.models import moe
+
+    T, K, H = MOVED[cell]
+    out = jax.ShapeDtypeStruct((T * K, H), jnp.float32, sharding=one_chip)
+    back = jax.ShapeDtypeStruct((T, K), jnp.int32, sharding=one_chip)
+    weights = jax.ShapeDtypeStruct((T, K), jnp.float32, sharding=one_chip)
+    keep = jax.ShapeDtypeStruct((T, K), jnp.bool_, sharding=one_chip)
+    text = jax.jit(lambda *a: moe._kept_sum(*a, jnp.bfloat16)).lower(
+        out, back, weights, keep).compile().as_text()
+    (a_pass,) = _loops(text)
+    assert len(a_pass) <= COMBINE_PASS, a_pass
+    assert not [ln for ln in a_pass if " scatter(" in ln]
+    # one gather a pass: the K rows of each of its positions
+    assert sum(" gather(" in ln for ln in text.splitlines()) <= 6

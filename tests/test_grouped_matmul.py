@@ -42,7 +42,8 @@ def operands(seed, m, k, n, groups, dtype):
 
 
 def per_group(x, w, sizes, first=0):
-    """Each group's rows times its matrix, in float64; zeros past them."""
+    """Each group's rows times its matrix, in float64; zeros past them
+    (the kernel leaves those rows as they were: compare up to ``sum(sizes)``)."""
     x, w = np.asarray(x, np.float64), np.asarray(w, np.float64)
     out = np.zeros((x.shape[0], w.shape[2]))
     at = 0
@@ -64,11 +65,14 @@ def test_against_numpy_and_ragged_dot(case, k, n, out_dtype):
     want = per_group(x, w, sizes)
     ragged = jax.lax.ragged_dot(x, w, s, preferred_element_type=out_dtype)
     step = 2 ** -7 if out_dtype == jnp.bfloat16 else 1e-5   # of values to 4
-    np.testing.assert_allclose(np.asarray(got, np.float64), want,
+    own = sum(sizes)   # the rows past the last group get no visit
+    np.testing.assert_allclose(np.asarray(got, np.float64)[:own], want[:own],
                                atol=4 * step, rtol=0)
-    np.testing.assert_allclose(np.asarray(got, np.float64),
-                               np.asarray(ragged, np.float64),
+    np.testing.assert_allclose(np.asarray(got, np.float64)[:own],
+                               np.asarray(ragged, np.float64)[:own],
                                atol=4 * step, rtol=0)
+    # interpreted, a row nobody wrote reads NaN
+    assert np.isnan(np.asarray(got, np.float64)[-(-own // 512) * 512:]).all()
 
 
 @pytest.mark.parametrize("rows,sizes", [
@@ -82,8 +86,10 @@ def test_a_row_count_the_tile_does_not_divide(rows, sizes):
     x, w, _ = operands(2, rows, 128, 128, 4, jnp.float32)
     got = gm.grouped_matmul(x, w, jnp.asarray(sizes, jnp.int32), 0,
                             jnp.float32)
-    np.testing.assert_allclose(np.asarray(got, np.float64),
-                               per_group(x, w, sizes), atol=1e-4, rtol=0)
+    own = sum(sizes)
+    np.testing.assert_allclose(np.asarray(got, np.float64)[:own],
+                               per_group(x, w, sizes)[:own], atol=1e-4,
+                               rtol=0)
 
 
 # a share of the experts: most of the sorted rows belong to no group here
@@ -91,35 +97,50 @@ def test_a_row_count_the_tile_does_not_divide(rows, sizes):
 @pytest.mark.parametrize("sizes", [[100, 0, 60, 40], [0, 0, 0, 200],
                                    [1, 0, 0, 0], [0, 0, 0, 0],
                                    [300, 300, 300, 124]])
-def test_an_unwritten_tail_is_not_visited(sizes, fused):
-    """``tail="unwritten"``: the groups' own rows are what they are with
-    the tail zeroed; no visit is the tail's, and with no row in any group
-    there is no visit at all."""
+def test_the_rows_past_the_last_group_are_not_visited(sizes, fused):
+    """The groups' own rows are ``ragged_dot``'s; no visit reaches past
+    them (interpreted, what nobody wrote reads NaN: every tile past the
+    last visited one), and with no row in any group there is no visit at
+    all. Rows of NaN past the groups' end harm nothing."""
     rows, live = 1024, sum(sizes)
     x, w, u = operands(5, rows, 128, 256, 4, jnp.bfloat16)
+    x = x.at[live:].set(jnp.nan)     # as `moe.py` leaves them: unwritten
     sz = jnp.asarray(sizes, jnp.int32)
+    ragged = lambda m: jax.lax.ragged_dot(
+        x, m, sz, preferred_element_type=jnp.float32)
     if fused:
-        zeroed = gm.grouped_swiglu(x, w, u, sz, 0, jnp.float32)
-        got = gm.grouped_swiglu(x, w, u, sz, 0, jnp.float32, "unwritten")
+        got = gm.grouped_swiglu(x, w, u, sz, 0, jnp.float32)
+        want = jax.nn.silu(ragged(w)) * ragged(u)
     else:
-        zeroed = gm.grouped_matmul(x, w, sz, 0, jnp.float32)
-        got = gm.grouped_matmul(x, w, sz, 0, jnp.float32, "unwritten")
-    np.testing.assert_array_equal(np.asarray(got)[:live],
-                                  np.asarray(zeroed)[:live])
-    assert not np.asarray(zeroed)[live:].any()
+        got = gm.grouped_matmul(x, w, sz, 0, jnp.float32)
+        want = ragged(w)
+    np.testing.assert_allclose(np.asarray(got)[:live],
+                               np.asarray(want)[:live], atol=1e-4, rtol=0)
     tm = 512
-    tile, group, total, _, _ = gm._visits(sz, rows, tm, "unwritten")
+    assert np.isnan(np.asarray(got)[-(-live // tm) * tm:]).all()
+    tile, group, total, _, _ = gm._visits(sz, rows, tm)
     visits = int(total[0])
     assert visits == sum(-(-(e) // tm) - s // tm
                          for s, e in zip(np.cumsum([0] + sizes[:-1]),
                                          np.cumsum(sizes)) if e > s)
-    assert np.all(np.asarray(group)[:max(visits, 1)] < 4 + (visits == 0))
+    # the static bound: a boundary inside a tile is one visit more
+    assert tile.shape == group.shape == (rows // tm + 4 - 1,)
+    assert np.all((0 <= np.asarray(group)) & (np.asarray(group) < 4))
     assert np.all((0 <= np.asarray(tile)) & (np.asarray(tile) < rows // tm))
-    # the zeroed tail is one more group's visits, over all its tiles
-    _, _, with_tail, _, _ = gm._visits(sz, rows, tm)
-    assert int(with_tail[0]) >= visits + (live < rows)
-    with pytest.raises(ValueError, match="tail 'skip'"):
-        gm.grouped_matmul(x, w, sz, 0, jnp.float32, "skip")
+    # the surplus repeat the last visit's blocks
+    assert (np.asarray(tile)[max(visits, 1) - 1:]
+            == np.asarray(tile)[max(visits, 1) - 1]).all()
+
+
+def test_unwritten_is_an_array_nobody_filled():
+    """No operation writes it (interpreted, it reads NaN); a program that
+    takes it holds no fill of its shape."""
+    rows = gm.unwritten((24, 128), jnp.bfloat16)
+    assert rows.shape == (24, 128) and rows.dtype == jnp.bfloat16
+    assert np.isnan(np.asarray(rows, np.float32)).all()
+    text = str(jax.make_jaxpr(lambda: gm.unwritten((24, 128),
+                                                   jnp.float32))())
+    assert "pallas_call" in text and "broadcast_in_dim" not in text
 
 
 @pytest.mark.parametrize("layer", [0, 2, 4])
