@@ -27,6 +27,18 @@ Design notes (why this is not a torch translation):
   scale (``RopeScaling``). A forward pass decompresses and calls
   ``ops.attention`` with the two widths; ``llama_decode`` keeps the latent
   row and the rotated shared key alone and attends in the absorbed form.
+- Two more operators are the same function told other widths
+  (dots3-note-prev has both; ``LlamaConfig.latent_widths``):
+  ``"window_latent_attention"``, latent attention at a second geometry
+  (the ``swa_`` fields, its own ``rope_theta``) whose query ``t`` sees the
+  ``sliding_window`` keys up to its own, and
+  ``"indexed_latent_attention"``, whose query attends the ``index_topk``
+  keys that a learned indexer scores highest (DeepSeek-V3.2's lightning
+  indexer; ``_index_queries_and_key``, ``_chosen_keys``); both may gate each head's
+  output by a sigmoid of the layer's input (``head_gate``). The window and
+  the selection reach ``ops.attention`` as arguments; a decode keeps the
+  last ``sliding_window`` latent rows of a window layer, and the latent
+  rows and the index keys of an indexed one.
 - Attention dispatches to ``ray_tpu.ops`` (Pallas flash attention on TPU,
   reference einsum path elsewhere; ring attention when the seq axis > 1).
 - bfloat16 activations / fp32 params+optimizer by default: MXU-native.
@@ -41,7 +53,7 @@ from __future__ import annotations
 import dataclasses
 import math
 from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -113,6 +125,26 @@ class RopeScaling:
                        / (high - low), 0.0, 1.0)
         return (interpolated * ramp + extrapolated * (1.0 - ramp)
                 ).astype(np.float32)
+
+
+# the operators that `_latent_attention` computes
+LATENT_OPERATORS = ("latent", "window", "indexed")
+
+
+class LatentWidths(NamedTuple):
+    """One latent operator's geometry (``LlamaConfig.latent_widths``):
+    heads, the two ranks, a head's own and rotary key dims and its value
+    dims, the rotary base, and what limits a query's keys: ``window`` (0:
+    none) and ``topk`` (0: no indexer)."""
+    heads: int
+    q_rank: int
+    kv_rank: int
+    nope: int
+    rope: int
+    v: int
+    theta: float
+    window: int
+    topk: int
 
 
 @dataclasses.dataclass(frozen=True)
@@ -192,6 +224,29 @@ class LlamaConfig:
     router_groups: int = 0
     router_topk_groups: int = 0
     experts_held: Optional[Tuple[int, int]] = None
+    # Latent attention's other operators (dots3-note-prev has all of it).
+    # "window_latent_attention": the swa_ fields are its geometry, as the
+    # seven above are the full layers', and query t sees keys t -
+    # sliding_window + 1 .. t. "indexed_latent_attention": the full
+    # geometry, and a query attends its index_topk keys of largest index
+    # score I[t, s] = sum_j w[t, j] ReLU(q_i[t, j] . k_i[s]) over
+    # index_heads heads of index_head_dim (every key while t < index_topk).
+    # head_gate: each head's output times sigmoid(u W_g)[head] before W_o,
+    # in both. latent_rescale: c_q times (hidden / q_rank) ** 0.5 and c_kv
+    # times (hidden / kv_rank) ** 0.5 after their norms, in all three.
+    sliding_window: int = 0
+    swa_num_heads: int = 0
+    swa_q_lora_rank: int = 0
+    swa_kv_lora_rank: int = 0
+    swa_qk_nope_head_dim: int = 0
+    swa_qk_rope_head_dim: int = 0
+    swa_v_head_dim: int = 0
+    swa_rope_theta: float = 10000.0
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    head_gate: bool = False
+    latent_rescale: bool = False
 
     @staticmethod
     def llama2_7b_smoke() -> "LlamaConfig":
@@ -218,17 +273,20 @@ class LlamaConfig:
 
     def layer_kinds(self) -> Tuple[str, ...]:
         """Each layer's kind, ``<operator>_<feed-forward>``: ``attention``,
-        ``conv`` or ``latent``, then ``routed`` (experts) or ``dense``."""
+        ``conv``, ``latent``, ``window`` or ``indexed``, then ``routed``
+        (experts) or ``dense``."""
         ops = tuple(self.layer_types) or ("full_attention",) * self.num_layers
         if len(ops) != self.num_layers:
             raise ValueError(f"layer_types names {len(ops)} layers, "
                              f"num_layers is {self.num_layers}")
         names = {"full_attention": "attention", "conv": "conv",
-                 "latent_attention": "latent"}
+                 "latent_attention": "latent",
+                 "window_latent_attention": "window",
+                 "indexed_latent_attention": "indexed"}
         unknown = sorted(set(ops) - set(names))
         if unknown:
             raise ValueError(f"layer_types {unknown}: expected "
-                             "'full_attention'|'conv'|'latent_attention'")
+                             + "|".join(repr(n) for n in names))
         return tuple(
             names[op] + ("_routed" if self.num_experts
                          and i >= self.num_dense_layers else "_dense")
@@ -260,6 +318,21 @@ class LlamaConfig:
         kinds = self.layer_kinds()
         return {k: kinds.count(k) for k in dict.fromkeys(kinds)}
 
+    def latent_widths(self, operator: str = "latent") -> "LatentWidths":
+        """What ``_latent_attention`` is told of a latent operator
+        (``latent``, ``window`` or ``indexed``)."""
+        if operator == "window":
+            return LatentWidths(
+                self.swa_num_heads, self.swa_q_lora_rank,
+                self.swa_kv_lora_rank, self.swa_qk_nope_head_dim,
+                self.swa_qk_rope_head_dim, self.swa_v_head_dim,
+                self.swa_rope_theta, self.sliding_window, 0)
+        return LatentWidths(
+            self.num_heads, self.q_lora_rank, self.kv_lora_rank,
+            self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim,
+            self.rope_theta, 0,
+            self.index_topk if operator == "indexed" else 0)
+
     def dense_width(self) -> int:
         """Width of a dense SwiGLU: the leading dense layers' own in a
         model with experts, whose ``mlp_hidden`` is one expert's."""
@@ -271,15 +344,26 @@ class LlamaConfig:
         q, kv = self.num_heads * self.head_dim, self.num_kv_heads * self.head_dim
         norms = ((q + kv) if self.qk_norm
                  else 2 * self.head_dim if self.qk_head_norm else 0)
-        nh, qr, kvr = self.num_heads, self.q_lora_rank, self.kv_lora_rank
-        nope, rope, vd = (self.qk_nope_head_dim, self.qk_rope_head_dim,
-                          self.v_head_dim)
         held = self.experts_held[1] if self.experts_held else E
+
+        def latent(operator):
+            w = self.latent_widths(operator)
+            gate = h * w.heads if self.head_gate and operator != "latent" \
+                else 0
+            return (h * w.q_rank + w.q_rank
+                    + w.q_rank * w.heads * (w.nope + w.rope)
+                    + h * (w.kv_rank + w.rope) + w.kv_rank
+                    + w.kv_rank * w.heads * (w.nope + w.v)
+                    + w.heads * w.v * h + gate)
+
+        ih, ihd = self.index_heads, self.index_head_dim
         half = {"attention": h * (q + 2 * kv) + q * h + norms,
                 "conv": 4 * h * h + h * self.conv_kernel,
-                "latent": (h * qr + qr + qr * nh * (nope + rope)
-                           + h * (kvr + rope) + kvr
-                           + kvr * nh * (nope + vd) + nh * vd * h),
+                "latent": latent("latent"), "window": latent("window"),
+                # the indexer: its queries, its one key with a LayerNorm's
+                # weight and bias, its heads' weights
+                "indexed": (latent("indexed") + self.q_lora_rank * ih * ihd
+                            + h * ihd + 2 * ihd + h * ih),
                 "routed": ((held + self.num_shared_experts) * 3 * h
                            * self.mlp_hidden + h * E
                            + (E if self.router_bias else 0)),
@@ -452,12 +536,18 @@ def _kind_logical_axes(cfg: LlamaConfig, kind: str) -> Dict[str, Any]:
                      wo=("heads", "head_dim", "embed"))
         if cfg.qk_norm or cfg.qk_head_norm:
             layer.update(q_norm=("norm",), k_norm=("norm",))
-    elif operator == "latent":  # the ranks stay whole on every device
+    elif operator in LATENT_OPERATORS:  # the ranks stay whole everywhere
         layer.update(wq_a=("embed", None), q_a_norm=("norm",),
                      wq_b=(None, "heads", "head_dim"),
                      wkv_a=("embed", None), kv_a_norm=("norm",),
                      wkv_b=(None, "heads", "head_dim"),
                      wo=("heads", "head_dim", "embed"))
+        if cfg.head_gate and operator != "latent":
+            layer.update(w_head_gate=("embed", "heads"))
+        if operator == "indexed":  # the indexer is whole on every device
+            layer.update(wi_q=(None, None, None), wi_k=("embed", None),
+                         wi_k_norm=("norm",), wi_k_bias=("norm",),
+                         wi_w=("embed", None))
     else:  # the gated short convolution: in-projection, taps, out
         layer.update(conv_in=("embed", "mlp"), conv_w=("mlp", None),
                      conv_out=("mlp", "embed"))
@@ -511,10 +601,8 @@ def init_llama(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
                 "wv": norm_init((L, h, nkv, hd), ks[2], h),
                 "wo": norm_init((L, nh, hd, h), ks[3], nh * hd),
             }
-        elif operator == "latent":
-            qr, kvr = cfg.q_lora_rank, cfg.kv_lora_rank
-            nope, rope, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
-                              cfg.v_head_dim)
+        elif operator in LATENT_OPERATORS:
+            nl, qr, kvr, nope, rope, vd = cfg.latent_widths(operator)[:6]
             kq, kk = jax.random.split(ks[1])
 
             def a_layer_at_a_time(shape, k, fan_in):
@@ -526,12 +614,25 @@ def init_llama(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
             layers = {
                 "wq_a": a_layer_at_a_time((h, qr), ks[0], h),
                 "q_a_norm": jnp.ones((L, qr), pd),
-                "wq_b": a_layer_at_a_time((qr, nh, nope + rope), kq, qr),
+                "wq_b": a_layer_at_a_time((qr, nl, nope + rope), kq, qr),
                 "wkv_a": a_layer_at_a_time((h, kvr + rope), kk, h),
                 "kv_a_norm": jnp.ones((L, kvr), pd),
-                "wkv_b": a_layer_at_a_time((kvr, nh, nope + vd), ks[2], kvr),
-                "wo": a_layer_at_a_time((nh, vd, h), ks[3], nh * vd),
+                "wkv_b": a_layer_at_a_time((kvr, nl, nope + vd), ks[2], kvr),
+                "wo": a_layer_at_a_time((nl, vd, h), ks[3], nl * vd),
             }
+            if operator != "latent":
+                kg, kiq, kik, kiw = jax.random.split(
+                    jax.random.fold_in(ks[3], 1), 4)
+            if cfg.head_gate and operator != "latent":
+                layers["w_head_gate"] = norm_init((L, h, nl), kg, h)
+            if operator == "indexed":
+                ih, ihd = cfg.index_heads, cfg.index_head_dim
+                layers.update(
+                    wi_q=a_layer_at_a_time((qr, ih, ihd), kiq, qr),
+                    wi_k=norm_init((L, h, ihd), kik, h),
+                    wi_k_norm=jnp.ones((L, ihd), pd),
+                    wi_k_bias=jnp.zeros((L, ihd), pd),
+                    wi_w=norm_init((L, h, ih), kiw, h))
         else:
             layers = {
                 "conv_in": norm_init((L, h, 3 * h), ks[0], h),
@@ -633,76 +734,203 @@ def _pairs_apart(w: jax.Array) -> jax.Array:
     return jnp.concatenate([pairs[..., 0], pairs[..., 1]], axis=-1)
 
 
-def latent_softmax_scale(cfg: LlamaConfig) -> float:
-    """``(qk_nope_head_dim + qk_rope_head_dim) ** -0.5``, times YaRN's
+def latent_softmax_scale(cfg: LlamaConfig,
+                         widths: Optional[LatentWidths] = None) -> float:
+    """``(qk_nope_head_dim + qk_rope_head_dim) ** -0.5`` of a latent
+    operator's ``widths`` (the full geometry's by default), times YaRN's
     ``m ** 2`` under a ``rope_scaling``."""
-    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    w = widths or cfg.latent_widths()
+    scale = (w.nope + w.rope) ** -0.5
     if cfg.rope_scaling is not None:
         scale *= cfg.rope_scaling.softmax_amplitude()
     return scale
 
 
+def _layer_norm(x: jax.Array, w: jax.Array, b: jax.Array,
+                eps: float) -> jax.Array:
+    dt = x.dtype
+    x = x.astype(jnp.float32)
+    x = x - jnp.mean(x, axis=-1, keepdims=True)
+    x = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+    return (x * w.astype(jnp.float32) + b.astype(jnp.float32)).astype(dt)
+
+
+def _index_queries_and_key(cfg: LlamaConfig, w: LatentWidths, u: jax.Array,
+                           c_q: jax.Array, lp: Dict[str, jax.Array],
+                           positions: jax.Array):
+    """The indexer's side of a position (DeepSeek-V3.2's lightning
+    indexer): its queries ``q_i [B, index_heads, S, index_head_dim] = c_q
+    W_iq``, its ONE key ``k_i [B, S, index_head_dim] = LayerNorm(u W_ik)``
+    (weight and bias), both rotated over their first ``qk_rope_head_dim``
+    dims at the layer's base (rotate-half over those dims as they lie),
+    and its heads' weights ``[B, S, index_heads] = u W_iw`` in float32."""
+    dt, rd = cfg.dtype, w.rope
+    q_i = jnp.einsum("bsr,rjd->bsjd", c_q, lp["wi_q"].astype(dt))
+    k_i = _layer_norm(jnp.einsum("bsh,hd->bsd", u, lp["wi_k"].astype(dt)),
+                      lp["wi_k_norm"], lp["wi_k_bias"], cfg.rms_eps)
+    q_i = jnp.concatenate(
+        [_rope(q_i[..., :rd], positions, w.theta), q_i[..., rd:]], axis=-1)
+    k_i = jnp.concatenate(
+        [_rope(k_i[:, :, None, :rd], positions, w.theta)[:, :, 0],
+         k_i[..., rd:]], axis=-1)
+    weights = jnp.einsum("bsh,hj->bsj", u, lp["wi_w"].astype(dt),
+                         preferred_element_type=jnp.float32)
+    return jnp.swapaxes(q_i, 1, 2), k_i, weights
+
+
+def _chosen_keys(scores: jax.Array, seen: jax.Array, k: int) -> jax.Array:
+    """Which keys a query attends: of the keys it may see (``seen``, which
+    broadcasts against ``scores [..., S, T]`` float32) the ``k`` of largest
+    score, all of them where it sees no more than ``k``. Exact, and no
+    sort: the ``k``-th largest score of each query is found bit by bit
+    (float32's order is that of its bits seen as an unsigned number, the
+    sign flipped and a negative's bits inverted; 32 counts of the scores at
+    or above a candidate), and a key stays if its score is at or above it,
+    so keys that tie with the ``k``-th all stay (``lax.top_k`` keeps the
+    lowest indices among them)."""
+    bits = jax.lax.bitcast_convert_type(scores, jnp.uint32)
+    ordered = jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+    ordered = jnp.where(seen, ordered, jnp.uint32(0))  # below every number
+
+    def a_bit(i, kth):
+        candidate = kth | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        enough = jnp.sum(ordered >= candidate[..., None], axis=-1,
+                         dtype=jnp.int32) >= k
+        return jnp.where(enough, candidate, kth)
+
+    kth = jax.lax.fori_loop(0, 32, a_bit,
+                            jnp.zeros(scores.shape[:-1], jnp.uint32))
+    return seen & (ordered >= kth[..., None])
+
+
 def _latent_attention(cfg: LlamaConfig, u: jax.Array,
                       lp: Dict[str, jax.Array], positions: jax.Array,
                       state: Optional[jax.Array] = None,
-                      cache_index: Optional[jax.Array] = None):
+                      cache_index: Optional[jax.Array] = None, *,
+                      operator: str = "latent",
+                      live: Optional[jax.Array] = None,
+                      counts: Optional[Dict[str, jax.Array]] = None):
     """Latent attention (DeepSeek-V2's MLA) on the normed input ``u [B, S,
-    H]`` -> (its output ``[B, S, H]``, the state after it or None).
+    H]`` -> (its output ``[B, S, H]``, the state after it or None), at the
+    widths of ``operator`` (``LlamaConfig.latent_widths``).
     ``c_q = RMSNorm(u W_qa)``; ``[q_nope | q_pe] = c_q W_qb`` a head;
     ``[c_kv | k_pe] = u W_kva``, ``c_kv = RMSNorm(c_kv)``; ``[k_nope | v] =
     c_kv W_kvb`` a head; the rotary parts rotated (``k_pe`` is one row a
-    position, every head's); causal softmax of ``(q_nope k_nope^T + q_pe
-    k_pe^T) * latent_softmax_scale``; the heads' values through ``W_o``.
+    position, every head's); softmax of ``(q_nope k_nope^T + q_pe k_pe^T)
+    * latent_softmax_scale`` over the keys the operator allows; the heads'
+    values through ``W_o``. Under ``latent_rescale`` ``c_q`` and ``c_kv``
+    are multiplied by ``(hidden / rank) ** 0.5`` after their norms.
+
+    The keys a query sees: the causal ones; of a ``window`` operator the
+    last ``sliding_window`` of them, its own among them; of an ``indexed``
+    one the ``index_topk`` that the indexer scores highest
+    (``_index_queries_and_key``, ``ops.index_scores``, ``_chosen_keys``:
+    scores and choice in float32). With ``w_head_gate`` among the leaves,
+    head ``n``'s output is multiplied by ``sigmoid(u W_g)[n]`` before
+    ``W_o``. ``counts``, where given, is left ``index_kept``: the (query,
+    key) pairs an indexed operator's choice kept, over the queries that
+    ``live [B, S]`` marks (all without it).
 
     Without a state the latent rows are decompressed to every head and
-    ``ops.attention`` is handed the two widths. ``state [B, max_len,
-    kv_lora_rank + qk_rope_head_dim]`` is all a decode keeps of a position,
-    the normed ``c_kv`` and the rotated ``k_pe``: the new rows are written
-    at ``cache_index`` and attention runs in the absorbed form, ``q_nope
-    W_kvb[k]^T`` against ``c_kv`` itself and the weighted ``c_kv`` through
-    ``W_kvb[v]``, so no key or value of a head is ever made."""
+    ``ops.attention`` is handed the two widths, the window and the choice.
+    ``state`` is all a decode keeps of a position, the normed ``c_kv`` and
+    the rotated ``k_pe`` (and an indexed operator's index key after them):
+    ``[B, max_len, kv_lora_rank + qk_rope_head_dim (+ index_head_dim)]``,
+    the new rows written at ``cache_index``; of a window operator ``[B,
+    sliding_window, ...]``, the last rows before this call in order, the
+    new ones pushed in at the end. Attention then runs in the absorbed
+    form, ``q_nope W_kvb[k]^T`` against ``c_kv`` itself and the weighted
+    ``c_kv`` through ``W_kvb[v]``, so no key or value of a head is ever
+    made."""
+    from ray_tpu.ops.attention import index_scores
+
     dt = cfg.dtype
-    nope, kvr = cfg.qk_nope_head_dim, cfg.kv_lora_rank
-    scale = latent_softmax_scale(cfg)
+    w = cfg.latent_widths(operator)
+    nope, kvr = w.nope, w.kv_rank
+    scale = latent_softmax_scale(cfg, w)
+    S = u.shape[1]
     with jax.named_scope("latent_attention"):
         c_q = _rms_norm(jnp.einsum("bsh,hr->bsr", u, lp["wq_a"].astype(dt)),
                         lp["q_a_norm"], cfg.rms_eps)
         wq_b, wkv_a = lp["wq_b"].astype(dt), lp["wkv_a"].astype(dt)
         wkv_b = lp["wkv_b"].astype(dt)
+        if cfg.latent_rescale:
+            c_q = c_q * (cfg.hidden / w.q_rank) ** 0.5
         q_nope = jnp.einsum("bsr,rnd->bsnd", c_q, wq_b[..., :nope])
         q_pe = _yarn_rope(
             jnp.einsum("bsr,rnd->bsnd", c_q, _pairs_apart(wq_b[..., nope:])),
-            positions, cfg.rope_theta, cfg.rope_scaling)
+            positions, w.theta, cfg.rope_scaling)
         c_kv = _rms_norm(jnp.einsum("bsh,hr->bsr", u, wkv_a[:, :kvr]),
                          lp["kv_a_norm"], cfg.rms_eps)
+        if cfg.latent_rescale:
+            c_kv = c_kv * (cfg.hidden / kvr) ** 0.5
         k_pe = _yarn_rope(
             jnp.einsum("bsh,hr->bsr", u, _pairs_apart(wkv_a[:, kvr:])),
-            positions, cfg.rope_theta, cfg.rope_scaling)
+            positions, w.theta, cfg.rope_scaling)
         q_nope = constrain(q_nope, ("batch", "seq", "heads", None))
         q_pe = constrain(q_pe, ("batch", "seq", "heads", None))
+        new_rows = [c_kv, k_pe]
+        if w.topk:
+            with jax.named_scope("indexer"):
+                q_i, k_i, head_weights = _index_queries_and_key(
+                    cfg, w, u, c_q, lp, positions)
+            new_rows.append(k_i)
+        keep = None
         if state is None:
+            if w.topk:
+                with jax.named_scope("indexer"):
+                    scores = index_scores(q_i, k_i, head_weights,
+                                          impl=cfg.attn_impl)
+                with jax.named_scope("index_choice"):
+                    at = jnp.arange(S)
+                    keep = _chosen_keys(scores, at[:, None] >= at[None, :],
+                                        w.topk)
             k_nope = jnp.einsum("bsr,rnd->bsnd", c_kv, wkv_b[..., :nope])
             v = jnp.einsum("bsr,rnd->bsnd", c_kv, wkv_b[..., nope:])
             k_nope = constrain(k_nope, ("batch", "seq", "heads", None))
             out = attention(q_nope, k_nope, v, impl=cfg.attn_impl,
                             causal=True, q_rope=q_pe, k_rope=k_pe,
-                            scale=scale)
+                            scale=scale, window=w.window or None, keep=keep)
         else:
-            state = jax.lax.dynamic_update_slice_in_dim(
-                state, jnp.concatenate([c_kv, k_pe], axis=-1).astype(
-                    state.dtype), cache_index, axis=1)
-            c_all, pe_all = state[..., :kvr], state[..., kvr:]
+            rows = jnp.concatenate(new_rows, axis=-1).astype(state.dtype)
+            q_pos = jnp.arange(S) + cache_index
+            if w.window:  # the last rows before this call, then the new
+                held = jnp.concatenate([state, rows], axis=1)
+                state = held[:, S:]
+                key_pos = q_pos[0] - w.window + jnp.arange(w.window + S)
+            else:
+                state = held = jax.lax.dynamic_update_slice_in_dim(
+                    state, rows, cache_index, axis=1)
+                key_pos = jnp.arange(state.shape[1])
+            seen = (q_pos[:, None] >= key_pos[None, :]) & (key_pos >= 0)
+            if w.window:
+                seen &= q_pos[:, None] - key_pos[None, :] < w.window
+            if w.topk:
+                with jax.named_scope("indexer"):
+                    scores = index_scores(q_i, held[..., -k_i.shape[-1]:],
+                                          head_weights, impl="reference")
+                with jax.named_scope("index_choice"):
+                    keep = seen = _chosen_keys(scores, seen, w.topk)
+            c_all = held[..., :kvr]
+            pe_all = held[..., kvr:kvr + w.rope]
             q_abs = jnp.einsum("bsnd,rnd->bsnr", q_nope, wkv_b[..., :nope])
             scores = (jnp.einsum("bsnr,btr->bnst", q_abs, c_all
                                  ).astype(jnp.float32)
                       + jnp.einsum("bsnd,btd->bnst", q_pe, pe_all
                                    ).astype(jnp.float32)) * scale
-            q_pos = jnp.arange(u.shape[1]) + cache_index
-            seen = q_pos[:, None] >= jnp.arange(state.shape[1])[None, :]
-            probs = jax.nn.softmax(jnp.where(seen[None, None], scores, -1e30),
+            seen = seen[None, None] if seen.ndim == 2 else seen[:, None]
+            probs = jax.nn.softmax(jnp.where(seen, scores, -1e30),
                                    axis=-1).astype(dt)
             weighted = jnp.einsum("bnst,btr->bsnr", probs, c_all)
             out = jnp.einsum("bsnr,rnd->bsnd", weighted, wkv_b[..., nope:])
+        if keep is not None and counts is not None:
+            mine = keep if live is None else keep & live[:, :, None]
+            counts["index_kept"] = jnp.sum(mine, dtype=jnp.int32)
+        if "w_head_gate" in lp:
+            gate = jax.nn.sigmoid(jnp.einsum(
+                "bsh,hn->bsn", u, lp["w_head_gate"].astype(dt),
+                preferred_element_type=jnp.float32))
+            out = out * gate[..., None].astype(dt)
         out = constrain(out, ("batch", "seq", "heads", None))
         y = jnp.einsum("bsnd,ndh->bsh", out, lp["wo"].astype(dt))
     return y, state
@@ -738,17 +966,24 @@ def _short_conv(cfg: LlamaConfig, u: jax.Array, lp: Dict[str, jax.Array],
 def _layer(cfg: LlamaConfig, x: jax.Array, lp: Dict[str, jax.Array],
            positions: jax.Array, kv_cache=None,
            cache_index: Optional[jax.Array] = None,
-           lora: Optional[Dict[str, Any]] = None, lora_scale: float = 0.0):
+           lora: Optional[Dict[str, Any]] = None, lora_scale: float = 0.0,
+           operator: Optional[str] = None,
+           live: Optional[jax.Array] = None):
     """One block. x: [B, S, H_model] -> (x, the layer's updated state or
-    None, the router's books of ``moe.expert_ffn`` or None for a dense
-    feed-forward). The layer's kind is read off its leaves: ``conv_in``
-    makes the operator the gated short convolution and ``wkv_a`` latent
-    attention, and not attention; ``router`` makes the feed-forward the
-    routed experts and not the dense SwiGLU. ``kv_cache`` is the layer's
-    own state in an incremental decode: (keys, values) for attention, the
-    last rows of ``z`` for the short convolution (``_short_conv``), the
-    latent rows for latent attention (``_latent_attention``)."""
+    None, the layer's books or None: the router's of ``moe.expert_ffn``
+    under routed experts, and ``index_kept`` of an indexed operator,
+    ``_latent_attention``'s count over the queries ``live`` marks). The
+    layer's kind is read off its leaves: ``conv_in`` makes the operator the
+    gated short convolution and ``wkv_a`` latent attention, and not
+    attention; ``router`` makes the feed-forward the routed experts and
+    not the dense SwiGLU. Which latent operator it is (``latent``,
+    ``window``, ``indexed``) the leaves do not say: ``operator`` does.
+    ``kv_cache`` is the layer's own state in an incremental decode: (keys,
+    values) for attention, the last rows of ``z`` for the short
+    convolution (``_short_conv``), the latent rows for latent attention
+    (``_latent_attention``)."""
     dt = cfg.dtype
+    counts: Dict[str, jax.Array] = {}
 
     def _ld(name, t_in, eq_a, eq_b):
         """Activation-side LoRA delta: (t_in @ A) @ B * scale, or 0."""
@@ -764,8 +999,9 @@ def _layer(cfg: LlamaConfig, x: jax.Array, lp: Dict[str, jax.Array],
         new_cache = None if kv_cache is None else state
         x = x + y
     elif "wkv_a" in lp:
-        y, new_cache = _latent_attention(cfg, h, lp, positions, kv_cache,
-                                         cache_index)
+        y, new_cache = _latent_attention(
+            cfg, h, lp, positions, kv_cache, cache_index,
+            operator=operator or "latent", live=live, counts=counts)
         x = x + y
     else:
         # --- attention ---
@@ -811,7 +1047,8 @@ def _layer(cfg: LlamaConfig, x: jax.Array, lp: Dict[str, jax.Array],
     h = _rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
     if "router" in lp:
         y, books = moe.expert_ffn(cfg, h, lp)
-        return constrain(x + y, ("batch", "seq", "embed")), new_cache, books
+        return (constrain(x + y, ("batch", "seq", "embed")), new_cache,
+                {**books, **counts})
     gate = (jnp.einsum("bsh,hm->bsm", h, lp["w_gate"].astype(dt))
             + _ld("w_gate", h, "bsh,hr->bsr", "bsr,rm->bsm"))
     up = (jnp.einsum("bsh,hm->bsm", h, lp["w_up"].astype(dt))
@@ -820,7 +1057,7 @@ def _layer(cfg: LlamaConfig, x: jax.Array, lp: Dict[str, jax.Array],
     x = (x + jnp.einsum("bsm,mh->bsh", act, lp["w_down"].astype(dt))
          + _ld("w_down", act, "bsm,mr->bsr", "bsr,rh->bsh"))
     x = constrain(x, ("batch", "seq", "embed"))
-    return x, new_cache, None
+    return x, new_cache, counts or None
 
 
 def init_decode_state(cfg: LlamaConfig, batch: int, max_len: int) -> list:
@@ -830,12 +1067,19 @@ def init_decode_state(cfg: LlamaConfig, batch: int, max_len: int) -> list:
     1`` rows of ``z`` ``[batch, conv_kernel - 1, hidden]`` for a short
     convolution, the normed latent row and the rotated shared key
     ``[batch, max_len, kv_lora_rank + qk_rope_head_dim]`` for latent
-    attention, all zeros."""
+    attention (an indexed operator's index key ``[index_head_dim]`` after
+    them; a window operator keeps its last ``sliding_window`` rows and not
+    ``max_len``), all zeros."""
+    def latent_rows(op):
+        w = cfg.latent_widths(op)
+        return (batch, w.window or max_len, w.kv_rank + w.rope
+                + (cfg.index_head_dim if w.topk else 0))
+
+    operators = [kind.split("_")[0] for kind in cfg.layer_kinds()]
     shapes = {
         "attention": (batch, max_len, cfg.num_kv_heads, cfg.head_dim),
         "conv": (batch, cfg.conv_kernel - 1, cfg.hidden),
-        "latent": (batch, max_len, cfg.kv_lora_rank + cfg.qk_rope_head_dim)}
-    operators = [kind.split("_")[0] for kind in cfg.layer_kinds()]
+        **{op: latent_rows(op) for op in LATENT_OPERATORS}}
     zeros = {op: jnp.zeros(shapes[op], cfg.dtype) for op in set(operators)}
     return [(zeros[op], zeros[op]) if op == "attention" else zeros[op]
             for op in operators]
@@ -865,7 +1109,8 @@ def llama_decode(
         lp = jax.tree.map(lambda a: a[j], stacks[kind])
         if "router" in lp:
             lp = moe.in_stack(lp, stacks[kind], j)
-        x, c, _ = _layer(cfg, x, lp, positions, kv_caches[i], cache_index)
+        x, c, _ = _layer(cfg, x, lp, positions, kv_caches[i], cache_index,
+                         operator=kind.split("_")[0])
         new_caches.append(c)
     x = _rms_norm(x, params["final_norm"], cfg.rms_eps)
     logits = jnp.einsum("bsh,hv->bsv", x, _lm_head(params).astype(cfg.dtype))
@@ -898,9 +1143,11 @@ def _hidden_and_books(
     lora_cfg: Optional[LoraConfig] = None,
     router_mask: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
-    """``llama_hidden``, and with it the routers' books stacked over the
-    layers that have routed experts, in the model's order
-    (``moe.expert_ffn``; None for a model without experts). ``router_mask
+    """``llama_hidden``, and with it the layers' books (``_layer``), each
+    key stacked over the layers that keep it, in the model's order: the
+    routers' over the layers that have routed experts
+    (``moe.expert_ffn``), ``index_kept`` over the indexed operators; None
+    for a model that has neither. ``router_mask
     [B, S]`` marks the positions that are a row's own: the books count
     them alone and the routed experts multiply their pairs alone, so a
     masked-out position's hidden state lacks its routed part (rows are
@@ -939,7 +1186,9 @@ def _hidden_and_books(
                 lp = moe.in_stack(lp, stacks[kind], i, router_mask,
                                   skip_unmasked=True)
             y, _, books = _layer(cfg, carry, lp, positions, lora=lo_i,
-                                 lora_scale=scale)
+                                 lora_scale=scale,
+                                 operator=kind.split("_")[0],
+                                 live=router_mask)
             return y, books
 
         return scan_fn, (part(stacks[kind]), part(lo_stacks.get(kind)),
@@ -978,10 +1227,11 @@ def _hidden_and_books(
             if b is not None:
                 books.append(b)
         first += n
-    if len(books) > 1:  # the routed layers', in the model's order
-        books = [jax.tree.map(lambda *bs: jnp.concatenate(bs), *books)]
-    return (_rms_norm(x, params["final_norm"], cfg.rms_eps),
-            books[0] if books else None)
+    # each key over the layers that keep it, in the model's order: the
+    # routers' over the routed layers, `index_kept` over the indexed ones
+    joined = {key: jnp.concatenate([b[key] for b in books if key in b])
+              for key in dict.fromkeys(k for b in books for k in b)}
+    return (_rms_norm(x, params["final_norm"], cfg.rms_eps), joined or None)
 
 
 def llama_forward(
@@ -1027,16 +1277,22 @@ def llama_next_token(
     is over their fp32 logits and a tie goes to the lowest id, as
     ``np.argmax`` has it. The hidden states are returned so that a caller
     who wants every position's logits applies ``llama_head`` to them and
-    runs the layers once. The load is None for a model without experts,
-    else ``moe.router_load`` over the positions ``live [B, S]`` marks (the
-    rows' own tokens and not their padding): two float32 a routed layer.
+    runs the layers once. The load is None for a model without experts or
+    an indexer, else ``moe.router_load`` over the positions ``live [B,
+    S]`` marks (the rows' own tokens and not their padding), two float32 a
+    routed layer, and ``index_kept``, an int32 an indexed operator: the
+    (query, key) pairs its choice kept over those positions' queries.
     With ``live`` the routed experts compute the marked positions alone,
     and the hidden states of the others are not a forward pass's."""
     x, books = _hidden_and_books(params, tokens, cfg, lora=lora,
                                  lora_cfg=lora_cfg, router_mask=live)
     rows = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
     ids = jnp.argmax(llama_head(params, rows, cfg), axis=-1)
-    load = moe.router_load(books) if books is not None else None
+    load = None
+    if books is not None:
+        load = moe.router_load(books) if "pairs" in books else {}
+        if "index_kept" in books:
+            load["index_kept"] = books["index_kept"]
     return ids.astype(jnp.int32), x, load
 
 
@@ -1105,7 +1361,8 @@ def llama_loss(params: Dict[str, Any], batch: Dict[str, jax.Array],
         nll = _nll_from_logits(logits, targets)
         ce = (jnp.mean(nll) if mask is None else
               jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0))
-    if books is not None:  # the routers' load-balancing term rides on it
+    if books is not None and "pairs" in books:
+        # the routers' load-balancing term rides on it
         ce = ce + cfg.router_aux_loss_coef * moe.load_balancing_loss(
             books, cfg)
     return ce

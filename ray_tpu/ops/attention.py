@@ -9,6 +9,14 @@ keys may have a second, rotary part whose key is one row a position shared
 by every head: ``q_rope [B, S, H, R]``, ``k_rope [B, S_kv, R]``; the scores
 are ``q k^T + q_rope k_rope^T``, times ``scale`` (by default the whole
 query width ``** -0.5``), and the result is ``[B, S, H, Dv]``.
+
+Fewer keys than the causal ones. ``window``: query ``t`` sees keys ``t -
+window + 1 .. t``, its own among them. ``keep [B, S, S_kv]`` (bool): query
+``t`` of row ``b`` sees key ``s`` only where ``keep[b, t, s]``, every head
+alike (an indexer's choice: ``index_scores`` gives what it is made from).
+Both come on top of ``causal``; the flash kernel takes them at two widths
+(the window's blocks outside it are not visited; the choice is a mask a
+block).
 """
 
 from __future__ import annotations
@@ -39,6 +47,8 @@ def reference_attention(
     q_rope: Optional[jax.Array] = None,
     k_rope: Optional[jax.Array] = None,
     scale: Optional[float] = None,
+    window: Optional[int] = None,
+    keep: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Plain einsum attention with fp32 softmax. ``q_offset`` positions the
     query block inside a longer kv sequence (decode with kv cache)."""
@@ -62,7 +72,13 @@ def reference_attention(
         if q_offset is not None:
             q_pos = q_pos + q_offset
         mask = q_pos[:, None] >= kv_pos[None, :]
+        if window is not None:
+            mask &= q_pos[:, None] - kv_pos[None, :] < window
         logits = jnp.where(mask[None, None], logits, _NEG_INF)
+    elif window is not None:
+        raise ValueError("a window is the causal keys' last ones")
+    if keep is not None:
+        logits = jnp.where(keep[:, None], logits, _NEG_INF)
     if valid_kv_len is not None:
         vmask = kv_pos[None, :] < valid_kv_len[:, None]  # [B, Skv]
         logits = jnp.where(vmask[:, None, None], logits, _NEG_INF)
@@ -73,7 +89,9 @@ def reference_attention(
 def _flash_per_shard(q: jax.Array, k: jax.Array, v: jax.Array,
                      causal: bool, q_rope: Optional[jax.Array] = None,
                      k_rope: Optional[jax.Array] = None,
-                     scale: Optional[float] = None) -> jax.Array:
+                     scale: Optional[float] = None,
+                     window: Optional[int] = None,
+                     keep: Optional[jax.Array] = None) -> jax.Array:
     """The Pallas kernel, run on each device's own shard when a mesh is in
     scope. A ``pallas_call`` has no partitioning rule: left to GSPMD its
     operands are all-gathered and every chip computes the whole batch."""
@@ -89,8 +107,14 @@ def _flash_per_shard(q: jax.Array, k: jax.Array, v: jax.Array,
                 "needs impl='reference'")
         if scale is None:
             scale = (q.shape[3] + q_rope.shape[3]) ** -0.5
-        return flash_attention_shared_rope(q, q_rope, k, k_rope, v, scale,
-                                           causal)
+        return flash_attention_shared_rope(
+            q, q_rope, k, k_rope, v, scale, causal, window,
+            None if keep is None else keep.astype(jnp.int8))
+    if window is not None or keep is not None:
+        raise NotImplementedError(
+            "the equal-width flash kernels see every causal key: a window "
+            "or a choice of keys needs the two-width forward or "
+            "impl='reference'")
     if scale is not None or v.shape[3] != q.shape[3]:
         raise NotImplementedError(
             "the equal-width flash kernels scale by head_dim ** -0.5 and "
@@ -123,12 +147,14 @@ def attention(
     q_rope: Optional[jax.Array] = None,
     k_rope: Optional[jax.Array] = None,
     scale: Optional[float] = None,
+    window: Optional[int] = None,
+    keep: Optional[jax.Array] = None,
 ) -> jax.Array:
     """impl: auto (on the TPU platform the flash kernel, on the CPU platform
     the reference), flash, reference. Ring attention is invoked explicitly
     via ops.ring_attention by the seq-parallel layer, not through this
     dispatcher. ``q_rope``, ``k_rope`` and ``scale`` are the module
-    docstring's two widths.
+    docstring's two widths, ``window`` and ``keep`` its fewer keys.
 
     ``auto`` on a TPU still takes the reference for what the kernel has no
     path for (cached decode, lengths off the 128 grid, widths it does not
@@ -152,6 +178,7 @@ def attention(
                 and q.shape[1] == k.shape[1]
                 and q.shape[1] % 128 == 0
                 and (q_rope is not None or scale is None)
+                and (q_rope is not None or (window is None and keep is None))
                 and takes_head_dim(q.shape[3] + shared, v.shape[3],
                                    shared_dim=shared))
             if kernel_takes_it:
@@ -178,7 +205,10 @@ def attention(
             raise NotImplementedError(
                 "flash attention does not support q_offset/valid_kv_len; "
                 "use impl='reference' for cached decode")
-        return _flash_per_shard(q, k, v, causal, q_rope, k_rope, scale)
+        fewer = {name: given for name, given in (
+            ("window", window), ("keep", keep)) if given is not None}
+        return _flash_per_shard(q, k, v, causal, q_rope, k_rope, scale,
+                                **fewer)
     if impl != "reference":
         raise ValueError(
             f"unknown attention impl {impl!r}; expected "
@@ -186,4 +216,42 @@ def attention(
             "(ring attention is the model layer's 'ring_seq' path)")
     return reference_attention(q, k, v, causal=causal, q_offset=q_offset,
                                valid_kv_len=valid_kv_len, q_rope=q_rope,
-                               k_rope=k_rope, scale=scale)
+                               k_rope=k_rope, scale=scale, window=window,
+                               keep=keep)
+
+
+def reference_index_scores(q: jax.Array, k: jax.Array,
+                           weights: jax.Array) -> jax.Array:
+    """``index_scores`` by plain einsums: every head's ``[S, T]`` float32
+    products at once, so for small sizes and a decode's few queries."""
+    products = jnp.einsum("bjsd,btd->bjst", q, k,
+                          preferred_element_type=jnp.float32)
+    return jnp.einsum("bjst,bsj->bst", jax.nn.relu(products),
+                      weights.astype(jnp.float32))
+
+
+def index_scores(q: jax.Array, k: jax.Array, weights: jax.Array, *,
+                 impl: str = "auto") -> jax.Array:
+    """An indexer's scores (DeepSeek-V3.2's lightning indexer): ``q [B, J,
+    S, D]`` (``J`` index heads), ``k [B, T, D]`` (ONE key a position) and
+    ``weights [B, S, J]`` -> ``I [B, S, T]`` float32, ``I[b, s, t] = sum_j
+    weights[b, s, j] ReLU(q[b, j, s] . k[b, t])``. impl as ``attention``'s:
+    the kernel (``ops/pallas/index_scores.py``: no ``[J, S, T]`` tensor is
+    made; it scores a prefill's square and leaves the blocks above the
+    diagonal zero) or the reference."""
+    if impl == "auto":
+        platform = jax.default_backend()
+        if platform not in ("tpu", "cpu"):
+            raise RuntimeError(
+                f"index_scores impl 'auto' knows the tpu and cpu platforms, "
+                f"not {platform!r}; name an impl")
+        impl = ("flash" if platform == "tpu" and q.shape[2] == k.shape[1]
+                and q.shape[2] % 128 == 0 else "reference")
+    if impl == "flash":
+        from ray_tpu.ops.pallas.index_scores import index_scores_causal
+
+        return index_scores_causal(q, k, weights)
+    if impl != "reference":
+        raise ValueError(f"unknown index_scores impl {impl!r}; expected "
+                         "auto|flash|reference")
+    return reference_index_scores(q, k, weights)

@@ -69,6 +69,19 @@ and trace names are the parent's). It runs under a scope of its own,
 equal-width path keeps the name it has there. There is no backward at two
 widths: differentiating it raises and says so (no cell trains such a
 model; the model layer's ``reference`` path differentiates).
+
+Fewer keys than the causal ones (dots3-note-prev's two operators), at two
+widths alone. ``window``: a query sees its last ``window`` keys, and the
+innermost grid dim is as long as the most key blocks a query block's
+window reaches (two or three at a window of 513), walked from the
+window's first block, so the blocks outside it cost neither a matmul nor a
+copy nor a grid step (``WINDOW_TRACE_NAME``). ``keep [B, S, S]`` int8: a
+choice of keys a query that every head shares, as an indexer makes it; its
+block is read beside the keys' and masks the scores
+(``SELECTED_TRACE_NAME``). The choice is known when the program runs and
+not before, so no block is skipped for it: the kernel computes the causal
+blocks whole, and its share of a roofline reckoned over the KEPT pairs
+says so. A call that passes neither compiles what it always did.
 """
 
 from __future__ import annotations
@@ -508,13 +521,48 @@ flash_attention.defvjp(_fa_fwd, _fa_bwd)
 SHARED_ROPE_TRACE_NAME = "flash_fwd_shared_rope"
 
 
-def _fwd_shared_rope_kernel(q_ref, qr_ref, k_ref, kr_ref, v_ref, o_ref,
-                            m_scr, l_scr, acc_scr, *, scale: float,
-                            causal: bool, block_q: int, block_k: int):
+# The same forward under fewer keys, by what limits them: a name each in
+# the device trace.
+WINDOW_TRACE_NAME = "flash_fwd_window"
+SELECTED_TRACE_NAME = "flash_fwd_selected"
+
+
+def _window_key_blocks(sq: int, block_q: int, block_k: int,
+                       window: int) -> int:
+    """The most key blocks any query block's window reaches: queries
+    ``iq * block_q`` on see keys from ``iq * block_q - window + 1`` (block
+    ``_first_key_block``) to the block's last query's own."""
+    def first(iq):
+        return max(iq * block_q - window + 1, 0) // block_k
+
+    return max((iq * block_q + block_q - 1) // block_k - first(iq) + 1
+               for iq in range(sq // block_q))
+
+
+def _first_key_block(iq, block_q: int, block_k: int, window: int):
+    """The first key block a query block's window reaches (``iq`` traced)."""
+    return jnp.maximum(iq * block_q - (window - 1), 0) // block_k
+
+
+def _fwd_shared_rope_kernel(*refs, scale: float, causal: bool, block_q: int,
+                            block_k: int, window: Optional[int] = None,
+                            selected: bool = False):
     """``_fwd_kernel`` with the scores in two parts, ``q k^T`` over the
     head's own width and ``q_rope k_rope^T`` over the shared rotary key's;
     operands in their own type, float32 accumulators, no logsumexp (there
-    is no backward to hand it to)."""
+    is no backward to hand it to). With ``window`` the innermost grid dim
+    walks the key blocks that the query block's window reaches and no
+    other; with ``selected`` a block of ``keep [B, S, S_kv]`` (int8) rides
+    along and masks the scores, every head alike. A query's row of a block
+    in which it sees no key fills with ``exp(0)``; the first block in which
+    it sees one rescales that away (``alpha`` is 0), and every query sees
+    its window's or its choice's keys somewhere."""
+    if selected:
+        (q_ref, qr_ref, k_ref, kr_ref, v_ref, keep_ref, o_ref,
+         m_scr, l_scr, acc_scr) = refs
+    else:
+        (q_ref, qr_ref, k_ref, kr_ref, v_ref, o_ref,
+         m_scr, l_scr, acc_scr) = refs
     iq = pl.program_id(2)
     ik = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -525,9 +573,13 @@ def _fwd_shared_rope_kernel(q_ref, qr_ref, k_ref, kr_ref, v_ref, o_ref,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
+    # the key block this step is at: under a window the walk starts at the
+    # window's first block
+    at = ik if window is None else ik + _first_key_block(
+        iq, block_q, block_k, window)
     run = True
     if causal:
-        run = ik * block_k <= iq * block_q + block_q - 1
+        run = at * block_k <= iq * block_q + block_q - 1
 
     @pl.when(run)
     def _compute():
@@ -542,9 +594,14 @@ def _fwd_shared_rope_kernel(q_ref, qr_ref, k_ref, kr_ref, v_ref, o_ref,
         if causal:
             q_pos = iq * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
-            k_pos = ik * block_k + jax.lax.broadcasted_iota(
+            k_pos = at * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+            seen = q_pos >= k_pos
+            if window is not None:
+                seen = seen & (q_pos - k_pos < window)
+            s = jnp.where(seen, s, _NEG_INF)
+        if selected:
+            s = jnp.where(keep_ref[0].astype(jnp.int32) != 0, s, _NEG_INF)
         m_prev = m_scr[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -564,17 +621,37 @@ def _fwd_shared_rope_kernel(q_ref, qr_ref, k_ref, kr_ref, v_ref, o_ref,
 
 def _flash_fwd_shared_rope(q: jax.Array, q_rope: jax.Array, k: jax.Array,
                            k_rope: jax.Array, v: jax.Array, *, scale: float,
-                           causal: bool) -> jax.Array:
+                           causal: bool, window: Optional[int] = None,
+                           keep: Optional[jax.Array] = None) -> jax.Array:
     """q [B,H,S,D], q_rope [B,H,S,R], k [B,H,S,D], k_rope [B,S,R] (one row
-    a position, every head's), v [B,H,S,Dv] → o [B,H,S,Dv]."""
+    a position, every head's), v [B,H,S,Dv], keep [B,S,S] int8 or None →
+    o [B,H,S,Dv]."""
     B, H, Sq, D = q.shape
     R, Skv, Dv = q_rope.shape[3], k.shape[2], v.shape[3]
+    if (window is not None or keep is not None) and not (
+            causal and Sq == Skv):
+        raise ValueError("a window or a choice of keys is a prefill's: "
+                         "causal, the queries' positions the keys'")
     # what the blocks fill in VMEM: a part of 64 pads to the lane width
     padded = D + -(-R // _LANES) * _LANES
     block_q, block_k = flash_tiles(Sq, Skv, head_dim=padded, value_dim=Dv)
     kernel = functools.partial(
         _fwd_shared_rope_kernel, scale=scale, causal=causal,
-        block_q=block_q, block_k=block_k)
+        block_q=block_q, block_k=block_k, window=window,
+        selected=keep is not None)
+    key_blocks = Skv // block_k
+    if window is not None:
+        key_blocks = _window_key_blocks(Sq, block_q, block_k, window)
+
+    def key_block(iq, ik):
+        """The key block of a grid step. Under a window: from its first
+        block on, and past the diagonal the diagonal's again, which costs
+        no copy."""
+        if window is None:
+            return ik
+        return jnp.minimum(
+            ik + _first_key_block(iq, block_q, block_k, window),
+            (iq * block_q + block_q - 1) // block_k)
 
     def rows(block, width):   # a head's rows: q, q_rope and o by iq
         return pl.BlockSpec((1, 1, block, width),
@@ -582,19 +659,29 @@ def _flash_fwd_shared_rope(q: jax.Array, q_rope: jax.Array, k: jax.Array,
 
     def keys(width):          # a head's keys and values by ik
         return pl.BlockSpec((1, 1, block_k, width),
-                            lambda b, h, iq, ik: (b, h, ik, 0))
+                            lambda b, h, iq, ik: (b, h, key_block(iq, ik), 0))
 
-    with jax.named_scope(SHARED_ROPE_TRACE_NAME):
+    in_specs = [
+        rows(block_q, D), rows(block_q, R), keys(D),
+        # the shared rotary key: no head in its index
+        pl.BlockSpec((1, block_k, R),
+                     lambda b, h, iq, ik: (b, key_block(iq, ik), 0)),
+        keys(Dv),
+    ]
+    operands = [q, q_rope, k, k_rope, v]
+    if keep is not None:  # the choice: no head in its index either
+        in_specs.append(pl.BlockSpec(
+            (1, block_q, block_k),
+            lambda b, h, iq, ik: (b, iq, key_block(iq, ik))))
+        operands.append(keep)
+    name = (SELECTED_TRACE_NAME if keep is not None else
+            WINDOW_TRACE_NAME if window is not None else
+            SHARED_ROPE_TRACE_NAME)
+    with jax.named_scope(name):
         return pl.pallas_call(
             kernel,
-            grid=(B, H, Sq // block_q, Skv // block_k),
-            in_specs=[
-                rows(block_q, D), rows(block_q, R), keys(D),
-                # the shared rotary key: no head in its index
-                pl.BlockSpec((1, block_k, R),
-                             lambda b, h, iq, ik: (b, ik, 0)),
-                keys(Dv),
-            ],
+            grid=(B, H, Sq // block_q, key_blocks),
+            in_specs=in_specs,
             out_specs=rows(block_q, Dv),
             out_shape=jax.ShapeDtypeStruct((B, H, Sq, Dv), q.dtype),
             scratch_shapes=[
@@ -606,30 +693,36 @@ def _flash_fwd_shared_rope(q: jax.Array, q_rope: jax.Array, k: jax.Array,
                 dimension_semantics=("parallel", "parallel", "parallel",
                                      "arbitrary")),
             interpret=_interpret(),
-        )(q, q_rope, k, k_rope, v)
+        )(*operands)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
 def flash_attention_shared_rope(q: jax.Array, q_rope: jax.Array,
                                 k: jax.Array, k_rope: jax.Array,
                                 v: jax.Array, scale: float,
-                                causal: bool = True) -> jax.Array:
+                                causal: bool = True,
+                                window: Optional[int] = None,
+                                keep: Optional[jax.Array] = None
+                                ) -> jax.Array:
     """The forward at two widths (module docstring): q, k ``[B, S, H, D]``,
     q_rope ``[B, S, H, R]``, k_rope ``[B, S, R]``, v ``[B, S, H, Dv]`` →
-    ``[B, S, H, Dv]``; softmax of ``(q k^T + q_rope k_rope^T) * scale``."""
+    ``[B, S, H, Dv]``; softmax of ``(q k^T + q_rope k_rope^T) * scale``
+    over the causal keys, of which ``window`` leaves a query its last
+    ``window`` and ``keep [B, S, S]`` (int8) the ones it marks."""
     o = _flash_fwd_shared_rope(
         jnp.swapaxes(q, 1, 2), jnp.swapaxes(q_rope, 1, 2),
         jnp.swapaxes(k, 1, 2), k_rope, jnp.swapaxes(v, 1, 2),
-        scale=scale, causal=causal)
+        scale=scale, causal=causal, window=window, keep=keep)
     return jnp.swapaxes(o, 1, 2)
 
 
-def _fa_shared_rope_fwd(q, q_rope, k, k_rope, v, scale, causal):
+def _fa_shared_rope_fwd(q, q_rope, k, k_rope, v, scale, causal, window,
+                        keep):
     return flash_attention_shared_rope(q, q_rope, k, k_rope, v, scale,
-                                       causal), None
+                                       causal, window, keep), None
 
 
-def _fa_shared_rope_bwd(scale, causal, res, g):
+def _fa_shared_rope_bwd(scale, causal, window, res, g):
     raise NotImplementedError(
         "flash attention at two widths (a shared rotary key beside the "
         "head's own, values narrower than keys) has a forward only: train "

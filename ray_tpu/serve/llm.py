@@ -90,12 +90,17 @@ class LlamaGenerator:
     # (a layer's kept pairs, covered to the pass) of `expert_rows_all`;
     # `step_device_s` the seconds (`time.perf_counter()`) from the call of
     # the step's program until its results are on the host, the span
-    # `llm.device`
+    # `llm.device`; `index_keys_seen` the (query, key) pairs the indexed
+    # operators' live queries could have attended (the causal ones),
+    # `index_keys_kept` those their indexers' choice kept (counted on the
+    # device), `window_keys_kept` the pairs inside the window operators'
+    # windows, each summed over steps and those layers
     STEP_COUNTERS = ("host_bytes", "positions_computed", "positions_live",
                      "expert_pairs_fullest", "expert_pairs_mean",
                      "expert_pairs_here", "expert_pairs_all",
                      "expert_pairs_skipped", "expert_rows_moved",
-                     "expert_rows_all", "step_device_s")
+                     "expert_rows_all", "step_device_s", "index_keys_kept",
+                     "index_keys_seen", "window_keys_kept")
 
     def __init__(self, config: str = "tiny", lora_rank: int = 4,
                  max_batch_size: int = 4,
@@ -118,6 +123,12 @@ class LlamaGenerator:
         self._pairs_a_position = self._cfg.experts_per_token * sum(
             n for kind, n in self._cfg.kind_counts().items()
             if kind.endswith("_routed"))
+        # the layers whose query attends an indexer's choice of its keys,
+        # and those whose query sees a window of them
+        self._indexed_layers, self._window_layers = (
+            sum(n for kind, n in self._cfg.kind_counts().items()
+                if kind.startswith(operator + "_"))
+            for operator in ("indexed", "window"))
         # adapt only the attention q/v projections: the cheap standard
         # LoRA target set, and enough for adapters to produce distinct
         # generations; the stacks are over the attention layers alone in a
@@ -219,11 +230,13 @@ class LlamaGenerator:
     def _run_step(self, tokens, last, mask, lora=None):
         """The step's one jitted program on numpy ``tokens [B, S]``, ``last
         [B]`` and ``mask [B, S]`` (the rows' own tokens: only a model with
-        experts is told, for its routers' load) -> (ids, hidden, load)."""
+        experts or an indexer is told, for its routers' load and for what
+        its choices kept) -> (ids, hidden, load)."""
         import jax.numpy as jnp
 
-        return self._step_fn(self._params, jnp.asarray(tokens), lora, last,
-                             mask if self._cfg.num_experts else None)
+        return self._step_fn(
+            self._params, jnp.asarray(tokens), lora, last,
+            mask if self._cfg.num_experts or self._indexed_layers else None)
 
     def _step(self, model_id: str, states: List[Optional[Dict]]) -> List:
         """One decode iteration for one adapter group: pad the live rows
@@ -231,7 +244,8 @@ class LlamaGenerator:
         program, which re-runs every row's whole prefix and returns the
         greedy next token of each row; ``bucket`` int32s come to the
         host, and the routers' load with them where the model has
-        experts. Nothing else runs on the device here (an op-by-op ``jnp``
+        experts, and an int32 an indexed operator of what its indexer
+        kept. Nothing else runs on the device here (an op-by-op ``jnp``
         call would compile a program of its own per shape), and ``last``
         goes in as numpy, as ``_fwd`` passes it. Three spans tile it where
         the device takes over (``events.span``): ``llm.prepare`` (the
@@ -279,7 +293,22 @@ class LlamaGenerator:
             counts["expert_pairs_skipped"] += (
                 (bucket * pad_len - live_positions)
                 * self._pairs_a_position)
-            if load is not None:
+            if self._indexed_layers or self._window_layers:
+                # a live query at position t of its row has t + 1 causal
+                # keys, and a window leaves it min(t + 1, window) of them
+                n = mask.sum(axis=1).astype(np.int64)
+                w = self._cfg.sliding_window
+                inside = np.where(n >= w, n * w - w * (w - 1) // 2,
+                                  n * (n + 1) // 2)
+                counts["index_keys_seen"] += int(
+                    (n * (n + 1) // 2).sum()) * self._indexed_layers
+                counts["window_keys_kept"] += (int(inside.sum())
+                                               * self._window_layers)
+            if load is not None and "index_kept" in load:
+                kept = load["index_kept"]
+                counts["host_bytes"] += kept.nbytes
+                counts["index_keys_kept"] += int(kept.sum())
+            if load is not None and "fullest" in load:
                 fullest, mean = load["fullest"], load["mean"]
                 counts["host_bytes"] += fullest.nbytes + mean.nbytes
                 counts["expert_pairs_fullest"] += float(fullest.sum())
@@ -344,7 +373,17 @@ class LlamaGenerator:
         program until its results are on the host: dispatch, transfer in,
         the program, transfer out; beside the engine's ``active_s`` it says
         how long an iteration the host works while the device has nothing
-        to do); and
+        to do); ``index_keys_seen`` and ``index_keys_kept`` (over the live
+        queries of the layers whose operator is ``indexed``: the (query,
+        key) pairs they could have attended, each query's causal keys,
+        reckoned on the host from the rows' lengths, and the pairs their
+        indexers' choice kept, counted on the device from the choice
+        itself and brought to the host with the step's tokens; both summed
+        over steps and indexed layers, 0 for a model without an indexer)
+        and ``window_keys_kept`` (over the live queries of the ``window``
+        layers, the pairs inside the window: ``min(t + 1,
+        sliding_window)`` for the query at position ``t`` of its row,
+        reckoned on the host; summed over steps and window layers); and
         ``layer_kinds``, how many layers of each kind this replica serves
         (``LlamaConfig.kind_counts``: ``attention_dense`` alone for a dense
         decoder)."""

@@ -20,7 +20,12 @@ others are what its parent ``2e5068b`` gives (``serve_chat_steady`` and
 kept its padding off already). PR 42 (where some pairs are not kept the
 kept pairs' rows alone move; `grouped_matmul`'s `tail` is gone) moved the
 six step programs of the three cells with experts; the inits, the dense
-cell's steps and training's are what its parent ``6f115a1`` gives.
+cell's steps and training's are what its parent ``6f115a1`` gives. PR 43
+(dots3-note-prev: ``_latent_attention`` told its widths, a window and a
+choice of keys in ``attention()`` and the two-width forward) moved none of
+the fourteen: they are what its parent ``33f40e9`` gives to the character;
+``serve_dots3_longdoc``'s three are new (traced at 8 rows like the others'
+steps; the cell serves 4).
 """
 
 import hashlib
@@ -42,6 +47,9 @@ PROGRAMS = {
     "serve_dsv2_docqa.init": "91b10ec8ff63401e",
     "serve_dsv2_docqa.step256": "c3465377128f97b8",
     "serve_dsv2_docqa.step1792": "f6519a8cefe24e4f",
+    "serve_dots3_longdoc.init": "af5788ce0837f29f",
+    "serve_dots3_longdoc.step2560": "f4c2f0fc384c3613",
+    "serve_dots3_longdoc.step5120": "ec3ce3ece2252171",
 }
 
 

@@ -1,0 +1,130 @@
+"""Attention over fewer keys than the causal ones, interpreted on the CPU:
+the two-width flash forward under a window and under a choice of keys
+against ``reference_attention``, and the indexer's score kernel against
+its einsums. The lengths are several blocks long and the window and the
+choice are shorter than they are, so blocks are skipped and keys masked."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.ops.attention import (
+    attention, index_scores, reference_attention, reference_index_scores)
+from ray_tpu.ops.pallas import flash_attention as fa
+from ray_tpu.ops.pallas import index_scores as ix
+
+
+def operands(seq, heads=2, d=32, r=16, dv=32, batch=2, seed=0):
+    ks = jax.random.split(jax.random.key(seed), 5)
+    return (jax.random.normal(ks[0], (batch, seq, heads, d)),
+            jax.random.normal(ks[1], (batch, seq, heads, d)),
+            jax.random.normal(ks[2], (batch, seq, heads, dv)),
+            dict(q_rope=jax.random.normal(ks[3], (batch, seq, heads, r)),
+                 k_rope=jax.random.normal(ks[4], (batch, seq, r))))
+
+
+def some_keys(seq, k, batch=2, seed=1):
+    """A choice of at most ``k`` causal keys a query, at random."""
+    scores = jax.random.normal(jax.random.key(seed), (batch, seq, seq))
+    at = jnp.arange(seq)
+    causal = at[:, None] >= at[None, :]
+    kth = jnp.sort(jnp.where(causal, scores, -jnp.inf), axis=-1)[..., -k]
+    return causal & (scores >= kth[..., None])
+
+
+@pytest.mark.parametrize("window", [1, 37, 128, 129, 513, 4000])
+@pytest.mark.parametrize("seq", [128, 384, 1024])
+def test_the_window_against_the_reference(seq, window):
+    q, k, v, rope = operands(seq)
+    got = attention(q, k, v, impl="flash", window=window, **rope)
+    want = reference_attention(q, k, v, window=window, **rope)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    # and the window is what it says: query t sees keys t - window + 1 .. t
+    at = jnp.arange(seq)
+    inside = (at[:, None] >= at[None, :]) & (
+        at[:, None] - at[None, :] < window)
+    same = reference_attention(q, k, v, keep=jnp.broadcast_to(
+        inside, (2, seq, seq)), **rope)
+    np.testing.assert_allclose(want, same, rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("kept", [1, 40, 300])
+@pytest.mark.parametrize("seq", [128, 384, 1024])
+def test_a_choice_of_keys_against_the_reference(seq, kept):
+    q, k, v, rope = operands(seq, seed=2)
+    keep = some_keys(seq, kept)
+    got = attention(q, k, v, impl="flash", keep=keep, **rope)
+    want = reference_attention(q, k, v, keep=keep, **rope)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    # both at once: the choice inside a window
+    both = attention(q, k, v, impl="flash", keep=keep, window=200, **rope)
+    np.testing.assert_allclose(
+        both, reference_attention(q, k, v, keep=keep, window=200, **rope),
+        rtol=2e-5, atol=2e-5)
+
+
+def test_neither_is_the_forward_it_always_was():
+    q, k, v, rope = operands(256, seed=3)
+    plain = attention(q, k, v, impl="flash", **rope)
+    np.testing.assert_array_equal(
+        plain, attention(q, k, v, impl="flash", window=None, keep=None,
+                         **rope))
+    # a window as long as the sequence, and a choice of every key
+    np.testing.assert_allclose(
+        plain, attention(q, k, v, impl="flash", window=256, **rope),
+        rtol=1e-6, atol=1e-6)
+    everything = jnp.ones((2, 256, 256), bool)
+    np.testing.assert_allclose(
+        plain, attention(q, k, v, impl="flash", keep=everything, **rope),
+        rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("seq, block_q, block_k, window, blocks", [
+    (5120, 640, 640, 513, 2), (3584, 512, 512, 513, 2),
+    (4608, 768, 768, 513, 2), (1024, 128, 128, 513, 5),
+    (1024, 512, 128, 130, 6), (1024, 1024, 1024, 513, 1),
+    (2048, 512, 512, 4000, 4)])
+def test_the_windows_walk_is_as_long_as_its_widest_reach(
+        seq, block_q, block_k, window, blocks):
+    assert fa._window_key_blocks(seq, block_q, block_k, window) == blocks
+    for iq in range(seq // block_q):
+        first = int(fa._first_key_block(jnp.int32(iq), block_q, block_k,
+                                        window))
+        assert first == max(iq * block_q - window + 1, 0) // block_k
+        last = (iq * block_q + block_q - 1) // block_k
+        assert last - first + 1 <= blocks
+
+
+def test_what_the_kernels_do_not_take():
+    q, k, v, rope = operands(128)
+    with pytest.raises(NotImplementedError, match="equal-width"):
+        attention(q, k, v, impl="flash", window=8)
+    with pytest.raises(ValueError, match="a prefill's"):
+        attention(q, k, v, impl="flash", causal=False, window=8, **rope)
+    with pytest.raises(ValueError, match="causal keys' last"):
+        reference_attention(q, k, v, causal=False, window=8)
+
+
+@pytest.mark.parametrize("seq, heads", [(128, 3), (384, 8), (1024, 4)])
+def test_the_index_scores_against_the_einsums(seq, heads):
+    ks = jax.random.split(jax.random.key(4), 3)
+    q = jax.random.normal(ks[0], (2, heads, seq, 32))
+    k = jax.random.normal(ks[1], (2, seq, 32))
+    w = jax.random.normal(ks[2], (2, seq, heads))
+    got = index_scores(q, k, w, impl="flash")
+    want = reference_index_scores(q, k, w)
+    assert got.dtype == jnp.float32 and got.shape == (2, seq, seq)
+    at = np.arange(seq)
+    causal = at[:, None] >= at[None, :]
+    np.testing.assert_allclose(np.where(causal, got, 0),
+                               np.where(causal, want, 0),
+                               rtol=2e-5, atol=2e-5)
+    # the blocks wholly above the diagonal are zeros, not products
+    block_q, block_k = ix.index_tiles(seq)
+    above = (at[None, :] // block_k * block_k
+             > at[:, None] // block_q * block_q + block_q - 1)
+    assert not np.asarray(got)[:, above].any()
+    np.testing.assert_array_equal(index_scores(q, k, w, impl="auto"), want)
+    with pytest.raises(ValueError, match="divide by 128"):
+        ix.index_tiles(200)

@@ -269,3 +269,77 @@ def test_the_kept_sums_loop_is_thin_and_holds_no_scatter(
     assert not [ln for ln in a_pass if " scatter(" in ln]
     # one gather a pass: the K rows of each of its positions
     assert sum(" gather(" in ln for ln in text.splitlines()) <= 6
+
+
+# ------------------------------------------------------------------------
+# fewer keys than the causal ones (serve_dots3_longdoc's six buckets): the
+# two-width forward under a window of 513 at the sliding layers' widths
+# (192 + 64 against 128) and under an indexer's choice at the full layers'
+# (128 + 64 against 128), and the indexer's score kernel (64 heads of 128)
+# ------------------------------------------------------------------------
+DOTS3_BUCKETS = [2560, 3072, 3584, 4096, 4608, 5120]
+
+
+@pytest.mark.parametrize("seq", DOTS3_BUCKETS)
+def test_the_window_forward_compiles_and_walks_its_windows_blocks(
+        seq, one_chip, compiled_for_tpu):
+    def of(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    q, q_rope, v = of(1, 2, seq, 192), of(1, 2, seq, 64), of(1, 2, seq, 128)
+    compiled = jax.jit(
+        lambda q, qr, k, kr, v: fa._flash_fwd_shared_rope(
+            q, qr, k, kr, v, scale=0.0625, causal=True, window=513)
+    ).lower(q, q_rope, q, of(1, seq, 64), v).compile()
+    assert fa.WINDOW_TRACE_NAME in compiled.as_text()
+    block_q, block_k = fa.flash_tiles(seq, seq, head_dim=320, value_dim=128)
+    # a window of 513 reaches two key blocks of 512 to 768 a query block,
+    # where the causal walk would visit up to ten
+    assert fa._window_key_blocks(seq, block_q, block_k, 513) == 2
+    reckoned = fa.tile_vmem_bytes(block_q, block_k, head_dim=320,
+                                  value_dim=128)
+    used = [n for n in _scoped_vmem(compiled) if n]
+    assert used and max(used) <= reckoned <= fa.VMEM_LIMIT_BYTES
+
+
+@pytest.mark.parametrize("seq", DOTS3_BUCKETS)
+def test_the_selected_forward_compiles_with_a_byte_a_pair(
+        seq, one_chip, compiled_for_tpu):
+    def of(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q, q_rope = of(1, 2, seq, 128), of(1, 2, seq, 64)
+    compiled = jax.jit(
+        lambda q, qr, k, kr, v, keep: fa._flash_fwd_shared_rope(
+            q, qr, k, kr, v, scale=0.0722, causal=True, keep=keep)
+    ).lower(q, q_rope, q, of(1, seq, 64), q,
+            of(1, seq, seq, dtype=jnp.int8)).compile()
+    assert fa.SELECTED_TRACE_NAME in compiled.as_text()
+    tile = fa.flash_tiles(seq, seq, head_dim=256, value_dim=128)
+    # the tile is the plain two-width forward's (`flash_tiles` is not told
+    # of the choice); its block, a byte a pair in two buffers, comes on top
+    # of that reckoning, and what Mosaic reports stays under the limit:
+    # 12.0 MB at 1024 x 1024, the largest tile
+    reckoned = fa.tile_vmem_bytes(*tile, head_dim=256, value_dim=128)
+    assert reckoned <= fa.VMEM_LIMIT_BYTES
+    used = [n for n in _scoped_vmem(compiled) if n]
+    assert used and max(used) <= min(reckoned + 2 * tile[0] * tile[1],
+                                     fa.VMEM_LIMIT_BYTES)
+
+
+@pytest.mark.parametrize("seq", DOTS3_BUCKETS)
+def test_the_index_scores_kernel_compiles_within_vmem(
+        seq, one_chip, compiled_for_tpu):
+    from ray_tpu.ops.pallas import index_scores as ix
+
+    def of(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(ix.index_scores_causal).lower(
+        of(1, 64, seq, 128), of(1, seq, 128),
+        of(1, seq, 64, dtype=jnp.float32)).compile()
+    assert ix.TRACE_NAME in compiled.as_text()
+    assert ix.index_tiles(seq) == (256, 512)
+    used = [n for n in _scoped_vmem(compiled) if n]
+    # 64 heads' query rows in two buffers are 8 MB of it
+    assert used and 8 * 2 ** 20 < max(used) <= fa.VMEM_LIMIT_BYTES
