@@ -39,6 +39,17 @@ Design notes (why this is not a torch translation):
   the selection reach ``ops.attention`` as arguments; a decode keeps the
   last ``sliding_window`` latent rows of a window layer, and the latent
   rows and the index keys of an indexed one.
+- A sixth operator, ``"mamba"`` (Mamba-2's mixer as granite-4.0-h's
+  ``granitemoehybrid`` computes it; ``_mamba``): one in-projection to a
+  gate, the convolved ``[x | B | C]`` and a step size a head, depthwise
+  causal taps with a bias and a SiLU (``_short_conv``'s taps), the
+  state-space scan (``ops.ssm.ssd_scan``: a chunked Pallas kernel, or the
+  recurrence itself), a gated RMSNorm and an out-projection. Its state in
+  ``llama_decode`` is no rows of a cache: the last ``mamba_conv_kernel -
+  1`` rows of ``[x | B | C]`` and the scan's state ``[B, heads, head_dim,
+  state]`` float32. With it come that family's scalars: no rope
+  (``use_rope``), and ``embedding_multiplier``, ``residual_multiplier``,
+  ``logits_scaling`` and ``attention_multiplier``.
 - Attention dispatches to ``ray_tpu.ops`` (Pallas flash attention on TPU,
   reference einsum path elsewhere; ring attention when the seq axis > 1).
 - bfloat16 activations / fp32 params+optimizer by default: MXU-native.
@@ -247,6 +258,28 @@ class LlamaConfig:
     index_topk: int = 0
     head_gate: bool = False
     latent_rescale: bool = False
+    # The state-space operator and the scalars of its family
+    # (granite-4.0-h has all of it). layer_types' "mamba": Mamba-2's mixer,
+    # mamba_heads heads of mamba_head_dim channels (together the inner
+    # width, mamba_expand x hidden in the source's terms) over a state of
+    # mamba_state dims, B and C one row a position for all heads (one
+    # group), mamba_conv_kernel depthwise causal taps with a bias over [x |
+    # B | C], scanned in chunks of mamba_chunk by the kernel. use_rope
+    # False: attention's queries and keys are not rotated ("nope").
+    # embedding_multiplier scales the embedded tokens, residual_multiplier
+    # every sub-layer's output before it joins the residual, logits are
+    # divided by logits_scaling, and attention_multiplier, where not 0, is
+    # the softmax scale in place of head_dim ** -0.5.
+    mamba_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_state: int = 0
+    mamba_conv_kernel: int = 4
+    mamba_chunk: int = 256
+    use_rope: bool = True
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    attention_multiplier: float = 0.0
 
     @staticmethod
     def llama2_7b_smoke() -> "LlamaConfig":
@@ -273,8 +306,8 @@ class LlamaConfig:
 
     def layer_kinds(self) -> Tuple[str, ...]:
         """Each layer's kind, ``<operator>_<feed-forward>``: ``attention``,
-        ``conv``, ``latent``, ``window`` or ``indexed``, then ``routed``
-        (experts) or ``dense``."""
+        ``conv``, ``latent``, ``window``, ``indexed`` or ``mamba``, then
+        ``routed`` (experts) or ``dense``."""
         ops = tuple(self.layer_types) or ("full_attention",) * self.num_layers
         if len(ops) != self.num_layers:
             raise ValueError(f"layer_types names {len(ops)} layers, "
@@ -282,7 +315,7 @@ class LlamaConfig:
         names = {"full_attention": "attention", "conv": "conv",
                  "latent_attention": "latent",
                  "window_latent_attention": "window",
-                 "indexed_latent_attention": "indexed"}
+                 "indexed_latent_attention": "indexed", "mamba": "mamba"}
         unknown = sorted(set(ops) - set(names))
         if unknown:
             raise ValueError(f"layer_types {unknown}: expected "
@@ -333,6 +366,14 @@ class LlamaConfig:
             self.rope_theta, 0,
             self.index_topk if operator == "indexed" else 0)
 
+    def mamba_widths(self) -> Tuple[int, int, int]:
+        """The state-space operator's ``(inner, conv, proj)`` widths: its
+        heads' channels, what the taps run over (``[x | B | C]``) and what
+        the in-projection makes (``[z | x B C | dt]``)."""
+        inner = self.mamba_heads * self.mamba_head_dim
+        conv = inner + 2 * self.mamba_state
+        return inner, conv, inner + conv + self.mamba_heads
+
     def dense_width(self) -> int:
         """Width of a dense SwiGLU: the leading dense layers' own in a
         model with experts, whose ``mlp_hidden`` is one expert's."""
@@ -357,7 +398,12 @@ class LlamaConfig:
                     + w.heads * w.v * h + gate)
 
         ih, ihd = self.index_heads, self.index_head_dim
+        inner, conv, proj = self.mamba_widths()
         half = {"attention": h * (q + 2 * kv) + q * h + norms,
+                # in-projection, taps and their bias, dt_bias, A_log and D
+                # a head, the gated norm, out-projection
+                "mamba": (h * proj + conv * (self.mamba_conv_kernel + 1)
+                          + 3 * self.mamba_heads + inner + inner * h),
                 "conv": 4 * h * h + h * self.conv_kernel,
                 "latent": latent("latent"), "window": latent("window"),
                 # the indexer: its queries, its one key with a LayerNorm's
@@ -548,6 +594,11 @@ def _kind_logical_axes(cfg: LlamaConfig, kind: str) -> Dict[str, Any]:
             layer.update(wi_q=(None, None, None), wi_k=("embed", None),
                          wi_k_norm=("norm",), wi_k_bias=("norm",),
                          wi_w=("embed", None))
+    elif operator == "mamba":  # its inner widths stay whole everywhere
+        layer.update(mamba_in=("embed", None), mamba_conv_w=(None, None),
+                     mamba_conv_b=(None,), mamba_dt_bias=(None,),
+                     mamba_a_log=(None,), mamba_d=(None,),
+                     mamba_norm=("norm",), mamba_out=(None, "embed"))
     else:  # the gated short convolution: in-projection, taps, out
         layer.update(conv_in=("embed", "mlp"), conv_w=("mlp", None),
                      conv_out=("mlp", "embed"))
@@ -633,6 +684,33 @@ def init_llama(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
                     wi_k_norm=jnp.ones((L, ihd), pd),
                     wi_k_bias=jnp.zeros((L, ihd), pd),
                     wi_w=norm_init((L, h, ih), kiw, h))
+        elif operator == "mamba":
+            # the mixer's own leaves as mamba_ssm and transformers start
+            # them, which is what sets how far a state remembers: A in 1 to
+            # 16, the step size log-uniform in 0.001 to 0.1 (its bias the
+            # inverse softplus), D ones, the taps and their bias uniform at
+            # fan-in (torch's Conv1d)
+            nm, taps = cfg.mamba_heads, cfg.mamba_conv_kernel
+            inner, conv, proj = cfg.mamba_widths()
+            ka, kdt, kw, kb = jax.random.split(ks[1], 4)
+            dt = jnp.maximum(jnp.exp(jax.random.uniform(
+                kdt, (L, nm), jnp.float32, math.log(0.001), math.log(0.1))),
+                1e-4)
+            layers = {
+                "mamba_in": norm_init((L, h, proj), ks[0], h),
+                "mamba_conv_w": jax.random.uniform(
+                    kw, (L, conv, taps), jnp.float32, -taps ** -0.5,
+                    taps ** -0.5).astype(pd),
+                "mamba_conv_b": jax.random.uniform(
+                    kb, (L, conv), jnp.float32, -taps ** -0.5,
+                    taps ** -0.5).astype(pd),
+                "mamba_dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(pd),
+                "mamba_a_log": jnp.log(jax.random.uniform(
+                    ka, (L, nm), jnp.float32, 1.0, 16.0)).astype(pd),
+                "mamba_d": jnp.ones((L, nm), pd),
+                "mamba_norm": jnp.ones((L, inner), pd),
+                "mamba_out": norm_init((L, inner, h), ks[3], inner),
+            }
         else:
             layers = {
                 "conv_in": norm_init((L, h, 3 * h), ks[0], h),
@@ -671,6 +749,23 @@ def init_llama(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
     if not cfg.tie_embeddings:
         out["lm_head"] = norm_init((h, cfg.vocab_size), ks[8], h)
     return out
+
+
+def _embed(params: Dict[str, Any], tokens: jax.Array,
+           cfg: LlamaConfig) -> jax.Array:
+    """The tokens' rows of the embedding in the activation type, times
+    ``embedding_multiplier``."""
+    x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, cfg.dtype)
+    return x
+
+
+def _scaled_logits(logits: jax.Array, scaling: float) -> jax.Array:
+    """The head's products over ``LlamaConfig.logits_scaling``."""
+    if scaling == 1.0:
+        return logits
+    return logits / jnp.asarray(scaling, logits.dtype)
 
 
 def _lm_head(params: Dict[str, Any]) -> jax.Array:
@@ -936,6 +1031,25 @@ def _latent_attention(cfg: LlamaConfig, u: jax.Array,
     return y, state
 
 
+def _causal_taps(z: jax.Array, w: jax.Array,
+                 state: Optional[jax.Array]) -> Tuple[jax.Array, jax.Array]:
+    """Depthwise causal taps over ``z [B, S, C]``: ``c_t = sum_j w[:, j]
+    z_{t-(K-1)+j}`` with ``w [C, K]``, summed in float32 -> (``c [B, S,
+    C]`` float32, ``z`` with the ``K - 1`` rows before it in front, whose
+    last ``K - 1`` rows are the state after this call). ``state [B, K-1,
+    C]`` is those rows before this call; None stands for zeros, a
+    sequence's start."""
+    dt, taps = z.dtype, w.shape[1]
+    if state is None:
+        state = jnp.zeros((z.shape[0], taps - 1, z.shape[2]), dt)
+    padded = jnp.concatenate([state.astype(dt), z], axis=1)
+    S = z.shape[1]
+    w = w.astype(jnp.float32)                              # [C, taps]
+    c = sum(w[:, j] * padded[:, j:j + S].astype(jnp.float32)
+            for j in range(taps))
+    return c, padded
+
+
 def _short_conv(cfg: LlamaConfig, u: jax.Array, lp: Dict[str, jax.Array],
                 state: Optional[jax.Array] = None):
     """The gated short convolution (LFM2's ``Lfm2ShortConv``) on the
@@ -946,21 +1060,63 @@ def _short_conv(cfg: LlamaConfig, u: jax.Array, lp: Dict[str, jax.Array],
     ``W_out (C * c)``. No bias. ``state [B, K-1, H]`` is the last ``K-1``
     rows of ``z`` before this call (zeros at a sequence's start, which is
     what ``None`` stands for): all an incremental decode keeps of a row."""
-    dt, taps = cfg.dtype, cfg.conv_kernel
+    dt = cfg.dtype
     with jax.named_scope("short_conv"):
         bcx = jnp.einsum("bsh,hc->bsc", u, lp["conv_in"].astype(dt))
         gate_b, gate_c, x = jnp.split(bcx, 3, axis=-1)
         z = gate_b * x
-        if state is None:
-            state = jnp.zeros((z.shape[0], taps - 1, z.shape[2]), dt)
-        padded = jnp.concatenate([state.astype(dt), z], axis=1)
-        S = z.shape[1]
-        w = lp["conv_w"].astype(jnp.float32)               # [H, taps]
-        c = sum(w[:, j] * padded[:, j:j + S].astype(jnp.float32)
-                for j in range(taps))
+        c, padded = _causal_taps(z, lp["conv_w"], state)
         y = jnp.einsum("bsh,hd->bsd", gate_c * c.astype(dt),
                        lp["conv_out"].astype(dt))
-    return y, padded[:, S:]
+    return y, padded[:, z.shape[1]:]
+
+
+def _mamba(cfg: LlamaConfig, u: jax.Array, lp: Dict[str, jax.Array],
+           state: Optional[Tuple[jax.Array, jax.Array]] = None):
+    """Mamba-2's mixer (granite-4.0-h's, as ``transformers``'
+    ``granitemoehybrid`` computes it) on the normed input ``u [B, S, H]``
+    -> (its output ``[B, S, H]``, the state after it). ``[z | xBC | dt] = u
+    W_in``; ``xBC = silu(bias + taps(xBC))``, depthwise and causal
+    (``_causal_taps``); ``[x | B | C] = xBC``, ``x`` as ``mamba_heads``
+    heads of ``mamba_head_dim``, ``B`` and ``C`` one row of ``mamba_state``
+    a position for every head; ``dt = softplus(dt + dt_bias)`` and ``A =
+    -exp(A_log)`` a head, float32, no clamp; ``y`` the state-space scan's
+    (``ops.ssm.ssd_scan``: ``h_t = exp(dt_t A) h_{t-1} + dt_t x_t B_t^T``,
+    ``y_t = h_t C_t + D x_t``); ``RMSNorm(y * silu(z)) * g`` over the whole
+    inner width, the gate BEFORE the norm; ``W_out``. No bias but the
+    taps'. ``state`` is all a decode keeps of a row: the last
+    ``mamba_conv_kernel - 1`` rows of ``xBC`` before its taps and the
+    scan's state ``[B, heads, head_dim, mamba_state]`` float32 (None:
+    zeros, a sequence's start). A forward pass and a decode's pass over a
+    prompt scan by ``attn_impl`` (the kernel on the chip), a single token
+    by the recurrence itself."""
+    from ray_tpu.ops.ssm import ssd_scan
+
+    dt_, f32 = cfg.dtype, jnp.float32
+    B, S = u.shape[:2]
+    inner, conv, _ = cfg.mamba_widths()
+    n = cfg.mamba_state
+    rows, h0 = (None, None) if state is None else state
+    with jax.named_scope("mamba_in_proj"):
+        zxbcdt = jnp.einsum("bsh,hc->bsc", u, lp["mamba_in"].astype(dt_))
+    z, xbc, dt = (zxbcdt[..., :inner], zxbcdt[..., inner:inner + conv],
+                  zxbcdt[..., inner + conv:])
+    with jax.named_scope("mamba_conv"):
+        c, padded = _causal_taps(xbc, lp["mamba_conv_w"], rows)
+        xbc = jax.nn.silu(c + lp["mamba_conv_b"].astype(f32)).astype(dt_)
+    x = xbc[..., :inner].reshape(B, S, cfg.mamba_heads, cfg.mamba_head_dim)
+    dt = jax.nn.softplus(dt.astype(f32) + lp["mamba_dt_bias"].astype(f32))
+    impl = cfg.attn_impl if cfg.attn_impl in ("flash", "auto") else "reference"
+    y, h = ssd_scan(x, dt, -jnp.exp(lp["mamba_a_log"].astype(f32)),
+                    xbc[..., inner:inner + n], xbc[..., inner + n:],
+                    lp["mamba_d"].astype(f32), chunk=cfg.mamba_chunk, h0=h0,
+                    impl="reference" if state is not None and S == 1
+                    else impl)
+    with jax.named_scope("mamba_gated_norm"):
+        y = y.reshape(B, S, inner).astype(f32) * jax.nn.silu(z.astype(f32))
+        y = _rms_norm(y, lp["mamba_norm"], cfg.rms_eps).astype(dt_)
+    out = jnp.einsum("bsc,ch->bsh", y, lp["mamba_out"].astype(dt_))
+    return out, (padded[:, S:], h)
 
 
 def _layer(cfg: LlamaConfig, x: jax.Array, lp: Dict[str, jax.Array],
@@ -974,16 +1130,25 @@ def _layer(cfg: LlamaConfig, x: jax.Array, lp: Dict[str, jax.Array],
     under routed experts, and ``index_kept`` of an indexed operator,
     ``_latent_attention``'s count over the queries ``live`` marks). The
     layer's kind is read off its leaves: ``conv_in`` makes the operator the
-    gated short convolution and ``wkv_a`` latent attention, and not
-    attention; ``router`` makes the feed-forward the routed experts and
+    gated short convolution, ``mamba_in`` the state-space mixer and
+    ``wkv_a`` latent attention, and not attention; ``router`` makes the
+    feed-forward the routed experts and
     not the dense SwiGLU. Which latent operator it is (``latent``,
     ``window``, ``indexed``) the leaves do not say: ``operator`` does.
     ``kv_cache`` is the layer's own state in an incremental decode: (keys,
     values) for attention, the last rows of ``z`` for the short
     convolution (``_short_conv``), the latent rows for latent attention
-    (``_latent_attention``)."""
+    (``_latent_attention``), the taps' rows and the scan's state for the
+    state-space mixer (``_mamba``). Every sub-layer's output joins the
+    residual times ``residual_multiplier``."""
     dt = cfg.dtype
     counts: Dict[str, jax.Array] = {}
+
+    def _res(y):
+        """A sub-layer's output as it joins the residual."""
+        if cfg.residual_multiplier == 1.0:
+            return y
+        return y * jnp.asarray(cfg.residual_multiplier, dt)
 
     def _ld(name, t_in, eq_a, eq_b):
         """Activation-side LoRA delta: (t_in @ A) @ B * scale, or 0."""
@@ -997,12 +1162,16 @@ def _layer(cfg: LlamaConfig, x: jax.Array, lp: Dict[str, jax.Array],
     if "conv_in" in lp:
         y, state = _short_conv(cfg, h, lp, kv_cache)
         new_cache = None if kv_cache is None else state
-        x = x + y
+        x = x + _res(y)
+    elif "mamba_in" in lp:
+        y, state = _mamba(cfg, h, lp, kv_cache)
+        new_cache = None if kv_cache is None else state
+        x = x + _res(y)
     elif "wkv_a" in lp:
         y, new_cache = _latent_attention(
             cfg, h, lp, positions, kv_cache, cache_index,
             operator=operator or "latent", live=live, counts=counts)
-        x = x + y
+        x = x + _res(y)
     else:
         # --- attention ---
         q = (jnp.einsum("bsh,hnd->bsnd", h, lp["wq"].astype(dt))
@@ -1019,8 +1188,17 @@ def _layer(cfg: LlamaConfig, x: jax.Array, lp: Dict[str, jax.Array],
         elif cfg.qk_head_norm:  # over each head's head_dim, one weight
             q = _rms_norm(q, lp["q_norm"], cfg.rms_eps)
             k = _rms_norm(k, lp["k_norm"], cfg.rms_eps)
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
+        if cfg.use_rope:
+            q = _rope(q, positions, cfg.rope_theta)
+            k = _rope(k, positions, cfg.rope_theta)
+        if cfg.attention_multiplier:
+            # the softmax scale's ratio to the kernels' head_dim ** -0.5,
+            # taken by the queries: where it is a power of two (granite's
+            # 2 ** -3) the product is exact in any float type, the flash
+            # forward, its tiles and the cached decode are untouched, and
+            # no kernel grows a `scale` that all but one caller leaves alone
+            q = q * jnp.asarray(cfg.attention_multiplier
+                                * cfg.head_dim ** 0.5, dt)
         q = constrain(q, ("batch", "seq", "heads", None))
         k = constrain(k, ("batch", "seq", "kv_heads", None))
         new_cache = None
@@ -1041,21 +1219,22 @@ def _layer(cfg: LlamaConfig, x: jax.Array, lp: Dict[str, jax.Array],
                 attn_out = attention(q, k, v, impl=cfg.attn_impl,
                                      causal=True)
         attn_out = constrain(attn_out, ("batch", "seq", "heads", None))
-        x = (x + jnp.einsum("bsnd,ndh->bsh", attn_out, lp["wo"].astype(dt))
-             + _ld("wo", attn_out, "bsnd,ndr->bsr", "bsr,rh->bsh"))
+        x = (x + _res(jnp.einsum("bsnd,ndh->bsh", attn_out,
+                                 lp["wo"].astype(dt)))
+             + _res(_ld("wo", attn_out, "bsnd,ndr->bsr", "bsr,rh->bsh")))
     # --- feed-forward: routed experts, or the dense SwiGLU ---
     h = _rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
     if "router" in lp:
         y, books = moe.expert_ffn(cfg, h, lp)
-        return (constrain(x + y, ("batch", "seq", "embed")), new_cache,
+        return (constrain(x + _res(y), ("batch", "seq", "embed")), new_cache,
                 {**books, **counts})
     gate = (jnp.einsum("bsh,hm->bsm", h, lp["w_gate"].astype(dt))
             + _ld("w_gate", h, "bsh,hr->bsr", "bsr,rm->bsm"))
     up = (jnp.einsum("bsh,hm->bsm", h, lp["w_up"].astype(dt))
           + _ld("w_up", h, "bsh,hr->bsr", "bsr,rm->bsm"))
     act = constrain(jax.nn.silu(gate) * up, ("batch", "seq", "mlp"))
-    x = (x + jnp.einsum("bsm,mh->bsh", act, lp["w_down"].astype(dt))
-         + _ld("w_down", act, "bsm,mr->bsr", "bsr,rh->bsh"))
+    x = (x + _res(jnp.einsum("bsm,mh->bsh", act, lp["w_down"].astype(dt)))
+         + _res(_ld("w_down", act, "bsm,mr->bsr", "bsr,rh->bsh")))
     x = constrain(x, ("batch", "seq", "embed"))
     return x, new_cache, counts or None
 
@@ -1069,7 +1248,11 @@ def init_decode_state(cfg: LlamaConfig, batch: int, max_len: int) -> list:
     ``[batch, max_len, kv_lora_rank + qk_rope_head_dim]`` for latent
     attention (an indexed operator's index key ``[index_head_dim]`` after
     them; a window operator keeps its last ``sliding_window`` rows and not
-    ``max_len``), all zeros."""
+    ``max_len``), and for a state-space mixer a pair that is no rows of a
+    cache: the last ``mamba_conv_kernel - 1`` rows of ``[x | B | C]``
+    ``[batch, mamba_conv_kernel - 1, inner + 2 mamba_state]`` and the
+    scan's state ``[batch, mamba_heads, mamba_head_dim, mamba_state]``
+    float32; all zeros."""
     def latent_rows(op):
         w = cfg.latent_widths(op)
         return (batch, w.window or max_len, w.kv_rank + w.rope
@@ -1080,7 +1263,14 @@ def init_decode_state(cfg: LlamaConfig, batch: int, max_len: int) -> list:
         "attention": (batch, max_len, cfg.num_kv_heads, cfg.head_dim),
         "conv": (batch, cfg.conv_kernel - 1, cfg.hidden),
         **{op: latent_rows(op) for op in LATENT_OPERATORS}}
-    zeros = {op: jnp.zeros(shapes[op], cfg.dtype) for op in set(operators)}
+    zeros = {op: jnp.zeros(shapes[op], cfg.dtype)
+             for op in set(operators) - {"mamba"}}
+    if "mamba" in operators:
+        zeros["mamba"] = (
+            jnp.zeros((batch, cfg.mamba_conv_kernel - 1,
+                       cfg.mamba_widths()[1]), cfg.dtype),
+            jnp.zeros((batch, cfg.mamba_heads, cfg.mamba_head_dim,
+                       cfg.mamba_state), jnp.float32))
     return [(zeros[op], zeros[op]) if op == "attention" else zeros[op]
             for op in operators]
 
@@ -1102,7 +1292,7 @@ def llama_decode(
     if positions is None:
         positions = jnp.broadcast_to(
             jnp.arange(S, dtype=jnp.int32) + cache_index, (B, S))
-    x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
+    x = _embed(params, tokens, cfg)
     stacks = _by_kind(params["layers"], cfg)
     new_caches = []
     for i, (kind, j) in enumerate(cfg.layer_places()):
@@ -1114,7 +1304,8 @@ def llama_decode(
         new_caches.append(c)
     x = _rms_norm(x, params["final_norm"], cfg.rms_eps)
     logits = jnp.einsum("bsh,hv->bsv", x, _lm_head(params).astype(cfg.dtype))
-    return logits.astype(jnp.float32), new_caches
+    return (_scaled_logits(logits.astype(jnp.float32), cfg.logits_scaling),
+            new_caches)
 
 
 def llama_hidden(
@@ -1157,7 +1348,7 @@ def _hidden_and_books(
     B, S = tokens.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-    x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
+    x = _embed(params, tokens, cfg)
     x = constrain(x, ("batch", "seq", "embed"))
 
     scale = lora_cfg.scale if lora_cfg is not None else 0.0
@@ -1248,7 +1439,7 @@ def llama_forward(
     x = llama_hidden(params, tokens, cfg, positions=positions,
                      lora=lora, lora_cfg=lora_cfg)
     logits = jnp.einsum("bsh,hv->bsv", x, _lm_head(params).astype(cfg.dtype))
-    return logits.astype(jnp.float32)
+    return _scaled_logits(logits.astype(jnp.float32), cfg.logits_scaling)
 
 
 def llama_head(params: Dict[str, Any], x: jax.Array,
@@ -1256,8 +1447,9 @@ def llama_head(params: Dict[str, Any], x: jax.Array,
     """Final hidden states [..., H] → logits [..., V], accumulated and
     kept in fp32 (``llama_forward`` rounds the product to the activation
     dtype first; the operands are the same)."""
-    return jnp.einsum("...h,hv->...v", x, _lm_head(params).astype(cfg.dtype),
-                      preferred_element_type=jnp.float32)
+    return _scaled_logits(
+        jnp.einsum("...h,hv->...v", x, _lm_head(params).astype(cfg.dtype),
+                   preferred_element_type=jnp.float32), cfg.logits_scaling)
 
 
 def llama_next_token(
@@ -1312,7 +1504,7 @@ def _nll_from_logits(logits: jax.Array, targets: jax.Array) -> jax.Array:
     return lse - target_logit
 
 
-def _chunked_ce(x, lm_head, targets, mask, chunk, dtype):
+def _chunked_ce(x, lm_head, targets, mask, chunk, dtype, logits_scaling=1.0):
     """Cross-entropy over seq chunks: logits for one chunk at a time, each
     chunk's logits recomputed in the backward (jax.checkpoint) so peak
     memory is B*chunk*V instead of B*S*V — the difference between a 7B
@@ -1329,6 +1521,7 @@ def _chunked_ce(x, lm_head, targets, mask, chunk, dtype):
     def body(carry, inp):
         xi, ti, mi = inp
         logits = jnp.einsum("bch,hv->bcv", xi, lm_head.astype(dtype))
+        logits = _scaled_logits(logits, logits_scaling)
         nll = _nll_from_logits(logits, ti)
         tot, cnt = carry
         return (tot + jnp.sum(nll * mi), cnt + jnp.sum(mi)), None
@@ -1354,10 +1547,11 @@ def llama_loss(params: Dict[str, Any], batch: Dict[str, jax.Array],
                                  lora_cfg=lora_cfg)
     if cfg.loss_chunk:
         ce = _chunked_ce(x, _lm_head(params), targets, mask,
-                         cfg.loss_chunk, cfg.dtype)
+                         cfg.loss_chunk, cfg.dtype, cfg.logits_scaling)
     else:
-        logits = jnp.einsum("bsh,hv->bsv", x,
-                            _lm_head(params).astype(cfg.dtype))
+        logits = _scaled_logits(
+            jnp.einsum("bsh,hv->bsv", x, _lm_head(params).astype(cfg.dtype)),
+            cfg.logits_scaling)
         nll = _nll_from_logits(logits, targets)
         ce = (jnp.mean(nll) if mask is None else
               jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0))

@@ -94,13 +94,17 @@ class LlamaGenerator:
     # operators' live queries could have attended (the causal ones),
     # `index_keys_kept` those their indexers' choice kept (counted on the
     # device), `window_keys_kept` the pairs inside the window operators'
-    # windows, each summed over steps and those layers
+    # windows, each summed over steps and those layers; `ssm_chunks_run` the
+    # chunks the state-space layers' scans ran (layers x rows x chunks of
+    # the padded length) and `ssm_chunks_live` those among them that hold
+    # one of a row's own positions
     STEP_COUNTERS = ("host_bytes", "positions_computed", "positions_live",
                      "expert_pairs_fullest", "expert_pairs_mean",
                      "expert_pairs_here", "expert_pairs_all",
                      "expert_pairs_skipped", "expert_rows_moved",
                      "expert_rows_all", "step_device_s", "index_keys_kept",
-                     "index_keys_seen", "window_keys_kept")
+                     "index_keys_seen", "window_keys_kept", "ssm_chunks_run",
+                     "ssm_chunks_live")
 
     def __init__(self, config: str = "tiny", lora_rank: int = 4,
                  max_batch_size: int = 4,
@@ -124,11 +128,12 @@ class LlamaGenerator:
             n for kind, n in self._cfg.kind_counts().items()
             if kind.endswith("_routed"))
         # the layers whose query attends an indexer's choice of its keys,
-        # and those whose query sees a window of them
-        self._indexed_layers, self._window_layers = (
+        # those whose query sees a window of them, and those whose
+        # operator scans a state over the sequence in chunks
+        self._indexed_layers, self._window_layers, self._ssm_layers = (
             sum(n for kind, n in self._cfg.kind_counts().items()
                 if kind.startswith(operator + "_"))
-            for operator in ("indexed", "window"))
+            for operator in ("indexed", "window", "mamba"))
         # adapt only the attention q/v projections: the cheap standard
         # LoRA target set, and enough for adapters to produce distinct
         # generations; the stacks are over the attention layers alone in a
@@ -304,6 +309,15 @@ class LlamaGenerator:
                     (n * (n + 1) // 2).sum()) * self._indexed_layers
                 counts["window_keys_kept"] += (int(inside.sum())
                                                * self._window_layers)
+            if self._ssm_layers:
+                # the scans run every row of the batch over the whole
+                # padded length; a chunk is live while its first position
+                # is one of its row's own
+                chunk = self._cfg.mamba_chunk
+                counts["ssm_chunks_run"] += (
+                    self._ssm_layers * bucket * -(-pad_len // chunk))
+                counts["ssm_chunks_live"] += self._ssm_layers * int(
+                    (-(-mask.sum(axis=1) // chunk)).sum())
             if load is not None and "index_kept" in load:
                 kept = load["index_kept"]
                 counts["host_bytes"] += kept.nbytes
@@ -383,7 +397,16 @@ class LlamaGenerator:
         and ``window_keys_kept`` (over the live queries of the ``window``
         layers, the pairs inside the window: ``min(t + 1,
         sliding_window)`` for the query at position ``t`` of its row,
-        reckoned on the host; summed over steps and window layers); and
+        reckoned on the host; summed over steps and window layers);
+        ``ssm_chunks_run`` and ``ssm_chunks_live`` (over the layers whose
+        operator is ``mamba``: the chunks of ``mamba_chunk`` positions
+        their scans ran, rows of the batch x chunks of the padded length,
+        and those among them that hold at least one of a row's own
+        positions, a row of ``n`` tokens having ``ceil(n / mamba_chunk)``;
+        both reckoned on the host from the rows' lengths and summed over
+        steps and those layers, 0 for a model without them: what is left
+        between them is chunks of padding, which a scan that stopped at a
+        row's end would not run); and
         ``layer_kinds``, how many layers of each kind this replica serves
         (``LlamaConfig.kind_counts``: ``attention_dense`` alone for a dense
         decoder)."""

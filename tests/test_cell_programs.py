@@ -25,7 +25,11 @@ cell's steps and training's are what its parent ``6f115a1`` gives. PR 43
 choice of keys in ``attention()`` and the two-width forward) moved none of
 the fourteen: they are what its parent ``33f40e9`` gives to the character;
 ``serve_dots3_longdoc``'s three are new (traced at 8 rows like the others'
-steps; the cell serves 4).
+steps; the cell serves 4). PR 46 (granite-4.0-h-micro: a state-space
+operator, ``_short_conv``'s taps shared with it through ``_causal_taps``,
+the family's four scalars and ``use_rope`` behind defaults) moved none of
+the seventeen: they are what its parent ``74f24a8`` gives to the
+character; ``serve_granite_toolcalls``'s three are new.
 """
 
 import hashlib
@@ -50,6 +54,9 @@ PROGRAMS = {
     "serve_dots3_longdoc.init": "af5788ce0837f29f",
     "serve_dots3_longdoc.step2560": "f4c2f0fc384c3613",
     "serve_dots3_longdoc.step5120": "ec3ce3ece2252171",
+    "serve_granite_toolcalls.init": "ce77369b6d8feac3",
+    "serve_granite_toolcalls.step256": "055167584e2c5775",
+    "serve_granite_toolcalls.step1024": "ac5a47f5f04e9fbe",
 }
 
 

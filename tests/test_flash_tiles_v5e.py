@@ -343,3 +343,45 @@ def test_the_index_scores_kernel_compiles_within_vmem(
     used = [n for n in _scoped_vmem(compiled) if n]
     # 64 heads' query rows in two buffers are 8 MB of it
     assert used and 8 * 2 ** 20 < max(used) <= fa.VMEM_LIMIT_BYTES
+
+
+# the state-space scan (serve_granite_toolcalls' four buckets) at
+# granite-4.0-h-micro's widths: 64 heads of 64 over a state of 128, chunks
+# of the published 256, 8 heads a grid step. Mosaic takes the blocks (the
+# heads' 64 lanes sliced out of 512, a [1, 1] decay broadcast in two
+# steps), and nothing of a chunk's [256, 256] decays is in the program
+# round the kernel
+GRANITE_BUCKETS = [256, 512, 768, 1024]
+
+
+@pytest.mark.parametrize("seq", GRANITE_BUCKETS)
+def test_the_state_space_scan_compiles_within_vmem(seq, one_chip,
+                                                   compiled_for_tpu):
+    from ray_tpu.ops.pallas import ssd_scan as ss
+
+    def of(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    f32 = jnp.float32
+    compiled = jax.jit(
+        lambda x, dt, a, b, c, d, h0: ss.ssd_scan_chunked(
+            x, dt, a, b, c, d, h0, 256)
+    ).lower(of(8, seq, 64, 64), of(8, seq, 64, dtype=f32),
+            of(64, dtype=f32), of(8, seq, 128), of(8, seq, 128),
+            of(64, dtype=f32), of(8, 64, 64, 128, dtype=f32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and ss.SSD_SCAN_TRACE_NAME in text
+    assert ss.ssd_heads_a_step(64, 64) == 8
+    used = [n for n in _scoped_vmem(compiled) if n]
+    # x and y blocks of [256, 512] bf16 in two buffers, 8 states of
+    # [128, 64] float32 in, out and kept: some 6 MB
+    assert used and 2 * 2 ** 20 < max(used) <= fa.VMEM_LIMIT_BYTES
+    # no tensor of a head's (or a chunk's) [256, 256] decays reaches HBM:
+    # what the kernel is handed is [8, S, ...] rows and the states
+    assert not re.search(r"\[8,\d+,\d+,256,256\]|\[8,\d+,256,256\]", text)
+    # what the program holds beside its operands and results is the
+    # running sums, their transposes and (here, where x comes as [8, S,
+    # 64, 64]) a copy of x in the kernel's layout: 0.6 to 102 MB, where the
+    # decays of 64 heads would be 134 to 537 MB in float32
+    decays = 8 * 64 * seq * 256 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < decays // 3
