@@ -63,7 +63,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from functools import partial
+from functools import cache, partial
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -176,8 +176,9 @@ class LlamaConfig:
     remat_policy: str = "dots"     # dots (save matmuls) | full (recompute all)
     attn_impl: str = "auto"        # auto | flash | reference | ring_seq
     loss_chunk: int = 0            # >0: lm-head CE in seq chunks of this size
-    #   (peak logits memory B*chunk*V instead of B*S*V; the backward
-    #    recomputes each chunk's logits under jax.checkpoint)
+    #   (peak float32 logits memory B*chunk*V instead of B*S*V; the backward
+    #    recomputes each chunk's softmax under jax.checkpoint, and under
+    #    remat_policy "full" the chunk's matmul too)
     # What the model is, beyond the dense decoder (OLMoE-1B-7B has all of
     # it): with num_experts > 0 the block's feed-forward is models/moe.py's
     # routed experts, each of width mlp_hidden, experts_per_token a
@@ -1386,15 +1387,17 @@ def _hidden_and_books(
                          index)
 
     # Each run of like layers is one scan, in the model's order, under the
-    # layer's remat policy. "dots": keep matmul outputs, recompute
-    # elementwise — near-zero extra MXU work for most of full remat's
-    # memory win. "full": recompute everything (longest-context fallback).
-    # "mixed:K": the model's first K layers keep their matmul outputs, the
-    # rest recompute — spends whatever HBM headroom full remat leaves on
-    # skipping recompute FLOPs (each dots layer trades ~160 MB at
-    # 7B/B=1/S=2k for one layer-forward less recompute per step); a run
-    # that K falls inside is scanned in two parts.
-    dots = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+    # layer's remat policy. "dots": keep matmul outputs (_dots_policy: the
+    # flash forward's two among them), recompute elementwise — near-zero
+    # extra MXU work for most of full remat's memory win. "full":
+    # recompute everything (longest-context fallback). "mixed:K": the
+    # model's first K layers keep their matmul outputs, the rest recompute
+    # — spends whatever HBM headroom full remat leaves on skipping
+    # recompute FLOPs (each dots layer trades ~210 MB at 7B/B=1/S=2k, 50 of
+    # them the flash forward's output and logsumexp, for one layer-forward
+    # less recompute per step); a run that K falls inside is scanned in
+    # two parts.
+    dots = _dots_policy()
     full = jax.checkpoint_policies.nothing_saveable
     keep = cfg.num_layers if cfg.remat_policy == "dots" else 0
     if cfg.remat and cfg.remat_policy.startswith("mixed:"):
@@ -1488,6 +1491,24 @@ def llama_next_token(
     return ids.astype(jnp.int32), x, load
 
 
+@cache
+def _dots_policy():
+    """What ``remat_policy="dots"`` keeps for the backward: every matmul's
+    output. ``dot_general``s by the policy of that name, and the flash
+    forward's output and logsumexp, which come out of a ``pallas_call``,
+    by the names ``_fa_fwd`` gives them: dropped, the backward runs that
+    forward a second time. One object for the process: a ``checkpoint``
+    equation holds its policy, and two traces of one program are then the
+    same jaxpr."""
+    from ray_tpu.ops.pallas.flash_attention import (
+        LSE_RESIDUAL_NAME, OUT_RESIDUAL_NAME)
+
+    policies = jax.checkpoint_policies
+    return policies.save_from_both_policies(
+        policies.dots_with_no_batch_dims_saveable,
+        policies.save_only_these_names(OUT_RESIDUAL_NAME, LSE_RESIDUAL_NAME))
+
+
 def _nll_from_logits(logits: jax.Array, targets: jax.Array) -> jax.Array:
     """-log p(target) without gather/scatter: the target logit comes from
     an iota-compare + masked reduce, so the backward is softmax - onehot
@@ -1504,11 +1525,17 @@ def _nll_from_logits(logits: jax.Array, targets: jax.Array) -> jax.Array:
     return lse - target_logit
 
 
-def _chunked_ce(x, lm_head, targets, mask, chunk, dtype, logits_scaling=1.0):
-    """Cross-entropy over seq chunks: logits for one chunk at a time, each
-    chunk's logits recomputed in the backward (jax.checkpoint) so peak
-    memory is B*chunk*V instead of B*S*V — the difference between a 7B
-    model fitting one 16-GiB chip or not."""
+def _chunked_ce(x, lm_head, targets, mask, chunk, dtype, logits_scaling,
+                remat_policy):
+    """Cross-entropy over seq chunks: the float32 logits, their
+    exponentials and the softmax's gradient exist for one chunk at a time
+    (B*chunk*V instead of B*S*V — the difference between a 7B model
+    fitting one 16-GiB chip or not), each chunk's recomputed in the
+    backward (jax.checkpoint). What the backward keeps of a chunk is
+    ``remat_policy``'s to say, as for the layers: under ``"full"`` nothing,
+    and the head's matmul runs a second time; under any other the matmul's
+    output in ``dtype`` (B*S*V*2 bytes in bf16 over the chunks), and the
+    elementwise part alone is recomputed."""
     B, S, H = x.shape
     assert S % chunk == 0, f"seq {S} not divisible by loss_chunk {chunk}"
     n = S // chunk
@@ -1517,7 +1544,10 @@ def _chunked_ce(x, lm_head, targets, mask, chunk, dtype, logits_scaling=1.0):
     mc = (jnp.moveaxis(mask.reshape(B, n, chunk), 1, 0)
           if mask is not None else jnp.ones_like(tc, jnp.float32))
 
-    @jax.checkpoint
+    @partial(
+        jax.checkpoint,
+        policy=(jax.checkpoint_policies.nothing_saveable
+                if remat_policy == "full" else _dots_policy()))
     def body(carry, inp):
         xi, ti, mi = inp
         logits = jnp.einsum("bch,hv->bcv", xi, lm_head.astype(dtype))
@@ -1547,7 +1577,8 @@ def llama_loss(params: Dict[str, Any], batch: Dict[str, jax.Array],
                                  lora_cfg=lora_cfg)
     if cfg.loss_chunk:
         ce = _chunked_ce(x, _lm_head(params), targets, mask,
-                         cfg.loss_chunk, cfg.dtype, cfg.logits_scaling)
+                         cfg.loss_chunk, cfg.dtype, cfg.logits_scaling,
+                         cfg.remat_policy)
     else:
         logits = _scaled_logits(
             jnp.einsum("bsh,hv->bsv", x, _lm_head(params).astype(cfg.dtype)),

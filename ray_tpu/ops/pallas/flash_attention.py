@@ -91,6 +91,7 @@ from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -493,11 +494,23 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return jnp.swapaxes(o, 1, 2)
 
 
+# The forward's two results as a remat policy may name them
+# (``jax.checkpoint_policies.save_only_these_names``): a ``pallas_call`` is
+# no ``dot_general``, so a policy that keeps matmul outputs drops them, and
+# the backward then runs the whole forward again to have them.
+OUT_RESIDUAL_NAME = "flash_fwd_out"
+LSE_RESIDUAL_NAME = "flash_fwd_lse"
+
+
 def _fa_fwd(q, k, v, causal):
+    # traced under differentiation alone: the primal above, which serving
+    # traces, carries no name
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
     o, lse = _flash_fwd(qt, kt, vt, causal=causal)
+    o = checkpoint_name(o, OUT_RESIDUAL_NAME)
+    lse = checkpoint_name(lse, LSE_RESIDUAL_NAME)
     return jnp.swapaxes(o, 1, 2), (qt, kt, vt, o, lse)
 
 
@@ -516,8 +529,13 @@ flash_attention.defvjp(_fa_fwd, _fa_bwd)
 
 # What the device trace calls the kernel: a Pallas call's HLO instruction
 # takes the name of its innermost named scope
-# (``tpu_custom_call:<this>.N``); the equal-width forward is
-# ``tpu_custom_call:checkpoint.N`` under a rematerialised layer.
+# (``tpu_custom_call:<this>.N``). The equal-width kernels have no scope of
+# their own and take their caller's: a serving step's forward is
+# ``tpu_custom_call:checkpoint.N`` (a run of layers under ``jax.checkpoint``),
+# and in a training step under ``remat_policy="dots"`` the forward is
+# ``closed_call.N``, dq and dk/dv ``checkpoint.N`` (PERF.md, PR 48: one
+# forward a layer; a policy that drops the forward's results runs it again
+# as ``rematted_computation.N``).
 SHARED_ROPE_TRACE_NAME = "flash_fwd_shared_rope"
 
 

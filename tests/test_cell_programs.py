@@ -29,7 +29,27 @@ steps; the cell serves 4). PR 46 (granite-4.0-h-micro: a state-space
 operator, ``_short_conv``'s taps shared with it through ``_causal_taps``,
 the family's four scalars and ``use_rope`` behind defaults) moved none of
 the seventeen: they are what its parent ``74f24a8`` gives to the
-character; ``serve_granite_toolcalls``'s three are new.
+character; ``serve_granite_toolcalls``'s three are new. PR 48
+(``remat_policy="dots"`` keeps the flash forward's two results by name and
+the loss's scan keeps its matmul's output; PR 47 was the same change and
+left nothing in the tree) moved ``train_l2_seq4k.grad``, as it meant to:
+three flash kernels a layer where there were four, three matmuls of the
+head's size where there were four. The seven ``init`` lines are what its
+parent ``800f69f`` gives to the character. The twelve ``step<length>``
+lines are re-baselined for ONE word an equation: a serving configuration
+leaves ``remat`` at its default, so its step holds a ``checkpoint``
+equation a run of layers, and that equation prints its policy's name
+(``dots_with_no_batch_dims_saveable`` at the parent,
+``save_from_both_policies.<locals>.policy`` now). What the twelve LOWER to
+did not move: a ``checkpoint`` that is not differentiated lowers to its
+body, the policy is in no StableHLO, and the sha256 of each step's
+``jax.jit(...).lower(...).as_text()`` at the cells' shapes is the parent's
+(builder's check, PR 48; the table is in CHANGES.md's PR 48 line): to the
+character as the CPU lowers it, and as a described v5e lowers it once the
+Mosaic kernels' debug locations are dropped (a kernel's serialised body
+holds its call stack's file names and line numbers, so there any line
+added above a frame of ``llama.py`` or ``flash_attention.py`` moves the
+text and nothing that is compiled).
 """
 
 import hashlib
@@ -38,25 +58,25 @@ import pytest
 
 PROGRAMS = {
     "train_l2_seq4k.init": "d02563bf9b97ea28",
-    "train_l2_seq4k.grad": "cc40eeae09e6ec41",
+    "train_l2_seq4k.grad": "45b9dbc41a5406f2",
     "serve_chat_steady.init": "3ff45d688c80d7f8",
-    "serve_chat_steady.step128": "ad4fedae56577eac",
-    "serve_chat_steady.step384": "f2df55b80038d939",
+    "serve_chat_steady.step128": "e007e82555a4c201",
+    "serve_chat_steady.step384": "3bf862312efd7151",
     "serve_olmoe_chat.init": "126fada9fb96dc80",
-    "serve_olmoe_chat.step128": "c5d5a9838815288c",
-    "serve_olmoe_chat.step1152": "b6d7aefc5f242f6b",
+    "serve_olmoe_chat.step128": "467f3dc0672ecd24",
+    "serve_olmoe_chat.step1152": "3f737ba4afc9c639",
     "serve_lfm2_rag.init": "5766fc6f6af74d3d",
-    "serve_lfm2_rag.step128": "e2128e6a78e96d87",
-    "serve_lfm2_rag.step1408": "3f34f48cd5d440ad",
+    "serve_lfm2_rag.step128": "b1b4f4d232790bef",
+    "serve_lfm2_rag.step1408": "c4a0782a8afd991a",
     "serve_dsv2_docqa.init": "91b10ec8ff63401e",
-    "serve_dsv2_docqa.step256": "c3465377128f97b8",
-    "serve_dsv2_docqa.step1792": "f6519a8cefe24e4f",
+    "serve_dsv2_docqa.step256": "b2397e30a8a6e355",
+    "serve_dsv2_docqa.step1792": "f451bead003d1ee6",
     "serve_dots3_longdoc.init": "af5788ce0837f29f",
-    "serve_dots3_longdoc.step2560": "f4c2f0fc384c3613",
-    "serve_dots3_longdoc.step5120": "ec3ce3ece2252171",
+    "serve_dots3_longdoc.step2560": "3b1382529e1694a0",
+    "serve_dots3_longdoc.step5120": "e99df469b56665d0",
     "serve_granite_toolcalls.init": "ce77369b6d8feac3",
-    "serve_granite_toolcalls.step256": "055167584e2c5775",
-    "serve_granite_toolcalls.step1024": "ac5a47f5f04e9fbe",
+    "serve_granite_toolcalls.step256": "d64242bfaebb3f21",
+    "serve_granite_toolcalls.step1024": "b6e4937003c0a85e",
 }
 
 

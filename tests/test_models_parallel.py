@@ -47,6 +47,65 @@ class TestShardingRules:
         assert spec[0] == ("data", "fsdp")
 
 
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+class TestWhatARematPolicyKeeps:
+    """`remat_policy` says what the backward keeps and never what it
+    computes: under "dots" every matmul's output is kept, the flash
+    forward's (a `pallas_call`) and the head's too, so neither runs a
+    second time; under "full" both do."""
+
+    @pytest.fixture(scope="class")
+    def model(self):
+        cfg = dataclasses.replace(
+            LlamaConfig.debug_1l(), num_layers=2, vocab_size=384,
+            dtype=jnp.float32, attn_impl="flash", loss_chunk=64)
+        params = init_llama(cfg, jax.random.key(0))
+        tok = jax.random.randint(jax.random.key(1), (2, 129), 0,
+                                 cfg.vocab_size)
+        return cfg, params, {"inputs": tok[:, :-1], "targets": tok[:, 1:]}
+
+    @pytest.fixture(scope="class")
+    def unrematted(self, model):
+        cfg, params, batch = model
+        return jax.value_and_grad(llama_loss)(params, batch, cfg)
+
+    # a scan's body is traced once for all its layers: a run of layers
+    # under one policy holds the flash forward, dq and dk/dv once, and
+    # "mixed:1" cuts the two layers into a run a policy. The head's three
+    # matmuls are the logits, dX and dW.
+    @pytest.mark.parametrize("policy, flash_calls, head_dots", [
+        ("dots", 3, 3), ("full", 4, 4), ("mixed:1", 3 + 4, 3)])
+    def test_the_backward_runs_no_kept_matmul_again(
+            self, model, unrematted, policy, flash_calls, head_dots):
+        cfg, params, batch = model
+        cfg = dataclasses.replace(cfg, remat=True, remat_policy=policy)
+        grad = jax.value_and_grad(llama_loss)
+        eqns = list(_equations(
+            jax.make_jaxpr(lambda p: grad(p, batch, cfg))(params).jaxpr))
+        assert sum(e.primitive.name == "pallas_call"
+                   for e in eqns) == flash_calls
+
+        def of_the_head(eqn):
+            # logits, dX or dW: the only matmuls with the vocabulary in them
+            return any(cfg.vocab_size in v.aval.shape
+                       for v in (*eqn.invars, *eqn.outvars))
+
+        assert sum(e.primitive.name == "dot_general" and of_the_head(e)
+                   for e in eqns) == head_dots
+        loss, grads = grad(params, batch, cfg)
+        want_loss, want = unrematted
+        assert float(loss) == float(want_loss)
+        for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(want)):
+            np.testing.assert_array_equal(a, b)
+
+
 class TestLlama:
     def test_mixed_remat_matches_full(self):
         """remat_policy='mixed:K' (first K layers keep matmul outputs,
