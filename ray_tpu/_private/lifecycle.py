@@ -25,12 +25,15 @@ registered process.
 from __future__ import annotations
 
 import json
+import logging
 import os
 import signal
 import tempfile
 import threading
 import time
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
+
+logger = logging.getLogger("ray_tpu")
 
 REGISTRY_DIRNAME = "pids"
 
@@ -42,6 +45,19 @@ DAEMON_ROLES = ("gcs", "agent", "forkserver", "worker")
 # mid-bootstrap (the spawner registers pids right after Popen, so the
 # window is really milliseconds); never GC it.
 _BOOTSTRAP_GRACE_S = 120.0
+
+# How long a SIGKILLed process is given to leave the process table. On the
+# CPU it is gone in milliseconds; the worker that held a chip stays `Zsl`
+# (its main thread dead, the others letting go of the device's memory) for
+# 1-2 s after a serving run with 8.65 GB on a v5e, and one handed to pid 1
+# is reaped within 5 s (PERF.md section 7).
+KILLED_GONE_TIMEOUT_S = 5.0
+
+# `Node.stop`'s grace for an agent between SIGTERM and SIGKILL: its
+# teardown closes its clients (2 s at most), gives its workers SIGTERM's
+# 2 s, waits KILLED_GONE_TIMEOUT_S for the killed and sweeps the registry
+# (1 s). An agent cut short of that hands its workers to pid 1.
+AGENT_TEARDOWN_GRACE_S = 10.0
 
 
 def default_session_roots() -> List[str]:
@@ -59,17 +75,24 @@ def default_session_roots() -> List[str]:
 # ---------------------------------------------------------------------------
 
 
+def _proc_stat(pid: int) -> Optional[List[str]]:
+    """The fields of ``/proc/<pid>/stat`` from the third (the state) on, or
+    None where it cannot be read. The comm before them may itself contain
+    spaces, so the split is after the LAST ')'."""
+    try:
+        with open(f"/proc/{pid}/stat", "rb") as f:
+            data = f.read().decode("ascii", "replace")
+        return data.rsplit(")", 1)[1].split()
+    except (OSError, IndexError):
+        return None
+
+
 def _proc_create_time(pid: int) -> Optional[float]:
     """Start time of ``pid`` (ticks-since-boot from /proc on Linux), or
     None when it cannot be determined. Only equality matters — the value
     is an identity token against pid recycling, not a timestamp."""
     try:
-        with open(f"/proc/{pid}/stat", "rb") as f:
-            data = f.read().decode("ascii", "replace")
-        # field 22 (1-indexed) after the parenthesized comm, which may
-        # itself contain spaces — split after the LAST ')'
-        tail = data.rsplit(")", 1)[1].split()
-        return float(tail[19])
+        return float(_proc_stat(pid)[19])  # field 22, 1-indexed
     except Exception:
         try:
             import psutil
@@ -95,14 +118,47 @@ def _pid_alive(pid: int, create_time: Optional[float] = None) -> bool:
         if now_ct is not None and abs(now_ct - create_time) > 1e-6:
             return False  # pid was recycled by an unrelated process
     # zombies hold their pid but are already dead for teardown purposes
+    stat = _proc_stat(pid)
+    return not (stat and stat[0] == "Z")
+
+
+def _in_process_table(pid: int, create_time: Optional[float]) -> bool:
+    """Whether ``pid`` still has its entry in the process table, zombies
+    included: a defunct worker whose threads are still letting go of a chip
+    is dead to `_pid_alive` and is still there. One exception: a whole
+    zombie whose parent is the calling process counts as gone, because
+    only the holder of its handle can reap it (``Popen.wait``) and no wait
+    here could end before its limit."""
+    if pid <= 0:
+        return False
     try:
-        with open(f"/proc/{pid}/stat", "rb") as f:
-            data = f.read().decode("ascii", "replace")
-        if data.rsplit(")", 1)[1].split()[0] == "Z":
-            return False
-    except Exception:
-        pass
-    return True
+        os.kill(pid, 0)
+    except ProcessLookupError:
+        return False
+    except OSError:
+        pass  # exists, owned by someone else
+    stat = _proc_stat(pid)
+    if stat is None:
+        return True  # no /proc to ask: kill(pid, 0) found it
+    state, ppid, threads, started = (stat[0], int(stat[1]), int(stat[17]),
+                                     float(stat[19]))
+    if create_time is not None and abs(started - create_time) > 1e-6:
+        return False  # the pid is another process's now
+    return not (state == "Z" and threads <= 1 and ppid == os.getpid())
+
+
+def wait_gone(records: List[Dict],
+              timeout_s: float = KILLED_GONE_TIMEOUT_S) -> List[Dict]:
+    """Wait, for ``timeout_s`` at most, until every record's ``pid`` (with
+    its ``create_time``, where known) has left the process table. Returns
+    the records that were still there at the limit."""
+    deadline = time.monotonic() + timeout_s
+    while True:
+        records = [r for r in records
+                   if _in_process_table(r["pid"], r.get("create_time"))]
+        if not records or time.monotonic() >= deadline:
+            return records
+        time.sleep(0.02)
 
 
 # ---------------------------------------------------------------------------
@@ -219,14 +275,28 @@ def _signal_record(rec: Dict, sig: int) -> None:
 
 def reap_session(session_dir: str, node_id: Optional[str] = None,
                  sigterm_timeout_s: float = 3.0,
-                 remove: bool = False) -> List[int]:
-    """Walk the session registry with escalating SIGTERM→SIGKILL.
+                 remove: bool = False,
+                 roles: Optional[Tuple[str, ...]] = None) -> List[int]:
+    """Walk the session registry with escalating SIGTERM→SIGKILL, and
+    return when the victims are gone from the process table.
 
     ``node_id`` limits the sweep to one node's processes (a worker node
-    leaving a shared session must not take the cluster down). Returns the
-    pids that were still alive when the sweep started. ``remove`` also
-    unlinks the session dir (shm segments live inside it)."""
-    victims = live_registered(session_dir, node_id)
+    leaving a shared session must not take the cluster down) and ``roles``
+    to some of `DAEMON_ROLES` (an agent reaps its workers before their
+    parent). Returns the pids that were still alive when the sweep
+    started. ``remove`` also unlinks the session dir (shm segments live
+    inside it).
+
+    The last wait (`wait_gone`) also covers a registered process that was
+    dead already and defunct: a worker whose parent was killed before it
+    is pid 1's to reap, and until then it may hold its chip."""
+    me = os.getpid()
+    registered = [r for r in list_registered(session_dir)
+                  if r["pid"] != me
+                  and (not node_id or r.get("node_id") == node_id)
+                  and (not roles or r.get("role") in roles)]
+    victims = [r for r in registered
+               if _pid_alive(r["pid"], r.get("create_time"))]
     for rec in victims:
         _signal_record(rec, signal.SIGTERM)
     deadline = time.monotonic() + sigterm_timeout_s
@@ -237,6 +307,11 @@ def reap_session(session_dir: str, node_id: Optional[str] = None,
                    if _pid_alive(r["pid"], r.get("create_time"))]
     for rec in pending:
         _signal_record(rec, signal.SIGKILL)
+    left = wait_gone(registered)
+    if left:
+        logger.warning("reap_session(%s): still in the process table: %s",
+                       session_dir,
+                       ", ".join(f"{r.get('role')}:{r['pid']}" for r in left))
     for rec in victims:
         if not _pid_alive(rec["pid"], rec.get("create_time")):
             unregister_process(session_dir, rec["pid"])
@@ -400,31 +475,38 @@ def fate_share_with_parent(
 
 def terminate_tree(procs: List, sigterm_timeout_s: float = 2.0) -> None:
     """SIGTERM (by pgid when possible) then SIGKILL a set of handles with
-    ``pid``/``poll()``. Shared by the agent's worker teardown and tests."""
-    live = [p for p in procs if p is not None and getattr(p, "pid", None)
-            and p.poll() is None]
-    for p in live:
-        try:
-            os.killpg(os.getpgid(p.pid), signal.SIGTERM)
-        except (ProcessLookupError, PermissionError, OSError):
+    ``pid``/``poll()``, and return when ``poll()`` says each has ended:
+    a ``Popen``'s reaps its child, and the agent's stand-in for another
+    process's child asks the process table, where a zombie still is.
+    Shared by `Node.stop`, the agent's worker teardown and tests."""
+
+    def signal_all(live: List, sig: int) -> None:
+        for p in live:
             try:
-                p.terminate()
-            except Exception:
-                pass
-    deadline = time.monotonic() + sigterm_timeout_s
-    while time.monotonic() < deadline:
-        if all(p.poll() is not None for p in live):
-            return
-        time.sleep(0.05)
-    for p in live:
-        if p.poll() is None:
-            try:
-                os.killpg(os.getpgid(p.pid), signal.SIGKILL)
+                os.killpg(os.getpgid(p.pid), sig)
             except (ProcessLookupError, PermissionError, OSError):
                 try:
-                    p.kill()
+                    (p.terminate if sig == signal.SIGTERM else p.kill)()
                 except Exception:
                     pass
+
+    def still_running(live: List, timeout_s: float) -> List:
+        deadline = time.monotonic() + timeout_s
+        while True:
+            live = [p for p in live if p.poll() is None]
+            if not live or time.monotonic() >= deadline:
+                return live
+            time.sleep(0.05)
+
+    live = [p for p in procs if p is not None and getattr(p, "pid", None)]
+    for sig, timeout_s in ((signal.SIGTERM, sigterm_timeout_s),
+                           (signal.SIGKILL, KILLED_GONE_TIMEOUT_S)):
+        live = still_running(live, 0.0)
+        signal_all(live, sig)
+        live = still_running(live, timeout_s)
+    if live:
+        logger.warning("terminate_tree: still in the process table: %s",
+                       [p.pid for p in live])
 
 
 def format_sessions(sessions: Optional[List[Dict]] = None) -> str:

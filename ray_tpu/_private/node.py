@@ -13,7 +13,6 @@ import atexit
 import json
 import os
 import shutil
-import signal
 import subprocess
 import sys
 import tempfile
@@ -197,41 +196,24 @@ class Node:
 
     # ---------------------------------------------------------------- down
     def stop(self, cleanup_session: bool = False) -> None:
-        """Stop this node's daemons, then walk the session pid registry.
+        """Stop this node's daemons, then walk the session pid registry,
+        and return when both are gone from the process table.
 
         The direct SIGTERM gives the agent its graceful window (it kills
-        its own workers/forkserver on SIGTERM); the registry sweep then
-        catches anything that escaped its spawner's process group —
+        its own workers, waits for them, and then its forkserver, on
+        SIGTERM: `lifecycle.AGENT_TEARDOWN_GRACE_S`); the registry sweep
+        then catches anything that escaped its spawner's process group —
         forkserver grandchildren setsid into foreign pgids, so signalling
-        ``head_proc``/``agent_proc`` groups alone leaks them.
+        ``head_proc``/``agent_proc`` groups alone leaks them — and waits
+        for what an agent cut short has handed to pid 1.
         ``cleanup_session`` sweeps the WHOLE session (every node) and
         unlinks the dir with its shm segments; otherwise only this node's
         registered processes are reaped (a worker node leaving a shared
         session must not take the cluster down).
         """
-        for proc in (self.agent_proc, self.head_proc):
-            if proc is not None and proc.poll() is None:
-                try:
-                    os.killpg(os.getpgid(proc.pid), signal.SIGTERM)
-                except (ProcessLookupError, PermissionError, OSError):
-                    try:
-                        proc.terminate()
-                    except Exception:
-                        pass
-        deadline = time.monotonic() + 3
-        for proc in (self.agent_proc, self.head_proc):
-            if proc is None:
-                continue
-            while proc.poll() is None and time.monotonic() < deadline:
-                time.sleep(CONFIG.node_boot_poll_s)
-            if proc.poll() is None:
-                try:
-                    os.killpg(os.getpgid(proc.pid), signal.SIGKILL)
-                except Exception:
-                    try:
-                        proc.kill()
-                    except Exception:
-                        pass
+        lifecycle.terminate_tree(
+            [self.agent_proc, self.head_proc],
+            sigterm_timeout_s=lifecycle.AGENT_TEARDOWN_GRACE_S)
         try:
             lifecycle.reap_session(
                 self.session_dir,

@@ -19,6 +19,13 @@ Design notes (why this is not a torch translation):
   ``params["layers"][kind]``. There is one block (``_layer``, which reads a
   layer's kind off its leaves) and one forward, which scans each run of
   like layers in the configuration's order (``LlamaConfig.layer_runs``).
+  A run that is a proper part of its kind's stack is scanned over its
+  slice of every leaf, which XLA copies every step, except in the forward
+  that nobody differentiates (``llama_next_token``, the serving step):
+  that one scans the run's indices and reads each layer out of the whole
+  stack in the body, and the loss keeps the slices because a
+  differentiated scan over a closed-over stack carries a cotangent of the
+  whole stack through every run.
 - A third operator, ``"latent_attention"`` (DeepSeek-V2's MLA;
   ``_latent_attention``): queries through a low-rank pair with a norm
   between, keys and values decompressed from one normed latent row a
@@ -1334,6 +1341,7 @@ def _hidden_and_books(
     lora: Optional[Dict[str, Any]] = None,
     lora_cfg: Optional[LoraConfig] = None,
     router_mask: Optional[jax.Array] = None,
+    in_place: bool = False,
 ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
     """``llama_hidden``, and with it the layers' books (``_layer``), each
     key stacked over the layers that keep it, in the model's order: the
@@ -1345,7 +1353,10 @@ def _hidden_and_books(
     masked-out position's hidden state lacks its routed part (rows are
     padded on the right and every operator is causal: no position that is
     marked reads one that is not). Without a mask every position is
-    computed and counted."""
+    computed and counted. With ``in_place``, which is a forward pass's to
+    ask for, a run that is a proper part of its stack reads each layer
+    where it lies and no slice of a stack is made (differentiated, every
+    such run's backward scan would carry a whole stack's cotangent)."""
     B, S = tokens.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
@@ -1358,24 +1369,39 @@ def _hidden_and_books(
 
     def scan_over(kind, start, n):
         """(scan body, xs) over layers ``start`` to ``start + n`` of a
-        kind's stack. With experts the body also sees the whole stack and
-        its own index in it."""
+        kind's stack. A run that is a proper part of its stack is handed
+        its slice of every leaf, or with ``in_place`` its layers' indices
+        alone, and the body reads each layer where it lies. With experts
+        the body also sees the whole stack and its own index in it."""
+        stack, lo_stack = stacks[kind], lo_stacks.get(kind) or {}
+        routed = "router" in stack
+        whole = jax.tree.leaves(stack)[0].shape[0] == n
+        reads = in_place and not whole
+
         def part(tree):
-            # broadcast None through the scan when no adapters: xs must be
-            # a pytree of arrays, so substitute an empty dict
-            if not tree or jax.tree.leaves(tree)[0].shape[0] == n:
-                return tree or {}
+            # xs is a pytree of arrays: no adapters are an empty dict, and
+            # so is what the body reads for itself
+            if whole:
+                return tree
+            if reads:
+                return {}
             return jax.tree.map(lambda a: a[start:start + n], tree)
 
-        routed = "router" in stacks[kind]
-        index = jnp.arange(start, start + n) if routed else None
+        index = jnp.arange(start, start + n) if routed or reads else None
 
         def scan_fn(carry, xs):
             lp, lo_i, i = xs
+            if reads:
+                # what `lax.scan` lowers `xs` to, `start` added to the
+                # counter; a layer of the expert stacks is read by no one
+                # (`moe.expert_ffn` reads `in_stack`'s) and compiles away
+                lp, lo_i = jax.tree.map(
+                    lambda a: jax.lax.dynamic_index_in_dim(
+                        a, i, keepdims=False), (stack, lo_stack))
             if routed:
                 # the masked positions are all that is wanted of the
                 # routed experts: the others' pairs are not multiplied
-                lp = moe.in_stack(lp, stacks[kind], i, router_mask,
+                lp = moe.in_stack(lp, stack, i, router_mask,
                                   skip_unmasked=True)
             y, _, books = _layer(cfg, carry, lp, positions, lora=lo_i,
                                  lora_scale=scale,
@@ -1383,8 +1409,7 @@ def _hidden_and_books(
                                  live=router_mask)
             return y, books
 
-        return scan_fn, (part(stacks[kind]), part(lo_stacks.get(kind)),
-                         index)
+        return scan_fn, (part(stack), part(lo_stack), index)
 
     # Each run of like layers is one scan, in the model's order, under the
     # layer's remat policy. "dots": keep matmul outputs (_dots_policy: the
@@ -1480,7 +1505,8 @@ def llama_next_token(
     With ``live`` the routed experts compute the marked positions alone,
     and the hidden states of the others are not a forward pass's."""
     x, books = _hidden_and_books(params, tokens, cfg, lora=lora,
-                                 lora_cfg=lora_cfg, router_mask=live)
+                                 lora_cfg=lora_cfg, router_mask=live,
+                                 in_place=True)
     rows = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
     ids = jnp.argmax(llama_head(params, rows, cfg), axis=-1)
     load = None

@@ -139,6 +139,10 @@ FAST_FILES = {
     "test_ssd_scan.py",
     # each cell's programs, hashed: what a PR left alone and what it moved
     "test_cell_programs.py",
+    # a serving step reads a partial run's layers where they lie in their
+    # stack: no slice in its jaxpr, the sliced form's results to the bit,
+    # the loss's gradient a per-layer loop's (two minutes)
+    "test_layer_runs_in_place.py",
     # the model layer's own tests (ISSUE 30): what the three cells trace.
     # The one block's forward, `mixed:K` remat, cached decode, the chunked
     # loss, LoRA; the train step on an fsdp x tensor mesh and the

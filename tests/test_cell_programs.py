@@ -49,7 +49,17 @@ character as the CPU lowers it, and as a described v5e lowers it once the
 Mosaic kernels' debug locations are dropped (a kernel's serialised body
 holds its call stack's file names and line numbers, so there any line
 added above a frame of ``llama.py`` or ``flash_attention.py`` moves the
-text and nothing that is compiled).
+text and nothing that is compiled). PR 50 (a serving step reads a partial
+run's layers where they lie in their stack: ``llama_next_token`` scans such
+a run over its layers' indices and the body indexes the whole stack, so no
+``slice`` of a layer stack is left outside a loop; PR 49 was the same change
+and left nothing in the tree) moved the four step programs of the two cells
+whose kinds lie in several runs, ``serve_granite_toolcalls`` (nine runs of
+two kinds) and ``serve_lfm2_rag`` (five of three, four of them partial);
+the seven ``init`` lines, ``train_l2_seq4k.grad`` (the loss keeps its
+slices, and Mistral has one kind) and the eight steps of the five cells
+whose every run is its whole stack are what its parent ``60e176b`` gives to
+the character.
 """
 
 import hashlib
@@ -66,8 +76,8 @@ PROGRAMS = {
     "serve_olmoe_chat.step128": "467f3dc0672ecd24",
     "serve_olmoe_chat.step1152": "3f737ba4afc9c639",
     "serve_lfm2_rag.init": "5766fc6f6af74d3d",
-    "serve_lfm2_rag.step128": "b1b4f4d232790bef",
-    "serve_lfm2_rag.step1408": "c4a0782a8afd991a",
+    "serve_lfm2_rag.step128": "69db9a37644bffff",
+    "serve_lfm2_rag.step1408": "80b36b565a92faa6",
     "serve_dsv2_docqa.init": "91b10ec8ff63401e",
     "serve_dsv2_docqa.step256": "b2397e30a8a6e355",
     "serve_dsv2_docqa.step1792": "f451bead003d1ee6",
@@ -75,8 +85,8 @@ PROGRAMS = {
     "serve_dots3_longdoc.step2560": "3b1382529e1694a0",
     "serve_dots3_longdoc.step5120": "e99df469b56665d0",
     "serve_granite_toolcalls.init": "ce77369b6d8feac3",
-    "serve_granite_toolcalls.step256": "d64242bfaebb3f21",
-    "serve_granite_toolcalls.step1024": "b6e4937003c0a85e",
+    "serve_granite_toolcalls.step256": "4adf109acac83db4",
+    "serve_granite_toolcalls.step1024": "ee9ab836cd96137f",
 }
 
 

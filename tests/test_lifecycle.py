@@ -83,6 +83,92 @@ def test_shutdown_reaps_everything(asyncio_log):
     assert not pending, pending
 
 
+def _in_process_table(rec) -> bool:
+    """The pid still has an entry that is the recorded process's, defunct
+    or not (`lifecycle._pid_alive` calls a zombie dead)."""
+    from ray_tpu._private import lifecycle
+
+    try:
+        os.kill(rec["pid"], 0)
+    except ProcessLookupError:
+        return False
+    now = lifecycle._proc_create_time(rec["pid"])
+    return now is None or rec.get("create_time") is None or \
+        abs(now - rec["create_time"]) < 1e-6
+
+
+@pytest.mark.parametrize("worker", ["idle", "deaf", "chip_holder"])
+def test_shutdown_leaves_no_entry_in_the_process_table(worker, monkeypatch):
+    """After `shutdown()` none of the session's pids is in the process
+    table, zombies included: a defunct worker handed to pid 1 may still
+    hold its chip. ``deaf`` is a worker that never runs its SIGTERM
+    handler (stopped here; wedged in native code in the field) and is
+    SIGKILLed after the grace; ``chip_holder`` leases a (fake) chip and is
+    SIGKILLed at once, as `WorkerHandle.terminate` does."""
+    import ray_tpu
+    from ray_tpu._private import lifecycle
+
+    if worker == "chip_holder":
+        monkeypatch.setenv("RAY_TPU_NUM_CHIPS", "1")
+    ray_tpu.init(num_cpus=2)
+    try:
+        session_dir = ray_tpu._global_node.session_dir
+
+        @ray_tpu.remote
+        class A:
+            def pid(self):
+                return os.getpid()
+
+        options = {"num_tpus": 1} if worker == "chip_holder" else {}
+        pid = ray_tpu.get(A.options(**options).remote().pid.remote(),
+                          timeout=60)
+        recorded = lifecycle.list_registered(session_dir)
+        assert {"gcs", "agent", "forkserver", "worker"} <= \
+            {r["role"] for r in recorded}, recorded
+        assert pid in {r["pid"] for r in recorded}
+        if worker != "idle":
+            os.kill(pid, signal.SIGSTOP)
+    finally:
+        ray_tpu.shutdown()
+
+    left = [r for r in recorded if _in_process_table(r)]
+    assert not left, f"still in the process table after shutdown: {left}"
+    assert not os.path.exists(session_dir)
+
+
+def test_wait_gone_sees_a_zombie_and_ends_at_its_limit():
+    """`wait_gone` alone, on a killed grandchild: defunct under a parent
+    that never reaps it, it is dead to `_pid_alive` and still there, and
+    the wait ends at its limit with it; once its parent is gone too it is
+    pid 1's to reap, and the wait ends when that has happened."""
+    from ray_tpu._private import lifecycle
+
+    parent = subprocess.Popen(
+        [sys.executable, "-c",
+         "import subprocess, sys, time\n"
+         "g = subprocess.Popen([sys.executable, '-c',"
+         " 'import time; time.sleep(120)'], start_new_session=True)\n"
+         "print(g.pid, flush=True)\n"
+         "time.sleep(120)\n"], stdout=subprocess.PIPE, text=True)
+    try:
+        grandchild = int(parent.stdout.readline())
+        rec = {"pid": grandchild,
+               "create_time": lifecycle._proc_create_time(grandchild)}
+        os.kill(grandchild, signal.SIGKILL)
+        deadline = time.monotonic() + 10
+        while lifecycle._pid_alive(grandchild, rec["create_time"]):
+            assert time.monotonic() < deadline, "SIGKILL never landed"
+            time.sleep(0.02)
+        t0 = time.monotonic()
+        assert lifecycle.wait_gone([rec], timeout_s=0.3) == [rec]
+        assert 0.3 <= time.monotonic() - t0 < 3.0
+    finally:
+        parent.kill()
+        parent.wait(timeout=10)
+    assert lifecycle.wait_gone([rec], timeout_s=30.0) == []
+    assert not _in_process_table(rec)
+
+
 def test_cluster_teardown_reaps_everything():
     import ray_tpu
     from ray_tpu._private import lifecycle
