@@ -372,9 +372,8 @@ class NodeAgent:
             self.oom_killer = OomKiller(
                 MemoryMonitor(usage_threshold=threshold), list_leases, kill)
             spawn_tracked(self.oom_killer.run(), "agent-oom-killer")
-        if CONFIG.prestart_workers:
-            spawn_tracked(self._prestart(), "agent-prestart")
-            spawn_tracked(self._warm_pool_loop(), "agent-warm-pool")
+        spawn_tracked(self._prestart(), "agent-prestart")
+        spawn_tracked(self._warm_pool_loop(), "agent-warm-pool")
 
     async def _events_flush_loop(self) -> None:
         """Batch-flush this agent's flight-recorder ring to the head
@@ -416,8 +415,8 @@ class NodeAgent:
         return when they are gone from the process table. The
         agent is the fate-share supervisor for its node: this runs on
         SIGTERM, on head-gone give-up, and when the spawning driver dies,
-        so no daemon outlives the session (VERDICT r5: 22 leaked daemons
-        starved the next benchmark run).
+        so no daemon outlives the session: leaked daemons starve whatever
+        runs next on the machine.
 
         In order: the workers die and are waited for while the forkserver,
         their parent and the only process that can reap them, lives; a
@@ -528,29 +527,12 @@ class NodeAgent:
                 self._consume_death_ledger()
             except Exception:
                 pass
-            if CONFIG.worker_pool_demand_paging:
-                # predictive refill (ISSUE 11): deficit = live waiters +
-                # warm floor − parked − mid-boot; the burst events from
-                # _note_actor_demand already pre-forked toward the batch
-                # window, so this tick only covers the floor and
-                # stragglers (a fork that died, an expired waiter)
-                self._refill_to_demand(include_floor=True)
-                continue
-            deficit = self.WARM_TARGET - self._warm_idle_count() \
-                - self._spawning_plain
-            if deficit <= 0:
-                continue
-            # legacy pacing (demand paging disabled): while a burst is
-            # actively draining the pool refill one fork per tick; once
-            # the burst passes, a whole admission window per tick.
-            now = time.monotonic()
-            busy = (now - getattr(self, "_last_warm_lease", 0.0) < 1.0
-                    or now - getattr(self, "_last_ready_report", 0.0) < 1.0
-                    or bool(self._ready_queue))
-            for _ in range(1 if busy
-                           else min(deficit, self.STARTUP_CONCURRENCY)):
-                self._pool_refills += 1
-                self._spawn_worker(pool_fill=True)
+            # predictive refill (ISSUE 11): deficit = live waiters +
+            # warm floor − parked − mid-boot; the burst events from
+            # _note_actor_demand already pre-forked toward the batch
+            # window, so this tick only covers the floor and
+            # stragglers (a fork that died, an expired waiter)
+            self._refill_to_demand(include_floor=True)
 
     def _consume_death_ledger(self) -> None:
         """Apply the forkserver's SIGCHLD death ledger: a warm worker that
@@ -615,8 +597,7 @@ class NodeAgent:
         actors, every extra fork stealing boot CPU from the burst on a
         2-core box). The spawn admission queue still bounds concurrent
         boots; this only sizes the pipeline."""
-        if not self.warm_lease_enabled or self._closing or \
-                not CONFIG.worker_pool_demand_paging:
+        if not self.warm_lease_enabled or self._closing:
             return
         # shed settled waiters (timed-out futures from re-arm windows):
         # without this the deque only drains when a registration pops
@@ -705,10 +686,7 @@ class NodeAgent:
             self._consume_death_ledger()
         except Exception:
             pass
-        handle = self._pop_idle_worker(None, unused_only=unused_only)
-        if handle is not None:
-            self._last_warm_lease = time.monotonic()
-        return handle
+        return self._pop_idle_worker(None, unused_only=unused_only)
 
     # ------------------------------------------------------------ head link
     async def _connect_head(self) -> None:
@@ -956,7 +934,7 @@ class NodeAgent:
                 continue
             self._launching_workers += 1
             handle.launching = True
-            if container or conda_prefix or not CONFIG.worker_forkserver:
+            if container or conda_prefix:
                 try:
                     self._launch_worker(handle, container, conda_prefix,
                                         env_key)
@@ -1460,7 +1438,6 @@ class NodeAgent:
         worker the head never acked exits and is no zombie) is preserved
         end to end."""
         fut = asyncio.get_running_loop().create_future()
-        self._last_ready_report = time.monotonic()
         self._ready_queue.append((p, fut))
         if not self._ready_flush_armed:
             self._ready_flush_armed = True
@@ -1472,7 +1449,8 @@ class NodeAgent:
 
     async def _flush_ready_batch(self) -> None:
         self._ready_flush_armed = False
-        batch, self._ready_queue = self._ready_queue, []
+        batch = self._ready_queue
+        self._ready_queue = []
         if not batch:
             return
         _note_hist(self._ready_batch_hist, len(batch))
@@ -1863,15 +1841,13 @@ class NodeAgent:
         else:
             # a chip actor never parks for the pool: a returned lease's
             # worker may be offered there, and it has already run code
-            if self.warm_lease_enabled and CONFIG.worker_pool_demand_paging \
-                    and not wants_chip:
+            if self.warm_lease_enabled and not wants_chip:
                 # demand paging (ISSUE 11): park for the next pool
                 # registration — the pre-forked pipeline from
                 # _note_actor_demand is already booting toward us
                 handle = await self._wait_pool_worker()
             if handle is not None:
                 self._demand_hits += 1
-                self._last_warm_lease = time.monotonic()
                 ev_source = "demand_hit"
             else:
                 self._pool_misses += 1
@@ -2385,7 +2361,7 @@ class NodeAgent:
         if size is None:
             return "absent" if any_absent else "conn"
         meta = (size, alive, any_absent)
-        if not (CONFIG.bcast_enabled and size >= CONFIG.bcast_min_bytes):
+        if size < CONFIG.bcast_min_bytes:
             return await spanned(
                 "stripe_pull", self.pulls.fetch(hex_id, alive, meta=meta),
                 len(alive))
@@ -3112,8 +3088,7 @@ class NodeAgent:
             if first < now and now - first >= grace:
                 suspects.append(dict(row, age_s=round(now - first, 1)))
         prev = {s["object_id"] + s["reason"] for s in self._leak_suspects}
-        if CONFIG.object_leak_repair_enabled:
-            self._repair_leaks(suspects, now)
+        self._repair_leaks(suspects, now)
         self._leak_suspects = suspects
         rec = _events.REC
         if rec.enabled:

@@ -81,7 +81,6 @@ _flag("object_pull_orphan_grace_s", 20.0)
 # instead of N serial root pulls. Objects below bcast_min_bytes keep the
 # plain multi-holder striped pull (tree bookkeeping costs more than it
 # saves on small objects).
-_flag("bcast_enabled", True)
 _flag("bcast_min_bytes", 8 * 1024 * 1024)
 # Children per tree node. 2 keeps every node's upload ≤ 2x the object
 # size; raise on networks where serving fan-out is cheap.
@@ -130,9 +129,6 @@ _flag("object_introspect_timeout_s", 10.0)
 # --- streaming data plane (ISSUE 12) -----------------------------------------
 # DataContext seeds its per-process defaults from these (env-overridable
 # like every flag); the streaming shuffle + executor read the context.
-# Kill switch: route random_shuffle/sort back through the materializing
-# AllToAll exchange.
-_flag("data_streaming_shuffle", True)
 # Byte budget over the input shards of ADMITTED-but-unfinished reducers
 # (0 = unlimited): a slow reducer backpressures further admission instead
 # of the exchange buffering the whole dataset in worker memory.
@@ -151,7 +147,6 @@ _flag("data_exec_idle_wait_s", 0.25)
 
 # --- workers ----------------------------------------------------------------
 _flag("num_workers_soft_limit", 0)  # 0 = num_cpus
-_flag("worker_forkserver", True)  # fork plain workers from a warm template
 _flag("worker_startup_concurrency", 0)  # 0 = max(2, num_cpus); processes
 # between fork and registration at once (reference:
 # maximum_startup_concurrency, worker_pool.h)
@@ -163,7 +158,6 @@ _flag("worker_register_timeout_s", 60)
 # stalling elastic-restart actor placement behind it.
 _flag("worker_kill_escalation_s", 5.0)
 _flag("idle_worker_killing_time_ms", 600_000)
-_flag("prestart_workers", True)
 
 # --- warm worker pool (ISSUE 10) ---------------------------------------------
 # Pre-warmed pool target: the agent keeps this many forked-but-idle
@@ -184,7 +178,6 @@ _flag("worker_pool_idle_ttl_s", 30.0)
 # cold-forking), and the refill loop sizes its fork burst from the
 # observed CreateActorBatch window + the live waiter queue — not one
 # fork per tick — so hit_ratio approaches 1 under creation bursts.
-_flag("worker_pool_demand_paging", True)
 # How long a missed actor start waits for a demand-paged pool worker
 # before falling back to a dedicated cold fork (never a failure mode).
 _flag("worker_pool_wait_s", 20.0)
@@ -196,19 +189,13 @@ _flag("worker_pool_demand_window_s", 5.0)
 # Cap on pool-fill forks enqueued per refill decision; 0 = uncapped
 # (the spawn admission queue still bounds concurrent boots).
 _flag("worker_pool_refill_burst_max", 0)
-# Worker processes defer their head TCP connection off the boot critical
-# path (background connect): time-to-leasable drops by one TCP setup +
-# two subscribe round trips per worker. Head-bound calls queue behind
-# the pending connect via the outage machinery (head_call).
-_flag("worker_lazy_head_connect", True)
 
 # --- multiplexed direct-call plane (ISSUE 11) --------------------------------
 # One ctrl connection per peer PROCESS carrying every actor/lease/owner
 # channel as a stream (per-call stream ids in the PR 3 framing) instead
 # of one TCP connection per driver→actor pair. Per-stream close fails
 # only that stream's in-flight calls; the session survives for its
-# siblings. Disable to fall back to dedicated per-channel clients.
-_flag("direct_call_mux_enabled", True)
+# siblings.
 # Fair interleaving quantum: frames one stream may place in the shared
 # session's outbound buffer per round-robin turn, so one chatty actor
 # cannot head-of-line-block its session siblings' dispatch order.
@@ -242,9 +229,6 @@ _flag("actor_create_batch_max", 256)  # flush immediately at this size
 # their node agent (unix socket); the agent flushes one ActorReadyBatch
 # head RPC per window, acking workers only after the head acked.
 _flag("actor_ready_batch_window_ms", 5.0)
-# Lease-request batching: a pool wanting k leases in one pump sends one
-# RequestWorkerLeaseBatch frame; grants stream back per entry.
-_flag("lease_batch_enabled", True)
 
 # --- fault tolerance --------------------------------------------------------
 _flag("task_max_retries_default", 3)
@@ -264,16 +248,6 @@ _flag("lineage_max_bytes", 64 * 1024 * 1024)
 # surfaces instead of resubmitting again.
 _flag("lineage_max_reconstruction_depth", 20)
 _flag("lineage_max_reconstruction_attempts", 3)
-# Leak-watchdog repair hook: when a suspect graduates with an
-# owner_unreachable / zero_refs verdict, the agent frees the store
-# copy instead of merely reporting it (the object is garbage — its
-# owner can never pull it again, or holds no reference to it).
-_flag("object_leak_repair_enabled", True)
-# Node fencing (partition tolerance): a node marked dead has its
-# incarnation fenced; a late re-register from that incarnation (the
-# partition healed) is rejected and the agent self-terminates, so no
-# zombie leases/object writes outlive the head's death verdict.
-_flag("node_fence_enabled", True)
 # Reconnect grace after an agent's TCP connection drops: a transient
 # blip (head restart, one lost socket) no longer instantly kills a
 # healthy node's actors — the node is only marked dead if it fails to
@@ -322,8 +296,8 @@ _flag("task_event_buffer_max", 100_000)
 # steps) that record span trees; children inherit the parent's verdict
 # via the trace context on the task-spec wire. 0 (default) disarms the
 # recorder entirely — every instrumentation site is then one attribute
-# load + branch (events.overhead_probe / the ray_perf A/B verify the
-# ~zero cost). Set to 1.0 when debugging where time goes per hop.
+# load + branch (events.overhead_probe measures it). Set to 1.0 when
+# debugging where time goes per hop.
 _flag("task_event_sample_rate", 0.0)
 # Per-process ring geometry: fixed-size mmap'd slots under
 # <session>/events/<role>-<pid>.ring. The file IS the flight recorder —
@@ -364,19 +338,15 @@ _flag("lease_retry_backoff_s", 0.2)  # lease-request retry pacing
 _flag("actor_call_batch_max", 64)  # specs per PushTaskBatch frame
 
 # --- submission/completion fast path (ISSUE 18) ------------------------------
-# Master switch for the driver-side fast path: spec-template cache on the
-# per-call submit paths, vectorized submit_many/fn.map, and the batched
-# completion delivery queue. Off = the pre-18 per-call path (the --ab
-# baseline arm in ray_perf flips this per round).
-_flag("submit_fastpath_enabled", True)
+# The driver-side fast path: spec-template cache on the per-call submit
+# paths, vectorized submit_many/fn.map, and the batched completion
+# delivery queue (task replies landing in one loop tick resolve through
+# one memory-store put_batch + one ref-counter pass instead of a lock
+# round trip per return).
 # Frozen spec templates cached per (function id, options hash); cap with
 # clear-on-cap like the callsite cache — real programs have a bounded set
 # of (function, options) signatures, and a clear simply re-freezes.
 _flag("spec_template_cache_max", 512)
-# Batch completion delivery: task replies landing in one loop tick resolve
-# through one memory-store put_batch + one ref-counter pass instead of a
-# lock round trip per return.
-_flag("completion_batch_enabled", True)
 
 # --- round-3 sweep 2: poll cadences + 2PC/bootstrap deadlines ----------------
 _flag("actor_resource_wait_poll_s", 0.1)  # actor waiting on node/PG capacity
@@ -397,9 +367,7 @@ _flag("pg_prepare_timeout_s", 10.0)  # 2PC bundle-prepare RPC deadline
 # --- head-plane durability (ISSUE 8) ----------------------------------------
 # WAL rides next to a file-backed RAY_TPU_GCS_PERSIST store: every
 # authoritative mutation is appended + fsynced BEFORE its RPC is acked,
-# so kill -9 at any point loses nothing acknowledged. Disable to fall
-# back to the debounced-snapshot-only behavior.
-_flag("gcs_wal_enabled", True)
+# so kill -9 at any point loses nothing acknowledged.
 # Group-commit window: appends buffer up to this long so one fsync
 # covers a whole mutation burst. 0 = fsync every batch immediately.
 _flag("gcs_wal_fsync_interval_ms", 2.0)
@@ -429,10 +397,9 @@ _flag("conda_failure_cache_s", 60.0)  # failed-env fast-fail window
 _flag("tpu_chips_per_host_default", 4)
 
 # --- elastic training plane -------------------------------------------------
-# write an in-store shard alongside every disk checkpoint so restarts can
-# restore through the broadcast-tree pull path without disk reads
-_flag("train_in_store_checkpoints", True)
-# in-store sharded checkpoints retained (pinned) by the driver; older
+# An in-store shard rides alongside every disk checkpoint, so restarts
+# restore through the broadcast-tree pull path without disk reads. This
+# many in-store sharded checkpoints stay pinned by the driver; older
 # manifests unpin their shards back to LRU eviction
 _flag("train_in_store_keep", 2)
 # bound on one collective-rendezvous attempt (jax.distributed.initialize

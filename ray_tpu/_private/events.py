@@ -13,8 +13,8 @@ Design constraints, in order:
 
 - **Disabled path ~zero.** With ``task_event_sample_rate == 0`` (the
   default) every instrumentation site is ONE attribute load + branch
-  (``if REC.enabled:``) — no dict building, no clock read.  Verified by
-  ``overhead_probe()`` and the ray_perf events A/B.
+  (``if REC.enabled:``) — no dict building, no clock read.  Measured by
+  ``overhead_probe()``.
 - **kill -9 durable.** The ring is a memory-mapped file of fixed-size
   slots under ``<session>/events/``; every recorded span is already in
   the page cache when the process dies, so a SIGKILL'd worker's last
@@ -585,9 +585,8 @@ def format_trace_tree(spans: List[Dict[str, Any]]) -> str:
 
 def overhead_probe(n: int = 200_000) -> float:
     """ns/op of the DISABLED instrumentation guard — the branch every
-    hot-path site pays when sampling is off. The scale_bench gate
-    multiplies this by the per-task site count and asserts the total is
-    <2% of the measured per-task budget."""
+    hot-path site pays when sampling is off
+    (``tests/test_flight_recorder.py`` bounds it)."""
     probe = SpanRecorder()  # enabled=False, no ring
     t0 = time.perf_counter()
     for _ in range(n):

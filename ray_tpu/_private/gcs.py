@@ -303,8 +303,7 @@ class HeadServer:
             # WAL rides next to a file-backed snapshot: per-mutation
             # durability with group-commit fsync. Redis mode keeps the
             # debounced snapshot (the external store outlives the head).
-            if not persist_path.startswith(("redis://", "rediss://")) \
-                    and CONFIG.gcs_wal_enabled:
+            if not persist_path.startswith(("redis://", "rediss://")):
                 from ray_tpu._private.wal import WriteAheadLog
 
                 self.wal = WriteAheadLog(
@@ -441,10 +440,9 @@ class HeadServer:
             if node is not None:
                 node.alive = False
                 node.recovering = False
-            if CONFIG.node_fence_enabled:
-                self.fenced_incarnations[data["node_id"]] = max(
-                    self.fenced_incarnations.get(data["node_id"], -1),
-                    int(data.get("incarnation", 0)))
+            self.fenced_incarnations[data["node_id"]] = max(
+                self.fenced_incarnations.get(data["node_id"], -1),
+                int(data.get("incarnation", 0)))
         elif op == "pg":
             self.placement_groups[data["pg"]["pg_id"]] = data["pg"]
         elif op == "pg_remove":
@@ -1078,8 +1076,7 @@ class HeadServer:
         # failed over, its leases voided). Letting it back in after the
         # partition heals would resurrect zombie state — reject, and the
         # agent self-terminates on seeing the verdict.
-        if CONFIG.node_fence_enabled and \
-                incarnation <= self.fenced_incarnations.get(node_id, -1):
+        if incarnation <= self.fenced_incarnations.get(node_id, -1):
             from ray_tpu._private.event import report_event
 
             report_event("WARNING", "NODE_FENCED",
@@ -1419,13 +1416,12 @@ class HeadServer:
         # in-flight placement commitments to a dead node are moot
         for actor_id in list(self._committed_nodes.get(node.node_id, ())):
             self._uncommit_placement(actor_id)
-        if CONFIG.node_fence_enabled:
-            # fence THIS incarnation: a later re-register from it (the
-            # partition healed) is rejected; a fresh boot (higher
-            # incarnation) may rejoin under the same node_id
-            self.fenced_incarnations[node.node_id] = max(
-                self.fenced_incarnations.get(node.node_id, -1),
-                node.incarnation)
+        # fence THIS incarnation: a later re-register from it (the
+        # partition healed) is rejected; a fresh boot (higher
+        # incarnation) may rejoin under the same node_id
+        self.fenced_incarnations[node.node_id] = max(
+            self.fenced_incarnations.get(node.node_id, -1),
+            node.incarnation)
         from ray_tpu._private.event import report_event
 
         report_event("ERROR", "NODE_DEAD",

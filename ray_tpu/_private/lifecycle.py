@@ -474,16 +474,25 @@ def fate_share_with_parent(
 
 
 def terminate_tree(procs: List, sigterm_timeout_s: float = 2.0) -> None:
-    """SIGTERM (by pgid when possible) then SIGKILL a set of handles with
-    ``pid``/``poll()``, and return when ``poll()`` says each has ended:
-    a ``Popen``'s reaps its child, and the agent's stand-in for another
-    process's child asks the process table, where a zombie still is.
+    """SIGTERM then SIGKILL a set of handles with ``pid``/``poll()``, and
+    return when ``poll()`` says each has ended: a ``Popen``'s reaps its
+    child, and the agent's stand-in for another process's child asks the
+    process table, where a zombie still is. A process that leads its
+    group (every daemon and worker this runtime starts: ``setsid`` or
+    ``start_new_session``) is signalled by group, which is its tree; one
+    that is a member of somebody else's group (a worker the agent did not
+    start, such as a C++ worker run from a user's shell) is signalled
+    alone: its group is its starter's, and a SIGTERM to it ends the shell,
+    or the test run, that started it.
     Shared by `Node.stop`, the agent's worker teardown and tests."""
 
     def signal_all(live: List, sig: int) -> None:
         for p in live:
             try:
-                os.killpg(os.getpgid(p.pid), sig)
+                if os.getpgid(p.pid) == p.pid:
+                    os.killpg(p.pid, sig)
+                else:
+                    os.kill(p.pid, sig)
             except (ProcessLookupError, PermissionError, OSError):
                 try:
                     (p.terminate if sig == signal.SIGTERM else p.kill)()

@@ -4,17 +4,11 @@ single-client sync/async tasks, 1:1 and n:n actor calls, put/get).
 
 Run: ``python -m ray_tpu._private.ray_perf [--filter substr]``
 Prints one line per benchmark: ``name: N ops/s`` plus a JSON summary.
-
-``--ab`` runs the alternating A/B mode (ISSUE 18): fast path vs legacy
-path interleaved per pair in the SAME process, so the printed deltas obey
-the same-day rule — never compare a number measured today against one
-recorded on a different day or box; shared-core machines drift too much.
 """
 
 from __future__ import annotations
 
 import json
-import os
 import statistics
 import time
 from typing import Callable, Dict, List
@@ -254,124 +248,10 @@ def main(filter_substr: str = "") -> Dict[str, float]:
     return results
 
 
-_AB_KNOBS = ("RAY_TPU_SUBMIT_FASTPATH_ENABLED",
-             "RAY_TPU_COMPLETION_BATCH_ENABLED")
-
-
-def run_ab(pairs: int = 3, n: int = 2000) -> Dict:
-    """Alternating A/B mode (ISSUE 18): each pair runs arm A (submit
-    fast path + batched completion ON) then arm B (both OFF) back to
-    back in the same interpreter, and the delta is computed per pair —
-    the codified same-day rule. CONFIG reads env per access, so
-    flipping the env vars switches the live path with no restart.
-
-    Three benches per arm:
-      - many_tasks: n tasks through fn.map (A) vs the same fn.map call,
-        which falls back to a per-call submit loop when the knob is off
-        (B) — identical API, identical result, only the driver path
-        differs. Reports e2e tasks/s AND main-thread submit µs/call.
-      - 1:1 actor calls async: n handle.method.remote() + one get.
-      - 1:1 actor calls sync: submit-get round trips (parity check —
-        the fast path must not tax the latency path).
-    """
-    import ray_tpu
-
-    if not ray_tpu.is_initialized():
-        ray_tpu.init(num_cpus=4)
-
-    @ray_tpu.remote
-    def noop1(i):
-        pass
-
-    @ray_tpu.remote
-    class Actor:
-        def noop(self):
-            pass
-
-    a = Actor.remote()
-    ray_tpu.get(a.noop.remote(), timeout=60)
-    ray_tpu.get(noop1.map(range(4)), timeout=60)
-
-    def set_arm(on: bool) -> None:
-        for k in _AB_KNOBS:
-            os.environ[k] = "1" if on else "0"
-        # drain stragglers from the previous arm so its completion work
-        # does not bleed into this arm's numbers (one shared core)
-        ray_tpu.get(a.noop.remote(), timeout=60)
-        time.sleep(0.2)
-
-    def run_arm() -> Dict[str, float]:
-        t0 = time.perf_counter()
-        refs = noop1.map(range(n))
-        t_submit = time.perf_counter()
-        ray_tpu.get(refs, timeout=600)
-        t_done = time.perf_counter()
-        arm = {
-            "many_tasks_submit_us": (t_submit - t0) / n * 1e6,
-            "many_tasks_per_s": n / (t_done - t0),
-        }
-        t0 = time.perf_counter()
-        ray_tpu.get([a.noop.remote() for _ in range(n)], timeout=600)
-        arm["actor_async_per_s"] = n / (time.perf_counter() - t0)
-        t0 = time.perf_counter()
-        for _ in range(200):
-            ray_tpu.get(a.noop.remote())
-        arm["actor_sync_per_s"] = 200 / (time.perf_counter() - t0)
-        return arm
-
-    saved = {k: os.environ.get(k) for k in _AB_KNOBS}
-    pair_rows: List[Dict] = []
-    try:
-        for i in range(pairs):
-            set_arm(True)
-            arm_a = run_arm()
-            set_arm(False)
-            arm_b = run_arm()
-            row = {"pair": i, "A": {k: round(v, 2) for k, v in arm_a.items()},
-                   "B": {k: round(v, 2) for k, v in arm_b.items()},
-                   "delta": {k: round(arm_a[k] / arm_b[k], 3)
-                             for k in arm_a if arm_b[k]}}
-            pair_rows.append(row)
-            print(json.dumps(row))
-    finally:
-        for k, v in saved.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-        ray_tpu.kill(a)
-
-    summary = {
-        "pairs": pairs,
-        "n": n,
-        "median_delta": {
-            k: round(statistics.median(r["delta"][k] for r in pair_rows), 3)
-            for k in pair_rows[0]["delta"]
-        } if pair_rows else {},
-        "median_A": {
-            k: round(statistics.median(r["A"][k] for r in pair_rows), 2)
-            for k in pair_rows[0]["A"]
-        } if pair_rows else {},
-        "median_B": {
-            k: round(statistics.median(r["B"][k] for r in pair_rows), 2)
-            for k in pair_rows[0]["B"]
-        } if pair_rows else {},
-    }
-    print(json.dumps({"ab_summary": summary}))
-    return {"pairs": pair_rows, "summary": summary}
-
-
 if __name__ == "__main__":
     import argparse
 
     parser = argparse.ArgumentParser()
     parser.add_argument("--filter", default="")
-    parser.add_argument("--ab", action="store_true",
-                        help="alternating fast-path A/B mode (ISSUE 18)")
-    parser.add_argument("--pairs", type=int, default=3)
-    parser.add_argument("--n", type=int, default=2000)
     args = parser.parse_args()
-    if args.ab:
-        run_ab(args.pairs, args.n)
-    else:
-        main(args.filter)
+    main(args.filter)

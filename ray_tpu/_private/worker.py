@@ -46,7 +46,6 @@ from ray_tpu._private.object_store import StoreClient, make_store_client
 from ray_tpu._private.protocol import (
     AsyncRpcClient,
     Connection,
-    ConnectionPool,
     RpcError,
     RpcServer,
 )
@@ -722,7 +721,6 @@ class Worker:
         # shape), so it only appends here (deque: lock-free under the
         # GIL) and the loop flushes
         self._pending_unpins: deque = deque()
-        self._owner_conn_pool = ConnectionPool()
         # Multiplexed direct-call plane (ISSUE 11): ONE session per peer
         # process carries every actor/lease/owner channel as a stream;
         # same-node sessions attach the shm doorbell lane. Identity fns
@@ -910,7 +908,7 @@ class Worker:
         # wait for the reconnect instead of spinning
         self._head_reconnected = asyncio.Event()
         self._head_boot_done = False
-        if self.mode == self.MODE_WORKER and CONFIG.worker_lazy_head_connect:
+        if self.mode == self.MODE_WORKER:
             # boot-path trim (ISSUE 10): the head TCP setup + subscribe
             # round trips move OFF the time-to-leasable critical path —
             # most executor workers touch the head rarely (readiness now
@@ -979,7 +977,7 @@ class Worker:
         self._head_reconnected.set()  # wake outage-queued control calls
 
     async def _connect_head_bg(self) -> None:
-        """Deferred worker-mode head connect (worker_lazy_head_connect):
+        """Deferred worker-mode head connect:
         retries until it lands; the watchdog takes over reconnects only
         after the first successful connect (``_head_boot_done``), so the
         two never race a double connect_tcp onto one client."""
@@ -1068,7 +1066,6 @@ class Worker:
             for client in (self.agent, self.head):
                 if client is not None:
                     await client.aclose()
-            await self._owner_conn_pool.aclose_all()
             await self._mux_pool.aclose_all()
 
         try:
@@ -1452,7 +1449,6 @@ class Worker:
         if addr.get("host") is not None and addr.get("port") is not None:
             # spilled lease requests / owner RPCs in flight to that agent
             # fail now (close() fails their pending futures)
-            self._owner_conn_pool.drop(addr["host"], addr["port"])
             self._mux_pool.drop(addr["host"], addr["port"])
         # every mux session to a process ON that node dies with it
         self._mux_pool.drop_node(node_id)
@@ -1493,31 +1489,20 @@ class Worker:
                              node_id: Optional[str] = None):
         """Open a direct-call channel to a peer process: a stream on the
         shared per-process mux session (ISSUE 11 — the connection is
-        multiplexed, same-node peers ride the shm lane), or a dedicated
-        AsyncRpcClient when the mux plane is disabled."""
-        if CONFIG.direct_call_mux_enabled:
-            return await self._mux_pool.stream(
-                addr["host"], addr["port"], label=label,
-                peer_node_id=node_id or addr.get("node_id"))
-        client = AsyncRpcClient()
-        await client.connect_tcp(addr["host"], addr["port"])
-        client.start_idle_monitor(CONFIG.client_idle_deadline_s)
-        return client
+        multiplexed, same-node peers ride the shm lane)."""
+        return await self._mux_pool.stream(
+            addr["host"], addr["port"], label=label,
+            peer_node_id=node_id or addr.get("node_id"))
 
     async def _owner_client(self, addr: Dict):
-        # shared race-guarded pool: concurrent spillback leases to one
-        # agent used to both connect and leak the overwritten loser's
-        # read loop — the bench-tail "second client in the connection
-        # pool" destroyed-pending warning. With the mux plane enabled
-        # the channel is the session's shared owner stream, so owner
+        # the channel is the mux session's shared owner stream, so owner
         # callbacks and actor/lease traffic to one process share ONE
-        # socket pair.
-        if CONFIG.direct_call_mux_enabled:
-            sess = await self._mux_pool.session(
-                addr["host"], addr["port"],
-                peer_node_id=addr.get("node_id"))
-            return sess.shared_stream("owner")
-        return await self._owner_conn_pool.get(addr["host"], addr["port"])
+        # socket pair (and concurrent spillback leases to one agent
+        # cannot both connect)
+        sess = await self._mux_pool.session(
+            addr["host"], addr["port"],
+            peer_node_id=addr.get("node_id"))
+        return sess.shared_stream("owner")
 
     # ------------------------------------------------------------------ put
     def put(self, value: Any) -> ObjectRef:
@@ -2299,66 +2284,20 @@ class Worker:
                         zip(kwargs.keys(),
                             self._build_args(tuple(kwargs.values())))}
                        if kwargs else {})
-        if CONFIG.submit_fastpath_enabled:
-            tpl = self._task_template(
-                function, num_returns, resources, max_retries,
-                retry_exceptions, scheduling_strategy, placement_group,
-                placement_group_bundle_index, runtime_env, name)
-            spec = tpl.instantiate(
-                task_id.binary(), wire_args, wire_kwargs,
-                trace_ctx=self._trace_for_submit(),
-                # stamped at FIRST submission and replayed verbatim, so a
-                # lineage re-execution seeds the task body's RNG
-                # identically and reproduces byte-identical returns
-                # (ISSUE 17)
-                replay_seed=_replay_seed(task_id.binary()))
-        else:
-            spec = self._build_task_spec_slow(
-                function, task_id, wire_args, wire_kwargs, num_returns,
-                resources, max_retries, retry_exceptions,
-                scheduling_strategy, placement_group,
-                placement_group_bundle_index, runtime_env, name)
+        tpl = self._task_template(
+            function, num_returns, resources, max_retries,
+            retry_exceptions, scheduling_strategy, placement_group,
+            placement_group_bundle_index, runtime_env, name)
+        spec = tpl.instantiate(
+            task_id.binary(), wire_args, wire_kwargs,
+            trace_ctx=self._trace_for_submit(),
+            # stamped at FIRST submission and replayed verbatim, so a
+            # lineage re-execution seeds the task body's RNG
+            # identically and reproduces byte-identical returns
+            # (ISSUE 17)
+            replay_seed=_replay_seed(task_id.binary()))
         return self._finish_submit(spec, task_id, "task:",
                                    self._submit_to_pool_sync)
-
-    def _build_task_spec_slow(
-            self, function, task_id, wire_args, wire_kwargs, num_returns,
-            resources, max_retries, retry_exceptions, scheduling_strategy,
-            placement_group, placement_group_bundle_index, runtime_env,
-            name) -> TaskSpec:
-        """Template-free spec construction — the pre-18 per-call path,
-        kept live behind ``submit_fastpath_enabled=0`` (the ray_perf
-        ``--ab`` baseline arm)."""
-        from ray_tpu._private.function_table import function_descriptor
-        from ray_tpu._private.resources import ResourceSet
-
-        fid, blob, fname = function_descriptor(function, self)
-        resources = dict(resources or {})
-        resources.setdefault("CPU", 1.0)
-        pg = None
-        if placement_group is not None:
-            pg = [placement_group.id_hex, max(placement_group_bundle_index, 0)]
-        return TaskSpec(
-            task_id=task_id.binary(),
-            job_id=self.job_id.binary(),
-            task_type=NORMAL_TASK,
-            function_id=fid,
-            function_blob=blob,
-            function_name=name or fname,
-            args=wire_args,
-            kwargs=wire_kwargs,
-            num_returns=num_returns,
-            resources=ResourceSet(resources).to_wire(),
-            owner_addr=self.direct_addr(),
-            max_retries=max_retries,
-            retry_exceptions=retry_exceptions,
-            scheduling_strategy=_strategy_wire(scheduling_strategy),
-            placement_group_id=(pg[0] if pg else None),
-            placement_group_bundle_index=(pg[1] if pg else -1),
-            runtime_env=runtime_env,
-            trace_ctx=self._trace_for_submit(),
-            replay_seed=_replay_seed(task_id.binary()),
-        )
 
     def _finish_submit(self, spec: TaskSpec, task_id: TaskID,
                        creator_prefix: str, post_target,
@@ -2427,19 +2366,6 @@ class Worker:
                 "(num_returns='streaming')")
         if max_retries < 0:
             max_retries = CONFIG.task_max_retries_default
-        if not CONFIG.submit_fastpath_enabled:
-            return [
-                self.submit_task(
-                    function, args, (kwargs_list[i] if kwargs_list else {}),
-                    num_returns=num_returns, resources=resources,
-                    max_retries=max_retries,
-                    retry_exceptions=retry_exceptions,
-                    scheduling_strategy=scheduling_strategy,
-                    placement_group=placement_group,
-                    placement_group_bundle_index=placement_group_bundle_index,
-                    runtime_env=runtime_env, name=name)
-                for i, args in enumerate(args_list)
-            ]
         self._n_tasks_submitted = \
             getattr(self, "_n_tasks_submitted", 0) + n
         tpl = self._task_template(
@@ -3211,30 +3137,11 @@ class Worker:
             max_retries = 2 ** 31
         wire_args = self._build_args(args) if args else []
         wire_kwargs = self._build_kwargs(kwargs) if kwargs else {}
-        if CONFIG.submit_fastpath_enabled:
-            tpl = self._actor_template(actor_id, method_name, num_returns,
-                                       max_retries)
-            spec = tpl.instantiate(
-                task_id.binary(), wire_args, wire_kwargs,
-                trace_ctx=self._trace_for_submit(), seq=seq)
-        else:
-            spec = TaskSpec(
-                task_id=task_id.binary(),
-                job_id=self.job_id.binary(),
-                task_type=ACTOR_TASK,
-                function_id=b"\x00" * 16,
-                function_name=method_name,
-                args=wire_args,
-                kwargs=wire_kwargs,
-                num_returns=num_returns,
-                resources={},
-                owner_addr=self.direct_addr(),
-                actor_id=actor_id.binary(),
-                actor_method=method_name,
-                seq=seq,
-                max_retries=max_retries,
-                trace_ctx=self._trace_for_submit(),
-            )
+        tpl = self._actor_template(actor_id, method_name, num_returns,
+                                   max_retries)
+        spec = tpl.instantiate(
+            task_id.binary(), wire_args, wire_kwargs,
+            trace_ctx=self._trace_for_submit(), seq=seq)
         return self._finish_submit(spec, task_id, "actor:", st.enqueue, self)
 
     def _actor_template(self, actor_id: ActorID, method_name: str,
@@ -3284,13 +3191,6 @@ class Worker:
                 "submit_actor_tasks_many does not support streaming calls")
         if max_retries < 0:
             max_retries = 2 ** 31
-        if not CONFIG.submit_fastpath_enabled:
-            return [
-                self.submit_actor_task(aid, method, args, kwargs,
-                                       num_returns=num_returns,
-                                       max_retries=max_retries)
-                for aid, method, args, kwargs in calls
-            ]
         self._n_actor_calls = getattr(self, "_n_actor_calls", 0) + n
         t0 = time.time()
         tc = self._trace_for_submit()  # ONE stamp for the whole batch
@@ -3626,7 +3526,7 @@ class _LeasePool:
         # k leases wanted in one pump ride ONE RequestWorkerLeaseBatch
         # frame (grants stream back per entry); PG leases keep the single
         # path — they resolve their target agent per request
-        if n > 1 and not self.pg and CONFIG.lease_batch_enabled:
+        if n > 1 and not self.pg:
             spawn_tracked(self._request_lease_batch(n), "lease-request")
         else:
             for _ in range(n):
@@ -3918,15 +3818,12 @@ class _LeasePool:
                 self.worker._on_task_failure(record, e, retriable=False)
             self._after_stream_item(conn)
 
-        if CONFIG.completion_batch_enabled:
-            # items from one BatchItems frame (or several frames in one
-            # read pass) resolve together via the worker's completion
-            # queue — one memory-store/ref-counter pass for the burst
-            w = self.worker
-            batches[bid] = lambda i, reply: \
-                w._completion_enqueue(on_item, i, reply)
-        else:
-            batches[bid] = on_item
+        # items from one BatchItems frame (or several frames in one
+        # read pass) resolve together via the worker's completion
+        # queue — one memory-store/ref-counter pass for the burst
+        w = self.worker
+        batches[bid] = lambda i, reply: \
+            w._completion_enqueue(on_item, i, reply)
         try:
             fut = client.call_future(
                 "PushTaskBatchStream",
@@ -4293,11 +4190,8 @@ class _ActorState:
                     record.spec.function_name)
                 worker._on_task_failure(record, e, retriable=False)
 
-        if CONFIG.completion_batch_enabled:
-            batches[bid] = lambda i, reply: \
-                worker._completion_enqueue(on_item, i, reply)
-        else:
-            batches[bid] = on_item
+        batches[bid] = lambda i, reply: \
+            worker._completion_enqueue(on_item, i, reply)
         for r in records:
             if r.spec.trace_ctx is not None:
                 _span_since(r, "enqueue_wait")

@@ -499,8 +499,8 @@ class StreamingShuffleOperator(PhysicalOperator):
         if len(bundles) == 1:
             self._dispatch_map(bundles[0])
             return
-        # sequential salts in list order — byte-identical to the popleft
-        # loop this replaces (the sha256 asserts in scale_bench hold)
+        # sequential salts in list order — byte-identical to dispatching
+        # the bundles one by one
         base = len(self._maps)
         salts = [base + i for i in range(len(bundles))]
         with _ev.trace_parent(self._trace):

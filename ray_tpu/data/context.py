@@ -39,8 +39,7 @@ class DataContext:
     backpressure_policies: Optional[list] = None
     # --- streaming shuffle (ISSUE 12) ---
     # False = legacy materializing AllToAll exchange for shuffle/sort
-    streaming_shuffle: bool = dataclasses.field(
-        default_factory=lambda: bool(CONFIG.data_streaming_shuffle))
+    streaming_shuffle: bool = True
     # byte budget over admitted-but-unfinished reducers' input shards
     shuffle_max_inflight_shard_bytes: int = dataclasses.field(
         default_factory=lambda: int(CONFIG.data_shuffle_inflight_bytes))

@@ -94,8 +94,6 @@ class _TrainSession:
         shard_step = None
         shard_nbytes = 0
         if checkpoint is not None:
-            from ray_tpu._private.config import CONFIG
-
             if isinstance(checkpoint, InStoreCheckpoint):
                 # store-only: one zero-copy put of the packed shard; the
                 # driver re-owns + pins it in CheckpointManager. Nothing
@@ -108,17 +106,16 @@ class _TrainSession:
                 self._shard_refs[shard_step] = shard_ref
             else:
                 ckpt_dir = self._persist_to_trial_dir(checkpoint)
-                if CONFIG.train_in_store_checkpoints:
-                    # disk checkpoints also get an in-store shard so a
-                    # restart can restore without disk reads
-                    import ray_tpu
-                    from ray_tpu.train._internal.util import pack_dir
+                # disk checkpoints also get an in-store shard so a
+                # restart can restore without disk reads
+                import ray_tpu
+                from ray_tpu.train._internal.util import pack_dir
 
-                    buf = pack_dir(checkpoint.path)
-                    shard_ref = ray_tpu.put(buf)
-                    shard_step = self.iteration
-                    shard_nbytes = len(memoryview(buf).cast("B"))
-                    self._shard_refs[shard_step] = shard_ref
+                buf = pack_dir(checkpoint.path)
+                shard_ref = ray_tpu.put(buf)
+                shard_step = self.iteration
+                shard_nbytes = len(memoryview(buf).cast("B"))
+                self._shard_refs[shard_step] = shard_ref
         self.iteration += 1
         self.result_queue.put(
             TrainingResult(TrainingResult.REPORT, metrics, ckpt_dir,

@@ -18,7 +18,6 @@ import os
 import random
 import signal
 import threading
-import time
 from typing import Callable, Dict, List, Optional
 
 import ray_tpu
@@ -162,67 +161,6 @@ class DaemonKiller(ResourceKiller):
             return f"{target.get('role', 'daemon')} pid={target['pid']}"
         except ProcessLookupError:
             return None
-
-
-class HeadKiller(ResourceKiller):
-    """``kill -9`` the head control plane (GCS) at random points while a
-    workload runs, then restart it after ``downtime_s`` — the chaos probe
-    for the durable head plane (WAL + recovery reconciliation, ISSUE 8).
-    Operates on a ``cluster_utils.Cluster`` head node whose process
-    handle the driver owns; the restarted head resumes from the same
-    ``RAY_TPU_GCS_PERSIST`` store and agents/drivers re-register through
-    their watchdogs."""
-
-    def __init__(self, cluster, downtime_s: float = 0.5,
-                 interval_s: float = 5.0, max_kills: Optional[int] = None,
-                 seed: Optional[int] = None, persist: Optional[str] = None):
-        super().__init__(interval_s, max_kills, seed)
-        self.cluster = cluster
-        self.downtime_s = downtime_s
-        self.persist = persist or os.environ.get("RAY_TPU_GCS_PERSIST", "")
-        self.restarts = 0
-
-    def find_target(self):
-        node = self.cluster.head_node
-        if node is None or node.head_proc is None \
-                or node.head_proc.poll() is not None:
-            return None
-        return node
-
-    def kill_target(self, target) -> Optional[str]:
-        target.head_proc.kill()  # SIGKILL: no flush, no atexit
-        target.head_proc.wait()
-        time.sleep(self.downtime_s)
-        self.restart_head(target)
-        return f"head kill -9 + restart #{self.restarts}"
-
-    def restart_head(self, node) -> None:
-        import subprocess
-        import sys
-
-        from ray_tpu._private import lifecycle
-        from ray_tpu._private.config import keep_off_accelerator
-
-        self.restarts += 1
-        log = open(os.path.join(node.session_dir, "logs",
-                                f"head_chaos_{self.restarts}.log"), "ab")
-        env = keep_off_accelerator(dict(os.environ))
-        env["RAY_TPU_SESSION_DIR"] = node.session_dir
-        env["RAY_TPU_PARENT_PID"] = str(os.getpid())
-        if self.persist:
-            env["RAY_TPU_GCS_PERSIST"] = self.persist
-        node.head_proc = subprocess.Popen(
-            [sys.executable, "-m", "ray_tpu._private.gcs",
-             "--session-dir", node.session_dir,
-             "--port", str(node.head_port)],
-            stdout=log, stderr=log, env=env,
-            start_new_session=True)
-        # spawner-side registration (the child re-registers idempotently):
-        # node.stop()'s sweep must reap the chaos-restarted head even if
-        # it is killed again before its own register_self runs
-        lifecycle.register_process(node.session_dir, "gcs",
-                                   node.head_proc.pid)
-        log.close()
 
 
 class NetworkPartitioner(ResourceKiller):

@@ -21,10 +21,21 @@ def _cli(*args, timeout=120):
         capture_output=True, text=True, timeout=timeout, env=env, cwd=REPO)
 
 
+def _live_sessions() -> set:
+    """Paths of the sessions `status` lists as LIVE (its lifecycle view)."""
+    r = _cli("status")
+    assert r.returncode == 0, r.stdout + r.stderr
+    return {line.split()[1] for line in r.stdout.splitlines()
+            if line.startswith("  LIVE ")}
+
+
 def test_cli_lifecycle():
+    others = _live_sessions()  # a neighbour's clusters are not this test's
     r = _cli("start", "--head", "--num-cpus", "2")
     assert r.returncode == 0, r.stdout + r.stderr
     try:
+        mine = _live_sessions() - others
+        assert mine, "`start --head` left no live session"
         assert os.path.exists("/tmp/ray_tpu_current_head")
         assert ":" in open("/tmp/ray_tpu_current_head").read()
 
@@ -43,7 +54,5 @@ def test_cli_lifecycle():
     assert r.returncode == 0, r.stdout + r.stderr
 
     # headless status is now valid (lifecycle view): it must report the
-    # stopped cluster as fully reaped — zero live sessions
-    r = _cli("status")
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert "live sessions: 0" in r.stdout
+    # stopped cluster as fully reaped — no session this test started lives
+    assert not (mine & _live_sessions())

@@ -7,6 +7,13 @@ import pytest
 
 import ray_tpu
 
+# The in-process client server keeps every ref of a connected client on the
+# client's behalf (`ClientServer._track`) and drops them when the client
+# releases them, which it does in a batch with its NEXT call, or disconnects
+# (`_on_disconnect`): a ref a test let go is still the server's at that
+# test's teardown, by design, and none outlives the client.
+pytestmark = pytest.mark.ref_leaks_ok
+
 
 @pytest.fixture(scope="module")
 def client_ctx():

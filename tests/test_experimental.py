@@ -78,14 +78,6 @@ class TestTimelineTracing:
         assert xs, "no complete task events"
         assert all(e["dur"] >= 0 for e in xs)
 
-    def test_span_mirrors_to_timeline(self, ray4):
-        from ray_tpu.util.tracing import span
-
-        with span("unit-span"):
-            pass
-        tl = ray_tpu.timeline()
-        assert any(e.get("name") == "span::unit-span" for e in tl)
-
 
 class TestPool:
     def test_map_and_apply(self, ray4):

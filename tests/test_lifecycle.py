@@ -169,6 +169,68 @@ def test_wait_gone_sees_a_zombie_and_ends_at_its_limit():
     assert not _in_process_table(rec)
 
 
+_TERMINATE_TREE_CALLER = """\
+import os, subprocess, sys, time
+from ray_tpu._private import lifecycle
+
+leader = sys.argv[1] == "leader"
+# the child starts a grandchild in its own group and waits for it
+child = subprocess.Popen(
+    [sys.executable, "-c",
+     "import subprocess, sys, time\\n"
+     "g = subprocess.Popen([sys.executable, '-c',"
+     " 'import time; time.sleep(120)'])\\n"
+     "print(g.pid, flush=True)\\n"
+     "time.sleep(120)\\n"],
+    stdout=subprocess.PIPE, text=True, start_new_session=leader)
+grandchild = int(child.stdout.readline())
+
+
+class NotMyChild:  # the agent's stand-in for a worker it did not start
+    pid = child.pid
+
+    def poll(self):
+        return child.poll()
+
+
+lifecycle.terminate_tree([NotMyChild()], sigterm_timeout_s=5.0)
+time.sleep(0.3)
+try:  # running, or defunct until pid 1 has reaped it
+    with open(f"/proc/{grandchild}/stat") as f:
+        state = f.read().rsplit(")", 1)[1].split()[0]
+except OSError:
+    state = "Z"
+print("grandchild gone" if state == "Z" else "grandchild alive", flush=True)
+if state != "Z":
+    os.kill(grandchild, 9)
+print("caller survived", flush=True)
+"""
+
+
+@pytest.mark.parametrize("child", ["member", "leader"])
+def test_terminate_tree_signals_a_group_only_through_its_leader(child):
+    """A process that leads its group is its tree and dies with it; a
+    process that is a member of its STARTER's group (a C++ worker run
+    from a shell or from a test, registered with the agent) is signalled
+    alone. Signalled by group, it took its starter along: an agent's
+    teardown ended the whole pytest run that had started the worker
+    (rc 143, two whole runs of five; PR 51)."""
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join(
+        [REPO] + ([os.environ["PYTHONPATH"]]
+                  if os.environ.get("PYTHONPATH") else [])))
+    # a session of its own: whatever the caller signals is not this run
+    proc = subprocess.run(
+        [sys.executable, "-c", _TERMINATE_TREE_CALLER, child],
+        capture_output=True, text=True, timeout=120, env=env,
+        start_new_session=True)
+    assert proc.returncode == 0, (proc.returncode, proc.stdout, proc.stderr)
+    assert "caller survived" in proc.stdout
+    # a leader's group went with it; a member's group is not the
+    # runtime's to signal, so what the member started lives on
+    want = "grandchild gone" if child == "leader" else "grandchild alive"
+    assert want in proc.stdout, proc.stdout
+
+
 def test_cluster_teardown_reaps_everything():
     import ray_tpu
     from ray_tpu._private import lifecycle
@@ -378,8 +440,8 @@ def _wait_pid_dead(proc, timeout_s: float) -> bool:
 # tests guard. So the inner conftest first points the driver side's two
 # root lookups at a root of its own (daemons are handed their
 # session_dir, so nothing else needs it), and only then loads the hooks.
-# Inner test files carry FAST_FILES names so that they are tier-1 tests
-# to the hooks (limit, ref-leak gate) like the files they stand for.
+# Inner test files are tier-1 tests to the hooks (limit, ref-leak gate), as
+# every file is that SLOW_FILES does not name.
 # ---------------------------------------------------------------------------
 _INNER_CONFTEST = """\
 import importlib.util

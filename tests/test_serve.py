@@ -124,17 +124,21 @@ def test_replica_death_recovery(serve_cluster):
         def __call__(self, cmd):
             if cmd == "die":
                 import os
+                import threading
 
-                os._exit(1)
+                # after the reply: a request that dies with its replica is
+                # retried on the replacement (the router's rule for
+                # transport errors) and would kill that one too, again and
+                # again from the handle's request pool, for its 60 s
+                threading.Timer(0.2, os._exit, args=(1,)).start()
+                return "dying"
             return "alive"
 
     handle = serve.run(Fragile.bind(), name="fragile",
                        route_prefix="/fragile")
     assert handle.remote("ping").result(timeout_s=30) == "alive"
-    try:
-        handle.remote("die").result(timeout_s=10)
-    except Exception:
-        pass
+    assert handle.remote("die").result(timeout_s=30) == "dying"
+    time.sleep(0.5)  # the replica is gone
     # the controller health-checks, replaces the replica, traffic resumes
     deadline = time.monotonic() + 60
     ok = False

@@ -183,22 +183,6 @@ def test_template_cache_cap_eviction(fastpath_cluster, monkeypatch):
         assert len(w._spec_templates) <= 4
 
 
-def test_knob_off_parity(fastpath_cluster, monkeypatch):
-    """With the fast path and batched completion disabled, the SAME
-    map()/submit_many API runs the legacy per-call path and produces
-    identical results — the knob changes the driver cost, never the
-    answer."""
-    @ray_tpu.remote
-    def cube(i):
-        return i ** 3
-
-    want = [i ** 3 for i in range(12)]
-    assert ray_tpu.get(cube.map(range(12)), timeout=120) == want
-    monkeypatch.setenv("RAY_TPU_SUBMIT_FASTPATH_ENABLED", "0")
-    monkeypatch.setenv("RAY_TPU_COMPLETION_BATCH_ENABLED", "0")
-    assert ray_tpu.get(cube.map(range(12)), timeout=120) == want
-
-
 def test_batched_ownership_and_lineage_bookkeeping(fastpath_cluster):
     """Batched submissions get the SAME owner-side bookkeeping as
     per-call ones (PR 17 parity): owned metadata with a task: creator,
@@ -233,7 +217,6 @@ def _kill_and_replace(cluster, node, res_key):
     return replacement
 
 
-@pytest.mark.slow
 def test_lineage_reconstruction_of_batched_submissions(fastpath_cluster):
     """Kill the node holding every return of a BATCHED submission:
     the owner replays each lost task under its original id and seed,

@@ -296,7 +296,6 @@ def test_headserver_snapshot_plus_wal_equals_full_replay(tmp_path):
 # ---------------------------------------------------------------------------
 # randomized kill-offset fuzz
 # ---------------------------------------------------------------------------
-@pytest.mark.slow
 def test_fuzz_random_kill_offsets(tmp_path):
     """Truncate the log at EVERY kind of offset a kill -9 could leave
     behind: replay must never raise and must always yield a seq-dense
@@ -323,7 +322,6 @@ def test_fuzz_random_kill_offsets(tmp_path):
         assert replay(path)[-1][1] == "again"
 
 
-@pytest.mark.slow
 def test_fuzz_random_corruption(tmp_path):
     """Flip one byte anywhere: replay yields an intact prefix (checksums
     catch the flip) and never raises."""
