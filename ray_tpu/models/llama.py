@@ -57,6 +57,19 @@ Design notes (why this is not a torch translation):
   state]`` float32. With it come that family's scalars: no rope
   (``use_rope``), and ``embedding_multiplier``, ``residual_multiplier``,
   ``logits_scaling`` and ``attention_multiplier``.
+- A seventh operator, ``"kda"`` (Kimi delta attention, Kimi Linear's gated
+  delta rule with a decay a channel, as Ling-3.0-flash's ``bailing_hybrid``
+  has it; ``_kda``): one in-projection to the queries, keys, values, the
+  decay's gate and the output's gate, depthwise causal taps and a SiLU over
+  ``[q | k | v]`` (``_causal_taps`` again), L2-normed queries and keys, a
+  bounded log-decay a channel, the delta rule (``ops.kda.kda``: a chunked
+  Pallas kernel, or the recurrence itself), an RMSNorm a head times a
+  sigmoid gate and an out-projection. Its state in ``llama_decode`` is the
+  last ``kda_conv_kernel - 1`` rows of ``[q | k | v]`` before their taps
+  and the rule's state ``[B, heads, head_dim, head_dim]`` float32. With it
+  come latent attention with full-rank queries (``q_lora_rank`` 0: one
+  matrix ``wq``, no ``wq_a`` and no norm between) and a head-wise gate on
+  the plain ``latent`` operator too.
 - Attention dispatches to ``ray_tpu.ops`` (Pallas flash attention on TPU,
   reference einsum path elsewhere; ring attention when the seq axis > 1).
 - bfloat16 activations / fp32 params+optimizer by default: MXU-native.
@@ -288,6 +301,21 @@ class LlamaConfig:
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
     attention_multiplier: float = 0.0
+    # Kimi delta attention (Ling-3.0-flash has it). layer_types' "kda":
+    # kda_heads heads of kda_head_dim key and value channels each, the
+    # queries, keys and values through kda_conv_kernel depthwise causal
+    # taps without a bias and a SiLU, queries and keys L2-normed a head,
+    # the log-decay a channel kda_lower_bound * sigmoid(exp(A_log) * (a +
+    # dt_bias)), in (kda_lower_bound, 0), the rule run in chunks of
+    # kda_chunk by the kernel. router_group_score: what a group of the
+    # group-limited choice is scored by, its best expert ("max") or the
+    # sum of its two best ("top2", DeepSeek-V3's noaux_tc).
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv_kernel: int = 4
+    kda_chunk: int = 64
+    kda_lower_bound: float = -5.0
+    router_group_score: str = "max"
 
     @staticmethod
     def llama2_7b_smoke() -> "LlamaConfig":
@@ -314,8 +342,8 @@ class LlamaConfig:
 
     def layer_kinds(self) -> Tuple[str, ...]:
         """Each layer's kind, ``<operator>_<feed-forward>``: ``attention``,
-        ``conv``, ``latent``, ``window``, ``indexed`` or ``mamba``, then
-        ``routed`` (experts) or ``dense``."""
+        ``conv``, ``latent``, ``window``, ``indexed``, ``mamba`` or ``kda``,
+        then ``routed`` (experts) or ``dense``."""
         ops = tuple(self.layer_types) or ("full_attention",) * self.num_layers
         if len(ops) != self.num_layers:
             raise ValueError(f"layer_types names {len(ops)} layers, "
@@ -323,7 +351,8 @@ class LlamaConfig:
         names = {"full_attention": "attention", "conv": "conv",
                  "latent_attention": "latent",
                  "window_latent_attention": "window",
-                 "indexed_latent_attention": "indexed", "mamba": "mamba"}
+                 "indexed_latent_attention": "indexed", "mamba": "mamba",
+                 "kda": "kda"}
         unknown = sorted(set(ops) - set(names))
         if unknown:
             raise ValueError(f"layer_types {unknown}: expected "
@@ -382,6 +411,13 @@ class LlamaConfig:
         conv = inner + 2 * self.mamba_state
         return inner, conv, inner + conv + self.mamba_heads
 
+    def kda_widths(self) -> Tuple[int, int]:
+        """Kimi delta attention's ``(inner, proj)`` widths: its heads'
+        channels, and what the in-projection makes (``[q | k | v | the
+        decay's gate | the output's gate]``)."""
+        inner = self.kda_heads * self.kda_head_dim
+        return inner, 5 * inner
+
     def dense_width(self) -> int:
         """Width of a dense SwiGLU: the leading dense layers' own in a
         model with experts, whose ``mlp_hidden`` is one expert's."""
@@ -397,17 +433,25 @@ class LlamaConfig:
 
         def latent(operator):
             w = self.latent_widths(operator)
-            gate = h * w.heads if self.head_gate and operator != "latent" \
-                else 0
-            return (h * w.q_rank + w.q_rank
-                    + w.q_rank * w.heads * (w.nope + w.rope)
-                    + h * (w.kv_rank + w.rope) + w.kv_rank
+            gate = h * w.heads if self.head_gate else 0
+            # the queries: a low-rank pair with a norm between, or one matrix
+            queries = (h * w.q_rank + w.q_rank
+                       + w.q_rank * w.heads * (w.nope + w.rope)
+                       if w.q_rank else h * w.heads * (w.nope + w.rope))
+            return (queries + h * (w.kv_rank + w.rope) + w.kv_rank
                     + w.kv_rank * w.heads * (w.nope + w.v)
                     + w.heads * w.v * h + gate)
 
         ih, ihd = self.index_heads, self.index_head_dim
         inner, conv, proj = self.mamba_widths()
+        kda_inner, kda_proj = self.kda_widths()
         half = {"attention": h * (q + 2 * kv) + q * h + norms,
+                # in-projection, beta's, the taps, A_log a head, dt_bias a
+                # channel, the norm's one weight a head's channel, out
+                "kda": (h * kda_proj + h * self.kda_heads
+                        + 3 * kda_inner * self.kda_conv_kernel
+                        + self.kda_heads + kda_inner + self.kda_head_dim
+                        + kda_inner * h),
                 # in-projection, taps and their bias, dt_bias, A_log and D
                 # a head, the gated norm, out-projection
                 "mamba": (h * proj + conv * (self.mamba_conv_kernel + 1)
@@ -591,12 +635,15 @@ def _kind_logical_axes(cfg: LlamaConfig, kind: str) -> Dict[str, Any]:
         if cfg.qk_norm or cfg.qk_head_norm:
             layer.update(q_norm=("norm",), k_norm=("norm",))
     elif operator in LATENT_OPERATORS:  # the ranks stay whole everywhere
-        layer.update(wq_a=("embed", None), q_a_norm=("norm",),
-                     wq_b=(None, "heads", "head_dim"),
-                     wkv_a=("embed", None), kv_a_norm=("norm",),
+        layer.update(wkv_a=("embed", None), kv_a_norm=("norm",),
                      wkv_b=(None, "heads", "head_dim"),
                      wo=("heads", "head_dim", "embed"))
-        if cfg.head_gate and operator != "latent":
+        if cfg.latent_widths(operator).q_rank:
+            layer.update(wq_a=("embed", None), q_a_norm=("norm",),
+                         wq_b=(None, "heads", "head_dim"))
+        else:  # full-rank queries: one matrix
+            layer.update(wq=("embed", "heads", "head_dim"))
+        if cfg.head_gate:
             layer.update(w_head_gate=("embed", "heads"))
         if operator == "indexed":  # the indexer is whole on every device
             layer.update(wi_q=(None, None, None), wi_k=("embed", None),
@@ -607,6 +654,11 @@ def _kind_logical_axes(cfg: LlamaConfig, kind: str) -> Dict[str, Any]:
                      mamba_conv_b=(None,), mamba_dt_bias=(None,),
                      mamba_a_log=(None,), mamba_d=(None,),
                      mamba_norm=("norm",), mamba_out=(None, "embed"))
+    elif operator == "kda":  # its inner widths stay whole everywhere
+        layer.update(kda_in=("embed", None), kda_beta=("embed", None),
+                     kda_conv_w=(None, None), kda_a_log=(None,),
+                     kda_dt_bias=(None,), kda_norm=("norm",),
+                     kda_out=(None, "embed"))
     else:  # the gated short convolution: in-projection, taps, out
         layer.update(conv_in=("embed", "mlp"), conv_w=("mlp", None),
                      conv_out=("mlp", "embed"))
@@ -670,19 +722,23 @@ def init_llama(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
                 return jax.lax.map(lambda lk: norm_init(shape, lk, fan_in),
                                    jax.random.split(k, L))
 
-            layers = {
+            queries = {
                 "wq_a": a_layer_at_a_time((h, qr), ks[0], h),
                 "q_a_norm": jnp.ones((L, qr), pd),
                 "wq_b": a_layer_at_a_time((qr, nl, nope + rope), kq, qr),
+            } if qr else {
+                "wq": a_layer_at_a_time((h, nl, nope + rope), ks[0], h)}
+            layers = {
+                **queries,
                 "wkv_a": a_layer_at_a_time((h, kvr + rope), kk, h),
                 "kv_a_norm": jnp.ones((L, kvr), pd),
                 "wkv_b": a_layer_at_a_time((kvr, nl, nope + vd), ks[2], kvr),
                 "wo": a_layer_at_a_time((nl, vd, h), ks[3], nl * vd),
             }
-            if operator != "latent":
+            if cfg.head_gate or operator == "indexed":
                 kg, kiq, kik, kiw = jax.random.split(
                     jax.random.fold_in(ks[3], 1), 4)
-            if cfg.head_gate and operator != "latent":
+            if cfg.head_gate:
                 layers["w_head_gate"] = norm_init((L, h, nl), kg, h)
             if operator == "indexed":
                 ih, ihd = cfg.index_heads, cfg.index_head_dim
@@ -718,6 +774,47 @@ def init_llama(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
                 "mamba_d": jnp.ones((L, nm), pd),
                 "mamba_norm": jnp.ones((L, inner), pd),
                 "mamba_out": norm_init((L, inner, h), ks[3], inner),
+            }
+        elif operator == "kda":
+            # the gate's leaves are drawn for the memory they give, not as
+            # a training run would start them: with a fan-in scaled gate
+            # and dt_bias at 0 the decay is exp(lower_bound / 2) a position
+            # and a state forgets in two. A channel's memory m is drawn
+            # log-uniform in 10 to 1000 positions and dt_bias set so that
+            # at a = 0 the decay is exp(-1 / m): lower_bound * sigmoid(
+            # exp(A_log) dt_bias) = -1 / m; exp(A_log) a head log-uniform
+            # in 0.5 to 2; the gate's columns at a quarter of fan-in
+            # scale, so that the data moves a channel's log-memory by some
+            # tenths. The taps uniform at fan-in (torch's Conv1d).
+            nk, hd_k, taps = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_conv_kernel
+            inner, proj = cfg.kda_widths()
+            ka, km, kw, kb = jax.random.split(ks[1], 4)
+            a_log = jax.random.uniform(ka, (L, nk), jnp.float32,
+                                       math.log(0.5), math.log(2.0))
+            memory = jnp.exp(jax.random.uniform(
+                km, (L, nk, hd_k), jnp.float32, math.log(10.0),
+                math.log(1000.0)))
+            at_rest = -jnp.log(-cfg.kda_lower_bound * memory - 1.0)
+            in_scale = jnp.ones((proj,), jnp.float32).at[
+                3 * inner:4 * inner].set(0.25)
+
+            def in_projection(lk):
+                return (jax.random.truncated_normal(
+                    lk, -2, 2, (h, proj), jnp.float32)
+                    * h ** -0.5 * in_scale).astype(pd)
+
+            layers = {
+                "kda_in": jax.lax.map(in_projection,
+                                      jax.random.split(ks[0], L)),
+                "kda_beta": norm_init((L, h, nk), kb, h),
+                "kda_conv_w": jax.random.uniform(
+                    kw, (L, 3 * inner, taps), jnp.float32, -taps ** -0.5,
+                    taps ** -0.5).astype(pd),
+                "kda_a_log": a_log.astype(pd),
+                "kda_dt_bias": (at_rest / jnp.exp(a_log)[..., None]
+                                ).reshape(L, inner).astype(pd),
+                "kda_norm": jnp.ones((L, hd_k), pd),
+                "kda_out": norm_init((L, inner, h), ks[3], inner),
             }
         else:
             layers = {
@@ -916,7 +1013,9 @@ def _latent_attention(cfg: LlamaConfig, u: jax.Array,
     """Latent attention (DeepSeek-V2's MLA) on the normed input ``u [B, S,
     H]`` -> (its output ``[B, S, H]``, the state after it or None), at the
     widths of ``operator`` (``LlamaConfig.latent_widths``).
-    ``c_q = RMSNorm(u W_qa)``; ``[q_nope | q_pe] = c_q W_qb`` a head;
+    ``c_q = RMSNorm(u W_qa)``; ``[q_nope | q_pe] = c_q W_qb`` a head (with
+    a ``q_lora_rank`` of 0 ``[q_nope | q_pe] = u W_q``: one matrix, ``wq``,
+    and no norm);
     ``[c_kv | k_pe] = u W_kva``, ``c_kv = RMSNorm(c_kv)``; ``[k_nope | v] =
     c_kv W_kvb`` a head; the rotary parts rotated (``k_pe`` is one row a
     position, every head's); softmax of ``(q_nope k_nope^T + q_pe k_pe^T)
@@ -952,17 +1051,30 @@ def _latent_attention(cfg: LlamaConfig, u: jax.Array,
     nope, kvr = w.nope, w.kv_rank
     scale = latent_softmax_scale(cfg, w)
     S = u.shape[1]
+    if w.topk and not w.q_rank:
+        raise ValueError("an indexed operator's indexer reads the queries' "
+                         "latent row: q_lora_rank cannot be 0")
     with jax.named_scope("latent_attention"):
-        c_q = _rms_norm(jnp.einsum("bsh,hr->bsr", u, lp["wq_a"].astype(dt)),
-                        lp["q_a_norm"], cfg.rms_eps)
-        wq_b, wkv_a = lp["wq_b"].astype(dt), lp["wkv_a"].astype(dt)
-        wkv_b = lp["wkv_b"].astype(dt)
-        if cfg.latent_rescale:
-            c_q = c_q * (cfg.hidden / w.q_rank) ** 0.5
-        q_nope = jnp.einsum("bsr,rnd->bsnd", c_q, wq_b[..., :nope])
-        q_pe = _yarn_rope(
-            jnp.einsum("bsr,rnd->bsnd", c_q, _pairs_apart(wq_b[..., nope:])),
-            positions, w.theta, cfg.rope_scaling)
+        if w.q_rank:
+            c_q = _rms_norm(
+                jnp.einsum("bsh,hr->bsr", u, lp["wq_a"].astype(dt)),
+                lp["q_a_norm"], cfg.rms_eps)
+            wq_b, wkv_a = lp["wq_b"].astype(dt), lp["wkv_a"].astype(dt)
+            wkv_b = lp["wkv_b"].astype(dt)
+            if cfg.latent_rescale:
+                c_q = c_q * (cfg.hidden / w.q_rank) ** 0.5
+            q_nope = jnp.einsum("bsr,rnd->bsnd", c_q, wq_b[..., :nope])
+            q_pe = _yarn_rope(
+                jnp.einsum("bsr,rnd->bsnd", c_q,
+                           _pairs_apart(wq_b[..., nope:])),
+                positions, w.theta, cfg.rope_scaling)
+        else:  # full-rank queries: one matrix, no norm
+            wq, wkv_a = lp["wq"].astype(dt), lp["wkv_a"].astype(dt)
+            wkv_b = lp["wkv_b"].astype(dt)
+            q_nope = jnp.einsum("bsh,hnd->bsnd", u, wq[..., :nope])
+            q_pe = _yarn_rope(
+                jnp.einsum("bsh,hnd->bsnd", u, _pairs_apart(wq[..., nope:])),
+                positions, w.theta, cfg.rope_scaling)
         c_kv = _rms_norm(jnp.einsum("bsh,hr->bsr", u, wkv_a[:, :kvr]),
                          lp["kv_a_norm"], cfg.rms_eps)
         if cfg.latent_rescale:
@@ -1127,6 +1239,67 @@ def _mamba(cfg: LlamaConfig, u: jax.Array, lp: Dict[str, jax.Array],
     return out, (padded[:, S:], h)
 
 
+def _kda(cfg: LlamaConfig, u: jax.Array, lp: Dict[str, jax.Array],
+         state: Optional[Tuple[jax.Array, jax.Array]] = None):
+    """Kimi delta attention (Kimi Linear's KDA as Ling-3.0-flash's
+    ``bailing_hybrid`` configures it) on the normed input ``u [B, S, H]``
+    -> (its output ``[B, S, H]``, the state after it). ``[q | k | v | a |
+    z] = u W_in``, each ``kda_heads`` heads of ``kda_head_dim``; ``[q | k |
+    v] = silu(taps([q | k | v]))``, depthwise and causal, no bias
+    (``_causal_taps``), rounded to the activations' type; ``q`` and ``k``
+    L2-normed a head, ``q`` then times ``kda_head_dim ** -0.5`` (by the
+    rule's ``l2_norm``, in float32); ``beta = sigmoid(u W_beta)`` a head; the
+    log-decay a channel ``g = kda_lower_bound * sigmoid(exp(A_log) * (a +
+    dt_bias))``, float32, in ``(kda_lower_bound, 0)``; ``o`` the delta
+    rule's (``ops.kda.kda``: ``S' = Diag(exp(g_t)) S_{t-1}``, ``S_t = S' +
+    beta_t k_t (v_t - S'^T k_t)^T``, ``o_t = S_t^T q_t``); ``RMSNorm(o)``
+    over each head's channels with ONE weight ``[kda_head_dim]``, times
+    ``sigmoid(z)``, the gate AFTER the norm; ``W_out``. No bias, no
+    positions. ``state`` is all a decode keeps of a row: the last
+    ``kda_conv_kernel - 1`` rows of ``[q | k | v]`` before their taps and
+    the rule's state ``[B, heads, head_dim, head_dim]`` float32 (None:
+    zeros, a sequence's start). A forward pass and a decode's pass over a
+    prompt run the rule by ``attn_impl`` (the kernel on the chip), a single
+    token by the recurrence itself."""
+    from ray_tpu.ops.kda import kda
+
+    dt_, f32 = cfg.dtype, jnp.float32
+    B, S = u.shape[:2]
+    nk, hd = cfg.kda_heads, cfg.kda_head_dim
+    inner, _ = cfg.kda_widths()
+    rows, s0 = (None, None) if state is None else state
+    with jax.named_scope("kda_in_proj"):
+        proj = jnp.einsum("bsh,hc->bsc", u, lp["kda_in"].astype(dt_))
+        beta = jax.nn.sigmoid(jnp.einsum(
+            "bsh,hn->bsn", u, lp["kda_beta"].astype(dt_),
+            preferred_element_type=f32))
+    qkv, a, z = (proj[..., :3 * inner], proj[..., 3 * inner:4 * inner],
+                 proj[..., 4 * inner:])
+    with jax.named_scope("kda_conv"):
+        # one pass: the taps, the SiLU and the rounding (each head's
+        # queries and keys are brought to unit length by the rule itself,
+        # `l2_norm`, where a head's channels lie side by side anyway)
+        c, padded = _causal_taps(qkv, lp["kda_conv_w"], rows)
+        qkv = jax.nn.silu(c).astype(dt_)
+        q, k, v = (qkv[..., i * inner:(i + 1) * inner].reshape(B, S, nk, hd)
+                   for i in range(3))
+    with jax.named_scope("kda_gate"):
+        # over the inner width as it lies: a head's factor once a channel
+        g = cfg.kda_lower_bound * jax.nn.sigmoid(
+            jnp.repeat(jnp.exp(lp["kda_a_log"].astype(f32)), hd)
+            * (a.astype(f32) + lp["kda_dt_bias"].astype(f32)))
+    impl = cfg.attn_impl if cfg.attn_impl in ("flash", "auto") else "reference"
+    o, s = kda(q, k, v, g.reshape(B, S, nk, hd), beta, s0, cfg.kda_chunk,
+               impl="reference" if state is not None and S == 1 else impl,
+               l2_norm=True)
+    with jax.named_scope("kda_gated_norm"):
+        o = (_rms_norm(o.astype(f32), lp["kda_norm"], cfg.rms_eps)
+             * jax.nn.sigmoid(z.astype(f32).reshape(B, S, nk, hd)))
+        o = o.reshape(B, S, inner).astype(dt_)
+    out = jnp.einsum("bsc,ch->bsh", o, lp["kda_out"].astype(dt_))
+    return out, (padded[:, S:], s)
+
+
 def _layer(cfg: LlamaConfig, x: jax.Array, lp: Dict[str, jax.Array],
            positions: jax.Array, kv_cache=None,
            cache_index: Optional[jax.Array] = None,
@@ -1138,8 +1311,9 @@ def _layer(cfg: LlamaConfig, x: jax.Array, lp: Dict[str, jax.Array],
     under routed experts, and ``index_kept`` of an indexed operator,
     ``_latent_attention``'s count over the queries ``live`` marks). The
     layer's kind is read off its leaves: ``conv_in`` makes the operator the
-    gated short convolution, ``mamba_in`` the state-space mixer and
-    ``wkv_a`` latent attention, and not attention; ``router`` makes the
+    gated short convolution, ``mamba_in`` the state-space mixer,
+    ``kda_in`` Kimi delta attention and ``wkv_a`` latent attention, and
+    not attention; ``router`` makes the
     feed-forward the routed experts and
     not the dense SwiGLU. Which latent operator it is (``latent``,
     ``window``, ``indexed``) the leaves do not say: ``operator`` does.
@@ -1147,7 +1321,8 @@ def _layer(cfg: LlamaConfig, x: jax.Array, lp: Dict[str, jax.Array],
     values) for attention, the last rows of ``z`` for the short
     convolution (``_short_conv``), the latent rows for latent attention
     (``_latent_attention``), the taps' rows and the scan's state for the
-    state-space mixer (``_mamba``). Every sub-layer's output joins the
+    state-space mixer (``_mamba``), the taps' rows and the rule's state
+    for Kimi delta attention (``_kda``). Every sub-layer's output joins the
     residual times ``residual_multiplier``."""
     dt = cfg.dtype
     counts: Dict[str, jax.Array] = {}
@@ -1173,6 +1348,10 @@ def _layer(cfg: LlamaConfig, x: jax.Array, lp: Dict[str, jax.Array],
         x = x + _res(y)
     elif "mamba_in" in lp:
         y, state = _mamba(cfg, h, lp, kv_cache)
+        new_cache = None if kv_cache is None else state
+        x = x + _res(y)
+    elif "kda_in" in lp:
+        y, state = _kda(cfg, h, lp, kv_cache)
         new_cache = None if kv_cache is None else state
         x = x + _res(y)
     elif "wkv_a" in lp:
@@ -1260,7 +1439,10 @@ def init_decode_state(cfg: LlamaConfig, batch: int, max_len: int) -> list:
     cache: the last ``mamba_conv_kernel - 1`` rows of ``[x | B | C]``
     ``[batch, mamba_conv_kernel - 1, inner + 2 mamba_state]`` and the
     scan's state ``[batch, mamba_heads, mamba_head_dim, mamba_state]``
-    float32; all zeros."""
+    float32, and for Kimi delta attention such a pair too: the last
+    ``kda_conv_kernel - 1`` rows of ``[q | k | v]`` ``[batch,
+    kda_conv_kernel - 1, 3 inner]`` and the rule's state ``[batch,
+    kda_heads, kda_head_dim, kda_head_dim]`` float32; all zeros."""
     def latent_rows(op):
         w = cfg.latent_widths(op)
         return (batch, w.window or max_len, w.kv_rank + w.rope
@@ -1272,13 +1454,19 @@ def init_decode_state(cfg: LlamaConfig, batch: int, max_len: int) -> list:
         "conv": (batch, cfg.conv_kernel - 1, cfg.hidden),
         **{op: latent_rows(op) for op in LATENT_OPERATORS}}
     zeros = {op: jnp.zeros(shapes[op], cfg.dtype)
-             for op in set(operators) - {"mamba"}}
+             for op in set(operators) - {"mamba", "kda"}}
     if "mamba" in operators:
         zeros["mamba"] = (
             jnp.zeros((batch, cfg.mamba_conv_kernel - 1,
                        cfg.mamba_widths()[1]), cfg.dtype),
             jnp.zeros((batch, cfg.mamba_heads, cfg.mamba_head_dim,
                        cfg.mamba_state), jnp.float32))
+    if "kda" in operators:
+        zeros["kda"] = (
+            jnp.zeros((batch, cfg.kda_conv_kernel - 1,
+                       3 * cfg.kda_widths()[0]), cfg.dtype),
+            jnp.zeros((batch, cfg.kda_heads, cfg.kda_head_dim,
+                       cfg.kda_head_dim), jnp.float32))
     return [(zeros[op], zeros[op]) if op == "attention" else zeros[op]
             for op in operators]
 

@@ -4,7 +4,9 @@ A model with experts is a ``LlamaConfig`` whose ``num_experts`` is above 0
 (OLMoE-1B-7B: 64 experts of width 1024, 8 a token, no shared expert;
 LFM2-24B-A2B: 64 of width 1536, 4 a token, after its leading dense layers;
 DeepSeek-V2: 160 of width 1536, 6 a token from 3 of 8 groups, beside two
-shared experts, of which a chip holds one group's 20).
+shared experts, of which a chip holds one group's 20; Ling-3.0-flash: 512
+of width 768, 8 a token from 4 of 8 groups under a bias, beside one shared
+expert, of which a chip holds two groups' 128).
 Its block is ``llama.py::_layer``: the operator half, the scan, remat, the
 head and the loss are the dense model's. This module holds what only the
 routed feed-forward needs: the router, the dispatch, the expert matmuls,
@@ -22,9 +24,13 @@ weights are renormalised or not (``norm_topk_prob``, over their sum plus
 ``router_norm_eps``) and scaled by ``routed_scaling_factor``. With
 ``router_groups`` the choice is group-limited (DeepSeek-V2's
 ``group_limited_greedy``): the experts lie in that many groups of
-neighbours, a group's score is its best expert's, a position keeps its
-best ``router_topk_groups`` groups and chooses its ``K`` among their
-experts (``_best_groups``). The defaults are OLMoE's router, to the bit.
+neighbours, a group's score is its best expert's
+(``router_group_score`` ``"max"``) or the sum of its two best
+(``"top2"``, DeepSeek-V3's ``noaux_tc``), a position keeps its best
+``router_topk_groups`` groups and chooses its ``K`` among their experts
+(``_best_groups``); with a bias too (``noaux_tc`` whole) the groups are
+scored and the experts chosen on the scores plus the bias, and the weights
+stay the scores without it. The defaults are OLMoE's router, to the bit.
 
 Shared experts (``num_shared_experts``) are one dense SwiGLU of that many
 times an expert's width, every position's, added to the routed sum (leaves
@@ -186,8 +192,11 @@ def _best_groups(cfg, probs: jax.Array) -> jax.Array:
     """``probs [T, E]`` with the experts outside each position's best
     ``router_topk_groups`` of ``router_groups`` groups set to 0, as
     DeepSeek-V2's ``group_limited_greedy`` masks them: a group is
-    ``E / router_groups`` neighbouring experts and its score its largest;
-    between groups that tie, the lower index stays (``lax.top_k``)."""
+    ``E / router_groups`` neighbouring experts and its score its largest
+    or, under ``router_group_score`` ``"top2"``, the sum of its two largest
+    (DeepSeek-V3's ``noaux_tc``; an expert that ties with its group's best
+    is its second); between groups that tie, the lower index stays
+    (``lax.top_k``)."""
     T, E = probs.shape
     G, keep = cfg.router_groups, cfg.router_topk_groups
     if E % G or not 0 < keep <= G:
@@ -197,8 +206,19 @@ def _best_groups(cfg, probs: jax.Array) -> jax.Array:
     # relayout of every row (19 ms a step at 8 x 1536 positions, PERF.md)
     group_of = jnp.arange(E, dtype=jnp.int32) // (E // G)       # [E]
     member = group_of[None, :] == jnp.arange(G, dtype=jnp.int32)[:, None]
-    best = jnp.max(jnp.where(member[None], probs[:, None, :], -jnp.inf),
-                   axis=-1)                                     # [T, G]
+    grouped = jnp.where(member[None], probs[:, None, :], -jnp.inf)
+    best = jnp.max(grouped, axis=-1)                            # [T, G]
+    if cfg.router_group_score == "top2":
+        # the first expert at its group's best leaves, and the best of
+        # what is left joins it
+        ids = jnp.arange(E, dtype=jnp.int32)
+        first = jnp.min(jnp.where(grouped == best[..., None], ids, E),
+                        axis=-1)                                # [T, G]
+        best = best + jnp.max(
+            jnp.where(ids == first[..., None], -jnp.inf, grouped), axis=-1)
+    elif cfg.router_group_score != "max":
+        raise ValueError(f"router_group_score {cfg.router_group_score!r}: "
+                         "expected max|top2")
     _, kept = jax.lax.top_k(best, keep)                         # [T, keep]
     stays = jnp.any(kept[:, :, None] == group_of[None, None, :],
                     axis=1)                                     # [T, E]
@@ -461,8 +481,10 @@ def expert_ffn(cfg, h: jax.Array, lp: Dict[str, jax.Array]
     first, count = held_experts(cfg)
     T = B * S
     x = h.reshape(T, H)
-    if cfg.router_groups and "router_bias" in lp:
-        raise ValueError("the group-limited choice has no bias on it here")
+    if cfg.router_groups and "router_bias" in lp \
+            and cfg.router_group_score != "top2":
+        raise ValueError("the group-limited choice by a group's best "
+                         "expert has no bias on it here")
     if cfg.router_scores not in ROUTER_SCORES:
         raise ValueError(f"router_scores {cfg.router_scores!r}: expected "
                          + "|".join(ROUTER_SCORES))
@@ -473,7 +495,14 @@ def expert_ffn(cfg, h: jax.Array, lp: Dict[str, jax.Array]
                             preferred_element_type=jnp.float32)
         probs = (jax.nn.sigmoid(logits) if cfg.router_scores == "sigmoid"
                  else jax.nn.softmax(logits, axis=-1))
-        if cfg.router_groups:
+        if cfg.router_groups and "router_bias" in lp:
+            # noaux_tc: the groups scored and the experts chosen on the
+            # scores plus the bias, the weights the scores without it
+            _, chosen = jax.lax.top_k(_best_groups(
+                cfg, probs + jax.lax.stop_gradient(
+                    lp["router_bias"].astype(jnp.float32))), K)
+            weights = jnp.take_along_axis(probs, chosen, axis=-1)
+        elif cfg.router_groups:
             # among the experts of each position's best groups
             weights, chosen = jax.lax.top_k(_best_groups(cfg, probs), K)
         elif "router_bias" in lp:

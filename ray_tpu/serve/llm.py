@@ -97,14 +97,15 @@ class LlamaGenerator:
     # windows, each summed over steps and those layers; `ssm_chunks_run` the
     # chunks the state-space layers' scans ran (layers x rows x chunks of
     # the padded length) and `ssm_chunks_live` those among them that hold
-    # one of a row's own positions
+    # one of a row's own positions; `kda_chunks_run` and `kda_chunks_live`
+    # the same two over the Kimi delta attention layers' chunks
     STEP_COUNTERS = ("host_bytes", "positions_computed", "positions_live",
                      "expert_pairs_fullest", "expert_pairs_mean",
                      "expert_pairs_here", "expert_pairs_all",
                      "expert_pairs_skipped", "expert_rows_moved",
                      "expert_rows_all", "step_device_s", "index_keys_kept",
                      "index_keys_seen", "window_keys_kept", "ssm_chunks_run",
-                     "ssm_chunks_live")
+                     "ssm_chunks_live", "kda_chunks_run", "kda_chunks_live")
 
     def __init__(self, config: str = "tiny", lora_rank: int = 4,
                  max_batch_size: int = 4,
@@ -129,11 +130,13 @@ class LlamaGenerator:
             if kind.endswith("_routed"))
         # the layers whose query attends an indexer's choice of its keys,
         # those whose query sees a window of them, and those whose
-        # operator scans a state over the sequence in chunks
-        self._indexed_layers, self._window_layers, self._ssm_layers = (
+        # operator carries a state over the sequence in chunks (the
+        # state-space scan's, the delta rule's)
+        (self._indexed_layers, self._window_layers, self._ssm_layers,
+         self._kda_layers) = (
             sum(n for kind, n in self._cfg.kind_counts().items()
                 if kind.startswith(operator + "_"))
-            for operator in ("indexed", "window", "mamba"))
+            for operator in ("indexed", "window", "mamba", "kda"))
         # adapt only the attention q/v projections: the cheap standard
         # LoRA target set, and enough for adapters to produce distinct
         # generations; the stacks are over the attention layers alone in a
@@ -309,15 +312,17 @@ class LlamaGenerator:
                     (n * (n + 1) // 2).sum()) * self._indexed_layers
                 counts["window_keys_kept"] += (int(inside.sum())
                                                * self._window_layers)
-            if self._ssm_layers:
-                # the scans run every row of the batch over the whole
-                # padded length; a chunk is live while its first position
-                # is one of its row's own
-                chunk = self._cfg.mamba_chunk
-                counts["ssm_chunks_run"] += (
-                    self._ssm_layers * bucket * -(-pad_len // chunk))
-                counts["ssm_chunks_live"] += self._ssm_layers * int(
-                    (-(-mask.sum(axis=1) // chunk)).sum())
+            # the scans and the delta rule run every row of the batch over
+            # the whole padded length; a chunk is live while its first
+            # position is one of its row's own
+            for name, layers, chunk in (
+                    ("ssm", self._ssm_layers, self._cfg.mamba_chunk),
+                    ("kda", self._kda_layers, self._cfg.kda_chunk)):
+                if layers:
+                    counts[name + "_chunks_run"] += (
+                        layers * bucket * -(-pad_len // chunk))
+                    counts[name + "_chunks_live"] += layers * int(
+                        (-(-mask.sum(axis=1) // chunk)).sum())
             if load is not None and "index_kept" in load:
                 kept = load["index_kept"]
                 counts["host_bytes"] += kept.nbytes
@@ -406,7 +411,9 @@ class LlamaGenerator:
         both reckoned on the host from the rows' lengths and summed over
         steps and those layers, 0 for a model without them: what is left
         between them is chunks of padding, which a scan that stopped at a
-        row's end would not run); and
+        row's end would not run); ``kda_chunks_run`` and
+        ``kda_chunks_live`` (the same two over the layers whose operator is
+        ``kda`` and their chunks of ``kda_chunk`` positions); and
         ``layer_kinds``, how many layers of each kind this replica serves
         (``LlamaConfig.kind_counts``: ``attention_dense`` alone for a dense
         decoder)."""

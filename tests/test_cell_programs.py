@@ -59,7 +59,12 @@ two kinds) and ``serve_lfm2_rag`` (five of three, four of them partial);
 the seven ``init`` lines, ``train_l2_seq4k.grad`` (the loss keeps its
 slices, and Mistral has one kind) and the eight steps of the five cells
 whose every run is its whole stack are what its parent ``60e176b`` gives to
-the character.
+the character. PR 52 (Ling-3.0-flash: a delta-rule operator, latent
+attention with full-rank queries and the head-wise gate on the plain
+``latent`` operator, a group of the router scored by its two best behind
+``router_group_score``, whose default is the parent's) moved none of the
+twenty: they are what its parent ``78fee0c`` gives to the character;
+``serve_ling3_repoctx``'s three are new.
 """
 
 import hashlib
@@ -87,6 +92,9 @@ PROGRAMS = {
     "serve_granite_toolcalls.init": "ce77369b6d8feac3",
     "serve_granite_toolcalls.step256": "4adf109acac83db4",
     "serve_granite_toolcalls.step1024": "ee9ab836cd96137f",
+    "serve_ling3_repoctx.init": "1e230ff66f2de897",
+    "serve_ling3_repoctx.step1024": "e87ec7f5021cdbd4",
+    "serve_ling3_repoctx.step3072": "32bfef41a865ab0e",
 }
 
 
