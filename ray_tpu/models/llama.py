@@ -1240,7 +1240,8 @@ def _mamba(cfg: LlamaConfig, u: jax.Array, lp: Dict[str, jax.Array],
 
 
 def _kda(cfg: LlamaConfig, u: jax.Array, lp: Dict[str, jax.Array],
-         state: Optional[Tuple[jax.Array, jax.Array]] = None):
+         state: Optional[Tuple[jax.Array, jax.Array]] = None,
+         lengths: Optional[jax.Array] = None):
     """Kimi delta attention (Kimi Linear's KDA as Ling-3.0-flash's
     ``bailing_hybrid`` configures it) on the normed input ``u [B, S, H]``
     -> (its output ``[B, S, H]``, the state after it). ``[q | k | v | a |
@@ -1260,7 +1261,10 @@ def _kda(cfg: LlamaConfig, u: jax.Array, lp: Dict[str, jax.Array],
     the rule's state ``[B, heads, head_dim, head_dim]`` float32 (None:
     zeros, a sequence's start). A forward pass and a decode's pass over a
     prompt run the rule by ``attn_impl`` (the kernel on the chip), a single
-    token by the recurrence itself."""
+    token by the recurrence itself. ``lengths [B]`` int32 (a serving
+    step's: how many positions of each right-padded row are its own) lets
+    the rule stop at a row's end (``ops.kda``): the positions past the
+    chunk that holds it get ``W_out`` of zeros, which is zeros."""
     from ray_tpu.ops.kda import kda
 
     dt_, f32 = cfg.dtype, jnp.float32
@@ -1291,7 +1295,7 @@ def _kda(cfg: LlamaConfig, u: jax.Array, lp: Dict[str, jax.Array],
     impl = cfg.attn_impl if cfg.attn_impl in ("flash", "auto") else "reference"
     o, s = kda(q, k, v, g.reshape(B, S, nk, hd), beta, s0, cfg.kda_chunk,
                impl="reference" if state is not None and S == 1 else impl,
-               l2_norm=True)
+               l2_norm=True, lengths=lengths)
     with jax.named_scope("kda_gated_norm"):
         o = (_rms_norm(o.astype(f32), lp["kda_norm"], cfg.rms_eps)
              * jax.nn.sigmoid(z.astype(f32).reshape(B, S, nk, hd)))
@@ -1305,7 +1309,8 @@ def _layer(cfg: LlamaConfig, x: jax.Array, lp: Dict[str, jax.Array],
            cache_index: Optional[jax.Array] = None,
            lora: Optional[Dict[str, Any]] = None, lora_scale: float = 0.0,
            operator: Optional[str] = None,
-           live: Optional[jax.Array] = None):
+           live: Optional[jax.Array] = None,
+           lengths: Optional[jax.Array] = None):
     """One block. x: [B, S, H_model] -> (x, the layer's updated state or
     None, the layer's books or None: the router's of ``moe.expert_ffn``
     under routed experts, and ``index_kept`` of an indexed operator,
@@ -1322,8 +1327,12 @@ def _layer(cfg: LlamaConfig, x: jax.Array, lp: Dict[str, jax.Array],
     convolution (``_short_conv``), the latent rows for latent attention
     (``_latent_attention``), the taps' rows and the scan's state for the
     state-space mixer (``_mamba``), the taps' rows and the rule's state
-    for Kimi delta attention (``_kda``). Every sub-layer's output joins the
-    residual times ``residual_multiplier``."""
+    for Kimi delta attention (``_kda``). ``lengths [B]`` int32, where a
+    serving step hands them, are the right-padded rows' own lengths, for an
+    operator that carries a state along the sequence and can stop at a
+    row's end: ``_kda`` does (``_mamba`` is not handed them yet: ROADMAP
+    S10 (2)). Every sub-layer's output joins the residual times
+    ``residual_multiplier``."""
     dt = cfg.dtype
     counts: Dict[str, jax.Array] = {}
 
@@ -1351,7 +1360,7 @@ def _layer(cfg: LlamaConfig, x: jax.Array, lp: Dict[str, jax.Array],
         new_cache = None if kv_cache is None else state
         x = x + _res(y)
     elif "kda_in" in lp:
-        y, state = _kda(cfg, h, lp, kv_cache)
+        y, state = _kda(cfg, h, lp, kv_cache, lengths)
         new_cache = None if kv_cache is None else state
         x = x + _res(y)
     elif "wkv_a" in lp:
@@ -1530,6 +1539,7 @@ def _hidden_and_books(
     lora_cfg: Optional[LoraConfig] = None,
     router_mask: Optional[jax.Array] = None,
     in_place: bool = False,
+    lengths: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
     """``llama_hidden``, and with it the layers' books (``_layer``), each
     key stacked over the layers that keep it, in the model's order: the
@@ -1544,7 +1554,10 @@ def _hidden_and_books(
     computed and counted. With ``in_place``, which is a forward pass's to
     ask for, a run that is a proper part of its stack reads each layer
     where it lies and no slice of a stack is made (differentiated, every
-    such run's backward scan would carry a whole stack's cotangent)."""
+    such run's backward scan would carry a whole stack's cotangent).
+    ``lengths [B]`` int32 are the right-padded rows' own lengths, for the
+    operators that stop at a row's end (``_layer``): the hidden states past
+    it are then not a forward pass's either."""
     B, S = tokens.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
@@ -1594,7 +1607,7 @@ def _hidden_and_books(
             y, _, books = _layer(cfg, carry, lp, positions, lora=lo_i,
                                  lora_scale=scale,
                                  operator=kind.split("_")[0],
-                                 live=router_mask)
+                                 live=router_mask, lengths=lengths)
             return y, books
 
         return scan_fn, (part(stack), part(lo_stack), index)
@@ -1691,10 +1704,18 @@ def llama_next_token(
     routed layer, and ``index_kept``, an int32 an indexed operator: the
     (query, key) pairs its choice kept over those positions' queries.
     With ``live`` the routed experts compute the marked positions alone,
-    and the hidden states of the others are not a forward pass's."""
+    and a model with Kimi delta attention is told each row's length (the
+    marks' row sums: a row's own tokens are its first) so that the rule
+    stops at its end; the hidden states of the others are not a forward
+    pass's. Without ``live`` every position is computed: ``last`` is not
+    taken for a length, because a caller who wants every position's
+    hidden state hands zeros there (``serve/llm.py::_FullLogits``)."""
+    lengths = None
+    if live is not None and "kda" in cfg.layer_types:
+        lengths = jnp.sum(live, axis=1, dtype=jnp.int32)
     x, books = _hidden_and_books(params, tokens, cfg, lora=lora,
                                  lora_cfg=lora_cfg, router_mask=live,
-                                 in_place=True)
+                                 in_place=True, lengths=lengths)
     rows = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
     ids = jnp.argmax(llama_head(params, rows, cfg), axis=-1)
     load = None

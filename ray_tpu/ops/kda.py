@@ -22,6 +22,16 @@ kernel, no lower than ``G_LOWER_BOUND`` a position; ``beta [B, S, H]``
 float32. The state, the decays and their sums are float32 everywhere.
 Returns ``o [B, S, H, Dv]`` in ``q``'s type and the state after the last
 position ``[B, H, Dk, Dv]`` float32: all a decode keeps of a row.
+
+``lengths [B]`` int32 says how many of a row's positions are its own where
+a batch's rows are padded on the right to one length (a serving step's
+are). The rule is causal, so a row's own outputs do not depend on it; the
+kernel stops at the end of the chunk that holds the row's last position,
+which is its whole time for the chunks after it, and every ``o`` past that
+chunk is zeros by either impl. The state the kernel then hands back is the
+one after that chunk, not after position ``S`` (the recurrence's, which
+runs every position whatever the lengths) and not after the row's last
+position: nothing in the tree reads a padded row's state.
 """
 
 from __future__ import annotations
@@ -72,14 +82,14 @@ def reference_kda(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def _kernel_kda(q, k, v, g, beta, s0, chunk, l2_norm):
+def _kernel_kda(q, k, v, g, beta, s0, chunk, l2_norm, lengths):
     from ray_tpu.ops.pallas.kda_chunk import kda_chunked
 
-    return kda_chunked(q, k, v, g, beta, s0, chunk, l2_norm)
+    return kda_chunked(q, k, v, g, beta, s0, chunk, l2_norm, lengths)
 
 
-def _kernel_kda_fwd(q, k, v, g, beta, s0, chunk, l2_norm):
-    return _kernel_kda(q, k, v, g, beta, s0, chunk, l2_norm), None
+def _kernel_kda_fwd(q, k, v, g, beta, s0, chunk, l2_norm, lengths):
+    return _kernel_kda(q, k, v, g, beta, s0, chunk, l2_norm, lengths), None
 
 
 def _kernel_kda_bwd(chunk, l2_norm, res, ct):
@@ -93,14 +103,16 @@ _kernel_kda.defvjp(_kernel_kda_fwd, _kernel_kda_bwd)
 
 def kda(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
         beta: jax.Array, s0: Optional[jax.Array] = None, chunk: int = 64,
-        *, impl: str = "auto", l2_norm: bool = False
+        *, impl: str = "auto", l2_norm: bool = False,
+        lengths: Optional[jax.Array] = None
         ) -> Tuple[jax.Array, jax.Array]:
     """The module docstring's rule. impl as ``ssd_scan``'s: ``auto`` (on
     the TPU platform the kernel for a length of whole chunks, else and on
     the CPU platform the reference), ``flash`` (the kernel at any length: a
     ragged last chunk is padded with positions whose ``g``, ``beta`` and
     ``k`` are 0, which neither decay the state nor write to it, and their
-    outputs dropped) or ``reference``."""
+    outputs dropped) or ``reference``. ``lengths``: the module docstring's
+    last paragraph (None: every row is whole)."""
     S = q.shape[1]
     if impl == "auto":
         platform = jax.default_backend()
@@ -111,7 +123,13 @@ def kda(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
         impl = "flash" if platform == "tpu" and S % chunk == 0 \
             else "reference"
     if impl == "reference":
-        return reference_kda(q, k, v, g, beta, s0, l2_norm)
+        o, s = reference_kda(q, k, v, g, beta, s0, l2_norm)
+        if lengths is not None:
+            # the kernel's zeros: past the chunk that holds a row's end
+            ends = -(-lengths // chunk) * chunk
+            o = jnp.where((jnp.arange(S) < ends[:, None])[..., None, None],
+                          o, 0)
+        return o, s
     if impl != "flash":
         raise ValueError(f"unknown kda impl {impl!r}; expected "
                          "auto|flash|reference")
@@ -123,5 +141,5 @@ def kda(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
         q, k, v, g, beta = (
             jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
             for a in (q, k, v, g, beta))
-    o, s = _kernel_kda(q, k, v, g, beta, s0, chunk, l2_norm)
+    o, s = _kernel_kda(q, k, v, g, beta, s0, chunk, l2_norm, lengths)
     return o[:, :S], s

@@ -54,6 +54,24 @@ a grid step takes ``hb`` heads of one chunk of one row, and the grid is
 what carries the state. The state is kept ``[Dv, Dk]`` a head, so that the
 decay of its key channels runs along the lanes.
 
+The rows' lengths. A batch's rows are padded on the right to one length,
+and a chunk whose first position lies past its row's end holds nothing of
+the row. The kernel is told how many chunks of each row hold a position of
+its own (``lengths``, as ``ceil(length / C)`` and the last such chunk's
+index, one int32 pair a row, prefetched into SMEM before the grid runs) and
+a grid step past them does none of the work above: it leaves the state as
+it is and writes ZEROS to its block of ``o`` (a padded position's output
+goes on into the gated norm, the out-projection and the next layers, and
+what nobody wrote may be a NaN, which a masked zero does not silence). The
+blocks' index maps hold such a step at the row's last live chunk, which is
+the block already in VMEM, so nothing is fetched for it either. The state
+handed back is the one after a row's last live CHUNK: the positions past
+the row's end inside that chunk are run like any other, as they were when
+the kernel knew no lengths, and a row of no position hands ``s0`` back.
+The vector unit binds the kernel, so a chunk not run is its time back but
+for 0.5 to 0.7 us a skipped grid step: 106 of 192 chunks live take 6.8 ms a
+layer, not 11.3; 8 whole rows 0.3 ms more (PERF.md section 6, PR 53).
+
 ``C`` and ``hb``, by the chip (PERF.md section 6, PR 52: 8 rows of 3072,
 32 heads of 128, bf16, the kernel alone, ms): chunks of 64, 128 and 256 at
 4 heads a step take 19.1, 16.6 and 17.2, and 18.4, 15.9 and 16.9 at 8. The
@@ -72,7 +90,7 @@ harness gives a warm-up call.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -99,14 +117,34 @@ def kda_heads_a_step(heads: int) -> int:
     return 4 if heads % 4 == 0 else heads
 
 
-def _kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref, o_ref, sT_ref,
-            state, *, hb: int, D: int, C: int, l2_norm: bool):
+def _kernel(chunks_ref, q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref, o_ref,
+            sT_ref, state, *, hb: int, D: int, C: int, l2_norm: bool):
     ic = pl.program_id(2)
+    # the row's chunks that hold a position of its own (`kda_chunked`)
+    live = ic < chunks_ref[0, pl.program_id(0)]
 
     @pl.when(ic == 0)
     def _enter():
         state[...] = s0_ref[0]
 
+    @pl.when(live)
+    def _chunk():
+        _a_chunk(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, state, hb=hb,
+                 D=D, C=C, l2_norm=l2_norm)
+
+    @pl.when(jnp.logical_not(live))
+    def _past_the_rows_end():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(ic == pl.num_programs(2) - 1)
+    def _leave():
+        sT_ref[0] = state[...]
+
+
+def _a_chunk(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, state, *, hb: int,
+             D: int, C: int, l2_norm: bool):
+    """A chunk's whole work (module docstring): ``o`` of its positions from
+    the state it enters with, and the state after it."""
     f32 = jnp.float32
     nb = C // _SUB
     mdt = q_ref.dtype                                      # the MXU's operands
@@ -186,52 +224,73 @@ def _kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref, o_ref, sT_ref,
                     + jax.lax.dot_general(vp, kd, tn,
                                           preferred_element_type=f32))
 
-    @pl.when(ic == pl.num_programs(2) - 1)
-    def _leave():
-        sT_ref[0] = state[...]
-
 
 def kda_chunked(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
                 beta: jax.Array, s0: jax.Array, chunk: int,
-                l2_norm: bool = False) -> Tuple[jax.Array, jax.Array]:
+                l2_norm: bool = False, lengths: Optional[jax.Array] = None
+                ) -> Tuple[jax.Array, jax.Array]:
     """``q``, ``k``, ``v`` ``[B, S, H, D]``, ``g [B, S, H, D]`` float32 (the
     log-decay a channel, in ``[G_LOWER_BOUND, 0]``), ``beta [B, S, H]``
     float32, the entering state ``s0 [B, H, D, D]`` float32 -> (``o [B, S,
     H, D]`` in ``q``'s type, the state after the last position ``[B, H, D,
     D]`` float32). ``S`` is whole chunks; key and value heads are one
     width, whole lane tiles. ``l2_norm``: each head's ``q`` and ``k`` are
-    brought to unit length here, ``q`` then times ``D ** -0.5``."""
+    brought to unit length here, ``q`` then times ``D ** -0.5``.
+    ``lengths [B]`` int32: how many of a row's positions are its own, the
+    rest being padding on its right (None: all ``S`` of every row). A chunk
+    that starts at or past a row's length is not run and not read: its
+    ``o`` is zeros, and the state is the one after the row's last chunk
+    that ran, ``s0`` for a row of no position (module docstring)."""
     B, S, H, D = q.shape
     if S % chunk or chunk % _SUB:
         raise ValueError(f"kda_chunked: a length of {S} is not whole chunks "
                          f"of {chunk}, or a chunk is not whole blocks of "
                          f"{_SUB}")
     if D % _LANES or not (k.shape == v.shape == g.shape == q.shape) \
-            or beta.shape != (B, S, H) or s0.shape != (B, H, D, D):
+            or beta.shape != (B, S, H) or s0.shape != (B, H, D, D) \
+            or (lengths is not None and lengths.shape != (B,)):
         raise ValueError(f"kda_chunked: q{q.shape} k{k.shape} v{v.shape} "
-                         f"g{g.shape} beta{beta.shape} s0{s0.shape}")
+                         f"g{g.shape} beta{beta.shape} s0{s0.shape} "
+                         f"lengths{getattr(lengths, 'shape', None)}")
     hb = kda_heads_a_step(H)
     Gr, C, f32 = H // hb, chunk, jnp.float32
     betas = jnp.transpose(beta.astype(f32).reshape(B, S, Gr, hb),
                           (0, 2, 1, 3))                    # [B, Gr, S, hb]
-    rows = pl.BlockSpec((1, C, hb * D), lambda i, h, c: (i, c, h))
-    states = pl.BlockSpec((1, hb, D, D), lambda i, h, c: (i, h, 0, 0))
+    # a row's live chunks and the last of them, made once, here: the body's
+    # test is one compare and an index map one `min` of two scalars (a map
+    # is traced again at every lowering, which no compile cache keeps)
+    live = (jnp.full((B,), S // C, jnp.int32) if lengths is None
+            else (lengths.astype(jnp.int32) + (C - 1)) // C)
+    chunks = jnp.stack([live, jnp.maximum(live - 1, 0)])   # [2, B]
+
+    def held(c, i, chunks_ref):
+        """Chunk ``c`` of row ``i``, or the row's last live one past it."""
+        return jax.lax.min(c, chunks_ref[1, i])
+
+    rows_in = pl.BlockSpec((1, C, hb * D),
+                           lambda i, h, c, n: (i, held(c, i, n), h))
+    states = pl.BlockSpec((1, hb, D, D), lambda i, h, c, n: (i, h, 0, 0))
     with jax.named_scope(KDA_CHUNK_TRACE_NAME):  # the kernel's alone
         o, sT = pl.pallas_call(
             functools.partial(_kernel, hb=hb, D=D, C=C, l2_norm=l2_norm),
-            grid=(B, Gr, S // C),
-            in_specs=[rows, rows, rows, rows,
-                      pl.BlockSpec((1, 1, C, hb),
-                                   lambda i, h, c: (i, h, c, 0)),
-                      states],
-            out_specs=[rows, states],
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(B, Gr, S // C),
+                in_specs=[rows_in, rows_in, rows_in, rows_in,
+                          pl.BlockSpec((1, 1, C, hb),
+                                       lambda i, h, c, n:
+                                       (i, h, held(c, i, n), 0)),
+                          states],
+                out_specs=[pl.BlockSpec((1, C, hb * D),
+                                        lambda i, h, c, n: (i, c, h)),
+                           states],
+                scratch_shapes=[pltpu.VMEM((hb, D, D), f32)]),
             out_shape=[jax.ShapeDtypeStruct((B, S, H * D), q.dtype),
                        jax.ShapeDtypeStruct((B, H, D, D), f32)],
-            scratch_shapes=[pltpu.VMEM((hb, D, D), f32)],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=flash_attention._interpret(),
-        )(q.reshape(B, S, H * D), k.reshape(B, S, H * D),
+        )(chunks, q.reshape(B, S, H * D), k.reshape(B, S, H * D),
           v.reshape(B, S, H * D), g.astype(f32).reshape(B, S, H * D), betas,
           jnp.swapaxes(s0.astype(f32), 2, 3))
     return o.reshape(B, S, H, D), jnp.swapaxes(sT, 2, 3)

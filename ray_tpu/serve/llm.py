@@ -20,6 +20,9 @@ logits are never made. A model with experts is told which positions are
 the rows' own, and its routed experts multiply those alone: the
 padding's pairs are sorted past the last expert's and get no visit
 (``models/moe.py::expert_ffn``; ``expert_pairs_skipped`` counts them).
+A model with Kimi delta attention is told the same, and the rule's kernel
+runs no chunk past a row's end (``ops/pallas/kda_chunk.py``;
+``kda_chunks_skipped`` counts them).
 ``LlamaGenerator._fwd`` is the step's function with no mask (every
 position computed; for a dense model the very program ``_step`` runs),
 followed by the head over every position, for callers that want the
@@ -98,14 +101,17 @@ class LlamaGenerator:
     # chunks the state-space layers' scans ran (layers x rows x chunks of
     # the padded length) and `ssm_chunks_live` those among them that hold
     # one of a row's own positions; `kda_chunks_run` and `kda_chunks_live`
-    # the same two over the Kimi delta attention layers' chunks
+    # the same two over the Kimi delta attention layers' grids, and
+    # `kda_chunks_skipped` the chunks of those grids that the kernel, told
+    # the rows' lengths, did not run: the difference of the two
     STEP_COUNTERS = ("host_bytes", "positions_computed", "positions_live",
                      "expert_pairs_fullest", "expert_pairs_mean",
                      "expert_pairs_here", "expert_pairs_all",
                      "expert_pairs_skipped", "expert_rows_moved",
                      "expert_rows_all", "step_device_s", "index_keys_kept",
                      "index_keys_seen", "window_keys_kept", "ssm_chunks_run",
-                     "ssm_chunks_live", "kda_chunks_run", "kda_chunks_live")
+                     "ssm_chunks_live", "kda_chunks_run", "kda_chunks_live",
+                     "kda_chunks_skipped")
 
     def __init__(self, config: str = "tiny", lora_rank: int = 4,
                  max_batch_size: int = 4,
@@ -238,13 +244,16 @@ class LlamaGenerator:
     def _run_step(self, tokens, last, mask, lora=None):
         """The step's one jitted program on numpy ``tokens [B, S]``, ``last
         [B]`` and ``mask [B, S]`` (the rows' own tokens: only a model with
-        experts or an indexer is told, for its routers' load and for what
-        its choices kept) -> (ids, hidden, load)."""
+        experts, an indexer or Kimi delta attention is told, for its
+        routers' load, for what its choices kept, and for the rows'
+        lengths, the marks' row sums, past which the delta rule's kernel
+        runs no chunk) -> (ids, hidden, load)."""
         import jax.numpy as jnp
 
         return self._step_fn(
             self._params, jnp.asarray(tokens), lora, last,
-            mask if self._cfg.num_experts or self._indexed_layers else None)
+            mask if self._cfg.num_experts or self._indexed_layers
+            or self._kda_layers else None)
 
     def _step(self, model_id: str, states: List[Optional[Dict]]) -> List:
         """One decode iteration for one adapter group: pad the live rows
@@ -312,9 +321,9 @@ class LlamaGenerator:
                     (n * (n + 1) // 2).sum()) * self._indexed_layers
                 counts["window_keys_kept"] += (int(inside.sum())
                                                * self._window_layers)
-            # the scans and the delta rule run every row of the batch over
-            # the whole padded length; a chunk is live while its first
-            # position is one of its row's own
+            # the scans run every row of the batch over the whole padded
+            # length, and the delta rule's grid is as large; a chunk is
+            # live while its first position is one of its row's own
             for name, layers, chunk in (
                     ("ssm", self._ssm_layers, self._cfg.mamba_chunk),
                     ("kda", self._kda_layers, self._cfg.kda_chunk)):
@@ -323,6 +332,15 @@ class LlamaGenerator:
                         layers * bucket * -(-pad_len // chunk))
                     counts[name + "_chunks_live"] += layers * int(
                         (-(-mask.sum(axis=1) // chunk)).sum())
+            if self._kda_layers:
+                # the delta rule's kernel is told the rows' lengths
+                # (`_run_step`) and runs no chunk past a row's end:
+                # reckoned here, from the mask the step handed the
+                # program, as the padding's pairs are
+                chunk = self._cfg.kda_chunk
+                counts["kda_chunks_skipped"] += self._kda_layers * int(
+                    (-(-pad_len // chunk) - -(-mask.sum(axis=1) // chunk)
+                     ).sum())
             if load is not None and "index_kept" in load:
                 kept = load["index_kept"]
                 counts["host_bytes"] += kept.nbytes
@@ -413,7 +431,13 @@ class LlamaGenerator:
         between them is chunks of padding, which a scan that stopped at a
         row's end would not run); ``kda_chunks_run`` and
         ``kda_chunks_live`` (the same two over the layers whose operator is
-        ``kda`` and their chunks of ``kda_chunk`` positions); and
+        ``kda`` and their chunks of ``kda_chunk`` positions: the first is
+        the kernel's grid, rows x chunks of the padded length) and
+        ``kda_chunks_skipped`` (the chunks of that grid past a row's end,
+        which the kernel is told and does not run: layers x the sum over
+        rows of the padded length's chunks less ``ceil(n / kda_chunk)``,
+        the difference of the two, reckoned on the host from the mask the
+        program was handed); and
         ``layer_kinds``, how many layers of each kind this replica serves
         (``LlamaConfig.kind_counts``: ``attention_dense`` alone for a dense
         decoder)."""

@@ -64,7 +64,16 @@ attention with full-rank queries and the head-wise gate on the plain
 ``latent`` operator, a group of the router scored by its two best behind
 ``router_group_score``, whose default is the parent's) moved none of the
 twenty: they are what its parent ``78fee0c`` gives to the character;
-``serve_ling3_repoctx``'s three are new.
+``serve_ling3_repoctx``'s three are new. PR 53 (the delta rule's kernel is
+told its rows' lengths: ``llama_next_token`` of a model with a ``kda``
+operator sums the mask it is handed anyway to a length a row,
+``_hidden_and_books`` -> ``_layer`` -> ``_kda`` -> ``ops.kda.kda`` carry it,
+and ``kda_chunked`` takes a row's live chunks as a scalar-prefetched operand
+and runs no chunk past them) moved ``serve_ling3_repoctx.step1024`` and
+``.step3072``, as it meant to: a ``reduce_sum`` of the mask and four
+kernels with one operand more. The twenty-one others, that cell's ``init``
+among them, are what its parent ``f43b5c9`` gives to the character: no
+length is made for a model without the operator.
 """
 
 import hashlib
@@ -93,8 +102,8 @@ PROGRAMS = {
     "serve_granite_toolcalls.step256": "4adf109acac83db4",
     "serve_granite_toolcalls.step1024": "ee9ab836cd96137f",
     "serve_ling3_repoctx.init": "1e230ff66f2de897",
-    "serve_ling3_repoctx.step1024": "e87ec7f5021cdbd4",
-    "serve_ling3_repoctx.step3072": "32bfef41a865ab0e",
+    "serve_ling3_repoctx.step1024": "8b4dd9cfe633fa0c",
+    "serve_ling3_repoctx.step3072": "8fcdcbb4de21164f",
 }
 
 

@@ -385,3 +385,32 @@ def test_the_state_space_scan_compiles_within_vmem(seq, one_chip,
     # decays of 64 heads would be 134 to 537 MB in float32
     decays = 8 * 64 * seq * 256 * 4
     assert compiled.memory_analysis().temp_size_in_bytes < decays // 3
+
+
+# Kimi delta attention's chunked kernel at serve_ling3_repoctx's longest
+# bucket and Ling-3.0-flash's widths (32 heads of 128, chunks of 128, 4
+# heads a grid step), told its rows' lengths: Mosaic takes the scalar
+# prefetch, the clamped index maps and the body under its condition
+def test_the_delta_rules_kernel_compiles_told_its_rows_lengths(
+        one_chip, compiled_for_tpu):
+    from ray_tpu.ops.pallas import kda_chunk as kc
+
+    def of(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    f32 = jnp.float32
+    rows = of(8, 3072, 32, 128)
+    compiled = jax.jit(
+        lambda q, k, v, g, beta, s0, n: kc.kda_chunked(
+            q, k, v, g, beta, s0, 128, True, n)
+    ).lower(rows, rows, rows, of(8, 3072, 32, 128, dtype=f32),
+            of(8, 3072, 32, dtype=f32), of(8, 32, 128, 128, dtype=f32),
+            of(8, dtype=jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and kc.KDA_CHUNK_TRACE_NAME in text
+    assert kc.kda_heads_a_step(32) == 4
+    used = [n for n in _scoped_vmem(compiled) if n]
+    # q, k, v and o blocks of [128, 512] bf16 and g's in float32 in two
+    # buffers, 4 states of [128, 128] float32 in, out and kept, and the
+    # body's own [128, 128] matrices: some 8 MB
+    assert used and 2 * 2 ** 20 < max(used) <= fa.VMEM_LIMIT_BYTES

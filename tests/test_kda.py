@@ -4,7 +4,10 @@ chunks SHORTER than the lengths, so that the state crosses edges, lengths
 that are not whole chunks, so that a ragged last chunk bites, an entering
 state, right-padded rows, the decay at its bound for whole chunks, and the
 controls that say the tolerance can tell a fault: the state dropped at the
-chunks' edges, the delta term left out.
+chunks' edges, the delta term left out. Then the kernel told its rows'
+lengths (PR 53): a row's own outputs to the bit, zeros past its end, the
+state after its last chunk that ran, and nothing of the chunks it skips
+read.
 
 Both sides compute in float32 here and differ by the order of sums alone
 (the kernel's sums run by chunk and its solve by block): some 1e-6 of
@@ -202,6 +205,83 @@ def test_the_dispatcher():
         kernel.kda_chunked(q, k, v, g, beta[..., :2], s0, 64)
     assert kernel.kda_heads_a_step(32) == 4
     assert kernel.kda_heads_a_step(2) == 2
+
+
+# ---------------------------------------------------------------------------
+# the kernel told its rows' lengths
+# ---------------------------------------------------------------------------
+CHUNK, LENGTH = 64, 256
+# one batch: no position, one, a chunk less one, a chunk, a chunk and one,
+# all but one, all
+ROWS = (0, 1, CHUNK - 1, CHUNK, CHUNK + 1, LENGTH - 1, LENGTH)
+
+
+@pytest.fixture(scope="module")
+def told():
+    """The kernel with and without its rows' lengths on one right-padded
+    batch, and with NaNs planted wherever it was told not to look."""
+    ops = inputs(B=len(ROWS), S=LENGTH, H=4)
+    lengths = jnp.asarray(ROWS, jnp.int32)
+    run = jax.jit(lambda *a, n=None: kernel.kda_chunked(*a, CHUNK, False, n))
+    q, k, v, g, beta, s0 = ops
+    # a NaN in every operand the kernel reads by chunk, from the first
+    # chunk edge at or past a row's end on
+    edge = -(-lengths // CHUNK) * CHUNK
+    past = (jnp.arange(LENGTH) >= edge[:, None])[..., None, None]
+    planted = tuple(jnp.where(past, jnp.nan, a) for a in (q, k, v, g)) + (
+        jnp.where(past[..., 0], jnp.nan, beta),)
+    return {"lengths": lengths, "s0": s0,
+            "none": run(*ops), "whole": run(
+                *ops, n=jnp.full(len(ROWS), LENGTH, jnp.int32)),
+            "told": run(*ops, n=lengths),
+            "planted": run(*planted, s0, n=lengths),
+            # what a row's state is after its first `c` chunks
+            "after": {c: run(*(a[:, :c * CHUNK] for a in ops[:5]), s0)[1]
+                      for c in range(1, LENGTH // CHUNK + 1)}}
+
+
+@pytest.mark.parametrize("row", range(len(ROWS)), ids=[
+    f"{n}_positions" for n in ROWS])
+def test_the_kernel_told_its_rows_lengths(told, row):
+    n = ROWS[row]
+    chunks = -(-n // CHUNK)
+    (o, s), (plain_o, _) = told["told"], told["none"]
+    # the row's own outputs (and its last chunk's, which runs whole) are
+    # the kernel's without lengths, to the bit; everything after is 0
+    np.testing.assert_array_equal(o[row, :chunks * CHUNK],
+                                  plain_o[row, :chunks * CHUNK])
+    assert not np.asarray(o[row, chunks * CHUNK:]).any()
+    # the state after the last chunk that ran; s0 for a row of none
+    want = told["after"][chunks][row] if chunks else told["s0"][row]
+    np.testing.assert_array_equal(s[row], want)
+    # the chunks past the row's end are not read: NaNs there reach nothing
+    for got, clean in zip(told["planted"], told["told"]):
+        np.testing.assert_array_equal(got[row], clean[row])
+    # no lengths and every row whole are one result
+    for a, b in zip(told["none"], told["whole"]):
+        np.testing.assert_array_equal(a[row], b[row])
+
+
+def test_the_dispatcher_hands_the_lengths_on():
+    """``kda`` with lengths: the kernel's result by ``flash``, ragged last
+    chunk and all; by ``reference`` the recurrence with the same zeros, so
+    that the two can be held to each other over every position."""
+    q, k, v, g, beta, s0 = inputs(B=3, S=200)
+    lengths = jnp.asarray([0, 70, 200], jnp.int32)
+    o, s = kda.kda(q, k, v, g, beta, s0, 64, impl="flash", lengths=lengths)
+    ref_o, _ = kda.kda(q, k, v, g, beta, s0, 64, impl="reference",
+                       lengths=lengths)
+    want_o, want_s = kda.reference_kda(q, k, v, g, beta, s0)
+    np.testing.assert_allclose(o, ref_o, **TIGHT)
+    np.testing.assert_allclose(ref_o[1, :128], want_o[1, :128], **TIGHT)
+    assert not np.asarray(ref_o[0]).any() and not np.asarray(
+        ref_o[1, 128:]).any()
+    np.testing.assert_array_equal(ref_o[2], want_o[2])
+    np.testing.assert_array_equal(s[0], s0[0])
+    np.testing.assert_allclose(s[2], want_s[2], **TIGHT)
+    with pytest.raises(ValueError, match=r"lengths\(2,\)"):
+        kernel.kda_chunked(q[:, :128], k[:, :128], v[:, :128], g[:, :128],
+                           beta[:, :128], s0, 64, False, lengths[:2])
 
 
 def test_the_backward_raises_by_name():

@@ -1,0 +1,136 @@
+"""The rows' lengths reach the delta rule from a serving step (PR 53):
+``llama_next_token`` of a model with Kimi delta attention takes them off
+the mask it is handed anyway, ``_hidden_and_books`` -> ``_layer`` ->
+``_kda`` carry them, and ``ops/pallas/kda_chunk.py`` runs no chunk past a
+row's end. Here, on a Ling-shaped tiny model (delta attention beside
+latent attention, routed experts; the kernel interpreted): the tokens and
+the rows' own hidden states are, to the bit, those of the same step with
+the lengths withheld from the kernel; and ``LlamaGenerator._step`` counts
+the chunks the kernel was told to skip, ``kda_chunks_skipped``, as the
+grid's less the live ones, and none for a model without the operator."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models.llama import LlamaConfig, init_llama, llama_next_token
+from ray_tpu.ops.pallas import kda_chunk
+from ray_tpu.serve.llm import LlamaGenerator
+
+CHUNK, BUCKET = 64, 256
+
+
+def ling_shaped(**over):
+    """Four layers as Ling-3.0-flash orders them (delta attention with a
+    dense feed-forward, then routed: delta, latent attention, delta), 2
+    heads of 128, chunks of 64, 8 experts of which 2 a token, in float32
+    through the kernels."""
+    kwargs = dict(
+        vocab_size=256, hidden=64, mlp_hidden=32, num_layers=4, num_heads=2,
+        num_kv_heads=2, head_dim=128, max_seq_len=BUCKET, rms_eps=1e-6,
+        dtype=jnp.float32, param_dtype=jnp.float32, attn_impl="flash",
+        num_experts=8, experts_per_token=2, norm_topk_prob=True,
+        router_scores="sigmoid", router_bias=True, router_norm_eps=1e-20,
+        routed_scaling_factor=2.5,
+        layer_types=("kda", "kda", "latent_attention", "kda"),
+        num_dense_layers=1, dense_mlp_hidden=96, kv_lora_rank=32,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        num_shared_experts=1, head_gate=True, kda_heads=2, kda_head_dim=128,
+        kda_chunk=CHUNK)
+    kwargs.update(over)
+    return LlamaConfig(**kwargs)
+
+
+def test_a_step_told_its_rows_lengths_is_the_step_that_was_not(monkeypatch):
+    """Rows of no token, one, a chunk and one, and the whole bucket, padded
+    on the right as ``_step`` pads them."""
+    cfg = ling_shaped()
+    params = init_llama(cfg, jax.random.key(3))
+    lengths = np.asarray([0, 1, CHUNK + 1, 150, BUCKET])
+    live = np.arange(BUCKET)[None, :] < lengths[:, None]
+    tokens = np.where(live, np.asarray(jax.random.randint(
+        jax.random.key(4), live.shape, 1, cfg.vocab_size)), 0)
+    last = np.maximum(lengths - 1, 0).astype(np.int32)
+    handed = []
+    sound = kda_chunk.kda_chunked
+
+    def step(withheld):
+        def kernel(*args):
+            handed.append(args[8])
+            return sound(*args[:8], None if withheld else args[8])
+
+        monkeypatch.setattr(kda_chunk, "kda_chunked", kernel)
+        ids, hidden, _ = jax.jit(lambda p, t, i, on: llama_next_token(
+            p, t, i, cfg, live=on))(params, tokens, last, live)
+        return np.asarray(ids), np.asarray(hidden)
+
+    ids, hidden = step(withheld=False)
+    # a kernel a run of like layers, each handed the rows' lengths
+    assert len(handed) == 3 and all(
+        n is not None and n.shape == (5,) and n.dtype == jnp.int32
+        for n in handed)
+    want_ids, want_hidden = step(withheld=True)
+    np.testing.assert_array_equal(ids[lengths > 0], want_ids[lengths > 0])
+    np.testing.assert_array_equal(hidden[live], want_hidden[live])
+    assert np.isfinite(hidden).all()
+    # and the lengths did something: the padding's hidden states moved
+    assert not np.array_equal(hidden[~live], want_hidden[~live])
+    # without a mask no length is made: every position is wanted
+    del handed[:]
+    monkeypatch.setattr(kda_chunk, "kda_chunked", lambda *args: (
+        handed.append(args[8]), sound(*args))[1])
+    llama_next_token(params, jnp.asarray(tokens), jnp.asarray(last), cfg)
+    assert handed == [None] * 3
+
+
+@pytest.fixture(scope="module")
+def generator():
+    made = []
+
+    def make(cfg):
+        made.append(LlamaGenerator(
+            config=cfg, max_batch_size=4, allowed_batch_sizes=[4],
+            max_new_tokens=4, seq_bucket=128))
+        return made[-1]
+
+    yield make
+    for gen in made:
+        gen.engine.shutdown()
+
+
+def test_the_step_counts_the_chunks_the_kernel_skipped(generator):
+    """Steps with a long row, a short one and empty ones in a batch of 4:
+    the counter is the grid's chunks less the live ones, whatever the
+    rows."""
+    gen = generator(ling_shaped())
+    assert "kda_chunks_skipped" in gen.STEP_COUNTERS
+    assert "kda_chunks_skipped" in LlamaGenerator.engine_stats.__doc__
+    states = [gen._prefill({"prompt": list(range(1, n + 1)), "max_new": 2},
+                           "") for n in (130, 5)] + [None, None]
+    gen._step("", states)
+    stats = gen.engine_stats()
+    # a bucket of 256: 3 layers x 4 rows x 4 chunks, of which the long row
+    # has three and the short one one
+    assert stats["kda_chunks_run"] == 3 * 4 * 4
+    assert stats["kda_chunks_live"] == 3 * (3 + 1)
+    assert stats["kda_chunks_skipped"] == 3 * (1 + 3 + 4 + 4)
+    # the short row alone, at a bucket of 128: two chunks a row
+    gen._step("", [None, states[1], None, None])
+    # and three whole rows of 128 beside it: nothing of theirs to skip
+    whole = [gen._prefill({"prompt": [7] * 128, "max_new": 2}, "")
+             for _ in range(3)]
+    gen._step("", whole + [None])
+    stats = gen.engine_stats()
+    assert stats["kda_chunks_skipped"] == 3 * (12 + 7 + 2)
+    assert stats["kda_chunks_skipped"] == (stats["kda_chunks_run"]
+                                           - stats["kda_chunks_live"])
+
+
+def test_a_model_without_the_operator_skips_no_chunk(generator):
+    gen = generator(LlamaConfig.debug_1l())
+    gen._step("", [gen._prefill({"prompt": [1, 2, 3], "max_new": 2}, ""),
+                   None, None, None])
+    stats = gen.engine_stats()
+    assert stats["positions_computed"] == 4 * 128
+    assert stats["kda_chunks_skipped"] == 0 == stats["kda_chunks_run"]
