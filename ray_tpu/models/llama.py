@@ -1009,7 +1009,8 @@ def _latent_attention(cfg: LlamaConfig, u: jax.Array,
                       cache_index: Optional[jax.Array] = None, *,
                       operator: str = "latent",
                       live: Optional[jax.Array] = None,
-                      counts: Optional[Dict[str, jax.Array]] = None):
+                      counts: Optional[Dict[str, jax.Array]] = None,
+                      lengths: Optional[jax.Array] = None):
     """Latent attention (DeepSeek-V2's MLA) on the normed input ``u [B, S,
     H]`` -> (its output ``[B, S, H]``, the state after it or None), at the
     widths of ``operator`` (``LlamaConfig.latent_widths``).
@@ -1034,7 +1035,10 @@ def _latent_attention(cfg: LlamaConfig, u: jax.Array,
     ``live [B, S]`` marks (all without it).
 
     Without a state the latent rows are decompressed to every head and
-    ``ops.attention`` is handed the two widths, the window and the choice.
+    ``ops.attention`` is handed the two widths, the window, the choice and
+    ``lengths [B]`` int32 (a serving step's: how many positions of each
+    right-padded row are its own), past which its kernel computes no block
+    and writes zeros, which ``W_o`` leaves zeros.
     ``state`` is all a decode keeps of a position, the normed ``c_kv`` and
     the rotated ``k_pe`` (and an indexed operator's index key after them):
     ``[B, max_len, kv_lora_rank + qk_rope_head_dim (+ index_head_dim)]``,
@@ -1105,7 +1109,8 @@ def _latent_attention(cfg: LlamaConfig, u: jax.Array,
             k_nope = constrain(k_nope, ("batch", "seq", "heads", None))
             out = attention(q_nope, k_nope, v, impl=cfg.attn_impl,
                             causal=True, q_rope=q_pe, k_rope=k_pe,
-                            scale=scale, window=w.window or None, keep=keep)
+                            scale=scale, window=w.window or None, keep=keep,
+                            lengths=lengths)
         else:
             rows = jnp.concatenate(new_rows, axis=-1).astype(state.dtype)
             q_pos = jnp.arange(S) + cache_index
@@ -1329,10 +1334,10 @@ def _layer(cfg: LlamaConfig, x: jax.Array, lp: Dict[str, jax.Array],
     state-space mixer (``_mamba``), the taps' rows and the rule's state
     for Kimi delta attention (``_kda``). ``lengths [B]`` int32, where a
     serving step hands them, are the right-padded rows' own lengths, for an
-    operator that carries a state along the sequence and can stop at a
-    row's end: ``_kda`` does (``_mamba`` is not handed them yet: ROADMAP
-    S10 (2)). Every sub-layer's output joins the residual times
-    ``residual_multiplier``."""
+    operator whose kernel can stop at a row's end: ``_kda``'s and
+    ``_latent_attention``'s do (``_mamba`` is not handed them yet: ROADMAP
+    S10 (2); nor is the equal-width flash forward). Every sub-layer's
+    output joins the residual times ``residual_multiplier``."""
     dt = cfg.dtype
     counts: Dict[str, jax.Array] = {}
 
@@ -1366,7 +1371,8 @@ def _layer(cfg: LlamaConfig, x: jax.Array, lp: Dict[str, jax.Array],
     elif "wkv_a" in lp:
         y, new_cache = _latent_attention(
             cfg, h, lp, positions, kv_cache, cache_index,
-            operator=operator or "latent", live=live, counts=counts)
+            operator=operator or "latent", live=live, counts=counts,
+            lengths=lengths)
         x = x + _res(y)
     else:
         # --- attention ---
@@ -1704,14 +1710,17 @@ def llama_next_token(
     routed layer, and ``index_kept``, an int32 an indexed operator: the
     (query, key) pairs its choice kept over those positions' queries.
     With ``live`` the routed experts compute the marked positions alone,
-    and a model with Kimi delta attention is told each row's length (the
-    marks' row sums: a row's own tokens are its first) so that the rule
-    stops at its end; the hidden states of the others are not a forward
-    pass's. Without ``live`` every position is computed: ``last`` is not
-    taken for a length, because a caller who wants every position's
-    hidden state hands zeros there (``serve/llm.py::_FullLogits``)."""
+    and a model with Kimi delta attention or latent attention is told each
+    row's length (the marks' row sums: a row's own tokens are its first)
+    so that the rule's kernel and the two-width flash forward stop at its
+    end; the hidden states of the others are not a forward pass's. Without
+    ``live`` every position is computed: ``last`` is not taken for a
+    length, because a caller who wants every position's hidden state hands
+    zeros there (``serve/llm.py::_FullLogits``)."""
     lengths = None
-    if live is not None and "kda" in cfg.layer_types:
+    if live is not None and any(
+            kind.split("_")[0] in LATENT_OPERATORS + ("kda",)
+            for kind in cfg.layer_kinds()):
         lengths = jnp.sum(live, axis=1, dtype=jnp.int32)
     x, books = _hidden_and_books(params, tokens, cfg, lora=lora,
                                  lora_cfg=lora_cfg, router_mask=live,

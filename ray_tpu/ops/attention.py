@@ -17,6 +17,14 @@ alike (an indexer's choice: ``index_scores`` gives what it is made from).
 Both come on top of ``causal``; the flash kernel takes them at two widths
 (the window's blocks outside it are not visited; the choice is a mask a
 block).
+
+``lengths [B]`` int32: how many of a row's positions are its own where a
+batch's rows are padded on the right to one length (a serving step's are).
+The keys are causal, so a row's own outputs do not depend on it; the flash
+kernel at two widths computes no block past a row's end and writes zeros
+there (``ops/pallas/flash_attention.py``). Every other path computes every
+position and does not read it: the outputs past a row's end are nobody's
+to read.
 """
 
 from __future__ import annotations
@@ -91,7 +99,8 @@ def _flash_per_shard(q: jax.Array, k: jax.Array, v: jax.Array,
                      k_rope: Optional[jax.Array] = None,
                      scale: Optional[float] = None,
                      window: Optional[int] = None,
-                     keep: Optional[jax.Array] = None) -> jax.Array:
+                     keep: Optional[jax.Array] = None,
+                     lengths: Optional[jax.Array] = None) -> jax.Array:
     """The Pallas kernel, run on each device's own shard when a mesh is in
     scope. A ``pallas_call`` has no partitioning rule: left to GSPMD its
     operands are all-gathered and every chip computes the whole batch."""
@@ -109,7 +118,7 @@ def _flash_per_shard(q: jax.Array, k: jax.Array, v: jax.Array,
             scale = (q.shape[3] + q_rope.shape[3]) ** -0.5
         return flash_attention_shared_rope(
             q, q_rope, k, k_rope, v, scale, causal, window,
-            None if keep is None else keep.astype(jnp.int8))
+            None if keep is None else keep.astype(jnp.int8), lengths)
     if window is not None or keep is not None:
         raise NotImplementedError(
             "the equal-width flash kernels see every causal key: a window "
@@ -149,12 +158,14 @@ def attention(
     scale: Optional[float] = None,
     window: Optional[int] = None,
     keep: Optional[jax.Array] = None,
+    lengths: Optional[jax.Array] = None,
 ) -> jax.Array:
     """impl: auto (on the TPU platform the flash kernel, on the CPU platform
     the reference), flash, reference. Ring attention is invoked explicitly
     via ops.ring_attention by the seq-parallel layer, not through this
     dispatcher. ``q_rope``, ``k_rope`` and ``scale`` are the module
-    docstring's two widths, ``window`` and ``keep`` its fewer keys.
+    docstring's two widths, ``window`` and ``keep`` its fewer keys,
+    ``lengths`` its last paragraph.
 
     ``auto`` on a TPU still takes the reference for what the kernel has no
     path for (cached decode, lengths off the 128 grid, widths it does not
@@ -206,7 +217,8 @@ def attention(
                 "flash attention does not support q_offset/valid_kv_len; "
                 "use impl='reference' for cached decode")
         fewer = {name: given for name, given in (
-            ("window", window), ("keep", keep)) if given is not None}
+            ("window", window), ("keep", keep), ("lengths", lengths))
+            if given is not None}
         return _flash_per_shard(q, k, v, causal, q_rope, k_rope, scale,
                                 **fewer)
     if impl != "reference":

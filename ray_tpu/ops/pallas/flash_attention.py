@@ -82,6 +82,25 @@ block is read beside the keys' and masks the scores
 not before, so no block is skipped for it: the kernel computes the causal
 blocks whole, and its share of a roofline reckoned over the KEPT pairs
 says so. A call that passes neither compiles what it always did.
+
+The rows' lengths, at two widths alone (``lengths [B]`` int32). A batch's
+rows are padded on the right to one length, and a block whose first
+position lies past its row's end holds nothing of the row. The kernel is
+told how many query blocks and how many key blocks of each row hold a
+position of its own (``ceil(length / block)`` and the last such block's
+index, for either, four int32 a row, prefetched into SMEM before the grid
+runs), and a grid step at a block past them computes nothing: a dead query
+block's running sums stay the zeros they began as and its ``o`` is written
+as ZEROS (a padded position's output goes on into ``W_o`` and the next
+layers' dense matmuls, and what nobody wrote may be a NaN). The index maps
+hold such a step's operands at the row's last live blocks, which are in
+VMEM already, so nothing is fetched for it either. The keys are causal, so
+every key skipped was masked for every query of the row's own and a masked
+score adds an exact 0: the rows' own outputs are the same to the bit; the
+padded queries inside a row's last live block are run like any other, as
+they were when the kernel knew no lengths. With ``None`` every row is
+whole and the call is the one it was: no operand, no test, no ``min``
+(PERF.md section 6, PR 54, has the times).
 """
 
 from __future__ import annotations
@@ -91,6 +110,7 @@ from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -559,12 +579,67 @@ def _window_key_blocks(sq: int, block_q: int, block_k: int,
 
 def _first_key_block(iq, block_q: int, block_k: int, window: int):
     """The first key block a query block's window reaches (``iq`` traced)."""
-    return jnp.maximum(iq * block_q - (window - 1), 0) // block_k
+    return jax.lax.div(jax.lax.max(iq * block_q - (window - 1), 0), block_k)
+
+
+@functools.cache
+def _shared_rope_steps(seq: int, head_dim: int, rope_dim: int,
+                       value_dim: int, window: Optional[int]):
+    """``(block_q, block_k, iq [n], at [n])``: the tiles of the two-width
+    forward at a prefill of ``seq`` and the (query block, key block) of
+    each grid step at or under the diagonal, the ones it computes for a
+    whole row."""
+    padded = head_dim + -(-rope_dim // _LANES) * _LANES
+    block_q, block_k = flash_tiles(seq, seq, head_dim=padded,
+                                   value_dim=value_dim)
+    key_blocks = (seq // block_k if window is None else
+                  _window_key_blocks(seq, block_q, block_k, window))
+    steps = [(iq, at) for iq in range(seq // block_q)
+             for at in range(
+                 0 if window is None
+                 else max(iq * block_q - window + 1, 0) // block_k,
+                 seq // block_k)[:key_blocks]
+             if at * block_k <= iq * block_q + block_q - 1]
+    iq, at = np.asarray(steps, np.int64).T
+    return block_q, block_k, iq, at
+
+
+def shared_rope_blocks(seq: int, lengths, *, head_dim: int, rope_dim: int,
+                       value_dim: int, window: Optional[int] = None
+                       ) -> Tuple[int, int]:
+    """What the rows' lengths are worth to the two-width forward at a
+    prefill of ``seq`` (module docstring), a head: ``(run, live)``, the grid
+    steps at or under the diagonal over every row of ``lengths [B]``
+    (numpy), which are the ones computed when every row is whole, and those
+    among them whose query block and key block both hold a position of
+    their row's own, which are the ones computed when the kernel is told
+    the lengths. Host arithmetic, by the kernel's own rule."""
+    block_q, block_k, iq, at = _shared_rope_steps(seq, head_dim, rope_dim,
+                                                  value_dim, window)
+    lengths = np.asarray(lengths, np.int64)[:, None]
+    live = ((iq < -(-lengths // block_q)) & (at < -(-lengths // block_k)))
+    return len(lengths) * len(iq), int(live.sum())
+
+
+def _live_blocks(lengths: jax.Array, block_q: int, block_k: int
+                 ) -> jax.Array:
+    """``[4, B]`` int32 of ``lengths [B]``: a row's live query blocks and
+    the last of them, then the same two of its key blocks. Made once,
+    outside the call and by ``lax`` alone, so that the body's test is two
+    compares and an index map a ``min`` and a ``select`` of scalars: a
+    ``jnp`` call on a tracer is a jaxpr traced, and a map is traced again
+    at every lowering, which no compile cache keeps."""
+    n = jax.lax.convert_element_type(lengths, jnp.int32)
+    rows = []
+    for block in (block_q, block_k):
+        live = jax.lax.div(jax.lax.add(n, block - 1), block)
+        rows += [live, jax.lax.max(jax.lax.sub(live, 1), 0)]
+    return jax.lax.concatenate([row.reshape(1, -1) for row in rows], 0)
 
 
 def _fwd_shared_rope_kernel(*refs, scale: float, causal: bool, block_q: int,
                             block_k: int, window: Optional[int] = None,
-                            selected: bool = False):
+                            selected: bool = False, told: bool = False):
     """``_fwd_kernel`` with the scores in two parts, ``q k^T`` over the
     head's own width and ``q_rope k_rope^T`` over the shared rotary key's;
     operands in their own type, float32 accumulators, no logsumexp (there
@@ -574,7 +649,14 @@ def _fwd_shared_rope_kernel(*refs, scale: float, causal: bool, block_q: int,
     along and masks the scores, every head alike. A query's row of a block
     in which it sees no key fills with ``exp(0)``; the first block in which
     it sees one rescales that away (``alpha`` is 0), and every query sees
-    its window's or its choice's keys somewhere."""
+    its window's or its choice's keys somewhere. With ``told`` a first
+    operand rides in front, ``blocks_ref [4, B]`` (`_flash_fwd_shared_rope`):
+    how many query and key blocks hold a position of the row's own. A step
+    at a block past them computes nothing, and a query block past them
+    ends as the zeros it began as."""
+    blocks_ref = None
+    if told:
+        blocks_ref, *refs = refs
     if selected:
         (q_ref, qr_ref, k_ref, kr_ref, v_ref, keep_ref, o_ref,
          m_scr, l_scr, acc_scr) = refs
@@ -598,6 +680,11 @@ def _fwd_shared_rope_kernel(*refs, scale: float, causal: bool, block_q: int,
     run = True
     if causal:
         run = at * block_k <= iq * block_q + block_q - 1
+    if told:  # and neither block past its row's end (lengths are causal's)
+        row = pl.program_id(0)
+        run = jax.lax.bitwise_and(run, jax.lax.bitwise_and(
+            jax.lax.lt(iq, blocks_ref[0, row]),
+            jax.lax.lt(at, blocks_ref[2, row])))
 
     @pl.when(run)
     def _compute():
@@ -640,57 +727,81 @@ def _fwd_shared_rope_kernel(*refs, scale: float, causal: bool, block_q: int,
 def _flash_fwd_shared_rope(q: jax.Array, q_rope: jax.Array, k: jax.Array,
                            k_rope: jax.Array, v: jax.Array, *, scale: float,
                            causal: bool, window: Optional[int] = None,
-                           keep: Optional[jax.Array] = None) -> jax.Array:
+                           keep: Optional[jax.Array] = None,
+                           lengths: Optional[jax.Array] = None) -> jax.Array:
     """q [B,H,S,D], q_rope [B,H,S,R], k [B,H,S,D], k_rope [B,S,R] (one row
-    a position, every head's), v [B,H,S,Dv], keep [B,S,S] int8 or None →
+    a position, every head's), v [B,H,S,Dv], keep [B,S,S] int8 or None,
+    lengths [B] int32 or None (the rows' own lengths: module docstring) →
     o [B,H,S,Dv]."""
     B, H, Sq, D = q.shape
     R, Skv, Dv = q_rope.shape[3], k.shape[2], v.shape[3]
-    if (window is not None or keep is not None) and not (
-            causal and Sq == Skv):
-        raise ValueError("a window or a choice of keys is a prefill's: "
-                         "causal, the queries' positions the keys'")
+    if (window is not None or keep is not None or lengths is not None) \
+            and not (causal and Sq == Skv):
+        raise ValueError("a window, a choice of keys or the rows' lengths "
+                         "are a prefill's: causal, the queries' positions "
+                         "the keys'")
+    if lengths is not None and lengths.shape != (B,):
+        raise ValueError(f"lengths{lengths.shape} for {B} rows")
     # what the blocks fill in VMEM: a part of 64 pads to the lane width
     padded = D + -(-R // _LANES) * _LANES
     block_q, block_k = flash_tiles(Sq, Skv, head_dim=padded, value_dim=Dv)
     kernel = functools.partial(
         _fwd_shared_rope_kernel, scale=scale, causal=causal,
         block_q=block_q, block_k=block_k, window=window,
-        selected=keep is not None)
+        selected=keep is not None, told=lengths is not None)
     key_blocks = Skv // block_k
     if window is not None:
         key_blocks = _window_key_blocks(Sq, block_q, block_k, window)
+    # no lengths, no operand: the call is the one it was
+    prefetched = [] if lengths is None else [
+        _live_blocks(lengths, block_q, block_k)]
 
-    def key_block(iq, ik):
+    def query_block(b, iq, *blocks_ref):
+        """Query block ``iq`` of row ``b``, or the row's last live one past
+        it, which costs no copy."""
+        return jax.lax.min(iq, blocks_ref[0][1, b]) if blocks_ref else iq
+
+    def key_block(b, iq, ik, *blocks_ref):
         """The key block of a grid step. Under a window: from its first
         block on, and past the diagonal the diagonal's again, which costs
-        no copy."""
-        if window is None:
-            return ik
-        return jnp.minimum(
-            ik + _first_key_block(iq, block_q, block_k, window),
-            (iq * block_q + block_q - 1) // block_k)
+        no copy; past the row's last live key block, or at a query block
+        past its last live one, that key block again, which costs none
+        either."""
+        at = ik
+        if window is not None:
+            at = jax.lax.min(
+                ik + _first_key_block(iq, block_q, block_k, window),
+                jax.lax.div(iq * block_q + block_q - 1, block_k))
+        if not blocks_ref:
+            return at
+        last = blocks_ref[0][3, b]
+        return jax.lax.select(jax.lax.gt(iq, blocks_ref[0][1, b]), last,
+                              jax.lax.min(at, last))
 
-    def rows(block, width):   # a head's rows: q, q_rope and o by iq
-        return pl.BlockSpec((1, 1, block, width),
-                            lambda b, h, iq, ik: (b, h, iq, 0))
+    def rows(block, width):   # a head's rows: q and q_rope by iq
+        return pl.BlockSpec(
+            (1, 1, block, width),
+            lambda b, h, iq, ik, *n: (b, h, query_block(b, iq, *n), 0))
 
     def keys(width):          # a head's keys and values by ik
-        return pl.BlockSpec((1, 1, block_k, width),
-                            lambda b, h, iq, ik: (b, h, key_block(iq, ik), 0))
+        return pl.BlockSpec(
+            (1, 1, block_k, width),
+            lambda b, h, iq, ik, *n: (b, h, key_block(b, iq, ik, *n), 0))
 
     in_specs = [
         rows(block_q, D), rows(block_q, R), keys(D),
         # the shared rotary key: no head in its index
-        pl.BlockSpec((1, block_k, R),
-                     lambda b, h, iq, ik: (b, key_block(iq, ik), 0)),
+        pl.BlockSpec(
+            (1, block_k, R),
+            lambda b, h, iq, ik, *n: (b, key_block(b, iq, ik, *n), 0)),
         keys(Dv),
     ]
     operands = [q, q_rope, k, k_rope, v]
     if keep is not None:  # the choice: no head in its index either
         in_specs.append(pl.BlockSpec(
             (1, block_q, block_k),
-            lambda b, h, iq, ik: (b, iq, key_block(iq, ik))))
+            lambda b, h, iq, ik, *n: (b, query_block(b, iq, *n),
+                                      key_block(b, iq, ik, *n))))
         operands.append(keep)
     name = (SELECTED_TRACE_NAME if keep is not None else
             WINDOW_TRACE_NAME if window is not None else
@@ -698,20 +809,25 @@ def _flash_fwd_shared_rope(q: jax.Array, q_rope: jax.Array, k: jax.Array,
     with jax.named_scope(name):
         return pl.pallas_call(
             kernel,
-            grid=(B, H, Sq // block_q, key_blocks),
-            in_specs=in_specs,
-            out_specs=rows(block_q, Dv),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(prefetched),
+                grid=(B, H, Sq // block_q, key_blocks),
+                in_specs=in_specs,
+                # every block of `o` is written, a dead one with zeros
+                out_specs=pl.BlockSpec(
+                    (1, 1, block_q, Dv),
+                    lambda b, h, iq, ik, *n: (b, h, iq, 0)),
+                scratch_shapes=[
+                    pltpu.VMEM((block_q, 128), jnp.float32),   # running max
+                    pltpu.VMEM((block_q, 128), jnp.float32),   # running sum
+                    pltpu.VMEM((block_q, Dv), jnp.float32),    # accumulator
+                ]),
             out_shape=jax.ShapeDtypeStruct((B, H, Sq, Dv), q.dtype),
-            scratch_shapes=[
-                pltpu.VMEM((block_q, 128), jnp.float32),   # running max
-                pltpu.VMEM((block_q, 128), jnp.float32),   # running sum
-                pltpu.VMEM((block_q, Dv), jnp.float32),    # accumulator
-            ],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "parallel",
                                      "arbitrary")),
             interpret=_interpret(),
-        )(*operands)
+        )(*prefetched, *operands)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
@@ -720,24 +836,29 @@ def flash_attention_shared_rope(q: jax.Array, q_rope: jax.Array,
                                 v: jax.Array, scale: float,
                                 causal: bool = True,
                                 window: Optional[int] = None,
-                                keep: Optional[jax.Array] = None
+                                keep: Optional[jax.Array] = None,
+                                lengths: Optional[jax.Array] = None
                                 ) -> jax.Array:
     """The forward at two widths (module docstring): q, k ``[B, S, H, D]``,
     q_rope ``[B, S, H, R]``, k_rope ``[B, S, R]``, v ``[B, S, H, Dv]`` →
     ``[B, S, H, Dv]``; softmax of ``(q k^T + q_rope k_rope^T) * scale``
     over the causal keys, of which ``window`` leaves a query its last
-    ``window`` and ``keep [B, S, S]`` (int8) the ones it marks."""
+    ``window`` and ``keep [B, S, S]`` (int8) the ones it marks.
+    ``lengths [B]`` int32: how many of a right-padded row's positions are
+    its own (None: all of every row); the blocks past them are not
+    computed, and their outputs are zeros."""
     o = _flash_fwd_shared_rope(
         jnp.swapaxes(q, 1, 2), jnp.swapaxes(q_rope, 1, 2),
         jnp.swapaxes(k, 1, 2), k_rope, jnp.swapaxes(v, 1, 2),
-        scale=scale, causal=causal, window=window, keep=keep)
+        scale=scale, causal=causal, window=window, keep=keep,
+        lengths=lengths)
     return jnp.swapaxes(o, 1, 2)
 
 
 def _fa_shared_rope_fwd(q, q_rope, k, k_rope, v, scale, causal, window,
-                        keep):
+                        keep, lengths):
     return flash_attention_shared_rope(q, q_rope, k, k_rope, v, scale,
-                                       causal, window, keep), None
+                                       causal, window, keep, lengths), None
 
 
 def _fa_shared_rope_bwd(scale, causal, window, res, g):

@@ -22,7 +22,9 @@ padding's pairs are sorted past the last expert's and get no visit
 (``models/moe.py::expert_ffn``; ``expert_pairs_skipped`` counts them).
 A model with Kimi delta attention is told the same, and the rule's kernel
 runs no chunk past a row's end (``ops/pallas/kda_chunk.py``;
-``kda_chunks_skipped`` counts them).
+``kda_chunks_skipped`` counts them); so is a model with latent attention,
+whose two-width flash forward computes no block past one
+(``ops/pallas/flash_attention.py``; ``flash_blocks_skipped``).
 ``LlamaGenerator._fwd`` is the step's function with no mask (every
 position computed; for a dense model the very program ``_step`` runs),
 followed by the head over every position, for callers that want the
@@ -103,7 +105,13 @@ class LlamaGenerator:
     # one of a row's own positions; `kda_chunks_run` and `kda_chunks_live`
     # the same two over the Kimi delta attention layers' grids, and
     # `kda_chunks_skipped` the chunks of those grids that the kernel, told
-    # the rows' lengths, did not run: the difference of the two
+    # the rows' lengths, did not run: the difference of the two;
+    # `flash_blocks_run` the grid steps at or under the diagonal of the
+    # latent operators' two-width flash forwards (layers x rows x heads x
+    # the padded length's), `flash_blocks_live` those among them whose
+    # query and key block both hold one of a row's own positions, and
+    # `flash_blocks_skipped` the rest, which the kernel, told the rows'
+    # lengths, did not compute
     STEP_COUNTERS = ("host_bytes", "positions_computed", "positions_live",
                      "expert_pairs_fullest", "expert_pairs_mean",
                      "expert_pairs_here", "expert_pairs_all",
@@ -111,7 +119,8 @@ class LlamaGenerator:
                      "expert_rows_all", "step_device_s", "index_keys_kept",
                      "index_keys_seen", "window_keys_kept", "ssm_chunks_run",
                      "ssm_chunks_live", "kda_chunks_run", "kda_chunks_live",
-                     "kda_chunks_skipped")
+                     "kda_chunks_skipped", "flash_blocks_run",
+                     "flash_blocks_live", "flash_blocks_skipped")
 
     def __init__(self, config: str = "tiny", lora_rank: int = 4,
                  max_batch_size: int = 4,
@@ -121,7 +130,7 @@ class LlamaGenerator:
         import jax
 
         from ray_tpu.models.llama import (
-            LlamaConfig, LoraConfig, init_llama)
+            LATENT_OPERATORS, LlamaConfig, LoraConfig, init_llama)
         from ray_tpu.models.moe import held_experts
 
         self._cfg = getattr(LlamaConfig, config)() \
@@ -134,15 +143,23 @@ class LlamaGenerator:
         self._pairs_a_position = self._cfg.experts_per_token * sum(
             n for kind, n in self._cfg.kind_counts().items()
             if kind.endswith("_routed"))
+        def layers_of(operator):
+            return sum(n for kind, n in self._cfg.kind_counts().items()
+                       if kind.startswith(operator + "_"))
+
         # the layers whose query attends an indexer's choice of its keys,
         # those whose query sees a window of them, and those whose
         # operator carries a state over the sequence in chunks (the
         # state-space scan's, the delta rule's)
         (self._indexed_layers, self._window_layers, self._ssm_layers,
-         self._kda_layers) = (
-            sum(n for kind, n in self._cfg.kind_counts().items()
-                if kind.startswith(operator + "_"))
-            for operator in ("indexed", "window", "mamba", "kda"))
+         self._kda_layers) = map(layers_of,
+                                 ("indexed", "window", "mamba", "kda"))
+        # the latent operators, whose prefill is the two-width flash
+        # forward's: (layers, their widths) each
+        self._flash_layers = [
+            (layers, self._cfg.latent_widths(operator))
+            for operator in LATENT_OPERATORS
+            if (layers := layers_of(operator))]
         # adapt only the attention q/v projections: the cheap standard
         # LoRA target set, and enough for adapters to produce distinct
         # generations; the stacks are over the attention layers alone in a
@@ -244,15 +261,16 @@ class LlamaGenerator:
     def _run_step(self, tokens, last, mask, lora=None):
         """The step's one jitted program on numpy ``tokens [B, S]``, ``last
         [B]`` and ``mask [B, S]`` (the rows' own tokens: only a model with
-        experts, an indexer or Kimi delta attention is told, for its
-        routers' load, for what its choices kept, and for the rows'
-        lengths, the marks' row sums, past which the delta rule's kernel
-        runs no chunk) -> (ids, hidden, load)."""
+        experts, latent attention or Kimi delta attention is told, for its
+        routers' load, for what its indexers' choices kept, and for the
+        rows' lengths, the marks' row sums, past which the delta rule's
+        kernel runs no chunk and the two-width flash forward computes no
+        block) -> (ids, hidden, load)."""
         import jax.numpy as jnp
 
         return self._step_fn(
             self._params, jnp.asarray(tokens), lora, last,
-            mask if self._cfg.num_experts or self._indexed_layers
+            mask if self._cfg.num_experts or self._flash_layers
             or self._kda_layers else None)
 
     def _step(self, model_id: str, states: List[Optional[Dict]]) -> List:
@@ -273,6 +291,7 @@ class LlamaGenerator:
         import numpy as np
 
         from ray_tpu.models.moe import moved_chunk
+        from ray_tpu.ops.pallas.flash_attention import shared_rope_blocks
 
         with events.span("llm.prepare", "serve"):
             live = [(i, s) for i, s in enumerate(states) if s is not None]
@@ -341,6 +360,19 @@ class LlamaGenerator:
                 counts["kda_chunks_skipped"] += self._kda_layers * int(
                     (-(-pad_len // chunk) - -(-mask.sum(axis=1) // chunk)
                      ).sum())
+            if self._flash_layers and pad_len % 128 == 0:
+                # the two-width flash forward is told the same lengths and
+                # computes no block past a row's end; a length off the
+                # kernel's 128 grid is the reference's, which has no blocks
+                lengths = mask.sum(axis=1)
+                for layers, w in self._flash_layers:
+                    run, own = shared_rope_blocks(
+                        pad_len, lengths, head_dim=w.nope, rope_dim=w.rope,
+                        value_dim=w.v, window=w.window or None)
+                    counts["flash_blocks_run"] += layers * w.heads * run
+                    counts["flash_blocks_live"] += layers * w.heads * own
+                    counts["flash_blocks_skipped"] += (layers * w.heads
+                                                       * (run - own))
             if load is not None and "index_kept" in load:
                 kept = load["index_kept"]
                 counts["host_bytes"] += kept.nbytes
@@ -437,7 +469,17 @@ class LlamaGenerator:
         which the kernel is told and does not run: layers x the sum over
         rows of the padded length's chunks less ``ceil(n / kda_chunk)``,
         the difference of the two, reckoned on the host from the mask the
-        program was handed); and
+        program was handed); ``flash_blocks_run``, ``flash_blocks_live``
+        and ``flash_blocks_skipped`` (over the layers whose operator is
+        latent attention, plain, windowed or indexed, at a padded length on
+        the two-width flash forward's 128 grid: the (query block, key
+        block) steps of its grid at or under the diagonal, rows x heads x
+        the padded length's by ``flash_tiles``, under a window the
+        window's; those among them whose two blocks both hold a position
+        of their row's own; and the rest, which the kernel is told and
+        does not compute: ``ops/pallas/flash_attention.py::
+        shared_rope_blocks``, reckoned on the host from the mask the
+        program was handed, summed over steps and those layers); and
         ``layer_kinds``, how many layers of each kind this replica serves
         (``LlamaConfig.kind_counts``: ``attention_dense`` alone for a dense
         decoder)."""

@@ -73,7 +73,20 @@ and runs no chunk past them) moved ``serve_ling3_repoctx.step1024`` and
 ``.step3072``, as it meant to: a ``reduce_sum`` of the mask and four
 kernels with one operand more. The twenty-one others, that cell's ``init``
 among them, are what its parent ``f43b5c9`` gives to the character: no
-length is made for a model without the operator.
+length is made for a model without the operator. PR 54 (the two-width flash
+forward is told its rows' lengths: ``llama_next_token`` makes them for a
+model with latent attention too, ``_layer`` -> ``_latent_attention`` ->
+``ops.attention.attention`` -> ``flash_attention_shared_rope`` carry them,
+and ``_flash_fwd_shared_rope`` takes a row's live blocks as a
+scalar-prefetched operand and computes no block past them) moved the six
+step programs of the three cells with latent attention,
+``serve_dsv2_docqa``, ``serve_dots3_longdoc`` and ``serve_ling3_repoctx``,
+as it meant to: a ``reduce_sum`` of the mask where there was none (the
+third cell had it), and every two-width kernel with one operand more and
+``lax`` primitives alone in its index maps. The seventeen others, every
+``init`` and the five other cells' steps and gradient, are what its parent
+``0f2f053`` gives to the character: the equal-width kernels, which they
+run, are not touched.
 """
 
 import hashlib
@@ -93,17 +106,17 @@ PROGRAMS = {
     "serve_lfm2_rag.step128": "69db9a37644bffff",
     "serve_lfm2_rag.step1408": "80b36b565a92faa6",
     "serve_dsv2_docqa.init": "91b10ec8ff63401e",
-    "serve_dsv2_docqa.step256": "b2397e30a8a6e355",
-    "serve_dsv2_docqa.step1792": "f451bead003d1ee6",
+    "serve_dsv2_docqa.step256": "3694036b0c6db661",
+    "serve_dsv2_docqa.step1792": "d4eca275aeee6adb",
     "serve_dots3_longdoc.init": "af5788ce0837f29f",
-    "serve_dots3_longdoc.step2560": "3b1382529e1694a0",
-    "serve_dots3_longdoc.step5120": "e99df469b56665d0",
+    "serve_dots3_longdoc.step2560": "bc492a5899efa585",
+    "serve_dots3_longdoc.step5120": "62f8cde7b9c7635f",
     "serve_granite_toolcalls.init": "ce77369b6d8feac3",
     "serve_granite_toolcalls.step256": "4adf109acac83db4",
     "serve_granite_toolcalls.step1024": "ee9ab836cd96137f",
     "serve_ling3_repoctx.init": "1e230ff66f2de897",
-    "serve_ling3_repoctx.step1024": "8b4dd9cfe633fa0c",
-    "serve_ling3_repoctx.step3072": "8fcdcbb4de21164f",
+    "serve_ling3_repoctx.step1024": "093dcdc0ed5ccc8c",
+    "serve_ling3_repoctx.step3072": "c2d07b3b2dc38a22",
 }
 
 

@@ -414,3 +414,37 @@ def test_the_delta_rules_kernel_compiles_told_its_rows_lengths(
     # buffers, 4 states of [128, 128] float32 in, out and kept, and the
     # body's own [128, 128] matrices: some 8 MB
     assert used and 2 * 2 ** 20 < max(used) <= fa.VMEM_LIMIT_BYTES
+
+
+# the two-width forward told its rows' lengths (PR 54), each of its three
+# forms at its cell's longest bucket and batch: Mosaic takes the scalar
+# prefetch, the index maps held at a row's last live blocks and the body
+# under its condition, in the VMEM it took without them
+@pytest.mark.parametrize("form, rows, seq, own", [
+    ("plain", 8, 1792, 128), ("window", 4, 5120, 192),
+    ("selected", 4, 5120, 128)])
+def test_the_two_width_forward_compiles_told_its_rows_lengths(
+        form, rows, seq, own, one_chip, compiled_for_tpu):
+    def of(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q, q_rope, v = (of(rows, 2, seq, own), of(rows, 2, seq, 64),
+                    of(rows, 2, seq, 128))
+    keep = of(rows, seq, seq, dtype=jnp.int8) if form == "selected" else None
+    compiled = jax.jit(
+        lambda q, qr, k, kr, v, keep, n: fa._flash_fwd_shared_rope(
+            q, qr, k, kr, v, scale=0.1, causal=True, keep=keep, lengths=n,
+            window=513 if form == "window" else None)
+    ).lower(q, q_rope, q, of(rows, seq, 64), v, keep,
+            of(rows, dtype=jnp.int32)).compile()
+    name = {"plain": fa.SHARED_ROPE_TRACE_NAME,
+            "window": fa.WINDOW_TRACE_NAME,
+            "selected": fa.SELECTED_TRACE_NAME}[form]
+    assert "tpu_custom_call" in compiled.as_text()
+    assert name in compiled.as_text()
+    tile = fa.flash_tiles(seq, seq, head_dim=own + 128, value_dim=128)
+    reckoned = fa.tile_vmem_bytes(*tile, head_dim=own + 128, value_dim=128)
+    used = [n for n in _scoped_vmem(compiled) if n]
+    assert used and max(used) <= min(
+        reckoned + (2 * tile[0] * tile[1] if keep is not None else 0),
+        fa.VMEM_LIMIT_BYTES)

@@ -7,7 +7,10 @@ latent attention, routed experts; the kernel interpreted): the tokens and
 the rows' own hidden states are, to the bit, those of the same step with
 the lengths withheld from the kernel; and ``LlamaGenerator._step`` counts
 the chunks the kernel was told to skip, ``kda_chunks_skipped``, as the
-grid's less the live ones, and none for a model without the operator."""
+grid's less the live ones, and none for a model without the operator. The
+two-width flash forward of the model's latent layer is told the same
+lengths (PR 54; the kernel's own tests are ``tests/test_flash_lengths.py``)
+and ``flash_blocks_skipped`` counts the blocks it did not compute."""
 
 import jax
 import jax.numpy as jnp
@@ -127,6 +130,44 @@ def test_the_step_counts_the_chunks_the_kernel_skipped(generator):
                                            - stats["kda_chunks_live"])
 
 
+def test_the_step_counts_the_blocks_the_flash_forward_skipped(
+        generator, monkeypatch):
+    """The same steps over the model's one latent layer (2 heads), at
+    tiles of 128: a bucket of 256 is 2 x 2 blocks a (row, head), 3 of them
+    at or under the diagonal, and a bucket of 128 is ONE, where an empty
+    row's alone is skipped."""
+    from ray_tpu.ops.pallas import flash_attention as fa
+
+    monkeypatch.setattr(fa, "flash_tiles", lambda *a, **kw: (128, 128))
+    fa._shared_rope_steps.cache_clear()
+    gen = generator(ling_shaped())
+    for name in ("flash_blocks_run", "flash_blocks_live",
+                 "flash_blocks_skipped"):
+        assert name in gen.STEP_COUNTERS
+        assert name in LlamaGenerator.engine_stats.__doc__
+    states = [gen._prefill({"prompt": list(range(1, n + 1)), "max_new": 2},
+                           "") for n in (130, 5)] + [None, None]
+    gen._step("", states)
+    stats = gen.engine_stats()
+    # the long row's blocks are all live, the short row's first alone
+    assert stats["flash_blocks_run"] == 2 * 4 * 3
+    assert stats["flash_blocks_live"] == 2 * (3 + 1)
+    assert stats["flash_blocks_skipped"] == 2 * (0 + 2 + 3 + 3)
+    # the short row alone, at a bucket of 128: the three empty rows' block
+    gen._step("", [None, states[1], None, None])
+    assert gen.engine_stats()["flash_blocks_skipped"] == 2 * (8 + 3)
+    # three whole rows of 128 and an empty one: the empty one's
+    whole = [gen._prefill({"prompt": [7] * 128, "max_new": 2}, "")
+             for _ in range(3)]
+    gen._step("", whole + [None])
+    stats = gen.engine_stats()
+    assert stats["flash_blocks_run"] == 2 * 4 * (3 + 1 + 1)
+    assert stats["flash_blocks_skipped"] == 2 * (8 + 3 + 1)
+    assert stats["flash_blocks_skipped"] == (stats["flash_blocks_run"]
+                                             - stats["flash_blocks_live"])
+    fa._shared_rope_steps.cache_clear()
+
+
 def test_a_model_without_the_operator_skips_no_chunk(generator):
     gen = generator(LlamaConfig.debug_1l())
     gen._step("", [gen._prefill({"prompt": [1, 2, 3], "max_new": 2}, ""),
@@ -134,3 +175,4 @@ def test_a_model_without_the_operator_skips_no_chunk(generator):
     stats = gen.engine_stats()
     assert stats["positions_computed"] == 4 * 128
     assert stats["kda_chunks_skipped"] == 0 == stats["kda_chunks_run"]
+    assert stats["flash_blocks_skipped"] == 0 == stats["flash_blocks_run"]
