@@ -70,6 +70,16 @@ Design notes (why this is not a torch translation):
   come latent attention with full-rank queries (``q_lora_rank`` 0: one
   matrix ``wq``, no ``wq_a`` and no norm between) and a head-wise gate on
   the plain ``latent`` operator too.
+- An eighth operator, ``"sliding_attention"`` (Mellum2-12B-A2.5B has it
+  in three layers of four): grouped-query attention whose query ``t`` sees
+  keys ``t - sliding_window + 1 .. t``. Its leaves are the ``attention``
+  layers' own, so ``_layer`` is told which it is (``operator``); its
+  queries and keys turn under plain rope while the ``attention`` layers
+  beside it take ``rope_scaling`` (YaRN: ``_yarn_rope``); a forward pass
+  hands ``ops.attention`` the window (the equal-width flash forward walks
+  the window's key blocks alone), and ``llama_decode`` keeps a sliding
+  layer's last ``sliding_window`` keys and values where an ``attention``
+  layer keeps ``max_len``.
 - Attention dispatches to ``ray_tpu.ops`` (Pallas flash attention on TPU,
   reference einsum path elsewhere; ring attention when the seq axis > 1).
 - bfloat16 activations / fp32 params+optimizer by default: MXU-native.
@@ -160,6 +170,9 @@ class RopeScaling:
 
 # the operators that `_latent_attention` computes
 LATENT_OPERATORS = ("latent", "window", "indexed")
+# the operators whose leaves are grouped-query attention's: every causal
+# key, or the last `sliding_window` of them
+ATTENTION_OPERATORS = ("attention", "sliding")
 
 
 class LatentWidths(NamedTuple):
@@ -266,6 +279,10 @@ class LlamaConfig:
     # head_gate: each head's output times sigmoid(u W_g)[head] before W_o,
     # in both. latent_rescale: c_q times (hidden / q_rank) ** 0.5 and c_kv
     # times (hidden / kv_rank) ** 0.5 after their norms, in all three.
+    # sliding_window is also the window of layer_types' "sliding_attention"
+    # (Mellum2-12B-A2.5B): grouped-query attention at the attention layers'
+    # own widths, under plain rope, while the "full_attention" layers
+    # beside it take rope_scaling.
     sliding_window: int = 0
     swa_num_heads: int = 0
     swa_q_lora_rank: int = 0
@@ -342,13 +359,14 @@ class LlamaConfig:
 
     def layer_kinds(self) -> Tuple[str, ...]:
         """Each layer's kind, ``<operator>_<feed-forward>``: ``attention``,
-        ``conv``, ``latent``, ``window``, ``indexed``, ``mamba`` or ``kda``,
-        then ``routed`` (experts) or ``dense``."""
+        ``sliding``, ``conv``, ``latent``, ``window``, ``indexed``,
+        ``mamba`` or ``kda``, then ``routed`` (experts) or ``dense``."""
         ops = tuple(self.layer_types) or ("full_attention",) * self.num_layers
         if len(ops) != self.num_layers:
             raise ValueError(f"layer_types names {len(ops)} layers, "
                              f"num_layers is {self.num_layers}")
-        names = {"full_attention": "attention", "conv": "conv",
+        names = {"full_attention": "attention",
+                 "sliding_attention": "sliding", "conv": "conv",
                  "latent_attention": "latent",
                  "window_latent_attention": "window",
                  "indexed_latent_attention": "indexed", "mamba": "mamba",
@@ -445,7 +463,8 @@ class LlamaConfig:
         ih, ihd = self.index_heads, self.index_head_dim
         inner, conv, proj = self.mamba_widths()
         kda_inner, kda_proj = self.kda_widths()
-        half = {"attention": h * (q + 2 * kv) + q * h + norms,
+        grouped_query = h * (q + 2 * kv) + q * h + norms
+        half = {"attention": grouped_query, "sliding": grouped_query,
                 # in-projection, beta's, the taps, A_log a head, dt_bias a
                 # channel, the norm's one weight a head's channel, out
                 "kda": (h * kda_proj + h * self.kda_heads
@@ -532,7 +551,12 @@ def _lora_targets(cfg: LlamaConfig, lcfg: LoraConfig
     if unknown:
         raise ValueError(f"LoRA targets {unknown}: expected some of "
                          f"{sorted(half)}")
-    out = {kind: tuple(t for t in lcfg.targets if half[t] in kind.split("_"))
+    def halves(kind):
+        operator, ffn = kind.split("_")
+        return ("attention" if operator in ATTENTION_OPERATORS else operator,
+                ffn)
+
+    out = {kind: tuple(t for t in lcfg.targets if half[t] in halves(kind))
            for kind in cfg.kind_counts()}
     absent = sorted(t for t in lcfg.targets
                     if not any(t in ts for ts in out.values()))
@@ -627,7 +651,7 @@ def _kind_logical_axes(cfg: LlamaConfig, kind: str) -> Dict[str, Any]:
     over layers."""
     operator, ffn = kind.split("_")
     layer = {"attn_norm": ("norm",), "mlp_norm": ("norm",)}
-    if operator == "attention":
+    if operator in ATTENTION_OPERATORS:
         layer.update(wq=("embed", "heads", "head_dim"),
                      wk=("embed", "kv_heads", "head_dim"),
                      wv=("embed", "kv_heads", "head_dim"),
@@ -705,7 +729,7 @@ def init_llama(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
         """The L layers of a kind, stacked; ks: ten keys, of which the
         eighth and ninth are the embedding's and the head's."""
         operator, ffn = kind.split("_")
-        if operator == "attention":
+        if operator in ATTENTION_OPERATORS:
             layers = {
                 "wq": norm_init((L, h, nh, hd), ks[0], h),
                 "wk": norm_init((L, h, nkv, hd), ks[1], h),
@@ -832,7 +856,8 @@ def init_llama(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
                           w_down=norm_init((L, m, h), ks[6], m))
         layers.update(attn_norm=jnp.ones((L, h), pd),
                       mlp_norm=jnp.ones((L, h), pd))
-        if operator == "attention" and (cfg.qk_norm or cfg.qk_head_norm):
+        if operator in ATTENTION_OPERATORS and (cfg.qk_norm
+                                                or cfg.qk_head_norm):
             # over the whole projection, or one weight shared by the heads
             q, k = (nh * hd, nkv * hd) if cfg.qk_norm else (hd, hd)
             layers.update(q_norm=jnp.ones((L, q), pd),
@@ -1326,9 +1351,15 @@ def _layer(cfg: LlamaConfig, x: jax.Array, lp: Dict[str, jax.Array],
     not attention; ``router`` makes the
     feed-forward the routed experts and
     not the dense SwiGLU. Which latent operator it is (``latent``,
-    ``window``, ``indexed``) the leaves do not say: ``operator`` does.
+    ``window``, ``indexed``) the leaves do not say, nor whether attention
+    sees every causal key or the last ``sliding_window`` (``sliding``:
+    plain rope there, ``rope_scaling``'s YaRN in the ``attention`` layers):
+    ``operator`` does.
     ``kv_cache`` is the layer's own state in an incremental decode: (keys,
-    values) for attention, the last rows of ``z`` for the short
+    values) for attention (``[B, max_len, kv_heads, head_dim]`` each, the
+    new rows written at ``cache_index``; of a sliding layer ``[B,
+    sliding_window, ...]``, the last rows before this call in order, the
+    new ones pushed in at the end), the last rows of ``z`` for the short
     convolution (``_short_conv``), the latent rows for latent attention
     (``_latent_attention``), the taps' rows and the scan's state for the
     state-space mixer (``_mamba``), the taps' rows and the rule's state
@@ -1390,9 +1421,19 @@ def _layer(cfg: LlamaConfig, x: jax.Array, lp: Dict[str, jax.Array],
         elif cfg.qk_head_norm:  # over each head's head_dim, one weight
             q = _rms_norm(q, lp["q_norm"], cfg.rms_eps)
             k = _rms_norm(k, lp["k_norm"], cfg.rms_eps)
+        window = cfg.sliding_window if operator == "sliding" else None
+        if operator == "sliding" and not window:
+            raise ValueError("a sliding_attention layer needs "
+                             "sliding_window > 0")
         if cfg.use_rope:
-            q = _rope(q, positions, cfg.rope_theta)
-            k = _rope(k, positions, cfg.rope_theta)
+            # YaRN is the full layers': a window's keys lie near the query
+            scaling = None if window else cfg.rope_scaling
+            if scaling is not None and scaling.softmax_amplitude() != 1.0:
+                raise ValueError(
+                    "attention scales its scores by head_dim ** -0.5: a "
+                    "rope_scaling with mscale_all_dim is latent attention's")
+            q = _yarn_rope(q, positions, cfg.rope_theta, scaling)
+            k = _yarn_rope(k, positions, cfg.rope_theta, scaling)
         if cfg.attention_multiplier:
             # the softmax scale's ratio to the kernels' head_dim ** -0.5,
             # taken by the queries: where it is a power of two (granite's
@@ -1404,7 +1445,20 @@ def _layer(cfg: LlamaConfig, x: jax.Array, lp: Dict[str, jax.Array],
         q = constrain(q, ("batch", "seq", "heads", None))
         k = constrain(k, ("batch", "seq", "kv_heads", None))
         new_cache = None
-        if kv_cache is not None:
+        if kv_cache is not None and window:
+            # the last `window` rows before this call, then the new ones:
+            # slot j holds position cache_index - window + j, so a query's
+            # place among the slots is its own plus `window`, and the slots
+            # before the sequence's start (zeros) are no keys
+            S = q.shape[1]
+            k, v = (jnp.concatenate([held, new.astype(held.dtype)], axis=1)
+                    for held, new in zip(kv_cache, (k, v)))
+            new_cache = (k[:, S:], v[:, S:])
+            started = cache_index - window + jnp.arange(window + S) >= 0
+            attn_out = attention(q, k, v, impl="reference", causal=True,
+                                 q_offset=window, window=window,
+                                 keep=started[None, None, :])
+        elif kv_cache is not None:
             ck, cv = kv_cache  # [B, max_S, nkv, d]
             ck = jax.lax.dynamic_update_slice_in_dim(ck, k, cache_index,
                                                      axis=1)
@@ -1419,7 +1473,7 @@ def _layer(cfg: LlamaConfig, x: jax.Array, lp: Dict[str, jax.Array],
                 attn_out = _ring_seq_attention(q, k, v)
             else:
                 attn_out = attention(q, k, v, impl=cfg.attn_impl,
-                                     causal=True)
+                                     causal=True, window=window)
         attn_out = constrain(attn_out, ("batch", "seq", "heads", None))
         x = (x + _res(jnp.einsum("bsnd,ndh->bsh", attn_out,
                                  lp["wo"].astype(dt)))
@@ -1450,7 +1504,7 @@ def init_decode_state(cfg: LlamaConfig, batch: int, max_len: int) -> list:
     ``[batch, max_len, kv_lora_rank + qk_rope_head_dim]`` for latent
     attention (an indexed operator's index key ``[index_head_dim]`` after
     them; a window operator keeps its last ``sliding_window`` rows and not
-    ``max_len``), and for a state-space mixer a pair that is no rows of a
+    ``max_len``, and so do a sliding layer's keys and values), and for a state-space mixer a pair that is no rows of a
     cache: the last ``mamba_conv_kernel - 1`` rows of ``[x | B | C]``
     ``[batch, mamba_conv_kernel - 1, inner + 2 mamba_state]`` and the
     scan's state ``[batch, mamba_heads, mamba_head_dim, mamba_state]``
@@ -1466,6 +1520,8 @@ def init_decode_state(cfg: LlamaConfig, batch: int, max_len: int) -> list:
     operators = [kind.split("_")[0] for kind in cfg.layer_kinds()]
     shapes = {
         "attention": (batch, max_len, cfg.num_kv_heads, cfg.head_dim),
+        "sliding": (batch, cfg.sliding_window, cfg.num_kv_heads,
+                    cfg.head_dim),
         "conv": (batch, cfg.conv_kernel - 1, cfg.hidden),
         **{op: latent_rows(op) for op in LATENT_OPERATORS}}
     zeros = {op: jnp.zeros(shapes[op], cfg.dtype)
@@ -1482,8 +1538,8 @@ def init_decode_state(cfg: LlamaConfig, batch: int, max_len: int) -> list:
                        3 * cfg.kda_widths()[0]), cfg.dtype),
             jnp.zeros((batch, cfg.kda_heads, cfg.kda_head_dim,
                        cfg.kda_head_dim), jnp.float32))
-    return [(zeros[op], zeros[op]) if op == "attention" else zeros[op]
-            for op in operators]
+    return [(zeros[op], zeros[op]) if op in ATTENTION_OPERATORS
+            else zeros[op] for op in operators]
 
 
 def llama_decode(

@@ -14,9 +14,10 @@ Fewer keys than the causal ones. ``window``: query ``t`` sees keys ``t -
 window + 1 .. t``, its own among them. ``keep [B, S, S_kv]`` (bool): query
 ``t`` of row ``b`` sees key ``s`` only where ``keep[b, t, s]``, every head
 alike (an indexer's choice: ``index_scores`` gives what it is made from).
-Both come on top of ``causal``; the flash kernel takes them at two widths
+Both come on top of ``causal``; the flash kernel takes both at two widths
 (the window's blocks outside it are not visited; the choice is a mask a
-block).
+block) and the window at equal widths too
+(``flash_attention.flash_attention_window``: forward only).
 
 ``lengths [B]`` int32: how many of a row's positions are its own where a
 batch's rows are padded on the right to one length (a serving step's are).
@@ -105,7 +106,7 @@ def _flash_per_shard(q: jax.Array, k: jax.Array, v: jax.Array,
     scope. A ``pallas_call`` has no partitioning rule: left to GSPMD its
     operands are all-gathered and every chip computes the whole batch."""
     from ray_tpu.ops.pallas.flash_attention import (
-        flash_attention, flash_attention_shared_rope)
+        flash_attention, flash_attention_shared_rope, flash_attention_window)
     from ray_tpu.parallel.sharding import ambient_mesh, logical_to_spec
 
     mesh = ambient_mesh()
@@ -119,15 +120,22 @@ def _flash_per_shard(q: jax.Array, k: jax.Array, v: jax.Array,
         return flash_attention_shared_rope(
             q, q_rope, k, k_rope, v, scale, causal, window,
             None if keep is None else keep.astype(jnp.int8), lengths)
-    if window is not None or keep is not None:
+    if keep is not None:
         raise NotImplementedError(
-            "the equal-width flash kernels see every causal key: a window "
-            "or a choice of keys needs the two-width forward or "
-            "impl='reference'")
+            "the equal-width flash kernels take no choice of keys: that "
+            "needs the two-width forward or impl='reference'")
     if scale is not None or v.shape[3] != q.shape[3]:
         raise NotImplementedError(
             "the equal-width flash kernels scale by head_dim ** -0.5 and "
             "take values of the keys' width")
+    if window is not None:
+        if not causal:
+            raise ValueError("a window is the causal keys' last ones")
+        if mesh is not None and mesh.size > 1:
+            raise NotImplementedError(
+                "the equal-width flash forward under a window runs on one "
+                "device; a mesh needs impl='reference'")
+        return flash_attention_window(q, k, v, window)
     if mesh is None or mesh.size == 1:
         return flash_attention(q, k, v, causal)
     if mesh.shape.get("seq", 1) > 1:
@@ -189,7 +197,7 @@ def attention(
                 and q.shape[1] == k.shape[1]
                 and q.shape[1] % 128 == 0
                 and (q_rope is not None or scale is None)
-                and (q_rope is not None or (window is None and keep is None))
+                and (q_rope is not None or keep is None)
                 and takes_head_dim(q.shape[3] + shared, v.shape[3],
                                    shared_dim=shared))
             if kernel_takes_it:

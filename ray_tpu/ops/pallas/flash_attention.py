@@ -70,8 +70,9 @@ equal-width path keeps the name it has there. There is no backward at two
 widths: differentiating it raises and says so (no cell trains such a
 model; the model layer's ``reference`` path differentiates).
 
-Fewer keys than the causal ones (dots3-note-prev's two operators), at two
-widths alone. ``window``: a query sees its last ``window`` keys, and the
+Fewer keys than the causal ones (dots3-note-prev's two operators: at two
+widths; the window at equal widths too, below). ``window``: a query sees
+its last ``window`` keys, and the
 innermost grid dim is as long as the most key blocks a query block's
 window reaches (two or three at a window of 513), walked from the
 window's first block, so the blocks outside it cost neither a matmul nor a
@@ -82,6 +83,18 @@ block is read beside the keys' and masks the scores
 not before, so no block is skipped for it: the kernel computes the causal
 blocks whole, and its share of a roofline reckoned over the KEPT pairs
 says so. A call that passes neither compiles what it always did.
+
+The window at equal widths (Mellum2-12B-A2.5B's sliding layers: 32 query
+heads on 4 key/value heads of 128 under a window of 1024).
+``flash_attention_window`` is ``_flash_fwd`` told the window: the same
+kernel body with the second test beside the causal one, the same walk of
+the window's key blocks from its first (two blocks of 1024 at a window of
+1024 whatever the length, where the causal walk is up to eight at 8192),
+the key/value head still ``h // n_rep`` in the index map, under a scope of
+its own (``EQUAL_WINDOW_TRACE_NAME``). Forward only: differentiating it
+raises and says so, as the two-width forward does; ``_dq_kernel`` and
+``_dkv_kernel`` see every causal key (ROADMAP M5). ``flash_attention``
+itself is the call it was.
 
 The rows' lengths, at two widths alone (``lengths [B]`` int32). A batch's
 rows are padded on the right to one length, and a block whose first
@@ -105,6 +118,7 @@ whole and the call is the one it was: no operand, no test, no ``min``
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import List, Optional, Tuple
 
@@ -224,7 +238,14 @@ def flash_tiles(sq: int, skv: int, *, head_dim: int = 128,
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, scale: float, causal: bool, block_q: int, block_k: int):
+                *, scale: float, causal: bool, block_q: int, block_k: int,
+                window: Optional[int] = None):
+    """With ``window`` the innermost grid dim walks the key blocks that the
+    query block's window reaches, from the window's first
+    (``_first_key_block``), and a query sees its last ``window`` keys; a
+    row of a block in which it sees none fills with ``exp(0)`` and the
+    first block in which it sees one rescales that away, as in
+    ``_fwd_shared_rope_kernel``."""
     iq = pl.program_id(2)
     ik = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -235,10 +256,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
+    # the key block this step is at: under a window the walk starts at the
+    # window's first block
+    at = ik if window is None else ik + _first_key_block(
+        iq, block_q, block_k, window)
     # Skip fully-masked blocks (strictly above the causal diagonal).
     run = True
     if causal:
-        run = ik * block_k <= iq * block_q + block_q - 1
+        run = at * block_k <= iq * block_q + block_q - 1
 
     @pl.when(run)
     def _compute():
@@ -251,9 +276,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         if causal:
             q_pos = iq * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
-            k_pos = ik * block_k + jax.lax.broadcasted_iota(
+            k_pos = at * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+            seen = q_pos >= k_pos
+            if window is not None:
+                seen = seen & (q_pos - k_pos < window)
+            s = jnp.where(seen, s, _NEG_INF)
         m_prev = m_scr[:, :1]                        # [bq, 1]
         m_cur = jnp.max(s, axis=-1, keepdims=True)   # [bq, 1]
         m_new = jnp.maximum(m_prev, m_cur)
@@ -278,29 +306,57 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
 
 
 def _flash_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
-               causal: bool) -> Tuple[jax.Array, jax.Array]:
-    """q [B,H,S,D], k/v [B,KVH,S,D] → (o [B,H,S,D], lse [B,H,S,128])."""
+               causal: bool, window: Optional[int] = None
+               ) -> Tuple[jax.Array, jax.Array]:
+    """q [B,H,S,D], k/v [B,KVH,S,D] → (o [B,H,S,D], lse [B,H,S,128]).
+    ``window``: a query sees its last ``window`` keys, and the innermost
+    grid dim is as long as the most key blocks a query block's window
+    reaches (module docstring); None is the call it always was."""
     B, H, Sq, D = q.shape
     KVH, Skv = k.shape[1], k.shape[2]
     n_rep = H // KVH
     scale = D ** -0.5
     block_q, block_k = flash_tiles(Sq, Skv, head_dim=D)
-    grid = (B, H, Sq // block_q, Skv // block_k)
+    key_blocks, told = Skv // block_k, {}
+    keys = lambda b, h, iq, ik: (b, h // n_rep, ik, 0)  # noqa: E731
+    if window is not None:
+        if not (causal and Sq == Skv):
+            raise ValueError("a window is a prefill's: causal, the queries' "
+                             "positions the keys'")
+        key_blocks = _window_key_blocks(Sq, block_q, block_k, window)
+        told = {"window": window}
+
+        def keys(b, h, iq, ik):
+            # from the window's first block on, and past the diagonal the
+            # diagonal's again, which costs no copy
+            return (b, h // n_rep, jax.lax.min(
+                ik + _first_key_block(iq, block_q, block_k, window),
+                jax.lax.div(iq * block_q + block_q - 1, block_k)), 0)
 
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal,
-        block_q=block_q, block_k=block_k)
+        block_q=block_q, block_k=block_k, **told)
+    # the windowed call under a scope of its own, which is what the device
+    # trace then calls it; the other keeps its caller's
+    scope = (contextlib.nullcontext() if window is None
+             else jax.named_scope(EQUAL_WINDOW_TRACE_NAME))
+    with scope:
+        return _fwd_call(kernel, (B, H, Sq // block_q, key_blocks), keys,
+                         q, k, v, block_q, block_k)
 
+
+def _fwd_call(kernel, grid, keys, q, k, v, block_q: int, block_k: int):
+    """``_flash_fwd``'s ``pallas_call``: ``keys`` is the index map of the
+    key and value blocks."""
+    B, H, Sq, D = q.shape
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, block_q, D),
                          lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_k, D),
-                         lambda b, h, iq, ik: (b, h // n_rep, ik, 0)),
-            pl.BlockSpec((1, 1, block_k, D),
-                         lambda b, h, iq, ik: (b, h // n_rep, ik, 0)),
+            pl.BlockSpec((1, 1, block_k, D), keys),
+            pl.BlockSpec((1, 1, block_k, D), keys),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_q, D),
@@ -543,6 +599,37 @@ def _fa_bwd(causal, res, g):
 
 
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
+
+
+# What the device trace calls the equal-width forward under a window (a
+# Pallas call's HLO instruction takes the name of its innermost named
+# scope): the full layers' call beside it keeps its caller's name.
+EQUAL_WINDOW_TRACE_NAME = "flash_fwd_sliding"
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def flash_attention_window(q: jax.Array, k: jax.Array, v: jax.Array,
+                           window: int) -> jax.Array:
+    """``flash_attention``'s forward under a window (module docstring): q
+    ``[B, S, H, D]``, k, v ``[B, S, KVH, D]`` → ``[B, S, H, D]``; causal,
+    query ``t`` sees keys ``t - window + 1 .. t``. Forward only."""
+    o, _ = _flash_fwd(jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
+                      jnp.swapaxes(v, 1, 2), causal=True, window=window)
+    return jnp.swapaxes(o, 1, 2)
+
+
+def _fa_window_fwd(q, k, v, window):
+    return flash_attention_window(q, k, v, window), None
+
+
+def _fa_window_bwd(window, res, g):
+    raise NotImplementedError(
+        "the equal-width flash forward under a window has no backward "
+        "(`_dq_kernel` and `_dkv_kernel` see every causal key): train such "
+        "a model with attn_impl='reference'")
+
+
+flash_attention_window.defvjp(_fa_window_fwd, _fa_window_bwd)
 
 
 # ------------------------------------------- two widths, a shared rotary key

@@ -99,7 +99,9 @@ class LlamaGenerator:
     # operators' live queries could have attended (the causal ones),
     # `index_keys_kept` those their indexers' choice kept (counted on the
     # device), `window_keys_kept` the pairs inside the window operators'
-    # windows, each summed over steps and those layers; `ssm_chunks_run` the
+    # windows (latent attention's `window`, grouped-query attention's
+    # `sliding`) and `window_keys_seen` the causal pairs of the same
+    # queries, each summed over steps and those layers; `ssm_chunks_run` the
     # chunks the state-space layers' scans ran (layers x rows x chunks of
     # the padded length) and `ssm_chunks_live` those among them that hold
     # one of a row's own positions; `kda_chunks_run` and `kda_chunks_live`
@@ -117,7 +119,8 @@ class LlamaGenerator:
                      "expert_pairs_here", "expert_pairs_all",
                      "expert_pairs_skipped", "expert_rows_moved",
                      "expert_rows_all", "step_device_s", "index_keys_kept",
-                     "index_keys_seen", "window_keys_kept", "ssm_chunks_run",
+                     "index_keys_seen", "window_keys_kept",
+                     "window_keys_seen", "ssm_chunks_run",
                      "ssm_chunks_live", "kda_chunks_run", "kda_chunks_live",
                      "kda_chunks_skipped", "flash_blocks_run",
                      "flash_blocks_live", "flash_blocks_skipped")
@@ -148,12 +151,12 @@ class LlamaGenerator:
                        if kind.startswith(operator + "_"))
 
         # the layers whose query attends an indexer's choice of its keys,
-        # those whose query sees a window of them, and those whose
-        # operator carries a state over the sequence in chunks (the
-        # state-space scan's, the delta rule's)
-        (self._indexed_layers, self._window_layers, self._ssm_layers,
-         self._kda_layers) = map(layers_of,
-                                 ("indexed", "window", "mamba", "kda"))
+        # those whose operator carries a state over the sequence in chunks
+        # (the state-space scan's, the delta rule's), and those whose query
+        # sees a window of its keys (latent or grouped-query attention)
+        (self._indexed_layers, self._ssm_layers,
+         self._kda_layers) = map(layers_of, ("indexed", "mamba", "kda"))
+        self._window_layers = layers_of("window") + layers_of("sliding")
         # the latent operators, whose prefill is the two-width flash
         # forward's: (layers, their widths) each
         self._flash_layers = [
@@ -336,10 +339,11 @@ class LlamaGenerator:
                 w = self._cfg.sliding_window
                 inside = np.where(n >= w, n * w - w * (w - 1) // 2,
                                   n * (n + 1) // 2)
-                counts["index_keys_seen"] += int(
-                    (n * (n + 1) // 2).sum()) * self._indexed_layers
+                causal = int((n * (n + 1) // 2).sum())
+                counts["index_keys_seen"] += causal * self._indexed_layers
                 counts["window_keys_kept"] += (int(inside.sum())
                                                * self._window_layers)
+                counts["window_keys_seen"] += causal * self._window_layers
             # the scans run every row of the batch over the whole padded
             # length, and the delta rule's grid is as large; a chunk is
             # live while its first position is one of its row's own
@@ -450,9 +454,11 @@ class LlamaGenerator:
         itself and brought to the host with the step's tokens; both summed
         over steps and indexed layers, 0 for a model without an indexer)
         and ``window_keys_kept`` (over the live queries of the ``window``
-        layers, the pairs inside the window: ``min(t + 1,
-        sliding_window)`` for the query at position ``t`` of its row,
-        reckoned on the host; summed over steps and window layers);
+        and the ``sliding`` layers, the pairs inside the window: ``min(t +
+        1, sliding_window)`` for the query at position ``t`` of its row,
+        reckoned on the host; summed over steps and those layers) beside
+        ``window_keys_seen`` (the causal pairs of the same queries, ``t +
+        1`` each: what the window leaves of them is the ratio);
         ``ssm_chunks_run`` and ``ssm_chunks_live`` (over the layers whose
         operator is ``mamba``: the chunks of ``mamba_chunk`` positions
         their scans ran, rows of the batch x chunks of the padded length,

@@ -86,7 +86,13 @@ third cell had it), and every two-width kernel with one operand more and
 ``lax`` primitives alone in its index maps. The seventeen others, every
 ``init`` and the five other cells' steps and gradient, are what its parent
 ``0f2f053`` gives to the character: the equal-width kernels, which they
-run, are not touched.
+run, are not touched. PR 55 (Mellum2-12B-A2.5B: a ``sliding`` operator on
+the ``attention`` layers' leaves, ``_fwd_kernel`` and ``_flash_fwd`` told a
+window behind ``window=None``, ``_layer``'s attention branch through
+``_yarn_rope``, whose ``None`` is ``_rope``) moved none of the twenty-three:
+they are what its parent ``760214b`` gives to the character;
+``serve_mellum2_projctx``'s three are new (traced at 8 rows like the others'
+steps; the cell serves 4).
 """
 
 import hashlib
@@ -117,6 +123,9 @@ PROGRAMS = {
     "serve_ling3_repoctx.init": "1e230ff66f2de897",
     "serve_ling3_repoctx.step1024": "093dcdc0ed5ccc8c",
     "serve_ling3_repoctx.step3072": "c2d07b3b2dc38a22",
+    "serve_mellum2_projctx.init": "26e74b5260e71edd",
+    "serve_mellum2_projctx.step4096": "2e886e4ebcae3f07",
+    "serve_mellum2_projctx.step8192": "bfe0ceee314e9591",
 }
 
 

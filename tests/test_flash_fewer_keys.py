@@ -1,8 +1,10 @@
 """Attention over fewer keys than the causal ones, interpreted on the CPU:
 the two-width flash forward under a window and under a choice of keys
-against ``reference_attention``, and the indexer's score kernel against
-its einsums. The lengths are several blocks long and the window and the
-choice are shorter than they are, so blocks are skipped and keys masked."""
+against ``reference_attention``, the equal-width forward under a window
+(grouped-query heads, 8 query heads a key head), and the indexer's score
+kernel against its einsums. The lengths are several blocks long and the
+window and the choice are shorter than they are, so blocks are skipped and
+keys masked."""
 
 import jax
 import jax.numpy as jnp
@@ -96,10 +98,78 @@ def test_the_windows_walk_is_as_long_as_its_widest_reach(
         assert last - first + 1 <= blocks
 
 
+def grouped(seq, heads=8, kv_heads=1, d=32, batch=2, seed=5):
+    ks = jax.random.split(jax.random.key(seed), 3)
+    return (jax.random.normal(ks[0], (batch, seq, heads, d)),
+            jax.random.normal(ks[1], (batch, seq, kv_heads, d)),
+            jax.random.normal(ks[2], (batch, seq, kv_heads, d)))
+
+
+# blocks of 128 (forced: `flash_tiles` gives one block up to 1152) and the
+# rule's own; a window smaller than, equal to and larger than a block, one
+# key, and longer than the sequence
+@pytest.mark.parametrize("window", [1, 37, 127, 128, 129, 300, 4000])
+@pytest.mark.parametrize("seq, tile", [(128, None), (512, 128), (512, None),
+                                       (768, 256)])
+def test_the_equal_width_window_against_the_reference(seq, tile, window,
+                                                      monkeypatch):
+    if tile:
+        monkeypatch.setattr(fa, "flash_tiles",
+                            lambda sq, skv, **kw: (tile, tile))
+    q, k, v = grouped(seq)
+    got = attention(q, k, v, impl="flash", window=window)
+    want = reference_attention(q, k, v, window=window)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    at = jnp.arange(seq)
+    inside = (at[:, None] >= at[None, :]) & (
+        at[:, None] - at[None, :] < window)
+    same = reference_attention(q, k, v, keep=jnp.broadcast_to(
+        inside, (2, seq, seq)))
+    np.testing.assert_allclose(want, same, rtol=1e-6, atol=1e-6)
+    if tile:  # the walk is the window's blocks and no other
+        assert fa._window_key_blocks(seq, tile, tile, window) == min(
+            seq // tile, -(-(window - 1) // tile) + 1)
+
+
+def test_the_equal_width_window_is_a_forward_alone_and_none_is_the_old_call():
+    q, k, v = grouped(256, heads=4, kv_heads=2)
+    plain = attention(q, k, v, impl="flash")
+    # a window as long as the sequence
+    np.testing.assert_allclose(
+        plain, attention(q, k, v, impl="flash", window=256),
+        rtol=1e-6, atol=1e-6)
+    # no window compiles what it did: `_flash_fwd` told `window=None` is
+    # the call without the argument, to the character
+    text = lambda **kw: str(jax.make_jaxpr(  # noqa: E731
+        lambda q, k, v: fa._flash_fwd(q, k, v, causal=True, **kw))(
+            *(jnp.swapaxes(a, 1, 2) for a in (q, k, v))))
+    assert text() == text(window=None)
+    assert "flash_fwd_sliding" not in text() and "window" not in text()
+    assert text(window=100) != text()
+    # the windowed call under a function and a scope of its own
+    call = lambda q, k, v: attention(  # noqa: E731
+        q, k, v, impl="flash", window=100)
+    assert "name=flash_attention_window" in str(
+        jax.make_jaxpr(call)(q, k, v))
+    assert fa.EQUAL_WINDOW_TRACE_NAME in jax.jit(call).lower(
+        q, k, v).as_text(debug_info=True)
+    assert fa.EQUAL_WINDOW_TRACE_NAME not in jax.jit(
+        lambda q, k, v: attention(q, k, v, impl="flash")).lower(
+            q, k, v).as_text(debug_info=True)
+    with pytest.raises(NotImplementedError, match="has no backward"):
+        jax.grad(lambda q: attention(q, k, v, impl="flash",
+                                     window=8).sum())(q)
+    with pytest.raises(ValueError, match="a prefill's"):
+        fa._flash_fwd(*(jnp.swapaxes(a, 1, 2) for a in (q, k, v)),
+                      causal=False, window=8)
+
+
 def test_what_the_kernels_do_not_take():
     q, k, v, rope = operands(128)
-    with pytest.raises(NotImplementedError, match="equal-width"):
-        attention(q, k, v, impl="flash", window=8)
+    with pytest.raises(NotImplementedError, match="no choice of keys"):
+        attention(q, k, v, impl="flash", keep=jnp.ones((2, 128, 128), bool))
+    with pytest.raises(ValueError, match="causal keys' last"):
+        attention(q, k, v, impl="flash", causal=False, window=8)
     with pytest.raises(ValueError, match="a prefill's"):
         attention(q, k, v, impl="flash", causal=False, window=8, **rope)
     with pytest.raises(ValueError, match="causal keys' last"):

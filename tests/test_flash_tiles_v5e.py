@@ -148,7 +148,10 @@ def test_the_two_width_forward_compiles_within_its_reckoning(
     (128, 4, 1536, 2048, 64), (384, 4, 1536, 2048, 64),
     (1408, 4, 1536, 2048, 64),
     (256, 6, 1536, 5120, 20), (1024, 6, 1536, 5120, 20),
-    (1792, 6, 1536, 5120, 20)])
+    (1792, 6, 1536, 5120, 20),
+    # serve_mellum2_projctx's (8, 896 from a hidden of 2304: seven lanes,
+    # column blocks of 128), 4 rows of 4096 and of 8192 as 8 of half that
+    (2048, 8, 896, 2304, 64), (4096, 8, 896, 2304, 64)])
 def test_grouped_matmul_compiles_within_its_reckoning(
         seq, pairs, width, hidden, experts, fused, one_chip,
         compiled_for_tpu):
@@ -300,6 +303,37 @@ def test_the_window_forward_compiles_and_walks_its_windows_blocks(
                                   value_dim=128)
     used = [n for n in _scoped_vmem(compiled) if n]
     assert used and max(used) <= reckoned <= fa.VMEM_LIMIT_BYTES
+
+
+# serve_mellum2_projctx's five buckets: the EQUAL-width forward told a
+# window of 1024, 8 query heads a key/value head of 128
+MELLUM2_BUCKETS = [4096, 5120, 6144, 7168, 8192]
+
+
+@pytest.mark.parametrize("seq", MELLUM2_BUCKETS)
+def test_the_equal_width_window_compiles_and_walks_two_key_blocks(
+        seq, one_chip, compiled_for_tpu):
+    def of(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    q, k = of(1, 8, seq, 128), of(1, 1, seq, 128)
+    compiled = jax.jit(
+        lambda q, k, v: fa._flash_fwd(q, k, v, causal=True, window=1024)
+    ).lower(q, k, k).compile()
+    assert fa.EQUAL_WINDOW_TRACE_NAME in compiled.as_text()
+    tiles = fa.flash_tiles(seq, seq, head_dim=128)
+    assert tiles == (1024, 1024)
+    # a window of 1024 reaches two key blocks of 1024 a query block, where
+    # the causal walk visits up to eight
+    assert fa._window_key_blocks(seq, *tiles, 1024) == 2
+    reckoned = fa.tile_vmem_bytes(*tiles, head_dim=128)
+    used = [n for n in _scoped_vmem(compiled) if n]
+    assert used and max(used) <= reckoned <= fa.VMEM_LIMIT_BYTES
+    # no window, no scope: the full layers' call keeps its caller's name
+    plain = jax.jit(
+        lambda q, k, v: fa._flash_fwd(q, k, v, causal=True)
+    ).lower(q, k, k).compile()
+    assert fa.EQUAL_WINDOW_TRACE_NAME not in plain.as_text()
 
 
 @pytest.mark.parametrize("seq", DOTS3_BUCKETS)
