@@ -1365,9 +1365,9 @@ def _layer(cfg: LlamaConfig, x: jax.Array, lp: Dict[str, jax.Array],
     state-space mixer (``_mamba``), the taps' rows and the rule's state
     for Kimi delta attention (``_kda``). ``lengths [B]`` int32, where a
     serving step hands them, are the right-padded rows' own lengths, for an
-    operator whose kernel can stop at a row's end: ``_kda``'s and
-    ``_latent_attention``'s do (``_mamba`` is not handed them yet: ROADMAP
-    S10 (2); nor is the equal-width flash forward). Every sub-layer's
+    operator whose kernel can stop at a row's end: ``_kda``'s,
+    ``_latent_attention``'s and attention's own flash forward do (``_mamba``
+    is not handed them yet: ROADMAP S10 (2)). Every sub-layer's
     output joins the residual times ``residual_multiplier``."""
     dt = cfg.dtype
     counts: Dict[str, jax.Array] = {}
@@ -1473,7 +1473,8 @@ def _layer(cfg: LlamaConfig, x: jax.Array, lp: Dict[str, jax.Array],
                 attn_out = _ring_seq_attention(q, k, v)
             else:
                 attn_out = attention(q, k, v, impl=cfg.attn_impl,
-                                     causal=True, window=window)
+                                     causal=True, window=window,
+                                     lengths=lengths)
         attn_out = constrain(attn_out, ("batch", "seq", "heads", None))
         x = (x + _res(jnp.einsum("bsnd,ndh->bsh", attn_out,
                                  lp["wo"].astype(dt)))
@@ -1766,17 +1767,15 @@ def llama_next_token(
     routed layer, and ``index_kept``, an int32 an indexed operator: the
     (query, key) pairs its choice kept over those positions' queries.
     With ``live`` the routed experts compute the marked positions alone,
-    and a model with Kimi delta attention or latent attention is told each
-    row's length (the marks' row sums: a row's own tokens are its first)
-    so that the rule's kernel and the two-width flash forward stop at its
-    end; the hidden states of the others are not a forward pass's. Without
+    and every model is told each row's length (the marks' row sums: a
+    row's own tokens are its first) so that the delta rule's kernel and the
+    flash forwards, at two widths and at equal ones, stop at its end; the
+    hidden states of the others are not a forward pass's. Without
     ``live`` every position is computed: ``last`` is not taken for a
     length, because a caller who wants every position's hidden state hands
     zeros there (``serve/llm.py::_FullLogits``)."""
     lengths = None
-    if live is not None and any(
-            kind.split("_")[0] in LATENT_OPERATORS + ("kda",)
-            for kind in cfg.layer_kinds()):
+    if live is not None:
         lengths = jnp.sum(live, axis=1, dtype=jnp.int32)
     x, books = _hidden_and_books(params, tokens, cfg, lora=lora,
                                  lora_cfg=lora_cfg, router_mask=live,

@@ -22,10 +22,11 @@ block) and the window at equal widths too
 ``lengths [B]`` int32: how many of a row's positions are its own where a
 batch's rows are padded on the right to one length (a serving step's are).
 The keys are causal, so a row's own outputs do not depend on it; the flash
-kernel at two widths computes no block past a row's end and writes zeros
-there (``ops/pallas/flash_attention.py``). Every other path computes every
-position and does not read it: the outputs past a row's end are nobody's
-to read.
+forwards, at two widths and at equal ones, under a window or not, compute
+no block past a row's end and write zeros there
+(``ops/pallas/flash_attention.py``; one device, forward only). The
+reference computes every position and does not read it: the outputs past a
+row's end are nobody's to read.
 """
 
 from __future__ import annotations
@@ -128,14 +129,18 @@ def _flash_per_shard(q: jax.Array, k: jax.Array, v: jax.Array,
         raise NotImplementedError(
             "the equal-width flash kernels scale by head_dim ** -0.5 and "
             "take values of the keys' width")
-    if window is not None:
+    if window is not None or lengths is not None:
         if not causal:
-            raise ValueError("a window is the causal keys' last ones")
+            raise ValueError("a window is the causal keys' last ones, and "
+                             "the rows' lengths are causal's")
         if mesh is not None and mesh.size > 1:
             raise NotImplementedError(
-                "the equal-width flash forward under a window runs on one "
-                "device; a mesh needs impl='reference'")
-        return flash_attention_window(q, k, v, window)
+                "the equal-width flash forward under a window or told the "
+                "rows' lengths runs on one device; a mesh needs "
+                "impl='reference'")
+        if window is not None:
+            return flash_attention_window(q, k, v, window, lengths)
+        return flash_attention(q, k, v, causal, lengths)
     if mesh is None or mesh.size == 1:
         return flash_attention(q, k, v, causal)
     if mesh.shape.get("seq", 1) > 1:

@@ -96,24 +96,41 @@ raises and says so, as the two-width forward does; ``_dq_kernel`` and
 ``_dkv_kernel`` see every causal key (ROADMAP M5). ``flash_attention``
 itself is the call it was.
 
-The rows' lengths, at two widths alone (``lengths [B]`` int32). A batch's
-rows are padded on the right to one length, and a block whose first
-position lies past its row's end holds nothing of the row. The kernel is
-told how many query blocks and how many key blocks of each row hold a
-position of its own (``ceil(length / block)`` and the last such block's
-index, for either, four int32 a row, prefetched into SMEM before the grid
-runs), and a grid step at a block past them computes nothing: a dead query
-block's running sums stay the zeros they began as and its ``o`` is written
-as ZEROS (a padded position's output goes on into ``W_o`` and the next
-layers' dense matmuls, and what nobody wrote may be a NaN). The index maps
-hold such a step's operands at the row's last live blocks, which are in
-VMEM already, so nothing is fetched for it either. The keys are causal, so
+The rows' lengths, in every forward (``lengths [B]`` int32: the two-width
+forward since PR 54, the equal-width one, full or under its window, since
+PR 56). A batch's rows are padded on the right to one length, and a block
+whose first position lies past its row's end holds nothing of the row. The
+kernel is told how many query blocks and how many key blocks of each row
+hold a position of its own (``_live_blocks``: ``ceil(length / block)`` and
+the last such block's index, for either, four int32 a row, prefetched into
+SMEM before the grid runs), and a grid step at a block past them computes
+nothing (``_both_live``): a dead query block's ``o`` is written as ZEROS (a
+padded position's output goes on into ``W_o`` and the next layers' dense
+matmuls, and what nobody wrote may be a NaN). The index maps
+(``_block_maps``, one copy for both forwards, ``lax`` alone) hold such a
+step's operands at the row's last live blocks, which are in VMEM already,
+so nothing is fetched for it either. The equal-width forward goes three
+steps further, because where a length has ONE block a dead step is a dead
+(row, head) and what it costs is what surrounds the matmuls (PERF.md
+section 6, PR 56: 2.3 us as above, 0.7 so): told the lengths it makes no
+``lse`` (the call is the forward's alone, and the logsumexp, twice the
+bytes of ``o``, is the backward's to read), a dead query block is neither
+begun nor finished (no scratch set, no division: the zeros' store alone),
+and an empty row's steps all name its first head's first blocks, one fetch
+a row where each head's own would be one a head. The keys are causal, so
 every key skipped was masked for every query of the row's own and a masked
-score adds an exact 0: the rows' own outputs are the same to the bit; the
-padded queries inside a row's last live block are run like any other, as
-they were when the kernel knew no lengths. With ``None`` every row is
-whole and the call is the one it was: no operand, no test, no ``min``
-(PERF.md section 6, PR 54, has the times).
+score adds an exact 0: the rows' own
+outputs are the same to the bit; the padded queries inside a row's last
+live block are run like any other, as they were when the kernel knew no
+lengths. With ``None`` every row is whole and the call is the one it was:
+no operand, no test, no ``min`` (``flash_attention``'s forward and backward
+as training runs them are the parent's program to the character, down to
+the ``//`` in the key head's index map, which told the lengths is
+``lax.div``). Lengths are a prefill's (causal, the queries' positions the
+keys') and the forward's alone: differentiating a call that has them
+raises. ``causal_blocks`` counts, on the host and by the kernels' own rule,
+the grid steps a forward computes with and without them (PERF.md section
+6, PRs 54 and 56, has the times).
 """
 
 from __future__ import annotations
@@ -237,20 +254,41 @@ def flash_tiles(sq: int, skv: int, *, head_dim: int = 128,
     return block_q, block_k
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, scale: float, causal: bool, block_q: int, block_k: int,
-                window: Optional[int] = None):
+def _fwd_kernel(*refs, scale: float, causal: bool, block_q: int,
+                block_k: int, window: Optional[int] = None,
+                told: bool = False):
     """With ``window`` the innermost grid dim walks the key blocks that the
     query block's window reaches, from the window's first
     (``_first_key_block``), and a query sees its last ``window`` keys; a
     row of a block in which it sees none fills with ``exp(0)`` and the
     first block in which it sees one rescales that away, as in
-    ``_fwd_shared_rope_kernel``."""
+    ``_fwd_shared_rope_kernel``. With ``told`` a first operand rides in
+    front, ``blocks_ref [4, B]`` (``_live_blocks``), and there is no
+    ``lse_ref`` (told the lengths the call is the forward's alone, and the
+    logsumexp is the backward's to read): a step at a block past its row's
+    live ones computes nothing, and a query block past them is neither
+    begun nor finished, its ``o`` written as zeros."""
+    blocks_ref = None
+    if told:
+        blocks_ref, *refs = refs
+    q_ref, k_ref, v_ref, o_ref, *lse_ref, m_scr, l_scr, acc_scr = refs
     iq = pl.program_id(2)
     ik = pl.program_id(3)
     nk = pl.num_programs(3)
+    if told:  # a dead query block costs its zeros' store and no more
+        live = jax.lax.lt(iq, blocks_ref[0, pl.program_id(0)])
 
-    @pl.when(ik == 0)
+        @pl.when(jax.lax.bitwise_and(ik == nk - 1,
+                                     jax.lax.bitwise_not(live)))
+        def _dead():
+            o_ref[0, 0] = jnp.zeros_like(o_ref[0, 0])
+
+    def of_a_live_block(edge):
+        """At the walk's first or last step, of a query block that holds
+        a position of its row's own (told no lengths: every one does)."""
+        return jax.lax.bitwise_and(edge, live) if told else edge
+
+    @pl.when(of_a_live_block(ik == 0))
     def _init():
         m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
@@ -264,6 +302,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
     run = True
     if causal:
         run = at * block_k <= iq * block_q + block_q - 1
+    if told:
+        run = _both_live(run, blocks_ref, iq, at)
 
     @pl.when(run)
     def _compute():
@@ -294,44 +334,67 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    @pl.when(ik == nk - 1)
+    @pl.when(of_a_live_block(ik == nk - 1))
     def _finalize():
         l = l_scr[:, :1]
         o_ref[0, 0] = (acc_scr[:] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
         # logsumexp row stats for the backward (lse layout [bq, 128]: the
         # row value broadcast across lanes — keeps stores 2D/tiled)
-        lse_ref[0, 0] = jnp.broadcast_to(
-            m_scr[:, :1] + jnp.log(jnp.maximum(l, 1e-30)),
-            lse_ref[0, 0].shape)
+        for ref in lse_ref:
+            ref[0, 0] = jnp.broadcast_to(
+                m_scr[:, :1] + jnp.log(jnp.maximum(l, 1e-30)),
+                ref[0, 0].shape)
 
 
 def _flash_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
-               causal: bool, window: Optional[int] = None
-               ) -> Tuple[jax.Array, jax.Array]:
+               causal: bool, window: Optional[int] = None,
+               lengths: Optional[jax.Array] = None
+               ) -> Tuple[jax.Array, Optional[jax.Array]]:
     """q [B,H,S,D], k/v [B,KVH,S,D] → (o [B,H,S,D], lse [B,H,S,128]).
     ``window``: a query sees its last ``window`` keys, and the innermost
     grid dim is as long as the most key blocks a query block's window
-    reaches (module docstring); None is the call it always was."""
+    reaches (module docstring). ``lengths [B]`` int32: the right-padded
+    rows' own lengths, past which no block is computed (module docstring:
+    ``o`` is zeros there, and ``lse`` is None: no backward reads it).
+    None, either, is the call it always was."""
     B, H, Sq, D = q.shape
     KVH, Skv = k.shape[1], k.shape[2]
     n_rep = H // KVH
     scale = D ** -0.5
+    if (window is not None or lengths is not None) \
+            and not (causal and Sq == Skv):
+        raise ValueError("a window or the rows' lengths are a prefill's: "
+                         "causal, the queries' positions the keys'")
+    if lengths is not None and lengths.shape != (B,):
+        raise ValueError(f"lengths{lengths.shape} for {B} rows")
     block_q, block_k = flash_tiles(Sq, Skv, head_dim=D)
     key_blocks, told = Skv // block_k, {}
-    keys = lambda b, h, iq, ik: (b, h // n_rep, ik, 0)  # noqa: E731
     if window is not None:
-        if not (causal and Sq == Skv):
-            raise ValueError("a window is a prefill's: causal, the queries' "
-                             "positions the keys'")
         key_blocks = _window_key_blocks(Sq, block_q, block_k, window)
-        told = {"window": window}
+        told["window"] = window
+    # no lengths, no operand: the call is the one it was
+    prefetched = []
+    if lengths is not None:
+        prefetched = [_live_blocks(lengths, block_q, block_k)]
+        told["told"] = True
+    query_block, key_block = _block_maps(block_q, block_k, window)
 
-        def keys(b, h, iq, ik):
-            # from the window's first block on, and past the diagonal the
-            # diagonal's again, which costs no copy
-            return (b, h // n_rep, jax.lax.min(
-                ik + _first_key_block(iq, block_q, block_k, window),
-                jax.lax.div(iq * block_q + block_q - 1, block_k)), 0)
+    def kv_head(h):
+        # told the lengths the maps hold `lax` alone; the call that is not
+        # told keeps the text it has, whose `//` is a `jit` in the map
+        return h // n_rep if lengths is None else jax.lax.div(h, n_rep)
+
+    def head(b, h, *blocks_ref):
+        # an empty row's steps all name its first head's first blocks: one
+        # fetch a row where each head's would be one a head
+        return (jax.lax.mul(h, jax.lax.min(blocks_ref[0][0, b], 1))
+                if blocks_ref else h)
+
+    def rows(b, h, iq, ik, *n):
+        return (b, head(b, h, *n), query_block(b, iq, *n), 0)
+
+    def keys(b, h, iq, ik, *n):
+        return (b, kv_head(head(b, h, *n)), key_block(b, iq, ik, *n), 0)
 
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal,
@@ -340,44 +403,38 @@ def _flash_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
     # trace then calls it; the other keeps its caller's
     scope = (contextlib.nullcontext() if window is None
              else jax.named_scope(EQUAL_WINDOW_TRACE_NAME))
+    # every block of a result is written, a dead one of `o` with zeros;
+    # told the lengths there is `o` alone
+    results = [(D, q.dtype)] if lengths is not None else [
+        (D, q.dtype), (128, jnp.float32)]
     with scope:
-        return _fwd_call(kernel, (B, H, Sq // block_q, key_blocks), keys,
-                         q, k, v, block_q, block_k)
-
-
-def _fwd_call(kernel, grid, keys, q, k, v, block_q: int, block_k: int):
-    """``_flash_fwd``'s ``pallas_call``: ``keys`` is the index map of the
-    key and value blocks."""
-    B, H, Sq, D = q.shape
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, D),
-                         lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_k, D), keys),
-            pl.BlockSpec((1, 1, block_k, D), keys),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_q, D),
-                         lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_q, 128),
-                         lambda b, h, iq, ik: (b, h, iq, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
-            jax.ShapeDtypeStruct((B, H, Sq, 128), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 128), jnp.float32),   # running max
-            pltpu.VMEM((block_q, 128), jnp.float32),   # running sum
-            pltpu.VMEM((block_q, D), jnp.float32),     # accumulator
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
-        interpret=_interpret(),
-    )(q, k, v)
+        o, *lse = pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(prefetched),
+                grid=(B, H, Sq // block_q, key_blocks),
+                in_specs=[
+                    pl.BlockSpec((1, 1, block_q, D), rows),
+                    pl.BlockSpec((1, 1, block_k, D), keys),
+                    pl.BlockSpec((1, 1, block_k, D), keys),
+                ],
+                out_specs=[
+                    pl.BlockSpec((1, 1, block_q, width),
+                                 lambda b, h, iq, ik, *n: (b, h, iq, 0))
+                    for width, _ in results],
+                scratch_shapes=[
+                    pltpu.VMEM((block_q, 128), jnp.float32),   # running max
+                    pltpu.VMEM((block_q, 128), jnp.float32),   # running sum
+                    pltpu.VMEM((block_q, D), jnp.float32),     # accumulator
+                ]),
+            out_shape=[jax.ShapeDtypeStruct((B, H, Sq, width), dtype)
+                       for width, dtype in results],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "parallel",
+                                     "arbitrary")),
+            interpret=_interpret(),
+        )(*prefetched, q, k, v)
+    return o, (lse[0] if lse else None)
 
 
 # ----------------------------------------------------------------- backward
@@ -562,11 +619,15 @@ def _flash_bwd(q, k, v, o, lse, do, *, causal: bool):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                    causal: bool = True) -> jax.Array:
+                    causal: bool = True,
+                    lengths: Optional[jax.Array] = None) -> jax.Array:
+    """``lengths [B]`` int32, where given, are the right-padded rows' own
+    lengths (module docstring): a prefill's and the forward's alone. None
+    is the call it always was, forward and backward."""
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
-    o, _ = _flash_fwd(qt, kt, vt, causal=causal)
+    o, _ = _flash_fwd(qt, kt, vt, causal=causal, lengths=lengths)
     return jnp.swapaxes(o, 1, 2)
 
 
@@ -578,9 +639,14 @@ OUT_RESIDUAL_NAME = "flash_fwd_out"
 LSE_RESIDUAL_NAME = "flash_fwd_lse"
 
 
-def _fa_fwd(q, k, v, causal):
+def _fa_fwd(q, k, v, causal, lengths):
     # traced under differentiation alone: the primal above, which serving
     # traces, carries no name
+    if lengths is not None:
+        raise NotImplementedError(
+            "the equal-width flash forward told its rows' lengths has no "
+            "backward (what lies past a row's end is zeros, not a forward "
+            "pass's): differentiate the call without them")
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
@@ -595,7 +661,7 @@ def _fa_bwd(causal, res, g):
     do = jnp.swapaxes(g, 1, 2)
     dq, dk, dv = _flash_bwd(qt, kt, vt, o, lse, do, causal=causal)
     return (jnp.swapaxes(dq, 1, 2), jnp.swapaxes(dk, 1, 2),
-            jnp.swapaxes(dv, 1, 2))
+            jnp.swapaxes(dv, 1, 2), None)
 
 
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
@@ -609,17 +675,20 @@ EQUAL_WINDOW_TRACE_NAME = "flash_fwd_sliding"
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def flash_attention_window(q: jax.Array, k: jax.Array, v: jax.Array,
-                           window: int) -> jax.Array:
+                           window: int,
+                           lengths: Optional[jax.Array] = None) -> jax.Array:
     """``flash_attention``'s forward under a window (module docstring): q
     ``[B, S, H, D]``, k, v ``[B, S, KVH, D]`` → ``[B, S, H, D]``; causal,
-    query ``t`` sees keys ``t - window + 1 .. t``. Forward only."""
+    query ``t`` sees keys ``t - window + 1 .. t``; ``lengths [B]`` int32
+    or None as ``flash_attention``'s. Forward only."""
     o, _ = _flash_fwd(jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
-                      jnp.swapaxes(v, 1, 2), causal=True, window=window)
+                      jnp.swapaxes(v, 1, 2), causal=True, window=window,
+                      lengths=lengths)
     return jnp.swapaxes(o, 1, 2)
 
 
-def _fa_window_fwd(q, k, v, window):
-    return flash_attention_window(q, k, v, window), None
+def _fa_window_fwd(q, k, v, window, lengths):
+    return flash_attention_window(q, k, v, window, lengths), None
 
 
 def _fa_window_bwd(window, res, g):
@@ -670,15 +739,11 @@ def _first_key_block(iq, block_q: int, block_k: int, window: int):
 
 
 @functools.cache
-def _shared_rope_steps(seq: int, head_dim: int, rope_dim: int,
-                       value_dim: int, window: Optional[int]):
-    """``(block_q, block_k, iq [n], at [n])``: the tiles of the two-width
-    forward at a prefill of ``seq`` and the (query block, key block) of
-    each grid step at or under the diagonal, the ones it computes for a
-    whole row."""
-    padded = head_dim + -(-rope_dim // _LANES) * _LANES
-    block_q, block_k = flash_tiles(seq, seq, head_dim=padded,
-                                   value_dim=value_dim)
+def _causal_steps(seq: int, block_q: int, block_k: int,
+                  window: Optional[int]):
+    """``(iq [n], at [n])``: the (query block, key block) of each grid step
+    at or under the diagonal of a forward at a prefill of ``seq`` and these
+    tiles, under a window its walk's: the ones computed for a whole row."""
     key_blocks = (seq // block_k if window is None else
                   _window_key_blocks(seq, block_q, block_k, window))
     steps = [(iq, at) for iq in range(seq // block_q)
@@ -688,24 +753,35 @@ def _shared_rope_steps(seq: int, head_dim: int, rope_dim: int,
                  seq // block_k)[:key_blocks]
              if at * block_k <= iq * block_q + block_q - 1]
     iq, at = np.asarray(steps, np.int64).T
-    return block_q, block_k, iq, at
+    return iq, at
+
+
+def causal_blocks(seq: int, lengths, tiles: Tuple[int, int],
+                  window: Optional[int] = None) -> Tuple[int, int]:
+    """What the rows' lengths are worth to a forward at a prefill of
+    ``seq`` and ``tiles`` (``(block_q, block_k)``), a head: ``(run,
+    live)``, the grid steps at or under the diagonal (inside the window's
+    walk) over every row of ``lengths [B]`` (numpy), which are the ones
+    computed when every row is whole, and those among them whose query
+    block and key block both hold a position of their row's own, which are
+    the ones computed when the kernel is told the lengths (module
+    docstring). Host arithmetic, by the kernels' own rule (``_both_live``),
+    for either forward."""
+    block_q, block_k = tiles
+    iq, at = _causal_steps(seq, block_q, block_k, window)
+    lengths = np.asarray(lengths, np.int64)[:, None]
+    live = ((iq < -(-lengths // block_q)) & (at < -(-lengths // block_k)))
+    return len(lengths) * len(iq), int(live.sum())
 
 
 def shared_rope_blocks(seq: int, lengths, *, head_dim: int, rope_dim: int,
                        value_dim: int, window: Optional[int] = None
                        ) -> Tuple[int, int]:
-    """What the rows' lengths are worth to the two-width forward at a
-    prefill of ``seq`` (module docstring), a head: ``(run, live)``, the grid
-    steps at or under the diagonal over every row of ``lengths [B]``
-    (numpy), which are the ones computed when every row is whole, and those
-    among them whose query block and key block both hold a position of
-    their row's own, which are the ones computed when the kernel is told
-    the lengths. Host arithmetic, by the kernel's own rule."""
-    block_q, block_k, iq, at = _shared_rope_steps(seq, head_dim, rope_dim,
-                                                  value_dim, window)
-    lengths = np.asarray(lengths, np.int64)[:, None]
-    live = ((iq < -(-lengths // block_q)) & (at < -(-lengths // block_k)))
-    return len(lengths) * len(iq), int(live.sum())
+    """``causal_blocks`` at the two-width forward's tiles: the head's own
+    width beside a rotary part that pads to the lane width in VMEM."""
+    padded = head_dim + -(-rope_dim // _LANES) * _LANES
+    return causal_blocks(seq, lengths, flash_tiles(
+        seq, seq, head_dim=padded, value_dim=value_dim), window)
 
 
 def _live_blocks(lengths: jax.Array, block_q: int, block_k: int
@@ -722,6 +798,47 @@ def _live_blocks(lengths: jax.Array, block_q: int, block_k: int
         live = jax.lax.div(jax.lax.add(n, block - 1), block)
         rows += [live, jax.lax.max(jax.lax.sub(live, 1), 0)]
     return jax.lax.concatenate([row.reshape(1, -1) for row in rows], 0)
+
+
+def _both_live(run, blocks_ref, iq, at):
+    """``run``, and neither the step's query block ``iq`` nor its key block
+    ``at`` past its row's end (``blocks_ref``: ``_live_blocks``, in SMEM;
+    lengths are causal's): two compares of scalars."""
+    row = pl.program_id(0)
+    return jax.lax.bitwise_and(run, jax.lax.bitwise_and(
+        jax.lax.lt(iq, blocks_ref[0, row]),
+        jax.lax.lt(at, blocks_ref[2, row])))
+
+
+def _block_maps(block_q: int, block_k: int, window: Optional[int]):
+    """``(query_block, key_block)``: the block indices a forward's index
+    maps name at a grid step, for both forwards. Each takes the prefetched
+    ``blocks_ref`` (``_live_blocks``) last where the kernel is told the
+    rows' lengths and nothing where it is not; ``lax`` alone."""
+
+    def query_block(b, iq, *blocks_ref):
+        """Query block ``iq`` of row ``b``, or the row's last live one past
+        it, which costs no copy."""
+        return jax.lax.min(iq, blocks_ref[0][1, b]) if blocks_ref else iq
+
+    def key_block(b, iq, ik, *blocks_ref):
+        """The key block of a grid step. Under a window: from its first
+        block on, and past the diagonal the diagonal's again, which costs
+        no copy; past the row's last live key block, or at a query block
+        past its last live one, that key block again, which costs none
+        either."""
+        at = ik
+        if window is not None:
+            at = jax.lax.min(
+                ik + _first_key_block(iq, block_q, block_k, window),
+                jax.lax.div(iq * block_q + block_q - 1, block_k))
+        if not blocks_ref:
+            return at
+        last = blocks_ref[0][3, b]
+        return jax.lax.select(jax.lax.gt(iq, blocks_ref[0][1, b]), last,
+                              jax.lax.min(at, last))
+
+    return query_block, key_block
 
 
 def _fwd_shared_rope_kernel(*refs, scale: float, causal: bool, block_q: int,
@@ -767,11 +884,8 @@ def _fwd_shared_rope_kernel(*refs, scale: float, causal: bool, block_q: int,
     run = True
     if causal:
         run = at * block_k <= iq * block_q + block_q - 1
-    if told:  # and neither block past its row's end (lengths are causal's)
-        row = pl.program_id(0)
-        run = jax.lax.bitwise_and(run, jax.lax.bitwise_and(
-            jax.lax.lt(iq, blocks_ref[0, row]),
-            jax.lax.lt(at, blocks_ref[2, row])))
+    if told:
+        run = _both_live(run, blocks_ref, iq, at)
 
     @pl.when(run)
     def _compute():
@@ -843,27 +957,7 @@ def _flash_fwd_shared_rope(q: jax.Array, q_rope: jax.Array, k: jax.Array,
     prefetched = [] if lengths is None else [
         _live_blocks(lengths, block_q, block_k)]
 
-    def query_block(b, iq, *blocks_ref):
-        """Query block ``iq`` of row ``b``, or the row's last live one past
-        it, which costs no copy."""
-        return jax.lax.min(iq, blocks_ref[0][1, b]) if blocks_ref else iq
-
-    def key_block(b, iq, ik, *blocks_ref):
-        """The key block of a grid step. Under a window: from its first
-        block on, and past the diagonal the diagonal's again, which costs
-        no copy; past the row's last live key block, or at a query block
-        past its last live one, that key block again, which costs none
-        either."""
-        at = ik
-        if window is not None:
-            at = jax.lax.min(
-                ik + _first_key_block(iq, block_q, block_k, window),
-                jax.lax.div(iq * block_q + block_q - 1, block_k))
-        if not blocks_ref:
-            return at
-        last = blocks_ref[0][3, b]
-        return jax.lax.select(jax.lax.gt(iq, blocks_ref[0][1, b]), last,
-                              jax.lax.min(at, last))
+    query_block, key_block = _block_maps(block_q, block_k, window)
 
     def rows(block, width):   # a head's rows: q and q_rope by iq
         return pl.BlockSpec(

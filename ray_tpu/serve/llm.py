@@ -16,17 +16,18 @@ row's last position, the argmax runs on the device), so a step brings
 ``bucket`` int32s to the host (and, from a model with experts, two
 float32 a routed layer of its routers' load, three where the replica
 holds a share of the experts) and the ``[bucket, S, vocab]``
-logits are never made. A model with experts is told which positions are
-the rows' own, and its routed experts multiply those alone: the
-padding's pairs are sorted past the last expert's and get no visit
-(``models/moe.py::expert_ffn``; ``expert_pairs_skipped`` counts them).
-A model with Kimi delta attention is told the same, and the rule's kernel
-runs no chunk past a row's end (``ops/pallas/kda_chunk.py``;
-``kda_chunks_skipped`` counts them); so is a model with latent attention,
-whose two-width flash forward computes no block past one
-(``ops/pallas/flash_attention.py``; ``flash_blocks_skipped``).
+logits are never made. Every model is told which positions are the rows'
+own. Routed experts multiply those alone: the padding's pairs are sorted
+past the last expert's and get no visit (``models/moe.py::expert_ffn``;
+``expert_pairs_skipped`` counts them). Kimi delta attention's kernel runs
+no chunk past a row's end (``ops/pallas/kda_chunk.py``;
+``kda_chunks_skipped`` counts them), and the flash forwards compute no
+block past one (``ops/pallas/flash_attention.py``): latent attention's
+two-width forward (``flash_blocks_skipped``) and grouped-query
+attention's equal-width one, full or under its window
+(``attn_blocks_skipped``).
 ``LlamaGenerator._fwd`` is the step's function with no mask (every
-position computed; for a dense model the very program ``_step`` runs),
+position computed: another program than ``_step``'s for every model),
 followed by the head over every position, for callers that want the
 logits themselves. What a benchmark asks of a served class is
 ``warm_step_programs``, ``compiled_step_programs``, ``engine_stats()``
@@ -113,7 +114,9 @@ class LlamaGenerator:
     # the padded length's), `flash_blocks_live` those among them whose
     # query and key block both hold one of a row's own positions, and
     # `flash_blocks_skipped` the rest, which the kernel, told the rows'
-    # lengths, did not compute
+    # lengths, did not compute; `attn_blocks_run`, `attn_blocks_live` and
+    # `attn_blocks_skipped` the same three over the grouped-query attention
+    # layers' equal-width flash forwards, full or under the window
     STEP_COUNTERS = ("host_bytes", "positions_computed", "positions_live",
                      "expert_pairs_fullest", "expert_pairs_mean",
                      "expert_pairs_here", "expert_pairs_all",
@@ -123,7 +126,9 @@ class LlamaGenerator:
                      "window_keys_seen", "ssm_chunks_run",
                      "ssm_chunks_live", "kda_chunks_run", "kda_chunks_live",
                      "kda_chunks_skipped", "flash_blocks_run",
-                     "flash_blocks_live", "flash_blocks_skipped")
+                     "flash_blocks_live", "flash_blocks_skipped",
+                     "attn_blocks_run", "attn_blocks_live",
+                     "attn_blocks_skipped")
 
     def __init__(self, config: str = "tiny", lora_rank: int = 4,
                  max_batch_size: int = 4,
@@ -162,6 +167,12 @@ class LlamaGenerator:
         self._flash_layers = [
             (layers, self._cfg.latent_widths(operator))
             for operator in LATENT_OPERATORS
+            if (layers := layers_of(operator))]
+        # grouped-query attention, whose prefill is the equal-width flash
+        # forward's: (layers, the window or None) each
+        self._attn_layers = [
+            (layers, window) for operator, window in (
+                ("attention", None), ("sliding", self._cfg.sliding_window))
             if (layers := layers_of(operator))]
         # adapt only the attention q/v projections: the cheap standard
         # LoRA target set, and enough for adapters to produce distinct
@@ -263,18 +274,16 @@ class LlamaGenerator:
 
     def _run_step(self, tokens, last, mask, lora=None):
         """The step's one jitted program on numpy ``tokens [B, S]``, ``last
-        [B]`` and ``mask [B, S]`` (the rows' own tokens: only a model with
-        experts, latent attention or Kimi delta attention is told, for its
-        routers' load, for what its indexers' choices kept, and for the
-        rows' lengths, the marks' row sums, past which the delta rule's
-        kernel runs no chunk and the two-width flash forward computes no
-        block) -> (ids, hidden, load)."""
+        [B]`` and ``mask [B, S]`` (the rows' own tokens, which every model
+        is told: for its routers' load, for what its indexers' choices
+        kept, and for the rows' lengths, the marks' row sums, past which
+        the delta rule's kernel runs no chunk and the flash forwards, at
+        two widths and at equal ones, compute no block) -> (ids, hidden,
+        load)."""
         import jax.numpy as jnp
 
-        return self._step_fn(
-            self._params, jnp.asarray(tokens), lora, last,
-            mask if self._cfg.num_experts or self._flash_layers
-            or self._kda_layers else None)
+        return self._step_fn(self._params, jnp.asarray(tokens), lora, last,
+                             mask)
 
     def _step(self, model_id: str, states: List[Optional[Dict]]) -> List:
         """One decode iteration for one adapter group: pad the live rows
@@ -294,7 +303,7 @@ class LlamaGenerator:
         import numpy as np
 
         from ray_tpu.models.moe import moved_chunk
-        from ray_tpu.ops.pallas.flash_attention import shared_rope_blocks
+        from ray_tpu.ops.pallas import flash_attention as fa
 
         with events.span("llm.prepare", "serve"):
             live = [(i, s) for i, s in enumerate(states) if s is not None]
@@ -364,19 +373,24 @@ class LlamaGenerator:
                 counts["kda_chunks_skipped"] += self._kda_layers * int(
                     (-(-pad_len // chunk) - -(-mask.sum(axis=1) // chunk)
                      ).sum())
-            if self._flash_layers and pad_len % 128 == 0:
-                # the two-width flash forward is told the same lengths and
-                # computes no block past a row's end; a length off the
-                # kernel's 128 grid is the reference's, which has no blocks
+            if pad_len % 128 == 0:
+                # the flash forwards are told the same lengths and compute
+                # no block past a row's end; a length off the kernels' 128
+                # grid is the reference's, which has no blocks
                 lengths = mask.sum(axis=1)
-                for layers, w in self._flash_layers:
-                    run, own = shared_rope_blocks(
-                        pad_len, lengths, head_dim=w.nope, rope_dim=w.rope,
-                        value_dim=w.v, window=w.window or None)
-                    counts["flash_blocks_run"] += layers * w.heads * run
-                    counts["flash_blocks_live"] += layers * w.heads * own
-                    counts["flash_blocks_skipped"] += (layers * w.heads
-                                                       * (run - own))
+                blocks = [("flash", layers * w.heads, fa.shared_rope_blocks(
+                    pad_len, lengths, head_dim=w.nope, rope_dim=w.rope,
+                    value_dim=w.v, window=w.window or None))
+                    for layers, w in self._flash_layers]
+                blocks += [("attn", layers * self._cfg.num_heads,
+                            fa.causal_blocks(pad_len, lengths, fa.flash_tiles(
+                                pad_len, pad_len,
+                                head_dim=self._cfg.head_dim), window))
+                           for layers, window in self._attn_layers]
+                for name, heads, (run, own) in blocks:
+                    counts[name + "_blocks_run"] += heads * run
+                    counts[name + "_blocks_live"] += heads * own
+                    counts[name + "_blocks_skipped"] += heads * (run - own)
             if load is not None and "index_kept" in load:
                 kept = load["index_kept"]
                 counts["host_bytes"] += kept.nbytes
@@ -485,7 +499,14 @@ class LlamaGenerator:
         of their row's own; and the rest, which the kernel is told and
         does not compute: ``ops/pallas/flash_attention.py::
         shared_rope_blocks``, reckoned on the host from the mask the
-        program was handed, summed over steps and those layers); and
+        program was handed, summed over steps and those layers);
+        ``attn_blocks_run``, ``attn_blocks_live`` and
+        ``attn_blocks_skipped`` (the same three over the layers whose
+        operator is grouped-query attention, ``attention`` or ``sliding``,
+        and their equal-width flash forward's grid, rows x query heads x
+        the padded length's steps at or under the diagonal, under
+        ``sliding_window`` the window's walk: ``causal_blocks`` of the same
+        module at ``flash_tiles``' tiles for ``head_dim``); and
         ``layer_kinds``, how many layers of each kind this replica serves
         (``LlamaConfig.kind_counts``: ``attention_dense`` alone for a dense
         decoder)."""

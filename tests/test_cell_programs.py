@@ -5,8 +5,10 @@ that did re-baselines the lines it meant to move and no other.
 A program is ``tests/benchmark/test_deepseek_v2.py::program_text``'s: the
 jaxpr, at the cell's real sizes and with addresses blanked, of the model's
 initialiser (``init``), of a serving step at 8 rows of the cell's shortest
-and of its longest warmed length (``step<length>``), or of training's
-value-and-gradient at 2 x 4096 (``grad``). Only shapes are traced: no
+and of its longest warmed length (``step<length>``; ``told<length>`` is
+this file's ``told_text``: the same step handed its mask, for a model
+without experts), or of training's value-and-gradient at 2 x 4096
+(``grad``). Only shapes are traced: no
 weight is made.
 
 Regenerate (prints the table below; paste the lines a PR means to move)::
@@ -92,7 +94,30 @@ window behind ``window=None``, ``_layer``'s attention branch through
 ``_yarn_rope``, whose ``None`` is ``_rope``) moved none of the twenty-three:
 they are what its parent ``760214b`` gives to the character;
 ``serve_mellum2_projctx``'s three are new (traced at 8 rows like the others'
-steps; the cell serves 4).
+steps; the cell serves 4). PR 56 (the equal-width flash forward is told its
+rows' lengths: ``llama_next_token`` makes them whenever it is handed a
+mask, ``_layer``'s attention branch -> ``ops.attention.attention`` ->
+``flash_attention`` / ``flash_attention_window`` carry them, and
+``_flash_fwd`` takes a row's live blocks as a scalar-prefetched operand and
+computes no block past them) moved the six step programs of the three cells
+whose model has experts and runs that forward, ``serve_olmoe_chat``,
+``serve_lfm2_rag`` and ``serve_mellum2_projctx``, as it meant to: a
+``reduce_sum`` of the mask where there was none, and every equal-width
+kernel with one operand more, one result fewer (no logsumexp: the call is
+the forward's alone) and ``lax`` primitives alone in its index maps. The twenty others are what its parent ``3fc52f6`` gives to the
+character: ``train_l2_seq4k.grad`` (no lengths: ``flash_attention`` with
+``lengths=None`` is the call it was, forward and backward), every ``init``,
+and the three latent cells' steps (``serve_dsv2_docqa``,
+``serve_dots3_longdoc``, ``serve_ling3_repoctx``: the two-width forward and
+the delta rule alone, told already). The four step lines of the two cells
+whose model has no experts, ``serve_chat_steady`` and
+``serve_granite_toolcalls``, did not move either, and not because their
+served step did not: ``program_text`` (the benchmark's) traces such a
+model's step with no mask, which is what ``_run_step`` handed it until this
+PR and what ``_FullLogits`` still runs. What the served class runs for them
+now, the same step handed the mask (one input more, the ``reduce_sum``, the
+kernels told), is ``told_text``'s, on record as ``told<length>``: four new
+lines.
 """
 
 import hashlib
@@ -106,11 +131,11 @@ PROGRAMS = {
     "serve_chat_steady.step128": "e007e82555a4c201",
     "serve_chat_steady.step384": "3bf862312efd7151",
     "serve_olmoe_chat.init": "126fada9fb96dc80",
-    "serve_olmoe_chat.step128": "467f3dc0672ecd24",
-    "serve_olmoe_chat.step1152": "3f737ba4afc9c639",
+    "serve_olmoe_chat.step128": "d39476f9ff26cc72",
+    "serve_olmoe_chat.step1152": "66ac03a0fa292003",
     "serve_lfm2_rag.init": "5766fc6f6af74d3d",
-    "serve_lfm2_rag.step128": "69db9a37644bffff",
-    "serve_lfm2_rag.step1408": "80b36b565a92faa6",
+    "serve_lfm2_rag.step128": "bc33adeb50b44ec3",
+    "serve_lfm2_rag.step1408": "e677dd673bf28574",
     "serve_dsv2_docqa.init": "91b10ec8ff63401e",
     "serve_dsv2_docqa.step256": "3694036b0c6db661",
     "serve_dsv2_docqa.step1792": "d4eca275aeee6adb",
@@ -124,16 +149,48 @@ PROGRAMS = {
     "serve_ling3_repoctx.step1024": "093dcdc0ed5ccc8c",
     "serve_ling3_repoctx.step3072": "c2d07b3b2dc38a22",
     "serve_mellum2_projctx.init": "26e74b5260e71edd",
-    "serve_mellum2_projctx.step4096": "2e886e4ebcae3f07",
-    "serve_mellum2_projctx.step8192": "bfe0ceee314e9591",
+    "serve_mellum2_projctx.step4096": "636a25abce39cf8c",
+    "serve_mellum2_projctx.step8192": "2d3f004b61858767",
+    "serve_chat_steady.told128": "db30cd54d721b7ac",
+    "serve_chat_steady.told384": "e905645daec74f90",
+    "serve_granite_toolcalls.told256": "821f2bd413047445",
+    "serve_granite_toolcalls.told1024": "e31d9cf9fe10211c",
 }
+
+
+def told_text(cell_name: str, length: int) -> str:
+    """``program_text``'s ``step<length>`` handed the mask: what the served
+    class runs since PR 56, which hands every model its rows' own tokens
+    (``program_text`` traces a model without experts with no mask)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.harness import loader
+    from ray_tpu.models.llama import init_llama, llama_next_token
+
+    cell = loader.load_cell(cell_name)
+    engine = dict(cell.get("engine") or {}, lora_rank=4, max_batch_size=8,
+                  allowed_batch_sizes=[8], max_new_tokens=8, seq_bucket=128)
+    cfg = loader.load_family(cell["model"]).served_kwargs(
+        cell["model"], engine, 1)["config"]
+    jaxpr = jax.make_jaxpr(lambda p, t, i, on: llama_next_token(
+        p, t, i, cfg, live=on))(
+            jax.eval_shape(lambda k: init_llama(cfg, k), jax.random.key(0)),
+            jax.ShapeDtypeStruct((8, length), jnp.int32),
+            jax.ShapeDtypeStruct((8,), jnp.int32),
+            jax.ShapeDtypeStruct((8, length), jnp.bool_))
+    return re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
 
 
 def program_hash(program: str) -> str:
     from tests.benchmark.test_deepseek_v2 import program_text
 
     cell, which = program.split(".")
-    return hashlib.sha256(program_text(cell, which).encode()).hexdigest()[:16]
+    text = (told_text(cell, int(which[len("told"):]))
+            if which.startswith("told") else program_text(cell, which))
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
 
 
 @pytest.mark.parametrize("program", sorted(PROGRAMS))

@@ -482,3 +482,28 @@ def test_the_two_width_forward_compiles_told_its_rows_lengths(
     assert used and max(used) <= min(
         reckoned + (2 * tile[0] * tile[1] if keep is not None else 0),
         fa.VMEM_LIMIT_BYTES)
+
+
+# the equal-width forward told its rows' lengths (PR 56), at the longest
+# bucket and batch of the cells that run it: Mellum2's full and sliding
+# layers (8 query heads a key head), OLMoE's one block a row, LFM2's head
+# of 64; in the VMEM it took without them, under the name it had
+@pytest.mark.parametrize("rows, seq, width, window", [
+    (4, 8192, 128, None), (4, 8192, 128, 1024), (8, 1152, 128, None),
+    (8, 1408, 64, None)])
+def test_the_equal_width_forward_compiles_told_its_rows_lengths(
+        rows, seq, width, window, one_chip, compiled_for_tpu):
+    def of(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q, k = of(rows, 8, seq, width), of(rows, 1, seq, width)
+    compiled = jax.jit(
+        lambda q, k, v, n: fa._flash_fwd(q, k, v, causal=True,
+                                         window=window, lengths=n)
+    ).lower(q, k, k, of(rows, dtype=jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert (fa.EQUAL_WINDOW_TRACE_NAME in compiled.as_text()) == bool(window)
+    tiles = fa.flash_tiles(seq, seq, head_dim=width)
+    reckoned = fa.tile_vmem_bytes(*tiles, head_dim=width)
+    used = [n for n in _scoped_vmem(compiled) if n]
+    assert used and max(used) <= reckoned <= fa.VMEM_LIMIT_BYTES

@@ -10,7 +10,9 @@ the chunks the kernel was told to skip, ``kda_chunks_skipped``, as the
 grid's less the live ones, and none for a model without the operator. The
 two-width flash forward of the model's latent layer is told the same
 lengths (PR 54; the kernel's own tests are ``tests/test_flash_lengths.py``)
-and ``flash_blocks_skipped`` counts the blocks it did not compute."""
+and ``flash_blocks_skipped`` counts the blocks it did not compute; the
+equal-width forward of grouped-query attention, full or ``sliding``, is
+told them too (PR 56) and ``attn_blocks_skipped`` counts its blocks."""
 
 import jax
 import jax.numpy as jnp
@@ -139,7 +141,6 @@ def test_the_step_counts_the_blocks_the_flash_forward_skipped(
     from ray_tpu.ops.pallas import flash_attention as fa
 
     monkeypatch.setattr(fa, "flash_tiles", lambda *a, **kw: (128, 128))
-    fa._shared_rope_steps.cache_clear()
     gen = generator(ling_shaped())
     for name in ("flash_blocks_run", "flash_blocks_live",
                  "flash_blocks_skipped"):
@@ -165,7 +166,91 @@ def test_the_step_counts_the_blocks_the_flash_forward_skipped(
     assert stats["flash_blocks_skipped"] == 2 * (8 + 3 + 1)
     assert stats["flash_blocks_skipped"] == (stats["flash_blocks_run"]
                                              - stats["flash_blocks_live"])
-    fa._shared_rope_steps.cache_clear()
+    # the model has no grouped-query attention layer
+    assert stats["attn_blocks_run"] == 0 == stats["attn_blocks_skipped"]
+
+
+def attention_shaped(sliding):
+    """Grouped-query attention, 4 heads on 2 of 32: two dense layers, or
+    Mellum2's pattern cut to three (two under a window of 65, then a full
+    one) with 4 experts of which 2 a token."""
+    kwargs = dict(
+        vocab_size=256, hidden=64, mlp_hidden=96, num_layers=2, num_heads=4,
+        num_kv_heads=2, head_dim=32, max_seq_len=384, rms_eps=1e-6,
+        dtype=jnp.float32, param_dtype=jnp.float32)
+    if sliding:
+        kwargs.update(
+            mlp_hidden=32, num_layers=3, num_experts=4, experts_per_token=2,
+            norm_topk_prob=True, sliding_window=65,
+            layer_types=("sliding_attention", "sliding_attention",
+                         "full_attention"))
+    return LlamaConfig(**kwargs)
+
+
+def blocks_by_hand(pad_len, lengths, window=None, block=128):
+    """``(run, live)`` a head over the rows: the kernel's rule, block by
+    block. A step is run if its key block is at or under the diagonal and,
+    under a window, holds a key that some query of the block sees."""
+    run = live = 0
+    for n in lengths:
+        for iq in range(pad_len // block):
+            for at in range(iq + 1):
+                if window and (at + 1) * block - 1 < iq * block - window + 1:
+                    continue
+                run += 1
+                live += iq * block < n and at * block < n
+    return run, live
+
+
+@pytest.mark.parametrize("sliding", [False, True], ids=["dense", "sliding"])
+def test_the_step_counts_the_blocks_the_equal_width_forward_skipped(
+        sliding, generator, monkeypatch):
+    """The same kind of steps through grouped-query attention alone, at
+    tiles of 128: a long row, a short one and two empty at a bucket of 384
+    (3 x 3 blocks a (row, head), 6 at or under the diagonal, 5 of them
+    inside a window of 65), then the short row alone at a bucket of 128,
+    then three whole rows of 128 and an empty one."""
+    from ray_tpu.ops.pallas import flash_attention as fa
+
+    monkeypatch.setattr(fa, "flash_tiles", lambda *a, **kw: (128, 128))
+    gen = generator(attention_shaped(sliding))
+    for name in ("attn_blocks_run", "attn_blocks_live",
+                 "attn_blocks_skipped"):
+        assert name in gen.STEP_COUNTERS
+        assert name in LlamaGenerator.engine_stats.__doc__
+    heads = 4
+    # (layers, window) of each operator
+    operators = [(2, 65), (1, None)] if sliding else [(2, None)]
+    states = [gen._prefill({"prompt": list(range(1, n + 1)), "max_new": 2},
+                           "") for n in (260, 5)] + [None, None]
+    gen._step("", states)
+    stats = gen.engine_stats()
+    if sliding:
+        assert stats["attn_blocks_run"] == heads * 4 * (2 * 5 + 6)
+        # the long row's blocks are all live, the short row's first alone
+        assert stats["attn_blocks_live"] == heads * (2 * (5 + 1) + (6 + 1))
+    else:
+        assert stats["attn_blocks_run"] == heads * 4 * 2 * 6
+        assert stats["attn_blocks_live"] == heads * 2 * (6 + 1)
+    gen._step("", [None, states[1], None, None])
+    whole = [gen._prefill({"prompt": [7] * 128, "max_new": 2}, "")
+             for _ in range(3)]
+    gen._step("", whole + [None])
+    stats = gen.engine_stats()
+    want_run = want_live = 0
+    for pad_len, lengths in ((384, (260, 5, 0, 0)), (128, (6, 0, 0, 0)),
+                             (128, (128, 128, 128, 0))):
+        for layers, window in operators:
+            run, live = blocks_by_hand(pad_len, lengths, window)
+            assert (run, live) == fa.causal_blocks(pad_len, lengths,
+                                                   (128, 128), window)
+            want_run += layers * heads * run
+            want_live += layers * heads * live
+    assert stats["attn_blocks_run"] == want_run
+    assert stats["attn_blocks_live"] == want_live
+    assert 0 < stats["attn_blocks_skipped"] == want_run - want_live
+    # `flash_blocks_*` are the two-width forward's alone
+    assert stats["flash_blocks_run"] == 0 == stats["flash_blocks_skipped"]
 
 
 def test_a_model_without_the_operator_skips_no_chunk(generator):
@@ -176,3 +261,7 @@ def test_a_model_without_the_operator_skips_no_chunk(generator):
     assert stats["positions_computed"] == 4 * 128
     assert stats["kda_chunks_skipped"] == 0 == stats["kda_chunks_run"]
     assert stats["flash_blocks_skipped"] == 0 == stats["flash_blocks_run"]
+    # its one attention layer (2 heads) at a bucket of 128: one block a
+    # (row, head), the three empty rows' skipped
+    assert (stats["attn_blocks_run"], stats["attn_blocks_live"],
+            stats["attn_blocks_skipped"]) == (4 * 2, 2, 3 * 2)

@@ -784,19 +784,19 @@ def test_llama_host_bytes_counts_ids_only():
         gen.engine.shutdown()
 
 
-def test_llama_step_compiles_nothing_after_fwd_warmed_its_shape():
-    """A caller that warms a (batch, seq) shape through ``_fwd`` has
+def test_llama_step_compiles_nothing_after_its_shape_was_warmed():
+    """A caller that warms a (batch, seq) shape as a benchmark does
+    (``warm_step_programs``: the step's program handed a mask, which every
+    model's step is since PR 56; ``_fwd``'s, with none, is another) has
     compiled everything ``_step`` runs at that shape: no backend
     compilation fires in the step, by the count a benchmark run is
     failed on (``compiles_in_window``)."""
-    import jax.numpy as jnp
-
     from benchmark.harness.onchip import count_compiles
 
     compiles = count_compiles()
     gen = _llama_gen(allowed=(2,), seq_bucket=24)
     try:
-        gen._fwd(gen._params, jnp.zeros((2, 24), jnp.int32), None)
+        gen.warm_step_programs(24)
         assert compiles, "the listener saw the warm-up compile nothing"
         warmed = len(compiles)
         states = [gen._prefill({"prompt": [3, 5, 7]}, ""),
