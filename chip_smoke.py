@@ -356,6 +356,9 @@ def run_serve(*, rehearsal: bool) -> dict:
         forward_compiles=info["forward_compiles"],
         compile_cache_dir=info["compile_cache_dir"],
         engine_steps=stats.get("steps"))
+    # what the persistent cache did with this bring-up's programs
+    say("serve", **{k: round(v, 2) if isinstance(v, float) else v
+                    for k, v in info["compiles"].items() if k != "slowest"})
     say("serve", base=outs[0], a1=outs[2], a2=outs[4])
     return info
 

@@ -1904,7 +1904,8 @@ class NodeAgent:
             await handle.conn.push(
                 "BecomeActor",
                 {"spec": spec, "actor_id": p["actor_id"],
-                 "assigned_instances": assigned},
+                 "assigned_instances": assigned,
+                 "actor_start": ev_source},
             )
 
         spawn_tracked(finish(), "agent-actor-finish")

@@ -385,6 +385,110 @@ class span:
         return False
 
 
+# ------------------------------------------------------------ start-up book
+# One book a process of where its start-up went, filled by `startup_span`:
+# seconds by phase (`startup.boot`, `startup.chip_bind`, `startup.construct`,
+# `startup.import_jax`, `startup.devices`, `startup.weights`, `startup.warm`;
+# a phase that runs again adds up), under `at` each phase's first
+# ``time.time()`` start, and what the phases' owners note beside them
+# (`actor_start`, `weights_ready_s`, `warm_s` by length). Read through
+# `startup_stats()`; `LlamaGenerator.engine_stats()["startup"]` is this dict.
+STARTUP: Dict[str, Any] = {"at": {}}
+# (the ring the root was decided under, `sampled_root()` then)
+_STARTUP_ROOT: List[Any] = [None, None]
+
+
+def startup_stats() -> Dict[str, Any]:
+    """The process's start-up book: a reference, not a copy."""
+    return STARTUP
+
+
+def startup_root() -> Optional[Tuple[int, int]]:
+    """``(trace_id, 0)`` of the one trace this process's start-up spans
+    share, decided once a ring by ``sampled_root()``; None with the
+    recorder off (one branch) or a start-up it did not sample."""
+    if not REC.enabled:
+        return None
+    if _STARTUP_ROOT[0] != REC.path:
+        _STARTUP_ROOT[:] = [REC.path, sampled_root()]
+    return _STARTUP_ROOT[1]
+
+
+class startup_span(span):
+    """A phase of this process's start-up: ``span("startup." + phase,
+    "startup")`` whose seconds also land in the book. Armed, it is recorded
+    under the process's start-up trace (``startup_root()``): a child of the
+    start-up span that encloses it on this thread, else a root of that
+    trace."""
+
+    __slots__ = ("at",)
+
+    def __init__(self, phase: str, extra: Optional[Dict] = None):
+        root, cur = startup_root(), _CUR_CTX.get()
+        inside = root is not None and cur is not None and cur[0] == root[0]
+        super().__init__("startup." + phase, "startup", extra,
+                         cur if inside else root)
+
+    def __enter__(self) -> "startup_span":
+        self.at = time.time()
+        return super().__enter__()
+
+    def __exit__(self, *exc) -> bool:
+        super().__exit__(*exc)
+        STARTUP["at"].setdefault(self.name, self.at)
+        STARTUP[self.name] = STARTUP.get(self.name, 0.0) + self.t1 - self.t0
+        return False
+
+
+def startup_record(name: str, at: float, dur_s: float,
+                   extra: Optional[Dict] = None) -> None:
+    """The ring's record of a start-up phase somebody else timed (one that
+    ended before this process had a ring, or ends on another thread than
+    it began on), a root of the start-up trace; nothing with the recorder
+    off."""
+    root = startup_root()
+    if root is not None:
+        REC.record(name, "startup", at, dur_s, root[0], REC.next_id(), 0,
+                   extra)
+
+
+class _FirstImport:
+    """A ``sys.meta_path`` entry that times the first import of one module
+    as a start-up phase, whoever imports it, and then takes itself out."""
+
+    def __init__(self, module: str, phase: str):
+        self.module, self.phase = module, phase
+
+    def find_spec(self, name, path=None, target=None):
+        if name != self.module:
+            return None
+        sys.meta_path.remove(self)
+        import importlib.util
+
+        spec = importlib.util.find_spec(name)
+        if spec is not None and spec.loader is not None:
+            run, phase = spec.loader.exec_module, self.phase
+
+            def exec_module(module):
+                with startup_span(phase):
+                    run(module)
+
+            spec.loader.exec_module = exec_module
+        return spec
+
+
+def time_first_import(module: str, phase: str) -> None:
+    """Have the process's first ``import <module>`` be the start-up phase
+    ``phase``, wherever it happens. Where the module is already imported
+    the phase reads 0.0 unless it was timed; asked twice, it is timed
+    once."""
+    if module in sys.modules:
+        STARTUP.setdefault("startup." + phase, 0.0)
+    elif not any(isinstance(f, _FirstImport) and f.module == module
+                 for f in sys.meta_path):
+        sys.meta_path.insert(0, _FirstImport(module, phase))
+
+
 # ------------------------------------------------------------ ring recovery
 def _span_dict(tup, role: str = "", pid: int = 0,
                node_id: str = "") -> Dict[str, Any]:

@@ -685,6 +685,14 @@ class Executor:
             finally:
                 os._exit(1)
             return
+        # which of the agent's `actor_start::{warm_hit, demand_hit, fork}`
+        # this worker was: the ring's record of its boot waited for it
+        book = _events.startup_stats()
+        book["actor_start"] = payload.get("actor_start", "")
+        if "startup.boot" in book:
+            _events.startup_record(
+                "startup.boot", book["at"]["startup.boot"],
+                book["startup.boot"], {"actor_start": book["actor_start"]})
         self.worker.current_actor_id = self._actor_id
         pg = spec.get("pg")
         if pg:
@@ -728,8 +736,14 @@ def _apply_accelerator_env(assigned: Dict[str, List[int]]) -> None:
         from ray_tpu._private.accelerators.tpu import TPUAcceleratorManager
         from ray_tpu._private.compile_cache import place_compile_cache
 
-        TPUAcceleratorManager.set_visible_accelerator_ids(assigned["TPU"])
-        place_compile_cache()
+        with _events.startup_span("chip_bind",
+                                  {"chips": len(assigned["TPU"])}):
+            TPUAcceleratorManager.set_visible_accelerator_ids(
+                assigned["TPU"])
+            place_compile_cache()
+        # a process that holds a chip: its first `import jax`, whoever
+        # makes it, is a phase of its start-up
+        _events.time_first_import("jax", "import_jax")
     if "GPU" in assigned:
         os.environ["CUDA_VISIBLE_DEVICES"] = ",".join(
             str(i) for i in assigned["GPU"]
@@ -807,8 +821,8 @@ async def _handle_capture_jax_trace(conn, p) -> Dict:
     return {"pid": os.getpid(), "trace_dir": out_dir, "files": files}
 
 
-def main() -> None:
-    boot_t0 = time.monotonic()
+def _boot() -> Worker:
+    """From the process's entry until it is ready for its first task."""
     agent_sock = os.environ["RAY_TPU_AGENT_SOCK"]
     from ray_tpu._private import lifecycle
     from ray_tpu._private import sanitizer as _sanitizer
@@ -855,11 +869,19 @@ def main() -> None:
 
     worker._on_agent_push = on_agent_push  # type: ignore[method-assign]
     worker.connect(agent_sock, mode=Worker.MODE_WORKER)
+    return worker
+
+
+def main() -> None:
+    # the ring is armed in `connect`: its record of this span waits for
+    # `become_actor`, which knows how the agent came by this worker
+    with _events.startup_span("boot"):
+        worker = _boot()
     if os.environ.get("RAY_TPU_BOOT_TRACE"):
         # time-to-leasable per worker (stderr -> worker .err log): the
         # number the warm pool exists to amortize
         print(f"BOOT_TRACE pid={os.getpid()} "
-              f"ready_ms={(time.monotonic() - boot_t0) * 1000:.1f} "
+              f"ready_ms={_events.STARTUP['startup.boot'] * 1000:.1f} "
               f"phases={getattr(worker, '_boot_trace', {})}",
               file=sys.stderr, flush=True)
 

@@ -20,6 +20,8 @@ import threading
 import time
 from typing import Any, Dict, Optional, Tuple
 
+from ray_tpu._private import events
+
 # admission-shed sentinel (kept under the old name too: external routers
 # from this repo's earlier rounds knew it as REJECTED)
 SHED = "__serve_shed__"
@@ -130,14 +132,17 @@ class Replica:
         args = tuple(self._resolve_deep(a) for a in args)
         kwargs = {k: self._resolve_deep(v) for k, v in kwargs.items()}
 
-        if isinstance(func_or_class, type):
-            self._callable = func_or_class(*args, **kwargs)
-            self._is_function = False
-        else:
-            self._callable = func_or_class
-            self._is_function = True
-        if user_config is not None:
-            self._apply_user_config(user_config)
+        # the user's callable whole: its constructor and its first
+        # `reconfigure`
+        with events.startup_span("construct", {"deployment": dep_name}):
+            if isinstance(func_or_class, type):
+                self._callable = func_or_class(*args, **kwargs)
+                self._is_function = False
+            else:
+                self._callable = func_or_class
+                self._is_function = True
+            if user_config is not None:
+                self._apply_user_config(user_config)
 
     @staticmethod
     def _resolve(arg):

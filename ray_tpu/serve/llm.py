@@ -51,10 +51,11 @@ from __future__ import annotations
 
 import collections
 import threading
+import time
 import zlib
 from typing import Any, Dict, List, Optional, Sequence
 
-from ray_tpu._private import events
+from ray_tpu._private import compile_cache, events
 from ray_tpu.serve._private.engine import ContinuousBatchingEngine
 from ray_tpu.serve.deployment import Application, Deployment
 
@@ -116,7 +117,10 @@ class LlamaGenerator:
     # `flash_blocks_skipped` the rest, which the kernel, told the rows'
     # lengths, did not compute; `attn_blocks_run`, `attn_blocks_live` and
     # `attn_blocks_skipped` the same three over the grouped-query attention
-    # layers' equal-width flash forwards, full or under the window
+    # layers' equal-width flash forwards, full or under the window;
+    # `step_compiles` and `step_compile_s` the back-end compilations, and
+    # their seconds, that began and ended inside a step's `llm.device`: a
+    # step that met a shape nobody warmed
     STEP_COUNTERS = ("host_bytes", "positions_computed", "positions_live",
                      "expert_pairs_fullest", "expert_pairs_mean",
                      "expert_pairs_here", "expert_pairs_all",
@@ -128,14 +132,23 @@ class LlamaGenerator:
                      "kda_chunks_skipped", "flash_blocks_run",
                      "flash_blocks_live", "flash_blocks_skipped",
                      "attn_blocks_run", "attn_blocks_live",
-                     "attn_blocks_skipped")
+                     "attn_blocks_skipped", "step_compiles",
+                     "step_compile_s")
 
     def __init__(self, config: str = "tiny", lora_rank: int = 4,
                  max_batch_size: int = 4,
                  allowed_batch_sizes: Optional[Sequence[int]] = (1, 2, 4),
                  max_new_tokens: int = 16, seq_bucket: int = 32,
                  max_adapters: int = 4, seed: int = 0):
+        # start-up's phases are spans that fill `events.startup_stats()`:
+        # the process's first `import jax` (0.0 where somebody had it
+        # already), then the client of the device coming up
+        events.time_first_import("jax", "import_jax")
         import jax
+
+        compile_cache.watch_compiles()
+        with events.startup_span("devices") as up:
+            up.extra = {"devices": len(jax.devices())}
 
         from ray_tpu.models.llama import (
             LATENT_OPERATORS, LlamaConfig, LoraConfig, init_llama)
@@ -179,9 +192,15 @@ class LlamaGenerator:
         # generations; the stacks are over the attention layers alone in a
         # model that has other operators (``init_lora``)
         self._lcfg = LoraConfig(rank=lora_rank, targets=("wq", "wv"))
-        # one jitted init: op-by-op it is a compile per parameter leaf
-        self._params = jax.jit(lambda k: init_llama(self._cfg, k))(
-            jax.random.PRNGKey(seed))
+        # one jitted init: op-by-op it is a compile per parameter leaf.
+        # The span ends when the call returns; the draw goes on beside the
+        # rest of the start-up, and a thread that waits for it notes when
+        # the parameters were on the device
+        with events.startup_span("weights") as draw:
+            self._params = jax.jit(lambda k: init_llama(self._cfg, k))(
+                jax.random.PRNGKey(seed))
+        threading.Thread(target=self._note_weights_ready, args=(draw,),
+                         name="weights-ready", daemon=True).start()
         self.max_new_tokens = max_new_tokens
         self.seq_bucket = max(8, int(seq_bucket))
         self._max_adapters = max_adapters
@@ -216,6 +235,16 @@ class LlamaGenerator:
             max_batch_size=max_batch_size,
             allowed_batch_sizes=allowed_batch_sizes,
             name="llama")
+
+    def _note_weights_ready(self, draw: "events.startup_span") -> None:
+        """``weights_ready_s`` of the start-up book: from the dispatch of
+        the init until the parameters are on the device."""
+        import jax
+
+        jax.block_until_ready(self._params)
+        ready_s = time.perf_counter() - draw.t0
+        events.startup_stats()["weights_ready_s"] = ready_s
+        events.startup_record("startup.weights_ready", draw.at, ready_s)
 
     # ------------------------------------------------------------- adapters
     def _adapter(self, model_id: str):
@@ -324,13 +353,20 @@ class LlamaGenerator:
             lora = self._adapter(model_id)
         # dispatch, transfer in, the program, transfer out: until every
         # result the host reads is on the host
+        compiled = compile_cache.compile_stats()
         with events.span("llm.device", "serve") as device:
             ids, _, load = self._run_step(tokens, last, mask, lora)
             ids = np.asarray(ids)
             if load is not None:
                 load = {k: np.asarray(v) for k, v in load.items()}
+        compiled_now = compile_cache.compile_stats()
         with events.span("llm.finish", "serve"):
             counts = self._counts
+            if compiled_now is not compiled:  # an event replaces the book
+                counts["step_compiles"] += (compiled_now["programs"]
+                                            - compiled["programs"])
+                counts["step_compile_s"] += (compiled_now["backend_s"]
+                                             - compiled["backend_s"])
             counts["step_device_s"] += device.t1 - device.t0
             counts["host_bytes"] += ids.nbytes
             counts["positions_computed"] += bucket * pad_len
@@ -509,9 +545,18 @@ class LlamaGenerator:
         module at ``flash_tiles``' tiles for ``head_dim``); and
         ``layer_kinds``, how many layers of each kind this replica serves
         (``LlamaConfig.kind_counts``: ``attention_dense`` alone for a dense
-        decoder)."""
+        decoder). Two books of the process ride along, each a reference to
+        a dict that exists already: ``startup`` (``events.startup_stats()``:
+        seconds by ``startup.*`` phase, ``weights_ready_s``, ``warm_s`` by
+        length) and ``compiles`` (``compile_cache.compile_stats()``: every
+        compilation by phase and by what the persistent cache did with
+        it); ``step_compiles`` and ``step_compile_s`` are the compilations
+        of that book that fell inside a step's ``llm.device``, which no
+        ``warm_step_programs`` covered."""
         return {**self.engine.stats(), **self._counts,
-                "layer_kinds": self._cfg.kind_counts()}
+                "layer_kinds": self._cfg.kind_counts(),
+                "startup": events.startup_stats(),
+                "compiles": compile_cache.compile_stats()}
 
     # ------------------------------------------- what a benchmark asks for
     def warm_step_programs(self, seq_len: int) -> None:
@@ -522,10 +567,13 @@ class LlamaGenerator:
         import numpy as np
 
         rows = self.engine.max_batch_size
-        ids, _, load = self._run_step(np.zeros((rows, seq_len), np.int32),
-                                      np.zeros(rows, np.int32),
-                                      np.zeros((rows, seq_len), bool))
-        jax.tree.map(np.asarray, (ids, load))
+        with events.startup_span("warm", {"seq_len": seq_len}) as warm:
+            ids, _, load = self._run_step(
+                np.zeros((rows, seq_len), np.int32),
+                np.zeros(rows, np.int32), np.zeros((rows, seq_len), bool))
+            jax.tree.map(np.asarray, (ids, load))
+        by_length = events.startup_stats().setdefault("warm_s", {})
+        by_length[seq_len] = by_length.get(seq_len, 0.0) + warm.t1 - warm.t0
 
     def logits_after_prompt(self, prompt: List[int]):
         """``[vocab]`` float32 after the prompt's last token, from the
@@ -562,6 +610,7 @@ class LlamaGenerator:
             "hidden": self._cfg.hidden,
             "compile_cache_dir": jax.config.jax_compilation_cache_dir,
             "forward_compiles": self._fwd._cache_size(),
+            "compiles": compile_cache.compile_stats(),
             "pid": os.getpid(),
         }
 

@@ -47,14 +47,31 @@ class JaxConfig:
 
 def _setup_worker(rank: int, world_size: int, coordinator: str,
                   cfg_wire: dict) -> None:
+    """A train worker's part of the start-up book
+    (``events.startup_stats()``): the whole of it is ``startup.construct``,
+    and where the workers form one jax mesh, the import and the rendezvous
+    in which the device's client comes up are ``startup.import_jax`` and
+    ``startup.devices``."""
+    from ray_tpu._private import events
+
+    with events.startup_span("construct", {"rank": rank}):
+        _setup_worker_body(rank, world_size, coordinator, cfg_wire)
+
+
+def _setup_worker_body(rank: int, world_size: int, coordinator: str,
+                       cfg_wire: dict) -> None:
     import os
+
+    from ray_tpu._private import compile_cache, events
 
     os.environ["RAY_TPU_TRAIN_RANK"] = str(rank)
     os.environ["RAY_TPU_TRAIN_WORLD_SIZE"] = str(world_size)
     os.environ["RAY_TPU_TRAIN_COORDINATOR"] = coordinator
     if cfg_wire["use_jax_distributed"]:
+        events.time_first_import("jax", "import_jax")
         import jax
 
+        compile_cache.watch_compiles()
         # Order matters: platform/device-count/collectives config must land
         # before the first backend touch, and a worker process recycled from
         # a previous group incarnation must drop its old coordination-service
@@ -75,18 +92,20 @@ def _setup_worker(rank: int, world_size: int, coordinator: str,
         # actor creation and its initialize() call used to park everyone
         # else on the coordination-service barrier forever. The timeout
         # turns that into a typed, retryable failure.
-        jax.distributed.initialize(
-            coordinator_address=coordinator,
-            num_processes=world_size,
-            process_id=rank,
-            initialization_timeout=int(
-                cfg_wire.get("rendezvous_timeout_s") or 300),
-        )
+        with events.startup_span("devices", {"processes": world_size}):
+            jax.distributed.initialize(
+                coordinator_address=coordinator,
+                num_processes=world_size,
+                process_id=rank,
+                initialization_timeout=int(
+                    cfg_wire.get("rendezvous_timeout_s") or 300),
+            )
+            local_devices = jax.local_device_count()
         expected = cfg_wire.get("num_local_devices")
-        if expected and jax.local_device_count() != expected:
+        if expected and local_devices != expected:
             raise RuntimeError(
                 f"worker {rank}: wanted {expected} local devices, got "
-                f"{jax.local_device_count()} — platform config landed too "
+                f"{local_devices} — platform config landed too "
                 "late (backend already initialized in this process)")
     if world_size > 1:
         from ray_tpu.util import collective as col

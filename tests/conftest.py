@@ -171,6 +171,21 @@ def pytest_collection_modifyitems(config, items):
             item.add_marker(pytest.mark.fast)
 
 
+@pytest.fixture
+def armed_recorder(tmp_path):
+    """The process's own recorder at rate 1.0 (the engine and the start-up
+    spans record into ``events.REC``), as ``tests/test_flight_recorder.py``
+    arms one of its own; left as it was found."""
+    from ray_tpu._private import events
+
+    rec = events.REC
+    was = (rec.enabled, rec.sample_rate)
+    assert rec.configure(str(tmp_path), "unit", sample_rate=1.0)
+    rec.drain()
+    yield rec
+    rec.enabled, rec.sample_rate = was
+
+
 # ---------------------------------------------------------------------------
 # Sanitizer gate (ISSUE 19): when the suite runs under RAY_TPU_SANITIZE=1
 # (test_sanitizer.py re-runs the kill -9 chaos test that way), any

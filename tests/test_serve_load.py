@@ -309,21 +309,6 @@ def test_engine_cancelled_before_joining_counts_no_wait():
     assert stats["queue_wait_s"] == stats["queue_wait_max_s"]
 
 
-@pytest.fixture
-def armed_recorder(tmp_path):
-    """The process's own recorder at rate 1.0 (the engine records into
-    ``events.REC``), as ``tests/test_flight_recorder.py`` arms one of its
-    own; left as it was found."""
-    from ray_tpu._private import events
-
-    rec = events.REC
-    was = (rec.enabled, rec.sample_rate)
-    assert rec.configure(str(tmp_path), "unit", sample_rate=1.0)
-    rec.drain()
-    yield rec
-    rec.enabled, rec.sample_rate = was
-
-
 def test_engine_spans_in_the_flight_recorder(armed_recorder):
     """Armed at 1.0, one request is one trace (``request.queue`` then
     ``request.generate``), and an iteration is three sibling spans that
