@@ -1222,7 +1222,8 @@ def _short_conv(cfg: LlamaConfig, u: jax.Array, lp: Dict[str, jax.Array],
 
 
 def _mamba(cfg: LlamaConfig, u: jax.Array, lp: Dict[str, jax.Array],
-           state: Optional[Tuple[jax.Array, jax.Array]] = None):
+           state: Optional[Tuple[jax.Array, jax.Array]] = None,
+           lengths: Optional[jax.Array] = None):
     """Mamba-2's mixer (granite-4.0-h's, as ``transformers``'
     ``granitemoehybrid`` computes it) on the normed input ``u [B, S, H]``
     -> (its output ``[B, S, H]``, the state after it). ``[z | xBC | dt] = u
@@ -1239,7 +1240,12 @@ def _mamba(cfg: LlamaConfig, u: jax.Array, lp: Dict[str, jax.Array],
     scan's state ``[B, heads, head_dim, mamba_state]`` float32 (None:
     zeros, a sequence's start). A forward pass and a decode's pass over a
     prompt scan by ``attn_impl`` (the kernel on the chip), a single token
-    by the recurrence itself."""
+    by the recurrence itself. ``lengths [B]`` int32 (a serving step's: how
+    many positions of each right-padded row are its own) lets the scan stop
+    at a row's end (``ops.ssm``): the scan's state is the one after the
+    row's last position, and the positions past the chunk that holds it get
+    ``W_out`` of zeros, which is zeros. The taps' rows are the last of the
+    padded length whatever the lengths."""
     from ray_tpu.ops.ssm import ssd_scan
 
     dt_, f32 = cfg.dtype, jnp.float32
@@ -1261,7 +1267,7 @@ def _mamba(cfg: LlamaConfig, u: jax.Array, lp: Dict[str, jax.Array],
                     xbc[..., inner:inner + n], xbc[..., inner + n:],
                     lp["mamba_d"].astype(f32), chunk=cfg.mamba_chunk, h0=h0,
                     impl="reference" if state is not None and S == 1
-                    else impl)
+                    else impl, lengths=lengths)
     with jax.named_scope("mamba_gated_norm"):
         y = y.reshape(B, S, inner).astype(f32) * jax.nn.silu(z.astype(f32))
         y = _rms_norm(y, lp["mamba_norm"], cfg.rms_eps).astype(dt_)
@@ -1392,7 +1398,7 @@ def _layer(cfg: LlamaConfig, x: jax.Array, lp: Dict[str, jax.Array],
         new_cache = None if kv_cache is None else state
         x = x + _res(y)
     elif "mamba_in" in lp:
-        y, state = _mamba(cfg, h, lp, kv_cache)
+        y, state = _mamba(cfg, h, lp, kv_cache, lengths)
         new_cache = None if kv_cache is None else state
         x = x + _res(y)
     elif "kda_in" in lp:
@@ -1768,8 +1774,9 @@ def llama_next_token(
     (query, key) pairs its choice kept over those positions' queries.
     With ``live`` the routed experts compute the marked positions alone,
     and every model is told each row's length (the marks' row sums: a
-    row's own tokens are its first) so that the delta rule's kernel and the
-    flash forwards, at two widths and at equal ones, stop at its end; the
+    row's own tokens are its first) so that the delta rule's kernel, the
+    state-space scan's and the flash forwards, at two widths and at equal
+    ones, stop at its end; the
     hidden states of the others are not a forward pass's. Without
     ``live`` every position is computed: ``last`` is not taken for a
     length, because a caller who wants every position's hidden state hands

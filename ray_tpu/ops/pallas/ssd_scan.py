@@ -36,12 +36,40 @@ state of ``8 x [128, 64]`` float32, some 3 MB of scoped VMEM in all; 16
 heads would halve the grid's steps (256 at 8 rows of 1024) and the
 recomputed ``C B^T`` (an eighth of a step's MXU work at 8) and double the
 unrolled body, for a kernel the vector unit bounds either way.
+
+The rows' lengths. A batch's rows are padded on the right to one length,
+and a chunk whose first position lies past its row's end holds nothing of
+the row. Told how many chunks of each row hold a position of its own
+(``lengths``, as ``ceil(length / Q)`` and the last such chunk's index, one
+int32 pair a row, prefetched into SMEM before the grid runs), a grid step
+past them does none of the work above: it leaves the state as it is and
+writes ZEROS to its block of ``y`` (a padded position's output goes on into
+the gated norm, the out-projection and the next layers, and what nobody
+wrote may be a NaN, which a masked zero does not silence). The six
+chunk-indexed operands' index maps hold such a step at the row's last live
+chunk, which is the block already in VMEM, so nothing is fetched for it
+either; a row of no position fetches its first chunk once and hands ``h0``
+back. What the state means is the dispatcher's to say (``ops/ssm.py``: it
+takes ``dt`` for 0 past a row's end, so the positions past it inside the
+last live chunk, which the kernel runs like any other, neither decay the
+state nor add to it, and the state handed back is the one after the row's
+last POSITION). Not told (``lengths=None``: training, ``llama_decode``'s
+pass over a prompt) the call is the one it was, with no scalar operand and
+no branch. The vector unit binds the kernel, so a chunk not run is its time
+back but for a skipped grid step's own, 1.4 us: at 8 rows of 1024 and 64
+heads (256 grid steps, 32 row-chunks a head group; ms a layer, the kernel
+alone, PERF.md section 6, PR 59) 1.07 not told; told, 1.10 with every
+row-chunk live (the scalar's read and the branch: 0.024 more), 0.62 with 10
+live, 0.54 with 7, 0.46 with 4 and 0.37 with none. The 1.4 us are the grid
+step's own (ten operands' index maps, a live step's being 4.2 us in all),
+not the zeros' write: with the output's block held too and nothing written
+the empty batch read 0.353 for 0.367.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -64,14 +92,38 @@ def ssd_heads_a_step(heads: int, head_dim: int) -> int:
     return heads
 
 
-def _kernel(x_ref, dt_ref, cumc_ref, cumr_ref, bt_ref, c_ref, d_ref, h0_ref,
-            y_ref, hT_ref, state, *, hb: int, P: int, Q: int):
+def _kernel(*refs, hb: int, P: int, Q: int, told: bool):
+    # told: the row's live chunks come first, from SMEM (`ssd_scan_chunked`)
+    chunks_ref, refs = (refs[0], refs[1:]) if told else (None, refs)
+    *of_a_chunk, h0_ref, y_ref, hT_ref, state = refs
     ic = pl.program_id(2)
 
     @pl.when(ic == 0)
     def _enter():
         state[...] = h0_ref[0]
 
+    if told:
+        live = ic < chunks_ref[0, pl.program_id(0)]
+
+        @pl.when(live)
+        def _chunk():
+            _a_chunk(*of_a_chunk, y_ref, state, hb=hb, P=P, Q=Q)
+
+        @pl.when(jnp.logical_not(live))
+        def _past_the_rows_end():
+            y_ref[...] = jnp.zeros_like(y_ref)
+    else:
+        _a_chunk(*of_a_chunk, y_ref, state, hb=hb, P=P, Q=Q)
+
+    @pl.when(ic == pl.num_programs(2) - 1)
+    def _leave():
+        hT_ref[0] = state[...]
+
+
+def _a_chunk(x_ref, dt_ref, cumc_ref, cumr_ref, bt_ref, c_ref, d_ref, y_ref,
+             state, *, hb: int, P: int, Q: int):
+    """A chunk's whole work (module docstring): ``y`` of its positions from
+    the state it enters with, and the state after it."""
     f32 = jnp.float32
     c = c_ref[0]                                           # [Q, N]
     bt = bt_ref[0]                                         # [N, Q]
@@ -107,20 +159,23 @@ def _kernel(x_ref, dt_ref, cumc_ref, cumr_ref, bt_ref, c_ref, d_ref, h0_ref,
                     + jnp.dot(bt, xw.astype(mdt),
                               preferred_element_type=f32))
 
-    @pl.when(ic == pl.num_programs(2) - 1)
-    def _leave():
-        hT_ref[0] = state[...]
-
 
 def ssd_scan_chunked(x: jax.Array, dt: jax.Array, a: jax.Array,
                      b: jax.Array, c: jax.Array, d: jax.Array,
-                     h0: jax.Array, chunk: int
+                     h0: jax.Array, chunk: int,
+                     lengths: Optional[jax.Array] = None
                      ) -> Tuple[jax.Array, jax.Array]:
     """``x [B, S, H, P]``, ``dt [B, S, H]`` float32 (after its softplus),
     ``a [H]`` float32 (negative), ``b`` and ``c`` ``[B, S, N]`` (one group:
     every head's), ``d [H]``, the entering state ``h0 [B, H, P, N]``
     float32 -> (``y [B, S, H, P]`` in ``x``'s type, the state after the
-    last position ``[B, H, P, N]`` float32). ``S`` is whole chunks."""
+    last position ``[B, H, P, N]`` float32). ``S`` is whole chunks.
+    ``lengths [B]`` int32: how many of a row's positions are its own, the
+    rest being padding on its right (None: all ``S`` of every row, and the
+    call it was before it knew of lengths). A chunk that starts at or past
+    a row's length is not run and not read: its ``y`` is zeros, and the
+    state is the one after the row's last chunk that ran, ``h0`` for a row
+    of no position (module docstring)."""
     B, S, H, P = x.shape
     N = b.shape[-1]
     if S % chunk or chunk % _LANES:
@@ -128,9 +183,11 @@ def ssd_scan_chunked(x: jax.Array, dt: jax.Array, a: jax.Array,
                          f"chunks of {chunk}, or a chunk is not whole lane "
                          f"tiles of {_LANES}")
     if b.shape != (B, S, N) or c.shape != (B, S, N) \
-            or dt.shape != (B, S, H) or h0.shape != (B, H, P, N):
+            or dt.shape != (B, S, H) or h0.shape != (B, H, P, N) \
+            or (lengths is not None and lengths.shape != (B,)):
         raise ValueError(f"ssd_scan_chunked: x{x.shape} dt{dt.shape} "
-                         f"b{b.shape} c{c.shape} h0{h0.shape}")
+                         f"b{b.shape} c{c.shape} h0{h0.shape} "
+                         f"lengths{getattr(lengths, 'shape', None)}")
     hb = ssd_heads_a_step(H, P)
     G, Q, f32 = H // hb, chunk, jnp.float32
     # the running sum of dt A inside each chunk: [B, S, H] float32 numbers
@@ -146,29 +203,52 @@ def ssd_scan_chunked(x: jax.Array, dt: jax.Array, a: jax.Array,
         jnp.swapaxes(cum, 1, 2), jnp.swapaxes(b, 1, 2), c,
         jnp.repeat(d.astype(f32), P).reshape(G, 1, hb * P),
         jnp.swapaxes(h0.astype(f32), 2, 3))
+    told = lengths is not None
+    if told:
+        # a row's live chunks and the last of them, made once, here: the
+        # body's test is one compare and an index map one `min` of two
+        # scalars (a map is traced again at every lowering, which no
+        # compile cache keeps)
+        live = (lengths.astype(jnp.int32) + (Q - 1)) // Q
+        operands = (jnp.stack([live, jnp.maximum(live - 1, 0)]),) + operands
+
+    def at(block, place, held=False):
+        """A block at ``place(i, g, k)``: row ``i``, head group ``g``,
+        chunk ``k`` or, ``held`` and told the lengths, the row's last live
+        chunk past it: the block already in VMEM, so nothing is fetched."""
+        if not told:
+            return pl.BlockSpec(block, place)
+        return pl.BlockSpec(block, lambda i, g, k, n: place(
+            i, g, jax.lax.min(k, n[1, i]) if held else k))
+
+    spec = dict(
+        grid=(B, G, S // Q),
+        in_specs=[
+            at((1, Q, hb * P), lambda i, g, k: (i, k, g), held=True),
+            at((1, 1, Q, hb), lambda i, g, k: (i, g, k, 0), held=True),
+            at((1, 1, Q, hb), lambda i, g, k: (i, g, k, 0), held=True),
+            at((1, hb, Q), lambda i, g, k: (i, g, k), held=True),
+            at((1, N, Q), lambda i, g, k: (i, 0, k), held=True),
+            at((1, Q, N), lambda i, g, k: (i, k, 0), held=True),
+            at((1, 1, hb * P), lambda i, g, k: (g, 0, 0)),
+            at((1, hb, N, P), lambda i, g, k: (i, g, 0, 0)),
+        ],
+        out_specs=[
+            at((1, Q, hb * P), lambda i, g, k: (i, k, g)),
+            at((1, hb, N, P), lambda i, g, k: (i, g, 0, 0)),
+        ],
+        scratch_shapes=[pltpu.VMEM((hb, N, P), f32)])
+    if told:
+        spec = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, **spec))
     with jax.named_scope(SSD_SCAN_TRACE_NAME):  # the kernel's alone
         y, hT = pl.pallas_call(
-            functools.partial(_kernel, hb=hb, P=P, Q=Q),
-            grid=(B, G, S // Q),
-            in_specs=[
-                pl.BlockSpec((1, Q, hb * P), lambda i, g, k: (i, k, g)),
-                pl.BlockSpec((1, 1, Q, hb), lambda i, g, k: (i, g, k, 0)),
-                pl.BlockSpec((1, 1, Q, hb), lambda i, g, k: (i, g, k, 0)),
-                pl.BlockSpec((1, hb, Q), lambda i, g, k: (i, g, k)),
-                pl.BlockSpec((1, N, Q), lambda i, g, k: (i, 0, k)),
-                pl.BlockSpec((1, Q, N), lambda i, g, k: (i, k, 0)),
-                pl.BlockSpec((1, 1, hb * P), lambda i, g, k: (g, 0, 0)),
-                pl.BlockSpec((1, hb, N, P), lambda i, g, k: (i, g, 0, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, Q, hb * P), lambda i, g, k: (i, k, g)),
-                pl.BlockSpec((1, hb, N, P), lambda i, g, k: (i, g, 0, 0)),
-            ],
+            functools.partial(_kernel, hb=hb, P=P, Q=Q, told=told),
             out_shape=[jax.ShapeDtypeStruct((B, S, H * P), x.dtype),
                        jax.ShapeDtypeStruct((B, H, N, P), f32)],
-            scratch_shapes=[pltpu.VMEM((hb, N, P), f32)],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=flash_attention._interpret(),
+            **spec,
         )(*operands)
     return y.reshape(B, S, H, P), jnp.swapaxes(hT, 2, 3)

@@ -13,6 +13,17 @@ position that every head reads (one group); ``D [H]``. The state, the
 decays and their sums are float32 everywhere. Returns ``y [B, S, H, P]`` in
 ``x``'s type and the state after the last position ``[B, H, P, N]``
 float32: all a decode keeps of a row.
+
+``lengths [B]`` int32 says how many of a row's positions are its own where
+a batch's rows are padded on the right to one length (a serving step's
+are). The scan is causal, so a row's own outputs do not depend on it. With
+it ``dt`` is taken for 0 at every position past its row's end, which then
+neither decays the state nor adds to it: the state handed back is the one
+after the row's last POSITION by either impl (``h0`` for a row of none),
+what a decode goes on from. The kernel runs no chunk that starts past a
+row's end, which is its whole time for such chunks, and every ``y`` past
+the chunk that holds the row's end is zeros by either impl; inside that
+chunk a padded position reads the standing state, ``y_t = h C_t + D x_t``.
 """
 
 from __future__ import annotations
@@ -72,15 +83,22 @@ _kernel_scan.defvjp(_kernel_scan_fwd, _kernel_scan_bwd)
 
 def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
              c: jax.Array, d: jax.Array, *, chunk: int = 256,
-             h0: Optional[jax.Array] = None, impl: str = "auto"
+             h0: Optional[jax.Array] = None, impl: str = "auto",
+             lengths: Optional[jax.Array] = None
              ) -> Tuple[jax.Array, jax.Array]:
     """The module docstring's scan. impl as ``attention``'s: ``auto`` (on
     the TPU platform the kernel for a length of whole chunks, else and on
     the CPU platform the reference), ``flash`` (the kernel at any length: a
     ragged last chunk is padded with positions whose ``dt`` is 0, which
     neither decay the state nor add to it, and their outputs dropped) or
-    ``reference``."""
+    ``reference``. ``lengths``: the module docstring's last paragraph
+    (None: every row is whole)."""
     S = x.shape[1]
+    if lengths is not None:
+        # a position past its row's end takes no step: by either impl the
+        # state stops at the row's last position
+        own = jnp.arange(S) < lengths[:, None]                 # [B, S]
+        dt = jnp.where(own[..., None], dt, 0)
     if impl == "auto":
         platform = jax.default_backend()
         if platform not in ("tpu", "cpu"):
@@ -90,7 +108,13 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
         impl = "flash" if platform == "tpu" and S % chunk == 0 \
             else "reference"
     if impl == "reference":
-        return reference_ssd_scan(x, dt, a, b, c, d, h0)
+        y, h = reference_ssd_scan(x, dt, a, b, c, d, h0)
+        if lengths is not None:
+            # the kernel's zeros: past the chunk that holds a row's end
+            ends = -(-lengths // chunk) * chunk
+            y = jnp.where((jnp.arange(S) < ends[:, None])[..., None, None],
+                          y, 0)
+        return y, h
     if impl != "flash":
         raise ValueError(f"unknown ssd_scan impl {impl!r}; expected "
                          "auto|flash|reference")
@@ -101,5 +125,15 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
     if pad:
         x, dt, b, c = (jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),)
                                * (v.ndim - 2)) for v in (x, dt, b, c))
-    y, h = _kernel_scan(x, dt, a, b, c, d, h0, chunk)
+    if lengths is None:
+        y, h = _kernel_scan(x, dt, a, b, c, d, h0, chunk)
+    else:
+        # a serving step's call, which nobody differentiates (jax raises a
+        # NotImplementedError of its own): under `custom_vjp`, whose trace
+        # of a kernel with a branch round its body churns memory, a
+        # bucket's warm-up call took 6.0 s on the chip and not 3.2 (PERF.md
+        # section 6, PR 59)
+        from ray_tpu.ops.pallas.ssd_scan import ssd_scan_chunked
+
+        y, h = ssd_scan_chunked(x, dt, a, b, c, d, h0, chunk, lengths)
     return y[:, :S], h

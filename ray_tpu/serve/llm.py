@@ -21,8 +21,10 @@ own. Routed experts multiply those alone: the padding's pairs are sorted
 past the last expert's and get no visit (``models/moe.py::expert_ffn``;
 ``expert_pairs_skipped`` counts them). Kimi delta attention's kernel runs
 no chunk past a row's end (``ops/pallas/kda_chunk.py``;
-``kda_chunks_skipped`` counts them), and the flash forwards compute no
-block past one (``ops/pallas/flash_attention.py``): latent attention's
+``kda_chunks_skipped`` counts them), nor does the state-space scan's
+(``ops/pallas/ssd_scan.py``; ``ssm_chunks_skipped``), and the flash
+forwards compute no block past one
+(``ops/pallas/flash_attention.py``): latent attention's
 two-width forward (``flash_blocks_skipped``) and grouped-query
 attention's equal-width one, full or under its window
 (``attn_blocks_skipped``).
@@ -104,12 +106,13 @@ class LlamaGenerator:
     # windows (latent attention's `window`, grouped-query attention's
     # `sliding`) and `window_keys_seen` the causal pairs of the same
     # queries, each summed over steps and those layers; `ssm_chunks_run` the
-    # chunks the state-space layers' scans ran (layers x rows x chunks of
-    # the padded length) and `ssm_chunks_live` those among them that hold
-    # one of a row's own positions; `kda_chunks_run` and `kda_chunks_live`
-    # the same two over the Kimi delta attention layers' grids, and
-    # `kda_chunks_skipped` the chunks of those grids that the kernel, told
-    # the rows' lengths, did not run: the difference of the two;
+    # chunks of the state-space layers' scans' grids (layers x rows x chunks
+    # of the padded length), `ssm_chunks_live` those among them that hold
+    # one of a row's own positions and `ssm_chunks_skipped` the chunks of
+    # those grids that the kernel, told the rows' lengths, did not run: the
+    # difference of the two; `kda_chunks_run`, `kda_chunks_live` and
+    # `kda_chunks_skipped` the same three over the Kimi delta attention
+    # layers' grids;
     # `flash_blocks_run` the grid steps at or under the diagonal of the
     # latent operators' two-width flash forwards (layers x rows x heads x
     # the padded length's), `flash_blocks_live` those among them whose
@@ -128,7 +131,8 @@ class LlamaGenerator:
                      "expert_rows_all", "step_device_s", "index_keys_kept",
                      "index_keys_seen", "window_keys_kept",
                      "window_keys_seen", "ssm_chunks_run",
-                     "ssm_chunks_live", "kda_chunks_run", "kda_chunks_live",
+                     "ssm_chunks_live", "ssm_chunks_skipped",
+                     "kda_chunks_run", "kda_chunks_live",
                      "kda_chunks_skipped", "flash_blocks_run",
                      "flash_blocks_live", "flash_blocks_skipped",
                      "attn_blocks_run", "attn_blocks_live",
@@ -306,9 +310,9 @@ class LlamaGenerator:
         [B]`` and ``mask [B, S]`` (the rows' own tokens, which every model
         is told: for its routers' load, for what its indexers' choices
         kept, and for the rows' lengths, the marks' row sums, past which
-        the delta rule's kernel runs no chunk and the flash forwards, at
-        two widths and at equal ones, compute no block) -> (ids, hidden,
-        load)."""
+        the delta rule's kernel and the state-space scan's run no chunk
+        and the flash forwards, at two widths and at equal ones, compute
+        no block) -> (ids, hidden, load)."""
         import jax.numpy as jnp
 
         return self._step_fn(self._params, jnp.asarray(tokens), lora, last,
@@ -389,26 +393,21 @@ class LlamaGenerator:
                 counts["window_keys_kept"] += (int(inside.sum())
                                                * self._window_layers)
                 counts["window_keys_seen"] += causal * self._window_layers
-            # the scans run every row of the batch over the whole padded
-            # length, and the delta rule's grid is as large; a chunk is
-            # live while its first position is one of its row's own
+            # the scans' and the delta rule's grids are every row of the
+            # batch over the whole padded length; a chunk is live while its
+            # first position is one of its row's own, and both kernels are
+            # told the rows' lengths (`_run_step`) and run no other:
+            # reckoned here, from the mask the step handed the program, as
+            # the padding's pairs are
             for name, layers, chunk in (
                     ("ssm", self._ssm_layers, self._cfg.mamba_chunk),
                     ("kda", self._kda_layers, self._cfg.kda_chunk)):
                 if layers:
-                    counts[name + "_chunks_run"] += (
-                        layers * bucket * -(-pad_len // chunk))
-                    counts[name + "_chunks_live"] += layers * int(
-                        (-(-mask.sum(axis=1) // chunk)).sum())
-            if self._kda_layers:
-                # the delta rule's kernel is told the rows' lengths
-                # (`_run_step`) and runs no chunk past a row's end:
-                # reckoned here, from the mask the step handed the
-                # program, as the padding's pairs are
-                chunk = self._cfg.kda_chunk
-                counts["kda_chunks_skipped"] += self._kda_layers * int(
-                    (-(-pad_len // chunk) - -(-mask.sum(axis=1) // chunk)
-                     ).sum())
+                    run = layers * bucket * -(-pad_len // chunk)
+                    own = layers * int((-(-mask.sum(axis=1) // chunk)).sum())
+                    counts[name + "_chunks_run"] += run
+                    counts[name + "_chunks_live"] += own
+                    counts[name + "_chunks_skipped"] += run - own
             if pad_len % 128 == 0:
                 # the flash forwards are told the same lengths and compute
                 # no block past a row's end; a length off the kernels' 128
@@ -510,24 +509,22 @@ class LlamaGenerator:
         ``window_keys_seen`` (the causal pairs of the same queries, ``t +
         1`` each: what the window leaves of them is the ratio);
         ``ssm_chunks_run`` and ``ssm_chunks_live`` (over the layers whose
-        operator is ``mamba``: the chunks of ``mamba_chunk`` positions
-        their scans ran, rows of the batch x chunks of the padded length,
+        operator is ``mamba``: the chunks of ``mamba_chunk`` positions of
+        their scans' grid, rows of the batch x chunks of the padded length,
         and those among them that hold at least one of a row's own
         positions, a row of ``n`` tokens having ``ceil(n / mamba_chunk)``;
         both reckoned on the host from the rows' lengths and summed over
-        steps and those layers, 0 for a model without them: what is left
-        between them is chunks of padding, which a scan that stopped at a
-        row's end would not run); ``kda_chunks_run`` and
-        ``kda_chunks_live`` (the same two over the layers whose operator is
-        ``kda`` and their chunks of ``kda_chunk`` positions: the first is
-        the kernel's grid, rows x chunks of the padded length) and
-        ``kda_chunks_skipped`` (the chunks of that grid past a row's end,
+        steps and those layers, 0 for a model without them) and
+        ``ssm_chunks_skipped`` (the chunks of that grid past a row's end,
         which the kernel is told and does not run: layers x the sum over
-        rows of the padded length's chunks less ``ceil(n / kda_chunk)``,
+        rows of the padded length's chunks less ``ceil(n / mamba_chunk)``,
         the difference of the two, reckoned on the host from the mask the
-        program was handed); ``flash_blocks_run``, ``flash_blocks_live``
-        and ``flash_blocks_skipped`` (over the layers whose operator is
-        latent attention, plain, windowed or indexed, at a padded length on
+        program was handed); ``kda_chunks_run``, ``kda_chunks_live`` and
+        ``kda_chunks_skipped`` (the same three over the layers whose
+        operator is ``kda`` and their chunks of ``kda_chunk`` positions,
+        told and reckoned the same way); ``flash_blocks_run``,
+        ``flash_blocks_live`` and ``flash_blocks_skipped`` (over the layers
+        whose operator is latent attention, plain, windowed or indexed, at a padded length on
         the two-width flash forward's 128 grid: the (query block, key
         block) steps of its grid at or under the diagonal, rows x heads x
         the padded length's by ``flash_tiles``, under a window the

@@ -117,7 +117,19 @@ model's step with no mask, which is what ``_run_step`` handed it until this
 PR and what ``_FullLogits`` still runs. What the served class runs for them
 now, the same step handed the mask (one input more, the ``reduce_sum``, the
 kernels told), is ``told_text``'s, on record as ``told<length>``: four new
-lines.
+lines. PR 59 (the state-space scan is told its rows' lengths: ``_layer`` ->
+``_mamba`` -> ``ops.ssm.ssd_scan`` carry what ``llama_next_token`` already
+made, ``dt`` is taken for 0 past a row's end, and ``ssd_scan_chunked`` takes
+a row's live chunks as a scalar-prefetched operand and runs no chunk past
+them) moved ``serve_granite_toolcalls.told256`` and ``.told1024``, as it
+meant to: a select on ``dt`` a run of mixers, and every scan's kernel with
+one operand more, ``lax`` primitives alone in its index maps and no
+``custom_vjp_call`` round it (told, the kernel is called bare: a bucket's
+warm-up call was 1.8 s longer with it, PERF.md section 6). The
+twenty-eight others are what its parent ``7c64761`` gives to the character:
+that cell's ``init``, ``step256`` and ``step1024`` among them (no mask, so
+no lengths: ``ssd_scan_chunked`` with ``lengths=None`` is the call it was),
+and every other cell's lines, no other model having the operator.
 """
 
 import hashlib
@@ -153,8 +165,8 @@ PROGRAMS = {
     "serve_mellum2_projctx.step8192": "2d3f004b61858767",
     "serve_chat_steady.told128": "db30cd54d721b7ac",
     "serve_chat_steady.told384": "e905645daec74f90",
-    "serve_granite_toolcalls.told256": "821f2bd413047445",
-    "serve_granite_toolcalls.told1024": "e31d9cf9fe10211c",
+    "serve_granite_toolcalls.told256": "5146abf0b9b112f0",
+    "serve_granite_toolcalls.told1024": "83d739cca3a05509",
 }
 
 

@@ -260,6 +260,7 @@ def test_a_model_without_the_operator_skips_no_chunk(generator):
     stats = gen.engine_stats()
     assert stats["positions_computed"] == 4 * 128
     assert stats["kda_chunks_skipped"] == 0 == stats["kda_chunks_run"]
+    assert stats["ssm_chunks_skipped"] == 0 == stats["ssm_chunks_run"]
     assert stats["flash_blocks_skipped"] == 0 == stats["flash_blocks_run"]
     # its one attention layer (2 heads) at a bucket of 128: one block a
     # (row, head), the three empty rows' skipped

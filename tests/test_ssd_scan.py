@@ -2,8 +2,10 @@
 against the recurrence itself (``ops.ssm.reference_ssd_scan``): chunks
 SHORTER than the lengths, so that the state crosses edges, lengths that are
 not whole chunks, so that a ragged last chunk bites, an entering state,
-right-padded rows, and the controls that say the tolerance can tell a
-fault: the state dropped at the chunks' edges, ``D x`` left out.
+right-padded rows, told their lengths (PR 59: no chunk past a row's end
+is run, and the state is the one after the row's last position) and not,
+and the controls that say the tolerance can tell a fault: the state
+dropped at the chunks' edges, ``D x`` left out.
 
 Both sides compute in float32 here and differ by the order of sums alone
 (the kernel's sums run by chunk): some 1e-5 of outputs of order 10.
@@ -83,24 +85,119 @@ def test_the_skip_is_in_it():
     np.testing.assert_allclose(without + d[:, None] * x, want, **TIGHT)
 
 
-def test_a_rows_padding_reaches_none_of_its_tokens():
+# rows of no position, one, a chunk and one, 300 and the whole length
+LENGTHS = (0, 1, 129, 300, 384)
+
+
+def padded_rows():
+    """Five rows of 384 with ``LENGTHS`` positions of their own, and the
+    same rows with other numbers where their padding lies."""
+    x, dt, a, b, c, d, h0 = inputs(B=5, S=384)
+    own = jnp.arange(384)[None, :] < jnp.asarray(LENGTHS)[:, None]
+    other = [jnp.where(own.reshape(own.shape + (1,) * (v.ndim - 2)), v,
+                       jnp.roll(v, 1, axis=0)[:, ::-1])
+             for v in (x, dt, b, c)]
+    return (x, dt, b, c), other, (a, d, h0)
+
+
+@pytest.mark.parametrize("told", [False, True], ids=["not_told", "told"])
+def test_a_rows_padding_reaches_none_of_its_tokens(told):
     """Rows padded on the right, as a serving step pads them: whatever lies
     after a row's own positions, its outputs are the recurrence's over its
-    own positions alone."""
-    x, dt, a, b, c, d, _ = inputs(S=384)
-    lengths = (300, 77)
-    got, _ = ssm.ssd_scan(x, dt, a, b, c, d, chunk=128, impl="flash")
-    other = [v.at[0, 300:].set(v[1, :84]).at[1, 77:].set(v[0, :307])
-             for v in (x, dt, b, c)]
-    moved, _ = ssm.ssd_scan(other[0], other[1], a, other[2], other[3], d,
-                            chunk=128, impl="flash")
-    for row, n in enumerate(lengths):
-        alone, _ = ssm.reference_ssd_scan(
-            *(v[row:row + 1, :n] for v in (x, dt)), a,
-            *(v[row:row + 1, :n] for v in (b, c)), d)
+    own positions alone. Told the rows' lengths, the state handed back is
+    the recurrence's over those positions too, whatever the padding holds,
+    and a chunk past a row's end is zeros."""
+    rows, other, (a, d, h0) = padded_rows()
+    lengths = jnp.asarray(LENGTHS, jnp.int32) if told else None
+
+    def scan(x, dt, b, c):
+        return ssm.ssd_scan(x, dt, a, b, c, d, chunk=128, h0=h0,
+                            impl="flash", lengths=lengths)
+
+    got, h = scan(*rows)
+    moved, h_moved = scan(*other)
+    for row, n in enumerate(LENGTHS):
+        x, dt, b, c = (v[row:row + 1, :n] for v in rows)
+        alone, h_alone = ssm.reference_ssd_scan(x, dt, a, b, c, d,
+                                                h0[row:row + 1])
         np.testing.assert_allclose(got[row, :n], alone[0], **TIGHT)
         np.testing.assert_array_equal(moved[row, :n], got[row, :n])
-    assert float(jnp.abs(moved[0, 300:] - got[0, 300:]).max()) > 0.1
+        if told:
+            np.testing.assert_allclose(h[row], h_alone[0], **TIGHT)
+            np.testing.assert_array_equal(h_moved[row], h[row])
+            assert not np.asarray(got[row, -(-n // 128) * 128:]).any()
+    if told:  # inside the chunk that holds a row's end the padding is run
+        assert float(jnp.abs(got[3, 300:]).max()) > 0.1
+    else:
+        assert float(jnp.abs(moved[3, 300:] - got[3, 300:]).max()) > 0.1
+        assert float(jnp.abs(h_moved[3] - h[3]).max()) > 0.1
+
+
+@pytest.mark.parametrize("impl", ["flash", "reference"])
+def test_a_row_of_no_position_hands_its_state_back(impl):
+    """To the bit, by either impl: no position of it takes a step."""
+    rows, _, (a, d, h0) = padded_rows()
+    x, dt, b, c = rows
+    y, h = ssm.ssd_scan(x, dt, a, b, c, d, chunk=128, h0=h0, impl=impl,
+                        lengths=jnp.asarray(LENGTHS, jnp.int32))
+    np.testing.assert_array_equal(h[0], h0[0])
+    assert not np.asarray(y[0]).any()
+    assert float(jnp.abs(h[1] - h0[1]).max()) > 1e-3      # one position does
+
+
+def test_told_the_lengths_the_two_impls_agree_at_every_position():
+    """The padding inside the chunk that holds a row's end reads the
+    standing state, the chunks past it are zeros, and the state is the one
+    after the row's last position: the recurrence's ``y`` and state under
+    the same lengths."""
+    rows, _, (a, d, h0) = padded_rows()
+    x, dt, b, c = rows
+    lengths = jnp.asarray(LENGTHS, jnp.int32)
+    want = ssm.ssd_scan(x, dt, a, b, c, d, chunk=128, h0=h0,
+                        impl="reference", lengths=lengths)
+    got = ssm.ssd_scan(x, dt, a, b, c, d, chunk=128, h0=h0, impl="flash",
+                       lengths=lengths)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, **TIGHT)
+
+
+def test_whole_rows_told_are_the_rows_not_told():
+    """To the bit: a batch with no padding pays the test a grid step and
+    nothing else."""
+    x, dt, a, b, c, d, h0 = inputs(S=384)
+    not_told = kernel.ssd_scan_chunked(x, dt, a, b, c, d, h0, 128)
+    told = kernel.ssd_scan_chunked(x, dt, a, b, c, d, h0, 128,
+                                   jnp.full((2,), 384, jnp.int32))
+    for t, n in zip(told, not_told):
+        np.testing.assert_array_equal(t, n)
+
+
+def test_the_kernel_takes_eight_positional_arguments_and_the_lengths():
+    """The benchmark's scan check calls it with eight; the lengths trail
+    them and default to none, which is the call that knew of none: one
+    operand fewer, no scalar prefetched."""
+    import inspect
+
+    params = list(inspect.signature(kernel.ssd_scan_chunked).parameters
+                  .values())
+    assert [p.name for p in params] == ["x", "dt", "a", "b", "c", "d", "h0",
+                                        "chunk", "lengths"]
+    assert params[8].default is None
+    x, dt, a, b, c, d, h0 = inputs(S=128, B=1)
+
+    def call(*more):
+        jaxpr = jax.make_jaxpr(lambda *v: kernel.ssd_scan_chunked(
+            *v, 128, *more))(x, dt, a, b, c, d, h0)
+        (eqn,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+        return eqn
+
+    plain, told = call(), call(jnp.ones((1,), jnp.int32))
+    assert len(plain.invars) == 8 and len(told.invars) == 9
+    assert plain.params["grid_mapping"].num_index_operands == 0
+    assert told.params["grid_mapping"].num_index_operands == 1
+    with pytest.raises(ValueError, match=r"lengths\(2,\)"):
+        kernel.ssd_scan_chunked(x, dt, a, b, c, d, h0, 128,
+                                jnp.ones((2,), jnp.int32))
 
 
 def test_bf16_operands_keep_a_float32_state():
@@ -130,6 +227,12 @@ def test_the_kernels_backward_raises_by_name_and_the_reference_has_one():
         jax.grad(total)(x, "flash")
     g = jax.grad(total)(x, "reference")
     assert g.shape == x.shape and float(jnp.abs(g).max()) > 0
+    # told the rows' lengths the kernel is called bare (a serving step's
+    # call: `ops/ssm.py`), and jax raises for it
+    with pytest.raises(NotImplementedError):
+        jax.grad(lambda x: ssm.ssd_scan(
+            x, dt, a, b, c, d, chunk=128, impl="flash",
+            lengths=jnp.asarray([100], jnp.int32))[0].sum())(x)
 
 
 def test_the_dispatcher():
