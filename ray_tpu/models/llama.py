@@ -79,7 +79,14 @@ Design notes (why this is not a torch translation):
   hands ``ops.attention`` the window (the equal-width flash forward walks
   the window's key blocks alone), and ``llama_decode`` keeps a sliding
   layer's last ``sliding_window`` keys and values where an ``attention``
-  layer keeps ``max_len``.
+  layer keeps ``max_len``. The two kinds may differ by more than the window
+  (Laguna-XS.2 has all of it): the sliding layers their own count of query
+  heads on the same key/value heads (``swa_num_heads``: ``wq``, ``wo`` and
+  the gate's leaf have one shape a kind, the stacks being a kind's own)
+  and their own ``swa_rope_theta``, a full layer turning the first
+  ``partial_rotary_factor`` of its head alone (``_yarn_rope``'s
+  ``rotary_dim``), and both gating each head's output (``head_gate``) as
+  the latent operators do.
 - Attention dispatches to ``ray_tpu.ops`` (Pallas flash attention on TPU,
   reference einsum path elsewhere; ring attention when the seq axis > 1).
 - bfloat16 activations / fp32 params+optimizer by default: MXU-native.
@@ -282,7 +289,14 @@ class LlamaConfig:
     # sliding_window is also the window of layer_types' "sliding_attention"
     # (Mellum2-12B-A2.5B): grouped-query attention at the attention layers'
     # own widths, under plain rope, while the "full_attention" layers
-    # beside it take rope_scaling.
+    # beside it take rope_scaling. Where the two kinds differ by more than
+    # the window (Laguna-XS.2), the swa_ fields say the sliding layers'
+    # side here too: swa_num_heads query heads (0: num_heads; the key/value
+    # heads and head_dim are one for both) and swa_rope_theta (0:
+    # rope_theta), over the whole head, while a full layer turns the first
+    # partial_rotary_factor of its head, under rope_scaling reckoned over
+    # those dims, and passes the rest through; head_gate is honoured by
+    # both (leaf w_head_gate [hidden, the kind's heads]).
     sliding_window: int = 0
     swa_num_heads: int = 0
     swa_q_lora_rank: int = 0
@@ -290,7 +304,8 @@ class LlamaConfig:
     swa_qk_nope_head_dim: int = 0
     swa_qk_rope_head_dim: int = 0
     swa_v_head_dim: int = 0
-    swa_rope_theta: float = 10000.0
+    swa_rope_theta: float = 0.0
+    partial_rotary_factor: float = 1.0
     index_heads: int = 0
     index_head_dim: int = 0
     index_topk: int = 0
@@ -414,12 +429,23 @@ class LlamaConfig:
                 self.swa_num_heads, self.swa_q_lora_rank,
                 self.swa_kv_lora_rank, self.swa_qk_nope_head_dim,
                 self.swa_qk_rope_head_dim, self.swa_v_head_dim,
-                self.swa_rope_theta, self.sliding_window, 0)
+                self.swa_rope_theta or self.rope_theta, self.sliding_window,
+                0)
         return LatentWidths(
             self.num_heads, self.q_lora_rank, self.kv_lora_rank,
             self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim,
             self.rope_theta, 0,
             self.index_topk if operator == "indexed" else 0)
+
+    def attention_heads(self, operator: str = "attention") -> int:
+        """The query heads of a grouped-query operator (``attention`` or
+        ``sliding``): the sliding layers' own count where one is given."""
+        return (self.swa_num_heads or self.num_heads
+                if operator == "sliding" else self.num_heads)
+
+    def rotary_dim(self) -> int:
+        """The leading dims of a head that a full layer's rope turns."""
+        return int(self.head_dim * self.partial_rotary_factor)
 
     def mamba_widths(self) -> Tuple[int, int, int]:
         """The state-space operator's ``(inner, conv, proj)`` widths: its
@@ -444,9 +470,7 @@ class LlamaConfig:
 
     def num_params(self) -> int:
         h, v, E = self.hidden, self.vocab_size, self.num_experts
-        q, kv = self.num_heads * self.head_dim, self.num_kv_heads * self.head_dim
-        norms = ((q + kv) if self.qk_norm
-                 else 2 * self.head_dim if self.qk_head_norm else 0)
+        kv = self.num_kv_heads * self.head_dim
         held = self.experts_held[1] if self.experts_held else E
 
         def latent(operator):
@@ -463,8 +487,15 @@ class LlamaConfig:
         ih, ihd = self.index_heads, self.index_head_dim
         inner, conv, proj = self.mamba_widths()
         kda_inner, kda_proj = self.kda_widths()
-        grouped_query = h * (q + 2 * kv) + q * h + norms
-        half = {"attention": grouped_query, "sliding": grouped_query,
+        def grouped_query(operator):
+            q = self.attention_heads(operator) * self.head_dim
+            norms = ((q + kv) if self.qk_norm
+                     else 2 * self.head_dim if self.qk_head_norm else 0)
+            gate = h * self.attention_heads(operator) if self.head_gate else 0
+            return h * (q + 2 * kv) + q * h + norms + gate
+
+        half = {"attention": grouped_query("attention"),
+                "sliding": grouped_query("sliding"),
                 # in-projection, beta's, the taps, A_log a head, dt_bias a
                 # channel, the norm's one weight a head's channel, out
                 "kda": (h * kda_proj + h * self.kda_heads
@@ -514,13 +545,17 @@ class LoraConfig:
 
     def num_params(self, cfg: LlamaConfig) -> int:
         h, m, r = cfg.hidden, cfg.dense_width(), self.rank
-        nh, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-        per = {"wq": h * r + r * nh * hd, "wk": h * r + r * nkv * hd,
-               "wv": h * r + r * nkv * hd, "wo": nh * hd * r + r * h,
-               "w_gate": h * r + r * m, "w_up": h * r + r * m,
-               "w_down": m * r + r * h}
+        nkv, hd = cfg.num_kv_heads, cfg.head_dim
+
+        def per(kind):
+            nh = cfg.attention_heads(kind.split("_")[0])
+            return {"wq": h * r + r * nh * hd, "wk": h * r + r * nkv * hd,
+                    "wv": h * r + r * nkv * hd, "wo": nh * hd * r + r * h,
+                    "w_gate": h * r + r * m, "w_up": h * r + r * m,
+                    "w_down": m * r + r * h}
+
         counts = cfg.kind_counts()
-        return sum(counts[kind] * per[t]
+        return sum(counts[kind] * per(kind)[t]
                    for kind, ts in _lora_targets(cfg, self).items()
                    for t in ts)
 
@@ -581,10 +616,10 @@ def _as_layers(by_kind: Dict[str, Dict], cfg: LlamaConfig) -> Dict:
     return by_kind.get(kinds[0], {}) if len(kinds) == 1 else by_kind
 
 
-def _lora_dims(cfg: LlamaConfig):
+def _lora_dims(cfg: LlamaConfig, kind: str):
     return {"embed": (cfg.hidden,), "mlp": (cfg.dense_width(),),
-            "heads": (cfg.num_heads,), "kv_heads": (cfg.num_kv_heads,),
-            "head_dim": (cfg.head_dim,)}
+            "heads": (cfg.attention_heads(kind.split("_")[0]),),
+            "kv_heads": (cfg.num_kv_heads,), "head_dim": (cfg.head_dim,)}
 
 
 def init_lora(cfg: LlamaConfig, lcfg: LoraConfig, key: jax.Array) -> Dict:
@@ -592,12 +627,11 @@ def init_lora(cfg: LlamaConfig, lcfg: LoraConfig, key: jax.Array) -> Dict:
     at the base), stacked over the layers of each kind that have the
     projection, for the scanned body; the tree has ``params["layers"]``'s
     shape (``_by_kind``)."""
-    dims = _lora_dims(cfg)
     r, counts = lcfg.rank, cfg.kind_counts()
     keys = dict(zip(lcfg.targets, jax.random.split(key, len(lcfg.targets))))
     by_kind = {}
     for j, (kind, targets) in enumerate(_lora_targets(cfg, lcfg).items()):
-        L, out = counts[kind], {}
+        L, out, dims = counts[kind], {}, _lora_dims(cfg, kind)
         for name in targets:
             k = keys[name] if j == 0 else jax.random.fold_in(keys[name], j)
             in_ax, out_ax = _LORA_SHAPES[name]
@@ -658,6 +692,8 @@ def _kind_logical_axes(cfg: LlamaConfig, kind: str) -> Dict[str, Any]:
                      wo=("heads", "head_dim", "embed"))
         if cfg.qk_norm or cfg.qk_head_norm:
             layer.update(q_norm=("norm",), k_norm=("norm",))
+        if cfg.head_gate:
+            layer.update(w_head_gate=("embed", "heads"))
     elif operator in LATENT_OPERATORS:  # the ranks stay whole everywhere
         layer.update(wkv_a=("embed", None), kv_a_norm=("norm",),
                      wkv_b=(None, "heads", "head_dim"),
@@ -716,7 +752,7 @@ def llama_logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
 
 def init_llama(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
     """Initialize params (truncated-normal fan-in scaling, fp32)."""
-    h, nh, nkv, hd = cfg.hidden, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    h, nkv, hd = cfg.hidden, cfg.num_kv_heads, cfg.head_dim
     ks = jax.random.split(key, 10)
     pd = cfg.param_dtype
 
@@ -730,12 +766,16 @@ def init_llama(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
         eighth and ninth are the embedding's and the head's."""
         operator, ffn = kind.split("_")
         if operator in ATTENTION_OPERATORS:
+            nh = cfg.attention_heads(operator)
             layers = {
                 "wq": norm_init((L, h, nh, hd), ks[0], h),
                 "wk": norm_init((L, h, nkv, hd), ks[1], h),
                 "wv": norm_init((L, h, nkv, hd), ks[2], h),
                 "wo": norm_init((L, nh, hd, h), ks[3], nh * hd),
             }
+            if cfg.head_gate:
+                layers["w_head_gate"] = norm_init(
+                    (L, h, nh), jax.random.fold_in(ks[3], 1), h)
         elif operator in LATENT_OPERATORS:
             nl, qr, kvr, nope, rope, vd = cfg.latent_widths(operator)[:6]
             kq, kk = jax.random.split(ks[1])
@@ -859,7 +899,8 @@ def init_llama(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
         if operator in ATTENTION_OPERATORS and (cfg.qk_norm
                                                 or cfg.qk_head_norm):
             # over the whole projection, or one weight shared by the heads
-            q, k = (nh * hd, nkv * hd) if cfg.qk_norm else (hd, hd)
+            q, k = ((cfg.attention_heads(operator) * hd, nkv * hd)
+                    if cfg.qk_norm else (hd, hd))
             layers.update(q_norm=jnp.ones((L, q), pd),
                           k_norm=jnp.ones((L, k), pd))
         return layers
@@ -934,9 +975,18 @@ def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 
 
 def _yarn_rope(x: jax.Array, positions: jax.Array, theta: float,
-               scaling: Optional[RopeScaling]) -> jax.Array:
+               scaling: Optional[RopeScaling],
+               rotary_dim: Optional[int] = None) -> jax.Array:
     """``_rope`` at ``scaling``'s frequencies and amplitude, of ``x [B, S,
-    H, D]`` or, one row a position, ``[B, S, D]``; None is theta's own."""
+    H, D]`` or, one row a position, ``[B, S, D]``; None is theta's own.
+    ``rotary_dim`` under ``D``: the first ``rotary_dim`` dims turn, pairs
+    ``(d, d + rotary_dim / 2)`` at the frequencies (and YaRN's correction
+    dims) of a head that wide, and the rest pass through as they are, the
+    amplitude not on them (HuggingFace's ``apply_rotary_pos_emb`` under a
+    ``partial_rotary_factor``)."""
+    if rotary_dim is not None and rotary_dim != x.shape[-1]:
+        turned = _yarn_rope(x[..., :rotary_dim], positions, theta, scaling)
+        return jnp.concatenate([turned, x[..., rotary_dim:]], axis=-1)
     heads = x if x.ndim == 4 else x[:, :, None, :]
     if scaling is None:
         return _rope(heads, positions, theta).reshape(x.shape)
@@ -978,6 +1028,16 @@ def _layer_norm(x: jax.Array, w: jax.Array, b: jax.Array,
     x = x - jnp.mean(x, axis=-1, keepdims=True)
     x = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
     return (x * w.astype(jnp.float32) + b.astype(jnp.float32)).astype(dt)
+
+
+def _gate_heads(out: jax.Array, u: jax.Array, w: jax.Array) -> jax.Array:
+    """``head_gate``: each head of ``out [B, S, heads, D]`` times the
+    sigmoid (float32) of the block's normed input ``u`` through ``w
+    [hidden, heads]``, in ``out``'s type."""
+    gate = jax.nn.sigmoid(jnp.einsum(
+        "bsh,hn->bsn", u, w.astype(out.dtype),
+        preferred_element_type=jnp.float32))
+    return out * gate[..., None].astype(out.dtype)
 
 
 def _index_queries_and_key(cfg: LlamaConfig, w: LatentWidths, u: jax.Array,
@@ -1172,10 +1232,7 @@ def _latent_attention(cfg: LlamaConfig, u: jax.Array,
             mine = keep if live is None else keep & live[:, :, None]
             counts["index_kept"] = jnp.sum(mine, dtype=jnp.int32)
         if "w_head_gate" in lp:
-            gate = jax.nn.sigmoid(jnp.einsum(
-                "bsh,hn->bsn", u, lp["w_head_gate"].astype(dt),
-                preferred_element_type=jnp.float32))
-            out = out * gate[..., None].astype(dt)
+            out = _gate_heads(out, u, lp["w_head_gate"])
         out = constrain(out, ("batch", "seq", "heads", None))
         y = jnp.einsum("bsnd,ndh->bsh", out, lp["wo"].astype(dt))
     return y, state
@@ -1432,14 +1489,18 @@ def _layer(cfg: LlamaConfig, x: jax.Array, lp: Dict[str, jax.Array],
             raise ValueError("a sliding_attention layer needs "
                              "sliding_window > 0")
         if cfg.use_rope:
-            # YaRN is the full layers': a window's keys lie near the query
+            # YaRN and the partial turn are the full layers': a window's
+            # keys lie near the query, and its theta may be its own
             scaling = None if window else cfg.rope_scaling
             if scaling is not None and scaling.softmax_amplitude() != 1.0:
                 raise ValueError(
                     "attention scales its scores by head_dim ** -0.5: a "
                     "rope_scaling with mscale_all_dim is latent attention's")
-            q = _yarn_rope(q, positions, cfg.rope_theta, scaling)
-            k = _yarn_rope(k, positions, cfg.rope_theta, scaling)
+            theta = (cfg.swa_rope_theta or cfg.rope_theta if window
+                     else cfg.rope_theta)
+            turned = None if window else cfg.rotary_dim()
+            q = _yarn_rope(q, positions, theta, scaling, turned)
+            k = _yarn_rope(k, positions, theta, scaling, turned)
         if cfg.attention_multiplier:
             # the softmax scale's ratio to the kernels' head_dim ** -0.5,
             # taken by the queries: where it is a power of two (granite's
@@ -1481,6 +1542,8 @@ def _layer(cfg: LlamaConfig, x: jax.Array, lp: Dict[str, jax.Array],
                 attn_out = attention(q, k, v, impl=cfg.attn_impl,
                                      causal=True, window=window,
                                      lengths=lengths)
+        if "w_head_gate" in lp:
+            attn_out = _gate_heads(attn_out, h, lp["w_head_gate"])
         attn_out = constrain(attn_out, ("batch", "seq", "heads", None))
         x = (x + _res(jnp.einsum("bsnd,ndh->bsh", attn_out,
                                  lp["wo"].astype(dt)))

@@ -186,9 +186,11 @@ class LlamaGenerator:
             for operator in LATENT_OPERATORS
             if (layers := layers_of(operator))]
         # grouped-query attention, whose prefill is the equal-width flash
-        # forward's: (layers, the window or None) each
+        # forward's: (layers, the kind's query heads, the window or None)
+        # each
         self._attn_layers = [
-            (layers, window) for operator, window in (
+            (layers, self._cfg.attention_heads(operator), window)
+            for operator, window in (
                 ("attention", None), ("sliding", self._cfg.sliding_window))
             if (layers := layers_of(operator))]
         # adapt only the attention q/v projections: the cheap standard
@@ -417,11 +419,11 @@ class LlamaGenerator:
                     pad_len, lengths, head_dim=w.nope, rope_dim=w.rope,
                     value_dim=w.v, window=w.window or None))
                     for layers, w in self._flash_layers]
-                blocks += [("attn", layers * self._cfg.num_heads,
+                blocks += [("attn", layers * heads,
                             fa.causal_blocks(pad_len, lengths, fa.flash_tiles(
                                 pad_len, pad_len,
                                 head_dim=self._cfg.head_dim), window))
-                           for layers, window in self._attn_layers]
+                           for layers, heads, window in self._attn_layers]
                 for name, heads, (run, own) in blocks:
                     counts[name + "_blocks_run"] += heads * run
                     counts[name + "_blocks_live"] += heads * own
@@ -536,7 +538,8 @@ class LlamaGenerator:
         ``attn_blocks_run``, ``attn_blocks_live`` and
         ``attn_blocks_skipped`` (the same three over the layers whose
         operator is grouped-query attention, ``attention`` or ``sliding``,
-        and their equal-width flash forward's grid, rows x query heads x
+        and their equal-width flash forward's grid, rows x the kind's own
+        query heads (``LlamaConfig.attention_heads``) x
         the padded length's steps at or under the diagonal, under
         ``sliding_window`` the window's walk: ``causal_blocks`` of the same
         module at ``flash_tiles``' tiles for ``head_dim``); and

@@ -129,7 +129,15 @@ warm-up call was 1.8 s longer with it, PERF.md section 6). The
 twenty-eight others are what its parent ``7c64761`` gives to the character:
 that cell's ``init``, ``step256`` and ``step1024`` among them (no mask, so
 no lengths: ``ssd_scan_chunked`` with ``lengths=None`` is the call it was),
-and every other cell's lines, no other model having the operator.
+and every other cell's lines, no other model having the operator. PR 60
+(Laguna-XS.2: the ``attention`` and ``sliding`` operators take their query
+heads by kind behind ``swa_num_heads`` 0, the sliding layers their own
+``swa_rope_theta`` behind 0, a full layer's rope the first
+``partial_rotary_factor`` of its head behind 1.0, and ``head_gate`` is
+honoured by the grouped-query branch, which no earlier model with that
+branch sets) moved none of the thirty: they are what its parent ``0df960f``
+gives to the character; ``serve_laguna_agentturns``'s three are new (traced
+at 8 rows like the others' steps; the cell serves 4).
 """
 
 import hashlib
@@ -163,6 +171,9 @@ PROGRAMS = {
     "serve_mellum2_projctx.init": "26e74b5260e71edd",
     "serve_mellum2_projctx.step4096": "636a25abce39cf8c",
     "serve_mellum2_projctx.step8192": "2d3f004b61858767",
+    "serve_laguna_agentturns.init": "e4025d438f2ceb1d",
+    "serve_laguna_agentturns.step1024": "049e8daa592d60d0",
+    "serve_laguna_agentturns.step6144": "e6e92836d5c93cd3",
     "serve_chat_steady.told128": "db30cd54d721b7ac",
     "serve_chat_steady.told384": "e905645daec74f90",
     "serve_granite_toolcalls.told256": "5146abf0b9b112f0",

@@ -86,7 +86,12 @@ def test_neither_is_the_forward_it_always_was():
     (5120, 640, 640, 513, 2), (3584, 512, 512, 513, 2),
     (4608, 768, 768, 513, 2), (1024, 128, 128, 513, 5),
     (1024, 512, 128, 130, 6), (1024, 1024, 1024, 513, 1),
-    (2048, 512, 512, 4000, 4)])
+    (2048, 512, 512, 4000, 4),
+    # a window narrower than the tile: two key blocks, never three
+    (6144, 1024, 1024, 512, 2), (6144, 1024, 1024, 511, 2),
+    (6144, 1024, 1024, 513, 2), (1024, 1024, 1024, 512, 1),
+    (6144, 1024, 1024, 1, 1), (6144, 1024, 1024, 1025, 2),
+    (6144, 1024, 1024, 1026, 3)])
 def test_the_windows_walk_is_as_long_as_its_widest_reach(
         seq, block_q, block_k, window, blocks):
     assert fa._window_key_blocks(seq, block_q, block_k, window) == blocks
@@ -129,6 +134,47 @@ def test_the_equal_width_window_against_the_reference(seq, tile, window,
     if tile:  # the walk is the window's blocks and no other
         assert fa._window_key_blocks(seq, tile, tile, window) == min(
             seq // tile, -(-(window - 1) // tile) + 1)
+
+
+# Laguna-XS.2's shapes of the same forward: groups of 6 (48 query heads on
+# 8) and of 8 at 64 heads in one model, and a window NARROWER than the tile
+# (512 inside blocks of 1024: the walk visits two key blocks a query block
+# of whose 2048 keys at most 512 are inside), one key either side of it, and
+# no window; told the rows' lengths (a whole row and one that ends inside a
+# block) and not
+# (interpreted, a head of a row costs a second: the model's 64 and 48 heads
+# once each, told; the windows' edges and the untold call at 16 and 12 heads
+# on 2, the same groups)
+@pytest.mark.parametrize("heads, kv_heads, window, told", [
+    (64, 8, 512, True), (48, 8, None, True),
+    (16, 2, 511, False), (16, 2, 511, True), (16, 2, 512, False),
+    (16, 2, 513, False), (16, 2, 513, True), (12, 2, None, False),
+    (12, 2, 512, True)])
+def test_a_window_narrower_than_the_tile_and_groups_of_six(
+        heads, kv_heads, window, told):
+    seq = 2048
+    assert fa.flash_tiles(seq, seq, head_dim=128) == (1024, 1024)
+    if window:
+        assert fa._window_key_blocks(seq, 1024, 1024, window) == 2
+    q, k, v = grouped(seq, heads=heads, kv_heads=kv_heads, d=128, batch=2)
+    lengths = jnp.asarray([seq, 1300], jnp.int32) if told else None
+    got = attention(q, k, v, impl="flash", window=window, lengths=lengths)
+    want = reference_attention(q, k, v, window=window)
+    own = (jnp.arange(seq)[None, :] < (
+        lengths if told else jnp.full((2,), seq))[:, None])
+    np.testing.assert_allclose(jnp.where(own[:, :, None, None], got, 0.0),
+                               jnp.where(own[:, :, None, None], want, 0.0),
+                               rtol=2e-5, atol=2e-5)
+    if window:  # one key more or fewer is another result
+        other = reference_attention(q, k, v, window=window + 1)
+        assert float(jnp.abs(jnp.where(own[:, :, None, None],
+                                       got - other, 0.0)).max()) > 1e-3
+    if heads // kv_heads == 6:  # query head n reads key head n // 6
+        wrong = reference_attention(
+            q, jnp.repeat(k, 8, axis=2)[:, :, :heads],
+            jnp.repeat(v, 8, axis=2)[:, :, :heads], window=window)
+        if kv_heads > 1:
+            assert float(jnp.abs(got - wrong).max()) > 1e-2
 
 
 def test_the_equal_width_window_is_a_forward_alone_and_none_is_the_old_call():

@@ -336,6 +336,37 @@ def test_the_equal_width_window_compiles_and_walks_two_key_blocks(
     assert fa.EQUAL_WINDOW_TRACE_NAME not in plain.as_text()
 
 
+# serve_laguna_agentturns's six buckets at its 4 rows: the sliding layers'
+# forward at 64 query heads on 8 (groups of 8) told a window of 512, NARROWER
+# than the tile, and the full layers' at 48 on 8 (groups of 6, the first
+# group that is no power of two), each told the rows' lengths
+LAGUNA_BUCKETS = [1024, 2048, 3072, 4096, 5120, 6144]
+
+
+@pytest.mark.parametrize("heads, window", [(64, 512), (48, None)])
+@pytest.mark.parametrize("seq", LAGUNA_BUCKETS)
+def test_lagunas_two_forwards_compile_told_their_rows_lengths(
+        seq, heads, window, one_chip, compiled_for_tpu):
+    def of(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q, k = of(4, heads, seq, 128), of(4, 8, seq, 128)
+    compiled = jax.jit(
+        lambda q, k, v, n: fa._flash_fwd(q, k, v, causal=True,
+                                         window=window, lengths=n)
+    ).lower(q, k, k, of(4, dtype=jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert (fa.EQUAL_WINDOW_TRACE_NAME in compiled.as_text()) == bool(window)
+    tiles = fa.flash_tiles(seq, seq, head_dim=128)
+    assert tiles == (1024, 1024)
+    if window:  # two key blocks a query block, one where there is one
+        assert fa._window_key_blocks(seq, *tiles, window) == min(
+            2, seq // 1024)
+    reckoned = fa.tile_vmem_bytes(*tiles, head_dim=128)
+    used = [n for n in _scoped_vmem(compiled) if n]
+    assert used and max(used) <= reckoned <= fa.VMEM_LIMIT_BYTES
+
+
 @pytest.mark.parametrize("seq", DOTS3_BUCKETS)
 def test_the_selected_forward_compiles_with_a_byte_a_pair(
         seq, one_chip, compiled_for_tpu):
