@@ -96,6 +96,38 @@ raises and says so, as the two-width forward does; ``_dq_kernel`` and
 ``_dkv_kernel`` see every causal key (ROADMAP M5). ``flash_attention``
 itself is the call it was.
 
+The window's one step (Laguna-XS.2's sliding layers: 64 query heads on 8
+of 128 under a window of 512, HALF the plain tile; PR 61). What a grid step
+of ``_fwd_kernel`` costs on a v5e goes by its QUERY rows far more than by
+its scores: some 3.2 ns a query row a step whatever the key block's length
+(the running max and sum are reduced across lanes and broadcast back, and
+the accumulator rescaled, a row at a time) beside 0.5 us a step and 0.6 us
+for the scores of 1024 x 768 more keys; so the walk costs its query rows
+times the key blocks each visits, and under a window narrower than the
+tile a block of 1024 queries visits two blocks of 1024 keys whatever the
+window: blocks of 512 x 512, a quarter of the scores a step, are 12 %
+SLOWER than 1024 x 1024, and the best rectangle, 512 x 1024, 6 % faster
+(PERF.md section 5, PR 61, has the sweep). What a narrow window allows is
+to visit each query row ONCE: a query block's keys are its own and the
+``tail`` before them that its first query sees, ``window - 1`` rounded up
+to a divisor of the block. ``window_step`` says where that holds, by the
+length, the window and the head's width alone: the query block is the
+shortest divisor of the length from 512 up that has such a divisor and
+fits VMEM beside its keys (``tile_vmem_bytes`` at ``block_q`` x ``block_q
++ tail``); not where the plain rule's keys are one block (the walk is one
+step a block there), and not where no block holds the tail beside it
+(Mellum2's window of 1024: the walk above, its programs the parent's).
+There ``_flash_fwd`` runs ``_window_step_kernel`` on a grid of (row, head,
+query block) with NO key dim: the keys and values ride in twice, cut into
+blocks of ``tail`` and into the query blocks' own, the step joins the two
+in VMEM, and its softmax is the plain one over one float32 tile, with
+nothing carried and nothing rescaled: the same scores in float32, the same
+mask (``q - k < window``), the same keys for every query, under the same
+scope. Laguna's window runs at 512 queries over 512 + 512 keys from 2048 to
+6144: 4 x 6144 x 64 heads in 7.3 ms where the walk took 14.0. Told the
+rows' lengths, a query block past its row's live ones is written as zeros
+and fetches nothing (below).
+
 The rows' lengths, in every forward (``lengths [B]`` int32: the two-width
 forward since PR 54, the equal-width one, full or under its window, since
 PR 56). A batch's rows are padded on the right to one length, and a block
@@ -254,6 +286,35 @@ def flash_tiles(sq: int, skv: int, *, head_dim: int = 128,
     return block_q, block_k
 
 
+# The shortest query block the window's step takes: under it a step's own
+# cost outweighs the scores a shorter block saves (PERF.md section 5, PR 61).
+_WINDOW_QUERY_FLOOR = 512
+
+
+def window_step(seq: int, window: Optional[int], *, head_dim: int = 128
+                ) -> Optional[Tuple[int, int]]:
+    """``(block_q, tail)`` where the equal-width forward under ``window``
+    at a prefill of ``seq`` runs ONE grid step a query block, over the
+    block's own keys and the ``tail`` keys before them (module docstring:
+    the window's one step): the shortest divisor of ``seq`` from 512 up that
+    has a divisor of its own no shorter than ``window - 1``, the tail, and
+    fits VMEM with it. None where it walks key blocks as the causal forward
+    does: with no window, where the plain rule's keys are one block (the
+    walk is one step a block already), or where no query block holds the
+    window's tail beside it. Pure: the length, the window and the head's
+    width."""
+    if window is None or flash_tiles(seq, seq, head_dim=head_dim)[1] == seq:
+        return None
+    for block_q in _divisors(seq):
+        if block_q < _WINDOW_QUERY_FLOOR:
+            continue
+        tail = next((t for t in _divisors(block_q) if t >= window - 1), None)
+        if tail and tile_vmem_bytes(block_q, block_q + tail,
+                                    head_dim=head_dim) <= VMEM_LIMIT_BYTES:
+            return block_q, tail
+    return None
+
+
 def _fwd_kernel(*refs, scale: float, causal: bool, block_q: int,
                 block_k: int, window: Optional[int] = None,
                 told: bool = False):
@@ -367,6 +428,9 @@ def _flash_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
                          "causal, the queries' positions the keys'")
     if lengths is not None and lengths.shape != (B,):
         raise ValueError(f"lengths{lengths.shape} for {B} rows")
+    step = window_step(Sq, window, head_dim=D)
+    if step is not None:
+        return _flash_fwd_window_step(q, k, v, window, lengths, *step), None
     block_q, block_k = flash_tiles(Sq, Skv, head_dim=D)
     key_blocks, told = Skv // block_k, {}
     if window is not None:
@@ -384,17 +448,12 @@ def _flash_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
         # told keeps the text it has, whose `//` is a `jit` in the map
         return h // n_rep if lengths is None else jax.lax.div(h, n_rep)
 
-    def head(b, h, *blocks_ref):
-        # an empty row's steps all name its first head's first blocks: one
-        # fetch a row where each head's would be one a head
-        return (jax.lax.mul(h, jax.lax.min(blocks_ref[0][0, b], 1))
-                if blocks_ref else h)
-
     def rows(b, h, iq, ik, *n):
-        return (b, head(b, h, *n), query_block(b, iq, *n), 0)
+        return (b, _row_head(b, h, *n), query_block(b, iq, *n), 0)
 
     def keys(b, h, iq, ik, *n):
-        return (b, kv_head(head(b, h, *n)), key_block(b, iq, ik, *n), 0)
+        return (b, kv_head(_row_head(b, h, *n)),
+                key_block(b, iq, ik, *n), 0)
 
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal,
@@ -435,6 +494,112 @@ def _flash_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
             interpret=_interpret(),
         )(*prefetched, q, k, v)
     return o, (lse[0] if lse else None)
+
+
+def _window_step_kernel(*refs, scale: float, window: int, block_q: int,
+                        tail: int, told: bool = False):
+    """One grid step a query block under a window no longer than ``tail +
+    1``: the scores of the block's ``block_q`` queries over the ``tail``
+    keys before the block (``kt_ref``, ``vt_ref``) and the block's own
+    (``k_ref``, ``v_ref``), side by side in one float32 tile, a plain
+    softmax over it (every key a query sees is in the tile, so there is no
+    running max, sum or accumulator to carry and none to rescale), and its
+    dot with the values. The row's first block has no keys before it: its
+    tail is the block's own first keys again (the index map's clamp),
+    masked as positions below 0. With ``told`` a first operand rides in
+    front, ``blocks_ref [4, B]`` (``_live_blocks``): a query block past its
+    row's live ones is written as zeros and computes nothing."""
+    blocks_ref = None
+    if told:
+        blocks_ref, *refs = refs
+    q_ref, kt_ref, vt_ref, k_ref, v_ref, o_ref = refs
+    iq = pl.program_id(2)
+
+    def _compute():
+        q = q_ref[0, 0].astype(jnp.float32)              # [bq, d]
+        k = jnp.concatenate([kt_ref[0, 0], k_ref[0, 0]],
+                            axis=0).astype(jnp.float32)  # [tail + bq, d]
+        v = jnp.concatenate([vt_ref[0, 0], v_ref[0, 0]],
+                            axis=0).astype(jnp.float32)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [bq, tail + bq]
+        q_pos = iq * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 0)
+        k_pos = iq * block_q - tail + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 1)
+        seen = (q_pos >= k_pos) & (q_pos - k_pos < window) & (k_pos >= 0)
+        s = jnp.where(seen, s, _NEG_INF)
+        p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+        # a query sees its own key: the sum is at least 1
+        o_ref[0, 0] = (jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+            / jnp.sum(p, axis=-1, keepdims=True)).astype(o_ref.dtype)
+
+    if not told:
+        _compute()
+        return
+    live = jax.lax.lt(iq, blocks_ref[0, pl.program_id(0)])
+    pl.when(live)(_compute)
+
+    @pl.when(jax.lax.bitwise_not(live))
+    def _dead():
+        o_ref[0, 0] = jnp.zeros_like(o_ref[0, 0])
+
+
+def _flash_fwd_window_step(q: jax.Array, k: jax.Array, v: jax.Array,
+                           window: int, lengths: Optional[jax.Array],
+                           block_q: int, tail: int) -> jax.Array:
+    """``_flash_fwd`` under a window at ``window_step``'s ``(block_q,
+    tail)``: q [B,H,S,D], k/v [B,KVH,S,D] → o [B,H,S,D], a grid of (row,
+    head, query block) and no key dim. The keys and the values are each
+    passed twice, once cut into blocks of ``tail`` (a query block's tail is
+    the block of them that ends where it begins) and once into the query
+    blocks' own. Told the rows' lengths, a step past a row's last live
+    query block names that block's operands again, an empty row's its
+    first head's, and fetches nothing (``_block_maps``' way)."""
+    B, H, S, D = q.shape
+    n_rep = H // k.shape[1]
+    prefetched = [] if lengths is None else [
+        _live_blocks(lengths, block_q, block_q)]
+    block, _ = _block_maps(block_q, block_q, None)
+
+    def rows(b, h, iq, *n):
+        return (b, _row_head(b, h, *n), block(b, iq, *n), 0)
+
+    def own(b, h, iq, *n):
+        return (b, jax.lax.div(_row_head(b, h, *n), n_rep),
+                block(b, iq, *n), 0)
+
+    def before(b, h, iq, *n):
+        return (b, jax.lax.div(_row_head(b, h, *n), n_rep), jax.lax.max(
+            block(b, iq, *n) * (block_q // tail) - 1, 0), 0)
+
+    kernel = functools.partial(
+        _window_step_kernel, scale=D ** -0.5, window=window,
+        block_q=block_q, tail=tail, told=lengths is not None)
+    with jax.named_scope(EQUAL_WINDOW_TRACE_NAME):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(prefetched),
+                grid=(B, H, S // block_q),
+                in_specs=[
+                    pl.BlockSpec((1, 1, block_q, D), rows),
+                    pl.BlockSpec((1, 1, tail, D), before),
+                    pl.BlockSpec((1, 1, tail, D), before),
+                    pl.BlockSpec((1, 1, block_q, D), own),
+                    pl.BlockSpec((1, 1, block_q, D), own),
+                ],
+                out_specs=pl.BlockSpec(
+                    (1, 1, block_q, D),
+                    lambda b, h, iq, *n: (b, h, iq, 0))),
+            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "parallel")),
+            interpret=_interpret(),
+        )(*prefetched, q, k, v, k, v)
 
 
 # ----------------------------------------------------------------- backward
@@ -774,6 +939,21 @@ def causal_blocks(seq: int, lengths, tiles: Tuple[int, int],
     return len(lengths) * len(iq), int(live.sum())
 
 
+def equal_width_blocks(seq: int, lengths, *, head_dim: int,
+                       window: Optional[int] = None) -> Tuple[int, int]:
+    """``causal_blocks`` by the equal-width forward's own rule: at
+    ``flash_tiles``' tiles, the window's walk among them; and where the
+    window runs one step a query block (``window_step``), the query blocks
+    over every row and those among them that hold a position of their
+    row's own."""
+    step = window_step(seq, window, head_dim=head_dim)
+    if step is None:
+        return causal_blocks(seq, lengths, flash_tiles(
+            seq, seq, head_dim=head_dim), window)
+    lengths = np.asarray(lengths, np.int64)
+    return len(lengths) * (seq // step[0]), int((-(-lengths // step[0])).sum())
+
+
 def shared_rope_blocks(seq: int, lengths, *, head_dim: int, rope_dim: int,
                        value_dim: int, window: Optional[int] = None
                        ) -> Tuple[int, int]:
@@ -808,6 +988,15 @@ def _both_live(run, blocks_ref, iq, at):
     return jax.lax.bitwise_and(run, jax.lax.bitwise_and(
         jax.lax.lt(iq, blocks_ref[0, row]),
         jax.lax.lt(at, blocks_ref[2, row])))
+
+
+def _row_head(b, h, *blocks_ref):
+    """Head ``h`` of row ``b`` in an equal-width forward's index map, or,
+    told the rows' lengths (``blocks_ref``: ``_live_blocks``), an empty
+    row's first head whatever ``h``: its steps all name its first head's
+    first blocks, one fetch a row where each head's would be one a head."""
+    return (jax.lax.mul(h, jax.lax.min(blocks_ref[0][0, b], 1))
+            if blocks_ref else h)
 
 
 def _block_maps(block_q: int, block_k: int, window: Optional[int]):

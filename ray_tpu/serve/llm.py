@@ -419,11 +419,10 @@ class LlamaGenerator:
                     pad_len, lengths, head_dim=w.nope, rope_dim=w.rope,
                     value_dim=w.v, window=w.window or None))
                     for layers, w in self._flash_layers]
-                blocks += [("attn", layers * heads,
-                            fa.causal_blocks(pad_len, lengths, fa.flash_tiles(
-                                pad_len, pad_len,
-                                head_dim=self._cfg.head_dim), window))
-                           for layers, heads, window in self._attn_layers]
+                blocks += [("attn", layers * heads, fa.equal_width_blocks(
+                    pad_len, lengths, head_dim=self._cfg.head_dim,
+                    window=window))
+                    for layers, heads, window in self._attn_layers]
                 for name, heads, (run, own) in blocks:
                     counts[name + "_blocks_run"] += heads * run
                     counts[name + "_blocks_live"] += heads * own
@@ -541,8 +540,10 @@ class LlamaGenerator:
         and their equal-width flash forward's grid, rows x the kind's own
         query heads (``LlamaConfig.attention_heads``) x
         the padded length's steps at or under the diagonal, under
-        ``sliding_window`` the window's walk: ``causal_blocks`` of the same
-        module at ``flash_tiles``' tiles for ``head_dim``); and
+        ``sliding_window`` the window's walk, or its query blocks where the
+        window runs one step a block: ``equal_width_blocks`` of the same
+        module, by the kernel's own rule for ``head_dim`` and the kind's
+        window); and
         ``layer_kinds``, how many layers of each kind this replica serves
         (``LlamaConfig.kind_counts``: ``attention_dense`` alone for a dense
         decoder). Two books of the process ride along, each a reference to

@@ -137,7 +137,26 @@ heads by kind behind ``swa_num_heads`` 0, the sliding layers their own
 honoured by the grouped-query branch, which no earlier model with that
 branch sets) moved none of the thirty: they are what its parent ``0df960f``
 gives to the character; ``serve_laguna_agentturns``'s three are new (traced
-at 8 rows like the others' steps; the cell serves 4).
+at 8 rows like the others' steps; the cell serves 4). PR 61 (the
+window's one step: where ``window_step`` says a query block holds its
+window's tail beside it, ``_flash_fwd`` under a window runs
+``_window_step_kernel``, one grid step a query block over the block's own
+keys and the tail before them, with no key dim and no running softmax)
+moved ``serve_laguna_agentturns.step6144``, as it meant to: the three
+sliding layers' call on a grid of (row, head, 12 query blocks of 512) with
+the keys and the values passed twice (blocks of 512, the tail's and the
+block's own) where it walked two blocks of 1024 keys a block of 1024
+queries, no scratch, and ``_live_blocks``' divisors 512. The thirty-two
+others are what its parent ``5e15c0b`` gives to the character: that cell's
+``init`` and its ``step1024`` (ISSUE 61 expected that line to move too, to
+blocks of 512 x 512 in the walk; the chip's sweep found them 12 % slower
+than the plain tile, section 6 of PERF.md, and where the plain rule's keys
+are ONE block the walk is one step a block already and stays);
+``serve_mellum2_projctx``'s three (a window of 1024 has a tail of 1024,
+which beside a block of 1024 is past VMEM's reckoning: the walk, which is
+the proof that its programs are the parent's); ``serve_dots3_longdoc``'s
+(the two-width forward keeps its walk) and every other line, no other call
+passing a window, and ``flash_tiles`` being the function it was.
 """
 
 import hashlib
@@ -173,7 +192,7 @@ PROGRAMS = {
     "serve_mellum2_projctx.step8192": "2d3f004b61858767",
     "serve_laguna_agentturns.init": "e4025d438f2ceb1d",
     "serve_laguna_agentturns.step1024": "049e8daa592d60d0",
-    "serve_laguna_agentturns.step6144": "e6e92836d5c93cd3",
+    "serve_laguna_agentturns.step6144": "a4e4961a94882f1f",
     "serve_chat_steady.told128": "db30cd54d721b7ac",
     "serve_chat_steady.told384": "e905645daec74f90",
     "serve_granite_toolcalls.told256": "5146abf0b9b112f0",

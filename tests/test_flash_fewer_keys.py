@@ -138,9 +138,9 @@ def test_the_equal_width_window_against_the_reference(seq, tile, window,
 
 # Laguna-XS.2's shapes of the same forward: groups of 6 (48 query heads on
 # 8) and of 8 at 64 heads in one model, and a window NARROWER than the tile
-# (512 inside blocks of 1024: the walk visits two key blocks a query block
-# of whose 2048 keys at most 512 are inside), one key either side of it, and
-# no window; told the rows' lengths (a whole row and one that ends inside a
+# (512 inside blocks of 1024: the walk visited two key blocks a query block
+# of whose 2048 keys at most 512 are inside; since PR 61 a step a query block
+# of 512 over 1024), one key either side of it, and no window; told the rows' lengths (a whole row and one that ends inside a
 # block) and not
 # (interpreted, a head of a row costs a second: the model's 64 and 48 heads
 # once each, told; the windows' edges and the untold call at 16 and 12 heads
@@ -153,9 +153,12 @@ def test_the_equal_width_window_against_the_reference(seq, tile, window,
 def test_a_window_narrower_than_the_tile_and_groups_of_six(
         heads, kv_heads, window, told):
     seq = 2048
+    # the plain tile, which the walk would visit two blocks of; since PR 61
+    # these windows run one step a query block of 512 (`window_step`)
     assert fa.flash_tiles(seq, seq, head_dim=128) == (1024, 1024)
     if window:
         assert fa._window_key_blocks(seq, 1024, 1024, window) == 2
+        assert fa.window_step(seq, window, head_dim=128) == (512, 512)
     q, k, v = grouped(seq, heads=heads, kv_heads=kv_heads, d=128, batch=2)
     lengths = jnp.asarray([seq, 1300], jnp.int32) if told else None
     got = attention(q, k, v, impl="flash", window=window, lengths=lengths)
@@ -175,6 +178,52 @@ def test_a_window_narrower_than_the_tile_and_groups_of_six(
             jnp.repeat(v, 8, axis=2)[:, :, :heads], window=window)
         if kv_heads > 1:
             assert float(jnp.abs(got - wrong).max()) > 1e-2
+
+
+# one grid step a query block (PR 61, ``window_step``): a length the plain
+# rule cuts into two blocks of 1024 runs, under a window whose tail fits
+# beside a query block, at query blocks of 512 over their own keys and the
+# tail before them (512 keys at a window of 300 to 513, 128 at one of 65),
+# with no key dim in the grid and no running softmax; a window of 514 at
+# 2048 (no block of 768), one of 1024 (its tail past VMEM) and keys in one
+# block walk as they did. Told the rows' lengths (a whole row, one that ends
+# inside a block, one under the window, an empty one) and not
+@pytest.mark.parametrize("told", [False, True])
+@pytest.mark.parametrize("seq, window, step", [
+    (2048, 512, (512, 512)), (2048, 300, (512, 512)),
+    (2048, 513, (512, 512)), (2048, 65, (512, 128)), (2048, 1, (512, 128)),
+    (1536, 514, (768, 768)), (2048, 514, None), (2048, 1024, None),
+    (1024, 512, None)])
+def test_the_windows_one_step_against_the_masked_softmax(
+        seq, window, step, told):
+    assert fa.window_step(seq, window, head_dim=128) == step
+    q, k, v = grouped(seq, heads=4, kv_heads=2, d=128, batch=4)
+    lengths = jnp.asarray([seq, seq - 400, 200, 0], jnp.int32)
+    got = attention(q, k, v, impl="flash", window=window,
+                    lengths=lengths if told else None)
+    want = reference_attention(q, k, v, window=window)
+    own = (jnp.arange(seq)[None, :] < (
+        lengths if told else jnp.full((4,), seq))[:, None])[:, :, None, None]
+    np.testing.assert_allclose(jnp.where(own, got, 0.0),
+                               jnp.where(own, want, 0.0),
+                               rtol=2e-5, atol=2e-5)
+    # one key more or fewer is another result
+    for other in {max(window - 1, 1), window + 1} - {window}:
+        off = reference_attention(q, k, v, window=other)
+        assert float(jnp.abs(jnp.where(own, got - off, 0.0)).max()) > 1e-3
+    block_q = step[0] if step else fa.flash_tiles(seq, seq, head_dim=128)[0]
+    if told:  # past a row's end a whole block is zeros, an empty row all
+        assert not np.asarray(got[3]).any()
+        assert not np.asarray(got[2, block_q:]).any()
+    # and the call is the grid the rule says: a step a query block and two
+    # operands of keys, or the walk's key dim
+    text = str(jax.make_jaxpr(lambda q, k, v: attention(
+        q, k, v, impl="flash", window=window))(q, k, v))
+    if step:
+        assert f"grid=(4, 4, {seq // block_q})" in text
+    else:
+        walk = fa._window_key_blocks(seq, block_q, block_q, window)
+        assert f"grid=(4, 4, {seq // block_q}, {walk})" in text
 
 
 def test_the_equal_width_window_is_a_forward_alone_and_none_is_the_old_call():

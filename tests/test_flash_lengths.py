@@ -37,7 +37,19 @@ def tiles(request, monkeypatch):
     """Tiles smaller than ``flash_tiles`` gives a length the interpreter
     can afford, so that a row has blocks to skip."""
     monkeypatch.setattr(fa, "flash_tiles", lambda *a, **kw: request.param)
+    # under the window: the walk's key blocks at these tiles (the window's
+    # one step a query block, PR 61, is `step`'s tests)
+    monkeypatch.setattr(fa, "window_step", lambda *a, **kw: None)
     return request.param
+
+
+@pytest.fixture
+def step(monkeypatch):
+    """The window's one step a query block (``window_step``) at a block
+    the interpreter can afford: two query blocks of 256, each over its own
+    keys and the 256 before them."""
+    monkeypatch.setattr(fa, "window_step", lambda *a, **kw: (256, 256))
+    return 256, 256
 
 
 def operands(form):
@@ -84,6 +96,21 @@ def past(a, axis, end, value):
 @pytest.mark.parametrize("form", FORMS)
 def test_a_rows_own_outputs_are_the_kernels_that_knew_no_lengths(
         form, row, tiles):
+    rows_own_outputs_are_the_untold_kernels(form, row, tiles)
+
+
+# the window's one step keeps them too; the keys it reads end with the
+# query blocks (a block's own and the tail before it)
+@pytest.mark.parametrize("row", sorted(ROWS))
+def test_the_windows_one_step_keeps_a_rows_own_outputs(row, step):
+    call, = [e for e in jax.make_jaxpr(lambda *a: fa._flash_fwd(
+        *a, causal=True, window=WINDOW))(*operands("sliding")[0]).jaxpr.eqns
+        if e.primitive.name == "pallas_call"]
+    assert call.params["grid_mapping"].grid == (2, 2 * HEADS, S // 256)
+    rows_own_outputs_are_the_untold_kernels("sliding", row, step)
+
+
+def rows_own_outputs_are_the_untold_kernels(form, row, tiles):
     arrays, axes, kwargs = operands(form)
     n = ROWS[row]
     lengths = jnp.asarray([n, S], jnp.int32)
@@ -186,6 +213,14 @@ def test_lowering_with_the_lengths_traces_no_more_than_without(form, traced):
         assert told < 220
 
 
+def test_lowering_the_windows_one_step_told_traces_no_more(traced, step):
+    lowered("sliding", True, traced), lowered("sliding", False, traced)
+    told = lowered("sliding", True, traced) + lowered("sliding", True, traced)
+    untold = (lowered("sliding", False, traced)
+              + lowered("sliding", False, traced))
+    assert 0 < told <= untold
+
+
 def block_maps(form, told):
     """The kernel's one ``pallas_call`` equation -> its block mappings."""
     arrays, _, kwargs = operands(form)
@@ -220,17 +255,30 @@ def test_no_index_map_holds_a_nested_jit(form, told):
         assert names <= allowed, names
 
 
+@pytest.mark.parametrize("told", [False, True], ids=["untold", "told"])
+def test_the_windows_one_step_maps_hold_lax_alone(told, step):
+    """Five operands (the queries, and the keys and the values twice: the
+    tail's blocks and the query blocks' own) and ``o``; told or not, no map
+    holds a nested ``jit``."""
+    maps = block_maps("sliding", told)
+    assert len(maps) == 6
+    names = {e.primitive.name for m in maps
+             for e in m.index_map_jaxpr.jaxpr.eqns}
+    assert names <= {"get", "min", "max", "mul", "sub", "div"}, names
+
+
 def named_blocks(mapping, blocks, grid):
     """Every block index an index map names over ``grid``, the prefetched
     ``blocks`` (``_live_blocks``) read as values (the map's jaxpr with its
-    state discharged) -> ``{(b, h, iq, ik): index}``."""
+    state discharged) -> ``{(b, h, iq, ik): index}`` (no ``ik`` on the
+    window's one step's grid of three)."""
     import itertools
 
     from jax._src.state import discharge
 
     jaxpr, consts = discharge.discharge_state(
         mapping.index_map_jaxpr.jaxpr, mapping.index_map_jaxpr.consts)
-    told = (blocks,) * (len(jaxpr.invars) - 4)   # none where not told
+    told = (blocks,) * (len(jaxpr.invars) - len(grid))  # none if not told
     return {step: tuple(int(i) for i in jax.core.eval_jaxpr(
         jaxpr, consts, *step, *told)[:4])
         for step in itertools.product(*map(range, grid))}
@@ -272,6 +320,45 @@ def test_a_dead_step_names_blocks_that_are_there_already(form, tiles):
     # `o` is written where it belongs, every block of it
     out = named_blocks(mapping.block_mappings[3], blocks, mapping.grid)
     assert all(index == (b, h, iq, 0) for (b, h, iq, _), index in out.items())
+
+
+def test_the_windows_one_step_names_blocks_that_are_there_already(step):
+    """The same three rows through the window's one step (a grid of (row,
+    head, query block)): a live step names what the call that is not told
+    names, the tail the block of keys that ends where the query block
+    begins (the row's first its own first keys, masked); a step past a
+    short row's end holds its head's last live blocks; an empty row's
+    steps hold ONE block of each operand, whatever the head."""
+    arrays, _, kwargs = operands("sliding")
+    arrays = [jnp.concatenate([a, a[:1]]) for a in arrays]     # three rows
+    lengths = jnp.asarray([0, 200, S], jnp.int32)
+    call, = [e for e in jax.make_jaxpr(lambda *a: fa._flash_fwd(
+        *a, lengths=lengths, **kwargs))(*arrays).jaxpr.eqns
+        if e.primitive.name == "pallas_call"]
+    untold, = [e for e in jax.make_jaxpr(lambda *a: fa._flash_fwd(
+        *a, **kwargs))(*arrays).jaxpr.eqns if e.primitive.name == "pallas_call"]
+    mapping = call.params["grid_mapping"]
+    assert mapping.grid == untold.params["grid_mapping"].grid == (
+        3, 2 * HEADS, S // 256)
+    blocks = np.asarray(fa._live_blocks(lengths, *step))
+
+    def named(m):
+        return named_blocks(m, blocks, mapping.grid)
+
+    for operand, (told, plain) in enumerate(zip(
+            mapping.block_mappings[:5],
+            untold.params["grid_mapping"].block_mappings)):
+        got, want = named(told), named(plain)
+        assert len({i for (b, *_), i in got.items() if b == 0}) == 1
+        for (b, h, iq), index in got.items():
+            if operand in (1, 2):   # the tail: the block before, or 0
+                assert want[b, h, iq][2] == max(iq - 1, 0)
+            if b == 2:
+                assert index == want[b, h, iq]
+            if b == 1:  # 200 positions: one live block of 256
+                assert index == want[b, h, 0]
+    out = named(mapping.block_mappings[5])
+    assert all(index == (b, h, iq, 0) for (b, h, iq), index in out.items())
 
 
 # what `_flash_fwd` without lengths traced to at the parent (`3fc52f6`), at

@@ -321,6 +321,9 @@ def test_the_equal_width_window_compiles_and_walks_two_key_blocks(
         lambda q, k, v: fa._flash_fwd(q, k, v, causal=True, window=1024)
     ).lower(q, k, k).compile()
     assert fa.EQUAL_WINDOW_TRACE_NAME in compiled.as_text()
+    # a tail of 1024 beside a block of 1024 is past VMEM's reckoning: the
+    # walk, at the plain tile
+    assert fa.window_step(seq, 1024, head_dim=128) is None
     tiles = fa.flash_tiles(seq, seq, head_dim=128)
     assert tiles == (1024, 1024)
     # a window of 1024 reaches two key blocks of 1024 a query block, where
@@ -338,8 +341,10 @@ def test_the_equal_width_window_compiles_and_walks_two_key_blocks(
 
 # serve_laguna_agentturns's six buckets at its 4 rows: the sliding layers'
 # forward at 64 query heads on 8 (groups of 8) told a window of 512, NARROWER
-# than the tile, and the full layers' at 48 on 8 (groups of 6, the first
-# group that is no power of two), each told the rows' lengths
+# than the plain tile, so one step a query block of 512 over its own keys and
+# the 512 before them wherever the keys are several blocks (PR 61), and the
+# full layers' at 48 on 8 (groups of 6, the first group that is no power of
+# two) at the plain 1024 x 1024, each told the rows' lengths
 LAGUNA_BUCKETS = [1024, 2048, 3072, 4096, 5120, 6144]
 
 
@@ -357,14 +362,41 @@ def test_lagunas_two_forwards_compile_told_their_rows_lengths(
     ).lower(q, k, k, of(4, dtype=jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert (fa.EQUAL_WINDOW_TRACE_NAME in compiled.as_text()) == bool(window)
-    tiles = fa.flash_tiles(seq, seq, head_dim=128)
-    assert tiles == (1024, 1024)
-    if window:  # two key blocks a query block, one where there is one
-        assert fa._window_key_blocks(seq, *tiles, window) == min(
-            2, seq // 1024)
+    step = window and fa.window_step(seq, window, head_dim=128)
+    assert step == ((512, 512) if window and seq > 1024 else None)
+    tiles = (step[0], sum(step)) if step else fa.flash_tiles(
+        seq, seq, head_dim=128)
+    assert step or tiles == (1024, 1024)
     reckoned = fa.tile_vmem_bytes(*tiles, head_dim=128)
     used = [n for n in _scoped_vmem(compiled) if n]
     assert used and max(used) <= reckoned <= fa.VMEM_LIMIT_BYTES
+
+
+# the same sliding forward at the cell's longest step, 4 x 6144 x 64 heads,
+# told the rows' lengths and not: the call's grid is a step a query block of
+# 512 (12 a row a head, no key dim), and Mosaic's VMEM is under the
+# reckoning of 512 queries beside 1024 keys (8.1 MB of the plain tile's 13.1)
+@pytest.mark.parametrize("told", [True, False])
+def test_the_windows_one_step_compiles_at_the_cells_longest_step(
+        told, one_chip, compiled_for_tpu):
+    def of(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    seq, window = 6144, 512
+    q, k, n = of(4, 64, seq, 128), of(4, 8, seq, 128), of(4, dtype=jnp.int32)
+
+    def forward(q, k, v, n):
+        return fa._flash_fwd(q, k, v, causal=True, window=window,
+                             lengths=n if told else None)[0]
+
+    assert "grid=(4, 64, 12)" in str(jax.make_jaxpr(forward)(q, k, k, n))
+    compiled = jax.jit(forward).lower(q, k, k, n).compile()
+    assert fa.EQUAL_WINDOW_TRACE_NAME in compiled.as_text()
+    assert fa.window_step(seq, window, head_dim=128) == (512, 512)
+    reckoned = fa.tile_vmem_bytes(512, 1024, head_dim=128)
+    used = [n for n in _scoped_vmem(compiled) if n]
+    assert used and max(used) <= reckoned
+    assert reckoned < 0.7 * fa.tile_vmem_bytes(1024, 1024, head_dim=128)
 
 
 @pytest.mark.parametrize("seq", DOTS3_BUCKETS)

@@ -253,6 +253,47 @@ def test_the_step_counts_the_blocks_the_equal_width_forward_skipped(
     assert stats["flash_blocks_run"] == 0 == stats["flash_blocks_skipped"]
 
 
+def test_the_step_counts_the_windows_one_step_a_query_block(generator):
+    """The counters count the blocks the kernel runs (PR 61): at a bucket
+    of 2048 the sliding layers' forward, told a window of 65, runs one step
+    a query block of 512 over its own keys and the 128 before them
+    (``window_step``: four steps a (row, head)), and the full layer's walks
+    the plain rule's 1024 x 1024 (three). A long row, a short one and two
+    empty."""
+    from ray_tpu.ops.pallas import flash_attention as fa
+
+    cfg = attention_shaped(sliding=True)
+    gen = generator(LlamaConfig(**{**cfg.__dict__, "max_seq_len": 2048}))
+    heads, lengths = 4, np.asarray((2000, 5, 0, 0))
+    states = [gen._prefill({"prompt": [1 + n % 200 for n in range(length)],
+                            "max_new": 2}, "") for length in lengths[:2]]
+    gen._step("", states + [None, None])
+    stats = gen.engine_stats()
+    assert stats["positions_computed"] == 4 * 2048
+    assert fa.window_step(2048, 65, head_dim=cfg.head_dim) == (512, 128)
+    assert fa.flash_tiles(2048, 2048, head_dim=cfg.head_dim) == (1024, 1024)
+    # every block of the long row's, the short row's first alone
+    sliding = fa.equal_width_blocks(2048, lengths, head_dim=cfg.head_dim,
+                                    window=65)
+    full = fa.equal_width_blocks(2048, lengths, head_dim=cfg.head_dim)
+    assert sliding == (4 * 4, 4 + 1) and full == (4 * 3, 3 + 1)
+    assert stats["attn_blocks_run"] == heads * (2 * 4 * 4 + 4 * 3)
+    assert stats["attn_blocks_live"] == heads * (2 * 5 + 4)
+    assert stats["attn_blocks_skipped"] == (stats["attn_blocks_run"]
+                                            - stats["attn_blocks_live"])
+    # the walk it replaces would count other blocks: three a (row, head)
+    # of a sliding layer, of twice the query rows and 1024 keys each
+    assert fa.causal_blocks(2048, lengths, (1024, 1024), 65) == (4 * 3, 4)
+    # kept pairs over computed pairs in the sliding layers, a head, says
+    # how well a step fits the window: 2.6 times the walk's share
+    kept = stats["window_keys_kept"]
+    assert kept == 2 * sum(n * 65 - 65 * 64 // 2 if n >= 65
+                           else n * (n + 1) // 2 for n in lengths)
+    computed = 2 * sliding[1] * 512 * (512 + 128)
+    walked = 2 * 4 * 1024 * 1024
+    assert 0.078 < kept / computed < 0.079 and computed / walked < 0.4
+
+
 def test_a_model_without_the_operator_skips_no_chunk(generator):
     gen = generator(LlamaConfig.debug_1l())
     gen._step("", [gen._prefill({"prompt": [1, 2, 3], "max_new": 2}, ""),

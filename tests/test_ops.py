@@ -113,6 +113,79 @@ class TestFlashTiles:
             flash_attention(q, k, v, True)
 
 
+class TestWindowStep:
+    """``window_step``: where the equal-width forward under a window runs
+    one grid step a query block, and at which block and tail. Pure: no chip
+    needed."""
+
+    # Laguna-XS.2's window at its buckets of several key blocks: a query
+    # block of 512 and the 512 keys before it, 1024 keys a query where the
+    # walk's two blocks of 1024 were 2048
+    @pytest.mark.parametrize("seq", range(2048, 6145, 1024))
+    def test_lagunas_window_runs_a_step_a_query_block_of_512(self, seq):
+        from ray_tpu.ops.pallas.flash_attention import (
+            causal_blocks, equal_width_blocks, flash_tiles, window_step)
+
+        assert window_step(seq, 512) == (512, 512)
+        whole, short = [seq, seq // 3, 0], seq // 3
+        run, live = equal_width_blocks(seq, whole, head_dim=128, window=512)
+        assert run == 3 * (seq // 512)
+        assert live == seq // 512 + -(-short // 512)
+        # the walk it replaces: two blocks of 1024 a block of 1024 queries
+        walk, _ = causal_blocks(seq, whole, flash_tiles(seq, seq), 512)
+        assert walk == 3 * (2 * (seq // 1024) - 1)
+        # two thirds of its scores at 2048, 6 in 11 at 6144
+        assert 3 * run * 512 * (512 + 512) <= 2 * walk * 1024 * 1024
+        # and no window: the causal walk, as ever
+        assert equal_width_blocks(seq, whole, head_dim=128) == (
+            causal_blocks(seq, whole, flash_tiles(seq, seq)))
+
+    # Mellum2's window at its five buckets and at the 3072 its warm-up
+    # stream compiles: its tail of 1024 beside a block of 1024 is past
+    # VMEM's reckoning, so the walk; Laguna's at 1024, the 1152 of nine
+    # 128s and 1408: the plain rule's keys are one block there, and a walk
+    # of one step is the step
+    @pytest.mark.parametrize("window, seq", [
+        *((1024, seq) for seq in range(3072, 8193, 1024)), (512, 1024),
+        (512, 1152), (512, 1408), (24, 256), (24, 128), (4000, 8192),
+        (1025, 4096), (700, 5120)])
+    def test_where_the_window_walks(self, window, seq):
+        from ray_tpu.ops.pallas.flash_attention import (
+            causal_blocks, equal_width_blocks, flash_tiles, window_step)
+
+        assert window_step(seq, window) is None
+        lengths = [seq, 5]
+        assert equal_width_blocks(
+            seq, lengths, head_dim=128, window=window) == causal_blocks(
+                seq, lengths, flash_tiles(seq, seq), window)
+
+    # the tail is the shortest divisor of the block that holds the window
+    # less one key (a block's first query sees so many keys before the
+    # block); the block is the shortest from 512 up that has such a divisor
+    @pytest.mark.parametrize("window, seq, step", [
+        (1, 2048, (512, 128)), (24, 2048, (512, 128)),
+        (129, 2048, (512, 128)), (130, 2048, (512, 256)),
+        (300, 5120, (512, 512)), (513, 5120, (512, 512)),
+        (514, 5120, (640, 640)), (514, 6144, (768, 768)),
+        (700, 6144, (768, 768)), (512, 1536, (512, 512)),
+        (512, 1920, (640, 640))])
+    def test_the_block_and_the_tail(self, window, seq, step):
+        from ray_tpu.ops.pallas.flash_attention import (
+            VMEM_LIMIT_BYTES, tile_vmem_bytes, window_step)
+
+        block_q, tail = step
+        assert window_step(seq, window) == step
+        assert seq % block_q == 0 == block_q % tail and tail >= window - 1
+        assert tile_vmem_bytes(block_q, block_q + tail) <= VMEM_LIMIT_BYTES
+
+    def test_a_head_of_64_takes_the_same_step(self):
+        from ray_tpu.ops.pallas.flash_attention import window_step
+
+        assert window_step(6144, 512, head_dim=64) == (512, 512)
+        # one block at 1408 for a head of 64: the walk
+        assert window_step(1408, 512, head_dim=64) is None
+
+
 class TestFlashAtTheRulesTiles:
     """Interpret mode, grouped heads (2 query heads on 1 key/value head),
     D 128, B 1: the serving buckets the rule before PR 29 left at block
