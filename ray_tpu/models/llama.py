@@ -87,6 +87,17 @@ Design notes (why this is not a torch translation):
   ``partial_rotary_factor`` of its head alone (``_yarn_rope``'s
   ``rotary_dim``), and both gating each head's output (``head_gate``) as
   the latent operators do.
+- A ninth operator, ``"indexed_attention"`` (Keye-VL-2.0-30B-A3B's
+  language model has it in every layer): grouped-query attention whose
+  query attends the ``index_topk`` keys a learned indexer chooses, the
+  indexed latent operator's indexer on the ``attention`` layers' leaves.
+  There is no query latent, so the indexer's queries come from the block's
+  normed input, and its whole head turns (``_index_queries_and_key`` is
+  told both); the choice reaches ``ops.attention`` as ``keep`` and the
+  equal-width flash forward masks by it; ``llama_decode`` keeps a layer's
+  index keys beside its keys and values. With it comes multi-axis rope
+  (``mrope_section``): a token has three positions and each of a head's
+  frequency pairs turns by one of them (``_rope``).
 - Attention dispatches to ``ray_tpu.ops`` (Pallas flash attention on TPU,
   reference einsum path elsewhere; ring attention when the seq axis > 1).
 - bfloat16 activations / fp32 params+optimizer by default: MXU-native.
@@ -178,8 +189,9 @@ class RopeScaling:
 # the operators that `_latent_attention` computes
 LATENT_OPERATORS = ("latent", "window", "indexed")
 # the operators whose leaves are grouped-query attention's: every causal
-# key, or the last `sliding_window` of them
-ATTENTION_OPERATORS = ("attention", "sliding")
+# key, the last `sliding_window` of them, or the `index_topk` an indexer
+# chooses (its leaves beside them)
+ATTENTION_OPERATORS = ("attention", "sliding", "chosen")
 
 
 class LatentWidths(NamedTuple):
@@ -311,6 +323,19 @@ class LlamaConfig:
     index_topk: int = 0
     head_gate: bool = False
     latent_rescale: bool = False
+    # The indexer on grouped-query attention, and multi-axis rope
+    # (Keye-VL-2.0-30B-A3B's language model has both). layer_types'
+    # "indexed_attention": the attention layers' widths and leaves, and a
+    # query attends its index_topk keys of largest index score as an
+    # indexed latent operator's does; there is no query latent, so the
+    # indexer's queries are u W_iq from the block's normed input, and its
+    # whole index_head_dim turns under rope. mrope_section (empty: one
+    # stream): a token has three positions (temporal, height, width) and
+    # the sections say how many of a head's head_dim / 2 frequency pairs,
+    # in order, turn by each; the grouped-query operators' queries and keys
+    # turn so (no rope_scaling, no partial turn beside it), an indexer's by
+    # the first stream; positions [B, S] are three equal streams.
+    mrope_section: Tuple[int, ...] = ()
     # The state-space operator and the scalars of its family
     # (granite-4.0-h has all of it). layer_types' "mamba": Mamba-2's mixer,
     # mamba_heads heads of mamba_head_dim channels (together the inner
@@ -374,14 +399,16 @@ class LlamaConfig:
 
     def layer_kinds(self) -> Tuple[str, ...]:
         """Each layer's kind, ``<operator>_<feed-forward>``: ``attention``,
-        ``sliding``, ``conv``, ``latent``, ``window``, ``indexed``,
-        ``mamba`` or ``kda``, then ``routed`` (experts) or ``dense``."""
+        ``sliding``, ``chosen``, ``conv``, ``latent``, ``window``,
+        ``indexed``, ``mamba`` or ``kda``, then ``routed`` (experts) or
+        ``dense``."""
         ops = tuple(self.layer_types) or ("full_attention",) * self.num_layers
         if len(ops) != self.num_layers:
             raise ValueError(f"layer_types names {len(ops)} layers, "
                              f"num_layers is {self.num_layers}")
         names = {"full_attention": "attention",
-                 "sliding_attention": "sliding", "conv": "conv",
+                 "sliding_attention": "sliding",
+                 "indexed_attention": "chosen", "conv": "conv",
                  "latent_attention": "latent",
                  "window_latent_attention": "window",
                  "indexed_latent_attention": "indexed", "mamba": "mamba",
@@ -438,8 +465,9 @@ class LlamaConfig:
             self.index_topk if operator == "indexed" else 0)
 
     def attention_heads(self, operator: str = "attention") -> int:
-        """The query heads of a grouped-query operator (``attention`` or
-        ``sliding``): the sliding layers' own count where one is given."""
+        """The query heads of a grouped-query operator (``attention``,
+        ``sliding`` or ``chosen``): the sliding layers' own count where one
+        is given."""
         return (self.swa_num_heads or self.num_heads
                 if operator == "sliding" else self.num_heads)
 
@@ -494,8 +522,13 @@ class LlamaConfig:
             gate = h * self.attention_heads(operator) if self.head_gate else 0
             return h * (q + 2 * kv) + q * h + norms + gate
 
+        # an indexer's own leaves beside its queries: its one key with a
+        # LayerNorm's weight and bias, its heads' weights
+        index_key_and_weights = h * ihd + 2 * ihd + h * ih
         half = {"attention": grouped_query("attention"),
                 "sliding": grouped_query("sliding"),
+                "chosen": (grouped_query("chosen") + h * ih * ihd
+                           + index_key_and_weights),
                 # in-projection, beta's, the taps, A_log a head, dt_bias a
                 # channel, the norm's one weight a head's channel, out
                 "kda": (h * kda_proj + h * self.kda_heads
@@ -508,10 +541,8 @@ class LlamaConfig:
                           + 3 * self.mamba_heads + inner + inner * h),
                 "conv": 4 * h * h + h * self.conv_kernel,
                 "latent": latent("latent"), "window": latent("window"),
-                # the indexer: its queries, its one key with a LayerNorm's
-                # weight and bias, its heads' weights
                 "indexed": (latent("indexed") + self.q_lora_rank * ih * ihd
-                            + h * ihd + 2 * ihd + h * ih),
+                            + index_key_and_weights),
                 "routed": ((held + self.num_shared_experts) * 3 * h
                            * self.mlp_hidden + h * E
                            + (E if self.router_bias else 0)),
@@ -680,6 +711,12 @@ def merge_lora(params: Dict, lora: Dict, cfg: LlamaConfig,
     return dict(params, layers=_as_layers(by_kind, cfg))
 
 
+# an indexer's leaves beside its queries', which come from the operator's
+# own source
+_INDEX_KEY_AXES = {"wi_k": ("embed", None), "wi_k_norm": ("norm",),
+                   "wi_k_bias": ("norm",), "wi_w": ("embed", None)}
+
+
 def _kind_logical_axes(cfg: LlamaConfig, kind: str) -> Dict[str, Any]:
     """One layer of a kind: leaf -> logical axes, without the leading dim
     over layers."""
@@ -694,6 +731,8 @@ def _kind_logical_axes(cfg: LlamaConfig, kind: str) -> Dict[str, Any]:
             layer.update(q_norm=("norm",), k_norm=("norm",))
         if cfg.head_gate:
             layer.update(w_head_gate=("embed", "heads"))
+        if operator == "chosen":  # the indexer is whole on every device
+            layer.update(wi_q=("embed", None, None), **_INDEX_KEY_AXES)
     elif operator in LATENT_OPERATORS:  # the ranks stay whole everywhere
         layer.update(wkv_a=("embed", None), kv_a_norm=("norm",),
                      wkv_b=(None, "heads", "head_dim"),
@@ -706,9 +745,7 @@ def _kind_logical_axes(cfg: LlamaConfig, kind: str) -> Dict[str, Any]:
         if cfg.head_gate:
             layer.update(w_head_gate=("embed", "heads"))
         if operator == "indexed":  # the indexer is whole on every device
-            layer.update(wi_q=(None, None, None), wi_k=("embed", None),
-                         wi_k_norm=("norm",), wi_k_bias=("norm",),
-                         wi_w=("embed", None))
+            layer.update(wi_q=(None, None, None), **_INDEX_KEY_AXES)
     elif operator == "mamba":  # its inner widths stay whole everywhere
         layer.update(mamba_in=("embed", None), mamba_conv_w=(None, None),
                      mamba_conv_b=(None,), mamba_dt_bias=(None,),
@@ -765,6 +802,24 @@ def init_llama(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
         """The L layers of a kind, stacked; ks: ten keys, of which the
         eighth and ninth are the embedding's and the head's."""
         operator, ffn = kind.split("_")
+
+        def a_layer_at_a_time(shape, k, fan_in):
+            # as moe.init_experts draws its stacks: a float32 draw of
+            # all layers' wo on its way to bf16 is gigabytes
+            return jax.lax.map(lambda lk: norm_init(shape, lk, fan_in),
+                               jax.random.split(k, L))
+
+        def indexer(source, kiq, kik, kiw):
+            """An indexer's leaves, its queries made from ``source`` dims
+            (the query latent's, or the block's input's)."""
+            ih, ihd = cfg.index_heads, cfg.index_head_dim
+            return dict(
+                wi_q=a_layer_at_a_time((source, ih, ihd), kiq, source),
+                wi_k=norm_init((L, h, ihd), kik, h),
+                wi_k_norm=jnp.ones((L, ihd), pd),
+                wi_k_bias=jnp.zeros((L, ihd), pd),
+                wi_w=norm_init((L, h, ih), kiw, h))
+
         if operator in ATTENTION_OPERATORS:
             nh = cfg.attention_heads(operator)
             layers = {
@@ -776,15 +831,12 @@ def init_llama(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
             if cfg.head_gate:
                 layers["w_head_gate"] = norm_init(
                     (L, h, nh), jax.random.fold_in(ks[3], 1), h)
+            if operator == "chosen":
+                layers.update(indexer(h, *jax.random.split(
+                    jax.random.fold_in(ks[3], 2), 3)))
         elif operator in LATENT_OPERATORS:
             nl, qr, kvr, nope, rope, vd = cfg.latent_widths(operator)[:6]
             kq, kk = jax.random.split(ks[1])
-
-            def a_layer_at_a_time(shape, k, fan_in):
-                # as moe.init_experts draws its stacks: a float32 draw of
-                # all layers' wo on its way to bf16 is gigabytes
-                return jax.lax.map(lambda lk: norm_init(shape, lk, fan_in),
-                                   jax.random.split(k, L))
 
             queries = {
                 "wq_a": a_layer_at_a_time((h, qr), ks[0], h),
@@ -805,13 +857,7 @@ def init_llama(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
             if cfg.head_gate:
                 layers["w_head_gate"] = norm_init((L, h, nl), kg, h)
             if operator == "indexed":
-                ih, ihd = cfg.index_heads, cfg.index_head_dim
-                layers.update(
-                    wi_q=a_layer_at_a_time((qr, ih, ihd), kiq, qr),
-                    wi_k=norm_init((L, h, ihd), kik, h),
-                    wi_k_norm=jnp.ones((L, ihd), pd),
-                    wi_k_bias=jnp.zeros((L, ihd), pd),
-                    wi_w=norm_init((L, h, ih), kiw, h))
+                layers.update(indexer(qr, kiq, kik, kiw))
         elif operator == "mamba":
             # the mixer's own leaves as mamba_ssm and transformers start
             # them, which is what sets how far a state remembers: A in 1 to
@@ -963,12 +1009,31 @@ def _rotate_pairs(x: jax.Array, cos: jax.Array, sin: jax.Array
     return out.astype(x.dtype)
 
 
-def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: [B, S, H, D]; rotate pairs (d, d + D/2) — llama convention."""
+def _rope(x: jax.Array, positions: jax.Array, theta: float,
+          sections: Tuple[int, ...] = ()) -> jax.Array:
+    """x: [B, S, H, D]; rotate pairs (d, d + D/2) — llama convention.
+    Multi-axis rope (Qwen2-VL's ``apply_multimodal_rotary_pos_emb``):
+    ``positions [3, B, S]``, a token's temporal, height and width
+    positions, and ``sections``, three counts that sum to ``D / 2``: pair
+    ``i`` turns by the stream whose section it falls in, the first
+    ``sections[0]`` pairs by the first stream and so on, each at its own
+    frequency ``theta ** (-2 i / D)``. ``positions [B, S]`` are three equal
+    streams, and the sections then say nothing."""
     d = x.shape[-1]
     half = d // 2
     freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
-    angles = positions[..., None].astype(jnp.float32) * freq  # [B,S,half]
+    if positions.ndim == 3:
+        if len(sections) != 3 or sum(sections) != half:
+            raise ValueError(
+                f"positions{positions.shape} are three streams: "
+                f"mrope_section {tuple(sections)} must deal a head's "
+                f"{half} frequency pairs to them")
+        stream = np.repeat(np.arange(3), sections)            # [half]
+        by_stream = positions[..., None].astype(jnp.float32) * freq
+        angles = jnp.where(stream == 0, by_stream[0], jnp.where(
+            stream == 1, by_stream[1], by_stream[2]))         # [B,S,half]
+    else:
+        angles = positions[..., None].astype(jnp.float32) * freq
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
     return _rotate_pairs(x, cos, sin)
@@ -1040,23 +1105,27 @@ def _gate_heads(out: jax.Array, u: jax.Array, w: jax.Array) -> jax.Array:
     return out * gate[..., None].astype(out.dtype)
 
 
-def _index_queries_and_key(cfg: LlamaConfig, w: LatentWidths, u: jax.Array,
-                           c_q: jax.Array, lp: Dict[str, jax.Array],
-                           positions: jax.Array):
+def _index_queries_and_key(cfg: LlamaConfig, u: jax.Array,
+                           source: jax.Array, lp: Dict[str, jax.Array],
+                           positions: jax.Array, rd: int, theta: float):
     """The indexer's side of a position (DeepSeek-V3.2's lightning
-    indexer): its queries ``q_i [B, index_heads, S, index_head_dim] = c_q
-    W_iq``, its ONE key ``k_i [B, S, index_head_dim] = LayerNorm(u W_ik)``
-    (weight and bias), both rotated over their first ``qk_rope_head_dim``
-    dims at the layer's base (rotate-half over those dims as they lie),
-    and its heads' weights ``[B, S, index_heads] = u W_iw`` in float32."""
-    dt, rd = cfg.dtype, w.rope
-    q_i = jnp.einsum("bsr,rjd->bsjd", c_q, lp["wi_q"].astype(dt))
+    indexer): its queries ``q_i [B, index_heads, S, index_head_dim] =
+    source W_iq`` (``source``: the operator's query latent ``c_q``, or the
+    block's normed input ``u`` where it has none), its ONE key ``k_i [B,
+    S, index_head_dim] = LayerNorm(u W_ik)`` (weight and bias), both
+    rotated over their first ``rd`` dims at ``theta`` (rotate-half over
+    those dims as they lie; by the first of three position streams), and
+    its heads' weights ``[B, S, index_heads] = u W_iw`` in float32."""
+    dt = cfg.dtype
+    if positions.ndim == 3:
+        positions = positions[0]
+    q_i = jnp.einsum("bsr,rjd->bsjd", source, lp["wi_q"].astype(dt))
     k_i = _layer_norm(jnp.einsum("bsh,hd->bsd", u, lp["wi_k"].astype(dt)),
                       lp["wi_k_norm"], lp["wi_k_bias"], cfg.rms_eps)
     q_i = jnp.concatenate(
-        [_rope(q_i[..., :rd], positions, w.theta), q_i[..., rd:]], axis=-1)
+        [_rope(q_i[..., :rd], positions, theta), q_i[..., rd:]], axis=-1)
     k_i = jnp.concatenate(
-        [_rope(k_i[:, :, None, :rd], positions, w.theta)[:, :, 0],
+        [_rope(k_i[:, :, None, :rd], positions, theta)[:, :, 0],
          k_i[..., rd:]], axis=-1)
     weights = jnp.einsum("bsh,hj->bsj", u, lp["wi_w"].astype(dt),
                          preferred_element_type=jnp.float32)
@@ -1177,7 +1246,7 @@ def _latent_attention(cfg: LlamaConfig, u: jax.Array,
         if w.topk:
             with jax.named_scope("indexer"):
                 q_i, k_i, head_weights = _index_queries_and_key(
-                    cfg, w, u, c_q, lp, positions)
+                    cfg, u, c_q, lp, positions, w.rope, w.theta)
             new_rows.append(k_i)
         keep = None
         if state is None:
@@ -1236,6 +1305,65 @@ def _latent_attention(cfg: LlamaConfig, u: jax.Array,
         out = constrain(out, ("batch", "seq", "heads", None))
         y = jnp.einsum("bsnd,ndh->bsh", out, lp["wo"].astype(dt))
     return y, state
+
+
+def _chosen_attention(cfg: LlamaConfig, u: jax.Array, q: jax.Array,
+                      k: jax.Array, v: jax.Array, lp: Dict[str, jax.Array],
+                      positions: jax.Array, state=None,
+                      cache_index: Optional[jax.Array] = None, *,
+                      live: Optional[jax.Array] = None,
+                      counts: Optional[Dict[str, jax.Array]] = None,
+                      lengths: Optional[jax.Array] = None):
+    """Grouped-query attention over the keys an indexer chooses
+    (``indexed_attention``): ``q [B, S, heads, D]``, ``k``, ``v`` ``[B, S,
+    kv_heads, D]`` as ``_layer`` made them (normed, rotated) -> (the heads'
+    outputs ``[B, S, heads, D]``, the state after it or None). The
+    indexer reads the block's normed input ``u`` for its queries too and
+    turns its whole head (``_index_queries_and_key``); query ``t`` attends
+    the ``index_topk`` causal keys of largest index score, every one while
+    it has no more (``ops.index_scores``, ``_chosen_keys``: float32), the
+    same keys for all its heads. ``counts`` is left ``index_kept`` as
+    ``_latent_attention`` leaves it. Without a state the choice goes to
+    ``ops.attention`` as ``keep`` beside ``lengths``; ``state`` is a
+    decode's (keys, values, index keys ``[B, max_len, index_head_dim]``),
+    the new rows written at ``cache_index`` and the choice made over the
+    held index keys."""
+    from ray_tpu.ops.attention import index_scores
+
+    if not cfg.index_topk:
+        raise ValueError("an indexed_attention layer needs index_topk > 0")
+    S = q.shape[1]
+    with jax.named_scope("indexer"):
+        # a choice is a step function of the scores: no gradient reaches
+        # the indexer through it (its own loss is its trainer's to add),
+        # and none is asked of the score kernel
+        q_i, k_i, head_weights = jax.lax.stop_gradient(
+            _index_queries_and_key(cfg, u, u, lp, positions,
+                                   cfg.index_head_dim, cfg.rope_theta))
+    cached = state is not None
+    if cached:
+        k, v, k_i = (jax.lax.dynamic_update_slice_in_dim(
+            held, new.astype(held.dtype), cache_index, axis=1)
+            for held, new in zip(state, (k, v, k_i)))
+        state = (k, v, k_i)
+    q_pos = jnp.arange(S) + (cache_index if cached else 0)
+    with jax.named_scope("indexer"):
+        scores = index_scores(q_i, k_i, head_weights,
+                              impl="reference" if cached else cfg.attn_impl)
+    with jax.named_scope("index_choice"):
+        keep = _chosen_keys(
+            scores, q_pos[:, None] >= jnp.arange(k.shape[1])[None, :],
+            cfg.index_topk)
+    if cached:
+        out = attention(q, k, v, impl="reference", causal=True,
+                        q_offset=cache_index, keep=keep)
+    else:
+        out = attention(q, k, v, impl=cfg.attn_impl, causal=True, keep=keep,
+                        lengths=lengths)
+    if counts is not None:
+        mine = keep if live is None else keep & live[:, :, None]
+        counts["index_kept"] = jnp.sum(mine, dtype=jnp.int32)
+    return out, state
 
 
 def _causal_taps(z: jax.Array, w: jax.Array,
@@ -1415,12 +1543,15 @@ def _layer(cfg: LlamaConfig, x: jax.Array, lp: Dict[str, jax.Array],
     feed-forward the routed experts and
     not the dense SwiGLU. Which latent operator it is (``latent``,
     ``window``, ``indexed``) the leaves do not say, nor whether attention
-    sees every causal key or the last ``sliding_window`` (``sliding``:
-    plain rope there, ``rope_scaling``'s YaRN in the ``attention`` layers):
-    ``operator`` does.
+    sees every causal key, the last ``sliding_window`` (``sliding``:
+    plain rope there, ``rope_scaling``'s YaRN in the ``attention`` layers)
+    or an indexer's choice of them (``chosen``: ``_chosen_attention``):
+    ``operator`` does. ``positions`` are ``[B, S]``, or under
+    ``mrope_section`` a token's three streams ``[3, B, S]`` (``_rope``).
     ``kv_cache`` is the layer's own state in an incremental decode: (keys,
     values) for attention (``[B, max_len, kv_heads, head_dim]`` each, the
-    new rows written at ``cache_index``; of a sliding layer ``[B,
+    new rows written at ``cache_index``; a ``chosen`` layer's index keys
+    after them; of a sliding layer ``[B,
     sliding_window, ...]``, the last rows before this call in order, the
     new ones pushed in at the end), the last rows of ``z`` for the short
     convolution (``_short_conv``), the latent rows for latent attention
@@ -1499,8 +1630,17 @@ def _layer(cfg: LlamaConfig, x: jax.Array, lp: Dict[str, jax.Array],
             theta = (cfg.swa_rope_theta or cfg.rope_theta if window
                      else cfg.rope_theta)
             turned = None if window else cfg.rotary_dim()
-            q = _yarn_rope(q, positions, theta, scaling, turned)
-            k = _yarn_rope(k, positions, theta, scaling, turned)
+            if cfg.mrope_section:
+                if scaling is not None or turned not in (None, cfg.head_dim):
+                    raise ValueError(
+                        "mrope_section deals a whole head's pairs at "
+                        "theta's own frequencies: no rope_scaling and no "
+                        "partial_rotary_factor beside it")
+                q = _rope(q, positions, theta, cfg.mrope_section)
+                k = _rope(k, positions, theta, cfg.mrope_section)
+            else:
+                q = _yarn_rope(q, positions, theta, scaling, turned)
+                k = _yarn_rope(k, positions, theta, scaling, turned)
         if cfg.attention_multiplier:
             # the softmax scale's ratio to the kernels' head_dim ** -0.5,
             # taken by the queries: where it is a power of two (granite's
@@ -1512,7 +1652,11 @@ def _layer(cfg: LlamaConfig, x: jax.Array, lp: Dict[str, jax.Array],
         q = constrain(q, ("batch", "seq", "heads", None))
         k = constrain(k, ("batch", "seq", "kv_heads", None))
         new_cache = None
-        if kv_cache is not None and window:
+        if operator == "chosen":
+            attn_out, new_cache = _chosen_attention(
+                cfg, h, q, k, v, lp, positions, kv_cache, cache_index,
+                live=live, counts=counts, lengths=lengths)
+        elif kv_cache is not None and window:
             # the last `window` rows before this call, then the new ones:
             # slot j holds position cache_index - window + j, so a query's
             # place among the slots is its own plus `window`, and the slots
@@ -1568,7 +1712,9 @@ def _layer(cfg: LlamaConfig, x: jax.Array, lp: Dict[str, jax.Array],
 def init_decode_state(cfg: LlamaConfig, batch: int, max_len: int) -> list:
     """What ``llama_decode`` carries from call to call, a layer's own
     state in the model's order: keys and values ``[batch, max_len,
-    kv_heads, head_dim]`` for an attention layer, the last ``conv_kernel -
+    kv_heads, head_dim]`` for an attention layer (and an
+    ``indexed_attention`` layer's index keys ``[batch, max_len,
+    index_head_dim]`` after them), the last ``conv_kernel -
     1`` rows of ``z`` ``[batch, conv_kernel - 1, hidden]`` for a short
     convolution, the normed latent row and the rotated shared key
     ``[batch, max_len, kv_lora_rank + qk_rope_head_dim]`` for latent
@@ -1590,6 +1736,7 @@ def init_decode_state(cfg: LlamaConfig, batch: int, max_len: int) -> list:
     operators = [kind.split("_")[0] for kind in cfg.layer_kinds()]
     shapes = {
         "attention": (batch, max_len, cfg.num_kv_heads, cfg.head_dim),
+        "chosen": (batch, max_len, cfg.num_kv_heads, cfg.head_dim),
         "sliding": (batch, cfg.sliding_window, cfg.num_kv_heads,
                     cfg.head_dim),
         "conv": (batch, cfg.conv_kernel - 1, cfg.hidden),
@@ -1608,8 +1755,14 @@ def init_decode_state(cfg: LlamaConfig, batch: int, max_len: int) -> list:
                        3 * cfg.kda_widths()[0]), cfg.dtype),
             jnp.zeros((batch, cfg.kda_heads, cfg.kda_head_dim,
                        cfg.kda_head_dim), jnp.float32))
-    return [(zeros[op], zeros[op]) if op in ATTENTION_OPERATORS
-            else zeros[op] for op in operators]
+    def state_of(op):
+        if op == "chosen":  # the index keys beside the keys and values
+            return (zeros[op], zeros[op], jnp.zeros(
+                (batch, max_len, cfg.index_head_dim), cfg.dtype))
+        return ((zeros[op], zeros[op]) if op in ATTENTION_OPERATORS
+                else zeros[op])
+
+    return [state_of(op) for op in operators]
 
 
 def llama_decode(
@@ -1656,7 +1809,9 @@ def llama_hidden(
 ) -> jax.Array:
     """tokens [B, S] int32 → final hidden states [B, S, H] (activation
     dtype, post final-norm). Layers run under ``lax.scan`` with optional
-    per-layer remat; LoRA adapters (if given) scan alongside the base."""
+    per-layer remat; LoRA adapters (if given) scan alongside the base.
+    ``positions [B, S]``, or a model with ``mrope_section``'s three streams
+    ``[3, B, S]``; each row's own indices where not given."""
     return _hidden_and_books(params, tokens, cfg, positions=positions,
                              lora=lora, lora_cfg=lora_cfg)[0]
 
@@ -1822,6 +1977,7 @@ def llama_next_token(
     lora: Optional[Dict[str, Any]] = None,
     lora_cfg: Optional[LoraConfig] = None,
     live: Optional[jax.Array] = None,
+    positions: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array, Optional[Dict[str, jax.Array]]]:
     """Greedy next token of each row without the [B, S, V] logits: tokens
     [B, S] and the index ``last`` [B] int32 of each row's newest token →
@@ -1843,13 +1999,15 @@ def llama_next_token(
     hidden states of the others are not a forward pass's. Without
     ``live`` every position is computed: ``last`` is not taken for a
     length, because a caller who wants every position's hidden state hands
-    zeros there (``serve/llm.py::_FullLogits``)."""
+    zeros there (``serve/llm.py::_FullLogits``). ``positions`` as
+    ``llama_hidden``'s: each row's own indices where not given."""
     lengths = None
     if live is not None:
         lengths = jnp.sum(live, axis=1, dtype=jnp.int32)
-    x, books = _hidden_and_books(params, tokens, cfg, lora=lora,
-                                 lora_cfg=lora_cfg, router_mask=live,
-                                 in_place=True, lengths=lengths)
+    x, books = _hidden_and_books(params, tokens, cfg, positions=positions,
+                                 lora=lora, lora_cfg=lora_cfg,
+                                 router_mask=live, in_place=True,
+                                 lengths=lengths)
     rows = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
     ids = jnp.argmax(llama_head(params, rows, cfg), axis=-1)
     load = None

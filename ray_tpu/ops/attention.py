@@ -16,8 +16,9 @@ window + 1 .. t``, its own among them. ``keep [B, S, S_kv]`` (bool): query
 alike (an indexer's choice: ``index_scores`` gives what it is made from).
 Both come on top of ``causal``; the flash kernel takes both at two widths
 (the window's blocks outside it are not visited; the choice is a mask a
-block) and the window at equal widths too
-(``flash_attention.flash_attention_window``: forward only).
+block) and either at equal widths
+(``flash_attention.flash_attention_window``,
+``flash_attention.flash_attention_selected``: forward only).
 
 ``lengths [B]`` int32: how many of a row's positions are its own where a
 batch's rows are padded on the right to one length (a serving step's are).
@@ -107,7 +108,8 @@ def _flash_per_shard(q: jax.Array, k: jax.Array, v: jax.Array,
     scope. A ``pallas_call`` has no partitioning rule: left to GSPMD its
     operands are all-gathered and every chip computes the whole batch."""
     from ray_tpu.ops.pallas.flash_attention import (
-        flash_attention, flash_attention_shared_rope, flash_attention_window)
+        flash_attention, flash_attention_selected,
+        flash_attention_shared_rope, flash_attention_window)
     from ray_tpu.parallel.sharding import ambient_mesh, logical_to_spec
 
     mesh = ambient_mesh()
@@ -121,23 +123,26 @@ def _flash_per_shard(q: jax.Array, k: jax.Array, v: jax.Array,
         return flash_attention_shared_rope(
             q, q_rope, k, k_rope, v, scale, causal, window,
             None if keep is None else keep.astype(jnp.int8), lengths)
-    if keep is not None:
-        raise NotImplementedError(
-            "the equal-width flash kernels take no choice of keys: that "
-            "needs the two-width forward or impl='reference'")
     if scale is not None or v.shape[3] != q.shape[3]:
         raise NotImplementedError(
             "the equal-width flash kernels scale by head_dim ** -0.5 and "
             "take values of the keys' width")
-    if window is not None or lengths is not None:
+    if window is not None or lengths is not None or keep is not None:
         if not causal:
-            raise ValueError("a window is the causal keys' last ones, and "
-                             "the rows' lengths are causal's")
+            raise ValueError("a window and a choice are of the causal "
+                             "keys, and the rows' lengths are causal's")
         if mesh is not None and mesh.size > 1:
             raise NotImplementedError(
-                "the equal-width flash forward under a window or told the "
-                "rows' lengths runs on one device; a mesh needs "
-                "impl='reference'")
+                "the equal-width flash forward under a window, under a "
+                "choice of keys or told the rows' lengths runs on one "
+                "device; a mesh needs impl='reference'")
+        if keep is not None:
+            if window is not None:
+                raise NotImplementedError(
+                    "the equal-width flash forward takes a window or a "
+                    "choice of keys, not both")
+            return flash_attention_selected(q, k, v, keep.astype(jnp.int8),
+                                            lengths)
         if window is not None:
             return flash_attention_window(q, k, v, window, lengths)
         return flash_attention(q, k, v, causal, lengths)
@@ -202,7 +207,8 @@ def attention(
                 and q.shape[1] == k.shape[1]
                 and q.shape[1] % 128 == 0
                 and (q_rope is not None or scale is None)
-                and (q_rope is not None or keep is None)
+                and (keep is None or keep.shape == (
+                    q.shape[0], q.shape[1], k.shape[1]))
                 and takes_head_dim(q.shape[3] + shared, v.shape[3],
                                    shared_dim=shared))
             if kernel_takes_it:

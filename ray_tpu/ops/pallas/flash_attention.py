@@ -96,6 +96,22 @@ raises and says so, as the two-width forward does; ``_dq_kernel`` and
 ``_dkv_kernel`` see every causal key (ROADMAP M5). ``flash_attention``
 itself is the call it was.
 
+The choice at equal widths (Keye-VL-2.0-30B-A3B's layers: 32 query heads
+on 4 key/value heads of 128, a query attending the 2048 keys its indexer
+chose; PR 62). ``flash_attention_selected`` is ``_flash_fwd`` handed ``keep
+[B, S, S]`` int8: the same kernel body with the choice's ``[block_q,
+block_k]`` block riding in beside the keys' (no head in its index map: the
+choice is a position's, one for all its heads, and the eight query heads of
+a group each fetch it again) and masking the scores before the running
+max; told the rows' lengths it skips the blocks past a row's end as every
+forward does, and it skips no block for the choice (known when the program
+runs and not before: the causal half is computed whole, and a share of a
+roofline reckoned over the KEPT pairs says so). The tiles are the plain
+rule's. Under a scope of its own, ``EQUAL_SELECTED_TRACE_NAME``
+(``flash_fwd_chosen``; the two-width forward under a choice is
+``SELECTED_TRACE_NAME``, ``flash_fwd_selected``). Forward only, and no
+logsumexp: differentiating it raises and says so.
+
 The window's one step (Laguna-XS.2's sliding layers: 64 query heads on 8
 of 128 under a window of 512, HALF the plain tile; PR 61). What a grid step
 of ``_fwd_kernel`` costs on a v5e goes by its QUERY rows far more than by
@@ -317,7 +333,7 @@ def window_step(seq: int, window: Optional[int], *, head_dim: int = 128
 
 def _fwd_kernel(*refs, scale: float, causal: bool, block_q: int,
                 block_k: int, window: Optional[int] = None,
-                told: bool = False):
+                told: bool = False, selected: bool = False):
     """With ``window`` the innermost grid dim walks the key blocks that the
     query block's window reaches, from the window's first
     (``_first_key_block``), and a query sees its last ``window`` keys; a
@@ -328,10 +344,16 @@ def _fwd_kernel(*refs, scale: float, causal: bool, block_q: int,
     ``lse_ref`` (told the lengths the call is the forward's alone, and the
     logsumexp is the backward's to read): a step at a block past its row's
     live ones computes nothing, and a query block past them is neither
-    begun nor finished, its ``o`` written as zeros."""
-    blocks_ref = None
-    if told:
-        blocks_ref, *refs = refs
+    begun nor finished, its ``o`` written as zeros. With ``selected`` a
+    block of ``keep [B, S, S_kv]`` (int8) rides in after the values and
+    masks the scores before the running max, every head alike, as in
+    ``_fwd_shared_rope_kernel``: a query's row of a block in which its
+    choice holds no key fills with ``exp(0)``, and the first block in which
+    it holds one rescales that away (every query's choice holds a key
+    somewhere)."""
+    refs = list(refs)
+    blocks_ref = refs.pop(0) if told else None
+    keep_ref = refs.pop(3) if selected else None
     q_ref, k_ref, v_ref, o_ref, *lse_ref, m_scr, l_scr, acc_scr = refs
     iq = pl.program_id(2)
     ik = pl.program_id(3)
@@ -383,6 +405,8 @@ def _fwd_kernel(*refs, scale: float, causal: bool, block_q: int,
             if window is not None:
                 seen = seen & (q_pos - k_pos < window)
             s = jnp.where(seen, s, _NEG_INF)
+        if selected:
+            s = jnp.where(keep_ref[0].astype(jnp.int32) != 0, s, _NEG_INF)
         m_prev = m_scr[:, :1]                        # [bq, 1]
         m_cur = jnp.max(s, axis=-1, keepdims=True)   # [bq, 1]
         m_new = jnp.maximum(m_prev, m_cur)
@@ -409,7 +433,8 @@ def _fwd_kernel(*refs, scale: float, causal: bool, block_q: int,
 
 def _flash_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
                causal: bool, window: Optional[int] = None,
-               lengths: Optional[jax.Array] = None
+               lengths: Optional[jax.Array] = None,
+               keep: Optional[jax.Array] = None
                ) -> Tuple[jax.Array, Optional[jax.Array]]:
     """q [B,H,S,D], k/v [B,KVH,S,D] → (o [B,H,S,D], lse [B,H,S,128]).
     ``window``: a query sees its last ``window`` keys, and the innermost
@@ -417,17 +442,24 @@ def _flash_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
     reaches (module docstring). ``lengths [B]`` int32: the right-padded
     rows' own lengths, past which no block is computed (module docstring:
     ``o`` is zeros there, and ``lse`` is None: no backward reads it).
-    None, either, is the call it always was."""
+    ``keep [B,S,S]`` int8: a choice of keys a query, every head's (module
+    docstring: the choice at equal widths). None, each, is the call it
+    always was."""
     B, H, Sq, D = q.shape
     KVH, Skv = k.shape[1], k.shape[2]
     n_rep = H // KVH
     scale = D ** -0.5
-    if (window is not None or lengths is not None) \
+    if (window is not None or lengths is not None or keep is not None) \
             and not (causal and Sq == Skv):
-        raise ValueError("a window or the rows' lengths are a prefill's: "
-                         "causal, the queries' positions the keys'")
+        raise ValueError("a window, a choice of keys or the rows' lengths "
+                         "are a prefill's: causal, the queries' positions "
+                         "the keys'")
     if lengths is not None and lengths.shape != (B,):
         raise ValueError(f"lengths{lengths.shape} for {B} rows")
+    if keep is not None and (window is not None
+                             or keep.shape != (B, Sq, Skv)):
+        raise ValueError(f"keep{keep.shape}: a choice is [rows, queries, "
+                         "keys] and comes without a window")
     step = window_step(Sq, window, head_dim=D)
     if step is not None:
         return _flash_fwd_window_step(q, k, v, window, lengths, *step), None
@@ -455,16 +487,28 @@ def _flash_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
         return (b, kv_head(_row_head(b, h, *n)),
                 key_block(b, iq, ik, *n), 0)
 
+    # the choice: a block of it beside the keys', no head in its index
+    chosen, chosen_specs = [], []
+    if keep is not None:
+        told["selected"] = True
+        chosen = [keep]
+        chosen_specs = [pl.BlockSpec(
+            (1, block_q, block_k),
+            lambda b, h, iq, ik, *n: (b, query_block(b, iq, *n),
+                                      key_block(b, iq, ik, *n)))]
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal,
         block_q=block_q, block_k=block_k, **told)
-    # the windowed call under a scope of its own, which is what the device
-    # trace then calls it; the other keeps its caller's
-    scope = (contextlib.nullcontext() if window is None
-             else jax.named_scope(EQUAL_WINDOW_TRACE_NAME))
+    # the windowed call and the one under a choice each under a scope of
+    # its own, which is what the device trace then calls it; the other
+    # keeps its caller's
+    scope = (jax.named_scope(EQUAL_WINDOW_TRACE_NAME) if window is not None
+             else jax.named_scope(EQUAL_SELECTED_TRACE_NAME)
+             if keep is not None else contextlib.nullcontext())
     # every block of a result is written, a dead one of `o` with zeros;
-    # told the lengths there is `o` alone
-    results = [(D, q.dtype)] if lengths is not None else [
+    # told the lengths, or under a choice, there is `o` alone
+    forward_alone = lengths is not None or keep is not None
+    results = [(D, q.dtype)] if forward_alone else [
         (D, q.dtype), (128, jnp.float32)]
     with scope:
         o, *lse = pl.pallas_call(
@@ -476,6 +520,7 @@ def _flash_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     pl.BlockSpec((1, 1, block_q, D), rows),
                     pl.BlockSpec((1, 1, block_k, D), keys),
                     pl.BlockSpec((1, 1, block_k, D), keys),
+                    *chosen_specs,
                 ],
                 out_specs=[
                     pl.BlockSpec((1, 1, block_q, width),
@@ -492,7 +537,7 @@ def _flash_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
                 dimension_semantics=("parallel", "parallel", "parallel",
                                      "arbitrary")),
             interpret=_interpret(),
-        )(*prefetched, q, k, v)
+        )(*prefetched, q, k, v, *chosen)
     return o, (lse[0] if lse else None)
 
 
@@ -864,6 +909,42 @@ def _fa_window_bwd(window, res, g):
 
 
 flash_attention_window.defvjp(_fa_window_fwd, _fa_window_bwd)
+
+
+# What the device trace calls the equal-width forward under a choice of
+# keys (the two-width forward's under one is ``SELECTED_TRACE_NAME``,
+# ``flash_fwd_selected``: a name each, so that one trace tells them apart).
+EQUAL_SELECTED_TRACE_NAME = "flash_fwd_chosen"
+
+
+@jax.custom_vjp
+def flash_attention_selected(q: jax.Array, k: jax.Array, v: jax.Array,
+                             keep: jax.Array,
+                             lengths: Optional[jax.Array] = None
+                             ) -> jax.Array:
+    """``flash_attention``'s forward under a choice of keys (module
+    docstring): q ``[B, S, H, D]``, k, v ``[B, S, KVH, D]``, keep ``[B, S,
+    S]`` int8 → ``[B, S, H, D]``; causal, query ``t`` of row ``b`` sees key
+    ``s`` where ``keep[b, t, s]`` is not 0, every head alike; ``lengths
+    [B]`` int32 or None as ``flash_attention``'s. Forward only."""
+    o, _ = _flash_fwd(jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
+                      jnp.swapaxes(v, 1, 2), causal=True, lengths=lengths,
+                      keep=keep)
+    return jnp.swapaxes(o, 1, 2)
+
+
+def _fa_selected_fwd(q, k, v, keep, lengths):
+    return flash_attention_selected(q, k, v, keep, lengths), None
+
+
+def _fa_selected_bwd(res, g):
+    raise NotImplementedError(
+        "the equal-width flash forward under a choice of keys has no "
+        "backward (`_dq_kernel` and `_dkv_kernel` see every causal key): "
+        "train such a model with attn_impl='reference'")
+
+
+flash_attention_selected.defvjp(_fa_selected_fwd, _fa_selected_bwd)
 
 
 # ------------------------------------------- two widths, a shared rotary key

@@ -2,7 +2,8 @@
 
 DeepSeek-V3.2's lightning indexer gives every (query, key) pair of a
 sequence one number, ``I[s, t] = sum_j w[s, j] ReLU(q[j, s] . k[t])``: a
-few narrow heads ``j`` (dots3-note-prev: 64 of 128), ONE key a position,
+few narrow heads ``j`` (dots3-note-prev: 64 of 128; Keye-VL-2.0-30B-A3B:
+16 of 64), ONE key a position,
 and a weight a head and query. The query then attends its ``index_topk``
 keys of largest ``I``. Made by einsums the heads' products are ``[J, S, T]``
 float32, 27 GB for 4 rows of 5120, where ``I`` itself is 0.4 GB: here a
@@ -33,7 +34,17 @@ def index_tiles(seq: int) -> Tuple[int, int]:
     """``(block_q, block_k)`` for a length: a query block holds every
     head's rows (64 heads of 128 in bf16: 4 MB at 256 rows, in two
     buffers), so it is the narrower; the key block is as wide as divides
-    the length, up to 512. Pure: the length is all it reads."""
+    the length, up to 512. Pure: the length is all it reads, so 16 heads
+    of 64 (Keye-VL-2.0-30B-A3B's) get the same tiles: a query block is then
+    0.5 MB, a key block ``[512, 64]`` is half a lane tile wide (padded to
+    128 in VMEM, the products at half the MXU's width), and a pair costs
+    2048 FLOP (10.4 ps at a v5e's peak) beside the 4 bytes of its float32
+    score (4.9 ps at its HBM's) where 64 heads of 128 cost 16 384: the
+    write is a seventeenth of what the multiplies need there and half
+    here, and what the kernel takes is mostly neither but the vector
+    unit's multiply, maximum and add a head and pair (4 x 8192 in 4.4 ms a
+    layer against 1.4 of need: PERF.md section 5, PR 62; wider tiles for
+    few narrow heads are an open ``perf_opt``)."""
     if seq % 128:
         raise ValueError(f"seq len {seq} must divide by 128")
     block_q = 256 if seq % 256 == 0 else 128

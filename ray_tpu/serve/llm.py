@@ -120,7 +120,8 @@ class LlamaGenerator:
     # `flash_blocks_skipped` the rest, which the kernel, told the rows'
     # lengths, did not compute; `attn_blocks_run`, `attn_blocks_live` and
     # `attn_blocks_skipped` the same three over the grouped-query attention
-    # layers' equal-width flash forwards, full or under the window;
+    # layers' equal-width flash forwards, full, under the window or under
+    # an indexer's choice (which skips no block of its own);
     # `step_compiles` and `step_compile_s` the back-end compilations, and
     # their seconds, that began and ended inside a step's `llm.device`: a
     # step that met a shape nobody warmed
@@ -176,8 +177,10 @@ class LlamaGenerator:
         # those whose operator carries a state over the sequence in chunks
         # (the state-space scan's, the delta rule's), and those whose query
         # sees a window of its keys (latent or grouped-query attention)
-        (self._indexed_layers, self._ssm_layers,
-         self._kda_layers) = map(layers_of, ("indexed", "mamba", "kda"))
+        (self._ssm_layers, self._kda_layers) = map(layers_of,
+                                                   ("mamba", "kda"))
+        # an indexer on latent or on grouped-query attention
+        self._indexed_layers = layers_of("indexed") + layers_of("chosen")
         self._window_layers = layers_of("window") + layers_of("sliding")
         # the latent operators, whose prefill is the two-width flash
         # forward's: (layers, their widths) each
@@ -191,7 +194,10 @@ class LlamaGenerator:
         self._attn_layers = [
             (layers, self._cfg.attention_heads(operator), window)
             for operator, window in (
-                ("attention", None), ("sliding", self._cfg.sliding_window))
+                ("attention", None), ("sliding", self._cfg.sliding_window),
+                # under a choice the forward skips no block but those past
+                # a row's end: the full layers' walk
+                ("chosen", None))
             if (layers := layers_of(operator))]
         # adapt only the attention q/v projections: the cheap standard
         # LoRA target set, and enough for adapters to produce distinct
@@ -497,7 +503,8 @@ class LlamaGenerator:
         the program, transfer out; beside the engine's ``active_s`` it says
         how long an iteration the host works while the device has nothing
         to do); ``index_keys_seen`` and ``index_keys_kept`` (over the live
-        queries of the layers whose operator is ``indexed``: the (query,
+        queries of the layers whose operator has an indexer, ``indexed``
+        on latent or ``chosen`` on grouped-query attention: the (query,
         key) pairs they could have attended, each query's causal keys,
         reckoned on the host from the rows' lengths, and the pairs their
         indexers' choice kept, counted on the device from the choice
@@ -536,9 +543,10 @@ class LlamaGenerator:
         program was handed, summed over steps and those layers);
         ``attn_blocks_run``, ``attn_blocks_live`` and
         ``attn_blocks_skipped`` (the same three over the layers whose
-        operator is grouped-query attention, ``attention`` or ``sliding``,
-        and their equal-width flash forward's grid, rows x the kind's own
-        query heads (``LlamaConfig.attention_heads``) x
+        operator is grouped-query attention, ``attention``, ``sliding``
+        or ``chosen`` (whose forward walks as a full layer's: a choice
+        skips no block), and their equal-width flash forward's grid, rows
+        x the kind's own query heads (``LlamaConfig.attention_heads``) x
         the padded length's steps at or under the diagonal, under
         ``sliding_window`` the window's walk, or its query blocks where the
         window runs one step a block: ``equal_width_blocks`` of the same

@@ -156,7 +156,17 @@ are ONE block the walk is one step a block already and stays);
 which beside a block of 1024 is past VMEM's reckoning: the walk, which is
 the proof that its programs are the parent's); ``serve_dots3_longdoc``'s
 (the two-width forward keeps its walk) and every other line, no other call
-passing a window, and ``flash_tiles`` being the function it was.
+passing a window, and ``flash_tiles`` being the function it was. PR 62
+(Keye-VL-2.0-30B-A3B's language model: an ``indexed_attention`` operator
+on the ``attention`` layers' leaves behind its own layer type, ``_rope``
+told three position streams behind ``mrope_section`` ``()``,
+``_index_queries_and_key`` told its queries' source and its rotary width,
+``_fwd_kernel`` and ``_flash_fwd`` told a choice of keys behind
+``keep=None``, and ``init_llama``'s indexer leaves drawn by one helper for
+both kinds of indexer) moved none of the thirty-three: they are what its
+parent ``dea3e11`` gives to the character, ``serve_dots3_longdoc``'s
+``init`` and steps among them; ``serve_keye_clipqa``'s three are new
+(traced at 8 rows like the others' steps; the cell serves 4).
 """
 
 import hashlib
@@ -193,6 +203,9 @@ PROGRAMS = {
     "serve_laguna_agentturns.init": "e4025d438f2ceb1d",
     "serve_laguna_agentturns.step1024": "049e8daa592d60d0",
     "serve_laguna_agentturns.step6144": "a4e4961a94882f1f",
+    "serve_keye_clipqa.init": "7c1b75c937e18797",
+    "serve_keye_clipqa.step4096": "5702a65fb4d25282",
+    "serve_keye_clipqa.step8192": "80a3186e7471b989",
     "serve_chat_steady.told128": "db30cd54d721b7ac",
     "serve_chat_steady.told384": "e905645daec74f90",
     "serve_granite_toolcalls.told256": "5146abf0b9b112f0",

@@ -1,8 +1,8 @@
 """Attention over fewer keys than the causal ones, interpreted on the CPU:
 the two-width flash forward under a window and under a choice of keys
 against ``reference_attention``, the equal-width forward under a window
-(grouped-query heads, 8 query heads a key head), and the indexer's score
-kernel against its einsums. The lengths are several blocks long and the
+and under a choice (grouped-query heads, 8 query heads a key head), and
+the indexer's score kernel against its einsums. The lengths are several blocks long and the
 window and the choice are shorter than they are, so blocks are skipped and
 keys masked."""
 
@@ -259,11 +259,71 @@ def test_the_equal_width_window_is_a_forward_alone_and_none_is_the_old_call():
                       causal=False, window=8)
 
 
+@pytest.mark.parametrize("told", [False, True])
+@pytest.mark.parametrize("heads, kv_heads", [(2, 2), (8, 2), (8, 1)])
+@pytest.mark.parametrize("seq, tile, kept", [
+    (128, None, 40), (512, 128, 40), (512, 128, 200), (512, None, 1)])
+def test_the_equal_width_choice_against_the_reference(
+        seq, tile, kept, heads, kv_heads, told, monkeypatch):
+    """The equal-width forward under ``keep`` (Keye-VL-2.0-30B-A3B's
+    layers): groups of 1, 4 and 8 query heads a key/value head, one block
+    and four a side, told the rows' lengths and not; the last key block of
+    each query's row holds NO chosen key (a whole block the choice left
+    empty, after blocks that held some), and a told row of 30 is shorter
+    than ``kept``."""
+    if tile:
+        monkeypatch.setattr(fa, "flash_tiles",
+                            lambda sq, skv, **kw: (tile, tile))
+    ks = jax.random.split(jax.random.key(7), 3)
+    q = jax.random.normal(ks[0], (2, seq, heads, 32))
+    k = jax.random.normal(ks[1], (2, seq, kv_heads, 32))
+    v = jax.random.normal(ks[2], (2, seq, kv_heads, 32))
+    at = jnp.arange(seq)
+    # no key of a query's own block of 128 but the first query's own
+    early = (at[None, :] < at[:, None] // 128 * 128) | (at[:, None] < 128)
+    keep = some_keys(seq, kept, seed=3) & early
+    keep = keep | (~keep.any(-1, keepdims=True) & (at[None, :] == 0))
+    lengths = jnp.asarray([seq - 70, 30], jnp.int32) if told else None
+    got = attention(q, k, v, impl="flash", keep=keep, lengths=lengths)
+    want = reference_attention(q, k, v, keep=keep)
+    assert got.dtype == q.dtype and got.shape == q.shape
+    for b, n in enumerate(lengths.tolist() if told else [seq, seq]):
+        np.testing.assert_allclose(got[b, :n], want[b, :n], rtol=2e-5,
+                                   atol=2e-5)
+    if told and tile:  # past a row's last live block: zeros
+        assert not np.asarray(got[1, 128:]).any()
+
+
+def test_the_equal_width_choice_is_a_forward_alone_and_none_is_the_old_call():
+    q, k, v, _ = operands(256, seed=5)
+    keep = some_keys(256, 30)
+    with pytest.raises(NotImplementedError, match="attn_impl='reference'"):
+        jax.grad(lambda q: attention(q, k, v, impl="flash",
+                                     keep=keep).sum())(q)
+    text = str(jax.make_jaxpr(lambda q, k, v, keep: attention(
+        q, k, v, impl="flash", keep=keep))(q, k, v, keep))
+    assert fa.EQUAL_SELECTED_TRACE_NAME != fa.SELECTED_TRACE_NAME
+    assert "flash_attention_selected" in text and "i8[2,256,256]" in text
+    # the call without a choice is the one it was: no operand, no name
+    plain = str(jax.make_jaxpr(lambda q, k, v: attention(
+        q, k, v, impl="flash"))(q, k, v))
+    assert "selected" not in plain and "i8[" not in plain
+    np.testing.assert_allclose(
+        attention(q, k, v, impl="flash",
+                  keep=jnp.ones((2, 256, 256), bool)),
+        attention(q, k, v, impl="flash"), rtol=2e-6, atol=2e-6)
+
+
 def test_what_the_kernels_do_not_take():
     q, k, v, rope = operands(128)
-    with pytest.raises(NotImplementedError, match="no choice of keys"):
-        attention(q, k, v, impl="flash", keep=jnp.ones((2, 128, 128), bool))
-    with pytest.raises(ValueError, match="causal keys' last"):
+    with pytest.raises(NotImplementedError, match="not both"):
+        attention(q, k, v, impl="flash", window=8,
+                  keep=jnp.ones((2, 128, 128), bool))
+    with pytest.raises(ValueError, match="comes without a window"):
+        fa._flash_fwd(jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
+                      jnp.swapaxes(v, 1, 2), causal=True,
+                      keep=jnp.ones((2, 128, 64), jnp.int8))
+    with pytest.raises(ValueError, match="of the causal keys"):
         attention(q, k, v, impl="flash", causal=False, window=8)
     with pytest.raises(ValueError, match="a prefill's"):
         attention(q, k, v, impl="flash", causal=False, window=8, **rope)
@@ -271,11 +331,18 @@ def test_what_the_kernels_do_not_take():
         reference_attention(q, k, v, causal=False, window=8)
 
 
-@pytest.mark.parametrize("seq, heads", [(128, 3), (384, 8), (1024, 4)])
-def test_the_index_scores_against_the_einsums(seq, heads):
+@pytest.mark.parametrize("seq, heads, width, tol", [
+    (128, 3, 32, 2e-5), (384, 8, 32, 2e-5), (1024, 4, 32, 2e-5),
+    # Keye-VL-2.0-30B-A3B's indexer: 16 heads of 64, half a lane tile. A
+    # score is a float32 sum over 16 heads of dots over 64 dims where the
+    # cases above sum 3 to 8 heads of 32: scores of 25 to 30 in absolute
+    # value, summed in another order than the einsums', so the tolerance
+    # is the wider by the sum's length
+    (512, 16, 64, 5e-5)])
+def test_the_index_scores_against_the_einsums(seq, heads, width, tol):
     ks = jax.random.split(jax.random.key(4), 3)
-    q = jax.random.normal(ks[0], (2, heads, seq, 32))
-    k = jax.random.normal(ks[1], (2, seq, 32))
+    q = jax.random.normal(ks[0], (2, heads, seq, width))
+    k = jax.random.normal(ks[1], (2, seq, width))
     w = jax.random.normal(ks[2], (2, seq, heads))
     got = index_scores(q, k, w, impl="flash")
     want = reference_index_scores(q, k, w)
@@ -284,7 +351,7 @@ def test_the_index_scores_against_the_einsums(seq, heads):
     causal = at[:, None] >= at[None, :]
     np.testing.assert_allclose(np.where(causal, got, 0),
                                np.where(causal, want, 0),
-                               rtol=2e-5, atol=2e-5)
+                               rtol=tol, atol=tol)
     # the blocks wholly above the diagonal are zeros, not products
     block_q, block_k = ix.index_tiles(seq)
     above = (at[None, :] // block_k * block_k

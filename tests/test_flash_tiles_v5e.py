@@ -442,6 +442,53 @@ def test_the_index_scores_kernel_compiles_within_vmem(
     assert used and 8 * 2 ** 20 < max(used) <= fa.VMEM_LIMIT_BYTES
 
 
+# serve_keye_clipqa's five buckets at Keye-VL-2.0-30B-A3B's widths: the
+# equal-width forward under the indexer's choice (32 query heads on 4 of
+# 128, a [1024, 1024] int8 block of the choice a grid step, told the rows'
+# lengths) and the index-score kernel at 16 heads of 64
+KEYE_BUCKETS = [4096, 5120, 6144, 7168, 8192]
+
+
+@pytest.mark.parametrize("seq", KEYE_BUCKETS)
+def test_the_equal_width_forward_under_a_choice_compiles_within_vmem(
+        seq, one_chip, compiled_for_tpu):
+    def of(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(fa.flash_attention_selected).lower(
+        of(1, seq, 32, 128), of(1, seq, 4, 128), of(1, seq, 4, 128),
+        of(1, seq, seq, dtype=jnp.int8), of(1, dtype=jnp.int32)).compile()
+    assert fa.EQUAL_SELECTED_TRACE_NAME in compiled.as_text()
+    tile = fa.flash_tiles(seq, seq, head_dim=128)
+    assert tile == (1024, 1024)
+    # the tile is the plain forward's (`flash_tiles` is not told of the
+    # choice); its block, a byte a pair in two buffers, comes on top of
+    # that reckoning, and what Mosaic reports stays under the limit
+    reckoned = fa.tile_vmem_bytes(*tile, head_dim=128)
+    used = [n for n in _scoped_vmem(compiled) if n]
+    assert used and max(used) <= min(reckoned + 2 * tile[0] * tile[1],
+                                     fa.VMEM_LIMIT_BYTES)
+
+
+@pytest.mark.parametrize("seq", KEYE_BUCKETS)
+def test_the_index_scores_kernel_compiles_at_16_heads_of_64(
+        seq, one_chip, compiled_for_tpu):
+    from ray_tpu.ops.pallas import index_scores as ix
+
+    def of(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(ix.index_scores_causal).lower(
+        of(1, 16, seq, 64), of(1, seq, 64),
+        of(1, seq, 16, dtype=jnp.float32)).compile()
+    assert ix.TRACE_NAME in compiled.as_text()
+    assert ix.index_tiles(seq) == (256, 512)
+    used = [n for n in _scoped_vmem(compiled) if n]
+    # 16 heads' query rows of 64 pad to the lane width: 2 MB in two
+    # buffers, a quarter of dots3's 64 heads of 128
+    assert used and max(used) < 8 * 2 ** 20
+
+
 # the state-space scan (serve_granite_toolcalls' four buckets) at
 # granite-4.0-h-micro's widths: 64 heads of 64 over a state of 128, chunks
 # of the published 256, 8 heads a grid step. Mosaic takes the blocks (the
